@@ -1,0 +1,386 @@
+"""Chip smoke: the JaxTrainer path on a TPU v5e, through the entry points a
+user calls. The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py             one chip: ray_tpu.init() -> raylet lease
+                                     {"TPU": 1} -> JaxTrainer worker ->
+                                     TrainStep -> GPT-2-124M (bf16, B=16,
+                                     T=1024), compile + 8 steps, flash kernel
+                                     checked against the XLA reference
+    python chip_smoke.py --chips 4   four chips, one worker leased {"TPU": 4}:
+                                     the same model and batch on a
+                                     {"dp": 2, "tp": 2} mesh against a
+                                     one-device mesh, and no other phase
+
+This process never initializes a JAX backend: a chip belongs to one process,
+and that process is the worker whose lease holds it. Everything known about
+the device travels back from that worker through train.report. Any failure
+exits non-zero; there is no retry, no smaller model and no CPU path. The last
+line of stdout is {"ok": true, "device": {...}} only after a run on a TPU.
+
+The script stops every process it starts and says so: after shutdown it lists
+what is left of its session (`processes_left_running`) and fails on any, or if
+the worker that held the chip still exists in any state.
+
+--rehearse runs the same control flow in a sandbox without a chip (tiny
+model, CPU devices, the pallas kernel in interpret mode). It proves paths and
+arguments, measures nothing, and never prints the "ok" line.
+
+Not a benchmark: the step seconds printed here are a sighting, not a metric.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import glob
+import json
+import os
+import sys
+import tempfile
+import time
+
+# Bounds the script holds the run to (bf16 inputs, fp32 accumulation).
+ATTN_REL_TOL = 2e-2   # max|flash - xla| / max|xla|, output and dq, dk, dv
+LOSS_REL_TOL = 2e-2   # |loss_mesh - loss_one_device| / loss_one_device, per step
+
+FULL = {"model": None, "B": 16, "T": 1024, "attn_shape": (16, 12, 1024, 64)}
+TINY = {
+    "model": {"vocab_size": 257, "block_size": 256, "n_layer": 2, "n_head": 4,
+              "n_embd": 64},
+    "B": 4, "T": 256, "attn_shape": (2, 2, 256, 64),
+}
+
+
+# ------------------------------------------------------------- worker side
+
+
+def _setup(config):
+    """Device report, model config and batch; shared by both loops."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.ops.attention import attention_path
+    from ray_tpu.train import _telemetry
+
+    devs = jax.devices()
+    kind = devs[0].device_kind
+    if not config["rehearse"] and devs[0].platform != "tpu":
+        raise RuntimeError(f"worker sees {devs[0].platform!r}, not a TPU")
+    peak = _telemetry.peak_flops_per_device(kind)
+    if not config["rehearse"] and peak is None:
+        raise RuntimeError(f"no peak FLOP/s known for device kind {kind!r}")
+    cache_dir = jax.config.jax_compilation_cache_dir
+    report = {
+        "worker_pid": os.getpid(),
+        "platform": devs[0].platform,
+        "device_kind": kind,
+        "device_count": len(devs),
+        "tpu_visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        "peak_flops_per_device": peak,
+        "attention_path": attention_path(config["T"]),
+        "compile_cache_dir": cache_dir,
+        "compile_cache_entries_before": _cache_entries(cache_dir),
+    }
+    cfg = (
+        GPT2Config.gpt2_124m() if config["model"] is None
+        else GPT2Config(**config["model"])
+    )
+    report.update(
+        model="gpt2_124m" if config["model"] is None else "tiny (rehearsal)",
+        dtype=jax.numpy.dtype(cfg.dtype).name, B=config["B"], T=config["T"])
+    rng = np.random.default_rng(config["seed"])
+    tokens = rng.integers(0, cfg.vocab_size, (config["B"], config["T"] + 1))
+    batch = {"idx": tokens[:, :-1].astype(np.int32),
+             "targets": tokens[:, 1:].astype(np.int32)}
+    return devs, report, cfg, batch
+
+
+def _mem(device, key):
+    return (device.memory_stats() or {}).get(key)  # None on CPU devices
+
+
+def _cache_entries(cache_dir):
+    return len(glob.glob(os.path.join(cache_dir, "*-cache"))) if cache_dir else 0
+
+
+def _run_steps(ts, state, batch, n):
+    """n calls of TrainStep.step, each timed to block_until_ready. Returns
+    per-call (seconds, loss, compiled) — `compiled` is a jit cache miss."""
+    import jax
+
+    out = []
+    for _ in range(n):
+        before = ts._step._cache_size()
+        t0 = time.perf_counter()
+        state, metrics = ts.step(state, batch)
+        jax.block_until_ready((state, metrics))
+        dt = time.perf_counter() - t0
+        out.append((dt, float(metrics["loss"]), ts._step._cache_size() != before))
+    return state, out
+
+
+def _check_losses(losses):
+    import math
+
+    if not all(math.isfinite(x) for x in losses):
+        raise RuntimeError(f"non-finite loss: {losses}")
+    if not losses[-1] < losses[1]:
+        raise RuntimeError(f"loss did not fall: {losses}")
+
+
+def _check_flash_vs_xla(config, on_tpu):
+    """flash_causal_attention against xla_causal_attention, same seed: the
+    output and the three gradients, as max-abs error over the reference's
+    max-abs value."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import flash_causal_attention, xla_causal_attention
+
+    shape = tuple(config["attn_shape"])
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(config["seed"]), 4)
+    q, k, v, w = (jax.random.normal(key, shape, jnp.bfloat16)
+                  for key in (kq, kk, kv, kw))
+
+    def run(attn):
+        def loss(q, k, v):
+            return (attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32)).sum()
+
+        return jax.jit(
+            lambda q, k, v: (attn(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+        )(q, k, v)
+
+    flash = run(functools.partial(flash_causal_attention, interpret=not on_tpu))
+    ref = run(xla_causal_attention)
+    errs = {}
+    for name, a, b in zip(("out", "dq", "dk", "dv"), flash, ref):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        if a.shape != shape or not bool(jnp.isfinite(a).all()):
+            raise RuntimeError(f"flash {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"flash vs xla beyond {ATTN_REL_TOL}: {errs}")
+    return errs
+
+
+def one_chip_loop(config):
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.train_step import TrainStep
+
+    devs, report, cfg, batch = _setup(config)
+    on_tpu = report["platform"] == "tpu"
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=devs[:1]))
+    state = ts.init(jax.random.PRNGKey(config["seed"]))
+    batch = ts.shard_batch(batch)
+    lowered = ts._step.lower(state, batch).as_text()
+    report["tpu_custom_calls_in_lowered_step"] = lowered.count("tpu_custom_call")
+    if on_tpu and not report["tpu_custom_calls_in_lowered_step"]:
+        raise RuntimeError("no tpu_custom_call in the lowered step")
+
+    # the compile step plus 8 steps
+    state, calls = _run_steps(ts, state, batch, 9)
+    losses = [loss for _, loss, _ in calls]
+    _check_losses(losses)
+    report.update(
+        compile_seconds=calls[0][0],
+        step_seconds=[dt for dt, _, _ in calls[1:]],
+        step_recompiled=[c for _, _, c in calls[1:]],
+        losses=losses,
+        peak_bytes_in_use=_mem(devs[0], "peak_bytes_in_use"),
+    )
+    if on_tpu and not report["peak_bytes_in_use"]:
+        raise RuntimeError("device reports no peak_bytes_in_use")
+    del state
+    report["flash_vs_xla_rel_err"] = _check_flash_vs_xla(config, on_tpu)
+    report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])
+    train.report(report)
+
+
+def four_chip_loop(config):
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.train_step import TrainStep
+
+    devs, report, cfg, batch = _setup(config)
+    on_tpu = report["platform"] == "tpu"
+    if len(devs) != 4:
+        raise RuntimeError(f"worker leased 4 chips sees {len(devs)} devices")
+    axes = {"dp": 2, "tp": 2}
+    runs = {}
+    for name, mesh in (
+        ("mesh", make_mesh(axes, devices=devs)),
+        ("one_device", make_mesh({"dp": 1}, devices=devs[:1])),
+    ):
+        ts = TrainStep(cfg, mesh)
+        state = ts.init(jax.random.PRNGKey(config["seed"]))
+        if name == "mesh":
+            # the layout GPT2_SHARDING_RULES promise: c_attn kernel P(fsdp, tp)
+            qkv = state["params"]["h_0"]["attn"]["c_attn"]["kernel"]
+            got = [tuple(s.data.shape) for s in qkv.addressable_shards]
+            want = (qkv.shape[0], qkv.shape[1] // axes["tp"])
+            if len(got) != 4 or any(g != want for g in got):
+                raise RuntimeError(f"qkv shards {got}, expected 4 x {want}")
+            report["qkv_shard_shape"] = list(want)
+            report["bytes_in_use_per_device"] = [
+                _mem(d, "bytes_in_use") for d in devs]
+            if on_tpu and not all(report["bytes_in_use_per_device"]):
+                raise RuntimeError("a device reports no bytes in use")
+        sharded = ts.shard_batch(batch)
+        state, calls = _run_steps(ts, state, sharded, 5)
+        if name == "mesh":
+            text = ts._step.lower(state, sharded).compile().as_text()
+            report["all_reduces_in_compiled_step"] = text.count("all-reduce(")
+            report["tpu_custom_calls_in_compiled_step"] = text.count("tpu_custom_call")
+            if not report["all_reduces_in_compiled_step"]:
+                raise RuntimeError("no all-reduce in the compiled mesh step")
+            if on_tpu and not report["tpu_custom_calls_in_compiled_step"]:
+                raise RuntimeError("no tpu_custom_call in the compiled mesh step")
+        del state
+        runs[name] = calls
+    # compile step + four steps each; compare the four
+    mesh_losses = [loss for _, loss, _ in runs["mesh"]]
+    ref_losses = [loss for _, loss, _ in runs["one_device"]]
+    _check_losses(mesh_losses)
+    _check_losses(ref_losses)
+    rel = [abs(a - b) / abs(b) for a, b in zip(mesh_losses, ref_losses)]
+    if max(rel) > LOSS_REL_TOL:
+        raise RuntimeError(
+            f"mesh vs one-device losses beyond {LOSS_REL_TOL}: "
+            f"{mesh_losses} vs {ref_losses}")
+    report.update(
+        mesh=axes,
+        losses_mesh=mesh_losses, losses_one_device=ref_losses,
+        loss_rel_diff=rel,
+        compile_seconds={k: v[0][0] for k, v in runs.items()},
+        step_seconds={k: [dt for dt, _, _ in v[1:]] for k, v in runs.items()},
+        step_recompiled={k: [c for _, _, c in v[1:]] for k, v in runs.items()},
+        peak_bytes_in_use_per_device=[
+            _mem(d, "peak_bytes_in_use") for d in devs],
+        compile_cache_entries_after=_cache_entries(report["compile_cache_dir"]),
+    )
+    train.report(report)
+
+
+# ------------------------------------------------------------- driver side
+
+
+def _print_worker_err_logs(session_dir, tail=6000):
+    """The chip tool shows only the end of the output: on failure, put the
+    end of each worker's .err log there."""
+    for path in sorted(glob.glob(os.path.join(session_dir, "logs", "worker-*.err"))):
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(0, f.tell() - tail))
+            text = f.read().decode(errors="replace").strip()
+        if text:
+            print(f"--- tail of {path}\n{text}", flush=True)
+
+
+def _left_running(session_dir):
+    """Processes of this run that are still there: everything the runtime
+    starts (GCS, raylet, agent, fork server, workers) names its session
+    directory on its command line."""
+    left = []
+    for path in glob.glob("/proc/[0-9]*/cmdline"):
+        try:
+            with open(path, "rb") as f:
+                cmd = f.read().replace(b"\0", b" ").decode(errors="replace")
+        except OSError:
+            continue  # gone between the listing and the read
+        if session_dir in cmd:
+            left.append(f"{path.split('/')[2]} {cmd[:160]}")
+    return left
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny model on CPU devices; never prints the ok line")
+    args = ap.parse_args()
+
+    import ray_tpu
+    from ray_tpu import api
+    from ray_tpu.train import JaxConfig, JaxTrainer, RunConfig, ScalingConfig
+
+    config = {**(TINY if args.rehearse else FULL),
+              "seed": args.seed, "rehearse": args.rehearse}
+    worker_env = {}
+    if args.rehearse:
+        ray_tpu.init(num_cpus=4, num_tpus=args.chips)
+        worker_env = {
+            "JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": f"--xla_force_host_platform_device_count={args.chips}",
+        }
+    else:
+        ray_tpu.init()  # resources autodetected: the node must find its chips
+    passed = False
+    try:
+        tpus = ray_tpu.cluster_resources().get("TPU", 0)
+        print(json.dumps({"node_resources": ray_tpu.cluster_resources()}), flush=True)
+        if tpus < args.chips:
+            raise SystemExit(
+                f"chip_smoke: node advertises TPU={tpus}, need {args.chips}")
+        if args.chips == 1:
+            loop, scaling = one_chip_loop, ScalingConfig(num_workers=1, use_tpu=True)
+        else:
+            loop, scaling = four_chip_loop, ScalingConfig(
+                num_workers=1, resources_per_worker={"TPU": 4})
+        result = JaxTrainer(
+            loop,
+            train_loop_config=config,
+            scaling_config=scaling,
+            jax_config=JaxConfig(env=worker_env),
+            run_config=RunConfig(
+                name="chip_smoke",
+                storage_path=tempfile.mkdtemp(prefix="chip_smoke_")),
+        ).fit()
+        report = {k: v for k, v in result.metrics.items()
+                  if not k.startswith("telemetry/")}
+        for key, value in report.items():
+            print(json.dumps({key: value}), flush=True)
+        if report["device_count"] != args.chips:
+            raise SystemExit(
+                f"chip_smoke: worker saw {report['device_count']} devices, "
+                f"leased {args.chips}")
+        jax = sys.modules.get("jax")
+        if jax is not None and jax._src.xla_bridge.backends_are_initialized():
+            raise SystemExit("chip_smoke: the parent initialized a JAX backend")
+        print(json.dumps({"parent_imported_jax": jax is not None,
+                          "parent_jax_backends_initialized": False}), flush=True)
+        passed = True
+    finally:
+        session_dir = api._local_node.session_dir
+        if not passed:
+            _print_worker_err_logs(session_dir)
+        t0 = time.perf_counter()
+        ray_tpu.shutdown()
+        left = _left_running(session_dir)
+        print(json.dumps({"shutdown_seconds": time.perf_counter() - t0,
+                          "processes_left_running": left}), flush=True)
+    if left:
+        raise SystemExit("chip_smoke: shutdown left processes running")
+    # A killed worker has no command line any more, but holds its chips until
+    # the kernel is done with it and it is reaped.
+    if os.path.exists(f"/proc/{report['worker_pid']}"):
+        raise SystemExit(
+            f"chip_smoke: worker {report['worker_pid']} is not gone yet")
+    device = {"platform": report["platform"], "kind": report["device_kind"],
+              "count": report["device_count"]}
+    if args.rehearse:
+        print(json.dumps({"rehearsal": "passed", "device": device}), flush=True)
+        return
+    if device["platform"] != "tpu":
+        raise SystemExit(f"chip_smoke: ran on {device['platform']!r}, not a TPU")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -385,9 +385,20 @@ class GcsServer:
     async def _health_check_loop(self):
         period = RTPU_CONFIG.health_check_period_ms / 1000.0
         threshold = RTPU_CONFIG.health_check_failure_threshold
+        checked = time.time()
         while True:
             await asyncio.sleep(period)
             now = time.time()
+            # A checker that was not running has seen nothing. When this loop
+            # wakes late — this event loop was blocked, or the whole host
+            # froze: libtpu bringing up four v5e chips stops every process
+            # on the host for up to 13 s at a time (PR 22) — the beats it
+            # could not receive were not missed. Credit the overshoot.
+            frozen = now - checked - period
+            if frozen > period:
+                for node_id in self.node_last_beat:
+                    self.node_last_beat[node_id] += frozen
+            checked = now
             for node_id, info in list(self.nodes.items()):
                 if info["state"] != "ALIVE":
                     continue

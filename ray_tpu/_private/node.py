@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 import os
+import signal
 import subprocess
 import sys
 import tempfile
@@ -46,6 +47,20 @@ def new_session_dir(base: Optional[str] = None) -> str:
     session = os.path.join(base, f"session_{time.strftime('%Y-%m-%d_%H-%M-%S')}_{os.getpid()}_{uuid.uuid4().hex[:6]}")
     os.makedirs(os.path.join(session, "logs"), exist_ok=True)
     return session
+
+
+def _kill_session(pgid: int, timeout: float = 3.0):
+    """SIGKILL whatever is left in the process group of a child started with
+    start_new_session=True (it led the group), and wait until it is empty.
+    After a clean exit there is nothing; after a kill -9 of a raylet its
+    agent and fork server are."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        try:
+            os.killpg(pgid, signal.SIGKILL)
+        except (ProcessLookupError, PermissionError):
+            return
+        time.sleep(0.02)
 
 
 class Node:
@@ -197,18 +212,21 @@ class Node:
             # Let an in-flight restart finish (and self-reap) before we
             # sweep self.processes, so no freshly-spawned GCS escapes.
             self._gcs_monitor.join(timeout=5.0)
-        for proc in self.processes.values():
-            try:
+        # Raylets before the GCS: a raylet's own shutdown kills and reaps its
+        # agent, fork server and workers, and still talks to the GCS.
+        raylets = [p for n, p in self.processes.items() if n.startswith("raylet")]
+        others = [p for p in self.processes.values() if p not in raylets]
+        # A raylet waits up to 60 s for killed workers to be reaped (one that
+        # held TPU chips takes seconds to give them back).
+        for procs, patience in ((raylets, 70), (others, 5)):
+            for proc in procs:
                 proc.terminate()
-            except Exception:
-                pass
-        deadline = time.time() + 3
-        for proc in self.processes.values():
-            try:
-                proc.wait(max(0.1, deadline - time.time()))
-            except Exception:
+            deadline = time.time() + patience
+            for proc in procs:
                 try:
+                    proc.wait(max(0.1, deadline - time.time()))
+                except subprocess.TimeoutExpired:
                     proc.kill()
-                except Exception:
-                    pass
+                    proc.wait()
+                _kill_session(proc.pid)
         self.processes.clear()

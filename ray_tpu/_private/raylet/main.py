@@ -23,6 +23,7 @@ import argparse
 import asyncio
 import logging
 import os
+import signal
 import sys
 import time
 from collections import deque
@@ -75,10 +76,16 @@ class NodeManager:
         self.labels = labels
         self._resources_dirty = True
         # Per-instance accelerator IDs (reference: scheduling_ids.h:162 —
-        # GPU_0-style instances; here TPU chip ids). Integer-TPU leases get
-        # specific chips via TPU_VISIBLE_CHIPS so two concurrent workers
-        # never see the same chip; fractional demands share the pool.
+        # GPU_0-style instances; here TPU chip ids). One process per chip:
+        # integer-TPU leases get specific chips via TPU_VISIBLE_CHIPS so two
+        # concurrent workers never see the same chip, and on a node that has
+        # chips a zero-TPU lease gets an env in which jax cannot reach them
+        # (a chip belongs to the first process that initializes it);
+        # fractional demands share the pool.
         self._free_chips: List[int] = list(range(int(resources.get("TPU", 0))))
+        self._no_chip_env: Dict[str, str] = (
+            accelerators.hidden_chip_env() if self._free_chips else {}
+        )
 
         self.plasma_name = f"/rtpu_plasma_{node_id.hex()[:12]}"
         self.plasma = PlasmaClient(
@@ -743,16 +750,19 @@ class NodeManager:
             return {"demand": demand, "bundle": bundle_key}
         return None
 
-    def _allocate_chips(self, num_tpu: float) -> Optional[List[int]]:
-        """Assign specific chip ids to an integer-TPU lease; None when the
-        demand is fractional/zero (worker then sees the node default)."""
-        if num_tpu <= 0 or num_tpu != int(num_tpu):
-            return None
+    def _allocate_chips(
+        self, num_tpu: float
+    ) -> Tuple[Optional[List[int]], Dict[str, str]]:
+        """(chip ids, worker env) for a lease: an integer-TPU lease owns
+        specific chips; a zero-TPU lease is kept off the node's chips; a
+        fractional one gets no assignment and sees the node default."""
+        if not num_tpu:
+            return None, self._no_chip_env
         n = int(num_tpu)
-        if len(self._free_chips) < n:
-            return None
+        if num_tpu != n or len(self._free_chips) < n:
+            return None, {}
         chips, self._free_chips = self._free_chips[:n], self._free_chips[n:]
-        return chips
+        return chips, accelerators.visible_chip_env(chips)
 
     def _release_lease(self, lease_id: bytes):
         lease = self.leases.pop(lease_id, None)
@@ -1014,10 +1024,8 @@ class NodeManager:
                                                                strategy):
                 grant = self._try_acquire(resources, strategy)
             if grant is not None:
-                chips = self._allocate_chips(resources.get("TPU", 0))
-                worker_env = dict(env_overrides or {})
-                if chips is not None:
-                    worker_env.update(accelerators.visible_chip_env(chips))
+                chips, chip_env = self._allocate_chips(resources.get("TPU", 0))
+                worker_env = {**(env_overrides or {}), **chip_env}
                 handle = await self.worker_pool.pop_worker(
                     job_id, worker_env or None
                 )
@@ -1276,7 +1284,8 @@ class NodeManager:
 
     async def _ring_spawn(self, ring: dict, grant: dict, first: dict):
         try:
-            handle = await self.worker_pool.pop_worker(ring["job_id"], None)
+            handle = await self.worker_pool.pop_worker(
+                ring["job_id"], self._no_chip_env or None)
         except Exception:
             logger.exception("ring worker spawn failed")
             handle = None
@@ -1387,14 +1396,10 @@ class NodeManager:
             pool, _ = self._pool_for(req.get("strategy", {}))
             pool.release(grant["demand"])
             return {"granted": False, "error": f"runtime_env setup failed: {e}"}
-        chips = self._allocate_chips(req["resources"].get("TPU", 0))
-        if chips is not None:
-            env.update(accelerators.visible_chip_env(chips))
+        chips, chip_env = self._allocate_chips(req["resources"].get("TPU", 0))
+        env = {**env, **chip_env}
         spec = req.get("spec")
-        spawn_extra = {
-            "node_id": self.node_id.hex(),
-            "plasma_name": self.plasma_name,
-        }
+        spawn_extra = {}
         sys_path = await self._job_sys_path(req["job_id"])
         if sys_path is not None:
             # None = transiently unknown: omit so the child runs its own
@@ -3033,18 +3038,23 @@ class NodeManager:
         _fr.flush_now()
         for t in self._bg:
             t.cancel()
+        # Children first, and reaped: nothing this raylet started is left
+        # running, or on its way out, when its own process ends.
         proc = getattr(self, "_agent_proc", None)
         if proc is not None:
             self._agent_proc = None
-            try:
-                proc.kill()
-            except Exception:
-                pass
-            try:
-                await asyncio.wait_for(self._deregister_agent(), timeout=5)
-            except Exception:
-                pass
-        self.worker_pool.shutdown()
+            proc.kill()
+            proc.wait()
+        await self.worker_pool.shutdown()
+        # Tell the GCS now; it would otherwise learn from missed heartbeats
+        # and keep routing work here meanwhile.
+        try:
+            if proc is not None:
+                await asyncio.wait_for(self._deregister_agent(), timeout=2)
+            await self.gcs.call(
+                "UnregisterNode", {"node_id": self.node_id.binary()}, timeout=2)
+        except Exception:
+            pass
         await self.server.stop()
         self.plasma.close()
         PlasmaClient.unlink(self.plasma_name)
@@ -3081,6 +3091,11 @@ def main(argv=None):
         labels.setdefault(k, v)
 
     async def run():
+        # SIGTERM is how Node.shutdown stops a raylet: take the agent, the
+        # fork server and the workers down with it instead of orphaning them.
+        stop = asyncio.Event()
+        for sig in (signal.SIGTERM, signal.SIGINT):
+            asyncio.get_running_loop().add_signal_handler(sig, stop.set)
         nm = NodeManager(
             node_id, args.host, args.gcs_address, resources, labels,
             args.session_dir, is_head=args.is_head,
@@ -3092,7 +3107,8 @@ def main(argv=None):
             with open(tmp, "w") as f:
                 f.write(str(port))
             os.replace(tmp, args.port_file)
-        await asyncio.Event().wait()
+        await stop.wait()
+        await nm.shutdown()
 
     asyncio.run(run())
 

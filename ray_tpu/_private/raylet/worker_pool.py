@@ -227,6 +227,10 @@ class WorkerPool:
             "job_id": job_id.hex(),
             "env": env_overrides or {},
             "log_prefix": log_prefix,
+            # The worker attaches plasma before it registers: a task can be
+            # pushed to it before the RegisterWorker reply is back.
+            "node_id": self._node_id.hex(),
+            "plasma_name": self._plasma_name,
         }
         if spawn_extra:
             msg.update(spawn_extra)
@@ -390,8 +394,10 @@ class WorkerPool:
 
     @staticmethod
     def _kill_pid(pid: int):
+        # A worker leads its own group (setsid). Before its setsid it is
+        # still in the raylet's group, which killpg(getpgid(pid)) would hit.
         try:
-            os.killpg(os.getpgid(pid), 9)
+            os.killpg(pid, 9)
         except Exception:
             try:
                 os.kill(pid, 9)
@@ -432,7 +438,10 @@ class WorkerPool:
             if h.job_id == job_id and not h.actor_id:
                 asyncio.ensure_future(self.kill_worker(h))
 
-    def shutdown(self):
+    async def shutdown(self):
+        """Kill every worker and the fork server, and return only when they
+        are gone: whoever stops the raylet must find no process of this node
+        afterwards, not one that is still on its way out."""
         # include workers still starting (forked but not yet registered)
         handles = (
             set(self.workers.values())
@@ -443,11 +452,24 @@ class WorkerPool:
             h.expected_death = True
             if h.pid:
                 self._kill_pid(h.pid)
-        if self._fs_proc is not None and self._fs_proc.returncode is None:
+        for _, proc in self._exec_procs:
+            if proc.poll() is None:
+                self._kill_pid(proc.pid)
+                proc.wait()
+        self._exec_procs.clear()
+        fs = self._fs_proc
+        if fs is not None and fs.returncode is None:
+            # EOF on its stdin: the fork server reaps its (killed) workers
+            # and exits by itself. A killed worker that held TPU chips is
+            # seconds in the kernel giving them back (6 s for one v5e chip,
+            # 16 s for four; PR 22), and holds them until it is reaped — so
+            # wait that long.
+            fs.stdin.close()
             try:
-                self._fs_proc.kill()
-            except Exception:
-                pass
+                await asyncio.wait_for(fs.wait(), 60)
+            except asyncio.TimeoutError:
+                fs.kill()
+                await fs.wait()
 
     def num_idle(self) -> int:
         return len(self._idle)

@@ -342,7 +342,13 @@ class CoreWorker:
         # submission at ~3k tasks/s — reference analogue: the Cython layer
         # posts into the asio io_service without a per-call thread switch).
         self._loop_work: deque = deque()
-        self._loop_work_lock = threading.Lock()
+        # Nothing GC-tracked is allocated under this lock, and it is
+        # re-entrant: an allocation can run the cyclic GC, whose
+        # ObjectRef.__del__ -> _on_ref_zero -> _post_batched re-enters on the
+        # same thread (a plain Lock deadlocked the driver's io loop there)
+        # or, from another thread holding the ref-counter lock, inverts the
+        # lock order.
+        self._loop_work_lock = threading.RLock()
         self._loop_work_scheduled = False
         # executor-side reply streaming for batched actor-task pushes
         self._reply_bufs: Dict[tuple, list] = {}
@@ -660,8 +666,9 @@ class CoreWorker:
     def _post_batched(self, kind: str, item):
         """Queue loop-side work from a foreign thread with one io-loop
         wakeup per burst instead of one run_coroutine_threadsafe per call."""
+        entry = (kind, item)  # allocated outside the lock: see its comment
         with self._loop_work_lock:
-            self._loop_work.append((kind, item))
+            self._loop_work.append(entry)
             if self._loop_work_scheduled:
                 return
             self._loop_work_scheduled = True
@@ -673,10 +680,13 @@ class CoreWorker:
     def _drain_loop_work(self):
         """Runs on the io loop: route every queued item, then kick each
         touched pump exactly once."""
+        fresh: deque = deque()
         with self._loop_work_lock:
-            work = self._loop_work
-            self._loop_work = deque()
+            # flag first: a re-entrant post (see the lock) during the swap
+            # then schedules another drain instead of stranding its item
             self._loop_work_scheduled = False
+            work = self._loop_work
+            self._loop_work = fresh
         normal_states: Dict[tuple, _LeaseState] = {}
         actor_subs: Dict[bytes, _ActorSubmitter] = {}
         frees: list = []
@@ -1761,9 +1771,15 @@ class CoreWorker:
                     await self._return_lease(state, lease)
             elif reply.get("spill"):
                 target = reply["spill"]
-                peer = await self.pool.get(target["ip"], target["port"])
                 state.requests_in_flight += 1
-                if hops < 4:
+                try:
+                    peer = await self.pool.get(target["ip"], target["port"])
+                except OSError:
+                    # spill target died before the cluster view caught up:
+                    # back to the local raylet, but not in a hot loop
+                    await asyncio.sleep(0.1)
+                    peer = None
+                if peer is not None and hops < 4:
                     asyncio.ensure_future(self._request_lease(key, state, peer, hops + 1))
                 else:
                     asyncio.ensure_future(self._request_lease(key, state))

@@ -26,7 +26,7 @@ import sys
 import threading
 
 
-def _reaper(out_lock):
+def _reaper(out_lock, children):
     while True:
         try:
             pid, status = os.waitpid(-1, 0)
@@ -38,9 +38,23 @@ def _reaper(out_lock):
             continue
         except InterruptedError:
             continue
+        children.discard(pid)
         rc = os.waitstatus_to_exitcode(status)
         with out_lock:
             print(json.dumps({"dead": pid, "rc": rc}), flush=True)
+
+
+def _kill_group(pid):
+    """SIGKILL a worker and whatever it started: after its setsid the worker
+    leads group `pid` (before it, it is still in the raylet's group, which
+    must not be hit)."""
+    try:
+        os.killpg(pid, signal.SIGKILL)
+    except (ProcessLookupError, PermissionError):
+        try:
+            os.kill(pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
 
 
 def _child_main(args, spawn):
@@ -70,20 +84,15 @@ def _child_main(args, spawn):
             if p and p not in sys.path:
                 sys.path.insert(0, p)
         importlib.invalidate_caches()
-    # If jax was preimported (by us or a plugin), its platform config may
-    # have been baked at import time — some platform plugins even force
-    # their own value, ignoring the env. Re-sync from the (inherited +
+    # If something preimported jax, its platform config was read from the
+    # fork server's environment at import time. Re-sync from the (inherited +
     # overridden) environment before any backend initializes, so workers
-    # honor JAX_PLATFORMS/XLA_FLAGS exactly like a fresh process would.
+    # honor JAX_PLATFORMS exactly like a fresh process would — the raylet
+    # keeps zero-TPU leases off the node's chips through it.
     if "jax" in sys.modules:
-        try:
-            import jax
+        import jax
 
-            jax.config.update(
-                "jax_platforms", os.environ.get("JAX_PLATFORMS") or None
-            )
-        except Exception:
-            pass
+        jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS") or None)
     log_prefix = spawn.get("log_prefix", "")
     if log_prefix:
         out = open(log_prefix + ".out", "ab", buffering=0)
@@ -232,7 +241,9 @@ def main(argv=None):
 
     out_lock = threading.Lock()
     signal.signal(signal.SIGCHLD, signal.SIG_DFL)
-    threading.Thread(target=_reaper, args=(out_lock,), daemon=True).start()
+    children = set()  # pids of forked workers not yet reaped
+    threading.Thread(
+        target=_reaper, args=(out_lock, children), daemon=True).start()
     with out_lock:
         print(json.dumps({"ready": True}), flush=True)
 
@@ -256,16 +267,21 @@ def main(argv=None):
                     traceback.print_exc()
                 finally:
                     os._exit(1)
+            children.add(pid)
             with out_lock:
                 print(json.dumps({"spawned": spawn["token"], "pid": pid}), flush=True)
         elif "kill" in req:
-            try:
-                os.killpg(os.getpgid(req["kill"]), signal.SIGKILL)
-            except (ProcessLookupError, PermissionError):
-                try:
-                    os.kill(req["kill"], signal.SIGKILL)
-                except ProcessLookupError:
-                    pass
+            _kill_group(req["kill"])
+    # EOF: the raylet closed the pipe or died. Workers die with their raylet:
+    # kill what is left (each leads its own group) and stay until the last is
+    # reaped, so none outlives this process as an orphan or a zombie.
+    for pid in list(children):
+        _kill_group(pid)
+    while True:
+        try:
+            os.waitpid(-1, 0)
+        except ChildProcessError:
+            return
 
 
 if __name__ == "__main__":

@@ -89,6 +89,8 @@ class Cluster:
 
         if ray_tpu.is_initialized():
             ray_tpu.shutdown()
-        for node in self.nodes:
+        # the head (first) goes last: the other raylets' shutdown still
+        # talks to its GCS
+        for node in reversed(self.nodes):
             node.shutdown()
         self.nodes.clear()

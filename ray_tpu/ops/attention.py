@@ -295,23 +295,32 @@ def xla_causal_attention(q, k, v):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # No try/except: a worker that cannot reach its chip must fail here, not
+    # train on the XLA path in silence.
+    return jax.devices()[0].platform == "tpu"
+
+
+def attention_path(seq_len: int) -> str:
+    """Which path `causal_attention` takes for this sequence length on this
+    process's backend: "flash" (the pallas kernel) or "xla"."""
+    if _on_tpu() and seq_len >= 256 and seq_len % 128 == 0:
+        return "flash"
+    return "xla"
 
 
 def causal_attention(q, k, v):
     """Layout-adapting entry: q/k/v (B, T, H, D) → (B, T, H, D).
 
     Uses the pallas flash kernel on TPU for sequences long enough to matter;
-    XLA path elsewhere (CPU tests, tiny shapes).
+    XLA path elsewhere (CPU tests, tiny shapes). A Mosaic kernel cannot be
+    partitioned by the compiler: under a multi-device mesh call it through
+    `parallel.train_step.attn_for_mesh` (shard_map over batch and heads).
     """
-    B, T, H, D = q.shape
+    T = q.shape[1]
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if _on_tpu() and T >= 256 and T % 128 == 0:
+    if attention_path(T) == "flash":
         o = flash_causal_attention(qt, kt, vt)
     else:
         o = xla_causal_attention(qt, kt, vt)

@@ -102,25 +102,36 @@ def _key_str(k) -> str:
     return str(k)
 
 
-def filter_spec_for_mesh(spec: P, mesh: Mesh) -> P:
+def filter_spec_for_mesh(spec: P, mesh: Mesh, shape=None) -> P:
     """Drop axis names the mesh doesn't have (lets one rule set serve many
-    mesh shapes — e.g. tp rules are no-ops on a pure-dp mesh)."""
-    def keep(entry):
-        if entry is None:
+    mesh shapes — e.g. tp rules are no-ops on a pure-dp mesh) and, given the
+    array's shape, axes whose size does not divide their dimension: GPT-2's
+    V=50257 embedding stays replicated over tp=2 instead of failing to place.
+    """
+    def keep(i, entry):
+        names = entry if isinstance(entry, (tuple, list)) else (entry,)
+        kept, span = [], 1
+        for e in names:
+            if e is None or e not in mesh.axis_names or mesh.shape[e] == 1:
+                continue
+            if shape is not None and shape[i] % (span * mesh.shape[e]):
+                continue
+            kept.append(e)
+            span *= mesh.shape[e]
+        if not kept:
             return None
-        if isinstance(entry, (tuple, list)):
-            kept = tuple(e for e in entry if e in mesh.axis_names and mesh.shape[e] > 1)
-            return kept if kept else None
-        return entry if entry in mesh.axis_names and mesh.shape[entry] > 1 else None
+        return tuple(kept) if isinstance(entry, (tuple, list)) else kept[0]
 
-    return P(*(keep(e) for e in spec))
+    return P(*(keep(i, e) for i, e in enumerate(spec)))
 
 
 def filtered_tree_specs(rules: ShardingRules, tree, mesh: Mesh):
-    """Rule-derived PartitionSpecs with axes the mesh lacks dropped."""
+    """Rule-derived PartitionSpecs with axes the mesh lacks, or that do not
+    divide the leaf's dimension, dropped."""
     specs = rules.tree_specs(tree)
-    return jax.tree.map(lambda s: filter_spec_for_mesh(s, mesh), specs,
-                        is_leaf=lambda x: isinstance(x, P))
+    return jax.tree.map(
+        lambda s, leaf: filter_spec_for_mesh(s, mesh, getattr(leaf, "shape", None)),
+        specs, tree, is_leaf=lambda x: isinstance(x, P))
 
 
 def filtered_tree_shardings(rules: ShardingRules, tree, mesh: Mesh):

@@ -39,16 +39,6 @@ from ray_tpu.parallel.mesh import (
 )
 
 
-def _jit_cache_size(fn) -> int:
-    """Compiled-executable count of a jitted callable; -1 when the private
-    probe is unavailable (telemetry then falls back to first-call-only
-    compile detection)."""
-    try:
-        return fn._cache_size()
-    except Exception:
-        return -1
-
-
 def _batch_counts(batch) -> Tuple[Optional[int], Optional[int]]:
     """(tokens, examples) in a batch dict for telemetry: the idx array's
     element count is token count, its second-to-last dim is batch size
@@ -66,40 +56,50 @@ def _batch_counts(batch) -> Tuple[Optional[int], Optional[int]]:
         return None, None
 
 
-def _ring_attn_for_mesh(mesh: Mesh, seq_axis: str = "sp"):
-    """Attention callable for GPT2Config.attn_fn: ring attention over the
-    sequence axis via shard_map, local flash attention per chunk-pair."""
+def attn_for_mesh(mesh: Mesh, seq_axis: str = "sp"):
+    """Attention callable for a config's attn_fn on a multi-device mesh:
+    shard_map over batch (dp, fsdp) and heads (tp), so the pallas flash
+    kernel — which the compiler cannot partition — stays in the step; with
+    sp > 1 the per-shard body is ring attention over the sequence axis,
+    local flash attention per chunk-pair."""
     from jax import shard_map
 
+    from ray_tpu.ops.attention import causal_attention
     from ray_tpu.ops.ring_attention import ring_causal_attention
 
-    data = tuple(
-        a for a in ("dp", "fsdp") if a in mesh.axis_names and mesh.shape[a] > 1
-    )
-    tp = "tp" if "tp" in mesh.axis_names and mesh.shape["tp"] > 1 else None
-    spec = P(data if data else None, seq_axis, tp, None)  # (B, T, H, D)
+    def live(a):
+        return a in mesh.axis_names and mesh.shape[a] > 1
 
-    fn = shard_map(
-        functools.partial(ring_causal_attention, axis_name=seq_axis),
+    data = tuple(a for a in ("dp", "fsdp") if live(a))
+    spec = P(  # (B, T, H, D)
+        data if data else None,
+        seq_axis if live(seq_axis) else None,
+        "tp" if live("tp") else None,
+        None,
+    )
+    body = (
+        functools.partial(ring_causal_attention, axis_name=seq_axis)
+        if live(seq_axis) else causal_attention
+    )
+    return shard_map(
+        body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
         check_vma=False,
     )
-    return fn
 
 
 def model_for_mesh(cfg, mesh: Optional[Mesh]):
-    """Instantiate the model wired for this mesh: ring attention iff sp > 1;
-    config type picks the family (GPT2 / GPT2MoE with an ep axis / Llama)."""
+    """Instantiate the model wired for this mesh: shard_map'd attention on
+    more than one device (ring attention iff sp > 1); config type picks the
+    family (GPT2 / GPT2MoE with an ep axis / Llama)."""
     import dataclasses
 
-    if (
-        mesh is not None
-        and "sp" in mesh.axis_names
-        and mesh.shape["sp"] > 1
+    if mesh is not None and cfg.attn_fn is None and mesh.devices.size > 1 and (
+        cfg.use_flash_attention or mesh.shape.get("sp", 1) > 1
     ):
-        cfg = dataclasses.replace(cfg, attn_fn=_ring_attn_for_mesh(mesh))
+        cfg = dataclasses.replace(cfg, attn_fn=attn_for_mesh(mesh))
     from ray_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEConfig
     from ray_tpu.models.llama import Llama, LlamaConfig
 
@@ -108,10 +108,6 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
     if isinstance(cfg, LlamaConfig):
         return Llama(cfg)
     return GPT2(cfg)
-
-
-# Backwards-compatible alias (pre-Llama name).
-gpt2_model_for_mesh = model_for_mesh
 
 
 def default_rules_for(cfg) -> ShardingRules:
@@ -218,7 +214,6 @@ class TrainStep:
             donate_argnums=(0,),
         )
         self._step_fn = step_fn
-        self._traced = False
         self._multi: Dict[int, Any] = {}
         self._tiled_cache = None
         # Step-level telemetry (train/_telemetry.py): wall time per step,
@@ -248,11 +243,11 @@ class TrainStep:
         return jax.device_put(batch, self.batch_sharding)
 
     def step(self, state, batch) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-        # No mesh context on the hot path: in/out shardings are explicit
-        # NamedShardings, so dispatch doesn't need the ambient mesh — the
-        # context manager costs real per-step Python time at small step
-        # sizes. First call traces under the mesh (shard_map ring attention
-        # resolves its axis names there), then cached dispatch skips it.
+        # No mesh context, on the first call or any other: in/out shardings
+        # are explicit NamedShardings and the shard_map'd attention carries
+        # its mesh, so neither tracing nor dispatch needs the ambient one.
+        # The jit cache key includes the ambient mesh: entering it for the
+        # first call only would compile every step twice.
         rec = self.telemetry
         if rec is None:
             # Telemetry off: the flight recorder still gets a breadcrumb
@@ -261,36 +256,18 @@ class TrainStep:
             # is the layer that answers it post-mortem.
             from ray_tpu._private import flight_recorder as _fr
 
-            if self._traced:
-                _fr.record("train.step", b"", "dispatch")
-                return self._step(state, batch)
-            _fr.record("train.step", b"", "trace+compile")
-            with self.mesh:
-                out = self._step(state, batch)
-            self._traced = True
-            return out
+            _fr.record("train.step", b"", "dispatch")
+            return self._step(state, batch)
         # Device-trace hook (train/_telemetry.DeviceTraceController): inert
         # two-attribute check unless a jax.profiler window was armed.
         rec.device_trace.on_step_begin()
         t0 = time.perf_counter()
-        was_traced = self._traced
-        cache_before = _jit_cache_size(self._step)
-        if self._traced:
-            out = self._step(state, batch)
-        else:
-            with self.mesh:
-                out = self._step(state, batch)
-            self._traced = True
+        cache_before = self._step._cache_size()
+        out = self._step(state, batch)
         # Compile detection by actual jit cache miss (not just first-call):
-        # the cache key includes the ambient mesh context, so the first
-        # call after the traced flag flips recompiles too — both must be
+        # a new batch shape recompiles too, and every compile must be
         # booked as compile time, not step time.
-        cache_after = _jit_cache_size(self._step)
-        compiled = (
-            cache_after != cache_before
-            if cache_before >= 0 and cache_after >= 0
-            else not was_traced
-        )
+        compiled = self._step._cache_size() != cache_before
         if compiled:
             # Contain the whole compile + first execution in THIS record:
             # without the sync, the async backlog drains inside the next
@@ -317,8 +294,7 @@ class TrainStep:
         Returns (state, metrics) with metrics stacked over steps."""
         key = num_steps
         fn = self._multi.get(key)
-        first = fn is None
-        if first:
+        if fn is None:
             def body(state, batch):
                 new_state, m = self._step_fn(state, batch)
                 return new_state, m
@@ -364,23 +340,12 @@ class TrainStep:
         if rec is not None:
             rec.device_trace.on_step_begin()
         t0 = time.perf_counter() if rec is not None else 0.0
-        cache_before = _jit_cache_size(fn) if rec is not None else -1
-        if not first:
-            # cached dispatch needs no ambient mesh (explicit shardings);
-            # the context manager costs ~1ms/call
-            out = fn(state, batches)
-        else:
-            with self.mesh:
-                out = fn(state, batches)
+        cache_before = fn._cache_size()
+        out = fn(state, batches)  # no ambient mesh needed: see step()
         if rec is not None:
             # one recording per dispatch: the scan body runs num_steps
             # optimizer steps inside XLA, so per-call overhead is amortized
-            cache_after = _jit_cache_size(fn)
-            compiled = (
-                cache_after != cache_before
-                if cache_before >= 0 and cache_after >= 0
-                else first
-            )
+            compiled = fn._cache_size() != cache_before
             if compiled:
                 # drain the compile + first-chunk backlog into this record
                 # (see step()); throughput/tokens only count cached calls

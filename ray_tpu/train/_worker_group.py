@@ -8,11 +8,13 @@ from __future__ import annotations
 import logging
 import os
 import socket
+import sys
 import threading
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private import repo_root
 from ray_tpu.train._checkpoint import Checkpoint
 from ray_tpu.train._session import (
     TrainContext,
@@ -38,24 +40,42 @@ def _to_actor_options(res: Dict[str, float]) -> Dict[str, Any]:
     }
 
 
+def _default_compile_cache_dir(environ) -> Optional[str]:
+    """Where this worker's persistent compile cache goes when the environment
+    does not say. JAX_COMPILATION_CACHE_DIR, where set, is jax's own setting
+    and nothing is set here; otherwise one fixed directory in the checkout —
+    the path is part of the cache key, so it is never a temp, pid or time
+    path. A worker held to the CPU gets none: its compiles take seconds, and
+    XLA:CPU logs a machine-feature error on every cache hit."""
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    if environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+        return None
+    return os.path.join(repo_root(), ".jax_cache")
+
+
 class _TrainWorker:
     """Actor hosting one training process (one jax process per worker)."""
 
     def __init__(self, rank: int, env: Dict[str, str]):
-        import sys
-
         for k, v in env.items():
             os.environ[k] = str(v)
-        # The fork server preimports the runtime, which pulls in jax — its
-        # import-time config snapshot predates our env vars. The backend is
-        # still uninitialized here (nothing touched a device), so pushing the
-        # platform through jax.config makes the env effective anyway;
+        cache_dir = _default_compile_cache_dir(os.environ)
+        if cache_dir:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+        # jax reads these when it is imported. A worker has normally not
+        # imported it yet; where something has, the backend is still
+        # uninitialized here (nothing touched a device), so pushing the
+        # values through jax.config makes them effective anyway.
         # XLA_FLAGS / TPU_VISIBLE_CHIPS are read at backend init and work
         # as plain env vars.
-        if "jax" in sys.modules and "JAX_PLATFORMS" in env:
+        if "jax" in sys.modules:
             import jax
 
-            jax.config.update("jax_platforms", env["JAX_PLATFORMS"] or None)
+            if "JAX_PLATFORMS" in env:
+                jax.config.update("jax_platforms", env["JAX_PLATFORMS"] or None)
+            if cache_dir:
+                jax.config.update("jax_compilation_cache_dir", cache_dir)
         self._rank = rank
         self._thread: Optional[threading.Thread] = None
 

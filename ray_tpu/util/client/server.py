@@ -228,6 +228,12 @@ def main():
     srv = ClientServer(args.port)
     port = srv.start()
     print(f"client server listening on {port}", flush=True)
+    # SIGTERM ends the server the way ctrl-C does: the interpreter exits
+    # normally and init()'s atexit shutdown stops the cluster it started
+    # (left to the default action, its GCS and raylet stayed for good).
+    import signal
+
+    signal.signal(signal.SIGTERM, signal.default_int_handler)
     try:
         while True:
             time.sleep(3600)

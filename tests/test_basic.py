@@ -1,6 +1,7 @@
 """Core API tests: tasks, objects, errors
 (modeled on reference python/ray/tests/test_basic.py)."""
 
+import os
 import time
 
 import numpy as np
@@ -221,3 +222,68 @@ def test_coordinating_tasks_in_deep_queue(shutdown_only):
     refs += [step.remote("noop", sig) for _ in range(40)]
     out = ray_tpu.get(refs, timeout=120)
     assert all(out), out
+
+
+def test_post_batched_survives_reentry_under_its_lock(ray_start_regular):
+    """An allocation inside _post_batched/_drain_loop_work's critical section
+    can run the cyclic GC, whose ObjectRef.__del__ -> _on_ref_zero ->
+    _post_batched re-enters on the same thread. With a plain Lock that
+    deadlocked the driver's io loop (every later RPC hung, and with it
+    tests/test_serve_routing.py::test_scale_down_zero_failures)."""
+    import threading
+
+    from ray_tpu._private import worker as worker_mod
+    from ray_tpu._private.ids import ObjectID
+
+    w = worker_mod.global_worker
+
+    def gc_inside_critical_section():
+        with w._loop_work_lock:
+            w._on_ref_zero(ObjectID.from_random())
+
+    t = threading.Thread(target=gc_inside_critical_section, daemon=True)
+    t.start()
+    t.join(10)
+    assert not t.is_alive(), "re-entrant _post_batched deadlocked"
+
+    @ray_tpu.remote
+    def f():
+        return 7
+
+    assert ray_tpu.get(f.remote(), timeout=60) == 7  # the io loop still runs
+
+
+@pytest.mark.parametrize("raylet_killed", [False, True],
+                         ids=["clean", "raylet_killed"])
+def test_shutdown_leaves_no_process(shutdown_only, raylet_killed):
+    """shutdown() returns when everything the node started is gone — not
+    SIGTERMed and on its way out: the chip check looks the moment the script
+    ends (PR 22 was refused for processes that outlived it by seconds).
+    After a kill -9 of the raylet its children end by themselves (fork server
+    on EOF, workers with it, agent on its watch of the raylet's pid)."""
+    from chip_smoke import _left_running  # scans /proc for the session
+
+    from ray_tpu import api
+
+    ray_tpu.init(num_cpus=2)
+    session_dir = api._local_node.session_dir
+
+    @ray_tpu.remote
+    class A:
+        def pid(self):
+            return os.getpid()
+
+    @ray_tpu.remote
+    def f():
+        return os.getpid()
+
+    a = A.remote()
+    pids = ray_tpu.get([a.pid.remote(), f.remote()], timeout=60)
+    assert len(_left_running(session_dir)) >= 5, pids
+    if raylet_killed:
+        api._local_node.kill_raylet()
+    ray_tpu.shutdown()
+    deadline = time.time() + (5 if raylet_killed else 0)
+    while (left := _left_running(session_dir)) and time.time() < deadline:
+        time.sleep(0.1)
+    assert left == []

@@ -900,7 +900,7 @@ print(f"DRIVER-{tag}-OK")
 def test_spillback_under_contention():
     """When the preferred node is saturated, lease requests spill to
     peers instead of queueing behind long tasks (reference:
-    hybrid_scheduling_policy.cc spillback; VERDICT r2 weak #7)."""
+    hybrid_scheduling_policy.cc spillback)."""
     cluster = Cluster(
         initialize_head=True, head_node_args={"resources": {"CPU": 2}}
     )

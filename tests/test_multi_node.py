@@ -147,3 +147,45 @@ def test_node_death_detected(three_node_cluster):
         time.sleep(0.5)
     else:
         pytest.fail("node death not detected")
+
+
+
+def test_lease_request_survives_spill_to_a_dead_node():
+    """Between a raylet's death and the GCS missing its heartbeats the cluster
+    view still offers it as a spill target. The refused connection must send
+    the lease request back to the local raylet, not end it and leave the
+    queued tasks without one (they then hung for good)."""
+    import asyncio
+    import types
+
+    from ray_tpu._private.worker import CoreWorker, _LeaseState
+
+    replies = [{"spill": {"ip": "127.0.0.1", "port": 1}}, {"error": "stop"}]
+    failed = []
+
+    class Raylet:
+        async def call(self, method, req, timeout=None):
+            return replies.pop(0)
+
+    class Pool:
+        async def get(self, ip, port):
+            raise ConnectionRefusedError(111, "Connect call failed")
+
+    core = types.SimpleNamespace(
+        raylet=Raylet(), pool=Pool(),
+        _fail_task=lambda spec, err: failed.append(str(err)))
+    core._request_lease = types.MethodType(CoreWorker._request_lease, core)
+    state = _LeaseState()
+    state.queue.append({"resources": {"CPU": 1}, "strategy": {}, "job_id": b"j"})
+    state.requests_in_flight = 1
+
+    async def drive():
+        await core._request_lease("key", state)
+        for _ in range(50):
+            if failed:
+                break
+            await asyncio.sleep(0.05)
+
+    asyncio.run(drive())
+    assert failed == ["stop"]  # the second request reached the local raylet
+    assert state.requests_in_flight == 0 and not state.queue

@@ -77,7 +77,7 @@ def test_unsupported_runtime_env_field_rejected(ray_start_regular):
         return 1
 
     with pytest.raises(ValueError, match="unsupported runtime_env"):
-        f.options(runtime_env={"conda": {"dependencies": []}}).remote()
+        f.options(runtime_env={"java_jars": ["x.jar"]}).remote()
 
 
 def test_log_to_driver(shutdown_only, capfd):

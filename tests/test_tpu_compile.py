@@ -1,0 +1,133 @@
+"""Compiles for a described (not attached) TPU v5e: what the chip's compiler
+would refuse — a Mosaic kernel it cannot partition, a mis-tiled slice, a
+program that does not fit 16 GB — fails here, at no chip time.
+
+The only file that describes a chip. Only one process may hold the TPU
+library, so the topology is described inside a fixture (never at import) and
+every compile runs in the test's own process. A compile that passes is not a
+chip run: nothing here is a time or a result.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from ray_tpu.models.gpt2 import GPT2Config
+from ray_tpu.ops import attention
+from ray_tpu.parallel.train_step import TrainStep, attn_for_mesh
+
+GIB = 1 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # Such a compile is written to the persistent cache but cannot be read
+    # back without a chip: keep the cache off round these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+
+
+def _qkv(shape, sharding):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    return x, x, x
+
+
+def _loss(attn):
+    return lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum()
+
+
+def _step_args(ts):
+    """(state, batch) of a TrainStep as shapes with its own shardings."""
+    shapes = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, ts.state_shardings)
+    tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32, sharding=ts.batch_sharding)
+    return state, {"idx": tokens, "targets": tokens}
+
+
+def test_flash_forward_compiles(one_chip):
+    c = jax.jit(attention.flash_causal_attention).lower(
+        *_qkv((16, 12, 1024, 64), one_chip)).compile()
+    assert c.as_text().count("tpu_custom_call") == 1
+
+
+def test_flash_backward_compiles(one_chip):
+    grad = jax.grad(_loss(attention.flash_causal_attention), argnums=(0, 1, 2))
+    c = jax.jit(grad).lower(*_qkv((16, 12, 1024, 64), one_chip)).compile()
+    # forward (for the residuals) + the dq and dkv kernels
+    assert c.as_text().count("tpu_custom_call") == 3
+
+
+def test_flash_long_wide_heads_compile(one_chip):
+    fn = jax.value_and_grad(_loss(attention.flash_causal_attention), argnums=(0, 1, 2))
+    c = jax.jit(fn).lower(*_qkv((2, 16, 4096, 128), one_chip)).compile()
+    assert c.as_text().count("tpu_custom_call") == 3
+
+
+def test_causal_attention_under_mesh_keeps_kernel(mesh_2x2, monkeypatch):
+    """A bare pallas_call in a dp/tp-sharded jit is refused ("Mosaic kernels
+    cannot be automatically partitioned"); attn_for_mesh shard_maps it."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    sharded = NamedSharding(mesh_2x2, P("dp", None, "tp", None))  # (B, T, H, D)
+    args = _qkv((16, 1024, 12, 64), sharded)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(attention.causal_attention).lower(*args).compile()
+    grad = jax.grad(_loss(attn_for_mesh(mesh_2x2)), argnums=(0, 1, 2))
+    c = jax.jit(grad).lower(*args).compile()
+    assert c.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.timeout(300)
+def test_gpt2_124m_step_fits_one_chip(topo, monkeypatch):
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:1]), ("dp",))
+    ts = TrainStep(GPT2Config.gpt2_124m(), mesh, telemetry=False)
+    c = ts._step.lower(*_step_args(ts)).compile()
+    m = c.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 16 * GIB, m
+    # one forward and two backward kernels in each of 12 remat'd layers,
+    # plus the forward recomputed
+    assert c.as_text().count("tpu_custom_call") == 48
+
+
+@pytest.mark.timeout(300)
+def test_gpt2_step_on_dp_tp_mesh_keeps_kernel(mesh_2x2, monkeypatch):
+    """Published widths on a dp=2, tp=2 mesh, depth cut to 2 for compile
+    time: V=50257 does not divide by tp (the embedding stays replicated over
+    it), the kernel survives the mesh, and the collectives are there."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    ts = TrainStep(GPT2Config.gpt2_124m(n_layer=2), mesh_2x2, telemetry=False)
+    assert ts.state_specs["params"]["wte"]["embedding"] == P(None, None)
+    assert ts.state_specs["params"]["h_0"]["attn"]["c_attn"]["kernel"] == P(None, "tp")
+    text = ts._step.lower(*_step_args(ts)).compile().as_text()
+    assert text.count("tpu_custom_call") == 8
+    assert "all-reduce(" in text

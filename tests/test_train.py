@@ -26,6 +26,21 @@ def ray_4cpu(tmp_path):
     ray_tpu.shutdown()
 
 
+@pytest.mark.parametrize("environ, want", [
+    ({"JAX_COMPILATION_CACHE_DIR": "/somewhere/else"}, None),  # jax's own setting
+    ({"JAX_PLATFORMS": "cpu"}, None),                          # held to the CPU
+    ({"JAX_PLATFORMS": "tpu,cpu"}, ".jax_cache"),
+    ({}, ".jax_cache"),
+])
+def test_worker_compile_cache_default_is_one_fixed_path(environ, want):
+    from ray_tpu._private import repo_root
+    from ray_tpu.train._worker_group import _default_compile_cache_dir
+
+    got = _default_compile_cache_dir(environ)
+    assert got == (want and os.path.join(repo_root(), want))
+    assert _default_compile_cache_dir(environ) == got  # no pid, time or temp part
+
+
 def test_report_rounds_and_context(ray_4cpu):
     def loop(config):
         ctx = train.get_context()

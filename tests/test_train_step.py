@@ -9,9 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.sharding import PartitionSpec as P
+
 from ray_tpu.models.gpt2 import GPT2Config
-from ray_tpu.parallel.mesh import make_mesh, single_axis_mesh
-from ray_tpu.parallel.train_step import TrainStep
+from ray_tpu.ops import attention
+from ray_tpu.parallel.mesh import filter_spec_for_mesh, make_mesh, single_axis_mesh
+from ray_tpu.parallel.train_step import TrainStep, attn_for_mesh
 
 CFG = GPT2Config.tiny(use_flash_attention=False, dtype=jnp.float32)
 
@@ -59,6 +62,62 @@ def test_parallel_matches_single_device(axes, baseline):
     losses, _ = _run(make_mesh(axes))
     np.testing.assert_allclose(losses, base_losses, rtol=2e-3, atol=2e-3)
     assert losses[-1] < losses[0]  # it actually learns
+
+
+def test_flash_config_under_mesh_matches_single_device(baseline):
+    """use_flash_attention=True on a multi-device mesh goes through
+    attn_for_mesh (shard_map over batch and heads); same trajectory."""
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, use_flash_attention=True)
+    ts = TrainStep(cfg, make_mesh({"dp": 2, "fsdp": 2, "tp": 2}), learning_rate=1e-3)
+    assert ts.model.config.attn_fn is not None
+    assert TrainStep(cfg, single_axis_mesh("dp", jax.devices()[:1])
+                     ).model.config.attn_fn is None
+    state = ts.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    losses = []
+    for _ in range(3):
+        state, m = ts.step(state, ts.shard_batch(_batch(rng)))
+        losses.append(float(m["loss"]))
+    np.testing.assert_allclose(losses, baseline[0], rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("axes", [{"dp": 2, "tp": 4}, {"fsdp": 4, "tp": 2}, {"dp": 8}])
+def test_attn_for_mesh_matches_reference(axes):
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.standard_normal((8, 64, 4, 16)), jnp.float32)
+               for _ in range(3))
+    got = jax.jit(attn_for_mesh(make_mesh(axes)))(q, k, v)
+    np.testing.assert_allclose(got, attention.causal_attention(q, k, v), atol=2e-5)
+
+
+def test_attention_path_is_decided_from_backend_and_shape(monkeypatch):
+    assert attention.attention_path(1024) == "xla"  # these tests run on CPU
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert [attention.attention_path(t) for t in (128, 256, 1000, 1024)] == [
+        "xla", "flash", "xla", "flash"]
+
+
+def test_on_tpu_does_not_swallow_a_backend_failure(monkeypatch):
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        attention.attention_path(1024)
+
+
+@pytest.mark.parametrize("spec, shape, want", [
+    (P("tp", "fsdp"), (50257, 768), P(None, "fsdp")),   # GPT-2's V on tp=2
+    (P("tp", "fsdp"), (50304, 768), P("tp", "fsdp")),
+    (P(("dp", "fsdp"), None), (6, 8), P(("dp",), None)),  # 6 % (2*2) != 0
+    (P("fsdp", "tp"), None, P("fsdp", "tp")),             # no shape: mesh only
+    (P("sp", "ep"), (8, 8), P(None, None)),               # axes the mesh lacks
+])
+def test_filter_spec_drops_axes_that_do_not_divide(spec, shape, want):
+    mesh = make_mesh({"dp": 2, "fsdp": 2, "tp": 2})
+    assert filter_spec_for_mesh(spec, mesh, shape) == want
 
 
 def test_state_is_sharded():
