@@ -21,6 +21,11 @@ The script stops every process it starts and says so: after shutdown it lists
 what is left of its session (`processes_left_running`) and fails on any, or if
 the worker that held the chip still exists in any state.
 
+It also holds the program's own clock to its own: TrainStep's telemetry (step
+seconds, tokens/s, MFU, from the completion of each step on the device) must
+agree with the steps as timed here to block_until_ready, and goodput after
+the compile call must show a busy device.
+
 --rehearse runs the same control flow in a sandbox without a chip (tiny
 model, CPU devices, the pallas kernel in interpret mode). It proves paths and
 arguments, measures nothing, and never prints the "ok" line.
@@ -35,6 +40,7 @@ import functools
 import glob
 import json
 import os
+import statistics
 import sys
 import tempfile
 import time
@@ -42,6 +48,8 @@ import time
 # Bounds the script holds the run to (bf16 inputs, fp32 accumulation).
 ATTN_REL_TOL = 2e-2   # max|flash - xla| / max|xla|, output and dq, dk, dv
 LOSS_REL_TOL = 2e-2   # |loss_mesh - loss_one_device| / loss_one_device, per step
+TELEMETRY_REL_TOL = 2e-2  # the program's step seconds, tokens/s and MFU against the blocked steps
+GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
 
 FULL = {"model": None, "B": 16, "T": 1024, "attn_shape": (16, 12, 1024, 64)}
 TINY = {
@@ -88,7 +96,8 @@ def _setup(config):
     )
     report.update(
         model="gpt2_124m" if config["model"] is None else "tiny (rehearsal)",
-        dtype=jax.numpy.dtype(cfg.dtype).name, B=config["B"], T=config["T"])
+        dtype=jax.numpy.dtype(cfg.dtype).name, B=config["B"], T=config["T"],
+        flops_per_token=cfg.flops_per_token(config["T"]))
     rng = np.random.default_rng(config["seed"])
     tokens = rng.integers(0, cfg.vocab_size, (config["B"], config["T"] + 1))
     batch = {"idx": tokens[:, :-1].astype(np.int32),
@@ -106,18 +115,26 @@ def _cache_entries(cache_dir):
 
 def _run_steps(ts, state, batch, n):
     """n calls of TrainStep.step, each timed to block_until_ready. Returns
-    per-call (seconds, loss, compiled) — `compiled` is a jit cache miss."""
+    per-call (seconds, loss, compiled) — `compiled` is a jit cache miss —
+    and the program's own telemetry summary after them, with its goodput
+    since the first call (the compile call) returned."""
     import jax
 
     out = []
-    for _ in range(n):
+    for i in range(n):
         before = ts._step._cache_size()
         t0 = time.perf_counter()
         state, metrics = ts.step(state, batch)
         jax.block_until_ready((state, metrics))
         dt = time.perf_counter() - t0
         out.append((dt, float(metrics["loss"]), ts._step._cache_size() != before))
-    return state, out
+        if i == 0:
+            warm, productive = time.perf_counter(), ts.telemetry.summary()["productive_time_s"]
+    telemetry = ts.telemetry.summary()
+    telemetry["n_devices"] = int(ts.mesh.devices.size)
+    telemetry["goodput_after_warmup"] = (
+        (telemetry["productive_time_s"] - productive) / (time.perf_counter() - warm))
+    return state, out, telemetry
 
 
 def _check_losses(losses):
@@ -182,10 +199,11 @@ def one_chip_loop(config):
         raise RuntimeError("no tpu_custom_call in the lowered step")
 
     # the compile step plus 8 steps
-    state, calls = _run_steps(ts, state, batch, 9)
+    state, calls, telemetry = _run_steps(ts, state, batch, 9)
     losses = [loss for _, loss, _ in calls]
     _check_losses(losses)
     report.update(
+        telemetry={"one_device": telemetry},
         compile_seconds=calls[0][0],
         step_seconds=[dt for dt, _, _ in calls[1:]],
         step_recompiled=[c for _, _, c in calls[1:]],
@@ -212,7 +230,7 @@ def four_chip_loop(config):
     if len(devs) != 4:
         raise RuntimeError(f"worker leased 4 chips sees {len(devs)} devices")
     axes = {"dp": 2, "tp": 2}
-    runs = {}
+    runs, telemetry = {}, {}
     for name, mesh in (
         ("mesh", make_mesh(axes, devices=devs)),
         ("one_device", make_mesh({"dp": 1}, devices=devs[:1])),
@@ -232,7 +250,7 @@ def four_chip_loop(config):
             if on_tpu and not all(report["bytes_in_use_per_device"]):
                 raise RuntimeError("a device reports no bytes in use")
         sharded = ts.shard_batch(batch)
-        state, calls = _run_steps(ts, state, sharded, 5)
+        state, calls, telemetry[name] = _run_steps(ts, state, sharded, 5)
         if name == "mesh":
             text = ts._step.lower(state, sharded).compile().as_text()
             report["all_reduces_in_compiled_step"] = text.count("all-reduce(")
@@ -255,6 +273,7 @@ def four_chip_loop(config):
             f"{mesh_losses} vs {ref_losses}")
     report.update(
         mesh=axes,
+        telemetry=telemetry,
         losses_mesh=mesh_losses, losses_one_device=ref_losses,
         loss_rel_diff=rel,
         compile_seconds={k: v[0][0] for k, v in runs.items()},
@@ -296,6 +315,36 @@ def _left_running(session_dir):
         if session_dir in cmd:
             left.append(f"{path.split('/')[2]} {cmd[:160]}")
     return left
+
+
+def _check_telemetry(report, enforce):
+    """The program's clock against this script's: each run's last step
+    seconds, tokens/s and MFU as TrainStep's telemetry has them, over the
+    same numbers from the steps timed here to block_until_ready."""
+    seconds = report["step_seconds"]
+    if not isinstance(seconds, dict):
+        seconds = {"one_device": seconds}
+    tokens = report["B"] * report["T"]
+    for name, tel in report["telemetry"].items():
+        rate = tokens / statistics.mean(seconds[name])
+        own = {"step_time_s": seconds[name][-1], "tokens_per_s": rate}
+        if report["peak_flops_per_device"]:
+            own["mfu"] = (rate * report["flops_per_token"]
+                          / (report["peak_flops_per_device"] * tel["n_devices"]))
+        ratio = {k: tel[k] / v if k in tel else None for k, v in own.items()}
+        print(json.dumps({"telemetry_over_blocked": {name: ratio},
+                          "goodput_after_warmup": tel["goodput_after_warmup"]}),
+              flush=True)
+        if not enforce:
+            continue
+        if any(r is None or abs(r - 1) > TELEMETRY_REL_TOL for r in ratio.values()):
+            raise SystemExit(
+                f"chip_smoke: {name}: telemetry {tel} disagrees with the blocked "
+                f"steps {own} by more than {TELEMETRY_REL_TOL}")
+        if tel["goodput_after_warmup"] < GOODPUT_FLOOR:
+            raise SystemExit(
+                f"chip_smoke: {name}: goodput after the compile call is "
+                f"{tel['goodput_after_warmup']}, under {GOODPUT_FLOOR}")
 
 
 def main():
@@ -342,10 +391,10 @@ def main():
                 name="chip_smoke",
                 storage_path=tempfile.mkdtemp(prefix="chip_smoke_")),
         ).fit()
-        report = {k: v for k, v in result.metrics.items()
-                  if not k.startswith("telemetry/")}
+        report = result.metrics
         for key, value in report.items():
             print(json.dumps({key: value}), flush=True)
+        _check_telemetry(report, enforce=not args.rehearse)
         if report["device_count"] != args.chips:
             raise SystemExit(
                 f"chip_smoke: worker saw {report['device_count']} devices, "

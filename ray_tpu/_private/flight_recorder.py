@@ -58,8 +58,14 @@ bytes, hex-encoded at dump time) and a short detail string/number.
   node.dead                      GCS marked a node dead
   chan.up / chan.down            direct call channel lifecycle
   collective.enter / collective.exit   gloo-style CPU collective ops
-  train.step                     one (multi-)step dispatch recorded by the
-                                 train telemetry layer
+  train.step                     a step program completed on the device:
+                                 (optimizer steps so far, seconds from the
+                                 completion before it to its own)
+  train.dispatch                 TrainStep enqueued a step program:
+                                 (step number, optimizer steps in it) —
+                                 "did step N ever start" for a hung mesh
+  train.compile                  a step call that missed the jit cache:
+                                 (optimizer steps so far, seconds)
   serve.request                  one replica-side serve request finished
   llm.admit / llm.preempt / llm.finish   serve/llm engine sequence
                                  lifecycle (admit carries the prompt

@@ -57,6 +57,22 @@ class GPT2Config:
         base.update(kw)
         return cls(**base)
 
+    def matmul_params(self) -> int:
+        """Parameters a token is multiplied by: qkv, the attention projection
+        and the two MLP matrices of each block, and the head. The embedding
+        tables (wte as a look-up, wpe) multiply nothing; the tied head does,
+        once."""
+        d = self.n_embd
+        return self.n_layer * 12 * d * d + self.vocab_size * d
+
+    def flops_per_token(self, seq_len: int) -> int:
+        """Model FLOPs a trained token needs at this sequence length: 6 x
+        matmul parameters (2 forward, 4 backward) + causal attention, QK^T
+        and PV at 2*T*d a token over all heads, half of it under the mask,
+        three times over: 6*L*T*n_head*head_dim. Recomputed operations
+        (remat, the flash kernels' own recompute) are not counted."""
+        return 6 * self.matmul_params() + 6 * self.n_layer * seq_len * self.n_embd
+
 
 class CausalSelfAttention(nn.Module):
     config: GPT2Config

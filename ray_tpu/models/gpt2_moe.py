@@ -42,6 +42,14 @@ class GPT2MoEConfig(GPT2Config):
         base.update(kw)
         return cls(**base)
 
+    def matmul_params(self) -> int:
+        """Active parameters: an MoE block's token passes the router and
+        top_k of the experts' two matrices in place of the dense MLP."""
+        d = self.n_embd
+        moe_blocks = self.n_layer // self.moe_every
+        moe_mlp = self.moe.top_k * 8 * d * d + d * self.moe.num_experts
+        return super().matmul_params() + moe_blocks * (moe_mlp - 8 * d * d)
+
 
 class MoEBlock(nn.Module):
     config: GPT2MoEConfig

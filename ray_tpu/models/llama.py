@@ -66,6 +66,21 @@ class LlamaConfig:
         raw = int(8 * self.n_embd / 3)
         return (raw + 127) // 128 * 128
 
+    def matmul_params(self) -> int:
+        """q and o (d x d), k and v (d x kv heads x head_dim), gate, up and
+        down (d x mlp_dim) of each block, and the untied head. The embedding
+        table multiplies nothing."""
+        d = self.n_embd
+        kv = self.n_kv_head * self.head_dim
+        return (self.n_layer * (2 * d * d + 2 * d * kv + 3 * d * self.mlp_dim)
+                + self.vocab_size * d)
+
+    def flops_per_token(self, seq_len: int) -> int:
+        """The rule of GPT2Config.flops_per_token; grouped queries save
+        memory, not operations."""
+        attn_width = self.n_head * self.head_dim
+        return 6 * self.matmul_params() + 6 * self.n_layer * seq_len * attn_width
+
     @classmethod
     def tiny(cls, **kw):
         base = dict(vocab_size=512, block_size=128, n_layer=2, n_head=4,

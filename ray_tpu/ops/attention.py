@@ -10,6 +10,13 @@ The reference framework has no attention kernels at all (its data plane is
 torch); this op is the building block its GPU stack gets from flash-attn, and
 the ring-attention layer (ray_tpu/ops/ring_attention.py) composes it per-step
 for sequence parallelism.
+
+The three pallas calls are named flash_fwd, flash_bwd_dq and flash_bwd_dkv.
+The name reaches the compiled instruction and the profiler's trace (wrapped by
+the transformations it went through, e.g. transpose_jvp_flash_bwd_dq_), on one
+chip and under a mesh alike, and is how the benchmark's per-kernel metrics
+(bench/layer_metrics/flash_*) find each call: a kernel that is split, fused
+or renamed takes a new name, and none is a substring of another.
 """
 
 from __future__ import annotations
@@ -102,6 +109,7 @@ def _flash_fwd(q, k, v, *, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -209,6 +217,7 @@ def _flash_bwd(res, g, *, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -233,6 +242,7 @@ def _flash_bwd(res, g, *, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -18,7 +18,6 @@ Axes (any subset may be trivial/size-1, one rule set serves all):
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -26,6 +25,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from jax.profiler import TraceAnnotation
+
+from ray_tpu._private import flight_recorder
 from ray_tpu.models.gpt2 import (
     GPT2,
     GPT2Config,
@@ -39,21 +41,19 @@ from ray_tpu.parallel.mesh import (
 )
 
 
-def _batch_counts(batch) -> Tuple[Optional[int], Optional[int]]:
-    """(tokens, examples) in a batch dict for telemetry: the idx array's
-    element count is token count, its second-to-last dim is batch size
-    (works for (B, T) steps and (num_steps, B, T) scan stacks)."""
-    try:
-        idx = batch.get("idx")
-        if idx is None or not hasattr(idx, "shape"):
-            return None, None
-        tokens = 1
-        for d in idx.shape:
-            tokens *= int(d)
-        examples = tokens // int(idx.shape[-1]) if idx.shape[-1] else None
-        return tokens, examples
-    except Exception:
-        return None, None
+def _batch_counts(batch) -> Tuple[Optional[int], Optional[int], Optional[int]]:
+    """(tokens, examples, sequence length) of a batch dict for telemetry:
+    the idx array's element count is the token count, its last dim the
+    sequence length (works for (B, T) steps and (num_steps, B, T) scan
+    stacks)."""
+    idx = batch.get("idx")
+    if idx is None or not getattr(idx, "shape", None) or not idx.shape[-1]:
+        return None, None, None
+    tokens = 1
+    for d in idx.shape:
+        tokens *= int(d)
+    seq_len = int(idx.shape[-1])
+    return tokens, tokens // seq_len, seq_len
 
 
 def attn_for_mesh(mesh: Mesh, seq_axis: str = "sp"):
@@ -160,7 +160,7 @@ class TrainStep:
         )
         self.batch_sharding = batch_sharding(mesh)
 
-        def init_fn(rng):
+        def train_init(rng):
             # Dummy batch for shape inference must still satisfy the mesh:
             # B divisible by dp*fsdp, T by sp (ring attention shard_maps
             # over them even during init).
@@ -178,28 +178,35 @@ class TrainStep:
                 "step": jnp.zeros((), jnp.int32),
             }
 
-        state_shape = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+        state_shape = jax.eval_shape(train_init, jax.random.PRNGKey(0))
         self.state_specs, self.state_shardings = filtered_tree_shardings(
             rules, state_shape, mesh
         )
-        self._init = jax.jit(init_fn, out_shardings=self.state_shardings)
+        self._init = jax.jit(train_init, out_shardings=self.state_shardings)
 
-        def step_fn(state, batch):
+        # The jitted functions are named for what they are (the trace's
+        # "XLA Modules" line reads jit_train_step), and the loss and the
+        # optimizer are scopes beside the ones flax gives the model's
+        # modules: xprof groups device time by them.
+        def train_step(state, batch):
             def loss_of(params):
                 if self._is_moe:
                     logits, lstate = self.model.apply(
                         {"params": params}, batch["idx"], mutable=["losses"]
                     )
                     aux = sum(jax.tree.leaves(lstate.get("losses", {})))
+                else:
+                    logits = self.model.apply({"params": params}, batch["idx"])
+                    aux = 0.0
+                with jax.named_scope("loss"):
                     return loss_fn(logits, batch["targets"]) + aux
-                logits = self.model.apply({"params": params}, batch["idx"])
-                return loss_fn(logits, batch["targets"])
 
             loss, grads = jax.value_and_grad(loss_of)(state["params"])
-            updates, opt_state = self.optimizer.update(
-                grads, state["opt_state"], state["params"]
-            )
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, state["opt_state"], state["params"]
+                )
+                params = optax.apply_updates(state["params"], updates)
             new_state = {
                 "params": params,
                 "opt_state": opt_state,
@@ -209,28 +216,31 @@ class TrainStep:
             return new_state, {"loss": loss, "grad_norm": gnorm}
 
         self._step = jax.jit(
-            step_fn,
+            train_step,
             out_shardings=(self.state_shardings, None),
             donate_argnums=(0,),
         )
-        self._step_fn = step_fn
+        self._step_fn = train_step
         self._multi: Dict[int, Any] = {}
         self._tiled_cache = None
-        # Step-level telemetry (train/_telemetry.py): wall time per step,
-        # compile time (jit cache misses are known exactly here), MFU from
-        # a per-model FLOPs estimate (flops_per_step overrides), goodput,
+        # Optimizer steps enqueued so far: the `step` every span of a step
+        # carries, in the profiler's trace and the flight recorder.
+        self.dispatched_steps = 0
+        # Step-level telemetry (train/_telemetry.py): device time per step
+        # from the completion clock, compile time (jit cache misses are
+        # known exactly here), MFU from the model config's own FLOP count at
+        # the batch's sequence length (flops_per_step overrides), goodput,
         # HBM. Registered process-globally so session.report auto-attaches
         # the summary. RTPU_TRAIN_TELEMETRY=0 disables.
+        self._flops_per_token = (
+            None if flops_per_step is not None
+            else getattr(model_cfg, "flops_per_token", None))
         self.telemetry = None
         if telemetry:
             from ray_tpu.train import _telemetry
 
             self.telemetry = _telemetry.StepRecorder(
                 flops_per_step=flops_per_step,
-                flops_per_token=(
-                    None if flops_per_step is not None
-                    else _telemetry.estimate_flops_per_token(model_cfg)
-                ),
                 n_devices=mesh.devices.size,
             )
             _telemetry.set_current_recorder(self.telemetry)
@@ -240,48 +250,58 @@ class TrainStep:
             return self._init(rng)
 
     def shard_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
-        return jax.device_put(batch, self.batch_sharding)
+        with TraceAnnotation("ray_tpu.train_step.shard_batch",
+                             step=self.dispatched_steps + 1):
+            return jax.device_put(batch, self.batch_sharding)
 
-    def step(self, state, batch) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    def _dispatch(self, fn, state, batches, num_steps: int):
+        """One call of a jitted step program, with everything the program
+        records about it: the spans on the profiler's clock, the flight
+        recorder's breadcrumb, and the hand-over to the recorder, whose
+        watcher books the step when it completes. Returns at enqueue."""
         # No mesh context, on the first call or any other: in/out shardings
         # are explicit NamedShardings and the shard_map'd attention carries
         # its mesh, so neither tracing nor dispatch needs the ambient one.
         # The jit cache key includes the ambient mesh: entering it for the
         # first call only would compile every step twice.
         rec = self.telemetry
-        if rec is None:
-            # Telemetry off: the flight recorder still gets a breadcrumb
-            # per dispatch (one deque append) — "did step N ever start" is
-            # exactly the question a hung mesh gets asked, and the recorder
-            # is the layer that answers it post-mortem.
-            from ray_tpu._private import flight_recorder as _fr
-
-            _fr.record("train.step", b"", "dispatch")
-            return self._step(state, batch)
-        # Device-trace hook (train/_telemetry.DeviceTraceController): inert
-        # two-attribute check unless a jax.profiler window was armed.
-        rec.device_trace.on_step_begin()
-        t0 = time.perf_counter()
-        cache_before = self._step._cache_size()
-        out = self._step(state, batch)
-        # Compile detection by actual jit cache miss (not just first-call):
-        # a new batch shape recompiles too, and every compile must be
-        # booked as compile time, not step time.
-        compiled = self._step._cache_size() != cache_before
-        if compiled:
-            # Contain the whole compile + first execution in THIS record:
-            # without the sync, the async backlog drains inside the next
-            # call's dispatch and poisons its step-time measurement.
-            jax.block_until_ready(out)
-        tokens, examples = _batch_counts(batch)
-        rec.record_step(
-            time.perf_counter() - t0,
-            tokens=None if compiled else tokens,
-            examples=None if compiled else examples,
-            compile_step=compiled,
-        )
-        rec.device_trace.on_step_end(out)
+        self.dispatched_steps += num_steps
+        step = self.dispatched_steps
+        if rec is not None:
+            # Device-trace hook (_telemetry.DeviceTraceController): inert
+            # two-attribute check unless a jax.profiler window was armed.
+            rec.device_trace.on_step_begin()
+            started = rec.clock()
+        with TraceAnnotation("ray_tpu.train_step.dispatch", step=step):
+            flight_recorder.record("train.dispatch", step, num_steps)
+            with TraceAnnotation("ray_tpu.train_step.jit", step=step):
+                cache_before = fn._cache_size()
+                out = fn(state, batches)
+                # Compile detection by actual jit cache miss (not just
+                # first-call): a new batch shape recompiles too, and every
+                # compile must be booked as compile time, not step time.
+                compiled = fn._cache_size() != cache_before
+                if compiled:
+                    # Contain the whole compile + first execution in THIS
+                    # call: without the sync the backlog would drain into
+                    # the next step's interval.
+                    jax.block_until_ready(out)
+            if rec is not None:
+                with TraceAnnotation("ray_tpu.train_step.record", step=step):
+                    tokens, examples, seq_len = _batch_counts(batches)
+                    flops = None
+                    if self._flops_per_token is not None and tokens:
+                        flops = self._flops_per_token(seq_len) * tokens
+                    rec.dispatched(
+                        out[1], started=started, step=step, steps=num_steps,
+                        tokens=tokens, examples=examples, flops=flops,
+                        compile_step=compiled)
+        if rec is not None:
+            rec.device_trace.on_step_end(out)
         return out
+
+    def step(self, state, batch) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+        return self._dispatch(self._step, state, batch, 1)
 
     def multi_step(self, state, batches, num_steps: int):
         """Run `num_steps` optimizer steps in ONE dispatch via lax.scan
@@ -299,11 +319,11 @@ class TrainStep:
                 new_state, m = self._step_fn(state, batch)
                 return new_state, m
 
-            def run(state, batches):
+            def train_multi_step(state, batches):
                 return jax.lax.scan(body, state, batches, length=num_steps)
 
             fn = jax.jit(
-                run,
+                train_multi_step,
                 out_shardings=(self.state_shardings, None),
                 donate_argnums=(0,),
             )
@@ -332,30 +352,6 @@ class TrainStep:
                 )
                 self._tiled_cache = (src, tiled)
             batches = self._tiled_cache[1]
-        rec = self.telemetry
-        if rec is None:
-            from ray_tpu._private import flight_recorder as _fr
-
-            _fr.record("train.step", b"", f"multi_step x{num_steps}")
-        if rec is not None:
-            rec.device_trace.on_step_begin()
-        t0 = time.perf_counter() if rec is not None else 0.0
-        cache_before = fn._cache_size()
-        out = fn(state, batches)  # no ambient mesh needed: see step()
-        if rec is not None:
-            # one recording per dispatch: the scan body runs num_steps
-            # optimizer steps inside XLA, so per-call overhead is amortized
-            compiled = fn._cache_size() != cache_before
-            if compiled:
-                # drain the compile + first-chunk backlog into this record
-                # (see step()); throughput/tokens only count cached calls
-                jax.block_until_ready(out)
-                tokens = examples = None
-            else:
-                tokens, examples = _batch_counts(batches)
-            rec.record_step(
-                time.perf_counter() - t0, steps=num_steps,
-                tokens=tokens, examples=examples, compile_step=compiled,
-            )
-            rec.device_trace.on_step_end(out)
-        return out
+        # one dispatch, one record: the scan body runs num_steps optimizer
+        # steps inside XLA, so the per-call overhead is amortized
+        return self._dispatch(fn, state, batches, num_steps)

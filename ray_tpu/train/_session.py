@@ -6,10 +6,13 @@ barrier semantics as the reference."""
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
+import time
 from typing import Any, Dict, Optional
 
+from ray_tpu.train import _telemetry
 from ray_tpu.train._checkpoint import Checkpoint
 
 
@@ -67,8 +70,21 @@ class _Session:
         self.finished = False
         self.error: Optional[BaseException] = None
 
-    def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint]):
-        self._slot.acquire()  # wait for a free pipeline slot
+    @contextlib.contextmanager
+    def _slot_wait(self, step: Optional[int]):
+        """The loop waits in the report queue for the driver: a span on the
+        profiler's clock, and seconds on the step recorder's counter."""
+        t0 = time.perf_counter()
+        with _telemetry.trace_span("ray_tpu.train.report.slot_wait", step):
+            yield
+        rec = _telemetry.current_recorder()
+        if rec is not None:
+            rec.add_slot_wait(time.perf_counter() - t0)
+
+    def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint],
+               step: Optional[int] = None):
+        with self._slot_wait(step):
+            self._slot.acquire()  # wait for a free pipeline slot
         with self._ack_cond:
             seq = self._submitted
             self._submitted += 1
@@ -78,7 +94,8 @@ class _Session:
             # strict barrier: return only after the consumer acked THIS
             # report — Tune trial loops rely on it (a checkpoint dir may be
             # reused right after report() returns)
-            self.consumed.wait()
+            with self._slot_wait(step):
+                self.consumed.wait()
         elif checkpoint is not None:
             # Reference semantics (train/_internal/session.py report :667):
             # the checkpoint is persisted before report() returns, so the
@@ -86,7 +103,7 @@ class _Session:
             # until the driver acked THIS report (acks are released only
             # after _consume_round copied/uploaded the dir). Metrics-only
             # reports keep the deep pipeline.
-            with self._ack_cond:
+            with self._slot_wait(step), self._ack_cond:
                 while self._acked <= seq:
                     self._ack_cond.wait()
 
@@ -108,8 +125,6 @@ def init_session(ctx: TrainContext, checkpoint: Optional[Checkpoint],
                  pipeline_depth: int = 1) -> _Session:
     global _session
     # A reused worker process must not report the previous run's telemetry.
-    from ray_tpu.train import _telemetry
-
     _telemetry.set_current_recorder(None)
     with _session_lock:
         _session = _Session(ctx, checkpoint, dataset_shards, pipeline_depth)
@@ -143,13 +158,15 @@ def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
     # Auto-attach step telemetry (train/_telemetry.py): if this worker runs
     # a TrainStep (or registered a StepRecorder), every report carries the
     # rolling step-time/MFU/goodput/throughput summary under telemetry/*
-    # keys — user metrics always win on collision.
-    from ray_tpu.train import _telemetry
-
-    auto = _telemetry.auto_report_metrics()
-    if auto:
-        metrics = {**auto, **metrics}
-    s.report(metrics, checkpoint)
+    # keys — user metrics always win on collision. The span carries the
+    # step the loop last dispatched.
+    rec = _telemetry.current_recorder()
+    step = rec.dispatched_steps if rec is not None else None
+    with _telemetry.trace_span("ray_tpu.train.report", step):
+        auto = _telemetry.auto_report_metrics()
+        if auto:
+            metrics = {**auto, **metrics}
+        s.report(metrics, checkpoint, step)
 
 
 def get_dataset_shard(name: str = "train"):

@@ -16,26 +16,45 @@ them through the three surfacing pipelines that already exist:
   3. ``session.report`` auto-attaches the rolling summary, so trainer
      results and the dashboard's ``/api/train`` see the same numbers.
 
-Step time is measured as the wall time of the dispatched step call (for
-``TrainStep`` this includes XLA dispatch and, under buffer donation on a
-busy device, converges to the true device step time). Goodput is the
-fraction of wall time since the recorder started that was spent inside
-productive (post-compile) steps — restarts, stalls, data loading and
-checkpoint pauses all show up as lost goodput, which is the number the
-TPU-scaling literature treats as the primary scaling diagnostic.
+Step time is the device's, not the dispatch's: a jitted step returns at
+enqueue, so ``TrainStep`` hands each call's metrics output (never the donated
+state) to ``StepRecorder.dispatched`` and a watcher thread waits on them in
+order. A step lasts from the completion before it — or from its own
+dispatch, where the device stood idle by then — to its own completion, and
+everything below reads that one clock: step seconds, tokens/s, MFU, the
+slow-step flag, the step SPAN and the flight-recorder breadcrumb. Goodput
+is the fraction of wall time since the recorder started that was spent
+inside productive (post-compile) steps, so a busy device reads about 1.0
+and compiles, restarts, input stalls and checkpoint pauses all show up as
+lost goodput, which is the number the TPU-scaling literature treats as the
+primary scaling diagnostic.
+
+The same boundaries are spans on the profiler's clock
+(``jax.profiler.TraceAnnotation``, a flag test while no trace is open), so
+a device-trace window holds what the host did beside the device ops:
+``ray_tpu.train_step.shard_batch``, ``.dispatch`` with its children ``.jit``
+and ``.record``, ``.wait`` (the watcher), and ``ray_tpu.train.report`` with
+``.slot_wait``. Each carries ``step``.
 
 Metric names are a stability contract — see ``ray_tpu/util/metrics.py``.
 """
 
 from __future__ import annotations
 
+import atexit
+import contextlib
+import dataclasses
+import logging
 import os
 import statistics
+import sys
 import threading
 import time
 import uuid
 from collections import deque
 from typing import Any, Dict, Optional
+
+logger = logging.getLogger(__name__)
 
 # Peak dense matmul throughput per chip (bf16 FLOP/s), keyed by substrings
 # of jax's ``device_kind``. Used for the MFU estimate; unknown device kinds
@@ -69,23 +88,56 @@ def peak_flops_per_device(device_kind: str) -> Optional[float]:
     return None
 
 
-def estimate_flops_per_token(model_cfg: Any) -> Optional[float]:
-    """~6N FLOPs/token (fwd+bwd) from a transformer config's shape fields.
+def trace_span(name: str, step: Optional[int] = None):
+    """A span on the profiler's clock, with the step number as the identifier
+    its spans share. A process that never imported jax has no profiler and
+    gets a null context."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, step=step)
 
-    Works for any config exposing n_layer/n_embd/vocab_size (GPT2, MoE,
-    Llama configs here). Attention FLOPs are sequence-length dependent and
-    omitted — for the model sizes this underestimates MFU by a few percent,
-    which is the conventional (and conservative) choice. Pass
-    ``flops_per_step`` to ``TrainStep`` for an exact per-model number.
-    """
-    n_layer = getattr(model_cfg, "n_layer", None)
-    n_embd = getattr(model_cfg, "n_embd", None)
-    vocab = getattr(model_cfg, "vocab_size", None)
-    if not (n_layer and n_embd and vocab):
-        return None
-    # params ≈ 12 * L * d^2 (attn qkv/proj + 4d MLP) + vocab embedding
-    params = 12 * n_layer * n_embd * n_embd + vocab * n_embd
-    return 6.0 * params
+
+def _finished(handle) -> bool:
+    """Whether the program that computes a step's output has completed, or
+    failed. The outputs of one program complete together, so one that is
+    ready tells for all: the loop may have waited on any of them, and
+    another's flag can still be a moment behind."""
+    import jax
+
+    leaves = [x for x in jax.tree.leaves(handle) if hasattr(x, "is_ready")]
+    try:
+        return not leaves or any(x.is_ready() for x in leaves)
+    except RuntimeError:
+        return True
+
+
+# The watcher thread ends after this long with nothing in flight, so an
+# abandoned recorder leaves no thread behind; the next dispatch starts one.
+_WATCHER_IDLE_S = 2.0
+# At interpreter exit a watcher is given this long to see its steps complete
+# (the runtime's own exit waits for them too). CPython ends a daemon thread
+# that returns from a wait while the interpreter is finalizing by unwinding it,
+# and inside the runtime's C++ frames that aborts the process.
+_EXIT_WAIT_S = 10.0
+
+
+@dataclasses.dataclass(slots=True)
+class _InFlight:
+    """One enqueued step program: what to wait on, when it was enqueued,
+    and what to book when it completes."""
+
+    handle: Any
+    started: float
+    enqueued: float
+    step: Optional[int]
+    steps: int
+    tokens: Optional[int]
+    examples: Optional[int]
+    flops: Optional[float]
+    compile_step: bool
 
 
 class StepRecorder:
@@ -93,7 +145,9 @@ class StepRecorder:
 
     Thread-safe; one recorder per training run (``TrainStep`` creates and
     registers one automatically, ``current_recorder()`` hands it to
-    ``session.report``).
+    ``session.report``). Two ways in: ``dispatched`` for a call that returns
+    at enqueue (the completion clock times it), ``record_step`` for a caller
+    that timed a finished step itself.
 
     Clock injection (``clock``/``wall_clock``) exists for deterministic
     unit tests; production uses monotonic time for durations and wall time
@@ -126,16 +180,32 @@ class StepRecorder:
         self.run_name = run_name
         self._emit_metrics = emit_metrics and os.environ.get(
             "RTPU_TRAIN_TELEMETRY", "1") != "0"
-        self._emit_spans = emit_spans and os.environ.get(
-            "RTPU_TRAIN_STEP_SPANS", "1") != "0"
+        self._emit_spans = emit_spans
         self._start = self._clock()
         self._trace_id = uuid.uuid4().hex
         self.steps = 0
         self.productive_steps = 0
         self.productive_s = 0.0
         self.compile_s = 0.0
+        self.compiles = 0
         self.tokens = 0
         self.examples = 0
+        self.flops = 0.0  # model FLOPs of the productive steps
+        # Host seconds at the two boundaries the loop can wait at: inside
+        # TrainStep's dispatch (compile calls apart) and in train.report's
+        # queue for the driver.
+        self.dispatch_s = 0.0
+        self.slot_wait_s = 0.0
+        # The completion clock: step programs in flight, oldest first, and
+        # when the newest finished one was seen complete. The watcher thread
+        # waits on them in order and books each at its completion, which a
+        # loop that waits for its steps spends inside its own wait.
+        self.dispatched_steps = 0
+        self._pending: deque = deque()
+        self._pending_cond = threading.Condition()
+        self._watcher: Optional[threading.Thread] = None
+        self._closing = False
+        self._last_done = self._start
         self._last_step_s = 0.0
         self._metrics = None
         self._hbm_bytes: Dict[str, float] = {}
@@ -175,6 +245,111 @@ class StepRecorder:
         # or RTPU_device_trace_steps; driven by TrainStep around dispatch.
         self.device_trace = DeviceTraceController()
 
+    # ------------------------------------------------- the completion clock
+
+    def dispatched(
+        self,
+        handle,
+        *,
+        started: float,
+        step: Optional[int] = None,
+        steps: int = 1,
+        tokens: Optional[int] = None,
+        examples: Optional[int] = None,
+        flops: Optional[float] = None,
+        compile_step: bool = False,
+    ) -> None:
+        """A step program of ``steps`` optimizer steps was enqueued at
+        ``started`` (this recorder's ``clock()``). ``handle`` is an output of
+        it that is not donated to the next call — its metrics; the watcher
+        thread waits on the handles in order and books each step at its
+        completion, so the caller gains no wait. A ``compile_step`` call has
+        been waited for by its caller: its time from ``started`` to now is
+        compile time, and it only moves the clock."""
+        now = self._clock()
+        entry = _InFlight(handle, started, now, step, steps, tokens, examples,
+                          flops, compile_step)
+        self.dispatched_steps = (
+            step if step is not None else self.dispatched_steps + steps)
+        if not compile_step:
+            self.dispatch_s += now - started
+        with self._pending_cond:
+            self._pending.append(entry)
+            if self._watcher is None:
+                self._watcher = threading.Thread(
+                    target=self._watch, name="train-step-watcher", daemon=True)
+                self._watcher.start()
+                atexit.register(self._stop_watcher)
+            self._pending_cond.notify_all()
+
+    def clock(self) -> float:
+        return self._clock()
+
+    def _watch(self) -> None:
+        """Wait on the oldest step in flight, book it at its completion, and
+        again; the thread ends when nothing has been in flight for a while."""
+        import jax
+
+        while True:
+            with self._pending_cond:
+                if not self._pending and not self._closing:
+                    self._pending_cond.wait(_WATCHER_IDLE_S)
+                if not self._pending:
+                    self._watcher = None
+                    atexit.unregister(self._stop_watcher)
+                    return
+                entry = self._pending[0]
+            try:
+                with trace_span("ray_tpu.train_step.wait", entry.step):
+                    jax.block_until_ready(entry.handle)
+                done = self._clock()
+                if entry.compile_step:
+                    self.record_step(entry.enqueued - entry.started,
+                                     steps=entry.steps, compile_step=True)
+                else:
+                    # from the completion before it, or from its own
+                    # dispatch where the device had run dry by then
+                    self.record_step(
+                        done - max(entry.started, self._last_done),
+                        steps=entry.steps, tokens=entry.tokens,
+                        examples=entry.examples, flops=entry.flops)
+            except Exception:
+                # the loop's own wait on this step raises the same error;
+                # the step is not booked and the clock restarts at the next
+                logger.warning("train step %s failed on the device",
+                               entry.step, exc_info=True)
+                done = self._clock()
+            with self._pending_cond:
+                self._last_done = done
+                self._pending.popleft()
+                self._pending_cond.notify_all()
+
+    def _stop_watcher(self) -> None:
+        with self._pending_cond:
+            self._closing = True
+            watcher = self._watcher
+            self._pending_cond.notify_all()
+        if watcher is not None:
+            watcher.join(_EXIT_WAIT_S)
+
+    def settle(self, timeout_s: float = 5.0) -> None:
+        """Return once every step whose program has completed (or failed) is
+        booked: the watcher is microseconds behind the device, more on a
+        starved host. Steps still running are not waited for. ``summary`` settles first, so a loop
+        that waited for a step reads it in its next report."""
+        deadline = time.monotonic() + timeout_s
+        with self._pending_cond:
+            while self._pending and _finished(self._pending[0].handle):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return
+                self._pending_cond.wait(left)
+
+    def add_slot_wait(self, seconds: float) -> None:
+        """train.report waited this long for the driver to take a report."""
+        with self._lock:
+            self.slot_wait_s += seconds
+
     # ------------------------------------------------------------ recording
 
     def record_step(
@@ -184,24 +359,29 @@ class StepRecorder:
         steps: int = 1,
         tokens: Optional[int] = None,
         examples: Optional[int] = None,
+        flops: Optional[float] = None,
         compile_step: bool = False,
         start_wall: Optional[float] = None,
     ) -> None:
-        """Record ``steps`` optimizer steps that took ``duration_s`` of wall
-        time in total. ``compile_step`` marks a jit-cache-miss call whose
-        duration is compile + one step — it's booked as compile time, not
-        productive step time, so MFU/throughput aren't poisoned by it."""
+        """Record ``steps`` finished optimizer steps that took ``duration_s``
+        in total, to completion (a call timed to its return at enqueue goes
+        through ``dispatched``). ``compile_step`` marks a jit-cache-miss call
+        whose duration is compile + one step — it's booked as compile time,
+        not productive step time, so MFU/throughput aren't poisoned by it.
+        ``flops`` is the model FLOPs of these steps where the caller knows
+        them; otherwise flops_per_step or flops_per_token x tokens."""
         duration_s = max(0.0, float(duration_s))
         from ray_tpu._private import flight_recorder as _fr
 
-        _fr.record("train.step", b"",
-                   f"{steps}x {duration_s:.4f}s"
-                   + (" compile" if compile_step else ""))
         with self._lock:
             self.steps += steps
             self._last_step_at = self._clock()
+            # numbers, not text: nothing is formatted before a dump
+            _fr.record("train.compile" if compile_step else "train.step",
+                       self.steps, duration_s)
             if compile_step:
                 self.compile_s += duration_s
+                self.compiles += 1
                 if self._storm_k > 0 and self.steps > self._storm_warmup:
                     now_m = self._clock()
                     self._compile_times.append(now_m)
@@ -244,6 +424,12 @@ class StepRecorder:
                 self.tokens += tokens
             if examples:
                 self.examples += examples
+            if not compile_step:
+                if flops is None and self._flops_per_step is not None:
+                    flops = self._flops_per_step * steps
+                elif flops is None and self._flops_per_token and tokens:
+                    flops = self._flops_per_token * tokens
+                self.flops += flops or 0.0
             sample_hbm = (
                 self.steps <= steps or self.steps % _HBM_SAMPLE_EVERY == 0
             )
@@ -320,19 +506,14 @@ class StepRecorder:
     def mfu(self) -> Optional[float]:
         """Model FLOPs utilization: achieved FLOP/s over peak FLOP/s.
 
-        Needs a FLOPs estimate (flops_per_step, or flops_per_token x
-        observed tokens) and a known device peak; returns None otherwise
+        Needs the steps' model FLOPs (given with each step, or from
+        flops_per_step or flops_per_token x observed tokens) and a known
+        device peak; returns None otherwise
         (e.g. on CPU) rather than a fabricated number."""
         peak = self._total_peak_flops()
-        if peak is None or self.productive_s <= 0:
+        if peak is None or self.productive_s <= 0 or not self.flops:
             return None
-        if self._flops_per_step is not None:
-            achieved = self._flops_per_step * self.productive_steps
-        elif self._flops_per_token is not None and self.tokens:
-            achieved = self._flops_per_token * self.tokens
-        else:
-            return None
-        return achieved / self.productive_s / peak
+        return self.flops / self.productive_s / peak
 
     def hbm_bytes_in_use(self) -> Dict[str, float]:
         """Latest per-device HBM bytes in use ({} on CPU — memory_stats()
@@ -342,18 +523,18 @@ class StepRecorder:
 
     def summary(self) -> Dict[str, Any]:
         """Rolling summary dict, also what session.report auto-attaches."""
+        self.settle()
         with self._lock:
-            steps = self.steps
-            productive = self.productive_s
-            compile_s = self.compile_s
-            last = self._last_step_s
-        out = {
-            "steps": steps,
-            "step_time_s": last,
-            "productive_time_s": round(productive, 6),
-            "compile_time_s": round(compile_s, 6),
-            "goodput": round(self.goodput(), 6),
-        }
+            out = {
+                "steps": self.steps,
+                "step_time_s": self._last_step_s,
+                "productive_time_s": round(self.productive_s, 6),
+                "compile_time_s": round(self.compile_s, 6),
+                "compiles": self.compiles,
+                "dispatch_time_s": round(self.dispatch_s, 6),
+                "slot_wait_time_s": round(self.slot_wait_s, 6),
+            }
+        out["goodput"] = round(self.goodput(), 6)
         tps = self.tokens_per_second()
         if tps is not None:
             out["tokens_per_s"] = round(tps, 3)
@@ -403,7 +584,7 @@ class StepRecorder:
             self._metrics = {
                 "step_seconds": Histogram(
                     "ray_tpu_train_step_seconds",
-                    "wall time per optimizer step",
+                    "device time per optimizer step, completion to completion",
                     boundaries=_STEP_SECONDS_BOUNDARIES, tag_keys=tags),
                 "steps_total": Counter(
                     "ray_tpu_train_steps_total",

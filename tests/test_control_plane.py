@@ -365,7 +365,12 @@ def test_upgrade_flush_and_adopt_on_shard():
             # the large response survived the pre-abort flush intact
             assert r["ok"] and len(r["blob"]) == 200_000
             # the connection is now a raw socket owned by the adopter —
-            # talk over a blocking dup of the client fd off-loop
+            # talk over a blocking dup of the client fd off-loop. The
+            # client's own transport still selects on the same socket: stop
+            # it reading, or under load the loop takes the five bytes of the
+            # reply before the blocking recv below does, which then waits
+            # for ever (seen as this test's 10 s timeout, PR 25).
+            c._writer.transport.pause_reading()
             raw = c._writer.get_extra_info("socket").dup()
             raw.setblocking(True)
             loop = asyncio.get_running_loop()

@@ -8,7 +8,9 @@ every compile runs in the test's own process. A compile that passes is not a
 chip run: nothing here is a time or a result.
 """
 
+import collections
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +74,25 @@ def _step_args(ts):
     return state, {"idx": tokens, "targets": tokens}
 
 
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+_CUSTOM_CALL = re.compile(
+    r"^\s*(?:ROOT )?%?([\w\-.]+) = .*custom_call_target=\"tpu_custom_call\"", re.M)
+
+
+def _kernel_calls(compiled_text):
+    """How many tpu_custom_calls of the compiled program carry each kernel's
+    name in their instruction name (the names are what the benchmark's
+    per-kernel metrics and a reader of the trace find them by). A call
+    without one of the three names fails."""
+    counts = collections.Counter()
+    for name in _CUSTOM_CALL.findall(compiled_text):
+        named = [k for k in KERNELS if k in name]
+        assert len(named) == 1, f"tpu_custom_call {name!r} names no one kernel"
+        counts[named[0]] += 1
+    assert sum(counts.values()) == compiled_text.count("tpu_custom_call")
+    return dict(counts)
+
+
 def test_flash_forward_compiles(one_chip):
     c = jax.jit(attention.flash_causal_attention).lower(
         *_qkv((16, 12, 1024, 64), one_chip)).compile()
@@ -83,6 +104,7 @@ def test_flash_backward_compiles(one_chip):
     c = jax.jit(grad).lower(*_qkv((16, 12, 1024, 64), one_chip)).compile()
     # forward (for the residuals) + the dq and dkv kernels
     assert c.as_text().count("tpu_custom_call") == 3
+    assert _kernel_calls(c.as_text()) == dict.fromkeys(KERNELS, 1)
 
 
 def test_flash_long_wide_heads_compile(one_chip):
@@ -102,6 +124,7 @@ def test_causal_attention_under_mesh_keeps_kernel(mesh_2x2, monkeypatch):
     grad = jax.grad(_loss(attn_for_mesh(mesh_2x2)), argnums=(0, 1, 2))
     c = jax.jit(grad).lower(*args).compile()
     assert c.as_text().count("tpu_custom_call") == 3
+    assert _kernel_calls(c.as_text()) == dict.fromkeys(KERNELS, 1)
 
 
 @pytest.mark.timeout(300)
@@ -117,6 +140,9 @@ def test_gpt2_124m_step_fits_one_chip(topo, monkeypatch):
     # one forward and two backward kernels in each of 12 remat'd layers,
     # plus the forward recomputed
     assert c.as_text().count("tpu_custom_call") == 48
+    assert _kernel_calls(c.as_text()) == {
+        "flash_fwd": 24, "flash_bwd_dq": 12, "flash_bwd_dkv": 12}
+    assert c.as_text().startswith("HloModule jit_train_step")
 
 
 @pytest.mark.timeout(300)
@@ -130,4 +156,6 @@ def test_gpt2_step_on_dp_tp_mesh_keeps_kernel(mesh_2x2, monkeypatch):
     assert ts.state_specs["params"]["h_0"]["attn"]["c_attn"]["kernel"] == P(None, "tp")
     text = ts._step.lower(*_step_args(ts)).compile().as_text()
     assert text.count("tpu_custom_call") == 8
+    assert _kernel_calls(text) == {
+        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
     assert "all-reduce(" in text

@@ -1,13 +1,17 @@
 """Step-level training telemetry (train/_telemetry.py): recorder math with
-a fake clock, metric export through util.metrics, HBM absent-on-CPU,
-TrainStep integration, session.report auto-attach, and SPAN events landing
-in the timeline dump.
+a fake clock, the completion clock with handles whose readiness the test
+controls, the model configs' FLOP counts, metric export through
+util.metrics, HBM absent-on-CPU, TrainStep integration, session.report
+auto-attach, the program's spans in a real profiler trace, and SPAN events
+landing in the timeline dump.
 
 CPU-only (JAX_PLATFORMS=cpu via conftest); everything here rides the fast
 marker — the cluster tests use the tiniest possible model/loops.
 """
 
+import glob
 import json
+import threading
 import time
 import urllib.request
 
@@ -15,7 +19,6 @@ import pytest
 
 from ray_tpu.train._telemetry import (
     StepRecorder,
-    estimate_flops_per_token,
     peak_flops_per_device,
     set_current_recorder,
 )
@@ -101,15 +104,182 @@ def test_mfu_from_flops_per_token_and_unknown_device():
     assert peak_flops_per_device("TPU v4") == pytest.approx(275e12)
 
 
-@pytest.mark.fast
-def test_flops_estimate_from_model_config():
+def _mistral_7b_l8():
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig(vocab_size=32768, block_size=8192, n_layer=8, n_head=32,
+                       n_kv_head=8, n_embd=4096, intermediate=14336)
+
+
+def _gpt2_small():
     from ray_tpu.models.gpt2 import GPT2Config
 
-    cfg = GPT2Config.tiny()
-    est = estimate_flops_per_token(cfg)
-    # 6 * (12 L d^2 + vocab d) for tiny: L=2, d=128, vocab=512
-    assert est == pytest.approx(6 * (12 * 2 * 128 * 128 + 512 * 128))
-    assert estimate_flops_per_token(object()) is None
+    return GPT2Config.gpt2_124m()
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("make_cfg, seq_len, flops", [
+    (_gpt2_small, 256, 755_347_968),
+    (_gpt2_small, 1024, 797_815_296),
+    (_mistral_7b_l8, 8192, 12_884_901_888),
+], ids=["gpt2_small.t256", "gpt2_small.t1024", "mistral_7b_l8.t8192"])
+def test_flops_estimate_from_model_config(make_cfg, seq_len, flops):
+    """The program's count is the benchmark's rule (bench/families/, which
+    the program does not import): the literals are what bench computes for
+    its three cells."""
+    assert make_cfg().flops_per_token(seq_len) == flops
+
+
+@pytest.mark.fast
+def test_flops_count_moe_by_active_parameters():
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.models.gpt2_moe import GPT2MoEConfig, MoEConfig
+
+    dense = GPT2Config.tiny()  # L=2, d=128, V=512
+    assert dense.flops_per_token(64) == 6 * (12 * 2 * 128 * 128 + 512 * 128) \
+        + 6 * 2 * 64 * 128
+    # every second block routed: one expert of the 4 d^2 + 4 d^2 kind a token
+    # is a dense MLP's count plus the router
+    one = GPT2MoEConfig.tiny(moe=MoEConfig(num_experts=4, top_k=1), moe_every=2)
+    assert one.matmul_params() == dense.matmul_params() + 128 * 4
+    two = GPT2MoEConfig.tiny(moe=MoEConfig(num_experts=4, top_k=2), moe_every=2)
+    assert two.matmul_params() == one.matmul_params() + 8 * 128 * 128
+
+
+class Handle:
+    """A step's output whose readiness the test controls (what
+    jax.block_until_ready and is_ready look for on a leaf)."""
+
+    def __init__(self):
+        self._done = threading.Event()
+
+    def block_until_ready(self):
+        assert self._done.wait(10)
+        return self
+
+    def is_ready(self):
+        return self._done.is_set()
+
+    def complete(self):
+        self._done.set()
+
+
+class PipelinedLoop:
+    """A loop that keeps one step in flight, on the fake clock: each dispatch
+    takes `dispatch_s` on the calling thread, each completion comes when the
+    test says."""
+
+    def __init__(self, clk, rec, tokens=1000):
+        self.clk, self.rec, self.tokens = clk, rec, tokens
+        self.in_flight = []
+
+    def dispatch(self, dispatch_s=0.001, compile_step=False):
+        started = self.clk()
+        self.clk.advance(dispatch_s)
+        h = Handle()
+        if compile_step:
+            h.complete()  # the caller waited for it
+        else:
+            self.in_flight.append(h)
+        self.rec.dispatched(h, started=started, tokens=self.tokens,
+                            examples=4, compile_step=compile_step)
+
+    def complete_at(self, t):
+        self.clk.t = t
+        self.in_flight.pop(0).complete()
+        self.rec.settle(30.0)  # the watcher books it; a loaded box may be slow
+
+    def finish(self):
+        """Complete what is still in flight, so no watcher is left waiting."""
+        while self.in_flight:
+            self.in_flight.pop(0).complete()
+
+
+@pytest.mark.fast
+def test_completion_clock_times_the_device_not_the_dispatch():
+    clk = FakeClock()
+    rec = _recorder(clk, flops_per_token=6e6, peak_flops=1e12, n_devices=1)
+    loop = PipelinedLoop(clk, rec)
+    loop.dispatch(2.0, compile_step=True)
+    rec.settle(30.0)
+    assert rec.compiles == 1 and rec.compile_s == pytest.approx(2.0)
+    loop.dispatch()
+    for k in range(1, 21):
+        loop.dispatch()  # step k+1 goes out before step k is done
+        loop.complete_at(2.0 + 0.1 * k)
+        assert rec.steps == 1 + k
+    s = rec.summary()
+    assert s["step_time_s"] == pytest.approx(0.1)
+    assert s["productive_time_s"] == pytest.approx(2.0)
+    assert s["tokens_per_s"] == pytest.approx(1000 / 0.1)
+    assert s["examples_per_s"] == pytest.approx(4 / 0.1)
+    assert s["mfu"] == pytest.approx(6e6 * 1000 / 0.1 / 1e12)
+    assert s["compiles"] == 1
+    assert s["dispatch_time_s"] == pytest.approx(21 * 0.001)
+    # elapsed 4.0 s, 2.0 s of it compile: goodput after it within a step of 1
+    assert (s["productive_time_s"] / (clk() - 2.0)) >= 1 - 1 / 20
+    assert rec.goodput() == pytest.approx(2.0 / 4.0, abs=0.01)
+    assert rec.pop_slow_step() is None
+    # a dispatch five times slower than a whole step moves nothing: the
+    # device had work queued, the completion comes on time
+    loop.dispatch(0.05)
+    loop.complete_at(4.1)
+    assert rec.pop_slow_step() is None
+    assert rec.summary()["step_time_s"] == pytest.approx(0.1)
+    # a completion five times late is a slow step
+    loop.dispatch()
+    loop.complete_at(4.6)
+    slow = rec.pop_slow_step()
+    assert slow is not None and slow["ratio"] == pytest.approx(5.0)
+    assert rec.summary()["step_time_s"] == pytest.approx(0.5)
+    loop.finish()
+
+
+@pytest.mark.fast
+def test_completion_clock_starts_a_step_at_its_dispatch_on_an_idle_device():
+    """Where the loop let the device run dry (input stall, checkpoint pause)
+    the step lasts from its own dispatch to its completion, and the pause is
+    lost goodput, not a slow step."""
+    clk = FakeClock()
+    rec = _recorder(clk)
+    loop = PipelinedLoop(clk, rec)
+    for k in range(10):
+        loop.dispatch()
+        loop.complete_at(k * 1.0 + 0.1)  # 0.9 s of every second idle
+        clk.t = (k + 1) * 1.0
+    assert rec.summary()["step_time_s"] == pytest.approx(0.1)
+    assert rec.productive_s == pytest.approx(1.0)
+    assert rec.goodput() == pytest.approx(0.1)
+    assert rec.pop_slow_step() is None
+    loop.finish()
+
+
+@pytest.mark.fast
+def test_completion_clock_leaves_no_thread_and_survives_a_failed_step(monkeypatch):
+    from ray_tpu.train import _telemetry
+
+    class Failing(Handle):
+        def block_until_ready(self):
+            raise RuntimeError("device lost")
+
+        is_ready = block_until_ready
+
+    monkeypatch.setattr(_telemetry, "_WATCHER_IDLE_S", 0.05)
+    clk = FakeClock()
+    rec = _recorder(clk)
+    rec.dispatched(Failing(), started=clk())
+    loop = PipelinedLoop(clk, rec)
+    loop.dispatch()
+    loop.complete_at(0.3)
+    assert rec.steps == 1  # the failed one is not booked, the next one is
+    watcher = rec._watcher
+    assert watcher is not None and watcher.daemon
+    watcher.join(5)
+    assert not watcher.is_alive() and rec._watcher is None
+    loop.dispatch()  # a later dispatch starts a new one
+    loop.complete_at(0.5)
+    assert rec.steps == 2
+    loop.finish()
 
 
 @pytest.mark.fast
@@ -226,11 +396,16 @@ def test_train_step_records_compile_and_steps():
     for _ in range(4):
         state, _ = ts.step(state, ts.shard_batch(batch))
     rec = ts.telemetry
+    # steps are booked when they complete, not when they are enqueued
+    jax.block_until_ready(state)
+    rec.settle(30.0)
     assert rec.steps == 4
+    assert rec.compiles == rec.steps - rec.productive_steps
     assert rec.compile_s > 0
     assert rec.productive_steps >= 2  # at most 2 calls were cache misses
     assert rec.productive_s > 0
     assert rec.tokens == 8 * 32 * rec.productive_steps
+    assert rec.flops == cfg.flops_per_token(32) * rec.tokens
     # CPU: no HBM stats, no MFU (unknown peak) — absent, not wrong
     assert rec.hbm_bytes_in_use() == {}
     s = rec.summary()
@@ -248,6 +423,102 @@ def test_telemetry_opt_out():
     cfg = GPT2Config.tiny(use_flash_attention=False, dtype=jnp.float32)
     ts = TrainStep(cfg, make_mesh({"dp": 8}), telemetry=False)
     assert ts.telemetry is None
+
+
+def _program_spans(trace_dir):
+    """(name, start, end, step, thread line) of every ray_tpu.* span in the
+    profiler's trace under trace_dir."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("ray_tpu."):
+                    stats = {k: v for k, v in e.stats}
+                    spans.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                  stats.get("step"), (plane.name, i)))
+    return spans
+
+
+def test_program_spans_in_a_device_trace_window(monkeypatch, tmp_path):
+    """A window opened by request_device_trace (forced on the CPU) round
+    three steps of a tiny TrainStep and their train.report holds every span
+    of the program, each with its step, the children inside their parents."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    monkeypatch.setenv("RTPU_device_trace_force", "1")
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.train_step import TrainStep
+    from ray_tpu.train import _telemetry
+    from ray_tpu.train._session import (
+        TrainContext, init_session, report, shutdown_session,
+    )
+
+    session = init_session(TrainContext(0, 1, 0, 1, "127.0.0.1"), None,
+                           pipeline_depth=4)
+    try:
+        cfg = GPT2Config.tiny(use_flash_attention=False, dtype=jnp.float32)
+        ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]))
+        state = ts.init(jax.random.PRNGKey(0))
+        idx = np.random.default_rng(0).integers(
+            0, cfg.vocab_size, (2, 16)).astype(np.int32)
+        batch = {"idx": idx, "targets": np.roll(idx, -1, 1)}
+        state, _ = ts.step(state, ts.shard_batch(batch))  # compiles: step 1
+        trace_dir = str(tmp_path / "window")
+        assert _telemetry.request_device_trace(3, trace_dir)
+        for _ in range(3):  # steps 2, 3, 4
+            state, _ = ts.step(state, ts.shard_batch(batch))
+            # the watcher has closed its wait on this step before the next
+            # begins, whatever the load (step 4's ends as the window closes)
+            jax.block_until_ready(state)
+            ts.telemetry.settle(30.0)
+            report({"loss": 0.0})
+            session.reports.get_nowait()
+            session.ack()
+        summary = ts.telemetry.summary()
+    finally:
+        _telemetry.set_current_recorder(None)
+        shutdown_session()
+    spans = _program_spans(trace_dir)
+    by_name = {}
+    for name, start, end, step, line in spans:
+        # the watcher may still be on its (instant) wait for step 1, the
+        # compile call, when the window opens
+        first = 1 if name == "ray_tpu.train_step.wait" else 2
+        assert isinstance(step, int) and first <= step <= 4, (name, step)
+        by_name.setdefault(name.removeprefix("ray_tpu."), {})[step] = (start, end, line)
+    assert sorted(by_name) == [
+        "train.report", "train.report.slot_wait", "train_step.dispatch",
+        "train_step.jit", "train_step.record", "train_step.shard_batch",
+        "train_step.wait"]
+    # the window opens inside step 2 (after its batch was placed) and closes
+    # when step 4 is complete (before it is reported)
+    assert sorted(by_name["train_step.dispatch"]) == [2, 3, 4]
+    # (step 4's wait ends as the window closes, on the watcher's thread)
+    assert {2, 3} <= set(by_name["train_step.wait"])
+    assert sorted(by_name["train_step.shard_batch"]) == [3, 4]
+    assert sorted(by_name["train.report"]) == [2, 3]
+
+    def inside(child, parent):
+        for step, (start, end, line) in by_name[child].items():
+            p_start, p_end, p_line = by_name[parent][step]
+            assert p_start <= start and end <= p_end and line == p_line, (child, step)
+
+    inside("train_step.jit", "train_step.dispatch")
+    inside("train_step.record", "train_step.dispatch")
+    inside("train.report.slot_wait", "train.report")
+    # the watcher waits on its own thread, and ends after the dispatch began
+    for step, (start, end, line) in by_name["train_step.wait"].items():
+        d_start, _, d_line = by_name["train_step.dispatch"][step]
+        assert line != d_line and end > d_start
+    # the counters at the same boundaries, through the summary that is there
+    assert summary["steps"] == 4 and summary["compiles"] == 1
+    assert summary["dispatch_time_s"] > 0 and summary["slot_wait_time_s"] > 0
 
 
 def test_step_spans_reach_timeline_dump(ray_start_regular, tmp_path):
