@@ -51,11 +51,13 @@ LOSS_REL_TOL = 2e-2   # |loss_mesh - loss_one_device| / loss_one_device, per ste
 TELEMETRY_REL_TOL = 2e-2  # the program's step seconds, tokens/s and MFU against the blocked steps
 GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
 
-FULL = {"model": None, "B": 16, "T": 1024, "attn_shape": (16, 12, 1024, 64)}
+# attn_shapes: the flash check runs at both head widths the benchmark's cells use
+FULL = {"model": None, "B": 16, "T": 1024,
+        "attn_shapes": [(16, 12, 1024, 64), (1, 8, 2048, 128)]}
 TINY = {
     "model": {"vocab_size": 257, "block_size": 256, "n_layer": 2, "n_head": 4,
               "n_embd": 64},
-    "B": 4, "T": 256, "attn_shape": (2, 2, 256, 64),
+    "B": 4, "T": 256, "attn_shapes": [(2, 2, 256, 64), (1, 2, 256, 128)],
 }
 
 
@@ -146,17 +148,18 @@ def _check_losses(losses):
         raise RuntimeError(f"loss did not fall: {losses}")
 
 
-def _check_flash_vs_xla(config, on_tpu):
-    """flash_causal_attention against xla_causal_attention, same seed: the
-    output and the three gradients, as max-abs error over the reference's
-    max-abs value."""
+def _check_flash_vs_xla(shape, seed, on_tpu):
+    """flash_causal_attention against xla_causal_attention at one (B, H, T, D),
+    same seed: the output and the three gradients, as max-abs error over the
+    reference's max-abs value, beside the tiles the kernel chose."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import flash_causal_attention, xla_causal_attention
+    from ray_tpu.ops.attention import (
+        flash_causal_attention, flash_tiles, xla_causal_attention)
 
-    shape = tuple(config["attn_shape"])
-    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(config["seed"]), 4)
+    shape = tuple(shape)
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
     q, k, v, w = (jax.random.normal(key, shape, jnp.bfloat16)
                   for key in (kq, kk, kv, kw))
 
@@ -177,8 +180,10 @@ def _check_flash_vs_xla(config, on_tpu):
             raise RuntimeError(f"flash {name}: bad shape or non-finite values")
         errs[name] = float(jnp.abs(a - b).max() / jnp.abs(b).max())
     if max(errs.values()) > ATTN_REL_TOL:
-        raise RuntimeError(f"flash vs xla beyond {ATTN_REL_TOL}: {errs}")
-    return errs
+        raise RuntimeError(f"flash vs xla at {shape} beyond {ATTN_REL_TOL}: {errs}")
+    b, h, t, d = shape
+    return {"shape": list(shape), "rel_err": errs,
+            "tiles": flash_tiles(b * h, t, d, jnp.bfloat16)._asdict()}
 
 
 def one_chip_loop(config):
@@ -213,7 +218,9 @@ def one_chip_loop(config):
     if on_tpu and not report["peak_bytes_in_use"]:
         raise RuntimeError("device reports no peak_bytes_in_use")
     del state
-    report["flash_vs_xla_rel_err"] = _check_flash_vs_xla(config, on_tpu)
+    report["flash_vs_xla"] = [
+        _check_flash_vs_xla(shape, config["seed"], on_tpu)
+        for shape in config["attn_shapes"]]
     report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])
     train.report(report)
 

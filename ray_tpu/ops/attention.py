@@ -1,15 +1,27 @@
 """Fused causal attention: pallas flash kernel (TPU) with an XLA fallback.
 
-FlashAttention-2-style tiling: the query axis is the pallas grid, K/V are
-streamed block-by-block with an online softmax (running max + sum in VMEM
-scratch, fp32). The backward pass recomputes attention per tile from the saved
-logsumexp — O(T) memory instead of O(T^2). All matmuls run on the MXU with
-fp32 accumulation.
+FlashAttention-2-style tiling. A grid step owns one tile of queries (forward,
+dq) or of keys (dkv) of a few heads and loops over the tiles of the other
+operand, whose whole sequence stays in VMEM; the forward keeps an online
+softmax (running max and sum), the backward recomputes the probabilities per
+tile from the saved logsumexp: O(T) memory instead of O(T^2).
 
-The reference framework has no attention kernels at all (its data plane is
-torch); this op is the building block its GPU stack gets from flash-attn, and
-the ring-attention layer (ray_tpu/ops/ring_attention.py) composes it per-step
-for sequence parallelism.
+Precision: q, k, v and dO tiles reach the MXU in the dtype they arrive in
+(bf16 from the models), every matmul accumulates in float32
+(preferred_element_type), and the probabilities and dS are cast to that dtype
+just before their matmuls; those two casts are the only rounding the kernels
+add. Scores, mask, running max and sum, exp, the rescale, lse, delta and the dq
+/ dk / dv accumulators are float32. float32 inputs stay float32 operands (which
+the MXU multiplies at default precision, one bf16 pass on a v5e, as XLA does).
+
+Tiles: `flash_tiles(bh, t, d, dtype)` chooses the tile a grid step owns, the
+tile it loops over and the heads it takes at once from the call's shape, and
+reckons the VMEM the call needs. The causal mask is built only on tiles the
+diagonal crosses; tiles wholly above it are never visited.
+
+The reference framework has no attention kernels at all (its data plane is torch);
+this op is what its GPU stack gets from flash-attn. Ring attention
+(ray_tpu/ops/ring_attention.py) does not call it: its chunk pairs are einsums.
 
 The three pallas calls are named flash_fwd, flash_bwd_dq and flash_bwd_dkv.
 The name reaches the compiled instruction and the profiler's trace (wrapped by
@@ -23,6 +35,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,15 +43,168 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+MIB = 1 << 20
+
+# --------------------------------------------------------------------------
+# tiles
+# --------------------------------------------------------------------------
+
+# Score elements (heads x rows x columns) one tile step works on: enough
+# that the matmuls hide the softmax and a grid step its fixed cost.
+_TILE_ELEMS = 512 * 512
+_MAX_BLOCK = 1024  # rows of a tile, the one a grid step owns or loops over
+# What the compiler may use without being asked (the scoped default on a
+# v5e) and what the rule will ask for at most (of 128 MiB there).
+_VMEM_SCOPED = 16 * MIB
+_VMEM_BUDGET = 96 * MIB
 
 
-def _pick_block(t: int, target: int = 128) -> int:
-    if t % target == 0:
-        return target
-    for b in (64, 32, 16, 8):
-        if t % b == 0:
-            return b
-    return t
+class FlashTiles(NamedTuple):
+    """block_q rows of the tile a grid step owns (queries in flash_fwd and
+    flash_bwd_dq, keys in flash_bwd_dkv), block_k rows of the tiles it
+    loops over (keys, resp. queries), heads per grid step."""
+
+    block_q: int
+    block_k: int
+    heads: int
+
+
+def _vmem_bytes(tiles, t, d, itemsize):
+    """VMEM of the hungriest of the three calls (dkv), in bytes: blocks are
+    double-buffered by the pipeline, a (.., 1, t) float32 row pads to 8
+    sublanes, d pads to 128 lanes, and the loop body holds four float32
+    score tiles (s, p, dp, ds) and two casts."""
+    block_q, block_k, heads = tiles
+    lanes = -(-d // 128) * 128
+    whole = 2 * 2 * t * lanes * itemsize + 2 * 2 * 8 * t * 4   # q, dO (or k, v); lse, delta
+    own = 2 * 4 * block_q * lanes * itemsize                   # two tiles in, two out
+    acc = 2 * block_q * lanes * 4
+    scores = block_q * block_k * (4 * 4 + 2 * itemsize)
+    return heads * (whole + own + acc + scores)
+
+
+def _divisor(t, cap):
+    """The largest multiple of 128 that divides t and is at most cap."""
+    return max(b for b in range(128, max(cap, 128) + 1, 128) if t % b == 0)
+
+
+def flash_tiles(bh: int, t: int, d: int, dtype) -> FlashTiles:
+    """Tiles for a causal flash call on (bh, t, d) operands of `dtype`, from
+    the shape alone: the largest square tile, a multiple of 128 that divides
+    t, up to _MAX_BLOCK (a tile step's matmuls must be long enough to hide
+    its softmax, whose per-row bookkeeping costs the same for a narrow tile
+    as for a wide one); where a head is less than _TILE_ELEMS of scores,
+    several heads a grid step (a grid step's fixed cost is what a short
+    call pays). Heads and then the tile shrink until `_vmem_bytes` reckons
+    that the call fits the VMEM budget."""
+    if t % 128:
+        raise ValueError(f"seq len {t} is not a multiple of 128")
+    itemsize = jnp.dtype(dtype).itemsize
+    block = _divisor(t, _MAX_BLOCK)
+    heads = max(1, min(bh, _TILE_ELEMS // (block * block)))
+
+    def over_budget():
+        return _vmem_bytes((block, block, heads), t, d, itemsize) > _VMEM_BUDGET
+
+    while over_budget() and heads > 1:
+        heads //= 2
+    while over_budget() and block > 128:
+        block = _divisor(t, block - 1)
+    return FlashTiles(block, block, heads)
+
+
+# --------------------------------------------------------------------------
+# the calls
+# --------------------------------------------------------------------------
+
+
+def _call(kernel, name, like, tiles, in_specs, out_specs, out_shape, interpret):
+    """The pallas_call of one of the three kernels on operands like `like`,
+    (bh, t, d): grid over groups of heads and the tiles a step owns."""
+    bh, t, d = like.shape
+    # The scoped default is enough for small calls; beyond it ask for what
+    # the rule reckoned, with a quarter more for what the reckoning leaves out.
+    vmem = _vmem_bytes(tiles, t, d, like.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(kernel, block_q=tiles.block_q, block_k=tiles.block_k),
+        grid=(pl.cdiv(bh, tiles.heads), t // tiles.block_q),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=max(_VMEM_SCOPED, vmem * 5 // 4)),
+        interpret=interpret,
+        name=name,
+    )
+
+
+def _specs(like, tiles):
+    """Block specs of a call on (bh, t, d) operands: the tile a grid step
+    owns and the whole sequence, of a (bh, t, d) array and of a (bh, 1, t)
+    row of float32 (lse, delta)."""
+    _, t, d = like.shape
+    g, block_q = tiles.heads, tiles.block_q
+    own = pl.BlockSpec((g, block_q, d), lambda b, i: (b, i, 0))
+    # a (g, 1, block_q) block keeps Mosaic's last-two-dims tiling rule
+    # satisfied, which a rank-2 (g, block_q) block does not
+    own_row = pl.BlockSpec((g, 1, block_q), lambda b, i: (b, 0, i))
+    whole = pl.BlockSpec((g, t, d), lambda b, i: (b, 0, 0))
+    whole_row = pl.BlockSpec((g, 1, t), lambda b, i: (b, 0, 0))
+    return own, own_row, whole, whole_row
+
+
+# --------------------------------------------------------------------------
+# what the three kernels share
+# --------------------------------------------------------------------------
+
+_NT = (((2,), (2,)), ((0,), (0,)))  # (g, m, c) x (g, n, c) -> (g, m, n)
+_NN = (((2,), (1,)), ((0,), (0,)))  # (g, m, c) x (g, c, n) -> (g, m, n)
+
+
+def _dot(a, b, dims):
+    """One MXU matmul per head: operands as they are, float32 out."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _split_scale(d):
+    """1/sqrt(d) as (factor on an operand of QK^T, factor on the float32
+    scores). On the operand only where it is a power of two, which no dtype
+    rounds."""
+    scale = 1.0 / math.sqrt(d)
+    return (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
+
+
+def _tile_loop(step, carry, plain, diag_start, diag_tiles, plain_after=None):
+    """step(j, carry, masked) over a grid step's tiles: the (lo, hi) range
+    `plain` without the mask, then the `diag_tiles` tiles from diag_start on,
+    which the diagonal crosses, with it (a static count, unrolled), then
+    the range `plain_after` without. A range may be None."""
+    unmasked = functools.partial(step, masked=False)
+    if plain is not None:
+        carry = jax.lax.fori_loop(*plain, unmasked, carry)
+    for s in range(diag_tiles):
+        carry = step(diag_start + s, carry, masked=True)
+    if plain_after is not None:
+        carry = jax.lax.fori_loop(*plain_after, unmasked, carry)
+    return carry
+
+
+def _row_minus_col(block_q, block_k):
+    """Row index less column index of a (block_q, block_k) tile's entries:
+    what a tile on the diagonal compares with its offset from it."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
+
+
+def _own_tile(t, block_q):
+    """Index of the tile this grid step owns; static where there is one."""
+    return 0 if t == block_q else pl.program_id(1)
+
+
+def _rows(ref, j, block):
+    """Tile j of `block` rows along the second axis of a (g, t, d) ref."""
+    return ref[:, pl.ds(pl.multiple_of(j * block, block), block), :]
 
 
 # --------------------------------------------------------------------------
@@ -46,72 +212,65 @@ def _pick_block(t: int, target: int = 128) -> int:
 # --------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q, block_k, seq_len):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # (block_q, d)
-    d = q.shape[-1]
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k):
+    g, _, d = q_ref.shape
+    ratio = block_q // block_k
+    i = _own_tile(k_ref.shape[1], block_q)
+    q_scale, s_scale = _split_scale(d)
+    q = q_ref[...]
+    if q_scale != 1.0:
+        q = q * q_scale
+    # entry (r, c) of q tile i against k tile j is visible iff
+    # i*block_q + r >= j*block_k + c
+    diff = _row_minus_col(block_q, block_k)
 
-    m = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
-    l = jnp.zeros((block_q, 1), dtype=jnp.float32)
-    acc = jnp.zeros((block_q, d), dtype=jnp.float32)
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-
-    def body(kj, carry):
+    def step(j, carry, masked):
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _dot(q, k, _NT)  # (g, block_q, block_k) float32
+        if s_scale != 1.0:
+            s = s * s_scale
+        if masked:
+            s = jnp.where(diff >= j * block_k - i * block_q, s, NEG_INF)
+        s_max = jnp.max(s, axis=-1, keepdims=True)
+        if carry is None:  # a row's first tile: nothing to rescale
+            p = jnp.exp(s - s_max)
+            return s_max, jnp.sum(p, axis=-1, keepdims=True), _dot(p.astype(v.dtype), v, _NN)
         m, l, acc = carry
-        k = k_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (block_q, block_k)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m, s_max)
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
         return m_new, l, acc
 
-    num_k_blocks = (qi + 1) * block_q // block_k  # causal: only blocks at/below diag
-    m, l, acc = jax.lax.fori_loop(0, num_k_blocks, body, (m, l, acc))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # lse rides a (bh, 1, t) layout: block (1, 1, block_q) keeps Mosaic's
-    # last-two-dims tiling rule satisfied (a (1, block_q) rank-2 block is not)
-    lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+    # k tiles 0 .. i*ratio-1 lie below the diagonal, the next `ratio` cross
+    # it. Tile 0 is every row's first; it holds key 0, which every row sees.
+    if isinstance(i, int):  # the only q tile: no tile lies below the diagonal
+        m, l, acc = _tile_loop(step, step(0, None, masked=True), None, 1, ratio - 1)
+    else:
+        init = (jnp.full((g, block_q, 1), NEG_INF, jnp.float32),
+                jnp.zeros((g, block_q, 1), jnp.float32),
+                jnp.zeros((g, block_q, d), jnp.float32))
+        m, l, acc = _tile_loop(step, init, (0, i * ratio), i * ratio, ratio)
+    o_ref[...] = (acc * (1.0 / l)).astype(o_ref.dtype)
+    lse = m + jnp.log(l)
+    for h in range(g):  # (g, block_q, 1) columns -> (g, 1, block_q) rows
+        lse_ref[h, 0] = lse[h, :, 0]
 
 
-def _flash_fwd(q, k, v, *, block_q, block_k, interpret):
-    bh, t, d = q.shape
-    scale = 1.0 / math.sqrt(d)
-    grid = (bh, t // block_q)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, seq_len=t
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
+def _flash_fwd(q, k, v, *, tiles, interpret):
+    own, own_row, whole, _ = _specs(q, tiles)
+    return _call(
+        _fwd_kernel, "flash_fwd", q, tiles,
+        in_specs=[own, whole, whole],
+        out_specs=[own, own_row],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((q.shape[0], 1, q.shape[1]), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
     )(q, k, v)
-    return o, lse
 
 
 # --------------------------------------------------------------------------
@@ -120,129 +279,106 @@ def _flash_fwd(q, k, v, *, block_q, block_k, interpret):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, block_q, block_k):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, None]
-    delta = delta_ref[0, 0][:, None]
-    d = q.shape[-1]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+                   *, block_q, block_k):
+    g, _, d = q_ref.shape
+    ratio = block_q // block_k
+    i = _own_tile(k_ref.shape[1], block_q)
+    q_scale, s_scale = _split_scale(d)
+    q = q_ref[...]
+    if q_scale != 1.0:
+        q = q * q_scale
+    do = do_ref[...]
+    # (g, 1, block_q) rows -> (g, block_q, 1) columns, a head at a time
+    lse = jnp.stack([lse_ref[h, 0][:, None] for h in range(g)])
+    delta = jnp.stack([delta_ref[h, 0][:, None] for h in range(g)])
+    diff = _row_minus_col(block_q, block_k)
 
-    def body(kj, dq):
-        k = k_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    def step(j, dq, masked):
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _dot(q, k, _NT)
+        if s_scale != 1.0:
+            s = s * s_scale
+        if masked:
+            s = jnp.where(diff >= j * block_k - i * block_q, s, NEG_INF)
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        return dq + _dot(ds.astype(k.dtype), k, _NN)
 
-    num_k_blocks = (qi + 1) * block_q // block_k
-    dq = jax.lax.fori_loop(0, num_k_blocks, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    below = None if isinstance(i, int) else (0, i * ratio)
+    dq = _tile_loop(step, jnp.zeros((g, block_q, d), jnp.float32),
+                    below, i * ratio, ratio)
+    dq_ref[...] = (dq * (q_scale * s_scale)).astype(dq_ref.dtype)  # 1/sqrt(d)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    *, scale, block_q, block_k, seq_len):
-    kj = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    d = k.shape[-1]
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+                    *, block_q, block_k):
+    """Owns block_q keys, loops over tiles of block_k queries. Scores are
+    held transposed, (keys, queries): lse and delta then broadcast along
+    sublanes as the (.., 1, t) rows they are stored as, and no matmul needs
+    a transposed operand."""
+    g, _, d = k_ref.shape
+    seq_len = q_ref.shape[1]
+    ratio = block_q // block_k
+    i = _own_tile(seq_len, block_q)
+    q_scale, s_scale = _split_scale(d)
+    k = k_ref[...]
+    if q_scale != 1.0:
+        k = k * q_scale  # for the scores alone: dk sums dS^T Q with q as it is
+    v = v_ref[...]
+    # entry (r, c), key r of tile i against query c of tile j, is visible
+    # iff j*block_k + c >= i*block_q + r
+    diff = _row_minus_col(block_q, block_k)
 
-    def body(qi, carry):
+    def step(j, carry, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        q = _rows(q_ref, j, block_k)
+        do = _rows(do_ref, j, block_k)
+        at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        lse = lse_ref[:, :, at]      # (g, 1, block_k)
+        delta = delta_ref[:, :, at]
+        s = _dot(k, q, _NT)          # (g, block_q keys, block_k queries)
+        if s_scale != 1.0:
+            s = s * s_scale
+        if masked:
+            s = jnp.where(diff <= j * block_k - i * block_q, s, NEG_INF)
         p = jnp.exp(s - lse)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dv = dv + _dot(p.astype(do.dtype), do, _NN)
+        dp = _dot(v, do, _NT)
         ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dk = dk + _dot(ds.astype(q.dtype), q, _NN)
         return dk, dv
 
-    first_q_block = kj * block_k // block_q  # causal: q blocks at/after the diagonal
-    num_q_blocks = seq_len // block_q
-    dk, dv = jax.lax.fori_loop(
-        first_q_block, num_q_blocks, body,
-        (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d), jnp.float32)),
-    )
-    # q was pre-scaled, so ds^T @ q_scaled already carries the 1/sqrt(d) factor.
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    zeros = jnp.zeros((g, block_q, d), jnp.float32)
+    # q tiles before i*ratio see none of these keys, the next `ratio` cross
+    # the diagonal, the rest see all of them
+    after = None if isinstance(i, int) else ((i + 1) * ratio, seq_len // block_k)
+    dk, dv = _tile_loop(step, (zeros, zeros), None, i * ratio, ratio, after)
+    dk_ref[...] = (dk * (q_scale * s_scale)).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, *, block_q, block_k, interpret):
+def _flash_bwd(res, do, *, tiles, interpret):
     q, k, v, o, lse = res
-    do = g
-    bh, t, d = q.shape
-    scale = 1.0 / math.sqrt(d)
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, None, :]  # (bh, 1, t) — same layout as lse
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k),
-        grid=(bh, t // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+    own, own_row, whole, whole_row = _specs(q, tiles)
+    like_q = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dq = _call(
+        _bwd_dq_kernel, "flash_bwd_dq", q, tiles,
+        in_specs=[own, whole, whole, own, own_row, own_row],
+        out_specs=own,
+        out_shape=like_q,
         interpret=interpret,
-        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k, seq_len=t
-        ),
-        grid=(bh, t // block_k),
-        in_specs=[
-            pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, t), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, t), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        ],
+    dk, dv = _call(
+        _bwd_dkv_kernel, "flash_bwd_dkv", q, tiles,
+        in_specs=[whole, own, own, whole, whole_row, whole_row],
+        out_specs=[own, own],
+        out_shape=[like_q, like_q],
         interpret=interpret,
-        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -252,43 +388,42 @@ def _flash_bwd(res, g, *, block_q, block_k, interpret):
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, block_q, block_k, interpret):
-    o, _ = _flash_fwd(q, k, v, block_q=block_q, block_k=block_k, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, tiles, interpret):
+    o, _ = _flash_fwd(q, k, v, tiles=tiles, interpret=interpret)
     return o
 
 
-def _flash_fwd_rule(q, k, v, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, block_q=block_q, block_k=block_k, interpret=interpret)
+def _flash_fwd_rule(q, k, v, tiles, interpret):
+    o, lse = _flash_fwd(q, k, v, tiles=tiles, interpret=interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(block_q, block_k, interpret, res, g):
-    return _flash_bwd(res, g, block_q=block_q, block_k=block_k, interpret=interpret)
+def _flash_bwd_rule(tiles, interpret, res, g):
+    return _flash_bwd(res, g, tiles=tiles, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_causal_attention(q, k, v, *, block_q=None, block_k=None, interpret=False):
-    """q/k/v: (B, H, T, D) → (B, H, T, D); fused causal attention."""
+    """q/k/v: (B, H, T, D) → (B, H, T, D); fused causal attention. Tiles
+    come from `flash_tiles`; block_q / block_k override it (the tests' way
+    to reach every tile shape at small sizes)."""
     b, h, t, d = q.shape
-    block_q = block_q or _pick_block(t)
-    block_k = block_k or _pick_block(t)
-    # The kernel's causal lower bound num_k_blocks = (qi+1)*block_q//block_k
-    # is 0 for early q blocks when block_q < block_k, leaving l==0 and o=NaN.
-    if block_q < block_k or block_q % block_k:
-        raise ValueError(
-            f"block_q ({block_q}) must be a multiple of block_k ({block_k}) "
-            "for the causal flash kernel: its causal bound "
-            "(qi+1)*block_q//block_k floors, skipping keys otherwise"
-        )
-    if t % block_q or t % block_k:
-        raise ValueError(f"seq len {t} must be divisible by block sizes")
+    tiles = flash_tiles(b * h, t, d, q.dtype)
+    if block_q or block_k:
+        block_q, block_k = block_q or tiles.block_q, block_k or tiles.block_k
+        if t % block_q or block_q % block_k:
+            raise ValueError(
+                f"block_q ({block_q}) must divide the seq len ({t}) and be a "
+                f"multiple of block_k ({block_k}): a grid step's tile is cut "
+                "into whole tiles of the other operand along the diagonal")
+        tiles = tiles._replace(block_q=block_q, block_k=block_k)
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
     vf = v.reshape(b * h, t, d)
-    o = _flash(qf, kf, vf, block_q, block_k, interpret)
+    o = _flash(qf, kf, vf, tiles, interpret)
     return o.reshape(b, h, t, d)
 
 

@@ -113,6 +113,22 @@ def test_flash_long_wide_heads_compile(one_chip):
     assert c.as_text().count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("shape", [
+    (128, 12, 256, 64),    # gpt2_small.t256: several heads a grid step
+    (32, 12, 1024, 64),    # gpt2_small.t1024
+    (1, 32, 8192, 128),    # mistral_7b_l8.fsdp4_t8192, one chip's share
+    (1, 7, 256, 64),       # a prime bh: the last grid step's heads run past the end
+], ids=lambda s: "x".join(map(str, s)))
+def test_flash_compiles_at_the_cells_shapes(one_chip, shape):
+    """Forward and backward with the tiles `flash_tiles` picks for the
+    benchmark's per-chip calls: a VMEM overflow or a mis-tiled slice of the
+    larger tiles fails here."""
+    fn = jax.value_and_grad(_loss(attention.flash_causal_attention), argnums=(0, 1, 2))
+    c = jax.jit(fn).lower(*_qkv(shape, one_chip)).compile()
+    assert c.as_text().count("tpu_custom_call") == 3
+    assert _kernel_calls(c.as_text()) == dict.fromkeys(KERNELS, 1)
+
+
 def test_causal_attention_under_mesh_keeps_kernel(mesh_2x2, monkeypatch):
     """A bare pallas_call in a dp/tp-sharded jit is refused ("Mosaic kernels
     cannot be automatically partitioned"); attn_for_mesh shard_maps it."""
