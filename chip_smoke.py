@@ -186,6 +186,21 @@ def _check_flash_vs_xla(shape, seed, on_tpu):
             "tiles": flash_tiles(b * h, t, d, jnp.bfloat16)._asdict()}
 
 
+def _windowed_flash_plan():
+    """The tiles of the windowed flash call of the benchmark's window layers,
+    (2 x 32, 8192, 128) under a window of 1,024, beside the causal call's at
+    that shape, and the path `causal_attention` takes there."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import attention_path, flash_tiles
+
+    bh, t, d, window = 64, 8192, 128, 1024
+    return {"shape": [bh, t, d], "window": window,
+            "tiles": flash_tiles(bh, t, d, jnp.bfloat16, window)._asdict(),
+            "causal_tiles": flash_tiles(bh, t, d, jnp.bfloat16)._asdict(),
+            "attention_path": attention_path(t)}
+
+
 def one_chip_loop(config):
     import jax
 
@@ -221,6 +236,7 @@ def one_chip_loop(config):
     report["flash_vs_xla"] = [
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
         for shape in config["attn_shapes"]]
+    report["windowed_flash"] = _windowed_flash_plan()
     report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])
     train.report(report)
 

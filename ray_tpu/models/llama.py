@@ -112,25 +112,40 @@ class RMSNorm(nn.Module):
         return rms_norm(x, w.astype(x.dtype), self.eps)
 
 
-def rope_angles(head_dim: int, theta: float, positions):
-    """(T,) int positions -> (T, head_dim//2) fp32 angles."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_angles(head_dim: int, theta: float, positions, inv_freq=None):
+    """(T,) int positions -> (T, head_dim//2) fp32 angles; `inv_freq`
+    (head_dim//2 floats) in place of the plain theta^(-2i/head_dim)."""
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     return positions.astype(jnp.float32)[:, None] * inv[None, :]
 
 
-def apply_rope(x, angles):
-    """x (B, T, H, D); angles (T, D//2). Rotate-half convention, fp32 math."""
+def apply_rope(x, angles, scale: float = 1.0):
+    """x (B, T, H, D); angles (T, D//2). Rotate-half convention, fp32 math;
+    cos and sin both times `scale` (YaRN's attention factor)."""
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(dtype)
 
 
 class LlamaAttention(nn.Module):
-    config: LlamaConfig
+    """`config` is a LlamaConfig or any config with its attention fields
+    (models/mellum.py). A layer of a model whose layers differ in kind says
+    how it differs: `window` keys a query sees (None: all before it), its
+    own rotary table `inv_freq` and the factor on the table's cos and sin."""
+
+    config: Any
+    window: Optional[int] = None
+    inv_freq: Optional[tuple] = None
+    rope_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
@@ -143,9 +158,9 @@ class LlamaAttention(nn.Module):
         v = dense(cfg.n_kv_head * hd, "wv")(x).reshape(B, T, cfg.n_kv_head, hd)
 
         positions = jnp.arange(T) + pos_offset
-        ang = rope_angles(hd, cfg.rope_theta, positions)
-        q = apply_rope(q, ang)
-        k = apply_rope(k, ang)
+        ang = rope_angles(hd, cfg.rope_theta, positions, self.inv_freq)
+        q = apply_rope(q, ang, self.rope_scale)
+        k = apply_rope(k, ang, self.rope_scale)
 
         if cfg.n_kv_head != cfg.n_head:
             rep = cfg.n_head // cfg.n_kv_head
@@ -155,16 +170,20 @@ class LlamaAttention(nn.Module):
             v = jnp.broadcast_to(v[:, :, :, None, :], (B, T, cfg.n_kv_head, rep, hd)
                                  ).reshape(B, T, cfg.n_head, hd)
 
+        # a window as long as the sequence holds all of it
+        window = self.window if self.window is not None and self.window < T else None
         if cfg.attn_fn is not None:
-            y = cfg.attn_fn(q, k, v)
+            y = cfg.attn_fn(q, k, v) if window is None else cfg.attn_fn(q, k, v, window=window)
         elif cfg.use_flash_attention:
             from ray_tpu.ops.attention import causal_attention
 
-            y = causal_attention(q, k, v)
+            y = causal_attention(q, k, v, window=window)
         else:
             att = jnp.einsum("bthd,bshd->bhts", q, k,
                              preferred_element_type=jnp.float32) / math.sqrt(hd)
             mask = jnp.tril(jnp.ones((T, T), dtype=bool))
+            if window is not None:
+                mask = mask & ~jnp.tril(jnp.ones((T, T), dtype=bool), -window)
             att = jnp.where(mask[None, None], att, -1e30)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhts,bshd->bthd", att, v)

@@ -14,16 +14,21 @@ add. Scores, mask, running max and sum, exp, the rescale, lse, delta and the dq
 / dk / dv accumulators are float32. float32 inputs stay float32 operands (which
 the MXU multiplies at default precision, one bf16 pass on a v5e, as XLA does).
 
-Tiles: `flash_tiles(bh, t, d, dtype)` chooses the tile a grid step owns, the
-tile it loops over and the heads it takes at once from the call's shape, and
-reckons the VMEM the call needs. The causal mask is built only on tiles the
-diagonal crosses; tiles wholly above it are never visited.
+Tiles: `flash_tiles(bh, t, d, dtype, window)` chooses the tile a grid step
+owns, the tile it loops over and the heads it takes at once from the call's
+shape, and reckons the VMEM the call needs. The causal mask is built only on
+tiles the diagonal crosses; tiles wholly above it are never visited. With a
+window (a query sees its last `window` keys, itself included) the same holds
+at the other edge: tiles wholly behind the window are never visited, those
+its trailing edge crosses are masked, those between are plain.
 
 The reference framework has no attention kernels at all (its data plane is torch);
 this op is what its GPU stack gets from flash-attn. Ring attention
 (ray_tpu/ops/ring_attention.py) does not call it: its chunk pairs are einsums.
 
-The three pallas calls are named flash_fwd, flash_bwd_dq and flash_bwd_dkv.
+The three pallas calls are named flash_fwd, flash_bwd_dq and flash_bwd_dkv,
+and flash_win<window>_fwd, flash_win<window>_bwd_dq, flash_win<window>_bwd_dkv
+where the call has a window shorter than its sequence.
 The name reaches the compiled instruction and the profiler's trace (wrapped by
 the transformations it went through, e.g. transpose_jvp_flash_bwd_dq_), on one
 chip and under a mesh alike, and is how the benchmark's per-kernel metrics
@@ -35,7 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +58,8 @@ MIB = 1 << 20
 # that the matmuls hide the softmax and a grid step its fixed cost.
 _TILE_ELEMS = 512 * 512
 _MAX_BLOCK = 1024  # rows of a tile, the one a grid step owns or loops over
+# Largest tile of a windowed call, as a share of its window.
+_WINDOW_TILE = 0.5
 # What the compiler may use without being asked (the scoped default on a
 # v5e) and what the rule will ask for at most (of 128 MiB there).
 _VMEM_SCOPED = 16 * MIB
@@ -62,11 +69,13 @@ _VMEM_BUDGET = 96 * MIB
 class FlashTiles(NamedTuple):
     """block_q rows of the tile a grid step owns (queries in flash_fwd and
     flash_bwd_dq, keys in flash_bwd_dkv), block_k rows of the tiles it
-    loops over (keys, resp. queries), heads per grid step."""
+    loops over (keys, resp. queries), heads per grid step; `window` keys a
+    query sees, itself included (None: every key before it)."""
 
     block_q: int
     block_k: int
     heads: int
+    window: Optional[int] = None
 
 
 def _vmem_bytes(tiles, t, d, itemsize):
@@ -74,7 +83,7 @@ def _vmem_bytes(tiles, t, d, itemsize):
     double-buffered by the pipeline, a (.., 1, t) float32 row pads to 8
     sublanes, d pads to 128 lanes, and the loop body holds four float32
     score tiles (s, p, dp, ds) and two casts."""
-    block_q, block_k, heads = tiles
+    block_q, block_k, heads = tiles[:3]
     lanes = -(-d // 128) * 128
     whole = 2 * 2 * t * lanes * itemsize + 2 * 2 * 8 * t * 4   # q, dO (or k, v); lse, delta
     own = 2 * 4 * block_q * lanes * itemsize                   # two tiles in, two out
@@ -88,7 +97,7 @@ def _divisor(t, cap):
     return max(b for b in range(128, max(cap, 128) + 1, 128) if t % b == 0)
 
 
-def flash_tiles(bh: int, t: int, d: int, dtype) -> FlashTiles:
+def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None) -> FlashTiles:
     """Tiles for a causal flash call on (bh, t, d) operands of `dtype`, from
     the shape alone: the largest square tile, a multiple of 128 that divides
     t, up to _MAX_BLOCK (a tile step's matmuls must be long enough to hide
@@ -96,11 +105,22 @@ def flash_tiles(bh: int, t: int, d: int, dtype) -> FlashTiles:
     as for a wide one); where a head is less than _TILE_ELEMS of scores,
     several heads a grid step (a grid step's fixed cost is what a short
     call pays). Heads and then the tile shrink until `_vmem_bytes` reckons
-    that the call fits the VMEM budget."""
+    that the call fits the VMEM budget.
+
+    With a `window` shorter than t the call is a windowed one: a tile of b
+    rows visits b + window + b scores a row where window are needed (the
+    diagonal tile and the one on the window's edge are half masked), so
+    the tile is at most _WINDOW_TILE of the window. A window of t or more
+    is the causal call."""
     if t % 128:
         raise ValueError(f"seq len {t} is not a multiple of 128")
+    if window is not None and window < 1:
+        raise ValueError(f"window {window}: a query sees at least itself")
     itemsize = jnp.dtype(dtype).itemsize
-    block = _divisor(t, _MAX_BLOCK)
+    if window is not None and window >= t:
+        window = None
+    cap = _MAX_BLOCK if window is None else min(_MAX_BLOCK, int(window * _WINDOW_TILE))
+    block = _divisor(t, cap)
     heads = max(1, min(bh, _TILE_ELEMS // (block * block)))
 
     def over_budget():
@@ -110,7 +130,7 @@ def flash_tiles(bh: int, t: int, d: int, dtype) -> FlashTiles:
         heads //= 2
     while over_budget() and block > 128:
         block = _divisor(t, block - 1)
-    return FlashTiles(block, block, heads)
+    return FlashTiles(block, block, heads, window)
 
 
 # --------------------------------------------------------------------------
@@ -125,6 +145,11 @@ def _call(kernel, name, like, tiles, in_specs, out_specs, out_shape, interpret):
     # The scoped default is enough for small calls; beyond it ask for what
     # the rule reckoned, with a quarter more for what the reckoning leaves out.
     vmem = _vmem_bytes(tiles, t, d, like.dtype.itemsize)
+    if tiles.window is not None:
+        # a windowed call says so, and how wide: a shape function sees
+        # names and shapes only
+        name = name.replace("flash_", f"flash_win{tiles.window}_", 1)
+        kernel = functools.partial(kernel, window=tiles.window)
     return pl.pallas_call(
         functools.partial(kernel, block_q=tiles.block_q, block_k=tiles.block_k),
         grid=(pl.cdiv(bh, tiles.heads), t // tiles.block_q),
@@ -175,19 +200,63 @@ def _split_scale(d):
     return (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
 
 
-def _tile_loop(step, carry, plain, diag_start, diag_tiles, plain_after=None):
+def _tile_loop(step, carry, plain, diag_start, diag_tiles, plain_after=None,
+               edge=None, edge_after=None):
     """step(j, carry, masked) over a grid step's tiles: the (lo, hi) range
     `plain` without the mask, then the `diag_tiles` tiles from diag_start on,
     which the diagonal crosses, with it (a static count, unrolled), then
-    the range `plain_after` without. A range may be None."""
+    the range `plain_after` without. A windowed call has tiles that the
+    window's trailing edge crosses as well, masked like the diagonal's: the
+    range `edge` before `plain`, `edge_after` after `plain_after`. A range
+    may be None."""
     unmasked = functools.partial(step, masked=False)
+    masked = functools.partial(step, masked=True)
+    if edge is not None:
+        carry = jax.lax.fori_loop(*edge, masked, carry)
     if plain is not None:
         carry = jax.lax.fori_loop(*plain, unmasked, carry)
     for s in range(diag_tiles):
         carry = step(diag_start + s, carry, masked=True)
     if plain_after is not None:
         carry = jax.lax.fori_loop(*plain_after, unmasked, carry)
+    if edge_after is not None:
+        carry = jax.lax.fori_loop(*edge_after, masked, carry)
     return carry
+
+
+def _band(window, block_q, block_k):
+    """Of the tiles on one side of the diagonal's own, counted from it: how
+    many lie wholly inside a window of `window` keys, and how many hold any
+    entry inside it (the rest up to that are cut by its trailing edge). The
+    farthest pair of a tile s steps away is block_q - 1 + (s + 1) * block_k
+    apart, the nearest s * block_k + 1."""
+    return max(0, (window - block_q) // block_k), (window + block_k - 2) // block_k
+
+
+def _before(diag, window, block_q, block_k):
+    """(plain, edge) ranges of the tiles before tile `diag`, the diagonal's
+    first: without a window all of them plain; with one the nearest that lie
+    wholly inside it plain, those its trailing edge cuts masked, the rest
+    not visited. `_tile_loop` takes the edge's first: a row they hide wholly
+    adds exp(0) terms under a running max of NEG_INF, which the rescale of
+    the row's first visible tile (alpha = 0) takes out again."""
+    if window is None:
+        return (0, diag), None
+    inside, any_inside = _band(window, block_q, block_k)
+    first_plain = jnp.maximum(diag - inside, 0)
+    return (first_plain, diag), (jnp.maximum(diag - any_inside, 0), first_plain)
+
+
+def _visible(diff, off, window, keys_first=False):
+    """Which entries of a tile a query sees. `diff` is row less column and
+    `off` the tile's offset from the diagonal, j*block_k - i*block_q; rows
+    are queries, or keys with `keys_first` (flash_bwd_dkv). Causal: the
+    query is not before the key. Windowed: and less than `window` after."""
+    if keys_first:
+        seen = diff <= off
+        return seen if window is None else seen & (diff > off - window)
+    seen = diff >= off
+    return seen if window is None else seen & (diff < off + window)
 
 
 def _row_minus_col(block_q, block_k):
@@ -212,7 +281,7 @@ def _rows(ref, j, block):
 # --------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, window=None):
     g, _, d = q_ref.shape
     ratio = block_q // block_k
     i = _own_tile(k_ref.shape[1], block_q)
@@ -231,7 +300,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k):
         if s_scale != 1.0:
             s = s * s_scale
         if masked:
-            s = jnp.where(diff >= j * block_k - i * block_q, s, NEG_INF)
+            s = jnp.where(_visible(diff, j * block_k - i * block_q, window), s, NEG_INF)
         s_max = jnp.max(s, axis=-1, keepdims=True)
         if carry is None:  # a row's first tile: nothing to rescale
             p = jnp.exp(s - s_max)
@@ -252,7 +321,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k):
         init = (jnp.full((g, block_q, 1), NEG_INF, jnp.float32),
                 jnp.zeros((g, block_q, 1), jnp.float32),
                 jnp.zeros((g, block_q, d), jnp.float32))
-        m, l, acc = _tile_loop(step, init, (0, i * ratio), i * ratio, ratio)
+        plain, edge = _before(i * ratio, window, block_q, block_k)
+        m, l, acc = _tile_loop(step, init, plain, i * ratio, ratio, edge=edge)
     o_ref[...] = (acc * (1.0 / l)).astype(o_ref.dtype)
     lse = m + jnp.log(l)
     for h in range(g):  # (g, block_q, 1) columns -> (g, 1, block_q) rows
@@ -279,7 +349,7 @@ def _flash_fwd(q, k, v, *, tiles, interpret):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, block_q, block_k):
+                   *, block_q, block_k, window=None):
     g, _, d = q_ref.shape
     ratio = block_q // block_k
     i = _own_tile(k_ref.shape[1], block_q)
@@ -300,20 +370,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         if s_scale != 1.0:
             s = s * s_scale
         if masked:
-            s = jnp.where(diff >= j * block_k - i * block_q, s, NEG_INF)
+            s = jnp.where(_visible(diff, j * block_k - i * block_q, window), s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = _dot(do, v, _NT)
         ds = p * (dp - delta)
         return dq + _dot(ds.astype(k.dtype), k, _NN)
 
-    below = None if isinstance(i, int) else (0, i * ratio)
+    plain, edge = (None, None) if isinstance(i, int) else _before(
+        i * ratio, window, block_q, block_k)
     dq = _tile_loop(step, jnp.zeros((g, block_q, d), jnp.float32),
-                    below, i * ratio, ratio)
+                    plain, i * ratio, ratio, edge=edge)
     dq_ref[...] = (dq * (q_scale * s_scale)).astype(dq_ref.dtype)  # 1/sqrt(d)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    *, block_q, block_k):
+                    *, block_q, block_k, window=None):
     """Owns block_q keys, loops over tiles of block_k queries. Scores are
     held transposed, (keys, queries): lse and delta then broadcast along
     sublanes as the (.., 1, t) rows they are stored as, and no matmul needs
@@ -342,7 +413,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         if s_scale != 1.0:
             s = s * s_scale
         if masked:
-            s = jnp.where(diff <= j * block_k - i * block_q, s, NEG_INF)
+            s = jnp.where(_visible(diff, j * block_k - i * block_q, window, keys_first=True),
+                          s, NEG_INF)
         p = jnp.exp(s - lse)
         dv = dv + _dot(p.astype(do.dtype), do, _NN)
         dp = _dot(v, do, _NT)
@@ -352,9 +424,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
 
     zeros = jnp.zeros((g, block_q, d), jnp.float32)
     # q tiles before i*ratio see none of these keys, the next `ratio` cross
-    # the diagonal, the rest see all of them
-    after = None if isinstance(i, int) else ((i + 1) * ratio, seq_len // block_k)
-    dk, dv = _tile_loop(step, (zeros, zeros), None, i * ratio, ratio, after)
+    # the diagonal, the rest see all of them (with a window: the nearest do,
+    # then come those its trailing edge cuts, the rest see none)
+    after = edge = None
+    if not isinstance(i, int):
+        first, last = (i + 1) * ratio, seq_len // block_k
+        if window is None:
+            after = (first, last)
+        else:
+            inside, any_inside = _band(window, block_q, block_k)
+            last_plain = jnp.minimum(first + inside, last)
+            after, edge = (first, last_plain), (last_plain, jnp.minimum(first + any_inside, last))
+    dk, dv = _tile_loop(step, (zeros, zeros), None, i * ratio, ratio, after, edge_after=edge)
     dk_ref[...] = (dk * (q_scale * s_scale)).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -406,12 +487,14 @@ def _flash_bwd_rule(tiles, interpret, res, g):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def flash_causal_attention(q, k, v, *, block_q=None, block_k=None, interpret=False):
-    """q/k/v: (B, H, T, D) → (B, H, T, D); fused causal attention. Tiles
-    come from `flash_tiles`; block_q / block_k override it (the tests' way
-    to reach every tile shape at small sizes)."""
+def flash_causal_attention(q, k, v, *, window=None, block_q=None, block_k=None,
+                           interpret=False):
+    """q/k/v: (B, H, T, D) → (B, H, T, D); fused causal attention, with
+    `window` over the last `window` keys alone (the query's own included).
+    Tiles come from `flash_tiles`; block_q / block_k override it (the tests'
+    way to reach every tile shape at small sizes)."""
     b, h, t, d = q.shape
-    tiles = flash_tiles(b * h, t, d, q.dtype)
+    tiles = flash_tiles(b * h, t, d, q.dtype, window)
     if block_q or block_k:
         block_q, block_k = block_q or tiles.block_q, block_k or tiles.block_k
         if t % block_q or block_q % block_k:
@@ -427,13 +510,15 @@ def flash_causal_attention(q, k, v, *, block_q=None, block_k=None, interpret=Fal
     return o.reshape(b, h, t, d)
 
 
-def xla_causal_attention(q, k, v):
+def xla_causal_attention(q, k, v, window=None):
     """Plain einsum-softmax reference path; XLA fuses it adequately on TPU."""
     d = q.shape[-1]
     t = q.shape[2]
     s = jnp.einsum("bhtd,bhsd->bhts", q, k, preferred_element_type=jnp.float32)
     s = s / math.sqrt(d)
     mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+    if window is not None and window < t:
+        mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bhsd->bhtd", p, v)
@@ -453,8 +538,9 @@ def attention_path(seq_len: int) -> str:
     return "xla"
 
 
-def causal_attention(q, k, v):
-    """Layout-adapting entry: q/k/v (B, T, H, D) → (B, T, H, D).
+def causal_attention(q, k, v, window=None):
+    """Layout-adapting entry: q/k/v (B, T, H, D) → (B, T, H, D); `window`
+    keys a query sees, itself included (None: all before it).
 
     Uses the pallas flash kernel on TPU for sequences long enough to matter;
     XLA path elsewhere (CPU tests, tiny shapes). A Mosaic kernel cannot be
@@ -466,7 +552,7 @@ def causal_attention(q, k, v):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     if attention_path(T) == "flash":
-        o = flash_causal_attention(qt, kt, vt)
+        o = flash_causal_attention(qt, kt, vt, window=window)
     else:
-        o = xla_causal_attention(qt, kt, vt)
+        o = xla_causal_attention(qt, kt, vt, window)
     return o.transpose(0, 2, 1, 3)

@@ -17,7 +17,8 @@ gather, riding ICI.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Tuple
+import functools
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -139,6 +140,269 @@ class MoE(nn.Module):
             "bsec,ebcm->bsm", combine, out, preferred_element_type=jnp.float32
         ).astype(x.dtype)
 
+
+# --------------------------------------------------------------------------
+# One chip's share of a dropless expert layer
+# --------------------------------------------------------------------------
+#
+# `MoE` above gives every expert a static capacity and drops what overflows.
+# `ExpertShare` drops nothing: the k assignments of every token are sorted
+# by expert, the experts held here work on their ragged groups of rows with
+# grouped matmuls, and each token gets its rows back weighted by its gates.
+# The router is as wide as the layer (every expert, held here or not); the
+# gates are renormalised over all k chosen; what the experts held elsewhere
+# would add is left out, and nothing here stands in for them.
+
+
+def route_plan(idx, first_expert, num_held):
+    """idx (N, k): the experts each token chose, of the whole layer. Returns
+    where each assignment goes when those to experts first_expert ..
+    first_expert + num_held - 1 are sorted by expert and put first:
+
+        order (N*k,)       the assignment (token * k + j) at each sorted row
+        inv (N, k)         the sorted row of each assignment
+        held (N, k)        whether the assignment's expert is held here
+        group_sizes (num_held,)   rows of each held expert, in order
+
+    Rows from group_sizes.sum() on belong to experts held elsewhere."""
+    n, k = idx.shape
+    local = idx - first_expert
+    held = (local >= 0) & (local < num_held)
+    key = jnp.where(held, local, num_held).reshape(n * k)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order).reshape(n, k)
+    group_sizes = (key[:, None] == jnp.arange(num_held)[None, :]).sum(0, dtype=jnp.int32)
+    return order, inv, held, group_sizes
+
+
+# Gather and scatter of rows along the plan, with backward passes that are
+# gathers too: the plan is a permutation, so what autodiff would write as a
+# scatter-add of N*k rows (serial on a TPU) is a gather along its inverse.
+# Rows of experts held elsewhere are never computed: `held` keeps whatever
+# lies there out of both directions.
+
+
+@jax.custom_vjp
+def dispatch_rows(x, order, inv, held):
+    """x (N, C) -> (rows, C): the token of each sorted row. `order` may be
+    cut to the buffer's first rows, with `inv` clipped into it: the rows of
+    experts held here come first, so what is cut is nobody's."""
+    return x[order // inv.shape[1]]
+
+
+def _dispatch_fwd(x, order, inv, held):
+    return dispatch_rows(x, order, inv, held), (inv, held)
+
+
+def _dispatch_bwd(res, g):
+    inv, held = res
+    dx = jnp.where(held[..., None], g[inv], 0).astype(jnp.float32).sum(1)
+    return dx.astype(g.dtype), None, None, None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(y, gates, order, inv, held):
+    """y (N*k, C) sorted rows, gates (N, k) float32 -> (N, C) float32: each
+    token's held rows, weighted by its gates and summed."""
+    rows = jnp.where(held[..., None], y[inv], 0)
+    return (rows.astype(jnp.float32) * gates[..., None]).sum(1)
+
+
+def _combine_fwd(y, gates, order, inv, held):
+    return combine_rows(y, gates, order, inv, held), (y, gates, order, inv, held)
+
+
+def _combine_bwd(res, g):
+    y, gates, order, inv, held = res
+    k = inv.shape[1]
+    # in the sorted rows' own order: one gather of the buffer's rows, where
+    # the forward's way round would gather every assignment's
+    g_rows = g[order // k]
+    sorted_gates = jnp.where(held, gates, 0).reshape(-1)[order]
+    d_y = (g_rows * sorted_gates[:, None]).astype(y.dtype)
+    d_gate_rows = (y.astype(jnp.float32) * g_rows).sum(-1)
+    d_gates = jnp.where(held, d_gate_rows[inv], 0)
+    return d_y, d_gates, None, None, None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+# Rows a tile of the grouped matmul takes, and the tiles of its other two
+# dimensions (megablox's (m, k, n) tiling). Measured on the v5e at the
+# benchmark's shapes, 32,768 rows of 131,072 in 16 groups, (2304, 896) and
+# (896, 2304) (my chip run, PR 29): (512, 1024, 1024) 1.28-1.30 ms forward
+# and 2.9-3.5 ms with both gradients; (512, 512, 512) 1.33-1.42 and 3.2-3.5;
+# (256, 1024, 1024) 1.38-1.52 and 3.1-3.7. XLA's own `jax.lax.ragged_dot`
+# at the same shapes: 3.97-5.53 ms and 10.7-10.8 ms.
+_GMM_TILING = (512, 1024, 1024)
+
+
+def _megablox_fits(rows):
+    from ray_tpu.ops.attention import _on_tpu
+
+    return _on_tpu() and rows % _GMM_TILING[0] == 0
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs (M, K) rows sorted by group, rhs (G, K, N), group_sizes (G,):
+    row r of group g times rhs[g]; operands in lhs's dtype, float32
+    accumulation, out in lhs's dtype. Rows past group_sizes.sum() belong to
+    no group: they are not worked on, and what the result holds there is
+    not defined.
+
+    On a TPU the kernel is megablox's (jax.experimental.pallas.ops.tpu:
+    `gmm` forward and for the gradient to the rows, `tgmm` for the gradient
+    to the matrices; the grid follows the groups' tiles, so the time follows
+    the rows routed here and not M). Megablox gives its calls no name of
+    the repo's; the compiler names them gmm and tgmm, which is how the
+    benchmark's moe_gmm metrics find them. Elsewhere, and where M is no
+    multiple of the row tile, `jax.lax.ragged_dot`."""
+    rhs = rhs.astype(lhs.dtype)
+    if _megablox_fits(lhs.shape[0]):
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, _GMM_TILING)
+    out = jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32)
+    return out.astype(lhs.dtype)
+
+
+# Rows of the buffer the experts work in, over the rows an even routing
+# would send here (rounded up to the row tile). At initialisation the
+# benchmark's deepest layer sends 0.21 to 0.36 of its assignments to a
+# quarter of the experts, by seed (my chip run, PR 29): 1.25 would send
+# one seed in four through the buffer of every row.
+_ROW_HEADROOM = 1.5
+
+
+def _expert_rows(rows, plan, weights, x, gates):
+    """The held experts on a buffer of the first `rows` sorted rows: x (N, C)
+    -> (N, C) float32. Every row routed here lies within `rows`."""
+    order, inv, held, group_sizes = plan
+    at, back = order[:rows], jnp.minimum(inv, rows - 1)
+    with jax.named_scope("moe.experts"):
+        taken = dispatch_rows(x, at, back, held)
+        hidden = (nn.silu(grouped_matmul(taken, weights["gate"], group_sizes))
+                  * grouped_matmul(taken, weights["up"], group_sizes))
+        out = grouped_matmul(hidden, weights["down"], group_sizes)
+    with jax.named_scope("moe.combine"):
+        return combine_rows(out, gates, at, back, held)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts_in_buffer(rooms, plan, weights, x, gates):
+    """`_expert_rows` on the smaller of two buffers (rooms: rows of the one
+    with headroom, rows of the one for every assignment) that holds the rows
+    routed here: both are compiled, one runs. The backward pass makes the
+    same choice and differentiates the branch it takes inside it (its
+    forward again, as under `nn.remat`): differentiating the `cond` itself
+    would have the branch that runs write zeros for everything the other
+    one would have kept."""
+    fits = plan[3].sum() <= rooms[0]
+    return jax.lax.cond(fits, functools.partial(_expert_rows, rooms[0]),
+                        functools.partial(_expert_rows, rooms[1]), plan, weights, x, gates)
+
+
+def _experts_in_buffer_fwd(rooms, plan, weights, x, gates):
+    return _experts_in_buffer(rooms, plan, weights, x, gates), (plan, weights, x, gates)
+
+
+def _experts_in_buffer_bwd(rooms, res, g):
+    plan, *operands = res
+
+    def back(rows, plan, *operands):
+        return jax.vjp(functools.partial(_expert_rows, rows, plan), *operands)[1](g)
+
+    fits = plan[3].sum() <= rooms[0]
+    grads = jax.lax.cond(fits, functools.partial(back, rooms[0]),
+                         functools.partial(back, rooms[1]), plan, *operands)
+    return (None, *grads)
+
+
+_experts_in_buffer.defvjp(_experts_in_buffer_fwd, _experts_in_buffer_bwd)
+
+
+class ExpertShare(nn.Module):
+    """(B, T, C) -> (B, T, C): the part of a top-k-of-`num_experts` SwiGLU
+    expert layer that experts first_expert .. first_expert + num_held - 1
+    compute (all of them where num_held is None). Sows the (B, T, k) choices
+    over the whole layer into "choices" (bench/families/__init__.py) and the
+    held experts' row counts into "moe_load" (TrainStep's telemetry).
+
+    No assignment is dropped, whatever the imbalance: every one of the
+    tokens x k rows may be routed here. The buffer that the rows are
+    gathered into has room for `_ROW_HEADROOM` times the even-routing load;
+    a step that routes more here takes the same path over a buffer of all
+    tokens x k rows instead (`jax.lax.cond`: both are compiled, one runs).
+    The grouped matmuls work on the rows routed here either way; the
+    buffer's size is what the gathers and the elementwise work follow."""
+
+    d_model: int
+    d_ff: int
+    num_experts: int
+    top_k: int
+    first_expert: int = 0
+    num_held: Optional[int] = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, C = x.shape
+        n, k = B * T, self.top_k
+        num_held = self.num_experts if self.num_held is None else self.num_held
+        with jax.named_scope("moe.route"):
+            # Router always in fp32: tiny matmul, big numerical leverage.
+            logits = nn.Dense(self.num_experts, use_bias=False, dtype=jnp.float32,
+                              param_dtype=jnp.float32, name="router")(x.astype(jnp.float32))
+            probs = jax.nn.softmax(logits, axis=-1)
+            idx = jax.lax.top_k(probs, k)[1]
+            # the chosen probabilities read through a one-hot product: the
+            # gradient of top_k's own values is a scatter, serial on a TPU
+            chosen = idx[..., None] == jnp.arange(self.num_experts)
+            top_p = jnp.where(chosen, probs[..., None, :], 0.0).sum(-1)
+            gates = (top_p / top_p.sum(-1, keepdims=True)).reshape(n, k)
+            self.sow("choices", "experts", idx)
+            order, inv, held, group_sizes = route_plan(
+                idx.reshape(n, k), self.first_expert, num_held)
+            self.sow("moe_load", "rows", group_sizes)
+
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        weights = {name: self.param(name, init, shape, jnp.float32)
+                   for name, shape in (("gate", (num_held, C, self.d_ff)),
+                                       ("up", (num_held, C, self.d_ff)),
+                                       ("down", (num_held, self.d_ff, C)))}
+
+        plan = (order, inv, held, group_sizes)
+        operands = (weights, x.reshape(n, C).astype(self.dtype), gates)
+        tile = _GMM_TILING[0]
+        room = -(-int(_ROW_HEADROOM * n * k * num_held / self.num_experts) // tile) * tile
+        if room < n * k:
+            y = _experts_in_buffer((room, n * k), plan, *operands)
+        else:
+            y = _expert_rows(n * k, plan, *operands)
+        return y.reshape(B, T, C).astype(x.dtype)
+
+
+def moe_load_metrics(loads, tokens, top_k):
+    """What TrainStep reports of a step's "moe_load" collection (one
+    (num_held,) count of rows a layer): the assignments computed here,
+    their share of all tokens * top_k * layers, and the fullest held
+    expert's rows over the mean's."""
+    rows = jnp.stack(jax.tree.leaves(loads)).astype(jnp.float32)  # (layers, num_held)
+    held = rows.sum()
+    return {"moe_rows_held": held,
+            "moe_held_share": held / (tokens * top_k * rows.shape[0]),
+            "moe_load_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0)}
+
+
+EXPERT_SHARE_SHARDING_PATTERNS = [
+    (r"moe/router/kernel", P()),
+    (r"moe/(gate|up)$", P("ep", "fsdp", "tp")),
+    (r"moe/down$", P("ep", "tp", "fsdp")),
+]
 
 # Expert weights sharded over 'ep' (leading E dim), inner dims reuse the
 # dense tp/fsdp layout; router replicated.

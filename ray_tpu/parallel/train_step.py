@@ -81,13 +81,27 @@ def attn_for_mesh(mesh: Mesh, seq_axis: str = "sp"):
         functools.partial(ring_causal_attention, axis_name=seq_axis)
         if live(seq_axis) else causal_attention
     )
-    return shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
-    )
+
+    def mapped(body):
+        return shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=spec,
+            check_vma=False,
+        )
+
+    whole = mapped(body)
+
+    def attn(q, k, v, window=None):
+        """`window`: a layer that sees its last `window` keys alone."""
+        if window is None:
+            return whole(q, k, v)
+        if live(seq_axis):
+            raise NotImplementedError("ring attention takes no window")
+        return mapped(functools.partial(causal_attention, window=window))(q, k, v)
+
+    return attn
 
 
 def model_for_mesh(cfg, mesh: Optional[Mesh]):
@@ -102,22 +116,28 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
         cfg = dataclasses.replace(cfg, attn_fn=attn_for_mesh(mesh))
     from ray_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEConfig
     from ray_tpu.models.llama import Llama, LlamaConfig
+    from ray_tpu.models.mellum import Mellum, MellumConfig
 
     if isinstance(cfg, GPT2MoEConfig):
         return GPT2MoE(cfg)
     if isinstance(cfg, LlamaConfig):
         return Llama(cfg)
+    if isinstance(cfg, MellumConfig):
+        return Mellum(cfg)
     return GPT2(cfg)
 
 
 def default_rules_for(cfg) -> ShardingRules:
     from ray_tpu.models.gpt2_moe import GPT2_MOE_SHARDING_RULES, GPT2MoEConfig
     from ray_tpu.models.llama import LLAMA_SHARDING_RULES, LlamaConfig
+    from ray_tpu.models.mellum import MELLUM_SHARDING_RULES, MellumConfig
 
     if isinstance(cfg, GPT2MoEConfig):
         return GPT2_MOE_SHARDING_RULES
     if isinstance(cfg, LlamaConfig):
         return LLAMA_SHARDING_RULES
+    if isinstance(cfg, MellumConfig):
+        return MELLUM_SHARDING_RULES
     return GPT2_SHARDING_RULES
 
 
@@ -144,13 +164,26 @@ class TrainStep:
         telemetry: bool = True,
     ):
         from ray_tpu.models.gpt2_moe import GPT2MoEConfig
+        from ray_tpu.models.mellum import MellumConfig
 
         self._is_moe = isinstance(model_cfg, GPT2MoEConfig)
+        # A dropless expert layer adds no term to the loss; the rows its held
+        # experts worked on (the "moe_load" collection) go out with the
+        # step's metrics, for the telemetry.
+        self._reports_moe_load = isinstance(model_cfg, MellumConfig)
         if rules is None:
             rules = default_rules_for(model_cfg)
         self.model_cfg = model_cfg
         self.mesh = mesh
         self.model = model_for_mesh(model_cfg, mesh)
+        # A model whose work follows its weights (routed experts: the rows an
+        # expert gets are the router's doing) says how many steps the rate
+        # climbs over, `lr_warmup_steps`: at the full rate from step 0 AdamW
+        # moves every weight by a third of its initial scale in 20 steps
+        # and the routing of those steps is nobody's workload.
+        warmup = getattr(model_cfg, "lr_warmup_steps", 0)
+        if warmup:
+            learning_rate = optax.linear_schedule(0.0, learning_rate, warmup)
         self.optimizer = optax.chain(
             optax.clip_by_global_norm(grad_clip),
             optax.adamw(
@@ -190,18 +223,25 @@ class TrainStep:
         # modules: xprof groups device time by them.
         def train_step(state, batch):
             def loss_of(params):
+                loads = None
                 if self._is_moe:
                     logits, lstate = self.model.apply(
                         {"params": params}, batch["idx"], mutable=["losses"]
                     )
                     aux = sum(jax.tree.leaves(lstate.get("losses", {})))
+                elif self._reports_moe_load:
+                    logits, sown = self.model.apply(
+                        {"params": params}, batch["idx"], mutable=["moe_load"]
+                    )
+                    aux, loads = 0.0, sown["moe_load"]
                 else:
                     logits = self.model.apply({"params": params}, batch["idx"])
                     aux = 0.0
                 with jax.named_scope("loss"):
-                    return loss_fn(logits, batch["targets"]) + aux
+                    return loss_fn(logits, batch["targets"]) + aux, loads
 
-            loss, grads = jax.value_and_grad(loss_of)(state["params"])
+            (loss, loads), grads = jax.value_and_grad(loss_of, has_aux=True)(
+                state["params"])
             with jax.named_scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, state["opt_state"], state["params"]
@@ -212,8 +252,13 @@ class TrainStep:
                 "opt_state": opt_state,
                 "step": state["step"] + 1,
             }
-            gnorm = optax.global_norm(grads)
-            return new_state, {"loss": loss, "grad_norm": gnorm}
+            metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
+            if loads is not None:
+                from ray_tpu.ops.moe import moe_load_metrics
+
+                metrics.update(moe_load_metrics(
+                    loads, batch["idx"].size, model_cfg.top_k))
+            return new_state, metrics
 
         self._step = jax.jit(
             train_step,

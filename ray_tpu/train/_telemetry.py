@@ -124,6 +124,13 @@ _WATCHER_IDLE_S = 2.0
 _EXIT_WAIT_S = 10.0
 
 
+# Keys of a step's metrics that the summary carries as they are, from the
+# newest completed step (a scan of several: its last): the rows this
+# program's experts worked on, their share of every token's assignments,
+# and the fullest held expert over the mean (ops/moe.py:moe_load_metrics).
+_STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean")
+
+
 @dataclasses.dataclass(slots=True)
 class _InFlight:
     """One enqueued step program: what to wait on, when it was enqueued,
@@ -196,6 +203,9 @@ class StepRecorder:
         # queue for the driver.
         self.dispatch_s = 0.0
         self.slot_wait_s = 0.0
+        # What the newest completed step said of its expert layers (the
+        # _STEP_GAUGES among its metrics), read when the watcher saw it done.
+        self.step_gauges: Dict[str, float] = {}
         # The completion clock: step programs in flight, oldest first, and
         # when the newest finished one was seen complete. The watcher thread
         # waits on them in order and books each at its completion, which a
@@ -303,6 +313,7 @@ class StepRecorder:
                 with trace_span("ray_tpu.train_step.wait", entry.step):
                     jax.block_until_ready(entry.handle)
                 done = self._clock()
+                self._read_gauges(entry.handle)
                 if entry.compile_step:
                     self.record_step(entry.enqueued - entry.started,
                                      steps=entry.steps, compile_step=True)
@@ -323,6 +334,19 @@ class StepRecorder:
                 self._last_done = done
                 self._pending.popleft()
                 self._pending_cond.notify_all()
+
+    def _read_gauges(self, metrics) -> None:
+        """The completed step's own gauges: scalars of a program that has
+        ended, so reading them waits for nothing."""
+        import numpy as np
+
+        if not isinstance(metrics, dict):
+            return
+        found = {k: float(np.asarray(metrics[k]).reshape(-1)[-1])
+                 for k in _STEP_GAUGES if k in metrics}
+        if found:
+            with self._lock:
+                self.step_gauges = found
 
     def _stop_watcher(self) -> None:
         with self._pending_cond:
@@ -533,6 +557,7 @@ class StepRecorder:
                 "compiles": self.compiles,
                 "dispatch_time_s": round(self.dispatch_s, 6),
                 "slot_wait_time_s": round(self.slot_wait_s, 6),
+                **self.step_gauges,
             }
         out["goodput"] = round(self.goodput(), 6)
         tps = self.tokens_per_second()

@@ -1,0 +1,320 @@
+"""models/mellum.py and ops/moe.py's ExpertShare on the CPU at tiny sizes,
+float32, seeded weights: against the plain reference of
+bench/families/mellum.py with the choice held (loss, every gradient, the
+choices themselves); the four shares of an expert layer against the uncut
+layer; a router that sends every token to the same experts (nothing is
+dropped); the YaRN table against numbers worked by hand; and what the layer
+sows, which leaves the step program as it was.
+"""
+
+import hashlib
+import json
+import math
+import os
+import re
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bench import families
+from ray_tpu.models import mellum
+from ray_tpu.models.mellum import Mellum, MellumConfig, YarnScaling, loss_fn, yarn_inv_freq
+from ray_tpu.ops import attention
+from ray_tpu.ops.moe import ExpertShare
+from ray_tpu.parallel.mesh import make_mesh
+from ray_tpu.parallel.train_step import TrainStep
+from ray_tpu.train import _telemetry
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILY = families.load("mellum")
+
+
+def _sizes(**changed):
+    """The cell's configuration at its rehearsal sizes: four layers 3:1, a
+    window shorter than the sequences below, heads x head_dim != hidden."""
+    with open(os.path.join(ROOT, "bench", "configs", "mellum2_12b_l4_ep4.json")) as f:
+        sizes = json.load(f)
+    sizes.update(sizes["rehearsal"])
+    sizes.update(changed)
+    return sizes
+
+
+def _batch(sizes, rows=2, t=96, seed=0):
+    tokens = jnp.asarray(np.random.default_rng(seed).integers(
+        0, sizes["vocab_size"], (rows, t + 1)), jnp.int32)
+    return tokens[:, :-1], tokens[:, 1:]
+
+
+@pytest.mark.parametrize("first_expert", [0, 4])
+def test_system_agrees_with_the_reference_with_the_choice_held(first_expert):
+    sizes = _sizes(first_expert_held=first_expert)
+    model = Mellum(FAMILY.build(sizes, "float32"))
+    idx, targets = _batch(sizes)
+    params = model.init(jax.random.PRNGKey(1), idx)["params"]
+
+    def system(p):
+        logits, sown = model.apply({"params": p}, idx, mutable=["choices"])
+        return loss_fn(logits, targets), sown["choices"]
+
+    with jax.default_matmul_precision("highest"):
+        (want, sown), want_g = jax.value_and_grad(system, has_aux=True)(params)
+    names = FAMILY.layer_names(sizes)
+    held = {name: jax.tree.leaves(sown[name])[0] for name in names}
+    assert all(c.shape == (2, 96, sizes["num_experts_per_tok"]) for c in held.values())
+    got, got_g = jax.value_and_grad(
+        lambda p: families.reference_loss(FAMILY, p, idx, targets, sizes, held))(params)
+    assert float(got) == pytest.approx(float(want), rel=2e-6)
+    flat_w, flat_g = jax.tree.leaves(want_g), jax.tree.leaves(got_g)
+    scale = max(float(jnp.abs(a).max()) for a in flat_w)
+    for a, b in zip(flat_w, flat_g):
+        assert float(jnp.abs(a - b).max()) <= 2e-5 * scale
+
+    # the reference's own choices, layer by layer on the same input
+    _, outer = families.split_params(FAMILY, params, sizes)
+    x, agree = FAMILY.embed(outer, idx, sizes), []
+    for name in names:
+        own = FAMILY.choice(x, params[name], sizes)
+        agree.append(float((held[name][..., :, None] == own[..., None, :]).any(-1).mean()))
+        x, _ = families.layer_with_aux(FAMILY, x, params[name], sizes, held[name])
+    assert min(agree) >= 0.995
+
+
+def _expert_layer(first, held, x, params):
+    layer = ExpertShare(x.shape[-1], params["gate"].shape[-1], 8, 2, first, held, jnp.float32)
+    share = {"router": params["router"],
+             **{k: params[k][first:first + held] for k in ("gate", "up", "down")}}
+    with jax.default_matmul_precision("highest"):
+        return layer.apply({"params": share}, x, mutable=["choices", "moe_load"])
+
+
+def _dense_experts(x, params, k=2):
+    """Every expert on every token, weighted by the token's gate."""
+    hi = jax.lax.Precision.HIGHEST
+    probs = jax.nn.softmax(jnp.einsum("btc,ce->bte", x, params["router"]["kernel"], precision=hi))
+    top_p, idx = jax.lax.top_k(probs, k)
+    gates = top_p / top_p.sum(-1, keepdims=True)
+    y = 0.0
+    for e in range(params["gate"].shape[0]):
+        weight = jnp.where(idx == e, gates, 0.0).sum(-1)
+        h = jax.nn.silu(jnp.einsum("btc,cf->btf", x, params["gate"][e], precision=hi)) \
+            * jnp.einsum("btc,cf->btf", x, params["up"][e], precision=hi)
+        y = y + weight[..., None] * jnp.einsum("btf,fc->btc", h, params["down"][e], precision=hi)
+    return y, idx
+
+
+@pytest.fixture(scope="module")
+def expert_params():
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, 24), jnp.float32)
+    layer = ExpertShare(24, 16, 8, 2, dtype=jnp.float32)
+    return x, layer.init(jax.random.PRNGKey(4), x)["params"]
+
+
+def test_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(expert_params):
+    x, params = expert_params
+    want, idx = _dense_experts(x, params)
+    shares = [_expert_layer(first, 2, x, params) for first in (0, 2, 4, 6)]
+    np.testing.assert_allclose(np.asarray(sum(y for y, _ in shares)), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    rows = np.concatenate([np.asarray(s["moe_load"]["rows"][0]) for _, s in shares])
+    np.testing.assert_array_equal(rows, np.bincount(np.asarray(idx).ravel(), minlength=8))
+    for _, sown in shares:  # every share sows the choices over the whole layer
+        np.testing.assert_array_equal(np.asarray(sown["choices"]["experts"][0]), np.asarray(idx))
+    whole, _ = _expert_layer(0, 8, x, params)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+def test_every_token_to_the_same_experts_and_none_is_dropped(expert_params):
+    x, params = expert_params
+    x = jnp.abs(x)
+    kernel = jnp.zeros_like(params["router"]["kernel"]).at[:, 5].set(2.0).at[:, 2].set(1.0)
+    params = {**params, "router": {"kernel": kernel}}
+    want, idx = _dense_experts(x, params)
+    assert set(np.asarray(idx).ravel()) == {2, 5}
+    got, sown = _expert_layer(0, 8, x, params)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+    rows = np.asarray(sown["moe_load"]["rows"][0])
+    assert rows[2] == rows[5] == x.shape[0] * x.shape[1] and rows.sum() == 2 * rows[2]
+    # the share that holds neither expert computes nothing, and says so
+    none, sown = _expert_layer(6, 2, x, params)
+    assert float(jnp.abs(none).max()) == 0.0 and int(sown["moe_load"]["rows"][0].sum()) == 0
+    # gradients too: against the dense layer's
+    loss = lambda f: lambda x: (f(x)[0] ** 2).sum()
+    g_got = jax.grad(loss(lambda x: _expert_layer(0, 8, x, params)))(x)
+    g_want = jax.grad(loss(lambda x: _dense_experts(x, params)))(x)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want), rtol=1e-4, atol=1e-6)
+
+
+def test_yarn_table_against_numbers_worked_by_hand():
+    """head_dim 128, theta 5e5, factor 16 over 8,192 original positions:
+    corr(32) = 128 ln(8192 / 64 pi) / (2 ln 5e5) = 18.08 -> low 18,
+    corr(1) = 128 ln(8192 / 2 pi) / (2 ln 5e5) = 34.98 -> high 35."""
+    ln = math.log
+    assert math.floor(128 * ln(8192 / (64 * math.pi)) / (2 * ln(5e5))) == 18
+    assert math.ceil(128 * ln(8192 / (2 * math.pi)) / (2 * ln(5e5))) == 35
+    yarn = YarnScaling(16.0, 8192, 1.2772588722239782)
+    got = yarn_inv_freq(128, 5e5, yarn)
+    assert got.shape == (64,) and got.dtype == np.float32
+    plain = lambda i: 5e5 ** (-2 * i / 128)
+    for i in (0, 7, 18):       # ramp 0: the plain frequency
+        assert got[i] == pytest.approx(plain(i), rel=1e-5)
+    for i in (35, 50, 63):     # ramp 1: divided by the factor
+        assert got[i] == pytest.approx(plain(i) / 16, rel=1e-5)
+    for i in (19, 26, 34):     # between: (i - 18) / 17 of the way
+        ramp = (i - 18) / 17
+        assert got[i] == pytest.approx(plain(i) * ((1 - ramp) + ramp / 16), rel=1e-5)
+    assert got[26] == pytest.approx(2.7044e-3, rel=1e-4)  # e^(-0.40625 x 13.1224) = 4.8395e-3, x (9/17 + 8/272)
+    # the reference computes its own, from the same published formula
+    sizes = _sizes()
+    theirs = FAMILY._yarn_inv_freq(128, sizes["rope_parameters"]["full_attention"])
+    np.testing.assert_allclose(np.asarray(theirs), got, rtol=2e-6)
+    cfg = MellumConfig(yarn=yarn)
+    inv, factor = cfg.rotary(mellum.FULL)
+    assert factor == 1.2772588722239782 and len(inv) == 64
+    assert cfg.rotary(mellum.SLIDING) == (None, 1.0)
+
+
+def test_flops_per_token_at_the_cell_s_size():
+    with open(os.path.join(ROOT, "bench", "configs", "mellum2_12b_l4_ep4.json")) as f:
+        sizes = json.load(f)
+    assert FAMILY.matmul_params(sizes) == 4 * (21_233_664 + 147_456 + 12_386_304) + 56_623_104
+    by_hand = 6 * 191_692_800 + 12 * 4096 * (4096 + 3 * 960)
+    assert FAMILY.flops_per_token(sizes, 8192) == by_hand == 1_493_041_152
+    assert FAMILY.build(sizes, "bfloat16").flops_per_token(8192) == by_hand
+
+
+def _tiny_step(cfg):
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
+    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    return ts, state, {"idx": tok, "targets": tok}
+
+
+def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
+    """`sow` into a collection that is not mutable does nothing: the step
+    lowers to the same text with the "choices" line and without it."""
+    cfg = MellumConfig.tiny(num_held=4)
+    texts = []
+    for sows in (True, False):
+        if not sows:
+            plain = nn.Module.sow
+            monkeypatch.setattr(ExpertShare, "sow", lambda self, col, *a, **k: (
+                plain(self, col, *a, **k) if col != "choices" else False))
+        ts, state, batch = _tiny_step(cfg)
+        texts.append(ts._step.lower(state, batch).as_text())
+    assert texts[0] == texts[1] and "stablehlo" in texts[0]
+
+
+# sha256 of the step as `_step_text` gives it, taken on the parent commit
+# (7bba7b8) by the same code.
+PARENT_STEPS = {
+    "gpt2_small": ("bdb9fd2845587a1e8e0dbc5176b4fb041d0c6e8bcb38aa1c1bdaa40956936163", 2, 1024),
+    "mistral_7b_l8": ("5f0d1dbbcc635c1c980087408fee591b15bb602225ff179e99367a00c84966f9", 1, 8192),
+}
+
+
+def _step_text(ts, state, batch):
+    """The step lowered for a TPU, with each Mosaic kernel's serialized body
+    (it holds source lines) taken out, and the step's jaxpr, which holds the
+    kernels' bodies as equations."""
+    traced = ts._step.trace(state, batch)
+    lowered = traced.lower(lowering_platforms=("tpu",)).as_text()
+    return re.sub(r'\\22body\\22: \\22[^\\]*\\22', "body", lowered) + str(traced.jaxpr)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_STEPS))
+def test_old_configurations_lower_to_the_parent_s_step(name, monkeypatch):
+    """The window in the flash kernels, the new arguments of LlamaAttention
+    and the new case in TrainStep change nothing of the step programs the
+    benchmark already measures, flash kernels included."""
+    want, rows, seq_len = PARENT_STEPS[name]
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
+        sizes = json.load(f)
+    cfg = families.load(sizes["family"]).build(sizes, "bfloat16")
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
+    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((rows, seq_len), jnp.int32)
+    text = _step_text(ts, state, {"idx": tok, "targets": tok})
+    assert "flash_fwd" in text and "flash_win" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
+def test_step_reports_its_expert_load_through_the_telemetry():
+    cfg = MellumConfig.tiny(num_held=4, dtype=jnp.float32)
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        state = ts.init(jax.random.PRNGKey(0))
+        idx, targets = _batch({"vocab_size": cfg.vocab_size}, t=64)
+        for _ in range(2):
+            state, m = ts.step(state, ts.shard_batch({"idx": idx, "targets": targets}))
+        jax.block_until_ready(m)
+        assert float(m["moe_rows_held"]) == round(
+            float(m["moe_held_share"]) * idx.size * cfg.top_k * cfg.n_layer)
+        assert 0.3 < float(m["moe_held_share"]) < 0.7   # 4 of 8 experts held
+        assert float(m["moe_load_max_over_mean"]) >= 1.0
+        report = _telemetry.auto_report_metrics()
+        for key in ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean"):
+            assert report[f"telemetry/{key}"] == pytest.approx(float(m[key]))
+    finally:
+        _telemetry.set_current_recorder(None)
+
+
+def test_shape_functions_of_the_new_kernels():
+    from bench import shapes
+
+    window = shapes.load("flash_window")
+    bh, t, d, w = 64, 8192, 128, 1024
+    mm = 2 * (w * t - w * w // 2) * d * bh  # one matmul over the scores a window needs
+    fwd = window(f"flash_win1024_fwd custom-call -> (bf16[{bh},{t},{d}], f32[{bh},1,{t}])")
+    dq = window(f"transpose_jvp_flash_win1024_bwd_dq_ custom-call -> bf16[{bh},{t},{d}]")
+    dkv = window(f"flash_win1024_bwd_dkv custom-call -> (bf16[{bh},{t},{d}], bf16[{bh},{t},{d}])")
+    assert fwd == (2 * mm, bh * (4 * t * d * 2 + t * 4))
+    assert dq[0] == 3 * mm and dkv[0] == 4 * mm
+    assert fwd[0] / shapes.flash_attention("a custom-call -> (bf16[64,8192,128], f32[64,1,8192])")[0] \
+        == pytest.approx(0.234, abs=1e-3)  # of a causal layer's scores
+    assert window("flash_fwd custom-call -> (bf16[64,8192,128], f32[64,1,8192])") is None
+    assert window("flash_win8192_fwd custom-call -> (bf16[64,8192,128], f32[64,1,8192])") is None
+
+    gmm = shapes.load("moe_gmm")
+    meta = "s32[17], s32[111], s32[111], s32[1], "
+    rows = 16384 * 8 * 16 // 64  # the even-routing load: the buffer's 49,152 rows / 1.5
+    up = gmm("gmm custom-call -> bf16[49152,896]", meta + "bf16[49152,2304], bf16[16,2304,896]")
+    assert up == (2 * rows * 2304 * 896, rows * (2304 + 896) * 2 + 16 * 2304 * 896 * 2)
+    d_rows = gmm("gmm custom-call -> bf16[49152,2304]",  # rhs transposed inside the call
+                 meta + "bf16[49152,896], bf16[16,2304,896]")
+    assert d_rows == up
+    down = gmm("gmm custom-call -> bf16[49152,2304]", meta + "bf16[49152,896], bf16[16,896,2304]")
+    assert down == up
+    d_up = gmm("tgmm custom-call -> bf16[16,2304,896]", meta + "bf16[2304,49152], bf16[49152,896]")
+    assert d_up == up
+    assert gmm("fusion fusion -> f32[128,256]", "f32[128,256], f32[128,256]") is None
+    assert gmm("gmm custom-call -> bf16[49152,896]", "") is None
+
+
+@pytest.mark.parametrize("routing", ["even", "all_to_the_held_experts"])
+def test_rows_beyond_the_buffer_s_headroom_take_the_whole_buffer(expert_params, routing):
+    """1,024 tokens, top-2 of 8, experts 2-3 held: the buffer has room for
+    1,024 of the 2,048 assignments (1.5 x the even load of 512, in row
+    tiles). Even routing fits it; a router that sends every token to both
+    held experts does not, and the step takes the buffer of all rows:
+    either way the layer is the dense one, values and gradients."""
+    _, params = expert_params
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 512, 24), jnp.float32)
+    if routing != "even":
+        x = jnp.abs(x)
+        kernel = jnp.zeros_like(params["router"]["kernel"]).at[:, 2].set(2.0).at[:, 3].set(1.0)
+        params = {**params, "router": {"kernel": kernel}}
+    got, sown = _expert_layer(2, 2, x, params)
+    rows = int(sown["moe_load"]["rows"][0].sum())
+    assert (rows <= 1024) == (routing == "even") and (routing == "even" or rows == 2048)
+    held_only = {**params, **{k: params[k].at[:2].set(0).at[4:].set(0)
+                              for k in ("gate", "up", "down")}}
+    want, _ = _dense_experts(x, held_only)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+    loss = lambda f: lambda x: (f(x)[0] ** 2).sum()
+    g_got = jax.grad(loss(lambda x: _expert_layer(2, 2, x, params)))(x)
+    g_want = jax.grad(loss(lambda x: _dense_experts(x, held_only)))(x)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want), rtol=1e-4, atol=1e-5)

@@ -9,6 +9,7 @@ chip run: nothing here is a time or a result.
 """
 
 import collections
+import math
 import os
 import re
 
@@ -175,3 +176,46 @@ def test_gpt2_step_on_dp_tp_mesh_keeps_kernel(mesh_2x2, monkeypatch):
     assert _kernel_calls(text) == {
         "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
     assert "all-reduce(" in text
+
+
+def test_windowed_flash_compiles_at_the_cell_s_shape(one_chip):
+    """mellum2_12b_l4_ep4.t8192's window layers: (2, 32, 8192, 128) under a
+    window of 1,024, forward and backward with the tiles `flash_tiles`
+    picks, each call under the name that says its window."""
+    windowed = lambda q, k, v: attention.flash_causal_attention(q, k, v, window=1024)
+    fn = jax.value_and_grad(_loss(windowed), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*_qkv((2, 32, 8192, 128), one_chip)).compile().as_text()
+    names = _CUSTOM_CALL.findall(text)
+    assert len(names) == text.count("tpu_custom_call") == 3
+    for kernel in ("flash_win1024_fwd", "flash_win1024_bwd_dq", "flash_win1024_bwd_dkv"):
+        assert sum(kernel in n for n in names) == 1, names
+    assert not any(k in n for k in KERNELS for n in names)
+
+
+def test_expert_share_compiles_at_the_cell_s_size(one_chip, monkeypatch):
+    """16 held experts of 64, top-8, on 16,384 tokens of width 2,304: the
+    three grouped matmuls and their six gradients are megablox's kernels
+    under the names the compiler gives them (gmm, tgmm: what the
+    benchmark's moe_gmm metrics look for), once for the buffer with headroom
+    and once for the buffer of every row, and the plan's gathers bring no
+    scatter of rows."""
+    from ray_tpu.ops.moe import ExpertShare
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    layer = ExpertShare(2304, 896, 64, 8, 0, 16)
+    x = jax.ShapeDtypeStruct((2, 8192, 2304), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, 2304), jnp.bfloat16)))["params"])
+    loss = lambda p, x: layer.apply({"params": p}, x).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    names = _CUSTOM_CALL.findall(text)
+    kinds = collections.Counter(re.sub(r"[.\d]+$", "", n) for n in names)
+    assert kinds == {"gmm": 2 * 6, "tgmm": 2 * 3}, kinds
+    # megablox's group metadata is made with scatters of a few hundred
+    # elements; none is as long as the tokens
+    scattered = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", text)
+    assert all(math.prod(map(int, s.split(","))) < 1024 for s in scattered), scattered
+    # the buffer with headroom: 1.5 x 131,072 x 16/64 rows
+    assert "bf16[49152,2304]" in text and "bf16[131072,2304]" in text
