@@ -245,7 +245,7 @@ def four_chip_loop(config):
     import jax
 
     from ray_tpu import train
-    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.mesh import collective_tally, make_mesh
     from ray_tpu.parallel.train_step import TrainStep
 
     devs, report, cfg, batch = _setup(config)
@@ -276,9 +276,13 @@ def four_chip_loop(config):
         state, calls, telemetry[name] = _run_steps(ts, state, sharded, 5)
         if name == "mesh":
             text = ts._step.lower(state, sharded).compile().as_text()
-            report["all_reduces_in_compiled_step"] = text.count("all-reduce(")
+            # the layout as the partitioner made it: collectives by kind and
+            # result shape, so a chip call shows it without a trace
+            tally = collective_tally(text)
+            report["collectives_in_compiled_step"] = {
+                str(c): n for c, n in sorted(tally.items(), key=lambda cn: -cn[0].nbytes * cn[1])}
             report["tpu_custom_calls_in_compiled_step"] = text.count("tpu_custom_call")
-            if not report["all_reduces_in_compiled_step"]:
+            if not any(c.kind == "all-reduce" for c in tally):
                 raise RuntimeError("no all-reduce in the compiled mesh step")
             if on_tpu and not report["tpu_custom_calls_in_compiled_step"]:
                 raise RuntimeError("no tpu_custom_call in the compiled mesh step")

@@ -14,7 +14,9 @@ GPT-2-124M data-parallel). Design notes for the MXU/HBM:
   so each block needs exactly one psum on the 'tp' axis per sublayer — XLA
   inserts it from the shardings;
 - 'fsdp' shards every weight's first dim (ZeRO-3-style gather-per-layer under
-  pjit), 'sp' shards the sequence dim of activations.
+  pjit), 'sp' shards the sequence dim of activations; the residual stream is
+  pinned at the block boundaries to the sharding the model is given (`stream`:
+  parallel/mesh.py:stream_sharding), so it is the weights that are gathered.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.mesh import ShardingRules
+from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,21 +121,24 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     config: GPT2Config
+    stream: Any = None  # the residual stream's sharding, or None
 
     @nn.compact
     def __call__(self, x, deterministic=True):
         cfg = self.config
-        x = x + CausalSelfAttention(cfg, name="attn")(
+        x = pin(x, self.stream)
+        x = pin(x + CausalSelfAttention(cfg, name="attn")(
             nn.LayerNorm(dtype=cfg.dtype, name="ln_1")(x), deterministic
-        )
+        ), self.stream)
         x = x + MLP(cfg, name="mlp")(
             nn.LayerNorm(dtype=cfg.dtype, name="ln_2")(x), deterministic
         )
-        return x
+        return pin(x, self.stream)
 
 
 class GPT2(nn.Module):
     config: GPT2Config
+    stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
 
     @nn.compact
     def __call__(self, idx, deterministic=True):
@@ -146,7 +151,7 @@ class GPT2(nn.Module):
         for i in range(cfg.n_layer):
             # remat each block: recompute activations in the backward pass to
             # trade FLOPs for HBM (jax.checkpoint).
-            x = nn.remat(Block)(cfg, name=f"h_{i}")(x, deterministic)
+            x = nn.remat(Block)(cfg, self.stream, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         # weight-tied head
         logits = wte.attend(x.astype(jnp.float32))

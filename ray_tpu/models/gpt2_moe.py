@@ -24,7 +24,7 @@ from ray_tpu.models.gpt2 import (
     loss_fn,
 )
 from ray_tpu.ops.moe import MOE_SHARDING_PATTERNS, MoE, MoEConfig
-from ray_tpu.parallel.mesh import ShardingRules
+from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,13 +53,15 @@ class GPT2MoEConfig(GPT2Config):
 
 class MoEBlock(nn.Module):
     config: GPT2MoEConfig
+    stream: Any = None  # the residual stream's sharding, or None (models/gpt2.py)
 
     @nn.compact
     def __call__(self, x, deterministic=True):
         cfg = self.config
-        x = x + CausalSelfAttention(cfg, name="attn")(
+        x = pin(x, self.stream)
+        x = pin(x + CausalSelfAttention(cfg, name="attn")(
             nn.LayerNorm(dtype=cfg.dtype, name="ln_1")(x), deterministic
-        )
+        ), self.stream)
         x = x + MoE(
             d_model=cfg.n_embd,
             d_ff=4 * cfg.n_embd,
@@ -67,26 +69,29 @@ class MoEBlock(nn.Module):
             dtype=cfg.dtype,
             name="moe",
         )(nn.LayerNorm(dtype=cfg.dtype, name="ln_2")(x), deterministic)
-        return x
+        return pin(x, self.stream)
 
 
 class DenseBlock(nn.Module):
     config: GPT2MoEConfig
+    stream: Any = None
 
     @nn.compact
     def __call__(self, x, deterministic=True):
         cfg = self.config
-        x = x + CausalSelfAttention(cfg, name="attn")(
+        x = pin(x, self.stream)
+        x = pin(x + CausalSelfAttention(cfg, name="attn")(
             nn.LayerNorm(dtype=cfg.dtype, name="ln_1")(x), deterministic
-        )
+        ), self.stream)
         x = x + MLP(cfg, name="mlp")(
             nn.LayerNorm(dtype=cfg.dtype, name="ln_2")(x), deterministic
         )
-        return x
+        return pin(x, self.stream)
 
 
 class GPT2MoE(nn.Module):
     config: GPT2MoEConfig
+    stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
 
     @nn.compact
     def __call__(self, idx, deterministic=True):
@@ -99,7 +104,7 @@ class GPT2MoE(nn.Module):
         for i in range(cfg.n_layer):
             is_moe = (i % cfg.moe_every) == (cfg.moe_every - 1)
             block = MoEBlock if is_moe else DenseBlock
-            x = block(cfg, name=f"h_{i}")(x, deterministic)
+            x = block(cfg, self.stream, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         return wte.attend(x.astype(jnp.float32))
 

@@ -20,6 +20,10 @@ TPU design notes:
 - tensor-parallel layout is Megatron-style: column-parallel q/k/v/gate/up
   (shard output dim on 'tp'), row-parallel o/down (shard input dim), one psum
   per sublayer inserted by XLA from the shardings;
+- the residual stream is pinned at the block boundaries to the sharding the
+  model is given (`stream`: parallel/mesh.py:stream_sharding, the batch's own
+  split), so under 'fsdp' XLA gathers a block's weights and not its
+  activations; with none (one device) nothing is emitted;
 - each block is wrapped in nn.remat (jax.checkpoint) to trade FLOPs for HBM.
 """
 
@@ -34,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.mesh import ShardingRules
+from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,26 +209,29 @@ class LlamaMLP(nn.Module):
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
+    stream: Any = None  # the residual stream's sharding, or None
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
         cfg = self.config
-        x = x + LlamaAttention(cfg, name="attn")(
+        x = pin(x, self.stream)
+        x = pin(x + LlamaAttention(cfg, name="attn")(
             RMSNorm(cfg.rms_eps, name="attn_norm")(x), pos_offset
-        )
+        ), self.stream)
         x = x + LlamaMLP(cfg, name="mlp")(RMSNorm(cfg.rms_eps, name="mlp_norm")(x))
-        return x
+        return pin(x, self.stream)
 
 
 class Llama(nn.Module):
     config: LlamaConfig
+    stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
 
     @nn.compact
     def __call__(self, idx, pos_offset=0):
         cfg = self.config
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb")(idx)
         for i in range(cfg.n_layer):
-            x = nn.remat(LlamaBlock)(cfg, name=f"h_{i}")(x, pos_offset)
+            x = nn.remat(LlamaBlock)(cfg, self.stream, name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           name="lm_head")(x.astype(jnp.float32))

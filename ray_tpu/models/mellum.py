@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, loss_fn  # noqa: F401
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, ExpertShare
-from ray_tpu.parallel.mesh import ShardingRules
+from ray_tpu.parallel.mesh import ShardingRules, pin
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -140,22 +140,25 @@ class MellumConfig:
 class MellumBlock(nn.Module):
     config: MellumConfig
     kind: str
+    stream: Any = None  # the residual stream's sharding, or None (models/llama.py)
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
         cfg = self.config
+        x = pin(x, self.stream)
         inv_freq, scale = cfg.rotary(self.kind)
         attn = LlamaAttention(
             cfg, window=cfg.sliding_window if self.kind == SLIDING else None,
             inv_freq=inv_freq, rope_scale=scale, name="attn")
-        x = x + attn(RMSNorm(cfg.rms_eps, name="attn_norm")(x), pos_offset)
+        x = pin(x + attn(RMSNorm(cfg.rms_eps, name="attn_norm")(x), pos_offset), self.stream)
         moe = ExpertShare(cfg.n_embd, cfg.expert_dim, cfg.num_experts, cfg.top_k,
                           cfg.first_expert, cfg.num_held, cfg.dtype, name="moe")
-        return x + moe(RMSNorm(cfg.rms_eps, name="moe_norm")(x))
+        return pin(x + moe(RMSNorm(cfg.rms_eps, name="moe_norm")(x)), self.stream)
 
 
 class Mellum(nn.Module):
     config: MellumConfig
+    stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
 
     @nn.compact
     def __call__(self, idx, pos_offset=0):
@@ -166,7 +169,7 @@ class Mellum(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0))(idx)
         for i, kind in enumerate(cfg.layer_types):
-            x = nn.remat(MellumBlock)(cfg, kind, name=f"h_{i}")(x, pos_offset)
+            x = nn.remat(MellumBlock)(cfg, kind, self.stream, name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(x.astype(jnp.float32))

@@ -5,7 +5,10 @@ This is the TPU-native substitute for the reference's NCCL process groups
 instead of creating communicator handles and calling collectives imperatively,
 we build a `jax.sharding.Mesh` over the slice's devices, annotate arrays with
 `NamedSharding`s, and let XLA insert ICI collectives during compilation
-(psum/all-gather/reduce-scatter chosen by the partitioner).
+(psum/all-gather/reduce-scatter chosen by the partitioner). The step program
+also says where the residual stream lives (`stream_sharding`: the batch's own
+split, the hidden dimension whole), so the partitioner gathers weights over
+fsdp and leaves the activations where they are.
 
 Axis conventions used across the framework:
   dp    — data parallel (batch dimension)
@@ -18,9 +21,10 @@ Axis conventions used across the framework:
 
 from __future__ import annotations
 
+import collections
 import math
 import re
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -153,8 +157,67 @@ def batch_sharding(mesh: Mesh, *, data_axes=("dp", "fsdp"), seq_axis="sp") -> Na
     return NamedSharding(mesh, P(data if data else None, seq))
 
 
+def stream_sharding(mesh: Mesh) -> Optional[NamedSharding]:
+    """Where a model's residual stream [batch, seq, hidden] lives: split as
+    the batch is (`batch_sharding`), the hidden dimension whole, which is
+    also what Megatron tp wants of it. None on a one-device mesh: there is
+    nothing to say, and the step lowers as it does without."""
+    if mesh.devices.size == 1:
+        return None
+    return NamedSharding(mesh, P(*batch_sharding(mesh).spec, None))
+
+
+def pin(x, sharding: Optional[NamedSharding]):
+    """`x` held to `sharding` inside a jitted program; `x` itself where
+    there is none. A NamedSharding carries its mesh: no ambient one."""
+    return x if sharding is None else jax.lax.with_sharding_constraint(x, sharding)
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+class Collective(NamedTuple):
+    """One kind of cross-device operation of a compiled program, by the
+    array it leaves on each device."""
+
+    kind: str  # all-reduce, all-gather, all-to-all, reduce-scatter, collective-permute
+    dtype: str
+    shape: Tuple[int, ...]
+
+    @property
+    def nbytes(self) -> int:
+        bits = re.search(r"\d+", self.dtype)  # pred has none: a byte
+        return math.prod(self.shape) * (int(bits.group()) if bits else 8) // 8
+
+    def __str__(self) -> str:
+        return f"{self.kind} {self.dtype}[{','.join(map(str, self.shape))}]"
+
+
+_COLLECTIVE = re.compile(
+    r"= (\([^=]*?\)|\S+) "
+    r"(all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)(?:-done)?\("
+    r"(?:.*?channel_id=(\d+))?")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def collective_tally(compiled_text: str) -> collections.Counter[Collective]:
+    """How many collectives of each kind and result shape a compiled
+    program (`compiled.as_text()`) holds: what the partitioner made of the
+    shardings, read without a chip. One channel counts once (the TPU
+    compiler writes an overlapped collective into each fusion that carries
+    a stage of it, and an asynchronous pair as `-start` and `-done`); a
+    collective of several arrays counts once for each."""
+    tally = collections.Counter()
+    seen = set()
+    for result, kind, channel in _COLLECTIVE.findall(compiled_text):
+        if channel in seen:
+            continue
+        if channel:
+            seen.add(channel)
+        for dtype, dims in _ARRAY.findall(result):
+            tally[Collective(kind, dtype, tuple(int(d) for d in dims.split(",") if d))] += 1
+    return tally
 
 
 def local_slice_info() -> dict:

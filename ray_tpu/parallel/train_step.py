@@ -6,7 +6,10 @@ stack (reference: python/ray/train/torch/train_loop_utils.py:453 prepare_model
 we build a `jax.sharding.Mesh`, assign PartitionSpecs to params/optimizer
 state/batch, and compile ONE train step under jit — XLA inserts the ICI
 collectives (grad psums over dp, param all-gathers over fsdp, activation
-collectives over tp, ring ppermutes over sp) from the shardings.
+collectives over tp, ring ppermutes over sp) from the shardings. Between the
+state and the batch the program states one thing more: the residual stream
+stays split as the batch is (`mesh.stream_sharding`, handed to the model by
+`model_for_mesh`), so the partitioner moves weights and not activations.
 
 Axes (any subset may be trivial/size-1, one rule set serves all):
   dp    batch;                 grads psum over it (DDP-equivalent)
@@ -38,6 +41,7 @@ from ray_tpu.parallel.mesh import (
     ShardingRules,
     batch_sharding,
     filtered_tree_shardings,
+    stream_sharding,
 )
 
 
@@ -106,8 +110,9 @@ def attn_for_mesh(mesh: Mesh, seq_axis: str = "sp"):
 
 def model_for_mesh(cfg, mesh: Optional[Mesh]):
     """Instantiate the model wired for this mesh: shard_map'd attention on
-    more than one device (ring attention iff sp > 1); config type picks the
-    family (GPT2 / GPT2MoE with an ep axis / Llama)."""
+    more than one device (ring attention iff sp > 1) and the residual
+    stream's sharding there (none on one device); config type picks the
+    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum)."""
     import dataclasses
 
     if mesh is not None and cfg.attn_fn is None and mesh.devices.size > 1 and (
@@ -118,13 +123,14 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
     from ray_tpu.models.llama import Llama, LlamaConfig
     from ray_tpu.models.mellum import Mellum, MellumConfig
 
+    stream = None if mesh is None else stream_sharding(mesh)
     if isinstance(cfg, GPT2MoEConfig):
-        return GPT2MoE(cfg)
+        return GPT2MoE(cfg, stream)
     if isinstance(cfg, LlamaConfig):
-        return Llama(cfg)
+        return Llama(cfg, stream)
     if isinstance(cfg, MellumConfig):
-        return Mellum(cfg)
-    return GPT2(cfg)
+        return Mellum(cfg, stream)
+    return GPT2(cfg, stream)
 
 
 def default_rules_for(cfg) -> ShardingRules:

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from ray_tpu.models.gpt2 import GPT2Config
 from ray_tpu.ops import attention
+from ray_tpu.parallel.mesh import collective_tally
 from ray_tpu.parallel.train_step import TrainStep, attn_for_mesh
 
 GIB = 1 << 30
@@ -65,14 +66,22 @@ def _loss(attn):
     return lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum()
 
 
-def _step_args(ts):
+def _step_args(ts, batch=(16, 1024)):
     """(state, batch) of a TrainStep as shapes with its own shardings."""
     shapes = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
     state = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         shapes, ts.state_shardings)
-    tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32, sharding=ts.batch_sharding)
+    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=ts.batch_sharding)
     return state, {"idx": tokens, "targets": tokens}
+
+
+def _live_bytes(compiled):
+    """What the program holds on a device: arguments, results that alias
+    none of them, and temporaries."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
@@ -150,10 +159,7 @@ def test_gpt2_124m_step_fits_one_chip(topo, monkeypatch):
     mesh = Mesh(np.array(topo.devices[:1]), ("dp",))
     ts = TrainStep(GPT2Config.gpt2_124m(), mesh, telemetry=False)
     c = ts._step.lower(*_step_args(ts)).compile()
-    m = c.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    assert live < 16 * GIB, m
+    assert _live_bytes(c) < 16 * GIB, c.memory_analysis()
     # one forward and two backward kernels in each of 12 remat'd layers,
     # plus the forward recomputed
     assert c.as_text().count("tpu_custom_call") == 48
@@ -176,6 +182,51 @@ def test_gpt2_step_on_dp_tp_mesh_keeps_kernel(mesh_2x2, monkeypatch):
     assert _kernel_calls(text) == {
         "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
     assert "all-reduce(" in text
+
+
+@pytest.mark.timeout(600)
+def test_mistral_step_under_fsdp_gathers_weights_not_activations(topo, monkeypatch):
+    """mistral_7b_l8.fsdp4_t8192's step at the cell's widths and batch, depth
+    cut to 2 for compile time: with the residual stream pinned to the
+    batch's split (parallel/mesh.py:stream_sharding) the partitioner gathers
+    each kernel whole, in bf16, and moves no activation. Left to choose it
+    keeps the weights where they are and all-reduces `bf16[4,8192,14336]`
+    five times a layer (PERF.md section 6, PR 30)."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    layers, batch, seq_len, d, ff, kv, vocab = 2, 4, 8192, 4096, 14336, 1024, 32768
+    cfg = LlamaConfig(vocab_size=vocab, block_size=32768, n_layer=layers, n_head=32,
+                      n_kv_head=8, n_embd=d, intermediate=ff, rope_theta=1e6)
+    ts = TrainStep(cfg, Mesh(np.array(topo.devices), ("fsdp",)), telemetry=False)
+    c = ts._step.lower(*_step_args(ts, (batch, seq_len))).compile()
+    text = c.as_text()
+    tally = collective_tally(text)
+
+    def rows(c):  # of a [..., width] array
+        return math.prod(c.shape[:-1]) if len(c.shape) > 1 else 1
+
+    # no sum and no gather leaves the batch's tokens on a chip
+    moved = {str(c): n for c, n in tally.items()
+             if c.kind in ("all-reduce", "all-gather") and c.dtype != "s32"
+             and rows(c) >= batch * seq_len}
+    assert not moved, moved
+    # what is exchanged is the embedding's look-up, made on each chip's
+    # quarter of the hidden dimension: one chip's share of the stream,
+    # forward and backward
+    exchanged = [c for c in tally.elements() if c.kind == "all-to-all"]
+    share = batch * seq_len * d * 2 // 4
+    assert len(exchanged) <= 2 and all(c.nbytes <= share for c in exchanged), exchanged
+    # every kernel is gathered whole, as the matmul's bf16 operand
+    gathered = {c.shape for c in tally if c.kind == "all-gather" and c.dtype == "bf16"}
+    assert {(d, ff), (ff, d), (d, d), (d, kv), (d, vocab)} <= gathered, gathered
+    # and each block's weight gradients are summed over the chips
+    summed = {c.shape for c in tally if c.kind == "all-reduce"}
+    assert {(d, ff), (ff, d), (d, vocab)} <= summed, summed
+    assert _kernel_calls(text) == {
+        "flash_fwd": 2 * layers, "flash_bwd_dq": layers, "flash_bwd_dkv": layers}
+    # 5.25 GiB; the parent's program, by the same code, holds 7.19
+    assert _live_bytes(c) < 5.5 * GIB, c.memory_analysis()
 
 
 def test_windowed_flash_compiles_at_the_cell_s_shape(one_chip):

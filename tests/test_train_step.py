@@ -13,7 +13,9 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.gpt2 import GPT2Config
 from ray_tpu.ops import attention
-from ray_tpu.parallel.mesh import filter_spec_for_mesh, make_mesh, single_axis_mesh
+from ray_tpu.parallel.mesh import (
+    Collective, batch_sharding, collective_tally, filter_spec_for_mesh, make_mesh,
+    single_axis_mesh, stream_sharding)
 from ray_tpu.parallel.train_step import TrainStep, attn_for_mesh
 
 CFG = GPT2Config.tiny(use_flash_attention=False, dtype=jnp.float32)
@@ -172,3 +174,112 @@ def test_multi_step_matches_repeated_step():
     # second call reuses the compiled scan (cached dispatch path)
     s2, m2 = ts2.multi_step(s2, b2, 4)
     assert int(s2["step"]) == 8
+
+
+# ---- the residual stream's sharding (parallel/mesh.py:stream_sharding)
+
+
+@pytest.mark.parametrize("axes, want", [
+    ({"fsdp": 4}, P(("fsdp",), None, None)),
+    ({"dp": 2, "tp": 2}, P(("dp",), None, None)),            # hidden whole: Megatron's stream
+    ({"dp": 2, "fsdp": 2, "sp": 2}, P(("dp", "fsdp"), "sp", None)),
+    ({"tp": 4}, P(None, None, None)),
+    ({"dp": 1, "fsdp": 1}, None),                            # one device: nothing to say
+])
+def test_stream_sharding_is_the_batch_s_split_with_the_hidden_dimension_whole(axes, want):
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n])
+    got = stream_sharding(mesh)
+    if want is None:
+        assert got is None
+        return
+    assert got.spec == want and got.mesh is mesh
+    assert tuple(got.spec)[:2] == tuple(batch_sharding(mesh).spec)
+
+
+def _family(name):
+    from ray_tpu.models.gpt2_moe import GPT2MoEConfig
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.models.mellum import MellumConfig
+
+    kw = dict(use_flash_attention=False)
+    return {"gpt2": lambda: GPT2Config.tiny(**kw),
+            "gpt2_moe": lambda: GPT2MoEConfig.tiny_moe(**kw),
+            "llama": lambda: LlamaConfig.tiny(**kw),
+            "mellum": lambda: MellumConfig.tiny(num_held=4, **kw)}[name]()
+
+
+FAMILIES = ("gpt2", "gpt2_moe", "llama", "mellum")
+
+
+def _lowered_step(cfg, mesh, B=8, T=64):
+    ts = TrainStep(cfg, mesh, telemetry=False)
+    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((B, T), jnp.int32)
+    return ts._step.lower(state, {"idx": tok, "targets": tok}).as_text()
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_one_device_step_lowers_as_it_does_without_the_stream_s_pin(family, monkeypatch):
+    """On a one-device mesh the rule is its own empty case: the step lowers
+    to the same text as with `pin` taken out of the model, so the one-chip
+    cells run the program they ran. A mesh of more devices does say where
+    the stream lives."""
+    import importlib
+
+    cfg = _family(family)
+    one = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    with_hook = _lowered_step(cfg, one)
+    assert "stablehlo" in with_hook and "sharding_constraint" not in with_hook
+    assert "sharding_constraint" in _lowered_step(cfg, make_mesh({"dp": 2}, devices=jax.devices()[:2]))
+    for module in ("gpt2", "gpt2_moe", "llama", "mellum"):
+        monkeypatch.setattr(
+            importlib.import_module(f"ray_tpu.models.{module}"), "pin", lambda x, sharding: x)
+    assert _lowered_step(cfg, one) == with_hook
+    assert "sharding_constraint" not in _lowered_step(
+        cfg, make_mesh({"dp": 2}, devices=jax.devices()[:2]))  # the hook was what said it
+
+
+@pytest.mark.parametrize("family", ("gpt2", "llama"))
+def test_fsdp_step_gathers_weights_and_leaves_the_activations(family):
+    """Four CPU devices, {"fsdp": 4}: the compiled step gathers kernels and
+    sums their gradients; no sum or gather leaves an array with the whole
+    batch's (B, T) in front on a device. Unpinned, the partitioner
+    all-reduces the MLP's intermediates and gathers the stream (the same
+    choice as on the TPU at the benchmark's widths: tests/test_tpu_compile.py)."""
+    B, T = 8, 64
+    cfg = _family(family)
+    ts = TrainStep(cfg, make_mesh({"fsdp": 4}, devices=jax.devices()[:4]), telemetry=False)
+    state = ts.init(jax.random.PRNGKey(0))
+    idx = np.zeros((B, T), np.int32)
+    batch = ts.shard_batch({"idx": idx, "targets": idx})
+    tally = collective_tally(ts._step.lower(state, batch).compile().as_text())
+    moved = {str(c): n for c, n in tally.items()
+             if c.kind in ("all-reduce", "all-gather") and c.shape[:2] == (B, T)
+             and c.dtype.startswith(("f", "bf"))}
+    assert not moved, moved
+    d = cfg.n_embd
+    gathered = {c.shape for c in tally if c.kind == "all-gather"}
+    assert {(d, d), (d, 3 * d) if family == "gpt2" else (d, cfg.mlp_dim)} <= gathered, gathered
+
+
+def test_collective_tally_reads_a_compiled_program_s_text():
+    """By kind and result shape; one channel once (the TPU compiler writes an
+    overlapped collective into every fusion that carries a stage of it); a
+    `-start` is its `-done`'s; a collective of several arrays once for each."""
+    text = """
+  %all-gather.1 = bf16[4096,14336]{1,0:T(8,128)(2,1)} all-gather(%p.1), channel_id=15, replica_groups=[1,4]<=[4], dimensions={0}
+  %all-gather.2 = bf16[4096,14336]{1,0:T(8,128)(2,1)S(1)} all-gather(%p.2), channel_id=15, replica_groups=[1,4]<=[4], dimensions={0}
+  %all-gather.3 = bf16[4096,14336]{1,0} all-gather(%p.3), channel_id=17, dimensions={0}
+  %ar = (f32[]{:T(128)}, f32[4096]{0}) all-reduce(%a, %b), channel_id=3, to_apply=%add
+  %cps = (bf16[96,1024]{1,0}, bf16[96,1024]{1,0}, u32[], u32[]) collective-permute-start(%x), channel_id=9
+  %cpd = bf16[96,1024]{1,0} collective-permute-done(%cps)
+  %a2a = bf16[4,1,8192,1024]{3,2,1,0} all-to-all(%y), channel_id=21, dimensions={0}
+  %fusion.7 = bf16[8192,14336]{1,0} fusion(%all-gather.3), kind=kOutput, calls=%fused
+"""
+    tally = collective_tally(text)
+    assert {str(c): n for c, n in tally.items()} == {
+        "all-gather bf16[4096,14336]": 2, "all-reduce f32[]": 1, "all-reduce f32[4096]": 1,
+        "collective-permute bf16[96,1024]": 1, "all-to-all bf16[4,1,8192,1024]": 1}
+    gather = Collective("all-gather", "bf16", (4096, 14336))
+    assert gather.nbytes == 4096 * 14336 * 2 and Collective("all-gather", "pred", (4, 8)).nbytes == 32
