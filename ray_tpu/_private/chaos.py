@@ -11,8 +11,7 @@ replayable).
 Named **injection sites** are threaded through the hot seams of the
 runtime; each is a plain ``hit(site, **attrs)`` call guarded by the
 module-level ``ARMED`` flag, so with no plan loaded the per-call cost is
-one module attribute read (the tier-1 perf gate keeps this honest: with
-``RTPU_chaos_plan`` unset the microbench rows must stay in-band).
+one module attribute read.
 
 SITE-NAME STABILITY CONTRACT
 ----------------------------

@@ -175,15 +175,10 @@ _FLAGS: Dict[str, Any] = {
     "profile_max_samples": 200_000,
     "device_trace_steps": 0,
     "device_trace_force": False,
-    # --- perf regression plane (stability contract) -------------------------
-    # Same contract as the profiling flags above: operators and CI key on
-    # these names (perf.yml, README "Catching a perf regression").
-    #   perf_history_path            the perf ledger (JSONL, one entry per
-    #                                committed measurement); relative paths
-    #                                resolve against the repo root
-    #   perf_band_scale              multiplier applied to every noise band
-    #                                in _private/perf_gate.py (set >1 on
-    #                                boxes noisier than the reference box)
+    # --- compile-storm detector (stability contract) ------------------------
+    # Same contract as the profiling flags above: operators key on these
+    # names (train/_telemetry.py reads them, the watchdog raises the
+    # incident).
     #   perf_compile_storm_k         >= K post-warmup jit compiles within
     #                                perf_compile_storm_window_s raise a
     #                                jit_cache_miss_storm incident
@@ -192,8 +187,6 @@ _FLAGS: Dict[str, Any] = {
     #   perf_compile_warmup_steps    compiles while total recorded steps
     #                                <= N are expected (first trace /
     #                                shape priming) and never counted
-    "perf_history_path": "PERF_HISTORY.jsonl",
-    "perf_band_scale": 1.0,
     "perf_compile_storm_k": 3,
     "perf_compile_storm_window_s": 120.0,
     "perf_compile_warmup_steps": 4,

@@ -5,8 +5,8 @@ task/span timeline + device-trace links) to every incident it opens — but a
 multi-MB trace is an artifact an operator has to go open. This pass closes
 the loop: it inspects the capture the moment it is written and records a
 compact, human-readable analysis *inside the incident record itself*, so
-``ray-tpu debug incidents`` / ``GET /api/perf`` show the probable cause
-without anyone loading Perfetto:
+``ray-tpu debug incidents`` shows the probable cause without anyone loading
+Perfetto:
 
   - **top folded stacks** — where the cluster's CPU time actually went
     during the capture window (per-stack share of all samples);
@@ -24,7 +24,7 @@ failure to analyze must never lose the incident (callers guard)."""
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 # Frames that indicate tracing/lowering/compilation rather than execution.
 # Conservative on purpose: matching real XLA/jax internals, not any frame
@@ -176,17 +176,3 @@ def attach_analysis(incident: Dict[str, Any]) -> bool:
     incident["analysis"] = analysis
     return True
 
-
-def latest_incident_analysis(gcs, limit: int = 20) -> Optional[Dict[str, Any]]:
-    """Newest incident that carries an analysis (dashboard convenience)."""
-    try:
-        incidents = gcs.call(
-            "ListIncidents", {"limit": limit}, timeout=10)["incidents"]
-    except Exception:
-        return None
-    for inc in reversed(incidents):
-        if inc.get("analysis"):
-            return {"id": inc.get("id"), "kind": inc.get("kind"),
-                    "time": inc.get("time"),
-                    "analysis": inc["analysis"]}
-    return None

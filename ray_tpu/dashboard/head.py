@@ -17,9 +17,6 @@ Routes:
                                    HBM rollups, ownership ledgers;
                                    ?group_by=job|actor|node, ?leaks=1
                                    runs the leak detector)
-  GET  /api/perf                   perf-gate ledger + latest delta report
-                                   (?metric= one metric's trajectory,
-                                   ?limit=N history depth)
   GET  /api/jobs/                  submitted jobs (job_submission API)
   POST /api/jobs/                  submit {entrypoint, runtime_env?, ...}
   GET  /api/jobs/<id>              job info
@@ -100,8 +97,6 @@ class DashboardHead:
             return self._logs_api(path, query or {})
         if path.startswith("/api/profile"):
             return self._profile_api(query or {})
-        if path == "/api/perf":
-            return self._perf_api(query or {})
         if path == "/api/memory":
             return self._memory_api(query or {})
         if path == "/api/node_stats":
@@ -259,47 +254,6 @@ class DashboardHead:
             return 200, report
         except Exception as e:
             return 500, {"error": str(e)}
-
-    def _perf_api(self, query):
-        """GET /api/perf: the perf regression plane over HTTP — the ledger
-        trajectory (PERF_HISTORY.jsonl via _private/perf_gate.py), the delta
-        report between the two newest entries, and the newest incident that
-        carries an auto-analysis ("why was that step slow"). Read-only: this
-        endpoint never runs a bench."""
-        from ray_tpu._private import perf_analysis, perf_gate as pg
-
-        try:
-            limit = int(query.get("limit", 20) or 20)
-        except ValueError:
-            return 400, {"error": "limit must be an integer"}
-        entries = pg.load_history(limit=limit)
-        out = {"path": pg.history_path(),
-               "history": [
-                   {k: e.get(k) for k in
-                    ("time", "iso", "git", "reps", "quick", "note",
-                     "metrics")}
-                   for e in entries
-               ]}
-        if len(entries) >= 2:
-            base, cur = entries[-2], entries[-1]
-            out["delta"] = pg.compare(
-                base["metrics"], cur["metrics"],
-                base_reps=base.get("reps", 1), cur_reps=cur.get("reps", 1))
-        metric = query.get("metric")
-        if metric:
-            out["series"] = [
-                {"time": e.get("time"), "git": e.get("git", ""),
-                 "value": e["metrics"].get(metric)}
-                for e in entries
-            ]
-        try:
-            analysis = perf_analysis.latest_incident_analysis(
-                self._gcs_client())
-        except Exception:
-            analysis = None  # ledger output stays useful without a GCS
-        if analysis:
-            out["latest_incident_analysis"] = analysis
-        return 200, out
 
     # ------------------------------------------------- workload telemetry
 

@@ -26,16 +26,6 @@ Commands:
                                  task timeline (Perfetto JSON); --flame for
                                  folded stacks, --pid N for one worker
   grafana  [-o FILE]             generated Grafana dashboard JSON
-  perf check   [--only SUBSTR] [--quick] [--history FILE] [--update]
-               [--strict]        run microbench metrics and gate them
-                                 against the PERF_HISTORY.jsonl baseline
-                                 (exit 1 on regression beyond noise band;
-                                 advisory on 1-core boxes unless --strict)
-  perf compare BASE HEAD [-o FILE] [--skip-noisy]
-                                 gate two microbench --json result files
-                                 (the CI A/B path, perf.yml)
-  perf history [--metric M] [--limit N]
-                                 print the perf ledger trajectory
   lint [PATHS...] [--baseline F] [--update-baseline] [--json] [--verbose]
                                  invariant lint plane: stability-contract
                                  cross-check (flags/metrics/events/chaos
@@ -666,112 +656,6 @@ def cmd_debug(args):
     print(f"wrote {len(files)} files to {out}")
 
 
-def cmd_perf(args):
-    """Perf regression plane (no cluster address needed — the bench boots
-    its own): `check` measures now and gates against the ledger head,
-    `compare` gates two saved measurements (CI), `history` prints the
-    ledger. Exit code 1 = regression beyond the noise band."""
-    from ray_tpu._private import perf_gate as pg
-
-    if args.perf_cmd == "check":
-        report, _result = pg.check(
-            only=args.only, quick=args.quick, history=args.history,
-            update=args.update, note=args.note)
-        exit_fail = report["status"] == "fail"
-        if exit_fail and pg.is_noisy_runner() and not args.strict:
-            # Cross-TIME comparison on a single-core box: co-tenant load is
-            # indistinguishable from a code regression (the CI A/B measures
-            # base and head back-to-back instead, so it stays strict).
-            report["advisory"] = True
-            exit_fail = False
-        if exit_fail and report.get("host_mismatch") and not args.strict:
-            # Baseline and current ran on different core counts: the
-            # multi-process rows measure the box, not the code.
-            report["advisory"] = True
-            exit_fail = False
-        if args.as_json:
-            print(json.dumps(report))
-        else:
-            print(pg.render_report(report))
-            if report.get("host_mismatch"):
-                hm = report["host_mismatch"]
-                print(f"warning: baseline measured on "
-                      f"{hm['baseline_cpus']} cpus, this run on "
-                      f"{hm['current_cpus']} — cross-core-count deltas on "
-                      "the multi-process rows track the runner, not the "
-                      "code")
-            if report.get("advisory"):
-                print("warning: regression(s) measured on a single-core box "
-                      "or across different core counts are ADVISORY — pass "
-                      "--strict to fail anyway, A/B the suspect metric "
-                      "back-to-back on one box, or re-baseline with "
-                      "--update")
-        if args.output:
-            with open(args.output, "w") as f:
-                json.dump(report, f, indent=2)
-        if exit_fail:
-            sys.exit(1)
-        return
-
-    if args.perf_cmd == "compare":
-        base = pg.load_result_entry(args.base)
-        head = pg.load_result_entry(args.head)
-        cpus_differ = (base["cpus"] and head["cpus"]
-                       and base["cpus"] != head["cpus"])
-        if args.skip_noisy and pg.is_noisy_runner():
-            report = {"status": "skipped",
-                      "reason": "single-core runner: multi-process metrics "
-                                "measure the OS scheduler, not the framework",
-                      "metrics": {}}
-            print("perf gate skipped: " + report["reason"])
-        elif args.skip_noisy and cpus_differ:
-            report = {"status": "skipped",
-                      "reason": f"core-count mismatch (base "
-                                f"{base['cpus']} vs head {head['cpus']} "
-                                "cpus): the multi-process rows scale with "
-                                "the core count — this comparison gates "
-                                "the runner, not the code",
-                      "metrics": {}}
-            print("perf gate skipped: " + report["reason"])
-        else:
-            report = pg.compare(base["metrics"], head["metrics"],
-                                base_reps=base["reps"],
-                                cur_reps=head["reps"])
-            if cpus_differ:
-                report["host_mismatch"] = {"baseline_cpus": base["cpus"],
-                                           "current_cpus": head["cpus"]}
-            print(pg.render_report(report))
-            if cpus_differ:
-                print(f"warning: base measured on {base['cpus']} cpus, "
-                      f"head on {head['cpus']} — deltas on the "
-                      "multi-process rows track the runner, not the code "
-                      "(pass --skip-noisy to skip such comparisons)")
-        if args.output:
-            with open(args.output, "w") as f:
-                json.dump(report, f, indent=2)
-        if report["status"] == "fail":
-            sys.exit(1)
-        return
-
-    # history
-    entries = pg.load_history(args.history, limit=args.limit)
-    if not entries:
-        print(f"no perf history at {pg.history_path(args.history)}")
-        return
-    if args.metric:
-        for e in entries:
-            v = e["metrics"].get(args.metric)
-            if v is not None:
-                print(f"{e.get('iso', e.get('time')):<25} "
-                      f"{e.get('git', ''):<12} reps={e.get('reps', 1)} "
-                      f"{args.metric}={v}")
-        return
-    for e in entries:
-        print(f"{e.get('iso', e.get('time')):<25} {e.get('git', ''):<12} "
-              f"reps={e.get('reps', 1)} {len(e['metrics'])} metrics"
-              + (f"  [{e['note']}]" if e.get("note") else ""))
-
-
 def cmd_job(args):
     from ray_tpu.job_submission import JobSubmissionClient
 
@@ -914,49 +798,6 @@ def main(argv=None):
     p = sub.add_parser("grafana")
     p.add_argument("-o", "--output", default=None)
     p.set_defaults(fn=cmd_grafana)
-
-    p = sub.add_parser(
-        "perf", help="perf regression gate: microbench A/B vs the "
-                     "PERF_HISTORY.jsonl ledger with per-metric noise bands")
-    psub = p.add_subparsers(dest="perf_cmd", required=True)
-    c = psub.add_parser("check", help="measure now, gate vs the ledger head")
-    c.add_argument("--only", default=None,
-                   help="comma-separated metric-name substrings "
-                        "(microbench --only)")
-    c.add_argument("--quick", action="store_true",
-                   help="single-rep reduced-duration pass (wider noise band)")
-    c.add_argument("--history", default=None,
-                   help="ledger path (default: RTPU_perf_history_path, "
-                        "PERF_HISTORY.jsonl at the repo root)")
-    c.add_argument("--update", action="store_true",
-                   help="append this measurement to the ledger")
-    c.add_argument("--note", default="", help="ledger entry note")
-    c.add_argument("--strict", action="store_true",
-                   help="fail on regression even on a single-core box "
-                        "(default: advisory there — co-tenant load is "
-                        "indistinguishable from a code regression)")
-    c.add_argument("--json", dest="as_json", action="store_true",
-                   help="print the structured delta report instead of the "
-                        "table")
-    c.add_argument("-o", "--output", default=None,
-                   help="also write the delta report JSON to FILE")
-    c.set_defaults(fn=cmd_perf)
-    c = psub.add_parser(
-        "compare", help="gate two microbench --json result files (CI A/B)")
-    c.add_argument("base", help="baseline microbench --json output file")
-    c.add_argument("head", help="candidate microbench --json output file")
-    c.add_argument("--skip-noisy", action="store_true",
-                   help="exit 0 with a skipped report on a single-core "
-                        "runner (the A/B would measure the scheduler)")
-    c.add_argument("-o", "--output", default=None,
-                   help="write the delta report JSON to FILE (CI artifact)")
-    c.set_defaults(fn=cmd_perf)
-    c = psub.add_parser("history", help="print the perf ledger")
-    c.add_argument("--history", default=None, help="ledger path override")
-    c.add_argument("--metric", default=None,
-                   help="print one metric's trajectory")
-    c.add_argument("--limit", type=int, default=0)
-    c.set_defaults(fn=cmd_perf)
 
     p = sub.add_parser(
         "lint",

@@ -240,8 +240,8 @@ class StepRecorder:
         self._median_cache: Optional[float] = None  # refreshed every 8 steps
         self._steps_since_median = 0
         self._slow_step: Optional[Dict[str, float]] = None
-        # Compile-storm detection (perf regression plane): the jit-cache-miss
-        # bookkeeping above already *knows* every recompilation; this turns
+        # Compile-storm detection: the jit-cache-miss bookkeeping above
+        # already *knows* every recompilation; this turns
         # "many compiles long after warmup" — the unstable-shapes/dtypes
         # failure mode that silently halves throughput — into a flag the
         # watchdog promotes to a jit_cache_miss_storm GCS incident. Config

@@ -58,12 +58,7 @@ change (add new series instead). The stable set:
     ray_tpu_profile_captures_total               counter, automatic
                                                  cluster-profile captures
 
-  perf regression plane (_private/perf_gate.py + _private/watchdog.py)
-    ray_tpu_perf_regressions_total     counter, labels: metric — gate
-                                       comparisons landing beyond the
-                                       noise band (perf check/compare)
-    ray_tpu_perf_gate_ratio            gauge, labels: metric — latest
-                                       current/baseline ratio per metric
+  compile-storm detector (train/_telemetry.py + _private/watchdog.py)
     ray_tpu_perf_compile_storms_total  counter — jit_cache_miss_storm
                                        incidents raised by the watchdog
 

@@ -18,7 +18,10 @@ Contracts under test:
   - the device-trace window produces + registers a jax.profiler trace dir
     (forced on CPU);
   - timeline filters (job_id server-side, trace_id) and the trace_ctx
-    enabled bit (fresh/stale workers record spans immediately).
+    enabled bit (fresh/stale workers record spans immediately);
+  - incident auto-analysis extracts top stacks / compile share / scheduling
+    delay from an attached merged-profile capture and writes a
+    human-readable summary into the incident record.
 """
 
 import json
@@ -29,6 +32,7 @@ import time
 import pytest
 
 import ray_tpu
+from ray_tpu._private import perf_analysis as pa
 from ray_tpu._private import sampling_profiler as sp
 
 
@@ -436,3 +440,101 @@ def test_trace_ctx_enabled_bit(ray_start_regular):
         assert tracing.is_enabled()
     finally:
         tracing.disable()
+
+
+# ------------------------------------------------------ incident analysis
+
+
+class _StubGcs:
+    def __init__(self):
+        self.calls = []
+
+    def call(self, method, payload, timeout=None):
+        self.calls.append((method, payload))
+        return {"ok": True}
+
+    def get_all_node_info(self):
+        return []
+
+
+class _StubCore:
+    mode = "driver"
+    node_id = None
+    is_shutdown = False
+    worker_id = b"\x01" * 16
+    tasks_completed = 0
+    _pending_tasks = {}
+    session_dir = ""
+
+    def __init__(self):
+        self.gcs = _StubGcs()
+
+
+def _synthetic_trace():
+    node = {"pid": "node:aa", "tid": "cpu:worker:1:MainThread"}
+    return {"traceEvents": [
+        {"cat": "cpu_sample", "ph": "X", "ts": 0.0, "dur": 600_000.0,
+         "name": "compile",
+         "args": {"stack": "MainThread;train;jax;pxla;backend_compile",
+                  "samples": 60}, **node},
+        {"cat": "cpu_sample", "ph": "X", "ts": 0.0, "dur": 400_000.0,
+         "name": "read_batch",
+         "args": {"stack": "MainThread;input;read_batch", "samples": 40},
+         **node},
+        {"cat": "span", "ph": "X", "ts": 0.0, "dur": 500_000.0,
+         "name": "train_step.compile", **node},
+        {"cat": "span", "ph": "X", "ts": 500_000.0, "dur": 500_000.0,
+         "name": "train_step", **node},
+        {"cat": "task_flow", "ph": "s", "id": "t1", "ts": 0.0, **node},
+        {"cat": "task_flow", "ph": "f", "id": "t1", "ts": 250_000.0, **node},
+        {"cat": "task", "ph": "X", "ts": 250_000.0, "dur": 750_000.0,
+         "name": "f", **node},
+    ]}
+
+
+@pytest.mark.fast
+def test_analyze_trace_extracts_shares():
+    a = pa.analyze_trace(_synthetic_trace())
+    assert a["cpu_seconds"] == pytest.approx(1.0)
+    assert a["top_stacks"][0]["stack"].endswith("backend_compile")
+    assert a["top_stacks"][0]["share"] == pytest.approx(0.6)
+    assert a["compile_share"] == pytest.approx(0.6)
+    assert a["compile_span_share"] == pytest.approx(0.5)
+    assert a["sched_delay"]["count"] == 1
+    assert a["sched_delay"]["max_ms"] == pytest.approx(250.0)
+    assert a["sched_delay"]["share"] == pytest.approx(0.25)
+
+
+@pytest.mark.fast
+def test_attach_analysis_writes_summary_into_incident(tmp_path):
+    path = tmp_path / "capture.json"
+    path.write_text(json.dumps(_synthetic_trace()))
+    inc = {"kind": "jit_cache_miss_storm", "profile_path": str(path)}
+    assert pa.attach_analysis(inc)
+    summary = inc["analysis"]["summary"]
+    assert "compile" in summary and "scheduling delay" in summary
+    assert "recompilation" in summary  # storm-specific hint
+    assert inc["analysis"]["top_stacks"]
+    # no capture / unreadable capture leaves the incident untouched
+    assert not pa.attach_analysis({"kind": "slow_step"})
+    assert not pa.attach_analysis(
+        {"kind": "slow_step", "profile_path": str(tmp_path / "gone.json")})
+
+
+def test_watchdog_incident_carries_analysis(monkeypatch, tmp_path):
+    """The full wiring: the watchdog's publish path attaches the analysis
+    derived from the incident's capture before it reaches the GCS."""
+    monkeypatch.setenv("RTPU_profile_on_incident", "0")
+    from ray_tpu._private.watchdog import StallWatchdog
+
+    path = tmp_path / "capture.json"
+    path.write_text(json.dumps(_synthetic_trace()))
+    core = _StubCore()
+    wd = StallWatchdog(core)
+    incident = {"kind": "slow_step", "detail": "x", "status": "open",
+                "profile_path": str(path)}
+    wd._publish(incident, b"")
+    sent = [p["incident"] for m, p in core.gcs.calls
+            if m == "ReportIncident"][0]
+    assert "analysis" in sent
+    assert "compile" in sent["analysis"]["summary"]

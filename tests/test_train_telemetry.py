@@ -1,9 +1,10 @@
 """Step-level training telemetry (train/_telemetry.py): recorder math with
-a fake clock, the completion clock with handles whose readiness the test
-controls, the model configs' FLOP counts, metric export through
-util.metrics, HBM absent-on-CPU, TrainStep integration, session.report
-auto-attach, the program's spans in a real profiler trace, and SPAN events
-landing in the timeline dump.
+a fake clock, the post-warmup jit-compile storm and its promotion to a
+jit_cache_miss_storm incident by the watchdog, the completion clock with
+handles whose readiness the test controls, the model configs' FLOP counts,
+metric export through util.metrics, HBM absent-on-CPU, TrainStep
+integration, session.report auto-attach, the program's spans in a real
+profiler trace, and SPAN events landing in the timeline dump.
 
 CPU-only (JAX_PLATFORMS=cpu via conftest); everything here rides the fast
 marker — the cluster tests use the tiniest possible model/loops.
@@ -144,6 +145,86 @@ def test_flops_count_moe_by_active_parameters():
     assert one.matmul_params() == dense.matmul_params() + 128 * 4
     two = GPT2MoEConfig.tiny(moe=MoEConfig(num_experts=4, top_k=2), moe_every=2)
     assert two.matmul_params() == one.matmul_params() + 8 * 128 * 128
+
+
+# ------------------------------------------------- compile-storm detection
+
+
+@pytest.mark.fast
+def test_compile_storm_detection_after_warmup():
+    clk = FakeClock()
+    rec = _recorder(clk, emit_metrics=False)
+    # warmup: the first compile is expected and never counted
+    rec.record_step(1.0, compile_step=True)
+    for _ in range(6):
+        clk.advance(0.1)
+        rec.record_step(0.1)
+    assert rec.pop_compile_storm() is None
+    # three post-warmup recompiles inside the window (default K=3, 120s)
+    for _ in range(3):
+        clk.advance(1.0)
+        rec.record_step(0.5, compile_step=True)
+    storm = rec.pop_compile_storm()
+    assert storm is not None and storm["compiles"] >= 3
+    assert storm["step"] == rec.steps
+    assert rec.pop_compile_storm() is None  # cleared on read
+
+
+@pytest.mark.fast
+def test_compile_storm_respects_window():
+    clk = FakeClock()
+    rec = _recorder(clk, emit_metrics=False)
+    rec.record_step(1.0, compile_step=True)
+    for _ in range(6):
+        clk.advance(0.1)
+        rec.record_step(0.1)
+    # compiles spread far wider than the 120s window never accumulate
+    for _ in range(4):
+        clk.advance(200.0)
+        rec.record_step(0.5, compile_step=True)
+    assert rec.pop_compile_storm() is None
+
+
+def test_watchdog_promotes_storm_to_incident(monkeypatch):
+    # incident publishing must not depend on a live cluster capture
+    monkeypatch.setenv("RTPU_profile_on_incident", "0")
+    from ray_tpu._private.watchdog import StallWatchdog
+    from ray_tpu.train import _telemetry
+    from test_profiling_plane import _StubCore
+
+    clk = FakeClock()
+    rec = _recorder(clk, emit_metrics=False)
+    rec.record_step(1.0, compile_step=True)
+    for _ in range(6):
+        clk.advance(0.1)
+        rec.record_step(0.1)
+    for _ in range(3):
+        clk.advance(1.0)
+        rec.record_step(0.5, compile_step=True)
+    prev = _telemetry.current_recorder()
+    _telemetry.set_current_recorder(rec)
+    try:
+        core = _StubCore()
+        wd = StallWatchdog(core)
+        wd.check()
+        incidents = [p["incident"] for m, p in core.gcs.calls
+                     if m == "ReportIncident"]
+        storms = [i for i in incidents if i["kind"] == "jit_cache_miss_storm"]
+        assert storms, incidents
+        inc = storms[0]
+        assert inc["compile_storm"]["compiles"] >= 3
+        assert "retraced" in inc["detail"]
+        # rate-limited: an immediate second storm does not refire
+        rec.record_step(0.5, compile_step=True)
+        rec.record_step(0.5, compile_step=True)
+        rec.record_step(0.5, compile_step=True)
+        wd.check()
+        incidents2 = [p["incident"] for m, p in core.gcs.calls
+                      if m == "ReportIncident"
+                      and p["incident"]["kind"] == "jit_cache_miss_storm"]
+        assert len(incidents2) == 1
+    finally:
+        _telemetry.set_current_recorder(prev)
 
 
 class Handle:
@@ -513,7 +594,10 @@ def test_program_spans_in_a_device_trace_window(monkeypatch, tmp_path):
     inside("train_step.record", "train_step.dispatch")
     inside("train.report.slot_wait", "train.report")
     # the watcher waits on its own thread, and ends after the dispatch began
+    # (step 1's dispatch, the compile call, came before the window opened)
     for step, (start, end, line) in by_name["train_step.wait"].items():
+        if step == 1:
+            continue
         d_start, _, d_line = by_name["train_step.dispatch"][step]
         assert line != d_line and end > d_start
     # the counters at the same boundaries, through the summary that is there

@@ -4,8 +4,8 @@ copy-free chunked transfer.
 The acceptance contract is structural, not timing-based: the put path and
 the chunk send path must never materialize an out-of-band buffer as Python
 bytes — asserted here by buffer identity (np.shares_memory) and by the
-"_oob" landed-in-place markers of the RPC layer. Timing lives in
-microbench.py (and the abbreviated smoke at the bottom of this file).
+"_oob" landed-in-place markers of the RPC layer (the smoke at the bottom of
+this file is abbreviated timing, a floor and not a measurement).
 """
 
 import asyncio
