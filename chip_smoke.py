@@ -201,6 +201,34 @@ def _windowed_flash_plan():
             "attention_path": attention_path(t)}
 
 
+def _remat_plans():
+    """What each benchmark cell's step would save across its blocks' remat on
+    this chip (models/remat.py): the rule is a pure function of the cell's
+    shapes and the chip's bytes_limit, so one chip can say it for a cell on
+    four."""
+    import importlib
+
+    from bench import families
+    from ray_tpu.models import remat
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        cells = json.load(f)["workloads"]
+    limit = remat.chip_limit(None)
+    plans = {}
+    for cell in cells:
+        with open(os.path.join(root, "bench", "configs", cell["config"] + ".json")) as f:
+            sizes = json.load(f)
+        with open(os.path.join(root, "bench", "traffic", cell["traffic"] + ".json")) as f:
+            mix = json.load(f)
+        cfg = families.load(sizes["family"]).build(sizes, sizes["compute_dtype"])
+        shape = remat.step_shape((mix["batch"], mix["seq_len"]), sizes["mesh"])
+        family = importlib.import_module(type(cfg).__module__)  # REMAT_RUNGS' own file
+        plans[cell["name"]] = {"shape": shape._asdict(),
+                               **family.remat_plan(cfg, shape, limit)._asdict()}
+    return plans
+
+
 def one_chip_loop(config):
     import jax
 
@@ -237,6 +265,7 @@ def one_chip_loop(config):
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
         for shape in config["attn_shapes"]]
     report["windowed_flash"] = _windowed_flash_plan()
+    report["remat_plans"] = _remat_plans()
     report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])
     train.report(report)
 

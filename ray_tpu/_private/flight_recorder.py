@@ -65,7 +65,12 @@ bytes, hex-encoded at dump time) and a short detail string/number.
                                  (step number, optimizer steps in it) —
                                  "did step N ever start" for a hung mesh
   train.compile                  a step call that missed the jit cache:
-                                 (optimizer steps so far, seconds)
+                                 (optimizer steps so far, seconds), or
+                                 where the model's blocks are under remat
+                                 (seconds, names saved across it, their
+                                 bytes a layer, in all, the step's
+                                 reckoned bytes, the limit held to):
+                                 models/remat.py:RematPlan
   serve.request                  one replica-side serve request finished
   llm.admit / llm.preempt / llm.finish   serve/llm engine sequence
                                  lifecycle (admit carries the prompt

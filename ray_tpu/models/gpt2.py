@@ -29,8 +29,10 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models import remat
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
@@ -114,7 +116,7 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic=True):
         cfg = self.config
-        h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="c_fc")(x)
+        h = checkpoint_name(nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="c_fc")(x), "mlp_up")
         h = nn.gelu(h, approximate=True)
         return nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj")(h)
 
@@ -136,6 +138,26 @@ class Block(nn.Module):
         return pin(x, self.stream)
 
 
+# What a block's remat saves after the flash kernel's output and logsumexp
+# (models/remat.py), and the ms of a step each spared for a GiB held at
+# GPT-2 small's widths on a v5e (PERF.md section 6, PR 33): the kernel's
+# operands spare the c_attn matmul's second run and the (B,T,H,D)->(B,H,T,D)
+# copies that are made again with it; the c_fc output spares that matmul's.
+REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 14.0), (("mlp_up",), 6.5))
+
+
+def remat_plan(cfg: GPT2Config, shape: remat.StepShape, limit) -> remat.RematPlan:
+    """What the blocks of a step of this shape save across remat, under a
+    chip's `limit` of bytes: a pure function of its arguments."""
+    d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, d // cfg.n_head, itemsize)
+    name_bytes["mlp_up"] = shape.rows * shape.seq_len * 4 * d * itemsize // shape.tp
+    held = remat.held_bytes(
+        shape, params=cfg.matmul_params() + cfg.block_size * d, width=d,
+        vocab=cfg.vocab_size, n_layer=cfg.n_layer, itemsize=itemsize)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+
+
 class GPT2(nn.Module):
     config: GPT2Config
     stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
@@ -148,10 +170,14 @@ class GPT2(nn.Module):
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="wte")
         wpe = nn.Embed(cfg.block_size, cfg.n_embd, dtype=cfg.dtype, name="wpe")
         x = wte(idx) + wpe(pos)
+        # remat each block (jax.checkpoint): the backward pass gets the
+        # block's input and computes its activations again, but for the
+        # residuals the plan keeps by name: the flash kernel's output and
+        # logsumexp always, so the kernel runs once a layer, then
+        # what of REMAT_RUNGS the chip's memory allows.
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         for i in range(cfg.n_layer):
-            # remat each block: recompute activations in the backward pass to
-            # trade FLOPs for HBM (jax.checkpoint).
-            x = nn.remat(Block)(cfg, self.stream, name=f"h_{i}")(x, deterministic)
+            x = nn.remat(Block, policy=keep)(cfg, self.stream, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         # weight-tied head
         logits = wte.attend(x.astype(jnp.float32))

@@ -24,7 +24,11 @@ TPU design notes:
   model is given (`stream`: parallel/mesh.py:stream_sharding, the batch's own
   split), so under 'fsdp' XLA gathers a block's weights and not its
   activations; with none (one device) nothing is emitted;
-- each block is wrapped in nn.remat (jax.checkpoint) to trade FLOPs for HBM.
+- each block is wrapped in nn.remat (jax.checkpoint): the backward pass gets
+  the block's input and computes its activations again, but for the
+  residuals a plan keeps by name (models/remat.py): the flash kernel's output
+  and logsumexp always, so the kernel runs once a layer, then what of
+  REMAT_RUNGS the chip's memory allows.
 """
 
 from __future__ import annotations
@@ -36,8 +40,10 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models import remat
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
@@ -202,9 +208,9 @@ class LlamaMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=cfg.dtype, name=name)
-        return dense(cfg.n_embd, "down")(
-            nn.silu(dense(cfg.mlp_dim, "gate")(x)) * dense(cfg.mlp_dim, "up")(x)
-        )
+        gate, up = (checkpoint_name(dense(cfg.mlp_dim, name)(x), "mlp_up")
+                    for name in ("gate", "up"))
+        return dense(cfg.n_embd, "down")(nn.silu(gate) * up)
 
 
 class LlamaBlock(nn.Module):
@@ -222,6 +228,27 @@ class LlamaBlock(nn.Module):
         return pin(x, self.stream)
 
 
+# What a block's remat saves after the flash kernel's output and logsumexp
+# (models/remat.py), and the ms of a step each spared for a GiB held at
+# Mistral-7B's widths on a v5e (PERF.md section 6, PR 33): the outputs of
+# `gate` and `up` spare those matmuls' second run (and under fsdp their
+# kernels' second gather), the kernel's operands the q, k, v projections',
+# the rotary embedding and the repeat of the key-value heads.
+REMAT_RUNGS = ((("mlp_up",), 30.7), (("attn_q", "attn_k", "attn_v"), 38.9))
+
+
+def remat_plan(cfg: LlamaConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
+    """What the blocks of a step of this shape save across remat, under a
+    chip's `limit` of bytes: a pure function of its arguments."""
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, cfg.head_dim, itemsize)
+    name_bytes["mlp_up"] = 2 * shape.rows * shape.seq_len * cfg.mlp_dim * itemsize // shape.tp
+    held = remat.held_bytes(
+        shape, params=cfg.matmul_params() + cfg.vocab_size * cfg.n_embd, width=cfg.n_embd,
+        vocab=cfg.vocab_size, n_layer=cfg.n_layer, itemsize=itemsize)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+
+
 class Llama(nn.Module):
     config: LlamaConfig
     stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
@@ -230,8 +257,9 @@ class Llama(nn.Module):
     def __call__(self, idx, pos_offset=0):
         cfg = self.config
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb")(idx)
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         for i in range(cfg.n_layer):
-            x = nn.remat(LlamaBlock)(cfg, self.stream, name=f"h_{i}")(x, pos_offset)
+            x = nn.remat(LlamaBlock, policy=keep)(cfg, self.stream, name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           name="lm_head")(x.astype(jnp.float32))

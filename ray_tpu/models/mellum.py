@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models import remat
 from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, loss_fn  # noqa: F401
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -156,6 +157,31 @@ class MellumBlock(nn.Module):
         return pin(x + moe(RMSNorm(cfg.rms_eps, name="moe_norm")(x)), self.stream)
 
 
+# What a block's remat saves after the flash kernel's output and logsumexp
+# (models/remat.py): the kernel's operands, and the ms of a step they spared
+# for a GiB held in the benchmark's cell on a v5e (PERF.md section 6, PR 33).
+# The expert layer's own residuals have no names yet.
+REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 37.6),)
+
+
+def remat_plan(cfg: MellumConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
+    """What the blocks of a step of this shape save across remat, under a
+    chip's `limit` of bytes: a pure function of its arguments."""
+    d, hd = cfg.n_embd, cfg.head_dim
+    layer = (2 * d * cfg.n_head * hd + 2 * d * cfg.n_kv_head * hd + d * cfg.num_experts
+             + cfg.experts_held * 3 * d * cfg.expert_dim)
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    # the expert layer's backward works in buffers of a row an assignment,
+    # every token's top_k of them: 7.5 such buffers in the step compiled for
+    # a v5e at the benchmark's cell and at twice its rows (4.2 and 8.2 GiB)
+    routed = int(7.5 * shape.rows * shape.seq_len * cfg.top_k * d * itemsize)
+    held = remat.held_bytes(
+        shape, params=cfg.n_layer * layer + 2 * cfg.vocab_size * d, width=d,
+        vocab=cfg.vocab_size, n_layer=cfg.n_layer, itemsize=itemsize, block=routed)
+    return remat.plan(REMAT_RUNGS, remat.attention_bytes(shape, cfg.n_head, hd, itemsize),
+                      cfg.n_layer, held, limit)
+
+
 class Mellum(nn.Module):
     config: MellumConfig
     stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
@@ -168,8 +194,10 @@ class Mellum(nn.Module):
         # tokens route alike and the experts' load swings with the data.
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0))(idx)
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
         for i, kind in enumerate(cfg.layer_types):
-            x = nn.remat(MellumBlock)(cfg, kind, self.stream, name=f"h_{i}")(x, pos_offset)
+            x = nn.remat(MellumBlock, policy=keep)(
+                cfg, kind, self.stream, name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(x.astype(jnp.float32))

@@ -1,0 +1,197 @@
+"""What a block under `nn.remat` keeps for its backward pass.
+
+Every block of every family is rematerialised: the backward pass gets the
+block's input and computes the rest again. Some of the rest is dear to
+compute again and cheap to hold, and a checkpoint policy saves it by name
+(`jax.ad_checkpoint.checkpoint_name` where it is made: ops/attention.py's
+`_flash_fwd_rule`, the families' MLPs). A name no policy asks for is an
+identity.
+
+First rung, always: `attn_out` and `attn_lse`, what only the flash kernel can
+give. They cost one more copy of the stream a layer and spare the kernel's
+second run. Further rungs by a rule: a family states, beside its blocks, its
+other names and what each is worth (`REMAT_RUNGS`: rungs of names that are
+only worth saving together, each with the milliseconds of a step it spared
+for a GiB held), and `plan` takes the rungs that spare most among those
+whose reckoned total stays under the chips' `bytes_limit` less a margin. It
+is a reckoning from shapes, as ops/attention.py's `flash_tiles` is, made at
+trace time in the model's `__call__`, where the batch's shape is static
+(`block_policy`); `traced` hands the plan to whoever books it (TrainStep at
+a compile). No option selects it and none turns it off.
+
+The constants are calibrated against what a v5e's allocator read of the
+benchmark's four cells' steps with each set of names saved (PERF.md section
+6, PR 33; tests/test_remat.py holds the thirteen readings and the error
+against them), and at shapes no chip ran against the step compiled for a
+described v5e (tests/test_tpu_compile.py).
+"""
+
+from __future__ import annotations
+
+import itertools
+from typing import Dict, NamedTuple, Optional, Tuple
+
+import jax
+
+GIB = 1 << 30
+
+FIRST_RUNG = ("attn_out", "attn_lse")
+
+# Bytes a parameter: the float32 weight and two AdamW moments, and the
+# float32 gradient beside them while the optimizer runs.
+_STATE_BYTES = (12, 4)
+# Of the chips' limit, what the reckoned total may reach. The rest is for what
+# the reckoning leaves out: the compiler's temporaries beside the largest
+# one, fragmentation, and whatever else the process keeps on the chip. The
+# reckoning has read up to 0.35 GiB under and 0.85 over what a v5e's
+# allocator read (tests/test_remat.py), and 15 GiB less a tenth is 13.5.
+_LIMIT_SHARE = 0.9
+
+
+class StepShape(NamedTuple):
+    """One chip's part of a step: `rows` sequences of `seq_len` tokens after
+    the batch's split, a parameter's state split over `state_split` chips
+    (fsdp x tp), a block's heads and MLP columns over `tp`."""
+
+    rows: int
+    seq_len: int
+    state_split: int = 1
+    tp: int = 1
+
+
+class RematPlan(NamedTuple):
+    """`names` saved across remat; the bytes they hold a layer and over all
+    layers on one chip; the step's reckoned total with them; and the limit
+    that total was held to (None: no chip said one, first rung alone)."""
+
+    names: Tuple[str, ...]
+    layer_bytes: int
+    saved_bytes: int
+    reckoned_bytes: int
+    limit_bytes: Optional[int]
+
+
+def step_shape(batch_shape, axis_sizes) -> StepShape:
+    """The StepShape of a (B, T) batch on a mesh of these axis sizes (none:
+    one device), split as parallel/mesh.py:batch_sharding splits it."""
+    rows, seq_len = batch_shape
+    size = lambda axis: axis_sizes.get(axis, 1)
+    return StepShape(max(1, rows // (size("dp") * size("fsdp"))), max(1, seq_len // size("sp")),
+                     size("fsdp") * size("tp"), size("tp"))
+
+
+def chip_limit(stream) -> Optional[int]:
+    """The bytes one chip's allocator may hand out, as every process of the
+    job reckons it: the least `bytes_limit` (`device.memory_stats()`) of the
+    devices this process can ask, among those the residual stream's sharding
+    lies on (parallel/mesh.py:stream_sharding; None: one device, the
+    process's first), rounded down to a whole GiB. Chips of one kind read
+    a few KiB apart from run to run (16,909,336,064 and 16,909,334,528 on
+    the v5e), and every host of a mesh has to trace the same program: the
+    rounding is what makes them agree. None where a device keeps no such
+    count (a CPU device) or none of the mesh's devices is this process's
+    (a chip that is described and not attached: tests/test_tpu_compile.py).
+    A chip that cannot say raises: a weaker plan is not taken in silence."""
+    local = set(jax.local_devices())
+    asked = jax.local_devices()[:1] if stream is None else [
+        d for d in stream.mesh.devices.flat if d in local]
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in asked]
+    if not limits or None in limits:
+        return None
+    return min(limits) // GIB * GIB
+
+
+class Held(NamedTuple):
+    """What one chip holds whatever is saved, in bytes: `always`, through the
+    whole step (its shard's state and gradients, each layer's input: one
+    copy of the stream a layer); `grads`, the gradients' part of that, which
+    fill up through the backward pass as the saved residuals are let go;
+    `logits`, the float32 logits, beside which the saved residuals pile up
+    while the forward runs; `head`, the logits and their gradient in the
+    compute dtype, the head's own fullest moment; `block`, what one block's
+    backward works in beside its weights, where a family says it is large
+    (an expert layer's buffers over every assignment)."""
+
+    always: int
+    grads: int
+    logits: int
+    head: int
+    block: int
+
+    def total(self, saved: int) -> int:
+        """The step's reckoned bytes with `saved` bytes of residuals over all
+        layers: `always` and the fullest of three moments. The head's own;
+        the logits beside everything saved (on the chip the saved residuals
+        did not add to the head's moment: gpt2_small read 10.79 GiB with
+        0.6, 2.3 and 2.8 GiB of them and 12.29 with 4.5); a block's
+        backward, with whatever is saved beyond the gradients still to
+        come."""
+        return self.always + max(self.head, self.logits + saved,
+                                 self.block + max(0, saved - self.grads))
+
+
+def held_bytes(shape: StepShape, *, params: int, width: int, vocab: int,
+               n_layer: int, itemsize: int, block: int = 0) -> Held:
+    """Held of a model of `params` parameters, `n_layer` layers on a stream
+    `width` wide in a dtype of `itemsize` bytes, and a head `vocab` wide."""
+    tokens = shape.rows * shape.seq_len
+    state, grads = (n * params // shape.state_split for n in _STATE_BYTES)
+    return Held(state + grads + n_layer * tokens * width * itemsize, grads,
+                4 * tokens * vocab, (4 + itemsize) * tokens * vocab, block)
+
+
+def attention_bytes(shape: StepShape, n_head: int, head_dim: int, itemsize: int) -> Dict[str, int]:
+    """Bytes a layer, on one chip, of the flash call's named residuals
+    (ops/attention.py): the output and the three operands are (rows, heads,
+    T, head_dim) each, key-value heads already repeated; the logsumexp is a
+    float32 a head and token."""
+    tokens = shape.rows * shape.seq_len
+    operand = tokens * n_head * head_dim * itemsize // shape.tp
+    return {"attn_out": operand, "attn_q": operand, "attn_k": operand, "attn_v": operand,
+            "attn_lse": tokens * n_head * 4 // shape.tp}
+
+
+def plan(rungs, name_bytes: Dict[str, int], n_layer: int, held: Held,
+         limit: Optional[int]) -> RematPlan:
+    """The first rung, and of `rungs` (each `(names, ms a step spared for a
+    GiB held)`) the set that spares most among those whose reckoned total
+    (`Held.total`) stays under `_LIMIT_SHARE` of `limit`; with no limit the
+    first rung alone. The first rung is taken whatever the limit: it is one
+    more copy of the stream a layer, whatever the shape."""
+    room = None if limit is None else int(limit * _LIMIT_SHARE)
+    first = sum(name_bytes[n] for n in FIRST_RUNG)
+    rung_bytes = [sum(name_bytes[n] for n in names) for names, _ in rungs]
+
+    def layer_bytes(chosen):
+        return first + sum(rung_bytes[i] for i in chosen)
+
+    fitting = [()] + [
+        chosen for k in range(1, len(rungs) + 1)
+        for chosen in itertools.combinations(range(len(rungs)), k)
+        if room is not None and held.total(n_layer * layer_bytes(chosen)) <= room]
+    taken = max(fitting, key=lambda chosen: sum(rungs[i][1] * rung_bytes[i] for i in chosen))
+    names = FIRST_RUNG + tuple(n for i in taken for n in rungs[i][0])
+    layer = layer_bytes(taken)
+    return RematPlan(names, layer, n_layer * layer, held.total(n_layer * layer), room)
+
+
+_traced = None  # (the configuration, its RematPlan) of the newest trace
+
+
+def block_policy(family_plan, cfg, batch_shape, stream):
+    """The checkpoint policy of `nn.remat` round the blocks of a model of
+    `cfg` on a (B, T) batch: it saves the names of the family's plan
+    (`family_plan(cfg, StepShape, limit)`) for the batch's part on one chip
+    of the stream's mesh, under those chips' limit. Called where the model
+    is traced; `traced` hands the plan on."""
+    global _traced
+    sizes = {} if stream is None else stream.mesh.shape
+    chosen = family_plan(cfg, step_shape(batch_shape, sizes), chip_limit(stream))
+    _traced = (cfg, chosen)
+    return jax.checkpoint_policies.save_only_these_names(*chosen.names)
+
+
+def traced(cfg) -> Optional[RematPlan]:
+    """The plan of the newest trace if it was of a model of `cfg`, else None:
+    what the program just compiled saves, for whoever books it."""
+    return _traced[1] if _traced is not None and _traced[0] == cfg else None

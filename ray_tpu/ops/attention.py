@@ -44,6 +44,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -476,7 +477,14 @@ def _flash(q, k, v, tiles, interpret):
 
 
 def _flash_fwd_rule(q, k, v, tiles, interpret):
+    # The backward's residuals by name, for a checkpoint policy to save
+    # across a block's remat (models/remat.py): what only the kernel can
+    # give, and its operands in its own (bh, t, d) layout. A name that no
+    # policy asks for is an identity.
+    q, k, v = (checkpoint_name(x, name) for x, name in
+               ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
     o, lse = _flash_fwd(q, k, v, tiles=tiles, interpret=interpret)
+    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
     return o, (q, k, v, o, lse)
 
 

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax.profiler import TraceAnnotation
 
 from ray_tpu._private import flight_recorder
+from ray_tpu.models import remat
 from ray_tpu.models.gpt2 import (
     GPT2,
     GPT2Config,
@@ -337,6 +338,9 @@ class TrainStep:
                     # call: without the sync the backlog would drain into
                     # the next step's interval.
                     jax.block_until_ready(out)
+                    if rec is not None:
+                        # what the program just traced saves across remat
+                        rec.remat_plan = remat.traced(self.model.config)
             if rec is not None:
                 with TraceAnnotation("ray_tpu.train_step.record", step=step):
                     tokens, examples, seq_len = _batch_counts(batches)

@@ -206,6 +206,9 @@ class StepRecorder:
         # What the newest completed step said of its expert layers (the
         # _STEP_GAUGES among its metrics), read when the watcher saw it done.
         self.step_gauges: Dict[str, float] = {}
+        # What the newest compiled step program saves across its blocks'
+        # remat (models/remat.py:RematPlan), set by TrainStep at a compile.
+        self.remat_plan = None
         # The completion clock: step programs in flight, oldest first, and
         # when the newest finished one was seen complete. The watcher thread
         # waits on them in order and books each at its completion, which a
@@ -401,8 +404,11 @@ class StepRecorder:
             self.steps += steps
             self._last_step_at = self._clock()
             # numbers, not text: nothing is formatted before a dump
+            detail = duration_s
+            if compile_step and self.remat_plan is not None:
+                detail = (duration_s, *self.remat_plan)
             _fr.record("train.compile" if compile_step else "train.step",
-                       self.steps, duration_s)
+                       self.steps, detail)
             if compile_step:
                 self.compile_s += duration_s
                 self.compiles += 1
@@ -572,6 +578,8 @@ class StepRecorder:
         hbm = self.hbm_bytes_in_use()
         if hbm:
             out["hbm_bytes_in_use"] = max(hbm.values())
+        if self.remat_plan is not None:
+            out["remat_saved_bytes"] = self.remat_plan.saved_bytes
         return out
 
     # ------------------------------------------------------------ emission

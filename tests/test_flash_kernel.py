@@ -1,9 +1,13 @@
 """The pallas flash kernels (ops/attention.py) in interpret mode on the CPU:
 output, dq, dk and dv against a plain float32 reference at `highest`
 precision, over tile shapes the chip's cells use and the ones that break a
-careless tile loop; and the tile rule itself over every shape the cells, the
-compile tests and these cases send it.
+careless tile loop; the tile rule itself over every shape the cells, the
+compile tests and these cases send it; and the names on the backward's
+residuals, by which a checkpoint policy keeps the forward kernel from running
+twice under remat.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +113,87 @@ def test_tile_rule_is_legal_for(shape, dtype):
             <= attention._VMEM_BUDGET)
     # pure: the same call, the same tiles
     assert flash_tiles(bh, t, d, dtype) == tiles
+
+
+# --------------------------------------------------------------------------
+# the backward's residuals by name: a checkpoint policy saves them across remat
+# --------------------------------------------------------------------------
+
+KEEP = jax.checkpoint_policies.save_only_these_names("attn_out", "attn_lse")
+
+
+def _mapped(attn):
+    """attn under a shard_map over the batch on four of conftest.py's virtual
+    devices, as parallel/train_step.py:attn_for_mesh wraps the kernel."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    spec = P("dp", None, None, None)
+    return jax.shard_map(attn, mesh=Mesh(np.array(jax.devices()[:4]), ("dp",)),
+                         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
+
+
+def _flash(**kw):
+    return functools.partial(attention.flash_causal_attention, interpret=True, **kw)
+
+
+# attention callable on (B, H, T, D), and the name its forward call carries
+REMAT_CASES = {
+    "causal": (_flash(), "flash_fwd"),
+    "window": (_flash(window=128), "flash_win128_fwd"),
+    "shard_map": (_mapped(_flash()), "flash_fwd"),
+    "shard_map_window": (_mapped(_flash(window=128)), "flash_win128_fwd"),
+}
+
+
+def _two_blocks(attn, remat):
+    """Loss of two blocks x + attention(x @ w), each under `remat`."""
+    def block(x, w):
+        b, t, c = x.shape
+        h = (x @ w).reshape(b, t, 2, c // 2).transpose(0, 2, 1, 3)
+        return x + attn(h, h, h).transpose(0, 2, 1, 3).reshape(b, t, c)
+
+    def loss(x, ws):
+        for w in ws:
+            x = remat(block)(x, w)
+        return (x.astype(F32) ** 2).mean()
+
+    return jax.grad(loss, argnums=(0, 1))
+
+
+def _pallas_calls(jaxpr):
+    """Names of the pallas calls of a jaxpr, those of its sub-jaxprs included
+    (the printed text shows a jaxpr that two equations share once)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_calls(sub)
+    return names
+
+
+@pytest.mark.parametrize("case", REMAT_CASES)
+def test_policy_saves_the_kernel_s_output_and_logsumexp_across_remat(case):
+    """Two rematted blocks hold two forward calls a block; under a policy
+    that saves `attn_out` and `attn_lse` one, and one of each backward
+    kernel as before; the gradients are the same in every bit. A name
+    without a policy, and with no remat at all, changes no call."""
+    attn, fwd = REMAT_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(keys[0], (4, 256, 128), BF16)
+    ws = [jax.random.normal(k, (128, 128), BF16) / 11 for k in keys[1:]]
+
+    def calls(remat):
+        names = _pallas_calls(jax.make_jaxpr(_two_blocks(attn, remat))(x, ws).jaxpr)
+        return {kind: sum(n.endswith(kind) for n in names) for kind in ("fwd", "bwd_dq", "bwd_dkv")}, names
+
+    unnamed = functools.partial(jax.checkpoint, policy=None)
+    saved = functools.partial(jax.checkpoint, policy=KEEP)
+    for remat, want_fwd in ((lambda f: f, 2), (unnamed, 4), (saved, 2)):
+        got, names = calls(remat)
+        assert got == {"fwd": want_fwd, "bwd_dq": 2, "bwd_dkv": 2}
+        assert fwd in names and all(n.startswith(fwd[:-3]) for n in names)
+    for a, b in zip(jax.tree.leaves(jax.jit(_two_blocks(attn, saved))(x, ws)),
+                    jax.tree.leaves(jax.jit(_two_blocks(attn, unnamed))(x, ws))):
+        assert np.isfinite(np.asarray(a.astype(F32))).all()
+        np.testing.assert_array_equal(np.asarray(a.astype(F32)), np.asarray(b.astype(F32)))

@@ -117,3 +117,41 @@ def test_state_is_sharded():
     assert len(kernel.sharding.device_set) == 8
     mu = state["opt_state"][1][0].mu["h_0"]["attn"]["wq"]["kernel"]
     assert mu.sharding == kernel.sharding
+
+
+@pytest.mark.parametrize("saved", ["first_rung", "every_name"])
+def test_residuals_saved_across_remat_change_no_loss_and_no_gradient(saved, monkeypatch):
+    """A step whose blocks save named residuals across their remat
+    (models/remat.py) and the same step with `policy=None`, the kernel in
+    interpret mode in both, in float32: the loss and every gradient are the
+    same in every bit, since a saved value and a recomputed one are the same
+    value. (In bf16 XLA rounds a value where its fusions end, and two
+    programs that fuse differently differ in the last bits.) `every_name`:
+    a chip with room for all of REMAT_RUNGS."""
+    from ray_tpu.models import llama, remat
+    from ray_tpu.ops.attention import flash_causal_attention
+
+    def attn(q, k, v):  # (B, T, H, D), as LlamaConfig.attn_fn takes them
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        return flash_causal_attention(q, k, v, interpret=True).transpose(0, 2, 1, 3)
+
+    cfg = LlamaConfig.tiny(attn_fn=attn, block_size=256, dtype=jnp.float32)
+    batch = _batch(np.random.default_rng(3), B=2, T=256)
+    if saved == "every_name":
+        monkeypatch.setattr(remat, "chip_limit", lambda stream: 16 * remat.GIB)
+    model = llama.Llama(cfg)
+    want_names = remat.FIRST_RUNG + (
+        tuple(n for names, _ in llama.REMAT_RUNGS for n in names) if saved == "every_name" else ())
+    params = model.init(jax.random.PRNGKey(0), batch["idx"])["params"]
+
+    def loss_and_grads():
+        return jax.jit(jax.value_and_grad(lambda p: llama.loss_fn(
+            model.apply({"params": p}, batch["idx"]), batch["targets"])))(params)
+
+    loss, grads = loss_and_grads()
+    assert remat.traced(cfg).names == want_names
+    monkeypatch.setattr(remat, "block_policy", lambda *a: None)
+    plain_loss, plain_grads = loss_and_grads()
+    assert np.isfinite(float(loss)) and float(loss) == float(plain_loss)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(plain_grads)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

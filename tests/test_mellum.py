@@ -7,6 +7,7 @@ dropped); the YaRN table against numbers worked by hand; and what the layer
 sows, which leaves the step program as it was.
 """
 
+import collections
 import hashlib
 import json
 import math
@@ -20,7 +21,7 @@ import numpy as np
 import pytest
 
 from bench import families
-from ray_tpu.models import mellum
+from ray_tpu.models import mellum, remat
 from ray_tpu.models.mellum import Mellum, MellumConfig, YarnScaling, loss_fn, yarn_inv_freq
 from ray_tpu.ops import attention
 from ray_tpu.ops.moe import ExpertShare
@@ -207,42 +208,62 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
     assert texts[0] == texts[1] and "stablehlo" in texts[0]
 
 
-# sha256 of the step as `_step_text` gives it, taken by the same code on
-# the commit before the model came (7bba7b8) and, the routed one, on the
-# commit before the residual stream was pinned (7494822).
-PARENT_STEPS = {
-    "gpt2_small": ("bdb9fd2845587a1e8e0dbc5176b4fb041d0c6e8bcb38aa1c1bdaa40956936163", 2, 1024),
-    "mistral_7b_l8": ("5f0d1dbbcc635c1c980087408fee591b15bb602225ff179e99367a00c84966f9", 1, 8192),
-    "mellum2_12b_l4_ep4": ("6a75532b01b607403869a598e66f62a4e682a2de7cf5f12f5d0aca3ccd9426ff", 2, 8192),
+# Each cell's configuration at its cell's shape (rows a chip, sequence
+# length) on its cell's mesh, with the rule of models/remat.py given a v5e's
+# limit, so that the program is the cell's own: sha256 of the step as
+# `_step_text` gives it, taken on the commit that put a checkpoint policy on
+# the blocks' remat (PR 33: the programs changed by design there, and were
+# pinned again for the next PR that means to leave them alone); the layers
+# whose attention is windowed, of all; the names the rule saves there after
+# the first rung.
+PINNED_STEPS = {
+    "gpt2_small": ("750c97196be051696bd3a36ebe6146841b19beb0abe996f414a90cb87be3786c", 32, 1024, 0, 12,
+                   ("attn_q", "attn_k", "attn_v", "mlp_up")),
+    "mistral_7b_l8": ("75c207e0b05ddb608f919f015f6d838a2d64df43303cb971f7259b60ed81a0e0", 1, 8192, 0, 8,
+                      ("mlp_up",)),
+    "mellum2_12b_l4_ep4": ("49a164abb1a6951832e858ce3d705fc33cd103de8cabf904faf1c0b6b653c13e", 2, 8192, 3, 4,
+                           ("attn_q", "attn_k", "attn_v")),
 }
 
 
 def _step_text(ts, state, batch):
     """The step lowered for a TPU, with each Mosaic kernel's serialized body
     (it holds source lines) taken out, and the step's jaxpr, which holds the
-    kernels' bodies as equations."""
+    kernels' bodies as equations (and the checkpoint policy as a function's
+    repr: its address is taken out)."""
     traced = ts._step.trace(state, batch)
     lowered = traced.lower(lowering_platforms=("tpu",)).as_text()
-    return re.sub(r'\\22body\\22: \\22[^\\]*\\22', "body", lowered) + str(traced.jaxpr)
+    return (re.sub(r'\\22body\\22: \\22[^\\]*\\22', "body", lowered)
+            + re.sub(r" at 0x[0-9a-f]+", "", str(traced.jaxpr)))
 
 
-@pytest.mark.parametrize("name", sorted(PARENT_STEPS))
+@pytest.mark.parametrize("name", sorted(PINNED_STEPS))
 def test_old_configurations_lower_to_the_parent_s_step(name, monkeypatch):
-    """The window in the flash kernels, the new arguments of LlamaAttention
-    and the new case in TrainStep change nothing of the step programs the
-    benchmark already measures, flash kernels included; nor does the
-    residual stream's pin (parallel/mesh.py), which a one-device mesh leaves
-    out: the one-chip cells run the parent's program."""
-    want, rows, seq_len = PARENT_STEPS[name]
+    """The step of each cell's configuration, lowered for a TPU on this box,
+    runs the forward flash kernel once a layer: the blocks' remat saves its
+    output and logsumexp (models/remat.py), where the step before PR 33 ran
+    it twice; one of each backward kernel a layer, as before. A windowed
+    layer's calls carry the window in their name. And the program is the
+    pinned one: a change that means to leave the cells' programs alone is
+    held to it."""
+    want, rows, seq_len, windowed, layers, saved = PINNED_STEPS[name]
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
         sizes = json.load(f)
     cfg = families.load(sizes["family"]).build(sizes, "bfloat16")
-    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
+    # the cell's own program: its mesh, and a v5e's limit for the rule
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * remat.GIB)
+    chips = math.prod(sizes["mesh"].values())
+    ts = TrainStep(cfg, make_mesh(sizes["mesh"], devices=jax.devices()[:chips]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
-    tok = jax.ShapeDtypeStruct((rows, seq_len), jnp.int32)
+    tok = jax.ShapeDtypeStruct((rows * chips, seq_len), jnp.int32)
     text = _step_text(ts, state, {"idx": tok, "targets": tok})
-    assert "flash_fwd" in text and ("flash_win" in text) == name.startswith("mellum")
+    assert remat.traced(ts.model.config).names == remat.FIRST_RUNG + saved
+    calls = collections.Counter(re.findall(r'kernel_name = "(flash_\w+)"', text))
+    win = f"flash_win{sizes.get('sliding_window')}_"
+    kernels = {"fwd": layers - windowed, "bwd_dq": layers - windowed, "bwd_dkv": layers - windowed}
+    assert calls == {**{f"flash_{k}": n for k, n in kernels.items()},
+                     **{win + k: windowed for k in kernels if windowed}}
     assert hashlib.sha256(text.encode()).hexdigest() == want
 
 

@@ -160,11 +160,11 @@ def test_gpt2_124m_step_fits_one_chip(topo, monkeypatch):
     ts = TrainStep(GPT2Config.gpt2_124m(), mesh, telemetry=False)
     c = ts._step.lower(*_step_args(ts)).compile()
     assert _live_bytes(c) < 16 * GIB, c.memory_analysis()
-    # one forward and two backward kernels in each of 12 remat'd layers,
-    # plus the forward recomputed
-    assert c.as_text().count("tpu_custom_call") == 48
-    assert _kernel_calls(c.as_text()) == {
-        "flash_fwd": 24, "flash_bwd_dq": 12, "flash_bwd_dkv": 12}
+    # one forward and two backward kernels in each of 12 remat'd layers:
+    # the forward's output and logsumexp are saved across the remat
+    # (models/remat.py), so it is not run again
+    assert c.as_text().count("tpu_custom_call") == 36
+    assert _kernel_calls(c.as_text()) == dict.fromkeys(KERNELS, 12)
     assert c.as_text().startswith("HloModule jit_train_step")
 
 
@@ -178,9 +178,9 @@ def test_gpt2_step_on_dp_tp_mesh_keeps_kernel(mesh_2x2, monkeypatch):
     assert ts.state_specs["params"]["wte"]["embedding"] == P(None, None)
     assert ts.state_specs["params"]["h_0"]["attn"]["c_attn"]["kernel"] == P(None, "tp")
     text = ts._step.lower(*_step_args(ts)).compile().as_text()
-    assert text.count("tpu_custom_call") == 8
-    assert _kernel_calls(text) == {
-        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+    # the policy reaches the kernel through the shard_map over heads
+    assert text.count("tpu_custom_call") == 6
+    assert _kernel_calls(text) == dict.fromkeys(KERNELS, 2)
     assert "all-reduce(" in text
 
 
@@ -223,10 +223,34 @@ def test_mistral_step_under_fsdp_gathers_weights_not_activations(topo, monkeypat
     # and each block's weight gradients are summed over the chips
     summed = {c.shape for c in tally if c.kind == "all-reduce"}
     assert {(d, ff), (ff, d), (d, vocab)} <= summed, summed
-    assert _kernel_calls(text) == {
-        "flash_fwd": 2 * layers, "flash_bwd_dq": layers, "flash_bwd_dkv": layers}
+    assert _kernel_calls(text) == dict.fromkeys(KERNELS, layers)
     # 5.25 GiB; the parent's program, by the same code, holds 7.19
     assert _live_bytes(c) < 5.5 * GIB, c.memory_analysis()
+
+
+@pytest.mark.timeout(300)
+def test_the_plan_at_a_shape_no_chip_ran_fits_the_chip(topo, monkeypatch):
+    """models/remat.py's rule at a shape the chip runs of PR 33 never saw,
+    GPT-2 small's widths 24 layers deep at half its cell's rows, given a
+    v5e's limit: it takes every name, and the step compiled with them holds
+    less than the 14 GiB the cells are held to, and not more than the
+    reckoning said by more than the error it showed against the chip's own
+    readings (tests/test_remat.py). (At the cell's own rows the rule takes
+    no further rung, reckoning 14.9 GiB with the operands: that step
+    compiled to 14.7, and to 11.7 without them. The routed cell's share
+    with 8 experts held and the operands saved compiled to 11.10 GiB where
+    the rule reckons 10.93.)"""
+    from ray_tpu.models import remat
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    ts = TrainStep(GPT2Config.gpt2_124m(n_layer=24), Mesh(np.array(topo.devices[:1]), ("dp",)),
+                   telemetry=False)
+    c = ts._step.lower(*_step_args(ts, (64, 256))).compile()
+    plan = remat.traced(ts.model.config)
+    assert plan.names == remat.FIRST_RUNG + ("attn_q", "attn_k", "attn_v", "mlp_up")
+    assert _live_bytes(c) < 14 * GIB, c.memory_analysis()
+    assert _live_bytes(c) - plan.reckoned_bytes <= 0.85 * GIB, (plan, c.memory_analysis())
 
 
 def test_windowed_flash_compiles_at_the_cell_s_shape(one_chip):
