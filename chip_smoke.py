@@ -201,6 +201,21 @@ def _windowed_flash_plan():
             "attention_path": attention_path(t)}
 
 
+def _selected_flash_plan():
+    """The tiles of the selected flash call of the benchmark's indexed layers,
+    (1 x 32, 16384, 128) over 2,048 keys a query, the words a row of its
+    packed mask has, and the rows a grid step of index_select takes."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import indexer
+    from ray_tpu.ops.attention import flash_tiles
+
+    bh, t, d, top_k = 32, 16384, 128, 2048
+    return {"shape": [bh, t, d], "select": top_k,
+            "tiles": flash_tiles(bh, t, d, jnp.bfloat16, select=top_k)._asdict(),
+            "mask_width": indexer.mask_width(t), "index_select_rows": indexer._select_block(t)}
+
+
 def _remat_plans():
     """What each benchmark cell's step would save across its blocks' remat on
     this chip (models/remat.py): the rule is a pure function of the cell's
@@ -265,6 +280,7 @@ def one_chip_loop(config):
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
         for shape in config["attn_shapes"]]
     report["windowed_flash"] = _windowed_flash_plan()
+    report["selected_flash"] = _selected_flash_plan()
     report["remat_plans"] = _remat_plans()
     report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])
     train.report(report)

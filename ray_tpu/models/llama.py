@@ -150,12 +150,19 @@ class LlamaAttention(nn.Module):
     """`config` is a LlamaConfig or any config with its attention fields
     (models/mellum.py). A layer of a model whose layers differ in kind says
     how it differs: `window` keys a query sees (None: all before it), its
-    own rotary table `inv_freq` and the factor on the table's cos and sin."""
+    own rotary table `inv_freq` and the factor on the table's cos and sin,
+    `qk_norm` an RMSNorm over each head of q and k before the rotary, and
+    `select`, a module that names the keys each query sees from the layer's
+    input: `select(x, pos_offset)` gives (packed mask, its transpose, keys a
+    query at most) or None where every key before a query is seen
+    (models/mellum.py:Indexer)."""
 
     config: Any
     window: Optional[int] = None
     inv_freq: Optional[tuple] = None
     rope_scale: float = 1.0
+    qk_norm: bool = False
+    select: Any = None
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
@@ -166,6 +173,10 @@ class LlamaAttention(nn.Module):
         q = dense(cfg.n_head * hd, "wq")(x).reshape(B, T, cfg.n_head, hd)
         k = dense(cfg.n_kv_head * hd, "wk")(x).reshape(B, T, cfg.n_kv_head, hd)
         v = dense(cfg.n_kv_head * hd, "wv")(x).reshape(B, T, cfg.n_kv_head, hd)
+        if self.qk_norm:
+            q = RMSNorm(cfg.rms_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_eps, name="k_norm")(k)
+        chosen = None if self.select is None else self.select(x, pos_offset)
 
         positions = jnp.arange(T) + pos_offset
         ang = rope_angles(hd, cfg.rope_theta, positions, self.inv_freq)
@@ -182,7 +193,14 @@ class LlamaAttention(nn.Module):
 
         # a window as long as the sequence holds all of it
         window = self.window if self.window is not None and self.window < T else None
-        if cfg.attn_fn is not None:
+        if chosen is not None:
+            if cfg.attn_fn is not None:
+                raise NotImplementedError("attention over selected keys runs on one device")
+            from ray_tpu.ops.attention import selected_attention
+
+            with jax.named_scope("attn.selected"):
+                y = selected_attention(q, k, v, *chosen)
+        elif cfg.attn_fn is not None:
             y = cfg.attn_fn(q, k, v) if window is None else cfg.attn_fn(q, k, v, window=window)
         elif cfg.use_flash_attention:
             from ray_tpu.ops.attention import causal_attention

@@ -11,6 +11,16 @@ the hidden size). The MLP is ops/moe.py's `ExpertShare`: the router sees
 every expert of the layer, this program computes the experts it holds
 (`first_expert`, `num_held`: one chip's share under expert parallelism) and
 leaves out what the others would add.
+
+A third kind of layer, `indexed_attention` (Keye-VL 2.0's language model:
+DeepSeek-V3.2's sparse attention on a Qwen3-MoE decoder), chooses its keys:
+an `Indexer` scores every causal pair from the layer's input with a few small
+heads (ops/indexer.py), each query keeps its `index_top_k` best keys, and
+attention runs over those alone (ops/attention.py:selected_attention). The
+selection is a set of integers, so the indexer sees its input under
+`stop_gradient` and takes no gradient from the loss; the configuration has
+no objective of the indexer's own and none is added. `qk_norm` is Qwen3's
+RMSNorm over each head of q and k.
 """
 
 from __future__ import annotations
@@ -20,16 +30,19 @@ import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import remat
-from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, loss_fn  # noqa: F401
+from ray_tpu.models.llama import (  # noqa: F401
+    LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, apply_rope, loss_fn, rope_angles)
+from ray_tpu.ops import indexer
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, INDEXED = "sliding_attention", "full_attention", "indexed_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +89,12 @@ class MellumConfig:
     rope_theta: float = 5e5
     yarn: Optional[YarnScaling] = None  # of the full_attention layers
     rms_eps: float = 1e-6
+    qk_norm: bool = False  # RMSNorm over each head of q and k, before the rotary
+    # of the indexed_attention layers: the indexer's heads, their width, and
+    # the keys a query keeps
+    index_heads: int = 16
+    index_dim: int = 64
+    index_top_k: int = 2048
     expert_dim: int = 896
     num_experts: int = 64  # the router's width
     top_k: int = 8
@@ -110,16 +129,26 @@ class MellumConfig:
         return int(self.n_layer * (attn + d * self.num_experts + experts)
                    + self.vocab_size * d)
 
+    def index_params(self) -> int:
+        """An indexer's three matrices: its query heads, its one key head and
+        a weight a head. They take no gradient."""
+        return self.n_embd * (self.index_heads * self.index_dim + self.index_dim
+                              + self.index_heads)
+
     def flops_per_token(self, seq_len: int) -> int:
         """6 x matmul parameters + 12 x heads x head_dim x the keys a query
         sees on average: T/2 in a full layer, w - w^2/(2T) under a window w
-        (GPT2Config.flops_per_token's rule, a window counted for what it
-        needs). The experts' term is the even-routing load."""
-        keys = 0.0
+        or a selection of w keys (GPT2Config.flops_per_token's rule, a
+        window counted for what it needs). The experts' term is the
+        even-routing load. An indexer runs forward only: 2 x its matrices
+        and 2 x heads x width x T/2 of scores."""
+        keys, index = 0.0, 0.0
         for kind in self.layer_types:
-            w = self.sliding_window if kind == SLIDING else seq_len
+            w = {SLIDING: self.sliding_window, INDEXED: self.index_top_k}.get(kind, seq_len)
             keys += seq_len / 2 if w >= seq_len else w - w * w / (2 * seq_len)
-        return int(6 * self.matmul_params() + 12 * self.n_head * self.head_dim * keys)
+            if kind == INDEXED:
+                index += 2 * self.index_params() + self.index_heads * self.index_dim * seq_len
+        return int(6 * self.matmul_params() + 12 * self.n_head * self.head_dim * keys + index)
 
     def rotary(self, kind: str):
         """(inv_freq as a tuple or None for the plain table, factor on cos
@@ -138,6 +167,43 @@ class MellumConfig:
         return cls(**base)
 
 
+class Indexer(nn.Module):
+    """(B, T, C) -> which keys each query of an `indexed_attention` layer
+    sees: (packed mask, its transpose, keys a query at most), or None where
+    the sequence is no longer than `index_top_k` and every key before a query
+    is seen. DeepSeek-V3.2's lightning indexer: `index_heads` query heads of
+    `index_dim` against one key head (LayerNorm, rotary over the whole
+    width at the layer's theta), ReLU, a learned weight a head and query;
+    the top `index_top_k` of each query's causal row, exactly
+    (ops/indexer.py). Sows the mean number of keys a query kept into
+    "attn_keys" (TrainStep's telemetry)."""
+
+    config: MellumConfig
+
+    @nn.compact
+    def __call__(self, x, pos_offset=0):
+        cfg = self.config
+        B, T, _ = x.shape
+        x = jax.lax.stop_gradient(x)
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=cfg.dtype, name=name)
+        with jax.named_scope("attn.index"):
+            q = dense(cfg.index_heads * cfg.index_dim, "wq")(x).reshape(
+                B, T, cfg.index_heads, cfg.index_dim)
+            k = nn.LayerNorm(dtype=cfg.dtype, name="k_norm")(dense(cfg.index_dim, "wk")(x))
+            w = dense(cfg.index_heads, "ww")(x)
+            if cfg.index_top_k >= T:  # the matrices are made at any length
+                return None
+            ang = rope_angles(cfg.index_dim, cfg.rope_theta, jnp.arange(T) + pos_offset)
+            q, k = apply_rope(q, ang), apply_rope(k[:, :, None, :], ang)[:, :, 0, :]
+            # the matrices take no gradient either: a set of integers has none
+            scores = indexer.index_scores(*jax.lax.stop_gradient((q, k, w)))
+        with jax.named_scope("attn.select"):
+            mask = indexer.index_select(scores, cfg.index_top_k)
+            self.sow("attn_keys", "selected",
+                     jax.lax.population_count(mask).sum(-1).astype(jnp.float32).mean())
+            return mask, indexer.transpose_packed(mask), cfg.index_top_k
+
+
 class MellumBlock(nn.Module):
     config: MellumConfig
     kind: str
@@ -150,7 +216,8 @@ class MellumBlock(nn.Module):
         inv_freq, scale = cfg.rotary(self.kind)
         attn = LlamaAttention(
             cfg, window=cfg.sliding_window if self.kind == SLIDING else None,
-            inv_freq=inv_freq, rope_scale=scale, name="attn")
+            inv_freq=inv_freq, rope_scale=scale, qk_norm=cfg.qk_norm,
+            select=Indexer(cfg, name="indexer") if self.kind == INDEXED else None, name="attn")
         x = pin(x + attn(RMSNorm(cfg.rms_eps, name="attn_norm")(x), pos_offset), self.stream)
         moe = ExpertShare(cfg.n_embd, cfg.expert_dim, cfg.num_experts, cfg.top_k,
                           cfg.first_expert, cfg.num_held, cfg.dtype, name="moe")
@@ -175,11 +242,23 @@ def remat_plan(cfg: MellumConfig, shape: remat.StepShape, limit) -> remat.RematP
     # every token's top_k of them: 7.5 such buffers in the step compiled for
     # a v5e at the benchmark's cell and at twice its rows (4.2 and 8.2 GiB)
     routed = int(7.5 * shape.rows * shape.seq_len * cfg.top_k * d * itemsize)
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, hd, itemsize)
+    first = remat.FIRST_RUNG
+    indexed = sum(kind == INDEXED for kind in cfg.layer_types)
+    if indexed and cfg.index_top_k < shape.seq_len:
+        # an indexed layer also holds its selection, the packed mask and its
+        # transpose (int32 words), in every layer where it holds any (a
+        # model's layers are of this kind or none is); and its forward works
+        # in a row of float32 scores a query, read once by the selection
+        layer += cfg.index_params()
+        first += ("attn_sel",)
+        name_bytes["attn_sel"] = (2 * shape.rows * shape.seq_len
+                                  * max(128, shape.seq_len // 32) * 4) * indexed // cfg.n_layer
+        routed = max(routed, 2 * shape.rows * shape.seq_len * shape.seq_len * 4)
     held = remat.held_bytes(
         shape, params=cfg.n_layer * layer + 2 * cfg.vocab_size * d, width=d,
         vocab=cfg.vocab_size, n_layer=cfg.n_layer, itemsize=itemsize, block=routed)
-    return remat.plan(REMAT_RUNGS, remat.attention_bytes(shape, cfg.n_head, hd, itemsize),
-                      cfg.n_layer, held, limit)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit, first)
 
 
 class Mellum(nn.Module):

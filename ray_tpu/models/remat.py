@@ -8,7 +8,8 @@ compute again and cheap to hold, and a checkpoint policy saves it by name
 identity.
 
 First rung, always: `attn_out` and `attn_lse`, what only the flash kernel can
-give. They cost one more copy of the stream a layer and spare the kernel's
+give (and `attn_sel` where a layer selects its keys: the packed masks, the
+dearest thing in such a layer to compute twice). They cost one more copy of the stream a layer and spare the kernel's
 second run. Further rungs by a rule: a family states, beside its blocks, its
 other names and what each is worth (`REMAT_RUNGS`: rungs of names that are
 only worth saving together, each with the milliseconds of a step it spared
@@ -61,14 +62,17 @@ class StepShape(NamedTuple):
 
 class RematPlan(NamedTuple):
     """`names` saved across remat; the bytes they hold a layer and over all
-    layers on one chip; the step's reckoned total with them; and the limit
-    that total was held to (None: no chip said one, first rung alone)."""
+    layers on one chip; the step's reckoned total with them; the limit that
+    total was held to (None: no chip said one, first rung alone); and of the
+    saved bytes those of `attn_sel`, a layer's selection of keys (0 where no
+    layer selects)."""
 
     names: Tuple[str, ...]
     layer_bytes: int
     saved_bytes: int
     reckoned_bytes: int
     limit_bytes: Optional[int]
+    sel_bytes: int = 0
 
 
 def step_shape(batch_shape, axis_sizes) -> StepShape:
@@ -152,14 +156,15 @@ def attention_bytes(shape: StepShape, n_head: int, head_dim: int, itemsize: int)
 
 
 def plan(rungs, name_bytes: Dict[str, int], n_layer: int, held: Held,
-         limit: Optional[int]) -> RematPlan:
-    """The first rung, and of `rungs` (each `(names, ms a step spared for a
+         limit: Optional[int], first_rung: Tuple[str, ...] = FIRST_RUNG) -> RematPlan:
+    """The first rung (`first_rung`: a family whose attention selects its keys
+    holds the selection there too, `attn_sel`), and of `rungs` (each `(names, ms a step spared for a
     GiB held)`) the set that spares most among those whose reckoned total
     (`Held.total`) stays under `_LIMIT_SHARE` of `limit`; with no limit the
     first rung alone. The first rung is taken whatever the limit: it is one
     more copy of the stream a layer, whatever the shape."""
     room = None if limit is None else int(limit * _LIMIT_SHARE)
-    first = sum(name_bytes[n] for n in FIRST_RUNG)
+    first = sum(name_bytes[n] for n in first_rung)
     rung_bytes = [sum(name_bytes[n] for n in names) for names, _ in rungs]
 
     def layer_bytes(chosen):
@@ -170,9 +175,10 @@ def plan(rungs, name_bytes: Dict[str, int], n_layer: int, held: Held,
         for chosen in itertools.combinations(range(len(rungs)), k)
         if room is not None and held.total(n_layer * layer_bytes(chosen)) <= room]
     taken = max(fitting, key=lambda chosen: sum(rungs[i][1] * rung_bytes[i] for i in chosen))
-    names = FIRST_RUNG + tuple(n for i in taken for n in rungs[i][0])
+    names = first_rung + tuple(n for i in taken for n in rungs[i][0])
     layer = layer_bytes(taken)
-    return RematPlan(names, layer, n_layer * layer, held.total(n_layer * layer), room)
+    return RematPlan(names, layer, n_layer * layer, held.total(n_layer * layer), room,
+                     n_layer * name_bytes.get("attn_sel", 0) if "attn_sel" in names else 0)
 
 
 _traced = None  # (the configuration, its RematPlan) of the newest trace

@@ -26,9 +26,18 @@ The reference framework has no attention kernels at all (its data plane is torch
 this op is what its GPU stack gets from flash-attn. Ring attention
 (ray_tpu/ops/ring_attention.py) does not call it: its chunk pairs are einsums.
 
+A third kind of call takes its mask from outside: causal attention over the
+keys a per-query selection names (ops/indexer.py: each query's top-k keys by
+a learned indexer's scores), handed over as a packed bit mask and its
+transpose. This first version visits every tile at or below the diagonal and
+masks each with its bits; a tile the selection leaves empty is computed and
+comes to nothing.
+
 The three pallas calls are named flash_fwd, flash_bwd_dq and flash_bwd_dkv,
-and flash_win<window>_fwd, flash_win<window>_bwd_dq, flash_win<window>_bwd_dkv
-where the call has a window shorter than its sequence.
+flash_win<window>_fwd, flash_win<window>_bwd_dq, flash_win<window>_bwd_dkv
+where the call has a window shorter than its sequence, and flash_sel<k>_fwd,
+flash_sel<k>_bwd_dq, flash_sel<k>_bwd_dkv where a selection of k keys a query,
+fewer than the sequence has, says what is seen.
 The name reaches the compiled instruction and the profiler's trace (wrapped by
 the transformations it went through, e.g. transpose_jvp_flash_bwd_dq_), on one
 chip and under a mesh alike, and is how the benchmark's per-kernel metrics
@@ -71,12 +80,14 @@ class FlashTiles(NamedTuple):
     """block_q rows of the tile a grid step owns (queries in flash_fwd and
     flash_bwd_dq, keys in flash_bwd_dkv), block_k rows of the tiles it
     loops over (keys, resp. queries), heads per grid step; `window` keys a
-    query sees, itself included (None: every key before it)."""
+    query sees, itself included (None: every key before it); `select` keys
+    a query sees of those before it, named by a mask (None: no mask)."""
 
     block_q: int
     block_k: int
     heads: int
     window: Optional[int] = None
+    select: Optional[int] = None
 
 
 def _vmem_bytes(tiles, t, d, itemsize):
@@ -98,7 +109,8 @@ def _divisor(t, cap):
     return max(b for b in range(128, max(cap, 128) + 1, 128) if t % b == 0)
 
 
-def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None) -> FlashTiles:
+def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None,
+                select: Optional[int] = None) -> FlashTiles:
     """Tiles for a causal flash call on (bh, t, d) operands of `dtype`, from
     the shape alone: the largest square tile, a multiple of 128 that divides
     t, up to _MAX_BLOCK (a tile step's matmuls must be long enough to hide
@@ -112,7 +124,13 @@ def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None) ->
     rows visits b + window + b scores a row where window are needed (the
     diagonal tile and the one on the window's edge are half masked), so
     the tile is at most _WINDOW_TILE of the window. A window of t or more
-    is the causal call."""
+    is the causal call.
+
+    With a selection of `select` keys a query, fewer than t, the call is a
+    selected one: the tile it loops over lies within one bit of the packed
+    mask's words (ops/indexer.py:mask_width lanes), the tile it owns is the
+    causal call's, and a grid step's rows of the mask count against the
+    budget. A selection of t keys or more is the causal call."""
     if t % 128:
         raise ValueError(f"seq len {t} is not a multiple of 128")
     if window is not None and window < 1:
@@ -120,6 +138,10 @@ def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None) ->
     itemsize = jnp.dtype(dtype).itemsize
     if window is not None and window >= t:
         window = None
+    if select is not None and select >= t:
+        select = None
+    if select is not None and window is not None:
+        raise ValueError("a call takes a window or a selection, not both")
     cap = _MAX_BLOCK if window is None else min(_MAX_BLOCK, int(window * _WINDOW_TILE))
     block = _divisor(t, cap)
     heads = max(1, min(bh, _TILE_ELEMS // (block * block)))
@@ -131,7 +153,25 @@ def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None) ->
         heads //= 2
     while over_budget() and block > 128:
         block = _divisor(t, block - 1)
-    return FlashTiles(block, block, heads, window)
+    if select is None:
+        return FlashTiles(block, block, heads, window)
+    from ray_tpu.ops.indexer import mask_width
+
+    width = mask_width(t)
+    inner = _divisor(width, block)
+    if block % inner:
+        block = inner
+    while heads > 1 and _select_vmem_bytes((block, inner, heads), t, d, itemsize) > _VMEM_BUDGET:
+        heads //= 2
+    return FlashTiles(block, inner, heads, None, select)
+
+
+def _select_vmem_bytes(tiles, t, d, itemsize):
+    """`_vmem_bytes` of a selected call: and the rows of the packed mask a
+    grid step owns, double-buffered."""
+    from ray_tpu.ops.indexer import mask_width
+
+    return _vmem_bytes(tiles, t, d, itemsize) + 2 * tiles[0] * mask_width(t) * 4
 
 
 # --------------------------------------------------------------------------
@@ -146,6 +186,9 @@ def _call(kernel, name, like, tiles, in_specs, out_specs, out_shape, interpret):
     # The scoped default is enough for small calls; beyond it ask for what
     # the rule reckoned, with a quarter more for what the reckoning leaves out.
     vmem = _vmem_bytes(tiles, t, d, like.dtype.itemsize)
+    if tiles.select is not None:
+        name = name.replace("flash_", f"flash_sel{tiles.select}_", 1)
+        vmem = _select_vmem_bytes(tiles, t, d, like.dtype.itemsize)
     if tiles.window is not None:
         # a windowed call says so, and how wide: a shape function sees
         # names and shapes only
@@ -466,6 +509,189 @@ def _flash_bwd(res, do, *, tiles, interpret):
 
 
 # --------------------------------------------------------------------------
+# attention over selected keys
+# --------------------------------------------------------------------------
+
+
+def _tile_bits(m_ref, j, block_k):
+    """Which entries of the tile of columns j * block_k onward the packed mask
+    m_ref (1, rows, W) allows, (rows, block_k) bool: the tile's columns are
+    block_k lanes of one bit of the words (ops/indexer.py)."""
+    width = m_ref.shape[2]
+    if block_k == width:
+        return ((m_ref[0] >> j) & 1) != 0
+    per_bit = width // block_k
+    lanes = pl.ds(pl.multiple_of((j % per_bit) * block_k, block_k), block_k)
+    return ((m_ref[0, :, lanes] >> (j // per_bit)) & 1) != 0
+
+
+def _sel_fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *, block_q, block_k):
+    """`_fwd_kernel` with every tile masked by the selection's bits, which
+    hold the causal mask too. A row may see nothing in its first tiles:
+    what those add under a running max of NEG_INF, the rescale of its
+    first visible tile takes out again."""
+    g, _, d = q_ref.shape
+    i = _own_tile(k_ref.shape[1], block_q)
+    q_scale, s_scale = _split_scale(d)
+    q = q_ref[...]
+    if q_scale != 1.0:
+        q = q * q_scale
+
+    def step(j, carry):
+        m, l, acc = carry
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _dot(q, k, _NT)
+        if s_scale != 1.0:
+            s = s * s_scale
+        s = jnp.where(_tile_bits(m_ref, j, block_k)[None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
+        return m_new, l, acc
+
+    init = (jnp.full((g, block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((g, block_q, 1), jnp.float32),
+            jnp.zeros((g, block_q, d), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(0, (i + 1) * (block_q // block_k), step, init)
+    o_ref[...] = (acc * (1.0 / l)).astype(o_ref.dtype)
+    lse = m + jnp.log(l)
+    for h in range(g):
+        lse_ref[h, 0] = lse[h, :, 0]
+
+
+def _sel_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, m_ref, dq_ref,
+                       *, block_q, block_k):
+    g, _, d = q_ref.shape
+    i = _own_tile(k_ref.shape[1], block_q)
+    q_scale, s_scale = _split_scale(d)
+    q = q_ref[...]
+    if q_scale != 1.0:
+        q = q * q_scale
+    do = do_ref[...]
+    lse = jnp.stack([lse_ref[h, 0][:, None] for h in range(g)])
+    delta = jnp.stack([delta_ref[h, 0][:, None] for h in range(g)])
+
+    def step(j, dq):
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _dot(q, k, _NT)
+        if s_scale != 1.0:
+            s = s * s_scale
+        s = jnp.where(_tile_bits(m_ref, j, block_k)[None], s, NEG_INF)
+        p = jnp.exp(s - lse)
+        ds = p * (_dot(do, v, _NT) - delta)
+        return dq + _dot(ds.astype(k.dtype), k, _NN)
+
+    dq = jax.lax.fori_loop(0, (i + 1) * (block_q // block_k), step,
+                           jnp.zeros((g, block_q, d), jnp.float32))
+    dq_ref[...] = (dq * (q_scale * s_scale)).astype(dq_ref.dtype)
+
+
+def _sel_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, m_ref, dk_ref, dv_ref,
+                        *, block_q, block_k):
+    """As `_bwd_dkv_kernel`: owns block_q keys, loops over the tiles of
+    block_k queries from the diagonal's first on; m_ref is the transposed
+    relation's mask, rows keys, bits and lanes queries."""
+    g, _, d = k_ref.shape
+    seq_len = q_ref.shape[1]
+    i = _own_tile(seq_len, block_q)
+    q_scale, s_scale = _split_scale(d)
+    k = k_ref[...]
+    if q_scale != 1.0:
+        k = k * q_scale
+    v = v_ref[...]
+
+    def step(j, carry):
+        dk, dv = carry
+        q = _rows(q_ref, j, block_k)
+        do = _rows(do_ref, j, block_k)
+        at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        lse = lse_ref[:, :, at]
+        delta = delta_ref[:, :, at]
+        s = _dot(k, q, _NT)
+        if s_scale != 1.0:
+            s = s * s_scale
+        s = jnp.where(_tile_bits(m_ref, j, block_k)[None], s, NEG_INF)
+        p = jnp.exp(s - lse)
+        dv = dv + _dot(p.astype(do.dtype), do, _NN)
+        ds = p * (_dot(v, do, _NT) - delta)
+        dk = dk + _dot(ds.astype(q.dtype), q, _NN)
+        return dk, dv
+
+    zeros = jnp.zeros((g, block_q, d), jnp.float32)
+    dk, dv = jax.lax.fori_loop(i * (block_q // block_k), seq_len // block_k, step, (zeros, zeros))
+    dk_ref[...] = (dk * (q_scale * s_scale)).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
+def _mask_spec(like, mask, tiles):
+    """Block spec of the packed mask (B, t, W) beside (B * heads, t, d)
+    operands: the rows of the tile a grid step owns, of the batch row its
+    heads belong to."""
+    per_row = like.shape[0] // mask.shape[0] // tiles.heads  # grid steps a batch row
+    return pl.BlockSpec((1, tiles.block_q, mask.shape[2]), lambda b, i: (b // per_row, i, 0))
+
+
+def _flash_sel_fwd(q, k, v, mask, *, tiles, interpret):
+    own, own_row, whole, _ = _specs(q, tiles)
+    return _call(
+        _sel_fwd_kernel, "flash_fwd", q, tiles,
+        in_specs=[own, whole, whole, _mask_spec(q, mask, tiles)],
+        out_specs=[own, own_row],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((q.shape[0], 1, q.shape[1]), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, v, mask)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_sel(q, k, v, mask, mask_t, tiles, interpret):
+    o, _ = _flash_sel_fwd(q, k, v, mask, tiles=tiles, interpret=interpret)
+    return o
+
+
+def _flash_sel_fwd_rule(q, k, v, mask, mask_t, tiles, interpret):
+    # as `_flash_fwd_rule`; `attn_sel` is what the backward needs of the
+    # selection: saved, the indexer and the selection run once a layer
+    q, k, v = (checkpoint_name(x, name) for x, name in
+               ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
+    mask, mask_t = checkpoint_name(mask, "attn_sel"), checkpoint_name(mask_t, "attn_sel")
+    o, lse = _flash_sel_fwd(q, k, v, mask, tiles=tiles, interpret=interpret)
+    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
+    return o, (q, k, v, o, lse, mask, mask_t)
+
+
+def _flash_sel_bwd_rule(tiles, interpret, res, do):
+    q, k, v, o, lse, mask, mask_t = res
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, None, :]
+    own, own_row, whole, whole_row = _specs(q, tiles)
+    like_q = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dq = _call(
+        _sel_bwd_dq_kernel, "flash_bwd_dq", q, tiles,
+        in_specs=[own, whole, whole, own, own_row, own_row, _mask_spec(q, mask, tiles)],
+        out_specs=own,
+        out_shape=like_q,
+        interpret=interpret,
+    )(q, k, v, do, lse, delta, mask)
+    dk, dv = _call(
+        _sel_bwd_dkv_kernel, "flash_bwd_dkv", q, tiles,
+        in_specs=[whole, own, own, whole, whole_row, whole_row, _mask_spec(q, mask_t, tiles)],
+        out_specs=[own, own],
+        out_shape=[like_q, like_q],
+        interpret=interpret,
+    )(q, k, v, do, lse, delta, mask_t)
+    return dq, dk, dv, None, None
+
+
+_flash_sel.defvjp(_flash_sel_fwd_rule, _flash_sel_bwd_rule)
+
+
+# --------------------------------------------------------------------------
 # public entry points
 # --------------------------------------------------------------------------
 
@@ -530,6 +756,44 @@ def xla_causal_attention(q, k, v, window=None):
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bhsd->bhtd", p, v)
+
+
+def flash_selected_attention(q, k, v, mask, mask_t, top_k, *, interpret=False):
+    """q/k/v: (B, H, T, D) -> (B, H, T, D): causal attention over the keys
+    that `mask` names, the packed mask (B, T, W) of ops/indexer.py with
+    `top_k` keys a query at most, and `mask_t` its transposed relation's.
+    Where top_k is the sequence or more every causal key is seen and the
+    call is `flash_causal_attention`."""
+    b, h, t, d = q.shape
+    if top_k >= t:
+        return flash_causal_attention(q, k, v, interpret=interpret)
+    tiles = flash_tiles(b * h, t, d, q.dtype, select=top_k)
+    # a grid step's heads are of one batch row, whose mask they share
+    tiles = tiles._replace(heads=math.gcd(tiles.heads, h))
+    o = _flash_sel(q.reshape(b * h, t, d), k.reshape(b * h, t, d), v.reshape(b * h, t, d),
+                   mask, mask_t, tiles, interpret)
+    return o.reshape(b, h, t, d)
+
+
+def xla_selected_attention(q, k, v, mask):
+    """Plain einsum-softmax over the keys the packed mask names."""
+    from ray_tpu.ops.indexer import unpack
+
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k, preferred_element_type=jnp.float32)
+    s = jnp.where(unpack(mask)[:, None], s / math.sqrt(q.shape[-1]), NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhts,bhsd->bhtd", p, v)
+
+
+def selected_attention(q, k, v, mask, mask_t, top_k):
+    """Layout-adapting entry, as `causal_attention`: q/k/v (B, T, H, D) ->
+    (B, T, H, D), over the keys of the packed mask."""
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    if attention_path(q.shape[1]) == "flash":
+        o = flash_selected_attention(qt, kt, vt, mask, mask_t, top_k)
+    else:
+        o = xla_selected_attention(qt, kt, vt, mask)
+    return o.transpose(0, 2, 1, 3)
 
 
 def _on_tpu() -> bool:

@@ -175,7 +175,8 @@ class TrainStep:
 
         self._is_moe = isinstance(model_cfg, GPT2MoEConfig)
         # A dropless expert layer adds no term to the loss; the rows its held
-        # experts worked on (the "moe_load" collection) go out with the
+        # experts worked on (the "moe_load" collection), and the keys a query
+        # kept where a layer selects them ("attn_keys"), go out with the
         # step's metrics, for the telemetry.
         self._reports_moe_load = isinstance(model_cfg, MellumConfig)
         if rules is None:
@@ -238,9 +239,9 @@ class TrainStep:
                     aux = sum(jax.tree.leaves(lstate.get("losses", {})))
                 elif self._reports_moe_load:
                     logits, sown = self.model.apply(
-                        {"params": params}, batch["idx"], mutable=["moe_load"]
+                        {"params": params}, batch["idx"], mutable=["moe_load", "attn_keys"]
                     )
-                    aux, loads = 0.0, sown["moe_load"]
+                    aux, loads = 0.0, sown
                 else:
                     logits = self.model.apply({"params": params}, batch["idx"])
                     aux = 0.0
@@ -264,7 +265,10 @@ class TrainStep:
                 from ray_tpu.ops.moe import moe_load_metrics
 
                 metrics.update(moe_load_metrics(
-                    loads, batch["idx"].size, model_cfg.top_k))
+                    loads["moe_load"], batch["idx"].size, model_cfg.top_k))
+                kept = jax.tree.leaves(loads.get("attn_keys", {}))
+                if kept:  # layers that select their keys: those a query kept
+                    metrics["attn_keys_selected_mean"] = sum(kept) / len(kept)
             return new_state, metrics
 
         self._step = jax.jit(

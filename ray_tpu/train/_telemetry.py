@@ -127,8 +127,11 @@ _EXIT_WAIT_S = 10.0
 # Keys of a step's metrics that the summary carries as they are, from the
 # newest completed step (a scan of several: its last): the rows this
 # program's experts worked on, their share of every token's assignments,
-# and the fullest held expert over the mean (ops/moe.py:moe_load_metrics).
-_STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean")
+# and the fullest held expert over the mean (ops/moe.py:moe_load_metrics);
+# the keys a query kept, mean over the layers that select them
+# (models/mellum.py:Indexer).
+_STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
+                "attn_keys_selected_mean")
 
 
 @dataclasses.dataclass(slots=True)

@@ -294,3 +294,41 @@ def test_expert_share_compiles_at_the_cell_s_size(one_chip, monkeypatch):
     assert all(math.prod(map(int, s.split(","))) < 1024 for s in scattered), scattered
     # the buffer with headroom: 1.5 x 131,072 x 16/64 rows
     assert "bf16[49152,2304]" in text and "bf16[131072,2304]" in text
+
+
+def test_selected_flash_compiles_at_the_cell_s_shape(one_chip):
+    """keye_vl2_30b_l4_ep8.t16384's layers: (1, 32, 16384, 128) over 2,048
+    keys a query named by a packed mask, forward and backward with the tiles
+    `flash_tiles` picks, each call under the name that says k."""
+    mask = jax.ShapeDtypeStruct((1, 16384, 512), jnp.int32, sharding=one_chip)
+    selected = lambda q, k, v, m, mt: attention.flash_selected_attention(
+        q, k, v, m, mt, 2048).astype(jnp.float32).sum()
+    fn = jax.value_and_grad(selected, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*_qkv((1, 32, 16384, 128), one_chip), mask, mask).compile().as_text()
+    names = _CUSTOM_CALL.findall(text)
+    assert len(names) == text.count("tpu_custom_call") == 3
+    for kernel in ("flash_sel2048_fwd", "flash_sel2048_bwd_dq", "flash_sel2048_bwd_dkv"):
+        assert sum(kernel in n for n in names) == 1, names
+    assert not any(k in n for k in KERNELS for n in names)
+
+
+def test_indexer_compiles_at_the_cell_s_shape(one_chip):
+    """The indexer's scores (16 heads of 64 against one key head over 16,384
+    positions) and the exact top-2,048 of each row, as pallas calls under
+    their names, and the mask's transpose beside them without a (T, T) array
+    of words."""
+    from ray_tpu.ops import indexer
+
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def select(q, k, w):
+        mask = indexer._pallas_select(indexer._pallas_scores(q, k, w, False), 2048, False)
+        return mask, indexer.transpose_packed(mask)
+
+    c = jax.jit(select).lower(shape((1, 16384, 16, 64), jnp.bfloat16),
+                              shape((1, 16384, 64), jnp.bfloat16),
+                              shape((1, 16384, 16), jnp.bfloat16)).compile()
+    names = _CUSTOM_CALL.findall(c.as_text())
+    assert sorted(re.sub(r"[.\d]+$", "", n) for n in names) == ["index_scores", "index_select"]
+    # the scores, 1 GiB of float32, are the only array of that size
+    assert GIB <= c.memory_analysis().temp_size_in_bytes < 1.25 * GIB
