@@ -186,6 +186,50 @@ def _check_flash_vs_xla(shape, seed, on_tpu):
             "tiles": flash_tiles(b * h, t, d, jnp.bfloat16)._asdict()}
 
 
+def _check_ssd_vs_chunked(seed, on_tpu):
+    """ops/ssd.py's two kernels against the same chunked form in jax.numpy
+    at the benchmark's widths (64 heads of 64, state 128, chunks of 256) on a
+    quarter of its sequence, same seed: the output, the chunk states and the
+    six gradients, as max-abs error over the reference's max-abs value."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssd
+
+    b, t, h, p, n, chunk = (1, 1024, 64, 64, 128, 256) if on_tpu else (1, 64, 4, 32, 16, 16)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x, w = (jax.random.normal(k, (b, t, h, p), jnp.bfloat16) for k in ks[:2])
+    bm, cm = (jax.random.normal(k, (b, t, 1, n), jnp.bfloat16) for k in ks[2:4])
+    dt = jax.nn.softplus(jax.random.normal(ks[4], (b, t, h)) - 3.0)
+    a = -jnp.exp(jax.random.uniform(ks[5], (h,), minval=0.0, maxval=2.77))
+    d = jnp.ones((h,))
+
+    def run(form):
+        def loss(*args):
+            y, states = form(*args)
+            return (y.astype(jnp.float32) * w.astype(jnp.float32)).sum(), (y, states)
+
+        grads, out = jax.jit(jax.grad(loss, argnums=range(6), has_aux=True))(x, dt, a, bm, cm, d)
+        return (*out, *grads)
+
+    kernels = run(lambda x, dt, a, bm, cm, d: ssd.ssd(
+        x, dt, a, bm, cm, d, chunk, interpret=not on_tpu))
+    chunked = run(lambda x, dt, a, bm, cm, d: ssd.ssd_chunked(
+        x, dt, ssd.chunk_log_decay(dt, a, chunk), bm, cm, d, chunk))
+    errs = {}
+    for name, got, want in zip(("y", "states", "dx", "ddt", "dA", "dB", "dC", "dD"),
+                               kernels, chunked):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"ssd {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"ssd kernels vs chunked form beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": [b, t, h, p, n], "chunk": chunk, "rel_err": errs,
+            "heads_a_slab_and_a_grid_step": list(ssd.head_tile(h, p)),
+            "ssd_path": ssd.ssd_path(t, h, p, 1, chunk)}
+
+
 def _windowed_flash_plan():
     """The tiles of the windowed flash call of the benchmark's window layers,
     (2 x 32, 8192, 128) under a window of 1,024, beside the causal call's at
@@ -256,13 +300,17 @@ def _flash_calls_by_cell(on_tpu):
     chips as one chip's rows on one device: the kernels see the same
     operands). On a TPU every cell runs the flash path, and there each
     forward call has one backward call beside it, `...bwd_fused`, and none
-    is `...bwd_dq` or `...bwd_dkv`."""
+    is `...bwd_dq` or `...bwd_dkv`; a layer that is a scan over a state
+    (`mamba` in the configuration's `layer_types`) has ssd_bwd once in place
+    of the flash pair, and ssd_fwd once where the step's remat plan saves
+    the scan's outputs (`ssm_y`) and twice where it does not."""
     import collections
     import re
 
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models import remat
     from ray_tpu.ops import attention
     from ray_tpu.parallel.mesh import make_mesh
     from ray_tpu.parallel.train_step import TrainStep
@@ -282,8 +330,12 @@ def _flash_calls_by_cell(on_tpu):
         kinds = collections.Counter()
         for k, n in found.items():
             kinds[k.removesuffix(attention.LEGACY_NAMES).rsplit("_bwd_", 1)[-1]] += n
-        if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer):
-            raise RuntimeError(f"{name}: {cfg.n_layer} layers, flash calls {calls[name]}")
+        scans = list(getattr(cfg, "layer_types", ())).count("mamba")
+        scan_fwd = scans * (1 if scans and "ssm_y" in remat.traced(cfg).names else 2)
+        if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans
+                           and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans):
+            raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans, "
+                               f"calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:
             raise RuntimeError(f"{name}: a backward call for one gradient alone: {calls[name]}")
     return calls
@@ -324,6 +376,7 @@ def one_chip_loop(config):
     report["flash_vs_xla"] = [
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
         for shape in config["attn_shapes"]]
+    report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()
     report["remat_plans"] = _remat_plans()

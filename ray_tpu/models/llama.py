@@ -151,8 +151,10 @@ class LlamaAttention(nn.Module):
     (models/mellum.py). A layer of a model whose layers differ in kind says
     how it differs: `window` keys a query sees (None: all before it), its
     own rotary table `inv_freq` and the factor on the table's cos and sin,
-    `qk_norm` an RMSNorm over each head of q and k before the rotary, and
-    `select`, a module that names the keys each query sees from the layer's
+    `qk_norm` an RMSNorm over each head of q and k before the rotary,
+    `rotary` False for a layer with no positional encoding at all, `q_scale`
+    a further factor on q (the kernel fixes the scores' 1/sqrt(head_dim);
+    models/granite.py: a published multiplier in its place), and `select`, a module that names the keys each query sees from the layer's
     input: `select(x, pos_offset)` gives (packed mask, its transpose, keys a
     query at most) or None where every key before a query is seen
     (models/mellum.py:Indexer)."""
@@ -163,6 +165,8 @@ class LlamaAttention(nn.Module):
     rope_scale: float = 1.0
     qk_norm: bool = False
     select: Any = None
+    rotary: bool = True
+    q_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
@@ -178,10 +182,13 @@ class LlamaAttention(nn.Module):
             k = RMSNorm(cfg.rms_eps, name="k_norm")(k)
         chosen = None if self.select is None else self.select(x, pos_offset)
 
-        positions = jnp.arange(T) + pos_offset
-        ang = rope_angles(hd, cfg.rope_theta, positions, self.inv_freq)
-        q = apply_rope(q, ang, self.rope_scale)
-        k = apply_rope(k, ang, self.rope_scale)
+        if self.rotary:
+            positions = jnp.arange(T) + pos_offset
+            ang = rope_angles(hd, cfg.rope_theta, positions, self.inv_freq)
+            q = apply_rope(q, ang, self.rope_scale)
+            k = apply_rope(k, ang, self.rope_scale)
+        if self.q_scale != 1.0:
+            q = q * self.q_scale
 
         if cfg.n_kv_head != cfg.n_head:
             rep = cfg.n_head // cfg.n_kv_head

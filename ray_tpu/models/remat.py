@@ -63,9 +63,10 @@ class StepShape(NamedTuple):
 class RematPlan(NamedTuple):
     """`names` saved across remat; the bytes they hold a layer and over all
     layers on one chip; the step's reckoned total with them; the limit that
-    total was held to (None: no chip said one, first rung alone); and of the
+    total was held to (None: no chip said one, first rung alone); of the
     saved bytes those of `attn_sel`, a layer's selection of keys (0 where no
-    layer selects)."""
+    layer selects); and what the family stated one block's backward works
+    in (`Held.block`; 0 where it states none)."""
 
     names: Tuple[str, ...]
     layer_bytes: int
@@ -73,6 +74,7 @@ class RematPlan(NamedTuple):
     reckoned_bytes: int
     limit_bytes: Optional[int]
     sel_bytes: int = 0
+    block_bytes: int = 0
 
 
 def step_shape(batch_shape, axis_sizes) -> StepShape:
@@ -178,7 +180,8 @@ def plan(rungs, name_bytes: Dict[str, int], n_layer: int, held: Held,
     names = first_rung + tuple(n for i in taken for n in rungs[i][0])
     layer = layer_bytes(taken)
     return RematPlan(names, layer, n_layer * layer, held.total(n_layer * layer), room,
-                     n_layer * name_bytes.get("attn_sel", 0) if "attn_sel" in names else 0)
+                     n_layer * name_bytes.get("attn_sel", 0) if "attn_sel" in names else 0,
+                     held.block)
 
 
 _traced = None  # (the configuration, its RematPlan) of the newest trace

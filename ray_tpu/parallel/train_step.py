@@ -113,7 +113,7 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
     """Instantiate the model wired for this mesh: shard_map'd attention on
     more than one device (ring attention iff sp > 1) and the residual
     stream's sharding there (none on one device); config type picks the
-    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum)."""
+    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum / Granite)."""
     import dataclasses
 
     if mesh is not None and cfg.attn_fn is None and mesh.devices.size > 1 and (
@@ -121,6 +121,7 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
     ):
         cfg = dataclasses.replace(cfg, attn_fn=attn_for_mesh(mesh))
     from ray_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEConfig
+    from ray_tpu.models.granite import Granite, GraniteConfig
     from ray_tpu.models.llama import Llama, LlamaConfig
     from ray_tpu.models.mellum import Mellum, MellumConfig
 
@@ -131,11 +132,14 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
         return Llama(cfg, stream)
     if isinstance(cfg, MellumConfig):
         return Mellum(cfg, stream)
+    if isinstance(cfg, GraniteConfig):
+        return Granite(cfg, stream)
     return GPT2(cfg, stream)
 
 
 def default_rules_for(cfg) -> ShardingRules:
     from ray_tpu.models.gpt2_moe import GPT2_MOE_SHARDING_RULES, GPT2MoEConfig
+    from ray_tpu.models.granite import GRANITE_SHARDING_RULES, GraniteConfig
     from ray_tpu.models.llama import LLAMA_SHARDING_RULES, LlamaConfig
     from ray_tpu.models.mellum import MELLUM_SHARDING_RULES, MellumConfig
 
@@ -145,6 +149,8 @@ def default_rules_for(cfg) -> ShardingRules:
         return LLAMA_SHARDING_RULES
     if isinstance(cfg, MellumConfig):
         return MELLUM_SHARDING_RULES
+    if isinstance(cfg, GraniteConfig):
+        return GRANITE_SHARDING_RULES
     return GPT2_SHARDING_RULES
 
 
@@ -171,14 +177,18 @@ class TrainStep:
         telemetry: bool = True,
     ):
         from ray_tpu.models.gpt2_moe import GPT2MoEConfig
+        from ray_tpu.models.granite import GraniteConfig
         from ray_tpu.models.mellum import MellumConfig
 
         self._is_moe = isinstance(model_cfg, GPT2MoEConfig)
-        # A dropless expert layer adds no term to the loss; the rows its held
-        # experts worked on (the "moe_load" collection), and the keys a query
-        # kept where a layer selects them ("attn_keys"), go out with the
-        # step's metrics, for the telemetry.
-        self._reports_moe_load = isinstance(model_cfg, MellumConfig)
+        # What a family's layers sow for the telemetry, collections that go
+        # out with the step's metrics. A dropless expert layer adds no term to
+        # the loss: the rows its held experts worked on ("moe_load"), and the
+        # keys a query kept where a layer selects them ("attn_keys"). A
+        # state-space layer: how far a chunk decays and how large its carried
+        # state grows ("ssm_stats").
+        self._sown = (["moe_load", "attn_keys"] if isinstance(model_cfg, MellumConfig)
+                      else ["ssm_stats"] if isinstance(model_cfg, GraniteConfig) else [])
         if rules is None:
             rules = default_rules_for(model_cfg)
         self.model_cfg = model_cfg
@@ -237,11 +247,10 @@ class TrainStep:
                         {"params": params}, batch["idx"], mutable=["losses"]
                     )
                     aux = sum(jax.tree.leaves(lstate.get("losses", {})))
-                elif self._reports_moe_load:
-                    logits, sown = self.model.apply(
-                        {"params": params}, batch["idx"], mutable=["moe_load", "attn_keys"]
-                    )
-                    aux, loads = 0.0, sown
+                elif self._sown:
+                    logits, loads = self.model.apply(
+                        {"params": params}, batch["idx"], mutable=self._sown)
+                    aux = 0.0
                 else:
                     logits = self.model.apply({"params": params}, batch["idx"])
                     aux = 0.0
@@ -261,7 +270,14 @@ class TrainStep:
                 "step": state["step"] + 1,
             }
             metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
-            if loads is not None:
+            if "ssm_stats" in (loads or {}):
+                stats = [layer["mamba"] for period in loads["ssm_stats"].values()
+                         for layer in period.values()]  # the mamba layers alone sow
+                metrics["ssm_chunk_log_decay_min"] = jnp.min(jnp.stack(
+                    [s["chunk_log_decay_min"][0] for s in stats]))
+                metrics["ssm_state_abs_max"] = jnp.max(jnp.stack(
+                    [s["state_abs_max"][0] for s in stats]))
+            elif loads is not None:
                 from ray_tpu.ops.moe import moe_load_metrics
 
                 metrics.update(moe_load_metrics(
