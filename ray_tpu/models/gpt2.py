@@ -33,6 +33,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import remat
+from ray_tpu.models.loss import loss_fn  # noqa: F401
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
@@ -182,12 +183,6 @@ class GPT2(nn.Module):
         # weight-tied head
         logits = wte.attend(x.astype(jnp.float32))
         return logits
-
-
-def loss_fn(logits, targets):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -ll.mean()
 
 
 def init_params(config: GPT2Config, rng=None):

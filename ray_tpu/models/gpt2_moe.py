@@ -21,8 +21,8 @@ from ray_tpu.models.gpt2 import (
     GPT2_SHARDING_PATTERNS,
     CausalSelfAttention,
     MLP,
-    loss_fn,
 )
+from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops.moe import MOE_SHARDING_PATTERNS, MoE, MoEConfig
 from ray_tpu.parallel.mesh import ShardingRules, pin
 

@@ -56,7 +56,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import remat
 from ray_tpu.models.llama import (  # noqa: F401
-    LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm, loss_fn)
+    LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm)
 from ray_tpu.ops.ssd import ssd
 from ray_tpu.parallel.mesh import ShardingRules, pin
 

@@ -44,6 +44,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import remat
+from ray_tpu.models.loss import loss_fn  # noqa: F401
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
 
@@ -289,12 +290,6 @@ class Llama(nn.Module):
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           name="lm_head")(x.astype(jnp.float32))
         return logits
-
-
-def loss_fn(logits, targets):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -ll.mean()
 
 
 def init_params(config: LlamaConfig, rng=None):

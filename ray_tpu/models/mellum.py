@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import remat
 from ray_tpu.models.llama import (  # noqa: F401
-    LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, apply_rope, loss_fn, rope_angles)
+    LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, apply_rope, rope_angles)
 from ray_tpu.ops import indexer
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin

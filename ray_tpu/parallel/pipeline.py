@@ -35,7 +35,8 @@ import optax
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.models.gpt2 import GPT2Config, Block, loss_fn
+from ray_tpu.models.gpt2 import GPT2Config, Block
+from ray_tpu.models.loss import loss_fn
 
 
 def _stack_layers(per_layer_params):

@@ -36,8 +36,8 @@ from ray_tpu.models.gpt2 import (
     GPT2,
     GPT2Config,
     GPT2_SHARDING_RULES,
-    loss_fn,
 )
+from ray_tpu.models.loss import loss_fn
 from ray_tpu.parallel.mesh import (
     ShardingRules,
     batch_sharding,

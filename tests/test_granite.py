@@ -19,6 +19,7 @@ from bench import families
 from ray_tpu.models import granite, remat
 from ray_tpu.models.granite import ATTENTION, MAMBA, Granite, GraniteConfig
 from ray_tpu.models.llama import LlamaAttention
+from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops import attention, ssd
 from ray_tpu.parallel.mesh import make_mesh
 from ray_tpu.parallel.train_step import TrainStep
@@ -46,7 +47,7 @@ def _batch(vocab, rows=2, t=64, seed=0):
 
 
 def _loss(cfg, params, idx, targets):
-    return granite.loss_fn(Granite(cfg).apply({"params": params}, idx), targets)
+    return loss_fn(Granite(cfg).apply({"params": params}, idx), targets)
 
 
 @pytest.fixture(scope="module")

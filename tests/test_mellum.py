@@ -22,7 +22,8 @@ import pytest
 
 from bench import families
 from ray_tpu.models import mellum, remat
-from ray_tpu.models.mellum import Mellum, MellumConfig, YarnScaling, loss_fn, yarn_inv_freq
+from ray_tpu.models.loss import loss_fn
+from ray_tpu.models.mellum import Mellum, MellumConfig, YarnScaling, yarn_inv_freq
 from ray_tpu.ops import attention
 from ray_tpu.ops.moe import ExpertShare
 from ray_tpu.parallel.mesh import make_mesh
@@ -211,17 +212,18 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
 # Each cell's configuration at its cell's shape (rows a chip, sequence
 # length) on its cell's mesh, with the rule of models/remat.py given a v5e's
 # limit, so that the program is the cell's own: sha256 of the step as
-# `_step_text` gives it, taken on the commit that made the flash backward
-# one call (PR 35: the programs changed by design there, as in PR 33, and
-# were pinned again for the next PR that means to leave them alone); the layers
+# `_step_text` gives it, taken on the commit that wrote the loss as
+# logsumexp less the target's logit (PR 37: the loss is in every program, so
+# all changed by design there, as in PR 33 and PR 35, and were pinned again
+# for the next PR that means to leave them alone); the layers
 # whose attention is windowed, of all; the names the rule saves there after
 # the first rung.
 PINNED_STEPS = {
-    "gpt2_small": ("911a3964235031b16efa92e987b33605747478354ab50aa59d6230e157028b0b", 32, 1024, 0, 12,
+    "gpt2_small": ("add2929d62f443901835f8e4201ce13b3f726688acfccf417674e0cb6da52e89", 32, 1024, 0, 12,
                    ("attn_q", "attn_k", "attn_v", "mlp_up")),
-    "mistral_7b_l8": ("29e9611be5444f3e6f076a3ff813c04c7f07d1a1c1e9425aab20bf615e04f0d4", 1, 8192, 0, 8,
+    "mistral_7b_l8": ("5f811bf68e55105a72f1c84e52a74870982bbc39e8a4bcfce632b077cca36547", 1, 8192, 0, 8,
                       ("mlp_up",)),
-    "mellum2_12b_l4_ep4": ("9fca5da34f835e58b6e46a47c3e9cac15532be8e89fe1eb73479efe3fd2092e9", 2, 8192, 3, 4,
+    "mellum2_12b_l4_ep4": ("14161c0e77bdc486b87eda02171041a88c50aba762cf08347edfa77cecfb5a9d", 2, 8192, 3, 4,
                            ("attn_q", "attn_k", "attn_v")),
 }
 

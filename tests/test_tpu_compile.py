@@ -103,6 +103,23 @@ def _kernel_calls(compiled_text):
     return dict(counts)
 
 
+_OPCODE = re.compile(r" [a-z][a-z0-9\-]*\(")
+_ARRAY = re.compile(r"\b[a-z]+\d+\[[\d,]*\]")
+
+
+def _entry_results(compiled_text):
+    """The array types (`f32[16,1024,50257]`) that the instructions of the
+    entry computation have as results, tuples' elements each, counted: what
+    the program writes. A shape inside a fused computation is a value that
+    never leaves the fusion."""
+    entry = compiled_text[compiled_text.index("\nENTRY "):]
+    counts = collections.Counter()
+    for line in entry[:entry.index("\n}")].splitlines()[2:]:
+        result = line.split(" = ", 1)[1]
+        counts.update(_ARRAY.findall(result[:_OPCODE.search(result).start()]))
+    return counts
+
+
 def test_flash_forward_compiles(one_chip):
     c = jax.jit(attention.flash_causal_attention).lower(
         *_qkv((16, 12, 1024, 64), one_chip)).compile()
@@ -155,6 +172,14 @@ def test_causal_attention_under_mesh_keeps_kernel(mesh_2x2, monkeypatch):
 
 @pytest.mark.timeout(300)
 def test_gpt2_124m_step_fits_one_chip(topo, monkeypatch):
+    """The whole step at published widths fits a chip, runs each kernel once
+    a layer, and writes the logits once, in bf16: the loss is logsumexp less
+    the target's logit (models/loss.py), so no float32 array of the logits'
+    size is written (log_softmax's result was one, 14.8 ms of the `t256`
+    cell's step, and its backward another pass over it). The trap this
+    holds: a loss that gathers the target's logit from
+    `logits.astype(float32)` makes XLA write the float32 logits as a second
+    result of the head's matmul, 6 GiB at the cell's shape."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     mesh = Mesh(np.array(topo.devices[:1]), ("dp",))
     ts = TrainStep(GPT2Config.gpt2_124m(), mesh, telemetry=False)
@@ -163,9 +188,12 @@ def test_gpt2_124m_step_fits_one_chip(topo, monkeypatch):
     # one forward and one backward kernel in each of 12 remat'd layers:
     # the forward's output and logsumexp are saved across the remat
     # (models/remat.py), so it is not run again
-    assert c.as_text().count("tpu_custom_call") == 24
-    assert _kernel_calls(c.as_text()) == dict.fromkeys(KERNELS, 12)
-    assert c.as_text().startswith("HloModule jit_train_step")
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 24
+    assert _kernel_calls(text) == dict.fromkeys(KERNELS, 12)
+    assert text.startswith("HloModule jit_train_step")
+    written = _entry_results(text)
+    assert written["bf16[16,1024,50257]"] and not written["f32[16,1024,50257]"], written
 
 
 @pytest.mark.timeout(300)
