@@ -60,7 +60,10 @@ bytes, hex-encoded at dump time) and a short detail string/number.
   collective.enter / collective.exit   gloo-style CPU collective ops
   train.step                     a step program completed on the device:
                                  (optimizer steps so far, seconds from the
-                                 completion before it to its own)
+                                 completion before it to its own); a step
+                                 flagged slow: (seconds, then what the host
+                                 did over it: seconds on a run queue,
+                                 stolen, waiting on I/O), where /proc says
   train.dispatch                 TrainStep enqueued a step program:
                                  (step number, optimizer steps in it) —
                                  "did step N ever start" for a hung mesh
@@ -71,6 +74,14 @@ bytes, hex-encoded at dump time) and a short detail string/number.
                                  bytes a layer, in all, the step's
                                  reckoned bytes, the limit held to):
                                  models/remat.py:RematPlan
+  train.device_profile           a device-trace window was reduced
+                                 (train/_device_profile.py): (steps in it,
+                                 (busy ms a step, idle share, seconds the
+                                 reduction took, then the shares of busy:
+                                 remat, optimizer, head and loss, copy,
+                                 unscoped, then the TPU planes it read: 0
+                                 where the times are the host's thunks and
+                                 no device's))
   serve.request                  one replica-side serve request finished
   llm.admit / llm.preempt / llm.finish   serve/llm engine sequence
                                  lifecycle (admit carries the prompt

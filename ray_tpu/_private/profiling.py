@@ -15,7 +15,7 @@ import sys
 import threading
 import time
 from collections import Counter
-from typing import Dict
+from typing import Dict, List, Optional
 
 
 def _frame_label(frame) -> str:
@@ -304,17 +304,48 @@ def register_capture(gcs, path: str, *, reason: str, extra=None) -> None:
         pass
 
 
-def register_device_trace(gcs, path: str, *, steps: int) -> None:
+def register_device_trace(gcs, path: str, *, steps: int, profile=None,
+                          key: Optional[str] = None) -> Optional[str]:
+    """Record a device-trace directory; the key it is kept under, so that
+    the same record can be put again once the trace is reduced to a device
+    profile (train/_device_profile.py:brief: the profile's path, its shares
+    and its largest (group, pass) rows)."""
     import json
     import time
 
     rec = {"path": path, "steps": steps, "host": _hostname(),
            "time": time.time()}
+    if profile is not None:
+        rec["profile"] = profile
+    key = key or f"device_trace:{rec['time']:.6f}"
     try:
-        gcs.kv_put(_CAPTURE_NS, f"device_trace:{rec['time']:.6f}".encode(),
-                   json.dumps(rec).encode())
+        gcs.kv_put(_CAPTURE_NS, key.encode(), json.dumps(rec).encode())
     except Exception:
-        pass
+        return None
+    return key
+
+
+def describe_device_trace(rec: dict) -> List[str]:
+    """A registered device trace as lines for a terminal: the directory, and
+    where the window was reduced what the step's time went to."""
+    lines = [f"device trace of {rec.get('steps', 0)} steps on "
+             f"{rec.get('host', '')}: {rec.get('path', '')}"]
+    prof = rec.get("profile")
+    if not prof:
+        return lines + ["  (not reduced to a device profile)"]
+    on = f"{prof.get('devices', 0)} x {prof.get('platform')}"
+    if prof.get("platform") != "tpu":
+        on += " (XLA's host thunks: no device's time)"
+    lines.append(f"  {prof.get('busy_ms', 0.0):.3f} ms busy a step over "
+                 f"{prof.get('steps', 0)} steps on {on}, table "
+                 f"{prof.get('table')}: {prof.get('path')}")
+    for group, which, ms, share in prof.get("top", []):
+        lines.append(f"    {group:<12}{which:<8}{ms:>10.3f} ms {100 * share:>6.2f}%")
+    shares = [f"{k[:-6]} {100 * v:.2f}%" for k, v in prof.items()
+              if k.endswith("_share")]
+    if shares:
+        lines.append("    of busy: " + ", ".join(shares))
+    return lines
 
 
 def list_registered(gcs, kind: str = "capture", limit: int = 20) -> list:

@@ -270,8 +270,9 @@ def merged_profile_trace(bundle: dict, task_events: Optional[List[dict]] = None,
     events += profile_trace_events(bundle)
     for dt in device_traces or []:
         # The device trace itself is a TensorBoard/XPlane directory — too
-        # alien to inline, so mark WHEN it was captured and WHERE it lives;
-        # open it with `tensorboard --logdir` / xprof for the device view.
+        # alien to inline, so mark WHEN it was captured and WHERE it lives,
+        # with what the program reduced it to (its device profile's largest
+        # rows: train/_device_profile.py).
         events.append({
             "cat": "device_trace",
             "name": "jax_device_trace",
@@ -281,7 +282,8 @@ def merged_profile_trace(bundle: dict, task_events: Optional[List[dict]] = None,
             "pid": "device_traces",
             "tid": dt.get("host", "") or "host",
             "args": {"path": dt.get("path", ""),
-                     "steps": dt.get("steps", 0)},
+                     "steps": dt.get("steps", 0),
+                     "profile": dt.get("profile")},
         })
     events.sort(key=lambda e: e["ts"])
     return {

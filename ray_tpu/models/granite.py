@@ -323,9 +323,12 @@ class Granite(nn.Module):
         for i in range(cfg.n_layer // cfg.period):
             x = GranitePeriod(cfg, keep, self.stream, name=f"p_{i}")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        # the tied head in float32, as models/llama.py's untied one
-        logits = x.astype(jnp.float32) @ emb.embedding.astype(jnp.float32).T
-        return logits / cfg.logits_scaling
+        # the tied head in float32, as models/llama.py's untied one, and under
+        # that one's name: written at the model's top level it would carry no
+        # scope, and the device profile (train/_device_profile.py) no head
+        with jax.named_scope("lm_head"):
+            logits = x.astype(jnp.float32) @ emb.embedding.astype(jnp.float32).T
+            return logits / cfg.logits_scaling
 
 
 GRANITE_SHARDING_RULES = ShardingRules([

@@ -237,8 +237,11 @@ class TrainStep:
 
         # The jitted functions are named for what they are (the trace's
         # "XLA Modules" line reads jit_train_step), and the loss and the
-        # optimizer are scopes beside the ones flax gives the model's
-        # modules: xprof groups device time by them.
+        # optimizer (with the gradients' global norm) are scopes beside the
+        # ones flax gives the model's modules: every instruction of the
+        # compiled step carries its scope and its pass in its op_name, and a
+        # device-trace window is reduced to ms a step by them
+        # (train/_device_profile.py).
         def train_step(state, batch):
             def loss_of(params):
                 loads = None
@@ -269,7 +272,9 @@ class TrainStep:
                 "opt_state": opt_state,
                 "step": state["step"] + 1,
             }
-            metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
+            with jax.named_scope("optimizer"):
+                grad_norm = optax.global_norm(grads)
+            metrics = {"loss": loss, "grad_norm": grad_norm}
             if "ssm_stats" in (loads or {}):
                 stats = [layer["mamba"] for period in loads["ssm_stats"].values()
                          for layer in period.values()]  # the mamba layers alone sow
@@ -341,7 +346,8 @@ class TrainStep:
         step = self.dispatched_steps
         if rec is not None:
             # Device-trace hook (_telemetry.DeviceTraceController): inert
-            # two-attribute check unless a jax.profiler window was armed.
+            # two-attribute check unless a jax.profiler window was armed; a
+            # window that closes is reduced to a device profile off this thread.
             rec.device_trace.on_step_begin()
             started = rec.clock()
         with TraceAnnotation("ray_tpu.train_step.dispatch", step=step):

@@ -439,6 +439,8 @@ def _cluster_profile(args, gcs):
     print(f"wrote {len(trace['traceEvents'])} events "
           f"({n_samples} CPU samples from {n_profiles} processes) to {out}")
     print("open in https://ui.perfetto.dev or chrome://tracing")
+    for rec in device:
+        print("\n".join(profiling.describe_device_trace(rec)))
 
 
 def cmd_grafana(args):

@@ -52,7 +52,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -112,6 +112,28 @@ def _finished(handle) -> bool:
         return not leaves or any(x.is_ready() for x in leaves)
     except RuntimeError:
         return True
+
+
+_TICK_S = 1.0 / os.sysconf("SC_CLK_TCK") if hasattr(os, "sysconf") else 0.01
+
+
+def _host_pressure() -> Optional[Tuple[float, float, float]]:
+    """Seconds so far that this process waited on a run queue
+    (/proc/self/schedstat, second field, ns) and that the machine's CPUs
+    spent stolen by the hypervisor and waiting on I/O (/proc/stat, first
+    line, ticks). None off Linux, and under a sandbox kernel that keeps no
+    such file (gVisor). The watcher reads it at each completion, so a step
+    that was slow says whether the host was: two small reads a step, on a
+    thread that is otherwise blocked; a watcher whose read found nothing
+    does not ask again (``StepRecorder._host_delta``)."""
+    try:
+        with open("/proc/self/schedstat") as f:
+            waited = int(f.read().split()[1]) / 1e9
+        with open("/proc/stat") as f:
+            cpu = f.readline().split()
+        return waited, int(cpu[8]) * _TICK_S, int(cpu[5]) * _TICK_S
+    except (OSError, IndexError, ValueError):
+        return None
 
 
 # The watcher thread ends after this long with nothing in flight, so an
@@ -225,6 +247,9 @@ class StepRecorder:
         self._watcher: Optional[threading.Thread] = None
         self._closing = False
         self._last_done = self._start
+        # _host_pressure() at the newest completion; False once a read found
+        # nothing (this kernel says nothing: not asked again)
+        self._pressure = None
         self._last_step_s = 0.0
         self._metrics = None
         self._hbm_bytes: Dict[str, float] = {}
@@ -323,6 +348,7 @@ class StepRecorder:
                     jax.block_until_ready(entry.handle)
                 done = self._clock()
                 self._read_gauges(entry.handle)
+                host = self._host_delta()
                 if entry.compile_step:
                     self.record_step(entry.enqueued - entry.started,
                                      steps=entry.steps, compile_step=True)
@@ -332,7 +358,7 @@ class StepRecorder:
                     self.record_step(
                         done - max(entry.started, self._last_done),
                         steps=entry.steps, tokens=entry.tokens,
-                        examples=entry.examples, flops=entry.flops)
+                        examples=entry.examples, flops=entry.flops, host=host)
             except Exception:
                 # the loop's own wait on this step raises the same error;
                 # the step is not booked and the clock restarts at the next
@@ -356,6 +382,17 @@ class StepRecorder:
         if found:
             with self._lock:
                 self.step_gauges = found
+
+    def _host_delta(self) -> Optional[Tuple[float, ...]]:
+        """What the host did to this process since the completion before: a
+        slow step's record says whether the host was slow."""
+        if self._pressure is False:
+            return None
+        before, now = self._pressure, _host_pressure()
+        self._pressure = False if now is None else now
+        if before is None or now is None:
+            return None
+        return tuple(b - a for a, b in zip(before, now))
 
     def _stop_watcher(self) -> None:
         with self._pending_cond:
@@ -395,6 +432,7 @@ class StepRecorder:
         flops: Optional[float] = None,
         compile_step: bool = False,
         start_wall: Optional[float] = None,
+        host: Optional[Tuple[float, float, float]] = None,
     ) -> None:
         """Record ``steps`` finished optimizer steps that took ``duration_s``
         in total, to completion (a call timed to its return at enqueue goes
@@ -402,17 +440,25 @@ class StepRecorder:
         whose duration is compile + one step — it's booked as compile time,
         not productive step time, so MFU/throughput aren't poisoned by it.
         ``flops`` is the model FLOPs of these steps where the caller knows
-        them; otherwise flops_per_step or flops_per_token x tokens."""
+        them; otherwise flops_per_step or flops_per_token x tokens. ``host``
+        is what ``_host_pressure`` read over these steps (seconds on a run
+        queue, stolen, waiting on I/O): a step flagged slow carries it."""
         duration_s = max(0.0, float(duration_s))
         from ray_tpu._private import flight_recorder as _fr
 
         with self._lock:
             self.steps += steps
             self._last_step_at = self._clock()
+            per_step = duration_s / max(steps, 1)
+            med = self._median_cache
+            slow = (not compile_step and self._slow_factor > 0 and med is not None
+                    and med > 0 and per_step > self._slow_factor * med)
             # numbers, not text: nothing is formatted before a dump
             detail = duration_s
             if compile_step and self.remat_plan is not None:
                 detail = (duration_s, *self.remat_plan)
+            elif slow and host is not None:
+                detail = (duration_s, *host)
             _fr.record("train.compile" if compile_step else "train.step",
                        self.steps, detail)
             if compile_step:
@@ -434,15 +480,12 @@ class StepRecorder:
             else:
                 self.productive_s += duration_s
                 self.productive_steps += steps
-                per_step = duration_s / max(steps, 1)
                 self._last_step_s = per_step
-                # flag BEFORE appending: the outlier must not dilute the
-                # median it is judged against. The median itself refreshes
-                # every 8 steps — a per-step O(1) compare, not a per-step
-                # sort (this path runs at millisecond step times).
-                med = self._median_cache
-                if (self._slow_factor > 0 and med is not None and med > 0
-                        and per_step > self._slow_factor * med):
+                # flagged (above) BEFORE appending: the outlier must not
+                # dilute the median it is judged against. The median itself
+                # refreshes every 8 steps — a per-step O(1) compare, not a
+                # per-step sort (this path runs at millisecond step times).
+                if slow:
                     self._slow_step = {
                         "step": self.steps,
                         "duration_s": per_step,
@@ -450,6 +493,11 @@ class StepRecorder:
                         "ratio": per_step / med,
                         "time": self._wall(),
                     }
+                    if host is not None:
+                        # why, as far as the process can see: the host's
+                        # share of the step (absent off Linux)
+                        self._slow_step.update(zip(
+                            ("sched_wait_s", "steal_s", "iowait_s"), host))
                 self._recent_steps.append(per_step)
                 self._steps_since_median += 1
                 if (self._steps_since_median >= 8
@@ -476,10 +524,6 @@ class StepRecorder:
         if self._emit_spans:
             self._emit_step_span(duration_s, steps, tokens, compile_step,
                                  start_wall)
-
-    def step_timer(self):
-        """Context manager measuring one step call: ``with rec.step_timer():``"""
-        return _StepTimer(self)
 
     def seconds_since_last_step(self) -> Optional[float]:
         """Age of the newest recorded step; None before the first step.
@@ -586,6 +630,8 @@ class StepRecorder:
             out["hbm_bytes_in_use"] = max(hbm.values())
         if self.remat_plan is not None:
             out["remat_saved_bytes"] = self.remat_plan.saved_bytes
+        if self.device_trace.profile is not None:
+            out["device_profile"] = self.device_trace.profile
         return out
 
     # ------------------------------------------------------------ emission
@@ -710,38 +756,16 @@ class StepRecorder:
             pass
 
 
-class _StepTimer:
-    def __init__(self, recorder: StepRecorder):
-        self._rec = recorder
-        self._t0 = None
-        self._w0 = None
-        self.tokens: Optional[int] = None
-        self.examples: Optional[int] = None
-        self.steps = 1
-        self.compile_step = False
-
-    def __enter__(self):
-        self._t0 = self._rec._clock()
-        self._w0 = self._rec._wall()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        if exc_type is None:
-            self._rec.record_step(
-                self._rec._clock() - self._t0,
-                steps=self.steps, tokens=self.tokens, examples=self.examples,
-                compile_step=self.compile_step, start_wall=self._w0,
-            )
-        return False
-
-
 # ------------------------------------------------------ device-trace window
 # The host-side sampler (profiling plane) sees Python; XLA device time is a
 # black box to it. This controller arms ``jax.profiler.trace`` around a
-# window of N train steps — TrainStep calls on_step_begin/on_step_end around
-# each dispatch — and registers the produced trace directory with the GCS so
-# the merged Perfetto timeline links to it (open with `tensorboard
-# --logdir` / xprof for the device view).
+# window of N train steps (TrainStep calls on_step_begin/on_step_end around
+# each dispatch). When the window closes the trace is reduced to a device
+# profile (train/_device_profile.py: ms a step by the program's own scopes
+# and passes), written as device_profile.json beside the raw trace, and the
+# record registered with the GCS carries its path and largest rows, so the
+# listings print numbers; the recorder's summary and a flight-recorder event
+# carry them too.
 
 
 class DeviceTraceController:
@@ -758,6 +782,11 @@ class DeviceTraceController:
         from ray_tpu._private.config import RTPU_CONFIG
 
         self._armed = max(0, int(RTPU_CONFIG.device_trace_steps))
+        # What the newest closed window reduced to (_device_profile.brief),
+        # the child process that reduces one and the thread that waits for it.
+        self.profile: Optional[Dict[str, Any]] = None
+        self._reducer: Optional[threading.Thread] = None
+        self._child = None
 
     # ------------------------------------------------------------- control
 
@@ -850,18 +879,67 @@ class DeviceTraceController:
                 jax.profiler.stop_trace()
             except Exception:
                 return
-        self._register(path)
+        # the directory is listed at once; its numbers follow under the same
+        # key when the trace is reduced. That takes seconds and holds an
+        # interpreter: a child process does it, and a daemon thread waits for
+        # the child, for a bounded time (_device_profile.CHILD_WAIT_S)
+        key = self._register(path, None, None)
+        self._reducer = threading.Thread(
+            target=self._reduce, args=(path, key), name="device-profile", daemon=True)
+        atexit.register(self._stop_reducer)
+        self._reducer.start()
 
-    def _register(self, path: str) -> None:
+    def wait_profile(self, timeout_s: Optional[float] = None) -> Optional[Dict[str, Any]]:
+        """The newest window's profile, once its reduction has ended."""
+        reducer = self._reducer
+        if reducer is not None:
+            reducer.join(timeout_s)
+        return self.profile
+
+    def _reduce(self, path: str, key: Optional[str]) -> None:
+        """What the window held (train/_device_profile.py), said four ways: a
+        file beside the trace, the GCS record, the recorder's summary, one
+        flight-recorder event."""
+        try:
+            from ray_tpu._private import flight_recorder as _fr
+            from ray_tpu.train import _device_profile
+
+            self._child = _device_profile.start_child(path)
+            profile = _device_profile.profile_from_child(self._child, path)
+            self.profile = _device_profile.brief(profile)
+            # numbers, not text: nothing is formatted before a dump
+            _fr.record("train.device_profile", profile["steps"], (
+                profile["busy_ms"], profile["idle_share"], profile["reduce_s"],
+                *profile["shares"].values(),
+                profile["devices"] if profile["platform"] == "tpu" else 0))
+            self._register(path, self.profile, key)
+        except Exception:
+            logger.warning("device trace %s was not reduced", path, exc_info=True)
+        finally:
+            self._child = None
+            atexit.unregister(self._stop_reducer)
+
+    def _stop_reducer(self) -> None:
+        """At interpreter exit: a reduction under way is given the watcher's
+        few seconds, then its child is ended with the process."""
+        reducer = self._reducer
+        if reducer is not None:
+            reducer.join(_EXIT_WAIT_S)
+        child = self._child
+        if child is not None and child.poll() is None:
+            child.kill()
+
+    def _register(self, path: str, profile, key: Optional[str]) -> Optional[str]:
         try:
             from ray_tpu._private import profiling, worker as worker_mod
 
             w = worker_mod.global_worker
             if w is not None:
-                profiling.register_device_trace(
-                    w.gcs, path, steps=self._target)
+                return profiling.register_device_trace(
+                    w.gcs, path, steps=self._target, profile=profile, key=key)
         except Exception:
             pass
+        return None
 
 
 def request_device_trace(num_steps: int = 3,

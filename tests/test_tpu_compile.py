@@ -383,3 +383,43 @@ def test_scan_kernels_compile_at_the_cell_s_shape(one_chip):
         and sum("ssd_bwd" in n for n in names) == 1, names
     states = 16 * 64 * 64 * 128 * 4
     assert states < c.memory_analysis().temp_size_in_bytes < 4 * states
+
+
+@pytest.mark.timeout(300)
+def test_the_scope_table_names_the_ledger_s_ops_of_gpt2_small_t256(topo, monkeypatch):
+    """`gpt2_small.t256`'s step compiled for the described v5e at the cell's
+    shapes, through train/_device_profile.py's table: the two entries the
+    ledger's `device_ops` prints first are named by where the program wrote
+    them. `multiply_reduce_fusion -> (f32[768], f32[128,256], f32[128,256],
+    f32[768], bf16[128,256,768])` is 24 input-gradient matmuls (`c_fc`,
+    `c_attn`, the head) whose name is their epilogue's, a LayerNorm's
+    backward sums; `copy -> bf16[128,12,256,64]` is the layout copies round
+    the flash calls; the Pallas calls are kernels under their own names."""
+    from ray_tpu.models import remat
+    from ray_tpu.train import _device_profile as dp
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * remat.GIB)  # the cell's own plan
+    mesh = Mesh(np.array(topo.devices[:1]), ("dp",))
+    ts = TrainStep(GPT2Config.gpt2_124m(), mesh, telemetry=False)
+    text = ts._step.lower(*_step_args(ts, (128, 256))).compile().as_text()
+    table = dp.scope_table(text)
+    assert table["module"] == "jit_train_step"
+    rows = table["rows"].values()
+    named = "-> (f32[768], f32[128,256], f32[128,256], f32[768], bf16[128,256,768])"
+    fused = collections.Counter((r[0], r[1], r[2], r[3]) for r in rows if r[4].endswith(named))
+    assert fused == {("h/mlp/c_fc", "bwd", "matmul", "mlp"): 12,
+                     ("h/attn/c_attn", "bwd", "matmul", "attn.proj"): 11,
+                     ("wte.attend", "bwd", "matmul", "head"): 1}, fused
+    copies = collections.Counter(
+        (r[0], r[2], r[3]) for r in rows if r[4] == "copy copy -> bf16[128,12,256,64]")
+    assert set(copies) == {("h/attn", "copy", "attn.core")} and sum(copies.values()) >= 48
+    kernels = collections.Counter((r[0], r[1], r[3]) for r in rows if r[2] == "kernel")
+    assert kernels == {("h/attn/flash_fwd", "fwd", "attn.core"): 12,
+                       (f"h/attn/{KERNELS[1]}", "bwd", "attn.core"): 12}, kernels
+    # every matmul of the dense layers and the head is a matmul whatever its
+    # fusion is called, in all three passes, and nothing scheduled is unscoped
+    # but a handful of XLA's own
+    matmuls = collections.Counter(r[1] for r in rows if r[2] == "matmul")
+    assert matmuls == {"fwd": 4 * 12 + 1, "bwd": 8 * 12 + 2, "remat": 12}, matmuls
+    assert sum(r[3] == "unscoped" for r in rows) < 20

@@ -12,6 +12,7 @@ marker — the cluster tests use the tiniest possible model/loops.
 
 import glob
 import json
+import os
 import threading
 import time
 import urllib.request
@@ -314,6 +315,82 @@ def test_completion_clock_times_the_device_not_the_dispatch():
     assert slow is not None and slow["ratio"] == pytest.approx(5.0)
     assert rec.summary()["step_time_s"] == pytest.approx(0.5)
     loop.finish()
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("waited", [2.0, 0.0])
+def test_a_slow_step_says_what_the_host_did_to_it(monkeypatch, waited):
+    """The watcher reads the process's run-queue wait and the machine's
+    steal and iowait at every completion; a step five times the median is
+    flagged with the three deltas over that step, zeros where the host did
+    nothing (the device or the program was slow), and its flight-recorder
+    event carries them."""
+    from ray_tpu._private import flight_recorder
+    from ray_tpu.train import _telemetry
+
+    host = {"sched": 10.0, "steal": 100.0, "iowait": 50.0}
+    monkeypatch.setattr(_telemetry, "_host_pressure",
+                        lambda: (host["sched"], host["steal"], host["iowait"]))
+    clk = FakeClock()
+    rec = _recorder(clk)
+    loop = PipelinedLoop(clk, rec)
+    loop.dispatch()
+    for k in range(1, 21):
+        loop.dispatch()
+        loop.complete_at(0.1 * k)
+    assert rec.pop_slow_step() is None
+    # over the slow step the process stood on a run queue for `waited` seconds
+    # of its 0.5, and a quarter second was stolen from the machine's CPUs
+    host["sched"] += waited
+    host["steal"] += waited / 8
+    loop.dispatch()
+    loop.complete_at(2.5)
+    slow = rec.pop_slow_step()
+    assert slow["ratio"] == pytest.approx(5.0) and slow["duration_s"] == pytest.approx(0.5)
+    assert slow["sched_wait_s"] == pytest.approx(waited)
+    assert slow["steal_s"] == pytest.approx(waited / 8) and slow["iowait_s"] == 0.0
+    assert all(isinstance(v, float) for v in slow.values() if v is not slow["step"])
+    last = [e for e in flight_recorder.dump() if e.get("event") == "train.step"][-1]
+    assert last["b"] == pytest.approx((0.5, waited, waited / 8, 0.0))
+    # the step after it is not slow and carries nothing
+    loop.dispatch()
+    loop.complete_at(2.6)
+    assert rec.pop_slow_step() is None
+    loop.finish()
+
+
+@pytest.mark.fast
+def test_a_slow_step_off_linux_carries_no_host_numbers(monkeypatch):
+    from ray_tpu.train import _telemetry
+
+    reads = []
+    monkeypatch.setattr(_telemetry, "_host_pressure", lambda: reads.append(1))
+    clk = FakeClock()
+    rec = _recorder(clk)
+    loop = PipelinedLoop(clk, rec)
+    loop.dispatch()
+    for k in range(1, 21):
+        loop.dispatch()
+        loop.complete_at(0.1 * k)
+    loop.dispatch()
+    loop.complete_at(2.5)
+    slow = rec.pop_slow_step()
+    assert slow["ratio"] == pytest.approx(5.0)
+    assert not {"sched_wait_s", "steal_s", "iowait_s"} & set(slow)
+    loop.finish()
+    assert len(reads) == 1  # a kernel that says nothing is asked once, not at every step
+
+
+def test_host_pressure_reads_this_machine():
+    from ray_tpu.train import _telemetry
+
+    read = _telemetry._host_pressure()
+    if not os.path.exists("/proc/self/schedstat"):
+        assert read is None
+        return
+    assert len(read) == 3 and all(isinstance(v, float) and v >= 0 for v in read)
+    again = _telemetry._host_pressure()
+    assert all(b >= a for a, b in zip(read, again))  # counters
 
 
 @pytest.mark.fast
