@@ -248,7 +248,8 @@ def _windowed_flash_plan():
 def _selected_flash_plan():
     """The tiles of the selected flash call of the benchmark's indexed layers,
     (1 x 32, 16384, 128) over 2,048 keys a query, the words a row of its
-    packed mask has, and the rows a grid step of index_select takes."""
+    packed mask has, and the rows a grid step of index_select takes with the
+    columns a step of its passes' loops does."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import indexer
@@ -257,7 +258,51 @@ def _selected_flash_plan():
     bh, t, d, top_k = 32, 16384, 128, 2048
     return {"shape": [bh, t, d], "select": top_k,
             "tiles": flash_tiles(bh, t, d, jnp.bfloat16, select=top_k)._asdict(),
-            "mask_width": indexer.mask_width(t), "index_select_rows": indexer._select_block(t)}
+            "mask_width": indexer.mask_width(t), "index_select_rows": indexer._select_block(t),
+            "index_select_chunk": indexer._select_chunk(t)}
+
+
+def _check_selection(seed, on_tpu):
+    """index_select at the benchmark's shape, (1, 16384, 16384) and 2,048
+    keys a query, on scores that index_scores makes from seeded operands (16
+    heads of 64): the packed mask of a few blocks of rows (the first, the two
+    either side of row top_k, the middle, the last) against a `lax.top_k`
+    selection of those rows, equal in every bit, and the compare-and-count
+    passes each block ran."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import indexer
+
+    t, top_k, heads, dim = (16384, 2048, 16, 64) if on_tpu else (512, 64, 4, 16)
+    kq, kk, kw = jax.random.split(jax.random.PRNGKey(seed), 3)
+    scores = indexer.index_scores(
+        jax.random.normal(kq, (1, t, heads, dim), jnp.bfloat16),
+        jax.random.normal(kk, (1, t, dim), jnp.bfloat16),
+        jax.random.normal(kw, (1, t, heads), jnp.bfloat16), interpret=not on_tpu)
+    mask, passes = indexer.index_select(scores, top_k, interpret=not on_tpu)
+    rows, width = indexer._select_block(t), indexer.mask_width(t)
+
+    @jax.jit
+    def by_top_k(block, first_row):
+        row = first_row + jnp.arange(rows)[:, None]
+        seen = jnp.where(jnp.arange(t)[None, :] <= row, block + 0.0, -jnp.inf)  # -0.0 is 0.0
+        best = jax.lax.top_k(seen, top_k)[1]  # equal scores: the lower position first
+        wanted = jnp.arange(top_k)[None, :] < jnp.minimum(row + 1, top_k)
+        chosen = jnp.zeros((rows, t), bool).at[jnp.arange(rows)[:, None], best].max(wanted)
+        return indexer._pack(chosen, width)
+
+    n_blocks = t // rows
+    checked = sorted({0, max(top_k // rows - 1, 0), min(top_k // rows, n_blocks - 1),
+                      n_blocks // 2, n_blocks - 1})
+    for i in checked:
+        want = by_top_k(scores[0, i * rows:(i + 1) * rows], i * rows)
+        if not bool((mask[0, i * rows:(i + 1) * rows] == want).all()):
+            raise RuntimeError(f"index_select differs from lax.top_k in rows {i * rows}..")
+    passes = [int(n) for n in passes[0]]
+    return {"shape": [1, t, t], "top_k": top_k, "rows_a_block": rows, "blocks_checked": checked,
+            "equal_in_every_bit": True, "passes_by_block": passes,
+            "passes_mean": sum(passes) / len(passes)}
 
 
 def _cells():
@@ -379,6 +424,7 @@ def one_chip_loop(config):
     report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()
+    report["index_select_vs_top_k"] = _check_selection(config["seed"], on_tpu)
     report["remat_plans"] = _remat_plans()
     report["flash_calls_by_cell"] = _flash_calls_by_cell(on_tpu)
     report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])

@@ -175,8 +175,9 @@ class Indexer(nn.Module):
     `index_dim` against one key head (LayerNorm, rotary over the whole
     width at the layer's theta), ReLU, a learned weight a head and query;
     the top `index_top_k` of each query's causal row, exactly
-    (ops/indexer.py). Sows the mean number of keys a query kept into
-    "attn_keys" (TrainStep's telemetry)."""
+    (ops/indexer.py). Sows into "attn_keys" (TrainStep's telemetry) the mean
+    number of keys a query kept, `selected`, and of compare-and-count passes
+    a block of rows took to find them, `select_passes`."""
 
     config: MellumConfig
 
@@ -198,9 +199,10 @@ class Indexer(nn.Module):
             # the matrices take no gradient either: a set of integers has none
             scores = indexer.index_scores(*jax.lax.stop_gradient((q, k, w)))
         with jax.named_scope("attn.select"):
-            mask = indexer.index_select(scores, cfg.index_top_k)
+            mask, passes = indexer.index_select(scores, cfg.index_top_k)
             self.sow("attn_keys", "selected",
                      jax.lax.population_count(mask).sum(-1).astype(jnp.float32).mean())
+            self.sow("attn_keys", "select_passes", passes.astype(jnp.float32).mean())
             return mask, indexer.transpose_packed(mask), cfg.index_top_k
 
 

@@ -14,13 +14,22 @@ caller hands in operands under `stop_gradient`.
 Exact top-k without a sort. A row of T scores is 16,384 long at the
 benchmark's shape and k is 2,048: `lax.top_k` there is a sort of every row.
 Instead the k-th largest score of a row is found bit by bit: float32 scores
-map to int32 keys of the same order, and 32 passes over the row, each a
-compare and a count, fix the key's bits from the top. Entries above it are
-taken; of the entries equal to it (the k-th itself, and any tie) the lowest
-positions are taken until the row has k, found the same way over the bits
-of the position. On a TPU both are pallas calls, `index_scores` and
-`index_select`, a block of rows a grid step with the whole row in VMEM;
-elsewhere the same arithmetic runs as XLA ops.
+map to int32 keys of the same order, and passes over the row, each a compare
+and a count, fix the key's bits from the top, 32 at most. Entries above it
+are taken; of the entries equal to it (the k-th itself, and any tie) the
+lowest positions are taken until the row has k, found the same way over the
+bits of the position. The whole-array form (`_select_rows`: every pass over
+every column) is the XLA path and the kernel's oracle. On a TPU both are
+pallas calls, `index_scores` and `index_select`. The selection takes a block
+of rows a grid step and does the passes its rows and its data need, and no
+others: it reads the columns up to the block's last row in lane-aligned
+chunks (the rest of the whole-row block the pipeline copied is not touched);
+it ends the key's search at the first trial value that exactly as many keys
+as each row wants lie at or above, which is then the set, ties and all; it
+searches a position's bits only in a block where some row is left with more
+keys tied at its threshold than it wants; and a block whose rows all keep
+every key they see runs no pass. What it ran it says in a second result, the
+passes a block.
 
 The packed mask. Bit b of word [t, c] says whether query t attends to key
 b * W + c, with W = `mask_width(T)` = max(128, T / 32) words a row: the keys
@@ -43,9 +52,18 @@ from ray_tpu.ops.attention import MIB, NEG_INF, attention_path
 
 INT_MIN = -(1 << 31)
 
-# Rows of scores a grid step of index_select holds in VMEM with its
-# temporaries (four arrays of a row each), and the tile of index_scores.
-_SELECT_BYTES = 4 * MIB
+# A grid step of index_select holds that many bytes of whole rows of scores
+# (the pipeline's block; the kernel reads its causal columns alone) and as
+# many of their keys. The rows of a block search together and stop together:
+# more rows share a pass's fixed cost (a cross-lane sum, a reduce to a scalar
+# and a branch) and stop later; at 256 rows a row's state no longer fits the
+# vector registers (TPU v5e at (1, 16384, 16384), ms a call: 4.7 / 3.6 / 3.2
+# / 5.1 at 2, 4, 8 and 16 MiB; PERF.md section 6, PR 39). And the columns a
+# step of a pass's loop takes: longer steps lose less to the loop, shorter
+# ones read less past the diagonal (3.4 / 3.2 / 3.2 / 3.4 ms at 512 to 4,096).
+_SELECT_BYTES = 8 * MIB
+_SELECT_CHUNK = 1024
+# The tile of index_scores.
 _SCORE_TILE = 512
 
 
@@ -128,48 +146,54 @@ def index_scores(q, k, w, *, interpret=False):
 # --------------------------------------------------------------------------
 
 
+def _keys(scores):
+    """float32 -> int32 in the scores' order: a negative float's bits, but
+    for the sign, run the other way, and one up, so that -0.0 ties with 0.0
+    and no score's key is INT_MIN (what a column a row cannot see holds)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    sign = bits >> 31  # 0, or -1 where the float is negative
+    return (bits ^ (sign & jnp.int32(0x7FFFFFFF))) - sign
+
+
 def _count(cond):
     """Entries of each row that hold, (R, 1) float32 (exact up to 2**24)."""
     return jnp.sum(jnp.where(cond, 1.0, 0.0), axis=1, keepdims=True)
 
 
-def _select_rows(scores, first_row, top_k):
-    """scores (R, T) float32, rows first_row .. first_row + R - 1 of a
-    sequence -> (R, T) bool: for row t the min(top_k, t + 1) entries s <= t
-    of largest score, ties to the lower s. Plain jnp on whole arrays: the
-    body of the pallas kernel and the XLA path alike."""
-    rows, t = scores.shape
-    pos = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
-    row = first_row + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+def _position_bits(t):
+    return max(1, (t - 1).bit_length())
+
+
+def _select_rows(scores, top_k):
+    """scores (T, T) float32 -> (T, T) bool: for row t the min(top_k, t + 1)
+    entries s <= t of largest score, ties to the lower s. Plain jnp on whole
+    arrays, every pass over every column whatever the data are: the XLA
+    path, and what the kernel is held to bit for bit."""
+    t = scores.shape[1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (scores.shape[0], 1), 0)
     want = jnp.minimum(row + 1, top_k).astype(jnp.float32)
-    # int32 keys in the scores' order: a negative float's bits, but for the
-    # sign, run the other way
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    bits = jnp.where(bits == INT_MIN, jnp.int32(0), bits)  # -0.0 ties with 0.0
-    keys = jnp.where(bits >= 0, bits, bits ^ jnp.int32(0x7FFFFFFF))
-    keys = jnp.where(pos <= row, keys, jnp.int32(INT_MIN))
+    keys = jnp.where(pos <= row, _keys(scores), jnp.int32(INT_MIN))
 
     # the k-th largest key: the largest v with `want` keys >= v, a bit at a
-    # time from the sign down
-    at_least = jnp.where(_count(keys >= 0) >= want, jnp.int32(0), jnp.int32(INT_MIN))
-
+    # time from the sign down (INT_MIN + 2**31 wraps to 0)
     def key_bit(n, at_least):
-        trial = at_least | (jnp.int32(1) << (30 - n))
+        trial = at_least + (jnp.int32(1) << (31 - n))
         return jnp.where(_count(keys >= trial) >= want, trial, at_least)
 
-    kth = jax.lax.fori_loop(0, 31, key_bit, at_least)
+    kth = jax.lax.fori_loop(0, 32, key_bit, jnp.full(row.shape, INT_MIN, jnp.int32))
     above = keys > kth
     tied = keys == kth
     # of the entries equal to it, the lowest positions until the row is
     # full: the position of the last one taken, a bit at a time
     short = want - _count(above)
+    n_bits = _position_bits(t)
 
     def pos_bit(n, last):
         trial = last | (jnp.int32(1) << (n_bits - 1 - n))
         return jnp.where(_count(tied & (pos < trial)) < short, trial, last)
 
-    n_bits = max(1, (t - 1).bit_length())
-    last = jax.lax.fori_loop(0, n_bits, pos_bit, jnp.zeros((rows, 1), jnp.int32))
+    last = jax.lax.fori_loop(0, n_bits, pos_bit, jnp.zeros(row.shape, jnp.int32))
     return above | (tied & (pos <= last))
 
 
@@ -181,9 +205,114 @@ def _pack(sel, width):
     return packed
 
 
-def _select_kernel(s_ref, o_ref, *, rows, top_k, width):
+def _xla_select(scores, top_k):
+    width = mask_width(scores.shape[1])
+    return jax.vmap(lambda s: _pack(_select_rows(s, top_k), width))(scores)
+
+
+def _select_kernel(s_ref, o_ref, n_ref, keys_ref, *, chunk, top_k):
+    """The selection of one block of rows over the columns those rows can
+    see, and no others: lane-aligned chunks of the scores up to the diagonal
+    become int32 keys in `keys_ref`; a pass counts over those chunks alone;
+    the key's bits are searched until every row has exactly the count it
+    wants (`keys >= trial` is then its set) or bit 0 is done; only a block
+    left with more keys tied at a row's threshold than the row wants
+    searches the position of the last one taken. Planes of the mask past the
+    diagonal are zeros. `n_ref` takes the passes the block ran. A row's
+    state is (rows, 128) with every lane alike, so that the loops compare
+    registers with registers."""
+    rows, t = keys_ref.shape
+    width = o_ref.shape[-1]
+    lanes = (rows, 128)
     first_row = pl.program_id(1) * rows
-    o_ref[0] = _pack(_select_rows(s_ref[0], first_row, top_k), width)
+    row = first_row + jax.lax.broadcasted_iota(jnp.int32, lanes, 0)
+    want = jnp.minimum(row + 1, top_k).astype(jnp.float32)
+    n_chunks = (first_row + rows + chunk - 1) // chunk
+
+    def over(state, n):
+        """A row's state beside n columns: its 128 lanes again and again."""
+        return jnp.concatenate([state] * (n // 128), axis=1)
+
+    def col(keys, first):
+        return first + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+
+    def make_keys(seen):
+        def of_chunk(c, _):
+            cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            keys = _keys(s_ref[0, :, cols])
+            if not seen:
+                keys = jnp.where(col(keys, c * chunk) <= over(row, chunk), keys, jnp.int32(INT_MIN))
+            keys_ref[:, cols] = keys
+        return of_chunk
+
+    # every row sees all of the chunks before the block's first row
+    jax.lax.fori_loop(0, first_row // chunk, make_keys(True), None)
+    jax.lax.fori_loop(first_row // chunk, n_chunks, make_keys(False), None)
+
+    one, zero = jnp.ones(lanes, jnp.float32), jnp.zeros(lanes, jnp.float32)
+
+    def count(holds):
+        """Columns of each row whose (keys, first column) `holds`, 128 at a
+        time in the order the vector unit takes them. Plain lax calls: the
+        slices are traced again in every layer of every program that holds
+        the call, and a jnp operator costs six times as much to trace."""
+        def of_chunk(c, acc):
+            keys = keys_ref[:, pl.ds(pl.multiple_of(c * chunk, chunk), chunk)]
+            for j in range(0, chunk, 128):
+                hit = holds(jax.lax.slice_in_dim(keys, j, j + 128, axis=1), c * chunk + j)
+                acc = jax.lax.add(acc, jax.lax.select(hit, one, zero))
+            return acc
+
+        acc = jax.lax.fori_loop(0, n_chunks, of_chunk, zero)
+        return jnp.broadcast_to(jnp.sum(acc, axis=1, keepdims=True), lanes)
+
+    def unsure(have):
+        return (jnp.max(have - want) > 0).astype(jnp.int32)
+
+    def key_bit(state):
+        bit, at_least, have, _ = state
+        trial = at_least + (jnp.int32(1) << bit)
+        n = count(lambda keys, _: jax.lax.ge(keys, trial))
+        at_least, have = jnp.where(n >= want, trial, at_least), jnp.where(n >= want, n, have)
+        return bit - 1, at_least, have, unsure(have)
+
+    # rows that want every key they see have it before the first pass
+    have = (row + 1).astype(jnp.float32)
+    bit, kth, have, tied = jax.lax.while_loop(
+        lambda state: (state[0] >= 0) & (state[3] > 0), key_bit,
+        (jnp.int32(31), jnp.full(lanes, INT_MIN, jnp.int32), have, unsure(have)))
+    kth = jnp.maximum(kth, jnp.int32(INT_MIN + 1))  # never a column past the diagonal
+    n_bits = _position_bits(t)
+    n_ref[...] = jnp.full(n_ref.shape, 31 - bit + tied * n_bits, jnp.int32)
+
+    def pack(take):
+        o_ref[0] = jnp.zeros((rows, width), jnp.int32)
+
+        def plane(b, _):
+            keys = keys_ref[:, pl.ds(pl.multiple_of(b * width, width), width)]
+            o_ref[0] |= jnp.where(take(keys, b * width), jnp.int32(1) << b, jnp.int32(0))
+
+        jax.lax.fori_loop(0, (first_row + rows + width - 1) // width, plane, None)
+
+    @pl.when(tied == 0)
+    def _():
+        beside = over(kth, width)
+        pack(lambda keys, _: keys >= beside)
+
+    @pl.when(tied > 0)
+    def _():
+        # of the entries equal to the k-th, the lowest positions until the
+        # row is full, as _select_rows finds them
+        short = want - count(lambda keys, _: jax.lax.gt(keys, kth))
+
+        def pos_bit(n, last):
+            trial = last | (jnp.int32(1) << (n_bits - 1 - n))
+            n_before = count(lambda keys, at: (keys == kth) & (col(keys, at) < trial))
+            return jnp.where(n_before < short, trial, last)
+
+        last = jax.lax.fori_loop(0, n_bits, pos_bit, jnp.zeros(lanes, jnp.int32))
+        pack(lambda keys, at: (keys > over(kth, width))
+             | ((keys == over(kth, width)) & (col(keys, at) <= over(last, width))))
 
 
 def _select_block(t):
@@ -194,28 +323,32 @@ def _select_block(t):
     return rows
 
 
+def _select_chunk(t):
+    """Columns a step of a pass's loop takes: whole planes of the mask."""
+    width = mask_width(t)
+    return max(c for c in range(width, max(width, min(t, _SELECT_CHUNK)) + 1, width) if t % c == 0)
+
+
 def _pallas_select(scores, top_k, interpret):
     b, t, _ = scores.shape
     rows, width = _select_block(t), mask_width(t)
-    return pl.pallas_call(
-        functools.partial(_select_kernel, rows=rows, top_k=top_k, width=width),
+    mask, passes = pl.pallas_call(
+        functools.partial(_select_kernel, chunk=_select_chunk(t), top_k=top_k),
         grid=(b, t // rows),
         in_specs=[pl.BlockSpec((1, rows, t), lambda n, i: (n, i, 0))],
-        out_specs=pl.BlockSpec((1, rows, width), lambda n, i: (n, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, width), jnp.int32),
+        out_specs=[pl.BlockSpec((1, rows, width), lambda n, i: (n, i, 0)),
+                   pl.BlockSpec((1, 1, 1, 128), lambda n, i: (n, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, t, width), jnp.int32),
+                   jax.ShapeDtypeStruct((b, t // rows, 1, 128), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
-            # the block twice (the pipeline's two buffers) and the kernel's
-            # temporaries: keys, positions, a compare's result, a count's terms
-            vmem_limit_bytes=max(16 * MIB, 10 * rows * t * 4)),
+            # the block twice (the pipeline's two buffers) and its keys
+            vmem_limit_bytes=max(16 * MIB, 4 * rows * t * 4)),
         interpret=interpret,
         name="index_select",
     )(scores)
-
-
-def _xla_select(scores, top_k):
-    width = mask_width(scores.shape[1])
-    return jax.vmap(lambda s: _pack(_select_rows(s, 0, top_k), width))(scores)
+    return mask, passes[:, :, 0, 0]
 
 
 def _bits(packed, n):
@@ -255,7 +388,10 @@ def unpack(packed):
 
 def index_select(scores, top_k: int, *, interpret=False):
     """scores (B, T, T) float32 -> the packed mask (B, T, W) int32 of each
-    query's min(top_k, t + 1) best keys at or before it."""
+    query's min(top_k, t + 1) best keys at or before it, and the
+    compare-and-count passes each block of rows took to find it (int32, a
+    block an entry; of 32 + the bits of a position)."""
     if interpret or attention_path(scores.shape[1]) == "flash":
         return _pallas_select(scores, top_k, interpret)
-    return _xla_select(scores, top_k)
+    b, t, _ = scores.shape
+    return _xla_select(scores, top_k), jnp.full((b, 1), 32 + _position_bits(t), jnp.int32)

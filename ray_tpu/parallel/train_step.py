@@ -184,9 +184,9 @@ class TrainStep:
         # What a family's layers sow for the telemetry, collections that go
         # out with the step's metrics. A dropless expert layer adds no term to
         # the loss: the rows its held experts worked on ("moe_load"), and the
-        # keys a query kept where a layer selects them ("attn_keys"). A
-        # state-space layer: how far a chunk decays and how large its carried
-        # state grows ("ssm_stats").
+        # keys a query kept where a layer selects them, with the passes the
+        # selection took ("attn_keys"). A state-space layer: how far a chunk
+        # decays and how large its carried state grows ("ssm_stats").
         self._sown = (["moe_load", "attn_keys"] if isinstance(model_cfg, MellumConfig)
                       else ["ssm_stats"] if isinstance(model_cfg, GraniteConfig) else [])
         if rules is None:
@@ -287,9 +287,14 @@ class TrainStep:
 
                 metrics.update(moe_load_metrics(
                     loads["moe_load"], batch["idx"].size, model_cfg.top_k))
-                kept = jax.tree.leaves(loads.get("attn_keys", {}))
-                if kept:  # layers that select their keys: those a query kept
-                    metrics["attn_keys_selected_mean"] = sum(kept) / len(kept)
+                # layers that select their keys: those a query kept, and the
+                # passes over its row's scores that finding them took
+                sown = jax.tree_util.tree_leaves_with_path(loads.get("attn_keys", {}))
+                for name, metric in (("selected", "attn_keys_selected_mean"),
+                                     ("select_passes", "attn_select_passes_mean")):
+                    layers = [x for path, x in sown if jax.tree_util.DictKey(name) in path]
+                    if layers:
+                        metrics[metric] = sum(layers) / len(layers)
             return new_state, metrics
 
         self._step = jax.jit(

@@ -150,13 +150,15 @@ _EXIT_WAIT_S = 10.0
 # newest completed step (a scan of several: its last): the rows this
 # program's experts worked on, their share of every token's assignments,
 # and the fullest held expert over the mean (ops/moe.py:moe_load_metrics);
-# the keys a query kept, mean over the layers that select them
+# the keys a query kept and the compare-and-count passes a block of rows
+# took to select them, means over the layers that select
 # (models/mellum.py:Indexer); a state-space model's most negative log-decay
 # of a chunk over layers and heads (how near a chunk's exp is to flushing to
 # zero) and its largest carried-state entry (what a narrower state would
 # have to hold; models/granite.py:Mamba2Mixer).
 _STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
-                "attn_keys_selected_mean", "ssm_chunk_log_decay_min", "ssm_state_abs_max")
+                "attn_keys_selected_mean", "attn_select_passes_mean",
+                "ssm_chunk_log_decay_min", "ssm_state_abs_max")
 
 
 @dataclasses.dataclass(slots=True)

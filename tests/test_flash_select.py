@@ -53,7 +53,7 @@ def test_index_scores_kernel_agrees_with_the_einsum(t):
 def test_selection_is_exact(path, top_k):
     scores = indexer._xla_scores(*_index_operands())
     select = indexer._xla_select if path == "xla" else (
-        lambda s, k: indexer._pallas_select(s, k, True))
+        lambda s, k: indexer._pallas_select(s, k, True)[0])
     sel = np.asarray(indexer.unpack(select(scores, top_k)))
     assert (sel == _by_hand(scores, top_k)).all()
     assert (sel.sum(-1) == np.minimum(np.arange(256) + 1, top_k)).all()
@@ -67,7 +67,7 @@ def test_ties_go_to_the_lower_position(path):
         jnp.arange(256) % 3 == 0, -0.0, 1.0)
     scores = scores.at[0, 200].set(2.5)
     select = indexer._xla_select if path == "xla" else (
-        lambda s, k: indexer._pallas_select(s, k, True))
+        lambda s, k: indexer._pallas_select(s, k, True)[0])
     sel = np.asarray(indexer.unpack(select(scores, 40)))
     assert (sel == _by_hand(scores, 40)).all()
     assert sel[0, 200, :40].all() and not sel[0, 200, 40:].any()
@@ -205,3 +205,81 @@ def test_calls_carry_the_selection_in_their_names():
     jaxpr = str(jax.make_jaxpr(lambda q, k, w: indexer._pallas_select(
         indexer._pallas_scores(q, k, w, True), 32, True))(q, k, w))
     assert {"index_scores", "index_select"} <= set(re.findall(r"name=(\w+)", jaxpr))
+
+
+# The selection kernel over the columns a block of rows can see: 1,024
+# positions in blocks of 64 rows or of 8, in chunks of 256 columns (two
+# planes of the mask), 100 keys a query unless said.
+_T, _CHUNK = 1024, 256
+
+
+def _continuous(seed=0):
+    return jax.random.normal(jax.random.PRNGKey(seed), (1, _T, _T), jnp.float32)
+
+
+def _above_the_diagonal(scores, value):
+    return jnp.where(jnp.tril(jnp.ones((_T, _T), bool)), scores, value)
+
+
+def _last_bit(scores, row, top_k):
+    """Row `row`: top_k - 1 scores of 2 and more, then 1 + 2**-23 and 1, the
+    rest under 1: the k-th and the (k+1)-th differ in the last bit."""
+    col = jnp.arange(_T)
+    values = jnp.where(col < top_k - 1, 2.0 + col, -1.0 - col).astype(jnp.float32)
+    values = values.at[top_k - 1].set(np.nextafter(np.float32(1), np.float32(2))).at[top_k].set(1.0)
+    return scores.at[0, row].set(jnp.roll(values, 17))  # row sees every column it needs: row >= top_k + 17
+
+
+def _tied_rows(scores, first, last):
+    """Rows first .. last - 1 on a grid of 8 values: ties by the dozen."""
+    return scores.at[0, first:last].set(jnp.round(2 * scores[0, first:last]) / 2)
+
+
+_SELECT_CASES = {
+    "continuous": lambda: _continuous(),
+    "inf_above": lambda: _above_the_diagonal(_continuous(), jnp.inf),
+    "nan_above": lambda: _above_the_diagonal(_continuous(), jnp.nan),
+    "last_bit": lambda: _last_bit(_continuous(), 700, 100),
+    "ties_in_one_block": lambda: _tied_rows(_continuous(), 512, 576),
+    "top_k_1": lambda: _continuous(1),
+    "top_k_t_less_1": lambda: _continuous(2),
+    "top_k_across_a_block": lambda: _continuous(3),
+}
+_SELECT_TOP_K = {"top_k_1": 1, "top_k_t_less_1": _T - 1, "top_k_across_a_block": 92}
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("case", sorted(_SELECT_CASES))
+def test_selection_kernel_reads_what_its_rows_see_and_stops_when_exact(monkeypatch, case, rows):
+    """Several blocks of rows and chunks of columns: the kernel's mask is the
+    whole-array form's and a stable sort's in every bit, whatever stands
+    above the diagonal, and it says how many passes each block of rows ran:
+    none where every row keeps all it sees, under 32 where the counts come
+    out exact before the last bit, all 32 where two keys differ in the last
+    bit alone, and the position's bits besides only where keys are tied
+    beyond what a row wants."""
+    monkeypatch.setattr(indexer, "_SELECT_BYTES", rows * _T * 4)
+    monkeypatch.setattr(indexer, "_SELECT_CHUNK", _CHUNK)
+    assert (indexer._select_block(_T), indexer._select_chunk(_T)) == (rows, _CHUNK)
+    scores, top_k = _SELECT_CASES[case](), _SELECT_TOP_K.get(case, 100)
+    mask, passes = indexer._pallas_select(scores, top_k, True)
+    assert (np.asarray(mask) == np.asarray(indexer._xla_select(scores, top_k))).all()
+    sel = np.asarray(indexer.unpack(mask))
+    assert (sel == _by_hand(scores, top_k)).all()
+    assert (sel.sum(-1) == np.minimum(np.arange(_T) + 1, top_k)).all()
+
+    passes = np.asarray(passes)
+    assert passes.shape == (1, _T // rows)
+    last_row = (np.arange(_T // rows) + 1) * rows - 1  # of each block
+    every_pass = 32 + 10  # the key's bits and a position's under 1,024
+    assert (passes[0, last_row < top_k] == 0).all()  # rows that keep all they see
+    searching = last_row >= top_k
+    assert (passes[0, searching] > 0).all()
+    early = passes[0] < 32
+    if case == "ties_in_one_block":
+        tied = (last_row >= 512) & (last_row < 576)
+        assert (passes[0, tied] == every_pass).all() and early[~tied].all()
+    elif case == "last_bit":
+        assert passes[0, 700 // rows] == 32 and np.delete(early, 700 // rows).all()
+    else:
+        assert early.all()

@@ -231,11 +231,16 @@ def test_step_reports_the_keys_a_query_kept_through_the_telemetry():
         state, m = ts.step(state, ts.shard_batch({"idx": idx, "targets": targets}))
         # mean of min(32, t + 1) over 128 positions
         assert float(m["attn_keys_selected_mean"]) == (sum(range(1, 33)) + 96 * 32) / 128
+        # beside it, read by its own name, the passes the selection took: on
+        # the CPU the whole-array form's, a key's 32 bits and 7 of a position
+        assert float(m["attn_select_passes_mean"]) == 39
         jax.block_until_ready(m)
-        assert _telemetry.auto_report_metrics()["telemetry/attn_keys_selected_mean"] == 28.125
+        reported = _telemetry.auto_report_metrics()
+        assert reported["telemetry/attn_keys_selected_mean"] == 28.125
+        assert reported["telemetry/attn_select_passes_mean"] == 39
         # at a length the selection says nothing the step reports none
-        short = ts.shard_batch({"idx": idx[:, :32], "targets": targets[:, :32]})
-        assert "attn_keys_selected_mean" not in ts.step(state, short)[1]
+        short = ts.step(state, ts.shard_batch({"idx": idx[:, :32], "targets": targets[:, :32]}))[1]
+        assert "attn_keys_selected_mean" not in short and "attn_select_passes_mean" not in short
     finally:
         _telemetry.set_current_recorder(None)
 

@@ -344,14 +344,18 @@ def test_indexer_compiles_at_the_cell_s_shape(one_chip):
     """The indexer's scores (16 heads of 64 against one key head over 16,384
     positions) and the exact top-2,048 of each row, as pallas calls under
     their names, and the mask's transpose beside them without a (T, T) array
-    of words."""
+    of words. The selection's loops end on what it counts (a `while` on a
+    scalar reduced from the rows' counts, trip counts from the block's first
+    row): Mosaic takes them at this shape. Its mask stays the call's first
+    result, so the benchmark's shape function reads the bytes it read."""
+    from bench import shapes, trace
     from ray_tpu.ops import indexer
 
     shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
 
     def select(q, k, w):
-        mask = indexer._pallas_select(indexer._pallas_scores(q, k, w, False), 2048, False)
-        return mask, indexer.transpose_packed(mask)
+        mask, passes = indexer._pallas_select(indexer._pallas_scores(q, k, w, False), 2048, False)
+        return mask, indexer.transpose_packed(mask), passes
 
     c = jax.jit(select).lower(shape((1, 16384, 16, 64), jnp.bfloat16),
                               shape((1, 16384, 64), jnp.bfloat16),
@@ -360,6 +364,11 @@ def test_indexer_compiles_at_the_cell_s_shape(one_chip):
     assert sorted(re.sub(r"[.\d]+$", "", n) for n in names) == ["index_scores", "index_select"]
     # the scores, 1 GiB of float32, are the only array of that size
     assert GIB <= c.memory_analysis().temp_size_in_bytes < 1.25 * GIB
+    call, = (line.strip() for line in c.as_text().splitlines()
+             if re.match(r"\s*%index_select[.\d]* = ", line))
+    kind = trace.kind(call)  # what the benchmark's trace reader makes of the event
+    assert kind.startswith("index_select custom-call -> (s32[1,16384,512], s32["), kind
+    assert shapes.load("index_select")(kind, "f32[1,16384,16384]") == (0, 536_870_912 + 33_554_432)
 
 
 def test_scan_kernels_compile_at_the_cell_s_shape(one_chip):
