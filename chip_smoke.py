@@ -230,6 +230,45 @@ def _check_ssd_vs_chunked(seed, on_tpu):
             "ssd_path": ssd.ssd_path(t, h, p, 1, chunk)}
 
 
+def _check_gated_conv_vs_plain(seed, on_tpu):
+    """ops/short_conv.py's two kernels against the plain sum of shifted
+    slices at the benchmark's width (three streams of 2,048, 3 taps) on a
+    quarter of its rows, same seed: the output and the gradients of the
+    streams and of the taps, as max-abs error over the reference's max-abs
+    value."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import short_conv
+
+    b, t, d, k = (1, 4096, 2048, 3) if on_tpu else (2, 40, 128, 3)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    bcu = jax.random.normal(ks[0], (b, t, 3 * d), jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (k, d), jnp.float32, -0.6, 0.6)
+    dy = jax.random.normal(ks[2], (b, t, d), jnp.bfloat16)
+
+    def run(form):
+        def loss(bcu, w):
+            y = form(bcu, w)
+            return (y.astype(jnp.float32) * dy.astype(jnp.float32)).sum(), y
+
+        grads, y = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(bcu, w)
+        return (y, *grads)
+
+    kernels = run(lambda bcu, w: short_conv.gated_short_conv(bcu, w, interpret=not on_tpu))
+    plain = run(short_conv.gated_conv_plain)
+    errs = {}
+    for name, got, want in zip(("y", "d_bcu", "dw"), kernels, plain):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"gated conv {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"gated conv kernels vs plain form beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": [b, t, 3 * d], "taps": k, "rel_err": errs,
+            "rows_a_tile": short_conv._tile(t), "conv_path": short_conv.conv_path(d, k)}
+
+
 def _windowed_flash_plan():
     """The tiles of the windowed flash call of the benchmark's window layers,
     (2 x 32, 8192, 128) under a window of 1,024, beside the causal call's at
@@ -348,7 +387,10 @@ def _flash_calls_by_cell(on_tpu):
     is `...bwd_dq` or `...bwd_dkv`; a layer that is a scan over a state
     (`mamba` in the configuration's `layer_types`) has ssd_bwd once in place
     of the flash pair, and ssd_fwd once where the step's remat plan saves
-    the scan's outputs (`ssm_y`) and twice where it does not."""
+    the scan's outputs (`ssm_y`) and twice where it does not; a layer that is
+    a gated short convolution (`conv`) has gated_conv_bwd once, and
+    gated_conv_fwd once where the plan saves its output (`conv_y`), else
+    twice."""
     import collections
     import re
 
@@ -375,12 +417,17 @@ def _flash_calls_by_cell(on_tpu):
         kinds = collections.Counter()
         for k, n in found.items():
             kinds[k.removesuffix(attention.LEGACY_NAMES).rsplit("_bwd_", 1)[-1]] += n
-        scans = list(getattr(cfg, "layer_types", ())).count("mamba")
-        scan_fwd = scans * (1 if scans and "ssm_y" in remat.traced(cfg).names else 2)
-        if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans
-                           and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans):
-            raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans, "
-                               f"calls {calls[name]}")
+        layer_kinds = list(getattr(cfg, "layer_types", ()))
+        scans, convs = layer_kinds.count("mamba"), layer_kinds.count("conv")
+        saved = remat.traced(cfg).names
+        scan_fwd = scans * (1 if "ssm_y" in saved else 2)
+        conv_fwd = convs * (1 if "conv_y" in saved else 2)
+        if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans - convs
+                           and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans
+                           and found["gated_conv_fwd"] == conv_fwd
+                           and found["gated_conv_bwd"] == convs):
+            raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans and "
+                               f"{convs} convolutions, calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:
             raise RuntimeError(f"{name}: a backward call for one gradient alone: {calls[name]}")
     return calls
@@ -422,6 +469,7 @@ def one_chip_loop(config):
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
         for shape in config["attn_shapes"]]
     report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
+    report["gated_conv_vs_plain"] = _check_gated_conv_vs_plain(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()
     report["index_select_vs_top_k"] = _check_selection(config["seed"], on_tpu)

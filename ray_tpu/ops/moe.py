@@ -238,6 +238,15 @@ combine_rows.defvjp(_combine_fwd, _combine_bwd)
 # and 2.9-3.5 ms with both gradients; (512, 512, 512) 1.33-1.42 and 3.2-3.5;
 # (256, 1024, 1024) 1.38-1.52 and 3.1-3.7. XLA's own `jax.lax.ragged_dot`
 # at the same shapes: 3.97-5.53 ms and 10.7-10.8 ms.
+#
+# At experts wider than a tile and no multiple of it, 8 groups of 2,048 rows
+# in a buffer of 24,576, (2048, 1792) and (1792, 2048) (my chip run, PR 41):
+# (512, 1024, 1024) 0.80-0.81 ms forward and 1.66-1.67 ms for both gradients,
+# the 768-wide remainder tile and all; an 896-wide tile on the 1,792 (two even
+# tiles) 0.75 ms forward and 1.81-1.84 for the gradients, which megablox runs
+# under the same tuple with the widths changing places; (512, 512, 512) 1.01-
+# 1.04 and 1.95; 1,792 or 2,048 in one tile does not fit VMEM. So one tiling
+# for every width the cells have.
 _GMM_TILING = (512, 1024, 1024)
 
 
@@ -325,12 +334,26 @@ def _experts_in_buffer_bwd(rooms, res, g):
 _experts_in_buffer.defvjp(_experts_in_buffer_fwd, _experts_in_buffer_bwd)
 
 
+SOFTMAX, SIGMOID = "softmax", "sigmoid"
+SELECTION_BIAS = "expert_bias"  # the leaf's name: no gradient moves it (TrainStep)
+
+
 class ExpertShare(nn.Module):
     """(B, T, C) -> (B, T, C): the part of a top-k-of-`num_experts` SwiGLU
     expert layer that experts first_expert .. first_expert + num_held - 1
     compute (all of them where num_held is None). Sows the (B, T, k) choices
     over the whole layer into "choices" (bench/families/__init__.py) and the
     held experts' row counts into "moe_load" (TrainStep's telemetry).
+
+    `router` is the form of the scores. SOFTMAX: a softmax over all experts,
+    the top k of it, gates renormalised over the chosen. SIGMOID
+    (DeepSeek-V3's, and LFM2's `use_expert_bias`): s = sigmoid of each logit;
+    the k experts are the top k of s + b, with b a bias a layer that stands
+    in the selection alone (under stop_gradient: the loss never moves it;
+    TrainStep does, from the counts of every expert's tokens, which are sown
+    into "moe_router"); the gates are s at the chosen, over their sum + 1e-6,
+    times `scaling`. `hand_up_choices`: return (y, choices) and sow nothing
+    into "choices", for a caller that sows several layers' as one entry.
 
     No assignment is dropped, whatever the imbalance: every one of the
     tokens x k rows may be routed here. The buffer that the rows are
@@ -347,6 +370,9 @@ class ExpertShare(nn.Module):
     first_expert: int = 0
     num_held: Optional[int] = None
     dtype: Any = jnp.bfloat16
+    router: str = SOFTMAX
+    scaling: float = 1.0
+    hand_up_choices: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -357,14 +383,26 @@ class ExpertShare(nn.Module):
             # Router always in fp32: tiny matmul, big numerical leverage.
             logits = nn.Dense(self.num_experts, use_bias=False, dtype=jnp.float32,
                               param_dtype=jnp.float32, name="router")(x.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
-            idx = jax.lax.top_k(probs, k)[1]
+            if self.router == SOFTMAX:
+                probs = jax.nn.softmax(logits, axis=-1)
+                idx = jax.lax.top_k(probs, k)[1]
+            else:
+                probs = jax.nn.sigmoid(logits)
+                bias = self.param(SELECTION_BIAS, nn.initializers.zeros,
+                                  (self.num_experts,), jnp.float32)
+                idx = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), k)[1]
             # the chosen probabilities read through a one-hot product: the
             # gradient of top_k's own values is a scatter, serial on a TPU
             chosen = idx[..., None] == jnp.arange(self.num_experts)
             top_p = jnp.where(chosen, probs[..., None, :], 0.0).sum(-1)
-            gates = (top_p / top_p.sum(-1, keepdims=True)).reshape(n, k)
-            self.sow("choices", "experts", idx)
+            if self.router == SOFTMAX:
+                gates = (top_p / top_p.sum(-1, keepdims=True)).reshape(n, k)
+            else:
+                gates = (top_p / (top_p.sum(-1, keepdims=True) + 1e-6)
+                         * self.scaling).reshape(n, k)
+                self.sow("moe_router", "rows", chosen.sum((0, 1, 2), dtype=jnp.int32))
+            if not self.hand_up_choices:
+                self.sow("choices", "experts", idx)
             order, inv, held, group_sizes = route_plan(
                 idx.reshape(n, k), self.first_expert, num_held)
             self.sow("moe_load", "rows", group_sizes)
@@ -383,7 +421,8 @@ class ExpertShare(nn.Module):
             y = _experts_in_buffer((room, n * k), plan, *operands)
         else:
             y = _expert_rows(n * k, plan, *operands)
-        return y.reshape(B, T, C).astype(x.dtype)
+        y = y.reshape(B, T, C).astype(x.dtype)
+        return (y, idx) if self.hand_up_choices else y
 
 
 def moe_load_metrics(loads, tokens, top_k):
@@ -396,6 +435,42 @@ def moe_load_metrics(loads, tokens, top_k):
     return {"moe_rows_held": held,
             "moe_held_share": held / (tokens * top_k * rows.shape[0]),
             "moe_load_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0)}
+
+
+# The selection bias of a SIGMOID router moves by this much a step, towards
+# the experts that got fewer tokens than the mean: DeepSeek-V3's rule
+# (arXiv:2412.19437, section 2.1.2) with its rate. LFM2's source says
+# `use_expert_bias` and gives no rule.
+SELECTION_BIAS_RATE = 1e-3
+
+
+def move_selection_bias(params, router_rows):
+    """`params` with every SIGMOID layer's selection bias moved by
+    SELECTION_BIAS_RATE * sign(mean load - load_i), from the step's own
+    "moe_router" collection (`router_rows`: a count of tokens an expert, all
+    experts of the layer, under the layer's own module path)."""
+    from flax import traverse_util
+
+    flat = traverse_util.flatten_dict(params)
+    for path, rows in traverse_util.flatten_dict(router_rows).items():
+        load = rows[0].astype(jnp.float32)  # sown once: a tuple of one
+        at = path[:-1] + (SELECTION_BIAS,)
+        flat[at] = flat[at] + SELECTION_BIAS_RATE * jnp.sign(load.mean() - load)
+    return traverse_util.unflatten_dict(flat)
+
+
+def router_metrics(params, router_rows):
+    """What TrainStep reports of the SIGMOID layers: the largest selection
+    bias, and the fullest expert's tokens over the mean's, over all
+    `num_experts` experts of a layer (held here or not)."""
+    from flax import traverse_util
+
+    rows = jnp.stack([r[0] for r in traverse_util.flatten_dict(router_rows).values()]
+                     ).astype(jnp.float32)  # (layers, num_experts)
+    biases = [b for path, b in traverse_util.flatten_dict(params).items()
+              if path[-1] == SELECTION_BIAS]
+    return {"moe_bias_abs_max": jnp.max(jnp.abs(jnp.stack(biases))),
+            "moe_router_load_max_over_mean": (rows.max(-1) / jnp.maximum(rows.mean(-1), 1.0)).max()}
 
 
 EXPERT_SHARE_SHARDING_PATTERNS = [

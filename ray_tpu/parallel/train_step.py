@@ -113,7 +113,7 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
     """Instantiate the model wired for this mesh: shard_map'd attention on
     more than one device (ring attention iff sp > 1) and the residual
     stream's sharding there (none on one device); config type picks the
-    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum / Granite)."""
+    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum / Granite / Lfm2)."""
     import dataclasses
 
     if mesh is not None and cfg.attn_fn is None and mesh.devices.size > 1 and (
@@ -122,6 +122,7 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
         cfg = dataclasses.replace(cfg, attn_fn=attn_for_mesh(mesh))
     from ray_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEConfig
     from ray_tpu.models.granite import Granite, GraniteConfig
+    from ray_tpu.models.lfm2 import Lfm2, Lfm2Config
     from ray_tpu.models.llama import Llama, LlamaConfig
     from ray_tpu.models.mellum import Mellum, MellumConfig
 
@@ -134,12 +135,15 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
         return Mellum(cfg, stream)
     if isinstance(cfg, GraniteConfig):
         return Granite(cfg, stream)
+    if isinstance(cfg, Lfm2Config):
+        return Lfm2(cfg, stream)
     return GPT2(cfg, stream)
 
 
 def default_rules_for(cfg) -> ShardingRules:
     from ray_tpu.models.gpt2_moe import GPT2_MOE_SHARDING_RULES, GPT2MoEConfig
     from ray_tpu.models.granite import GRANITE_SHARDING_RULES, GraniteConfig
+    from ray_tpu.models.lfm2 import LFM2_SHARDING_RULES, Lfm2Config
     from ray_tpu.models.llama import LLAMA_SHARDING_RULES, LlamaConfig
     from ray_tpu.models.mellum import MELLUM_SHARDING_RULES, MellumConfig
 
@@ -151,6 +155,8 @@ def default_rules_for(cfg) -> ShardingRules:
         return MELLUM_SHARDING_RULES
     if isinstance(cfg, GraniteConfig):
         return GRANITE_SHARDING_RULES
+    if isinstance(cfg, Lfm2Config):
+        return LFM2_SHARDING_RULES
     return GPT2_SHARDING_RULES
 
 
@@ -178,7 +184,9 @@ class TrainStep:
     ):
         from ray_tpu.models.gpt2_moe import GPT2MoEConfig
         from ray_tpu.models.granite import GraniteConfig
+        from ray_tpu.models.lfm2 import Lfm2Config
         from ray_tpu.models.mellum import MellumConfig
+        from ray_tpu.ops.moe import SELECTION_BIAS, move_selection_bias, router_metrics
 
         self._is_moe = isinstance(model_cfg, GPT2MoEConfig)
         # What a family's layers sow for the telemetry, collections that go
@@ -186,9 +194,13 @@ class TrainStep:
         # the loss: the rows its held experts worked on ("moe_load"), and the
         # keys a query kept where a layer selects them, with the passes the
         # selection took ("attn_keys"). A state-space layer: how far a chunk
-        # decays and how large its carried state grows ("ssm_stats").
+        # decays and how large its carried state grows ("ssm_stats"). A router
+        # that selects under a bias: every expert's tokens ("moe_router"),
+        # which is also what moves the bias.
         self._sown = (["moe_load", "attn_keys"] if isinstance(model_cfg, MellumConfig)
-                      else ["ssm_stats"] if isinstance(model_cfg, GraniteConfig) else [])
+                      else ["ssm_stats"] if isinstance(model_cfg, GraniteConfig)
+                      else ["moe_load", "moe_router"] if isinstance(model_cfg, Lfm2Config)
+                      else [])
         if rules is None:
             rules = default_rules_for(model_cfg)
         self.model_cfg = model_cfg
@@ -202,13 +214,17 @@ class TrainStep:
         warmup = getattr(model_cfg, "lr_warmup_steps", 0)
         if warmup:
             learning_rate = optax.linear_schedule(0.0, learning_rate, warmup)
-        self.optimizer = optax.chain(
-            optax.clip_by_global_norm(grad_clip),
-            optax.adamw(
-                learning_rate, b2=beta2, weight_decay=weight_decay,
-                mask=lambda params: jax.tree.map(lambda p: p.ndim > 1, params),
-            ),
+        adamw = optax.adamw(
+            learning_rate, b2=beta2, weight_decay=weight_decay,
+            mask=lambda params: jax.tree.map(lambda p: p.ndim > 1, params),
         )
+        if "moe_router" in self._sown:
+            # The selection bias is a leaf of the parameters and none of the
+            # optimizer's: no moment is kept for it, nothing decays it, and
+            # what moves it is the step's own routing (below).
+            adamw = optax.masked(adamw, lambda params: jax.tree_util.tree_map_with_path(
+                lambda path, _: path[-1].key != SELECTION_BIAS, params))
+        self.optimizer = optax.chain(optax.clip_by_global_norm(grad_clip), adamw)
         self.batch_sharding = batch_sharding(mesh)
 
         def train_init(rng):
@@ -267,6 +283,8 @@ class TrainStep:
                     grads, state["opt_state"], state["params"]
                 )
                 params = optax.apply_updates(state["params"], updates)
+                if "moe_router" in (loads or {}):
+                    params = move_selection_bias(params, loads["moe_router"])
             new_state = {
                 "params": params,
                 "opt_state": opt_state,
@@ -287,6 +305,8 @@ class TrainStep:
 
                 metrics.update(moe_load_metrics(
                     loads["moe_load"], batch["idx"].size, model_cfg.top_k))
+                if "moe_router" in loads:
+                    metrics.update(router_metrics(params, loads["moe_router"]))
                 # layers that select their keys: those a query kept, and the
                 # passes over its row's scores that finding them took
                 sown = jax.tree_util.tree_leaves_with_path(loads.get("attn_keys", {}))

@@ -155,8 +155,12 @@ _EXIT_WAIT_S = 10.0
 # (models/mellum.py:Indexer); a state-space model's most negative log-decay
 # of a chunk over layers and heads (how near a chunk's exp is to flushing to
 # zero) and its largest carried-state entry (what a narrower state would
-# have to hold; models/granite.py:Mamba2Mixer).
+# have to hold; models/granite.py:Mamba2Mixer); a router that selects under a
+# bias: the largest bias of any layer and expert, and the fullest expert's
+# tokens over the mean's among all the experts of a layer, held here or not
+# (the counts that move the bias; ops/moe.py:router_metrics).
 _STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
+                "moe_bias_abs_max", "moe_router_load_max_over_mean",
                 "attn_keys_selected_mean", "attn_select_passes_mean",
                 "ssm_chunk_log_decay_min", "ssm_state_abs_max")
 

@@ -68,6 +68,14 @@ OP_NAMES = {
                          "p/h/mamba/ssm.scan/ssd_fwd", "fwd", "ssm"),
     "state_space_norm": ("jit(train_step)/jvp(Granite)/p_0/h_3/mamba/ssm.gate/norm/mul",
                          "p/h/mamba/ssm.gate/norm", "fwd", "ssm"),
+    "gated_convolution": (
+        "jit(train_step)/jvp(Lfm2)/p_0/h_2/conv/conv.mix/gated_conv_fwd/pallas_call",
+        "p/h/conv/conv.mix/gated_conv_fwd", "fwd", "conv"),
+    "gated_convolution_projection": (
+        "jit(train_step)/transpose(jvp(Lfm2))/p_0/jvp(Lfm2)/p_0/checkpoint/h_0/conv/conv.in_proj/"
+        "in_proj/dot_general", "p/h/conv/conv.in_proj/in_proj", "bwd", "conv"),
+    "operator_norm": ("jit(train_step)/jvp(Lfm2)/p_0/h_1/operator_norm/rsqrt",
+                      "p/h/operator_norm", "fwd", "norm"),
     "period_norm": ("jit(train_step)/jvp(Granite)/p_0/h_5/mixer_norm/rsqrt",
                     "p/h/mixer_norm", "fwd", "norm"),
     "backward_in_a_period": (
@@ -128,6 +136,10 @@ def _tiny(family):
         return MellumConfig.tiny(num_held=4, layer_types=(INDEXED,) * 2, qk_norm=True,
                                  index_top_k=32, index_heads=4, index_dim=16, yarn=None,
                                  block_size=256), True
+    if family == "lfm2":
+        from ray_tpu.models.lfm2 import Lfm2Config
+
+        return Lfm2Config.tiny(num_held=4), True
     from ray_tpu.models.granite import GraniteConfig
 
     return GraniteConfig.tiny(), True
@@ -141,7 +153,7 @@ def _compiled_text(cfg, t=128):  # an indexed layer packs its mask 128 keys to a
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_moe", "mellum", "mellum_indexed",
-                                    "granite"])
+                                    "granite", "lfm2"])
 def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     """The tiny configuration's step, compiled here: every scheduled
     instruction has a group of the one vocabulary and a pass, few are
@@ -162,8 +174,10 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     groups = {r[3] for r in rows.values()}
     assert {"embed", "attn.proj", "attn.core", "norm", "head", "loss", "optimizer"} <= groups
     want = {"gpt2": "mlp", "llama": "mlp", "gpt2_moe": "moe", "mellum": "moe",
-            "mellum_indexed": "moe", "granite": "ssm"}[family]
+            "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv"}[family]
     assert want in groups
+    if family == "lfm2":  # dense and routed MLPs in one model
+        assert {"mlp", "moe"} <= groups
     assert any(r[2] == "matmul" and r[3] == "head" for r in rows.values())
 
 
