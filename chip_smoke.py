@@ -53,11 +53,11 @@ GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
 
 # attn_shapes: the flash check runs at both head widths the benchmark's cells use
 FULL = {"model": None, "B": 16, "T": 1024,
-        "attn_shapes": [(16, 12, 1024, 64), (1, 8, 2048, 128)]}
+        "attn_shapes": [(16, 1024, 12, 64), (1, 2048, 8, 128)]}
 TINY = {
     "model": {"vocab_size": 257, "block_size": 256, "n_layer": 2, "n_head": 4,
               "n_embd": 64},
-    "B": 4, "T": 256, "attn_shapes": [(2, 2, 256, 64), (1, 2, 256, 128)],
+    "B": 4, "T": 256, "attn_shapes": [(2, 256, 2, 64), (1, 256, 2, 128)],
 }
 
 
@@ -149,7 +149,7 @@ def _check_losses(losses):
 
 
 def _check_flash_vs_xla(shape, seed, on_tpu):
-    """flash_causal_attention against xla_causal_attention at one (B, H, T, D),
+    """flash_causal_attention against xla_causal_attention at one (B, T, H, D),
     same seed: the output and the three gradients, as max-abs error over the
     reference's max-abs value, beside the tiles the kernel chose."""
     import jax
@@ -181,9 +181,9 @@ def _check_flash_vs_xla(shape, seed, on_tpu):
         errs[name] = float(jnp.abs(a - b).max() / jnp.abs(b).max())
     if max(errs.values()) > ATTN_REL_TOL:
         raise RuntimeError(f"flash vs xla at {shape} beyond {ATTN_REL_TOL}: {errs}")
-    b, h, t, d = shape
+    _, t, h, d = shape
     return {"shape": list(shape), "rel_err": errs,
-            "tiles": flash_tiles(b * h, t, d, jnp.bfloat16)._asdict()}
+            "tiles": flash_tiles(h, t, d, jnp.bfloat16)._asdict()}
 
 
 def _check_ssd_vs_chunked(seed, on_tpu):
@@ -277,10 +277,10 @@ def _windowed_flash_plan():
 
     from ray_tpu.ops.attention import attention_path, flash_tiles
 
-    bh, t, d, window = 64, 8192, 128, 1024
-    return {"shape": [bh, t, d], "window": window,
-            "tiles": flash_tiles(bh, t, d, jnp.bfloat16, window)._asdict(),
-            "causal_tiles": flash_tiles(bh, t, d, jnp.bfloat16)._asdict(),
+    h, t, d, window = 32, 8192, 128, 1024
+    return {"shape": [2 * h, t, d], "window": window,
+            "tiles": flash_tiles(h, t, d, jnp.bfloat16, window)._asdict(),
+            "causal_tiles": flash_tiles(h, t, d, jnp.bfloat16)._asdict(),
             "attention_path": attention_path(t)}
 
 
@@ -294,9 +294,9 @@ def _selected_flash_plan():
     from ray_tpu.ops import indexer
     from ray_tpu.ops.attention import flash_tiles
 
-    bh, t, d, top_k = 32, 16384, 128, 2048
-    return {"shape": [bh, t, d], "select": top_k,
-            "tiles": flash_tiles(bh, t, d, jnp.bfloat16, select=top_k)._asdict(),
+    h, t, d, top_k = 32, 16384, 128, 2048
+    return {"shape": [h, t, d], "select": top_k,
+            "tiles": flash_tiles(h, t, d, jnp.bfloat16, select=top_k)._asdict(),
             "mask_width": indexer.mask_width(t), "index_select_rows": indexer._select_block(t),
             "index_select_chunk": indexer._select_chunk(t)}
 
@@ -392,14 +392,13 @@ def _flash_calls_by_cell(on_tpu):
     gated_conv_fwd once where the plan saves its output (`conv_y`), else
     twice."""
     import collections
-    import re
 
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import remat
     from ray_tpu.ops import attention
-    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.mesh import kernel_tally, make_mesh
     from ray_tpu.parallel.train_step import TrainStep
 
     calls = {}
@@ -409,7 +408,7 @@ def _flash_calls_by_cell(on_tpu):
         tok = jax.ShapeDtypeStruct((shape.rows, shape.seq_len), jnp.int32)
         text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
             lowering_platforms=("tpu",)).as_text()
-        found = collections.Counter(re.findall(r'kernel_name = "(\w+)"', text))
+        found = kernel_tally(text)
         calls[name] = dict(sorted(found.items()))
         fwd = sum(n for k, n in found.items() if k.startswith("flash_") and k.endswith("_fwd"))
         # the plain causal call's name goes on with those of the two calls it

@@ -141,10 +141,12 @@ class Block(nn.Module):
 
 # What a block's remat saves after the flash kernel's output and logsumexp
 # (models/remat.py), and the ms of a step each spared for a GiB held at
-# GPT-2 small's widths on a v5e (PERF.md section 6, PR 33): the kernel's
-# operands spare the c_attn matmul's second run and the (B,T,H,D)->(B,H,T,D)
-# copies that are made again with it; the c_fc output spares that matmul's.
-REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 14.0), (("mlp_up",), 6.5))
+# GPT-2 small's widths on a v5e: the kernel's operands spare the c_attn
+# matmul's second run and the split of its output into q, k and v that is
+# made again with it (7.9 at T=256, 7.1 at T=1,024: PERF.md section 6,
+# PR 42; 14.0 while the layout copies round the kernel were made again too,
+# PR 33); the c_fc output spares that matmul's.
+REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 7.5), (("mlp_up",), 6.5))
 
 
 def remat_plan(cfg: GPT2Config, shape: remat.StepShape, limit) -> remat.RematPlan:

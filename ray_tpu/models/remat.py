@@ -148,9 +148,9 @@ def held_bytes(shape: StepShape, *, params: int, width: int, vocab: int,
 
 def attention_bytes(shape: StepShape, n_head: int, head_dim: int, itemsize: int) -> Dict[str, int]:
     """Bytes a layer, on one chip, of the flash call's named residuals
-    (ops/attention.py): the output and the three operands are (rows, heads,
-    T, head_dim) each, key-value heads already repeated; the logsumexp is a
-    float32 a head and token."""
+    (ops/attention.py): the output and the three operands are (rows, T,
+    heads * head_dim) each, key-value heads already repeated; the logsumexp
+    is a float32 a head and token."""
     tokens = shape.rows * shape.seq_len
     operand = tokens * n_head * head_dim * itemsize // shape.tp
     return {"attn_out": operand, "attn_q": operand, "attn_k": operand, "attn_v": operand,

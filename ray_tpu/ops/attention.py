@@ -9,10 +9,34 @@ instead of O(T^2). The backward is one call for all three gradients: it
 visits each score tile once and does the five matmuls the mathematics has
 (QK^T, dO V^T, P^T dO, dS^T Q, dS K) and one exp there. dk and dv of the
 keys a grid step owns are its carry; dq is summed over the key tiles in a
-float32 (heads, T, d) scratch that a head group's grid steps hand on, so
+float32 (T, heads * d) scratch that a head group's grid steps hand on, so
 that call's tile axis runs in order, and a query tile's rows are written
 out by the grid step that owns the same tile of keys, the last that any of
 its queries sees.
+
+Layout: the calls' operands are (rows, T, heads * D) and a block is
+(1, tile, g * D) at block index (row, tile, head group): the g heads of a
+grid step are lane ranges of its tiles, side by side. Mosaic wants a block's
+last dimension to be whole vregs (128 lanes) or the whole array's. Heads
+narrower than a vreg (D = 64, 32) stay in their batch row, (B, T, H * D):
+the calls read q, k, v and dO where the projections wrote them and write o,
+dq, dk and dv where the next matmul reads them, a free reshape of the
+models' (B, T, H, D), so no transpose stands beside a call and no array is
+held with D padded to 128 lanes. They go 128 // D to a vreg and stay
+together in their 128 lanes, and the MXU does the separating: the operand
+that a grid step holds (q in the forward, k and v in the backward) is copied
+once a head with the other heads' lanes set to zero, and QK^T and dO V^T
+contract over all 128 lanes (exact zeros added, the passes a 64-deep
+contraction takes on a 128-deep array anyway); the products against the
+128-wide v, dO, q and k tiles carry a head's result in its own lanes and the
+neighbours' in the others, and each head's lanes are taken from its own
+product when the grid step writes out. A head that fills its lanes (D a
+multiple of 128) is a row of its own, (B * H, T, D), one a grid step
+(`_as_rows` says why: the transposes there are free where (B, T, H * D)
+would cost a pass over each operand). The logsumexp is float32 rows,
+(B * H, 1, T); delta, sum(o * dO) over a head's lanes, is made by the
+backward call itself from the o and dO it holds, rows of a scratch: XLA made
+a transposed float32 copy of o * dO a layer to sum it into rows.
 
 Precision: q, k, v and dO tiles reach the MXU in the dtype they arrive in
 (bf16 from the models), every matmul accumulates in float32
@@ -22,7 +46,7 @@ add. Scores, mask, running max and sum, exp, the rescale, lse, delta and the dq
 / dk / dv accumulators (dq's scratch among them) are float32. float32 inputs stay float32 operands (which
 the MXU multiplies at default precision, one bf16 pass on a v5e, as XLA does).
 
-Tiles: `flash_tiles(bh, t, d, dtype, window)` chooses the tile a grid step
+Tiles: `flash_tiles(h, t, d, dtype, window)` chooses the tile a grid step
 owns, the tile it loops over and the heads it takes at once from the call's
 shape, and reckons the VMEM the call needs. The causal mask is built only on
 tiles the diagonal crosses; tiles wholly above it are never visited. With a
@@ -109,19 +133,27 @@ class FlashTiles(NamedTuple):
     select: Optional[int] = None
 
 
+def _lanes(d):
+    """d padded to whole vregs of 128 lanes."""
+    return -(-d // 128) * 128
+
+
 def _vmem_bytes(tiles, t, d, itemsize):
     """VMEM of the hungrier of the two calls (the backward), in bytes: blocks
-    are double-buffered by the pipeline, a (.., 1, t) float32 row pads to 8
-    sublanes, d pads to 128 lanes, dq's float32 accumulator for the whole
-    sequence is scratch (held once), and the loop body holds four float32
-    score tiles (s, p, dp, ds) and three casts (p, dS and dS turned)."""
+    are double-buffered by the pipeline and hold a grid step's heads side by
+    side in whole vregs of 128 lanes, a (.., 1, t) float32 row pads to 8
+    sublanes, dq's float32 accumulator and delta's rows for the whole
+    sequence are scratch (held once), and a head has its own copy of k and v
+    (its neighbours' lanes zeroed), its own float32 dk and dv (d padded to
+    128 lanes), and in the loop body four float32 score tiles (s, p, dp, ds)
+    and three casts (p, dS and dS turned)."""
     block_q, block_k, heads = tiles[:3]
-    lanes = -(-d // 128) * 128
-    whole = 2 * 2 * t * lanes * itemsize + 2 * 2 * 8 * t * 4   # q, dO; lse, delta
-    own = 2 * 5 * block_q * lanes * itemsize                   # k, v in; dq, dk, dv out
-    acc = 2 * block_q * lanes * 4 + t * lanes * 4              # dk, dv; dq
-    scores = block_q * block_k * (4 * 4 + 3 * itemsize)
-    return heads * (whole + own + acc + scores)
+    width = _lanes(heads * d)
+    whole = 2 * 3 * t * width * itemsize + heads * 3 * 8 * t * 4  # q, o, dO; lse twice, delta
+    own = 2 * 5 * block_q * width * itemsize                   # k, v in; dq, dk, dv out
+    acc = heads * 2 * block_q * _lanes(d) * (4 + itemsize) + t * width * 4  # dk, dv, k, v; dq
+    scores = heads * block_q * block_k * (4 * 4 + 3 * itemsize)
+    return whole + own + acc + scores
 
 
 def _divisor(t, cap):
@@ -129,16 +161,32 @@ def _divisor(t, cap):
     return max(b for b in range(128, max(cap, 128) + 1, 128) if t % b == 0)
 
 
-def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None,
+def _legal_heads(h, d):
+    """How many of a batch row's h heads, d wide, a grid step may take,
+    ascending: they divide h, and side by side they are whole vregs of 128
+    lanes or the whole row (Mosaic's rule for a block's last dimension). A
+    head that fills its lanes is a row of its own (`_as_rows`): one."""
+    if 128 % d and d % 128:
+        raise ValueError(
+            f"head dim {d}: the flash calls take heads that fill whole vregs of 128 "
+            "lanes or go a whole number to one")
+    if d % 128 == 0:
+        return [1]
+    return [g for g in range(1, h + 1) if h % g == 0 and (g * d % 128 == 0 or g == h)]
+
+
+def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
                 select: Optional[int] = None) -> FlashTiles:
-    """Tiles for a causal flash call on (bh, t, d) operands of `dtype`, from
-    the shape alone: the largest square tile, a multiple of 128 that divides
-    t, up to _MAX_BLOCK (a tile step's matmuls must be long enough to hide
-    its softmax, whose per-row bookkeeping costs the same for a narrow tile
-    as for a wide one); where a head is less than _TILE_ELEMS of scores,
-    several heads a grid step (a grid step's fixed cost is what a short
-    call pays). Heads and then the tile shrink until `_vmem_bytes` reckons
-    that the call fits the VMEM budget.
+    """Tiles for a causal flash call on (B, t, h, d) inputs of `dtype`,
+    from the shape alone: the largest square tile, a multiple of 128 that
+    divides t, up to _MAX_BLOCK (a tile step's matmuls must be long enough
+    to hide its softmax, whose per-row bookkeeping costs the same for a
+    narrow tile as for a wide one); where a head is less than _TILE_ELEMS of
+    scores, several heads a grid step (a grid step's fixed cost is what a
+    short call pays), of `_legal_heads` the most that stay within
+    _TILE_ELEMS, else the fewest: 128 // d where heads are narrower than a
+    vreg; one where a head fills its lanes. Heads and then the tile shrink
+    until `_vmem_bytes` reckons that the call fits the VMEM budget.
 
     With a `window` shorter than t the call is a windowed one: a tile of b
     rows visits b + window + b scores a row where window are needed (the
@@ -164,13 +212,14 @@ def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None,
         raise ValueError("a call takes a window or a selection, not both")
     cap = _MAX_BLOCK if window is None else min(_MAX_BLOCK, int(window * _WINDOW_TILE))
     block = _divisor(t, cap)
-    heads = max(1, min(bh, _TILE_ELEMS // (block * block)))
+    legal = _legal_heads(h, d)
+    heads = max((g for g in legal if g * block * block <= _TILE_ELEMS), default=legal[0])
 
-    def over_budget():
-        return _vmem_bytes((block, block, heads), t, d, itemsize) > _VMEM_BUDGET
+    def over_budget(reckon=_vmem_bytes, inner=None):
+        return reckon((block, inner or block, heads), t, d, itemsize) > _VMEM_BUDGET
 
-    while over_budget() and heads > 1:
-        heads //= 2
+    while over_budget() and heads > legal[0]:
+        heads = legal[legal.index(heads) - 1]
     while over_budget() and block > 128:
         block = _divisor(t, block - 1)
     if select is None:
@@ -181,8 +230,8 @@ def flash_tiles(bh: int, t: int, d: int, dtype, window: Optional[int] = None,
     inner = _divisor(width, block)
     if block % inner:
         block = inner
-    while heads > 1 and _select_vmem_bytes((block, inner, heads), t, d, itemsize) > _VMEM_BUDGET:
-        heads //= 2
+    while heads > legal[0] and over_budget(_select_vmem_bytes, inner):
+        heads = legal[legal.index(heads) - 1]
     return FlashTiles(block, inner, heads, None, select)
 
 
@@ -202,12 +251,15 @@ def _select_vmem_bytes(tiles, t, d, itemsize):
 LEGACY_NAMES = "_flash_bwd_dq_flash_bwd_dkv"  # the module's docstring, its last paragraph
 
 
-def _call(kernel, name, like, tiles, in_specs, out_specs, out_shape, interpret, scratch=()):
+def _call(kernel, name, like, d, tiles, in_specs, out_specs, out_shape, interpret, scratch=()):
     """The pallas_call of one of the kernels on operands like `like`,
-    (bh, t, d): grid over groups of heads and the tiles a step owns. With
-    `scratch`, which a group's grid steps hand on from one tile to the next,
-    the tiles run in order."""
-    bh, t, d = like.shape
+    (rows, t, h * d): grid over rows, their groups of heads and the tiles
+    a step owns. With `scratch`, which a group's grid steps hand on from one
+    tile to the next, the tiles run in order."""
+    b, t, width = like.shape
+    if tiles.heads not in _legal_heads(width // d, d):
+        raise ValueError(f"{tiles.heads} heads a grid step of {width // d} heads {d} wide: "
+                         "they divide a batch row's and fill whole vregs or the whole row")
     # The scoped default is enough for small calls; beyond it ask for what
     # the rule reckoned, with a quarter more for what the reckoning leaves out.
     vmem = _vmem_bytes(tiles, t, d, like.dtype.itemsize)
@@ -222,32 +274,34 @@ def _call(kernel, name, like, tiles, in_specs, out_specs, out_shape, interpret, 
     if name == "flash_bwd_fused":  # neither selected nor windowed
         name += LEGACY_NAMES
     return pl.pallas_call(
-        functools.partial(kernel, block_q=tiles.block_q, block_k=tiles.block_k),
-        grid=(pl.cdiv(bh, tiles.heads), t // tiles.block_q),
+        functools.partial(kernel, d=d, block_q=tiles.block_q, block_k=tiles.block_k),
+        grid=(b, width // (tiles.heads * d), t // tiles.block_q),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary" if scratch else "parallel"),
+            dimension_semantics=("parallel", "parallel", "arbitrary" if scratch else "parallel"),
             vmem_limit_bytes=max(_VMEM_SCOPED, vmem * 5 // 4)),
         interpret=interpret,
         name=name,
     )
 
 
-def _specs(like, tiles):
-    """Block specs of a call on (bh, t, d) operands: the tile a grid step
-    owns and the whole sequence, of a (bh, t, d) array and of a (bh, 1, t)
-    row of float32 (lse, delta)."""
-    _, t, d = like.shape
+def _specs(like, d, tiles):
+    """Block specs of a call on (rows, t, h * d) operands: the tile a grid
+    step owns and the whole sequence, of the lanes of its heads in a
+    (rows, t, h * d) array and of their (rows * h, 1, t) rows of float32
+    (lse)."""
+    _, t, width = like.shape
     g, block_q = tiles.heads, tiles.block_q
-    own = pl.BlockSpec((g, block_q, d), lambda b, i: (b, i, 0))
+    groups = width // (g * d)  # grid steps a row, tile for tile
+    own = pl.BlockSpec((1, block_q, g * d), lambda b, h, i: (b, i, h))
     # a (g, 1, block_q) block keeps Mosaic's last-two-dims tiling rule
     # satisfied, which a rank-2 (g, block_q) block does not
-    own_row = pl.BlockSpec((g, 1, block_q), lambda b, i: (b, 0, i))
-    whole = pl.BlockSpec((g, t, d), lambda b, i: (b, 0, 0))
-    whole_row = pl.BlockSpec((g, 1, t), lambda b, i: (b, 0, 0))
+    own_row = pl.BlockSpec((g, 1, block_q), lambda b, h, i: (b * groups + h, 0, i))
+    whole = pl.BlockSpec((1, t, g * d), lambda b, h, i: (b, 0, h))
+    whole_row = pl.BlockSpec((g, 1, t), lambda b, h, i: (b * groups + h, 0, 0))
     return own, own_row, whole, whole_row
 
 
@@ -255,14 +309,69 @@ def _specs(like, tiles):
 # what the kernels share
 # --------------------------------------------------------------------------
 
-_NT = (((2,), (2,)), ((0,), (0,)))  # (g, m, c) x (g, n, c) -> (g, m, n)
-_NN = (((2,), (1,)), ((0,), (0,)))  # (g, m, c) x (g, c, n) -> (g, m, n)
-_TN = (((1,), (1,)), ((0,), (0,)))  # (g, c, m) x (g, c, n) -> (g, m, n)
+_NT = (((1,), (1,)), ((), ()))  # (m, c) x (n, c) -> (m, n)
+_NN = (((1,), (0,)), ((), ()))  # (m, c) x (c, n) -> (m, n)
+_TN = (((0,), (0,)), ((), ()))  # (c, m) x (c, n) -> (m, n)
 
 
 def _dot(a, b, dims):
-    """One MXU matmul per head: operands as they are, float32 out."""
+    """One MXU matmul: operands as they are, float32 out."""
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+class _Head(NamedTuple):
+    """Where one head of a (rows, heads * d) tile lies: `lanes`, the (first,
+    last + 1) lanes cut out of the tile for it, whole vregs: its own where d
+    fills them, else the 128 (or the whole row's, if fewer) it shares with
+    its neighbours; `own`, its lanes within that cut, None where the cut is
+    the head's alone."""
+
+    lanes: tuple
+    own: Optional[tuple]
+
+    @property
+    def width(self):
+        return self.lanes[1] - self.lanes[0]
+
+    def is_own(self, shape):
+        """Which entries of a (rows, lanes) array are in the head's lanes."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return (lane >= self.own[0]) & (lane < self.own[1])
+
+    def alone(self, x):
+        """x, a cut, with its neighbours' lanes zeroed: a matmul that
+        contracts the whole cut then sums exact zeros for them."""
+        return x if self.own is None else jnp.where(self.is_own(x.shape), x, jnp.zeros_like(x))
+
+
+def _heads(d, width):
+    """The heads of a (rows, width) tile, d lanes each, side by side."""
+    span = d if d % 128 == 0 else min(128, width)
+    heads = []
+    for h in range(width // d):
+        first = h * d // span * span
+        last = min(first + span, width)  # a row that ends inside a vreg
+        heads.append(_Head((first, last),
+                           None if last - first == d else (h * d - first, (h + 1) * d - first)))
+    return heads
+
+
+def _cuts(ref, heads, rows=slice(None)):
+    """Each head's cut of `rows` of a (1, t, width) ref, a cut that heads
+    share read once."""
+    cuts = {lanes: ref[0, rows, lanes[0]:lanes[1]] for lanes in dict.fromkeys(h.lanes for h in heads)}
+    return [cuts[head.lanes] for head in heads]
+
+
+def _side_by_side(heads, parts):
+    """{lanes: tile}: the heads' results, (rows, lanes) each and whole in the
+    head's own lanes, put where a block holds them: a cut that heads share
+    takes each head's lanes from that head's part."""
+    out = {}
+    for head, part in zip(heads, parts):
+        seen = out.get(head.lanes)
+        out[head.lanes] = part if seen is None else jnp.where(head.is_own(part.shape), part, seen)
+    return out
 
 
 def _split_scale(d):
@@ -341,12 +450,12 @@ def _row_minus_col(block_q, block_k):
 
 def _own_tile(t, block_q):
     """Index of the tile this grid step owns; static where there is one."""
-    return 0 if t == block_q else pl.program_id(1)
+    return 0 if t == block_q else pl.program_id(2)
 
 
-def _rows(ref, j, block):
-    """Tile j of `block` rows along the second axis of a (g, t, d) ref."""
-    return ref[:, pl.ds(pl.multiple_of(j * block, block), block), :]
+def _rows(j, block):
+    """Rows of tile j of `block` rows, for a ref's sequence axis."""
+    return pl.ds(pl.multiple_of(j * block, block), block)
 
 
 # --------------------------------------------------------------------------
@@ -354,66 +463,102 @@ def _rows(ref, j, block):
 # --------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, window=None):
-    g, _, d = q_ref.shape
+def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible):
+    """(heads, step) of a forward grid step: step(j, carry, masked) takes the
+    online softmax of each of its heads, carry[h] = (m, l, acc), over the
+    tile of block_k keys j; a carry of None is a row's first tile, with
+    nothing to rescale. q is held a head, its neighbours' lanes zeroed
+    (`_Head.alone`); acc is as wide as the head's cut of v and whole in the
+    head's own lanes. `visible(j)` is the tile's mask."""
+    heads = _heads(d, q_ref.shape[2])
+    q_scale, s_scale = _split_scale(d)
+    qs = [head.alone(q) for head, q in zip(heads, _cuts(q_ref, heads))]
+    if q_scale != 1.0:
+        qs = [q * q_scale for q in qs]
+
+    def step(j, carry, masked=True):
+        rows = _rows(j, block_k)
+        ks, vs = _cuts(k_ref, heads, rows), _cuts(v_ref, heads, rows)
+        # stage by stage over the heads, not head by head: the order the
+        # scheduler is given is the order it keeps where it is free to choose
+        ss = [_dot(q, k, _NT) for q, k in zip(qs, ks)]  # (block_q, block_k) float32
+        if s_scale != 1.0:
+            ss = [s * s_scale for s in ss]
+        if masked:
+            seen = visible(j)
+            ss = [jnp.where(seen, s, NEG_INF) for s in ss]
+        ms = [jnp.max(s, axis=-1, keepdims=True) for s in ss]
+        if carry is not None:
+            ms = [jnp.maximum(m, m_new) for (m, _, _), m_new in zip(carry, ms)]
+        ps = [jnp.exp(s - m) for s, m in zip(ss, ms)]
+        ls = [jnp.sum(p, axis=-1, keepdims=True) for p in ps]
+        accs = [_dot(p.astype(v.dtype), v, _NN) for p, v in zip(ps, vs)]
+        if carry is not None:
+            alphas = [jnp.exp(m - m_new) for (m, _, _), m_new in zip(carry, ms)]
+            ls = [l * alpha + l_new for (_, l, _), alpha, l_new in zip(carry, alphas, ls)]
+            accs = [acc * alpha + acc_new for (_, _, acc), alpha, acc_new in zip(carry, alphas, accs)]
+        return tuple(zip(ms, ls, accs))
+
+    return heads, step
+
+
+def _fwd_init(heads, block_q):
+    """The carry of `_fwd_step` before a row's first tile."""
+    return tuple((jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((block_q, 1), jnp.float32),
+                  jnp.zeros((block_q, head.width), jnp.float32))
+                 for head in heads)
+
+
+def _fwd_write(o_ref, lse_ref, heads, carry):
+    """A forward grid step's results: each head's lanes of o, and its
+    logsumexp, a (block_q, 1) column, as the (1, block_q) row it is kept as."""
+    outs = [acc * (1.0 / l) for _, l, acc in carry]
+    for (first, last), o in _side_by_side(heads, outs).items():
+        o_ref[0, :, first:last] = o.astype(o_ref.dtype)
+    for h, (m, l, _) in enumerate(carry):
+        lse_ref[h, 0] = (m + jnp.log(l))[:, 0]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, window=None):
     ratio = block_q // block_k
     i = _own_tile(k_ref.shape[1], block_q)
-    q_scale, s_scale = _split_scale(d)
-    q = q_ref[...]
-    if q_scale != 1.0:
-        q = q * q_scale
     # entry (r, c) of q tile i against k tile j is visible iff
     # i*block_q + r >= j*block_k + c
     diff = _row_minus_col(block_q, block_k)
-
-    def step(j, carry, masked):
-        k = _rows(k_ref, j, block_k)
-        v = _rows(v_ref, j, block_k)
-        s = _dot(q, k, _NT)  # (g, block_q, block_k) float32
-        if s_scale != 1.0:
-            s = s * s_scale
-        if masked:
-            s = jnp.where(_visible(diff, j * block_k - i * block_q, window), s, NEG_INF)
-        s_max = jnp.max(s, axis=-1, keepdims=True)
-        if carry is None:  # a row's first tile: nothing to rescale
-            p = jnp.exp(s - s_max)
-            return s_max, jnp.sum(p, axis=-1, keepdims=True), _dot(p.astype(v.dtype), v, _NN)
-        m, l, acc = carry
-        m_new = jnp.maximum(m, s_max)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
-        return m_new, l, acc
+    heads, step = _fwd_step(
+        q_ref, k_ref, v_ref, d, block_k,
+        lambda j: _visible(diff, j * block_k - i * block_q, window))
 
     # k tiles 0 .. i*ratio-1 lie below the diagonal, the next `ratio` cross
     # it. Tile 0 is every row's first; it holds key 0, which every row sees.
     if isinstance(i, int):  # the only q tile: no tile lies below the diagonal
-        m, l, acc = _tile_loop(step, step(0, None, masked=True), None, 1, ratio - 1)
+        carry = _tile_loop(step, step(0, None, masked=True), None, 1, ratio - 1)
     else:
-        init = (jnp.full((g, block_q, 1), NEG_INF, jnp.float32),
-                jnp.zeros((g, block_q, 1), jnp.float32),
-                jnp.zeros((g, block_q, d), jnp.float32))
         plain, edge = _before(i * ratio, window, block_q, block_k)
-        m, l, acc = _tile_loop(step, init, plain, i * ratio, ratio, edge=edge)
-    o_ref[...] = (acc * (1.0 / l)).astype(o_ref.dtype)
-    lse = m + jnp.log(l)
-    for h in range(g):  # (g, block_q, 1) columns -> (g, 1, block_q) rows
-        lse_ref[h, 0] = lse[h, :, 0]
+        carry = _tile_loop(step, _fwd_init(heads, block_q), plain, i * ratio, ratio, edge=edge)
+    _fwd_write(o_ref, lse_ref, heads, carry)
 
 
-def _flash_fwd(q, k, v, *, tiles, interpret):
-    own, own_row, whole, _ = _specs(q, tiles)
+@functools.partial(jax.jit, static_argnames=("d", "tiles", "interpret"))
+def _forward_call(q, k, v, mask=None, *, d, tiles, interpret):
+    """o and lse from the forward call on (rows, t, h * d) operands; with
+    `mask`, the packed mask of a selection, the selected call. Under a jit
+    of its own, as `_backward_call`: a model's layers then share one trace and
+    one lowering of a kernel, which a step would else make again a layer."""
+    own, own_row, whole, _ = _specs(q, d, tiles)
+    masks = () if mask is None else (mask,)
+    b, t, width = q.shape
     return _call(
-        _fwd_kernel, "flash_fwd", q, tiles,
-        in_specs=[own, whole, whole],
+        _fwd_kernel if mask is None else _sel_fwd_kernel, "flash_fwd", q, d, tiles,
+        in_specs=[own, whole, whole, *(_mask_spec(q, m, tiles) for m in masks)],
         out_specs=[own, own_row],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((q.shape[0], 1, q.shape[1]), jnp.float32),
+            jax.ShapeDtypeStruct((b * width // d, 1, t), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q, k, v, *masks)
 
 
 # --------------------------------------------------------------------------
@@ -421,76 +566,106 @@ def _flash_fwd(q, k, v, *, tiles, interpret):
 # --------------------------------------------------------------------------
 
 
-def _bwd_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, i, block_k, visible):
-    """step(j, (dk, dv), masked) of the backward grid step that owns key tile
-    i, over the tile of block_k queries j: the tile's part of dk and dv onto
-    the carry, its part of dq onto the rows of dq_acc, which a head group's
-    first grid step clears. Scores are held transposed, (keys, queries): lse
-    and delta then broadcast along sublanes as the (.., 1, t) rows they are
-    stored as, and dS^T is an operand of dk's matmul as it stands; dq's
-    contracts its key axis. `visible(j)` is the tile's mask."""
-    q_scale, s_scale = _split_scale(k_ref.shape[2])
-    k = k_ref[...]
+def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, block_k, visible):
+    """(heads, step) of the backward grid step that owns key tile i:
+    step(j, carry, masked) over the tile of block_k queries j puts the tile's
+    part of each head's dk and dv onto carry[h] = (dk, dv), as wide as the
+    head's cut and whole in its own lanes, and its part of dq onto the rows
+    of dq_acc. A head group's first grid step clears dq_acc and fills
+    `delta`, the group's (heads, 1, t) float32 rows of sum(o * dO) over a
+    head's lanes, from the whole sequence of o and dO that it holds: both
+    are scratch that the group's grid steps hand on. k and v are held
+    a head, the neighbours' lanes zeroed (`_Head.alone`), so dq's parts of
+    the heads of one cut add up side by side. Scores are held transposed,
+    (keys, queries): lse and delta then broadcast along sublanes as the
+    (.., 1, t) rows they are stored as, and dS^T is an operand of dk's
+    matmul as it stands; dq's contracts its key axis. `visible(j)` is the
+    tile's mask."""
+    heads = _heads(d, k_ref.shape[2])
+    q_scale, s_scale = _split_scale(d)
+    ks = [head.alone(k) for head, k in zip(heads, _cuts(k_ref, heads))]
     if q_scale != 1.0:
         # a power of two: dq sums dS K with the scaled keys and is spared
         # that factor at the end; dk sums dS^T Q with q as it is
-        k = k * q_scale
-    v = v_ref[...]
+        ks = [k * q_scale for k in ks]
+    vs = [head.alone(v) for head, v in zip(heads, _cuts(v_ref, heads))]
+
+    def fill(j, _):
+        # sum(o * dO) of 128 queries is the diagonal of o dO^T, a matmul
+        # whose products are exact and whose sums are float32, and the
+        # diagonal summed over sublanes is the row that `step` reads: a sum
+        # over lanes would come out a column, and turning columns into rows
+        # costs more than this small matmul
+        rows = _rows(j, block_k)
+        on_diagonal = _row_minus_col(128, 128) == 0
+        for h, (head, o, do) in enumerate(zip(heads, _cuts(o_ref, heads, rows),
+                                              _cuts(do_ref, heads, rows))):
+            o = head.alone(o)
+            for first in range(0, block_k, 128):
+                both = _dot(o[first:first + 128], do[first:first + 128], _NT)
+                delta[h, :, pl.ds(pl.multiple_of(j * block_k + first, 128), 128)] = jnp.sum(
+                    jnp.where(on_diagonal, both, 0.0), axis=0, keepdims=True)
+        return _
 
     @pl.when(i == 0)
     def _():
         dq_acc[...] = jnp.zeros(dq_acc.shape, dq_acc.dtype)
+        jax.lax.fori_loop(0, q_ref.shape[1] // block_k, fill, 0)
 
     def step(j, carry, masked=True):
-        dk, dv = carry
-        q = _rows(q_ref, j, block_k)
-        do = _rows(do_ref, j, block_k)
-        at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
-        lse = lse_ref[:, :, at]      # (g, 1, block_k)
-        delta = delta_ref[:, :, at]
-        s = _dot(k, q, _NT)          # (g, block_q keys, block_k queries)
+        at = _rows(j, block_k)
+        qs, dos = _cuts(q_ref, heads, at), _cuts(do_ref, heads, at)
+        # stage by stage over the heads, as the forward's step
+        ss = [_dot(k, q, _NT) for k, q in zip(ks, qs)]  # (block_q keys, block_k queries)
         if s_scale != 1.0:
-            s = s * s_scale
+            ss = [s * s_scale for s in ss]
         if masked:
-            s = jnp.where(visible(j), s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + _dot(p.astype(do.dtype), do, _NN)
-        ds = (p * (_dot(v, do, _NT) - delta)).astype(q.dtype)
-        dk = dk + _dot(ds, q, _NN)
-        dq_acc[:, at, :] += _dot(ds, k, _TN)
-        return dk, dv
+            seen = visible(j)
+            ss = [jnp.where(seen, s, NEG_INF) for s in ss]
+        ps = [jnp.exp(s - lse_ref[h, :, at]) for h, s in enumerate(ss)]  # less a (1, block_k) row
+        dvs = [dv + _dot(p.astype(do.dtype), do, _NN) for (_, dv), p, do in zip(carry, ps, dos)]
+        dss = [(p * (_dot(v, do, _NT) - delta[h, :, at])).astype(do.dtype)
+               for h, (p, v, do) in enumerate(zip(ps, vs, dos))]
+        dks = [dk + _dot(ds, q, _NN) for (dk, _), ds, q in zip(carry, dss, qs)]
+        dq = {}
+        for head, ds, k in zip(heads, dss, ks):
+            part = _dot(ds, k, _TN)
+            dq[head.lanes] = part if head.lanes not in dq else dq[head.lanes] + part
+        for (first, last), part in dq.items():
+            dq_acc[at, first:last] += part
+        return tuple(zip(dks, dvs))
 
-    return step
+    return heads, step
 
 
-def _bwd_write(dq_ref, dk_ref, dv_ref, dq_acc, dk, dv, i):
-    """A backward grid step's results. No later key tile is seen by the
-    queries of tile i, so their rows of dq are whole."""
-    _, block_q, d = dk_ref.shape
+def _bwd_run(loop, refs, i, d, block_q, block_k, visible):
+    """A backward grid step: `loop(step, carry)` over its tiles from zeroed
+    dk and dv, then its results. No later key tile is seen by the queries
+    of tile i, so their rows of dq are whole."""
+    *ins, dq_ref, dk_ref, dv_ref, dq_acc, delta = refs
+    heads, step = _bwd_step(*ins, dq_acc, delta, i, d, block_k, visible)
+    zeros = tuple((jnp.zeros((block_q, head.width), jnp.float32),) * 2 for head in heads)
+    carry = loop(step, zeros)
     q_scale, s_scale = _split_scale(d)
-    dq_ref[...] = (_rows(dq_acc, i, block_q) * s_scale).astype(dq_ref.dtype)
-    dk_ref[...] = (dk * (q_scale * s_scale)).astype(dk_ref.dtype)  # 1/sqrt(d)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    dq_ref[0] = (dq_acc[_rows(i, block_q), :] * s_scale).astype(dq_ref.dtype)
+    for ref, parts, scale in ((dk_ref, [dk for dk, _ in carry], q_scale * s_scale),  # 1/sqrt(d)
+                              (dv_ref, [dv for _, dv in carry], 1.0)):
+        for (first, last), x in _side_by_side(heads, parts).items():
+            ref[0, :, first:last] = (x * scale if scale != 1.0 else x).astype(ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, *, block_q, block_k, window=None):
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+                dq_acc, delta, *, d, block_q, block_k, window=None):
     """Owns block_q keys, loops over tiles of block_k queries once, and
     yields all three gradients: dk and dv of its keys, and onto dq_acc, the
-    float32 (g, t, d) a head group's grid steps hand on, each query tile's
-    part of dq."""
-    g, _, d = k_ref.shape
+    float32 (t, heads * d) a head group's grid steps hand on, each query
+    tile's part of dq."""
     seq_len = q_ref.shape[1]
     ratio = block_q // block_k
     i = _own_tile(seq_len, block_q)
     # entry (r, c), key r of tile i against query c of tile j, is visible
     # iff j*block_k + c >= i*block_q + r
     diff = _row_minus_col(block_q, block_k)
-    step = _bwd_step(
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, i, block_k,
-        lambda j: _visible(diff, j * block_k - i * block_q, window, keys_first=True))
-
-    zeros = jnp.zeros((g, block_q, d), jnp.float32)
     # q tiles before i*ratio see none of these keys, the next `ratio` cross
     # the diagonal, the rest see all of them (with a window: the nearest do,
     # then come those its trailing edge cuts, the rest see none)
@@ -503,30 +678,35 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
             inside, any_inside = _band(window, block_q, block_k)
             last_plain = jnp.minimum(first + inside, last)
             after, edge = (first, last_plain), (last_plain, jnp.minimum(first + any_inside, last))
-    dk, dv = _tile_loop(step, (zeros, zeros), None, i * ratio, ratio, after, edge_after=edge)
-    _bwd_write(dq_ref, dk_ref, dv_ref, dq_acc, dk, dv, i)
+    _bwd_run(
+        lambda step, zeros: _tile_loop(step, zeros, None, i * ratio, ratio, after, edge_after=edge),
+        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta),
+        i, d, block_q, block_k,
+        lambda j: _visible(diff, j * block_k - i * block_q, window, keys_first=True))
 
 
-def _flash_bwd(res, do, *, tiles, interpret, mask_t=None):
-    """dq, dk, dv from the one backward call; with `mask_t`, the transposed
-    relation's packed mask, the selected call."""
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def _backward_call(res, do, mask_t=None, *, tiles, interpret):
+    """dq, dk, dv from the one backward call, (rows, t, h * d) like its
+    operands; with `mask_t`, the transposed relation's packed mask, the
+    selected call."""
     q, k, v, o, lse = res
-    delta = jnp.sum(
-        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
-    )[:, None, :]  # (bh, 1, t) — same layout as lse
-    own, _, whole, whole_row = _specs(q, tiles)
+    b, t, width = q.shape
+    d = width * b // lse.shape[0]
+    own, _, whole, whole_row = _specs(q, d, tiles)
     like_q = jax.ShapeDtypeStruct(q.shape, q.dtype)
     masks = () if mask_t is None else (mask_t,)
     return _call(
-        _bwd_kernel if mask_t is None else _sel_bwd_kernel, "flash_bwd_fused", q, tiles,
-        in_specs=[whole, own, own, whole, whole_row, whole_row,
+        _bwd_kernel if mask_t is None else _sel_bwd_kernel, "flash_bwd_fused", q, d, tiles,
+        in_specs=[whole, own, own, whole, whole, whole_row,
                   *(_mask_spec(q, m, tiles) for m in masks)],
         out_specs=[own, own, own],
         out_shape=[like_q, like_q, like_q],
         interpret=interpret,
-        # dq's accumulator: float32 rows of the whole sequence for a grid step's heads
-        scratch=(pltpu.VMEM((tiles.heads, *q.shape[1:]), jnp.float32),),
-    )(q, k, v, do, lse, delta, *masks)
+        # dq's accumulator and sum(o * dO): float32, the whole sequence of a grid step's heads
+        scratch=(pltpu.VMEM((t, tiles.heads * d), jnp.float32),
+                 pltpu.VMEM((tiles.heads, 1, t), jnp.float32)),
+    )(q, k, v, o, do, lse, *masks)
 
 
 # --------------------------------------------------------------------------
@@ -546,104 +726,39 @@ def _tile_bits(m_ref, j, block_k):
     return ((m_ref[0, :, lanes] >> (j // per_bit)) & 1) != 0
 
 
-def _sel_fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *, block_q, block_k):
+def _sel_fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *, d, block_q, block_k):
     """`_fwd_kernel` with every tile masked by the selection's bits, which
     hold the causal mask too. A row may see nothing in its first tiles:
     what those add under a running max of NEG_INF, the rescale of its
     first visible tile takes out again."""
-    g, _, d = q_ref.shape
     i = _own_tile(k_ref.shape[1], block_q)
-    q_scale, s_scale = _split_scale(d)
-    q = q_ref[...]
-    if q_scale != 1.0:
-        q = q * q_scale
-
-    def step(j, carry):
-        m, l, acc = carry
-        k = _rows(k_ref, j, block_k)
-        v = _rows(v_ref, j, block_k)
-        s = _dot(q, k, _NT)
-        if s_scale != 1.0:
-            s = s * s_scale
-        s = jnp.where(_tile_bits(m_ref, j, block_k)[None], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
-        return m_new, l, acc
-
-    init = (jnp.full((g, block_q, 1), NEG_INF, jnp.float32),
-            jnp.zeros((g, block_q, 1), jnp.float32),
-            jnp.zeros((g, block_q, d), jnp.float32))
-    m, l, acc = jax.lax.fori_loop(0, (i + 1) * (block_q // block_k), step, init)
-    o_ref[...] = (acc * (1.0 / l)).astype(o_ref.dtype)
-    lse = m + jnp.log(l)
-    for h in range(g):
-        lse_ref[h, 0] = lse[h, :, 0]
+    heads, step = _fwd_step(q_ref, k_ref, v_ref, d, block_k,
+                            lambda j: _tile_bits(m_ref, j, block_k))
+    carry = jax.lax.fori_loop(0, (i + 1) * (block_q // block_k), step,
+                              _fwd_init(heads, block_q))
+    _fwd_write(o_ref, lse_ref, heads, carry)
 
 
-def _sel_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, m_ref,
-                    dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, block_k):
+def _sel_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, m_ref,
+                    dq_ref, dk_ref, dv_ref, dq_acc, delta, *, d, block_q, block_k):
     """As `_bwd_kernel`: owns block_q keys, loops over the tiles of block_k
     queries from the diagonal's first on, every one masked; m_ref is the
     transposed relation's mask, rows keys, bits and lanes queries."""
-    g, _, d = k_ref.shape
     seq_len = q_ref.shape[1]
     i = _own_tile(seq_len, block_q)
-    step = _bwd_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, i, block_k,
-                     lambda j: _tile_bits(m_ref, j, block_k)[None])
-    zeros = jnp.zeros((g, block_q, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(i * (block_q // block_k), seq_len // block_k, step, (zeros, zeros))
-    _bwd_write(dq_ref, dk_ref, dv_ref, dq_acc, dk, dv, i)
+    _bwd_run(
+        lambda step, zeros: jax.lax.fori_loop(
+            i * (block_q // block_k), seq_len // block_k, step, zeros),
+        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta),
+        i, d, block_q, block_k, lambda j: _tile_bits(m_ref, j, block_k))
 
 
 def _mask_spec(like, mask, tiles):
-    """Block spec of the packed mask (B, t, W) beside (B * heads, t, d)
-    operands: the rows of the tile a grid step owns, of the batch row its
-    heads belong to."""
-    per_row = like.shape[0] // mask.shape[0] // tiles.heads  # grid steps a batch row
-    return pl.BlockSpec((1, tiles.block_q, mask.shape[2]), lambda b, i: (b // per_row, i, 0))
-
-
-def _flash_sel_fwd(q, k, v, mask, *, tiles, interpret):
-    own, own_row, whole, _ = _specs(q, tiles)
-    return _call(
-        _sel_fwd_kernel, "flash_fwd", q, tiles,
-        in_specs=[own, whole, whole, _mask_spec(q, mask, tiles)],
-        out_specs=[own, own_row],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((q.shape[0], 1, q.shape[1]), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, mask)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash_sel(q, k, v, mask, mask_t, tiles, interpret):
-    o, _ = _flash_sel_fwd(q, k, v, mask, tiles=tiles, interpret=interpret)
-    return o
-
-
-def _flash_sel_fwd_rule(q, k, v, mask, mask_t, tiles, interpret):
-    # as `_flash_fwd_rule`; `attn_sel` is what the backward needs of the
-    # selection, the transposed relation's mask: saved, the indexer and the
-    # selection run once a layer
-    q, k, v = (checkpoint_name(x, name) for x, name in
-               ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
-    mask_t = checkpoint_name(mask_t, "attn_sel")
-    o, lse = _flash_sel_fwd(q, k, v, mask, tiles=tiles, interpret=interpret)
-    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
-    return o, (q, k, v, o, lse, mask_t)
-
-
-def _flash_sel_bwd_rule(tiles, interpret, res, do):
-    *res, mask_t = res
-    return (*_flash_bwd(res, do, tiles=tiles, interpret=interpret, mask_t=mask_t), None, None)
-
-
-_flash_sel.defvjp(_flash_sel_fwd_rule, _flash_sel_bwd_rule)
+    """Block spec of the packed mask (B, t, W) beside operands like `like`,
+    whose rows are batch rows or their heads (`_as_rows`): the rows of the tile
+    a grid step owns, of its batch row."""
+    per_row = like.shape[0] // mask.shape[0]
+    return pl.BlockSpec((1, tiles.block_q, mask.shape[2]), lambda b, h, i: (b // per_row, i, 0))
 
 
 # --------------------------------------------------------------------------
@@ -651,26 +766,58 @@ _flash_sel.defvjp(_flash_sel_fwd_rule, _flash_sel_bwd_rule)
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, tiles, interpret):
-    o, _ = _flash_fwd(q, k, v, tiles=tiles, interpret=interpret)
-    return o
+def _as_rows(x):
+    """(B, T, H, D) as the calls take it, (rows, T, heads * D). Heads
+    narrower than a vreg stay side by side in their batch row, (B, T, H * D):
+    a free reshape of what a projection wrote, lane-dense where (.., T, D)
+    would pad D to 128 lanes in HBM. A head that fills its lanes is a row
+    of its own, (B * H, T, D): XLA has no layout of (B, T, H, D) that is
+    (B, T, H * D)'s tiling, so whatever works on heads between a projection
+    and the call (rotary, a norm, the repeat of key-value heads) would pay a
+    pass over each operand to get there, while it writes (B, H, T, D) as
+    its own output's layout for nothing (PERF.md section 6, PR 42)."""
+    b, t, h, d = x.shape
+    if d % 128:
+        return x.reshape(b, t, h * d)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
-def _flash_fwd_rule(q, k, v, tiles, interpret):
+def _as_heads(x, shape):
+    """`_as_rows` undone: (B, T, H, D) = `shape` from (rows, T, heads * D)."""
+    b, t, h, d = shape
+    if d % 128:
+        return x.reshape(shape)
+    return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash(q, k, v, mask, mask_t, tiles, interpret):
+    """(B, T, H, D) -> (B, T, H, D) through the two calls; with `mask` and
+    `mask_t` (else both None) over the keys a selection names."""
+    return _flash_fwd_rule(q, k, v, mask, mask_t, tiles, interpret)[0]
+
+
+def _flash_fwd_rule(q, k, v, mask, mask_t, tiles, interpret):
     # The backward's residuals by name, for a checkpoint policy to save
     # across a block's remat (models/remat.py): what only the kernel can
-    # give, and its operands in its own (bh, t, d) layout. A name that no
-    # policy asks for is an identity.
-    q, k, v = (checkpoint_name(x, name) for x, name in
+    # give, and its operands as it reads them (`_as_rows`). `attn_sel` is
+    # what the backward needs of a selection, the transposed relation's
+    # mask: saved, the indexer and the selection run once a layer. A name
+    # that no policy asks for is an identity.
+    q4 = q.shape
+    q, k, v = (checkpoint_name(_as_rows(x), name) for x, name in
                ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
-    o, lse = _flash_fwd(q, k, v, tiles=tiles, interpret=interpret)
+    if mask_t is not None:
+        mask_t = checkpoint_name(mask_t, "attn_sel")
+    o, lse = _forward_call(q, k, v, mask, d=q4[3], tiles=tiles, interpret=interpret)
     o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
-    return o, (q, k, v, o, lse)
+    return _as_heads(o, q4), (q, k, v, o, lse, mask_t)
 
 
-def _flash_bwd_rule(tiles, interpret, res, g):
-    return _flash_bwd(res, g, tiles=tiles, interpret=interpret)
+def _flash_bwd_rule(tiles, interpret, res, do):
+    *res, mask_t = res
+    grads = _backward_call(tuple(res), _as_rows(do), mask_t, tiles=tiles, interpret=interpret)
+    return (*(_as_heads(g, do.shape) for g in grads), None, None)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -678,12 +825,12 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_causal_attention(q, k, v, *, window=None, block_q=None, block_k=None,
                            interpret=False):
-    """q/k/v: (B, H, T, D) → (B, H, T, D); fused causal attention, with
+    """q/k/v: (B, T, H, D) → (B, T, H, D); fused causal attention, with
     `window` over the last `window` keys alone (the query's own included).
     Tiles come from `flash_tiles`; block_q / block_k override it (the tests'
     way to reach every tile shape at small sizes)."""
-    b, h, t, d = q.shape
-    tiles = flash_tiles(b * h, t, d, q.dtype, window)
+    _, t, h, d = q.shape
+    tiles = flash_tiles(h, t, d, q.dtype, window)
     if block_q or block_k:
         block_q, block_k = block_q or tiles.block_q, block_k or tiles.block_k
         if t % block_q or block_q % block_k:
@@ -692,63 +839,53 @@ def flash_causal_attention(q, k, v, *, window=None, block_q=None, block_k=None,
                 f"multiple of block_k ({block_k}): a grid step's tile is cut "
                 "into whole tiles of the other operand along the diagonal")
         tiles = tiles._replace(block_q=block_q, block_k=block_k)
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
-    o = _flash(qf, kf, vf, tiles, interpret)
-    return o.reshape(b, h, t, d)
+    return _flash(q, k, v, None, None, tiles, interpret)
 
 
 def xla_causal_attention(q, k, v, window=None):
-    """Plain einsum-softmax reference path; XLA fuses it adequately on TPU."""
-    d = q.shape[-1]
-    t = q.shape[2]
-    s = jnp.einsum("bhtd,bhsd->bhts", q, k, preferred_element_type=jnp.float32)
+    """Plain einsum-softmax reference path, (B, T, H, D) → (B, T, H, D); XLA
+    fuses it adequately on TPU."""
+    t, d = q.shape[1], q.shape[3]
+    s = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32)
     s = s / math.sqrt(d)
     mask = jnp.tril(jnp.ones((t, t), dtype=bool))
     if window is not None and window < t:
         mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", p, v)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
 
 
 def flash_selected_attention(q, k, v, mask, mask_t, top_k, *, interpret=False):
-    """q/k/v: (B, H, T, D) -> (B, H, T, D): causal attention over the keys
+    """q/k/v: (B, T, H, D) -> (B, T, H, D): causal attention over the keys
     that `mask` names, the packed mask (B, T, W) of ops/indexer.py with
     `top_k` keys a query at most, and `mask_t` its transposed relation's.
     Where top_k is the sequence or more every causal key is seen and the
     call is `flash_causal_attention`."""
-    b, h, t, d = q.shape
+    _, t, h, d = q.shape
     if top_k >= t:
         return flash_causal_attention(q, k, v, interpret=interpret)
-    tiles = flash_tiles(b * h, t, d, q.dtype, select=top_k)
     # a grid step's heads are of one batch row, whose mask they share
-    tiles = tiles._replace(heads=math.gcd(tiles.heads, h))
-    o = _flash_sel(q.reshape(b * h, t, d), k.reshape(b * h, t, d), v.reshape(b * h, t, d),
-                   mask, mask_t, tiles, interpret)
-    return o.reshape(b, h, t, d)
+    return _flash(q, k, v, mask, mask_t, flash_tiles(h, t, d, q.dtype, select=top_k), interpret)
 
 
 def xla_selected_attention(q, k, v, mask):
-    """Plain einsum-softmax over the keys the packed mask names."""
+    """Plain einsum-softmax over the keys the packed mask names, (B, T, H, D)
+    → (B, T, H, D)."""
     from ray_tpu.ops.indexer import unpack
 
-    s = jnp.einsum("bhtd,bhsd->bhts", q, k, preferred_element_type=jnp.float32)
+    s = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32)
     s = jnp.where(unpack(mask)[:, None], s / math.sqrt(q.shape[-1]), NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", p, v)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
 
 
 def selected_attention(q, k, v, mask, mask_t, top_k):
-    """Layout-adapting entry, as `causal_attention`: q/k/v (B, T, H, D) ->
-    (B, T, H, D), over the keys of the packed mask."""
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    """As `causal_attention`, over the keys of the packed mask: q/k/v
+    (B, T, H, D) -> (B, T, H, D)."""
     if attention_path(q.shape[1]) == "flash":
-        o = flash_selected_attention(qt, kt, vt, mask, mask_t, top_k)
-    else:
-        o = xla_selected_attention(qt, kt, vt, mask)
-    return o.transpose(0, 2, 1, 3)
+        return flash_selected_attention(q, k, v, mask, mask_t, top_k)
+    return xla_selected_attention(q, k, v, mask)
 
 
 def _on_tpu() -> bool:
@@ -766,7 +903,7 @@ def attention_path(seq_len: int) -> str:
 
 
 def causal_attention(q, k, v, window=None):
-    """Layout-adapting entry: q/k/v (B, T, H, D) → (B, T, H, D); `window`
+    """q/k/v (B, T, H, D) → (B, T, H, D), as the models hold them; `window`
     keys a query sees, itself included (None: all before it).
 
     Uses the pallas flash kernel on TPU for sequences long enough to matter;
@@ -774,12 +911,6 @@ def causal_attention(q, k, v, window=None):
     partitioned by the compiler: under a multi-device mesh call it through
     `parallel.train_step.attn_for_mesh` (shard_map over batch and heads).
     """
-    T = q.shape[1]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    if attention_path(T) == "flash":
-        o = flash_causal_attention(qt, kt, vt, window=window)
-    else:
-        o = xla_causal_attention(qt, kt, vt, window)
-    return o.transpose(0, 2, 1, 3)
+    if attention_path(q.shape[1]) == "flash":
+        return flash_causal_attention(q, k, v, window=window)
+    return xla_causal_attention(q, k, v, window)

@@ -220,6 +220,39 @@ def collective_tally(compiled_text: str) -> collections.Counter[Collective]:
     return tally
 
 
+_FUNCTION = re.compile(r"^  func\.func \w+ @([\w.]+)\(", re.M)
+_CALLED = re.compile(r"call @([\w.]+)\(")
+_KERNEL = re.compile(r'kernel_name = "(\w+)"')
+
+
+def kernel_tally(lowered_text: str) -> collections.Counter[str]:
+    """How often a lowered program (`lowered.as_text()`, StableHLO) runs each
+    Pallas kernel, by the kernel's name: read without a chip, as
+    `collective_tally` reads a compiled one. A kernel under a jit of its own
+    (ops/attention.py's calls) is lowered once, in that jit's function, and
+    runs as often as the function is called."""
+    starts = [(m.start(), m.group(1)) for m in _FUNCTION.finditer(lowered_text)]
+    bodies = {fn: lowered_text[at:end] for (at, fn), (end, _) in
+              zip(starts, starts[1:] + [(len(lowered_text), None)])}
+    callers = collections.defaultdict(collections.Counter)  # callee -> {caller: calls}
+    for fn, body in bodies.items():
+        for callee, n in collections.Counter(_CALLED.findall(body)).items():
+            callers[callee][fn] += n
+    runs = {"main": 1}  # times a function runs in one run of the program
+
+    def count(fn):
+        if fn not in runs:
+            runs[fn] = 0  # a function that calls itself runs no more for it
+            runs[fn] = sum(n * count(caller) for caller, n in callers[fn].items())
+        return runs[fn]
+
+    tally = collections.Counter()
+    for fn, body in bodies.items():
+        for name in _KERNEL.findall(body):
+            tally[name] += count(fn)
+    return tally
+
+
 def local_slice_info() -> dict:
     """Topology of the slice this process sees."""
     devs = jax.devices()

@@ -90,7 +90,7 @@ def test_packed_mask_and_its_transpose(t):
 
 def _attention_case(t, top_k, dtype=jnp.float32, b=2, h=4, d=32):
     ks = jax.random.split(jax.random.PRNGKey(t + top_k), 3)
-    q, k, v = (jax.random.normal(key, (b, h, t, d), dtype) for key in ks)
+    q, k, v = (jax.random.normal(key, (b, t, h, d), dtype) for key in ks)
     mask = indexer._xla_select(indexer._xla_scores(*_index_operands(b, t, seed=top_k)), top_k)
     return (q, k, v), mask, indexer.transpose_packed(mask)
 
@@ -115,11 +115,43 @@ def test_selected_kernels_agree_with_masked_attention(t, top_k):
     # and the masked einsum is attention over the selected keys alone
     seen = np.asarray(indexer.unpack(mask))
     q, k, v = (np.asarray(x, np.float64) for x in operands)
-    s = np.where(seen[:, None], np.einsum("bhtd,bhsd->bhts", q, k) / np.sqrt(q.shape[-1]), -np.inf)
+    s = np.where(seen[:, None], np.einsum("bthd,bshd->bhts", q, k) / np.sqrt(q.shape[-1]), -np.inf)
     p = np.exp(s - s.max(-1, keepdims=True))
-    by_hand = np.einsum("bhts,bhsd->bhtd", p / p.sum(-1, keepdims=True), v)
+    by_hand = np.einsum("bhts,bshd->bthd", p / p.sum(-1, keepdims=True), v)
     np.testing.assert_allclose(
         attention.xla_selected_attention(*operands, mask), by_hand, rtol=1e-4, atol=1e-5)
+
+
+# (heads a row, their width, heads a grid step): how a grid step's heads lie
+# in its tiles' lanes; two batch rows, each with its own mask
+LANES = {
+    "d32_the_whole_row_under_128_lanes": (2, 32, 2),
+    "d32_four_heads_a_vreg_of_eight": (8, 32, 4),
+    "d64_a_pair_a_vreg_of_four": (4, 64, 2),
+    "d64_two_pairs_a_step_of_eight": (8, 64, 4),
+    "d128_one_head_a_step_of_two": (2, 128, 1),
+    "d128_four_heads_a_row_each": (4, 128, 1),
+}
+
+
+@pytest.mark.parametrize("case", LANES)
+def test_selected_kernels_whatever_lanes_a_head_lies_in(case):
+    """Forward and the three gradients with the heads a grid step set by
+    hand: a head's lanes of a tile, the batch row's mask and the rows of lse
+    and delta are found by the block index maps, and random operands are
+    distinct a head and a row."""
+    h, d, heads = LANES[case]
+    t, top_k = 256, 40
+    operands, mask, mask_t = _attention_case(t, top_k, h=h, d=d)
+    assert not (np.asarray(mask[0]) == np.asarray(mask[1])).all()
+    tiles = attention.flash_tiles(h, t, d, jnp.float32, select=top_k)._replace(heads=heads)
+    want, want_grads = _value_and_grads(
+        lambda q, k, v: attention.xla_selected_attention(q, k, v, mask), operands)
+    got, got_grads = _value_and_grads(
+        lambda q, k, v: attention._flash(q, k, v, mask, mask_t, tiles, True), operands)
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    for g, w, name in zip(got_grads, want_grads, "qkv"):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=2e-5, err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("top_k", [256, 300])
@@ -160,7 +192,7 @@ def test_rows_that_see_nothing_in_their_first_tiles(t, top_k, heads):
     step visits are empty and add nothing to dq, dk or dv; several heads a
     grid step share the batch row's mask."""
     ks = jax.random.split(jax.random.PRNGKey(t + top_k), 3)
-    operands = tuple(jax.random.normal(key, (2, heads, t, 32), jnp.float32) for key in ks)
+    operands = tuple(jax.random.normal(key, (2, t, heads, 32), jnp.float32) for key in ks)
     nearest = jnp.broadcast_to(jnp.arange(t, dtype=jnp.float32)[None, None, :], (2, t, t))
     mask = indexer._xla_select(nearest, top_k)
     seen = np.asarray(indexer.unpack(mask))[0]

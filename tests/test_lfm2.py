@@ -5,11 +5,9 @@ leaf no gradient moves, the four quarters of the experts against the uncut
 layer, the remat rule's plan for the cell, the cell's lowered step and the
 telemetry's two gauges."""
 
-import collections
 import contextlib
 import json
 import os
-import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +20,7 @@ from ray_tpu.models.lfm2 import ATTENTION, CONV, Lfm2, Lfm2Config
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops import attention, moe, short_conv
 from ray_tpu.ops.moe import SELECTION_BIAS, SELECTION_BIAS_RATE, SIGMOID, ExpertShare
-from ray_tpu.parallel.mesh import make_mesh
+from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
 
@@ -369,7 +367,7 @@ def test_the_cell_s_step_runs_the_kernels_as_its_plan_says(monkeypatch):
     tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
     text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
         lowering_platforms=("tpu",)).as_text()
-    calls = collections.Counter(re.findall(r'kernel_name = "(\w+)"', text))
+    calls = kernel_tally(text)
     assert "conv_y" in remat.traced(cfg).names
     # megablox names its kernels' bodies "kernel" (the compiler names the
     # calls gmm and tgmm after the functions round them)
@@ -380,7 +378,7 @@ def test_the_cell_s_step_runs_the_kernels_as_its_plan_says(monkeypatch):
     monkeypatch.setattr(lfm2, "REMAT_RUNGS", lfm2.REMAT_RUNGS[1:])
     text = ts.__class__(cfg, ts.mesh, telemetry=False)._step.trace(
         state, {"idx": tok, "targets": tok}).lower(lowering_platforms=("tpu",)).as_text()
-    calls = collections.Counter(re.findall(r'kernel_name = "(\w+)"', text))
+    calls = kernel_tally(text)
     assert (calls["gated_conv_fwd"], calls["gated_conv_bwd"]) == (8, 4)
 
 

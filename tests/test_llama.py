@@ -4,6 +4,8 @@ Same semantics-preservation contract as test_train_step.py: every parallelism
 axis combination must give the single-device loss trajectory, because the
 shardings only move FLOPs. Plus unit checks for RoPE and GQA math."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -131,9 +133,7 @@ def test_residuals_saved_across_remat_change_no_loss_and_no_gradient(saved, monk
     from ray_tpu.models import llama, remat
     from ray_tpu.ops.attention import flash_causal_attention
 
-    def attn(q, k, v):  # (B, T, H, D), as LlamaConfig.attn_fn takes them
-        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        return flash_causal_attention(q, k, v, interpret=True).transpose(0, 2, 1, 3)
+    attn = functools.partial(flash_causal_attention, interpret=True)  # (B, T, H, D)
 
     cfg = LlamaConfig.tiny(attn_fn=attn, block_size=256, dtype=jnp.float32)
     batch = _batch(np.random.default_rng(3), B=2, T=256)

@@ -7,7 +7,6 @@ dropped); the YaRN table against numbers worked by hand; and what the layer
 sows, which leaves the step program as it was.
 """
 
-import collections
 import hashlib
 import json
 import math
@@ -26,7 +25,7 @@ from ray_tpu.models.loss import loss_fn
 from ray_tpu.models.mellum import Mellum, MellumConfig, YarnScaling, yarn_inv_freq
 from ray_tpu.ops import attention
 from ray_tpu.ops.moe import ExpertShare
-from ray_tpu.parallel.mesh import make_mesh
+from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
 
@@ -212,18 +211,20 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
 # Each cell's configuration at its cell's shape (rows a chip, sequence
 # length) on its cell's mesh, with the rule of models/remat.py given a v5e's
 # limit, so that the program is the cell's own: sha256 of the step as
-# `_step_text` gives it, taken on the commit that wrote the loss as
-# logsumexp less the target's logit (PR 37: the loss is in every program, so
-# all changed by design there, as in PR 33 and PR 35, and were pinned again
-# for the next PR that means to leave them alone); the layers
+# `_step_text` gives it, taken on the commit that gave the flash calls
+# their operands' rows (PR 42: (B, T, H * D) where heads are narrower than
+# a vreg, delta made inside the backward call everywhere; the calls are in
+# every program, so all changed by design there, as in PR 33, PR 35 and
+# PR 37, and were pinned again for the next PR that means to leave them
+# alone); the layers
 # whose attention is windowed, of all; the names the rule saves there after
 # the first rung.
 PINNED_STEPS = {
-    "gpt2_small": ("add2929d62f443901835f8e4201ce13b3f726688acfccf417674e0cb6da52e89", 32, 1024, 0, 12,
+    "gpt2_small": ("eca64911e99d36ebc7cd2ff68eaea86fb593b0dd00a52d88a7cde4ceb3cec8a5", 32, 1024, 0, 12,
                    ("attn_q", "attn_k", "attn_v", "mlp_up")),
-    "mistral_7b_l8": ("5f811bf68e55105a72f1c84e52a74870982bbc39e8a4bcfce632b077cca36547", 1, 8192, 0, 8,
+    "mistral_7b_l8": ("0eb48414debed15ee727d223dea32f572416e645ef708a92042b33c41c911472", 1, 8192, 0, 8,
                       ("mlp_up",)),
-    "mellum2_12b_l4_ep4": ("14161c0e77bdc486b87eda02171041a88c50aba762cf08347edfa77cecfb5a9d", 2, 8192, 3, 4,
+    "mellum2_12b_l4_ep4": ("f199ec865398c04b83d05b600fe684119d3c870385234ca2fc08109de29dcfbc", 2, 8192, 3, 4,
                            ("attn_q", "attn_k", "attn_v")),
 }
 
@@ -262,7 +263,7 @@ def test_old_configurations_lower_to_the_parent_s_step(name, monkeypatch):
     tok = jax.ShapeDtypeStruct((rows * chips, seq_len), jnp.int32)
     text = _step_text(ts, state, {"idx": tok, "targets": tok})
     assert remat.traced(ts.model.config).names == remat.FIRST_RUNG + saved
-    calls = collections.Counter(re.findall(r'kernel_name = "(flash_\w+)"', text))
+    calls = {k: n for k, n in kernel_tally(text).items() if k.startswith("flash_")}
     win = f"flash_win{sizes.get('sliding_window')}_"
     kernels = {"fwd": layers - windowed, "bwd_fused": layers - windowed}
     # the plain causal backward call's name ends with those of the two calls

@@ -122,13 +122,13 @@ def _entry_results(compiled_text):
 
 def test_flash_forward_compiles(one_chip):
     c = jax.jit(attention.flash_causal_attention).lower(
-        *_qkv((16, 12, 1024, 64), one_chip)).compile()
+        *_qkv((16, 1024, 12, 64), one_chip)).compile()
     assert c.as_text().count("tpu_custom_call") == 1
 
 
 def test_flash_backward_compiles(one_chip):
     grad = jax.grad(_loss(attention.flash_causal_attention), argnums=(0, 1, 2))
-    c = jax.jit(grad).lower(*_qkv((16, 12, 1024, 64), one_chip)).compile()
+    c = jax.jit(grad).lower(*_qkv((16, 1024, 12, 64), one_chip)).compile()
     # forward (for the residuals) + the one backward call
     assert c.as_text().count("tpu_custom_call") == 2
     assert _kernel_calls(c.as_text()) == dict.fromkeys(KERNELS, 1)
@@ -136,15 +136,19 @@ def test_flash_backward_compiles(one_chip):
 
 def test_flash_long_wide_heads_compile(one_chip):
     fn = jax.value_and_grad(_loss(attention.flash_causal_attention), argnums=(0, 1, 2))
-    c = jax.jit(fn).lower(*_qkv((2, 16, 4096, 128), one_chip)).compile()
+    c = jax.jit(fn).lower(*_qkv((2, 4096, 16, 128), one_chip)).compile()
     assert c.as_text().count("tpu_custom_call") == 2
 
 
 @pytest.mark.parametrize("shape", [
-    (128, 12, 256, 64),    # gpt2_small.t256: several heads a grid step
-    (32, 12, 1024, 64),    # gpt2_small.t1024
-    (1, 32, 8192, 128),    # mistral_7b_l8.fsdp4_t8192, one chip's share
-    (1, 7, 256, 64),       # a prime bh: the last grid step's heads run past the end
+    (128, 256, 12, 64),    # gpt2_small.t256: two pairs of heads a grid step
+    (32, 1024, 12, 64),    # gpt2_small.t1024: a pair, one vreg of lanes
+    (1, 8192, 32, 128),    # mistral_7b_l8.fsdp4_t8192, one chip's share
+    (1, 4096, 32, 64),     # granite4_h_micro_l10.t4096's attention layer
+    (2, 8192, 32, 64),     # lfm2_8b_a1b_l5_ep4.t8192's
+    (1, 256, 7, 64),       # a prime h: the whole row, which ends inside a vreg
+    (2, 256, 8, 32),       # four heads a vreg
+    (2, 256, 2, 32),       # a row narrower than a vreg
 ], ids=lambda s: "x".join(map(str, s)))
 def test_flash_compiles_at_the_cells_shapes(one_chip, shape):
     """Forward and backward with the tiles `flash_tiles` picks for the
@@ -282,12 +286,12 @@ def test_the_plan_at_a_shape_no_chip_ran_fits_the_chip(topo, monkeypatch):
 
 
 def test_windowed_flash_compiles_at_the_cell_s_shape(one_chip):
-    """mellum2_12b_l4_ep4.t8192's window layers: (2, 32, 8192, 128) under a
+    """mellum2_12b_l4_ep4.t8192's window layers: (2, 8192, 32, 128) under a
     window of 1,024, forward and backward with the tiles `flash_tiles`
     picks, each call under the name that says its window."""
     windowed = lambda q, k, v: attention.flash_causal_attention(q, k, v, window=1024)
     fn = jax.value_and_grad(_loss(windowed), argnums=(0, 1, 2))
-    text = jax.jit(fn).lower(*_qkv((2, 32, 8192, 128), one_chip)).compile().as_text()
+    text = jax.jit(fn).lower(*_qkv((2, 8192, 32, 128), one_chip)).compile().as_text()
     names = _CUSTOM_CALL.findall(text)
     assert len(names) == text.count("tpu_custom_call") == 2
     for kernel in ("flash_win1024_fwd", "flash_win1024_bwd_fused"):
@@ -325,14 +329,14 @@ def test_expert_share_compiles_at_the_cell_s_size(one_chip, monkeypatch):
 
 
 def test_selected_flash_compiles_at_the_cell_s_shape(one_chip):
-    """keye_vl2_30b_l4_ep8.t16384's layers: (1, 32, 16384, 128) over 2,048
+    """keye_vl2_30b_l4_ep8.t16384's layers: (1, 16384, 32, 128) over 2,048
     keys a query named by a packed mask, forward and backward with the tiles
     `flash_tiles` picks, each call under the name that says k."""
     mask = jax.ShapeDtypeStruct((1, 16384, 512), jnp.int32, sharding=one_chip)
     selected = lambda q, k, v, m, mt: attention.flash_selected_attention(
         q, k, v, m, mt, 2048).astype(jnp.float32).sum()
     fn = jax.value_and_grad(selected, argnums=(0, 1, 2))
-    text = jax.jit(fn).lower(*_qkv((1, 32, 16384, 128), one_chip), mask, mask).compile().as_text()
+    text = jax.jit(fn).lower(*_qkv((1, 16384, 32, 128), one_chip), mask, mask).compile().as_text()
     names = _CUSTOM_CALL.findall(text)
     assert len(names) == text.count("tpu_custom_call") == 2
     for kernel in ("flash_sel2048_fwd", "flash_sel2048_bwd_fused"):
@@ -460,8 +464,10 @@ def test_the_scope_table_names_the_ledger_s_ops_of_gpt2_small_t256(topo, monkeyp
     them. `multiply_reduce_fusion -> (f32[768], f32[128,256], f32[128,256],
     f32[768], bf16[128,256,768])` is 24 input-gradient matmuls (`c_fc`,
     `c_attn`, the head) whose name is their epilogue's, a LayerNorm's
-    backward sums; `copy -> bf16[128,12,256,64]` is the layout copies round
-    the flash calls; the Pallas calls are kernels under their own names."""
+    backward sums; `copy -> bf16[128,12,256,64]`, until PR 42 the layout
+    copies round the flash calls, 96 a step, is gone with every other op on
+    an array of that shape or of (128, 256, 12, 64): the calls read
+    (128, 256, 768); the Pallas calls are kernels under their own names."""
     from ray_tpu.models import remat
     from ray_tpu.train import _device_profile as dp
 
@@ -478,9 +484,8 @@ def test_the_scope_table_names_the_ledger_s_ops_of_gpt2_small_t256(topo, monkeyp
     assert fused == {("h/mlp/c_fc", "bwd", "matmul", "mlp"): 12,
                      ("h/attn/c_attn", "bwd", "matmul", "attn.proj"): 11,
                      ("wte.attend", "bwd", "matmul", "head"): 1}, fused
-    copies = collections.Counter(
-        (r[0], r[2], r[3]) for r in rows if r[4] == "copy copy -> bf16[128,12,256,64]")
-    assert set(copies) == {("h/attn", "copy", "attn.core")} and sum(copies.values()) >= 48
+    by_heads = [r[4] for r in rows if "[128,12,256,64]" in r[4] or "[128,256,12,64]" in r[4]]
+    assert not by_heads, by_heads
     kernels = collections.Counter((r[0], r[1], r[3]) for r in rows if r[2] == "kernel")
     assert kernels == {("h/attn/flash_fwd", "fwd", "attn.core"): 12,
                        (f"h/attn/{KERNELS[1]}", "bwd", "attn.core"): 12}, kernels
