@@ -269,6 +269,46 @@ def _check_gated_conv_vs_plain(seed, on_tpu):
             "rows_a_tile": short_conv._tile(t), "conv_path": short_conv.conv_path(d, k)}
 
 
+def _check_flash_mla_vs_plain(seed, on_tpu):
+    """ops/attention.py's latent pair against the plain form in float32 at
+    the benchmark's head widths (32 heads, scores 128 + 64 deep, values 128)
+    on a quarter of a row of its tokens, same seed: the output and the five
+    gradients (q's two parts, the heads' own keys, the key all heads share,
+    the values), as max-abs error over the reference's max-abs value; and
+    the tiles the rule gives the cell's own call."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+
+    b, t, h, d, r = (1, 2048, 32, 128, 64) if on_tpu else (1, 128, 2, 128, 64)
+    shapes = ((b, t, h, d), (b, t, h, r), (b, t, h, d), (b, t, r), (b, t, h, d), (b, t, h, d))
+    *ops, do = (jax.random.normal(k, s, jnp.bfloat16)
+                for k, s in zip(jax.random.split(jax.random.PRNGKey(seed), 6), shapes))
+
+    def run(attn, ops):
+        def loss(*ops):
+            o = attn(*ops)
+            return (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(), o
+
+        grads, o = jax.jit(jax.grad(loss, argnums=range(5), has_aux=True))(*ops)
+        return (o, *grads)
+
+    kernels = run(lambda *a: attention.flash_latent_attention(*a, interpret=not on_tpu), ops)
+    with jax.default_matmul_precision("highest"):
+        plain = run(attention.xla_latent_attention, [x.astype(jnp.float32) for x in ops])
+    errs = {}
+    for name, got, want in zip(("o", "dq", "dq_shared", "dk", "dk_shared", "dv"), kernels, plain):
+        got = got.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"latent attention {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"latent pair vs plain form beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": [b, t, h, d, r], "rel_err": errs,
+            "tiles_of_the_cell": list(attention.flash_tiles(32, 8192, d, jnp.bfloat16, shared=r))}
+
+
 def _windowed_flash_plan():
     """The tiles of the windowed flash call of the benchmark's window layers,
     (2 x 32, 8192, 128) under a window of 1,024, beside the causal call's at
@@ -469,6 +509,7 @@ def one_chip_loop(config):
         for shape in config["attn_shapes"]]
     report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
     report["gated_conv_vs_plain"] = _check_gated_conv_vs_plain(config["seed"], on_tpu)
+    report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()
     report["index_select_vs_top_k"] = _check_selection(config["seed"], on_tpu)

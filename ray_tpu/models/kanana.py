@@ -1,0 +1,342 @@
+"""Decoder whose attention reads its keys and values through a latent (MLA)
+and whose MLPs are dense in the leading layers and routed after them, with a
+shared expert beside the routed ones (kanana-2-30b-a3b-instruct-2601:
+`model_type` deepseek_v3; HF `modeling_deepseek_v3.py`).
+
+The equations, with d the hidden size, H heads and x the residual stream:
+
+    x0 = E[idx]
+    a layer:  x <- x + attn(RMSNorm(x));  x <- x + ffn(RMSNorm(x))
+    logits = W_head RMSNorm(x)       untied; operands in the compute dtype,
+                                     float32 sums
+    loss   = mean cross-entropy of the next token             float32
+
+attention (ops/attention.py's latent pair, flash_mla_fwd and
+flash_mla_bwd_fused on a TPU), a head's widths `nope_dim` (128), `rope_dim`
+(64) and `v_dim` (128), the latent `kv_latent` (512) wide:
+
+    q = W_q h                        d -> H x (nope + rope); a head's q is
+                                     [q_nope ; q_pe]
+    [c ; k_pe] = W_kva h             d -> latent + rope; k_pe is one key a
+                                     token, for all heads, and is not normed
+    c <- RMSNorm(c)
+    [k_nope ; v] = W_kvb c           latent -> H x (nope + v), a head's
+                                     [k_nope ; v]
+    rotary on q_pe and k_pe alone, interleaved: the rope_dim entries are read
+    as adjacent pairs (put apart, then the half-split rotation), theta
+    `rope_theta`, no scaling
+    scores of head h: (q_nope_h . k_nope_h + q_pe_h . k_pe) / sqrt(nope + rope),
+    causal softmax; o_h = P_h v_h
+    out = W_o o                      H x v -> d; no bias anywhere
+
+This is the expanded form, training's; the absorbed form (scores against the
+latent itself) is decode's and is not here. No array of H keys nope + rope
+wide is made: the kernels add the two products tile by tile.
+
+ffn of the first `num_dense_layers` layers: W_down (silu(W_gate h) * W_up h),
+`intermediate` wide. Of the others: ops/moe.py's `ExpertShare` with the
+SIGMOID router (models/lfm2.py says what it computes; here top 6 of 128, the
+gates over their sum + 1e-20, times 2.448), of which this program computes
+`num_held` experts from `first_expert` on, **plus the shared expert**, one
+SwiGLU `shared_experts * expert_dim` wide that every token passes through:
+under a share it is whole on every chip, and counted once when shares are
+summed.
+
+All blocks are one parameter group, `p_0`, which sows its routed blocks'
+choices stacked, (routed blocks, B, T, top_k): models/lfm2.py says why. The
+bias's rule, the initialisers and the absence of an auxiliary loss are under
+`assumed` in bench/configs/kanana2_30b_l5_ep8.json.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.models import remat
+from ray_tpu.models.llama import (LLAMA_SHARDING_PATTERNS, LlamaMLP, RMSNorm, apply_rope,
+                                  rope_angles)
+from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
+from ray_tpu.parallel.mesh import ShardingRules, pin
+
+
+@dataclasses.dataclass(frozen=True)
+class KananaConfig:
+    vocab_size: int = 128256
+    block_size: int = 32768
+    n_embd: int = 2048
+    n_layer: int = 48
+    num_dense_layers: int = 1
+    n_head: int = 32
+    kv_latent: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    intermediate: int = 6144  # of the dense layers' MLP
+    expert_dim: int = 768
+    num_experts: int = 128  # the router's width
+    top_k: int = 6
+    first_expert: int = 0
+    num_held: Optional[int] = None  # experts computed here; None: all
+    shared_experts: int = 2  # one SwiGLU this many expert widths wide
+    routed_scaling: float = 2.448
+    gate_eps: float = 1e-20
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    use_flash_attention: bool = True
+    attn_fn: Any = None  # set under a mesh, which the latent pair has no form for yet
+    lr_warmup_steps: int = 2000  # as MellumConfig.lr_warmup_steps
+
+    @property
+    def mlp_dim(self) -> int:
+        return self.intermediate
+
+    @property
+    def shared_dim(self) -> int:
+        return self.shared_experts * self.expert_dim
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts if self.num_held is None else self.num_held
+
+    @property
+    def routed_layers(self) -> int:
+        return max(0, self.n_layer - self.num_dense_layers)
+
+    def attention_params(self) -> int:
+        d, h = self.n_embd, self.n_head
+        return (d * h * (self.nope_dim + self.rope_dim) + d * (self.kv_latent + self.rope_dim)
+                + self.kv_latent * h * (self.nope_dim + self.v_dim) + h * self.v_dim * d)
+
+    def matmul_params(self) -> int:
+        """Each layer's four attention matrices and its MLP (the dense one's
+        three matrices; a routed one's router, the shared expert whole and
+        the expert matrices a token meets at even routing: top_k experts, of
+        which held / num_experts are here), and the untied head. The
+        embedding is a look-up."""
+        d = self.n_embd
+        experts = self.top_k * self.experts_held / self.num_experts * 3 * d * self.expert_dim
+        routed = d * self.num_experts + 3 * d * self.shared_dim + experts
+        dense = self.n_layer - self.routed_layers
+        return int(self.n_layer * self.attention_params() + dense * 3 * d * self.intermediate
+                   + self.routed_layers * routed + self.vocab_size * d)
+
+    def flops_per_token(self, seq_len: int) -> int:
+        """6 x matmul parameters + the causal term, which for a score nope +
+        rope deep and a value v wide is 6 T H (nope + rope + v) / 2 a layer
+        (GPT2Config.flops_per_token's rule, where both are the head's one
+        width)."""
+        return (6 * self.matmul_params() + 3 * self.n_layer * seq_len * self.n_head
+                * (self.nope_dim + self.rope_dim + self.v_dim))
+
+    @classmethod
+    def tiny(cls, **kw):
+        base = dict(vocab_size=512, block_size=128, n_embd=64, n_layer=3, n_head=4,
+                    kv_latent=32, nope_dim=16, rope_dim=8, v_dim=16, intermediate=96,
+                    expert_dim=32, num_experts=8, top_k=2)
+        base.update(kw)
+        return cls(**base)
+
+
+def pairs_apart(x):
+    """(..., 2 n) read as n adjacent pairs -> (..., 2 n) with the pairs'
+    first entries in the first half and their second in the second: the
+    order in which the half-split rotation turns each pair (the source's
+    `rope_interleave`). Queries and keys take the same order, so their
+    products are those of the pairs where they lay."""
+    *lead, width = x.shape
+    return x.reshape(*lead, width // 2, 2).swapaxes(-1, -2).reshape(*lead, width)
+
+
+class LatentAttention(nn.Module):
+    """(B, T, d) -> (B, T, d): the module docstring's attention."""
+
+    config: KananaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        if cfg.attn_fn is not None:
+            raise NotImplementedError("latent attention runs on one device")
+        B, T, C = x.shape
+        H, nope, rope = cfg.n_head, cfg.nope_dim, cfg.rope_dim
+        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+        with jax.named_scope("mla.q"):
+            q = dense(H * (nope + rope), "q_proj")(x).reshape(B, T, H, nope + rope)
+            q, q_pe = q[..., :nope], q[..., nope:]
+        with jax.named_scope("mla.kv_a"):
+            latent = dense(cfg.kv_latent + rope, "kv_a_proj")(x)
+            latent, k_pe = latent[..., :cfg.kv_latent], latent[..., cfg.kv_latent:]
+        with jax.named_scope("mla.kv_norm"):
+            latent = RMSNorm(cfg.rms_eps, name="kv_a_norm")(latent)
+        with jax.named_scope("mla.kv_b"):
+            kv = dense(H * (nope + cfg.v_dim), "kv_b_proj")(latent).reshape(
+                B, T, H, nope + cfg.v_dim)
+            k, v = kv[..., :nope], kv[..., nope:]
+        with jax.named_scope("mla.rope"):
+            angles = rope_angles(rope, cfg.rope_theta, jnp.arange(T))
+            q_pe = apply_rope(pairs_apart(q_pe), angles)
+            k_pe = apply_rope(pairs_apart(k_pe)[:, :, None], angles)[:, :, 0]
+        with jax.named_scope("attn.core"):
+            if cfg.use_flash_attention:
+                from ray_tpu.ops.attention import latent_attention
+            else:
+                from ray_tpu.ops.attention import xla_latent_attention as latent_attention
+            y = latent_attention(q, q_pe, k, k_pe, v)
+        with jax.named_scope("mla.o"):
+            return dense(C, "o_proj")(y.reshape(B, T, H * cfg.v_dim))
+
+
+class SharedExpert(nn.Module):
+    """The SwiGLU every token passes through beside its routed experts."""
+
+    config: KananaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+        gate, up = (checkpoint_name(dense(cfg.shared_dim, name)(x), "shared_up")
+                    for name in ("gate", "up"))
+        return dense(cfg.n_embd, "down")(nn.silu(gate) * up)
+
+
+class KananaBlock(nn.Module):
+    """A block and the choices of its expert layer, (x, (B, T, top_k)); a
+    block with a dense MLP hands up None."""
+
+    config: KananaConfig
+    routed: bool
+    stream: Any = None  # the residual stream's sharding, or None (models/llama.py)
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        x = pin(x, self.stream)
+        x = pin(x + LatentAttention(cfg, name="attn")(RMSNorm(cfg.rms_eps, name="attn_norm")(x)),
+                self.stream)
+        h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
+        if not self.routed:
+            return pin(x + LlamaMLP(cfg, name="mlp")(h), self.stream), None
+        y, chosen = ExpertShare(
+            cfg.n_embd, cfg.expert_dim, cfg.num_experts, cfg.top_k, cfg.first_expert,
+            cfg.num_held, cfg.dtype, router=SIGMOID, scaling=cfg.routed_scaling,
+            hand_up_choices=True, gate_eps=cfg.gate_eps, name="moe")(h)
+        with jax.named_scope("moe.shared"):
+            y = y + SharedExpert(cfg, name="shared")(h)
+        return pin(x + y, self.stream), chosen
+
+
+# What a block's remat saves after the first rung (the latent pair's output
+# and logsumexp), and the ms of a step each spared for a GiB held in the
+# benchmark's cell on a v5e (my chip run, PR 43, call 2; PERF.md section 6:
+# 552.35 ms a step with the first rung alone): the kernels' operands spare
+# the four projections before them, the latent's norm and the rotary (20.44
+# ms for 2.21 GiB); the shared expert's gate and up those two matmuls in
+# four layers (2.66 ms for 0.375 GiB); the dense MLP's likewise in one (4.28
+# ms for 0.375 GiB). The expert layer's own residuals have no names.
+REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v", "attn_q_shared", "attn_k_shared"), 9.3),
+               (("shared_up",), 7.1), (("mlp_up",), 11.4))
+
+
+def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
+    """What the blocks of a step of this shape save across remat, under a
+    chip's `limit` of bytes: a pure function of its arguments. A name's
+    bytes are its layers' mean over all layers, since the rule counts a
+    layer's bytes n_layer times."""
+    d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
+    tokens = shape.rows * shape.seq_len
+    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    head = lambda width: tokens * cfg.n_head * width * itemsize
+    dense = cfg.n_layer - cfg.routed_layers
+    name_bytes = dict(
+        attn_out=head(cfg.v_dim), attn_lse=tokens * cfg.n_head * 4,
+        attn_q=head(cfg.nope_dim), attn_k=head(cfg.nope_dim), attn_v=head(cfg.v_dim),
+        attn_q_shared=head(cfg.rope_dim),
+        attn_k_shared=tokens * max(cfg.rope_dim, 128) * itemsize,  # a vreg of lanes a token
+        shared_up=share(2 * tokens * cfg.shared_dim * itemsize, cfg.routed_layers),
+        mlp_up=share(2 * tokens * cfg.intermediate * itemsize, dense))
+    params = (cfg.n_layer * cfg.attention_params() + dense * 3 * d * cfg.intermediate
+              + cfg.routed_layers * (d * cfg.num_experts + 3 * d * cfg.shared_dim
+                                     + cfg.experts_held * 3 * d * cfg.expert_dim)
+              + 2 * cfg.vocab_size * d)  # embedding and the untied head
+    held = remat.held_bytes(
+        shape, params=params, width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
+        itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+
+
+def _block_bytes(cfg: KananaConfig, itemsize: int) -> int:
+    """What the largest block's backward works in, bytes a token, from its
+    widths: the latent pair's operands and output (q's two parts, a head's
+    own keys, the values, o), each with its gradient; and of a routed block
+    the expert layer's buffers of a row an assignment that are as wide as the
+    stream (the rows gathered and the rows given back, each with its
+    gradient; the ones an expert wide are live a grouped matmul at a time),
+    of a dense one the MLP's gate and up with their gradients. 172 KB a token
+    at the published widths in bf16: the step compiled for a v5e at the
+    benchmark's cell holds 11.51 GiB with the first rung alone, 12.71 with
+    the kernels' operands saved too and 13.27 with every rung, where this
+    makes the rule reckon 11.51, 12.71 and 13.46 (tests/test_tpu_compile.py)."""
+    heads = 2 * cfg.n_head * (2 * cfg.nope_dim + cfg.rope_dim + 2 * cfg.v_dim) * itemsize
+    experts = cfg.top_k * 4 * cfg.n_embd * itemsize
+    dense = 4 * cfg.intermediate * itemsize
+    return heads + (experts if cfg.routed_layers else dense)
+
+
+class KananaGroup(nn.Module):
+    """Every block of the model, each under nn.remat: the one parameter group."""
+
+    config: KananaConfig
+    keep: Any  # the blocks' checkpoint policy
+    stream: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        choices = []
+        for i in range(cfg.n_layer):
+            x, chosen = nn.remat(KananaBlock, policy=self.keep)(
+                cfg, i >= cfg.num_dense_layers, self.stream, name=f"h_{i}")(x)
+            if chosen is not None:
+                choices.append(chosen)
+        if choices:
+            self.sow("choices", "experts", jnp.stack(choices))
+        return x
+
+
+class Kanana(nn.Module):
+    config: KananaConfig
+    stream: Any = None  # parallel/mesh.py:stream_sharding of the step's mesh
+
+    @nn.compact
+    def __call__(self, idx):
+        cfg = self.config
+        x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
+                     embedding_init=nn.initializers.normal(0.02))(idx)
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        x = KananaGroup(cfg, keep, self.stream, name="p_0")(x)
+        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+        # operands in the compute dtype, float32 logits (models/lfm2.py's head, untied)
+        head = self.param("lm_head", nn.initializers.lecun_normal(),
+                          (cfg.n_embd, cfg.vocab_size), jnp.float32)
+        with jax.named_scope("lm_head"):
+            return jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
+
+
+KANANA_SHARDING_RULES = ShardingRules([
+    (r"attn/q_proj/kernel", P("fsdp", "tp")),
+    (r"attn/kv_a_proj/kernel", P("fsdp", None)),  # the latent and the shared key stay whole
+    (r"attn/kv_b_proj/kernel", P(None, "tp")),
+    (r"attn/o_proj/kernel", P("tp", "fsdp")),
+    (r"shared/(gate|up)/kernel", P("fsdp", "tp")),
+    (r"shared/down/kernel", P("tp", "fsdp")),
+    (r"lm_head$", P("fsdp", "tp")),
+] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())

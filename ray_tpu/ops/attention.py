@@ -38,6 +38,27 @@ would cost a pass over each operand). The logsumexp is float32 rows,
 backward call itself from the o and dO it holds, rows of a scratch: XLA made
 a transposed float32 copy of o * dO a layer to sum it into rows.
 
+The latent pair (flash_mla_fwd, flash_mla_bwd_fused: latent attention in its
+expanded form) has scores 192 deep in two parts, q_nope . k_nope over a
+head's own 128 and q_pe . k_pe over 64 that a token has once for all heads,
+and values 128 wide. 192 is 1.5 vregs, so the two parts are operands of
+their own and the kernels add their products tile by tile: a head's own
+part, its values, o and their gradients are (B, T, H * 128), the query's
+second part (B, T, H * 64), every width in its batch row (nothing works on
+the own parts between the projections and the call, and o goes into the
+output projection as it is); the shared key is (B, T, 128), the 64 twice, so
+that a head's second query part, one of two in a vreg with its neighbour's
+lanes zeroed, contracts with it as it stands: the 64-wide machinery above
+with one key for every head. A grid step takes heads in pairs. No array of
+H keys 192 wide is written, v is not padded to the score's width, and the
+shared key's gradient leaves the backward call summed over the heads: every
+grid step of a batch row adds its heads' dS^T q_pe to one float32
+(1, T, 128) output block, which stays in VMEM while the row's head groups
+and tiles run, in order (that call's second grid axis is sequential too).
+It is the causal kernels' tile loop, mask, online softmax and fused
+backward with `shared` set: `_Shared` holds the second part's refs, and the
+softmax scale is 1/sqrt(128 + 64).
+
 Precision: q, k, v and dO tiles reach the MXU in the dtype they arrive in
 (bf16 from the models), every matmul accumulates in float32
 (preferred_element_type), and the probabilities and dS are cast to that dtype
@@ -45,6 +66,9 @@ just before their matmuls; those two casts are the only rounding the kernels
 add. Scores, mask, running max and sum, exp, the rescale, lse, delta and the dq
 / dk / dv accumulators (dq's scratch among them) are float32. float32 inputs stay float32 operands (which
 the MXU multiplies at default precision, one bf16 pass on a v5e, as XLA does).
+The latent pair adds its two score products in float32 before the scale and
+the mask, and the shared key's gradient is summed over heads and tiles in
+float32 and cast once, outside the call.
 
 Tiles: `flash_tiles(h, t, d, dtype, window)` chooses the tile a grid step
 owns, the tile it loops over and the heads it takes at once from the call's
@@ -68,9 +92,10 @@ comes to nothing.
 
 The two pallas calls are named flash_fwd and flash_bwd_fused,
 flash_win<window>_fwd and flash_win<window>_bwd_fused where the call has a
-window shorter than its sequence, and flash_sel<k>_fwd and
+window shorter than its sequence, flash_sel<k>_fwd and
 flash_sel<k>_bwd_fused where a selection of k keys a query, fewer than the
-sequence has, says what is seen. (Until PR 35 the backward was two calls,
+sequence has, says what is seen, and flash_mla_fwd and flash_mla_bwd_fused
+where the scores have a second part whose key all heads share. (Until PR 35 the backward was two calls,
 flash_bwd_dq and flash_bwd_dkv, which both computed QK^T, dO V^T and exp.)
 The name reaches the compiled instruction and the profiler's trace (wrapped by
 the transformations it went through, e.g. transpose_jvp_flash_bwd_fused_), on
@@ -156,6 +181,21 @@ def _vmem_bytes(tiles, t, d, itemsize):
     return whole + own + acc + scores
 
 
+def _shared_vmem_bytes(tiles, t, r, itemsize):
+    """What a latent call's second score part adds to `_vmem_bytes`: the
+    heads' r-wide query parts (the whole sequence, double-buffered), the one
+    key all heads share as a tile of `_lanes(r)` lanes in and the query
+    parts' gradient out, the key's float32 gradient for the whole sequence
+    (an output block a batch row's grid steps sum into), the query parts'
+    float32 accumulator, and a head's own copy of the key tile and float32
+    sum of its gradient."""
+    block_q, _, heads = tiles[:3]
+    width, lanes = _lanes(heads * r), _lanes(r)
+    whole = 2 * t * width * itemsize + 2 * t * lanes * 4 + t * width * 4
+    own = 2 * block_q * (lanes + width) * itemsize
+    return whole + own + heads * block_q * lanes * (4 + itemsize)
+
+
 def _divisor(t, cap):
     """The largest multiple of 128 that divides t and is at most cap."""
     return max(b for b in range(128, max(cap, 128) + 1, 128) if t % b == 0)
@@ -176,7 +216,7 @@ def _legal_heads(h, d):
 
 
 def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
-                select: Optional[int] = None) -> FlashTiles:
+                select: Optional[int] = None, shared: Optional[int] = None) -> FlashTiles:
     """Tiles for a causal flash call on (B, t, h, d) inputs of `dtype`,
     from the shape alone: the largest square tile, a multiple of 128 that
     divides t, up to _MAX_BLOCK (a tile step's matmuls must be long enough
@@ -198,7 +238,14 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
     selected one: the tile it loops over lies within one bit of the packed
     mask's words (ops/indexer.py:mask_width lanes), the tile it owns is the
     causal call's, and a grid step's rows of the mask count against the
-    budget. A selection of t keys or more is the causal call."""
+    budget. A selection of t keys or more is the causal call.
+
+    With `shared`, the width of a second score part whose key all heads
+    share (latent attention: the rotary part), the call is a latent one: d
+    is the width of a head's own score part and of its values, a grid step's
+    heads are those whose second parts fill whole vregs side by side
+    (128 // shared at least), and `_shared_vmem_bytes` counts against the
+    budget."""
     if t % 128:
         raise ValueError(f"seq len {t} is not a multiple of 128")
     if window is not None and window < 1:
@@ -212,11 +259,15 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
         raise ValueError("a call takes a window or a selection, not both")
     cap = _MAX_BLOCK if window is None else min(_MAX_BLOCK, int(window * _WINDOW_TILE))
     block = _divisor(t, cap)
-    legal = _legal_heads(h, d)
+    if shared is not None and (d % 128 or window is not None or select is not None):
+        raise ValueError("a latent call is causal, and its heads' own parts fill whole vregs")
+    legal = _legal_heads(h, d if shared is None else shared)
     heads = max((g for g in legal if g * block * block <= _TILE_ELEMS), default=legal[0])
 
     def over_budget(reckon=_vmem_bytes, inner=None):
-        return reckon((block, inner or block, heads), t, d, itemsize) > _VMEM_BUDGET
+        tiles = (block, inner or block, heads)
+        more = 0 if shared is None else _shared_vmem_bytes(tiles, t, shared, itemsize)
+        return reckon(tiles, t, d, itemsize) + more > _VMEM_BUDGET
 
     while over_budget() and heads > legal[0]:
         heads = legal[legal.index(heads) - 1]
@@ -251,18 +302,23 @@ def _select_vmem_bytes(tiles, t, d, itemsize):
 LEGACY_NAMES = "_flash_bwd_dq_flash_bwd_dkv"  # the module's docstring, its last paragraph
 
 
-def _call(kernel, name, like, d, tiles, in_specs, out_specs, out_shape, interpret, scratch=()):
+def _call(kernel, name, like, d, tiles, in_specs, out_specs, out_shape, interpret, scratch=(),
+          shared=None):
     """The pallas_call of one of the kernels on operands like `like`,
     (rows, t, h * d): grid over rows, their groups of heads and the tiles
     a step owns. With `scratch`, which a group's grid steps hand on from one
-    tile to the next, the tiles run in order."""
+    tile to the next, the tiles run in order. With `shared`, the width of a
+    latent call's second score part: a backward call's head groups run in
+    order too, since they sum the shared key's gradient into one block."""
     b, t, width = like.shape
-    if tiles.heads not in _legal_heads(width // d, d):
+    if tiles.heads not in _legal_heads(width // d, shared or d):
         raise ValueError(f"{tiles.heads} heads a grid step of {width // d} heads {d} wide: "
                          "they divide a batch row's and fill whole vregs or the whole row")
     # The scoped default is enough for small calls; beyond it ask for what
     # the rule reckoned, with a quarter more for what the reckoning leaves out.
     vmem = _vmem_bytes(tiles, t, d, like.dtype.itemsize)
+    if shared is not None:
+        vmem += _shared_vmem_bytes(tiles, t, shared, like.dtype.itemsize)
     if tiles.select is not None:
         name = name.replace("flash_", f"flash_sel{tiles.select}_", 1)
         vmem = _select_vmem_bytes(tiles, t, d, like.dtype.itemsize)
@@ -281,7 +337,8 @@ def _call(kernel, name, like, d, tiles, in_specs, out_specs, out_shape, interpre
         out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary" if scratch else "parallel"),
+            dimension_semantics=("parallel", "arbitrary" if scratch and shared else "parallel",
+                                 "arbitrary" if scratch else "parallel"),
             vmem_limit_bytes=max(_VMEM_SCOPED, vmem * 5 // 4)),
         interpret=interpret,
         name=name,
@@ -458,23 +515,54 @@ def _rows(j, block):
     return pl.ds(pl.multiple_of(j * block, block), block)
 
 
+class _Shared(NamedTuple):
+    """The second score part of a latent call's grid step: `q`, the heads'
+    r-wide query parts side by side, (1, rows, heads * r); `k`, the one key
+    a token has for all heads, (1, rows, lanes), repeated to fill the lanes
+    of a cut so that a head's part of `q`, its neighbours' lanes zeroed,
+    contracts with it as it stands. The backward call's besides: `dq`, the
+    block of the query parts' gradient it writes; `dk`, the key's float32
+    gradient for the whole sequence, (1, t, lanes), the one block that every
+    grid step of a batch row adds its heads' sum to; `dq_acc`, float32
+    (t, heads * r), as the call's own dq_acc."""
+
+    q: object
+    k: object
+    dq: object = None
+    dk: object = None
+    dq_acc: object = None
+
+    def heads(self, count):
+        return _heads(self.q.shape[2] // count, self.q.shape[2])
+
+
+def _score_depth(d, heads, shared):
+    """What the scores are scaled by the root of: a head's own width, and
+    its second part's."""
+    return d if shared is None else d + shared.q.shape[2] // len(heads)
+
+
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
 
-def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible):
+def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible, shared=None):
     """(heads, step) of a forward grid step: step(j, carry, masked) takes the
     online softmax of each of its heads, carry[h] = (m, l, acc), over the
     tile of block_k keys j; a carry of None is a row's first tile, with
     nothing to rescale. q is held a head, its neighbours' lanes zeroed
     (`_Head.alone`); acc is as wide as the head's cut of v and whole in the
-    head's own lanes. `visible(j)` is the tile's mask."""
+    head's own lanes. `visible(j)` is the tile's mask. With `shared`
+    (`_Shared`), a head's scores are the sum of two products, the second of
+    its part of shared.q with the tile of shared.k."""
     heads = _heads(d, q_ref.shape[2])
-    q_scale, s_scale = _split_scale(d)
+    q_scale, s_scale = _split_scale(_score_depth(d, heads, shared))
     qs = [head.alone(q) for head, q in zip(heads, _cuts(q_ref, heads))]
+    parts = [] if shared is None else shared.heads(len(heads))
+    qs2 = [part.alone(q) for part, q in zip(parts, _cuts(shared.q, parts))] if parts else []
     if q_scale != 1.0:
-        qs = [q * q_scale for q in qs]
+        qs, qs2 = ([q * q_scale for q in xs] for xs in (qs, qs2))
 
     def step(j, carry, masked=True):
         rows = _rows(j, block_k)
@@ -482,6 +570,9 @@ def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible):
         # stage by stage over the heads, not head by head: the order the
         # scheduler is given is the order it keeps where it is free to choose
         ss = [_dot(q, k, _NT) for q, k in zip(qs, ks)]  # (block_q, block_k) float32
+        if shared is not None:
+            k2 = shared.k[0, rows, :]
+            ss = [s + _dot(q2, k2, _NT) for s, q2 in zip(ss, qs2)]
         if s_scale != 1.0:
             ss = [s * s_scale for s in ss]
         if masked:
@@ -520,7 +611,8 @@ def _fwd_write(o_ref, lse_ref, heads, carry):
         lse_ref[h, 0] = (m + jnp.log(l))[:, 0]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, window=None):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, window=None,
+                shared=None):
     ratio = block_q // block_k
     i = _own_tile(k_ref.shape[1], block_q)
     # entry (r, c) of q tile i against k tile j is visible iff
@@ -528,7 +620,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, win
     diff = _row_minus_col(block_q, block_k)
     heads, step = _fwd_step(
         q_ref, k_ref, v_ref, d, block_k,
-        lambda j: _visible(diff, j * block_k - i * block_q, window))
+        lambda j: _visible(diff, j * block_k - i * block_q, window), shared)
 
     # k tiles 0 .. i*ratio-1 lie below the diagonal, the next `ratio` cross
     # it. Tile 0 is every row's first; it holds key 0, which every row sees.
@@ -566,7 +658,8 @@ def _forward_call(q, k, v, mask=None, *, d, tiles, interpret):
 # --------------------------------------------------------------------------
 
 
-def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, block_k, visible):
+def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, block_k, visible,
+              shared=None):
     """(heads, step) of the backward grid step that owns key tile i:
     step(j, carry, masked) over the tile of block_k queries j puts the tile's
     part of each head's dk and dv onto carry[h] = (dk, dv), as wide as the
@@ -580,14 +673,20 @@ def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, 
     (keys, queries): lse and delta then broadcast along sublanes as the
     (.., 1, t) rows they are stored as, and dS^T is an operand of dk's
     matmul as it stands; dq's contracts its key axis. `visible(j)` is the
-    tile's mask."""
+    tile's mask. With `shared` (`_Shared`) the scores have their second
+    product, of the tile of shared.k that the step owns, held a head like k,
+    with the heads' parts of shared.q; a third entry of carry[h] sums the
+    head's dS^T times those parts, whole in the head's own lanes, and
+    shared.dq_acc takes dS times the key as dq_acc takes dS K."""
     heads = _heads(d, k_ref.shape[2])
-    q_scale, s_scale = _split_scale(d)
+    q_scale, s_scale = _split_scale(_score_depth(d, heads, shared))
     ks = [head.alone(k) for head, k in zip(heads, _cuts(k_ref, heads))]
+    parts = [] if shared is None else shared.heads(len(heads))
+    ks2 = [part.alone(shared.k[0]) for part in parts]
     if q_scale != 1.0:
         # a power of two: dq sums dS K with the scaled keys and is spared
         # that factor at the end; dk sums dS^T Q with q as it is
-        ks = [k * q_scale for k in ks]
+        ks, ks2 = ([k * q_scale for k in xs] for xs in (ks, ks2))
     vs = [head.alone(v) for head, v in zip(heads, _cuts(v_ref, heads))]
 
     def fill(j, _):
@@ -610,6 +709,8 @@ def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, 
     @pl.when(i == 0)
     def _():
         dq_acc[...] = jnp.zeros(dq_acc.shape, dq_acc.dtype)
+        if shared is not None:
+            shared.dq_acc[...] = jnp.zeros(shared.dq_acc.shape, shared.dq_acc.dtype)
         jax.lax.fori_loop(0, q_ref.shape[1] // block_k, fill, 0)
 
     def step(j, carry, masked=True):
@@ -617,45 +718,80 @@ def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, 
         qs, dos = _cuts(q_ref, heads, at), _cuts(do_ref, heads, at)
         # stage by stage over the heads, as the forward's step
         ss = [_dot(k, q, _NT) for k, q in zip(ks, qs)]  # (block_q keys, block_k queries)
+        if shared is not None:
+            qs2 = _cuts(shared.q, parts, at)
+            ss = [s + _dot(k2, q2, _NT) for s, k2, q2 in zip(ss, ks2, qs2)]
         if s_scale != 1.0:
             ss = [s * s_scale for s in ss]
         if masked:
             seen = visible(j)
             ss = [jnp.where(seen, s, NEG_INF) for s in ss]
         ps = [jnp.exp(s - lse_ref[h, :, at]) for h, s in enumerate(ss)]  # less a (1, block_k) row
-        dvs = [dv + _dot(p.astype(do.dtype), do, _NN) for (_, dv), p, do in zip(carry, ps, dos)]
+        dvs = [dv + _dot(p.astype(do.dtype), do, _NN) for (_, dv, *_), p, do in zip(carry, ps, dos)]
         dss = [(p * (_dot(v, do, _NT) - delta[h, :, at])).astype(do.dtype)
                for h, (p, v, do) in enumerate(zip(ps, vs, dos))]
-        dks = [dk + _dot(ds, q, _NN) for (dk, _), ds, q in zip(carry, dss, qs)]
-        dq = {}
-        for head, ds, k in zip(heads, dss, ks):
-            part = _dot(ds, k, _TN)
-            dq[head.lanes] = part if head.lanes not in dq else dq[head.lanes] + part
-        for (first, last), part in dq.items():
-            dq_acc[at, first:last] += part
-        return tuple(zip(dks, dvs))
+        dks = [dk + _dot(ds, q, _NN) for (dk, *_), ds, q in zip(carry, dss, qs)]
+        _sum_dq(dq_acc, at, heads, dss, ks)
+        if shared is None:
+            return tuple(zip(dks, dvs))
+        dks2 = [dk2 + _dot(ds, q2, _NN) for (_, _, dk2), ds, q2 in zip(carry, dss, qs2)]
+        _sum_dq(shared.dq_acc, at, parts, dss, ks2)
+        return tuple(zip(dks, dvs, dks2))
 
     return heads, step
 
 
-def _bwd_run(loop, refs, i, d, block_q, block_k, visible):
+def _sum_dq(dq_acc, at, heads, dss, ks):
+    """dS K of each head onto rows `at` of dq_acc, the heads of one cut
+    added up side by side first (each is zero in the others' lanes)."""
+    dq = {}
+    for head, ds, k in zip(heads, dss, ks):
+        part = _dot(ds, k, _TN)
+        dq[head.lanes] = part if head.lanes not in dq else dq[head.lanes] + part
+    for (first, last), part in dq.items():
+        dq_acc[at, first:last] += part
+
+
+def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None):
     """A backward grid step: `loop(step, carry)` over its tiles from zeroed
     dk and dv, then its results. No later key tile is seen by the queries
     of tile i, so their rows of dq are whole."""
     *ins, dq_ref, dk_ref, dv_ref, dq_acc, delta = refs
-    heads, step = _bwd_step(*ins, dq_acc, delta, i, d, block_k, visible)
+    heads, step = _bwd_step(*ins, dq_acc, delta, i, d, block_k, visible, shared)
     zeros = tuple((jnp.zeros((block_q, head.width), jnp.float32),) * 2 for head in heads)
+    if shared is not None:
+        lanes = shared.k.shape[2]
+        zeros = tuple(z + (jnp.zeros((block_q, lanes), jnp.float32),) for z in zeros)
     carry = loop(step, zeros)
-    q_scale, s_scale = _split_scale(d)
+    q_scale, s_scale = _split_scale(_score_depth(d, heads, shared))
     dq_ref[0] = (dq_acc[_rows(i, block_q), :] * s_scale).astype(dq_ref.dtype)
-    for ref, parts, scale in ((dk_ref, [dk for dk, _ in carry], q_scale * s_scale),  # 1/sqrt(d)
-                              (dv_ref, [dv for _, dv in carry], 1.0)):
+    for ref, parts, scale in ((dk_ref, [c[0] for c in carry], q_scale * s_scale),  # 1/sqrt(d)
+                              (dv_ref, [c[1] for c in carry], 1.0)):
         for (first, last), x in _side_by_side(heads, parts).items():
             ref[0, :, first:last] = (x * scale if scale != 1.0 else x).astype(ref.dtype)
+    if shared is None:
+        return
+    at = _rows(i, block_q)
+    shared.dq[0] = (shared.dq_acc[at, :] * s_scale).astype(shared.dq.dtype)
+    # the heads' parts of the shared key's gradient, each whole in its own
+    # lanes of its cut: the cuts summed, then the r-wide lane groups (a
+    # rotation by r moves each group onto the next), so every group holds the
+    # sum over the grid step's heads; onto the batch row's block, which the
+    # row's first grid step clears
+    parts = shared.heads(len(heads))
+    dk2 = sum(_side_by_side(parts, [c[2] for c in carry]).values())
+    r = shared.q.shape[2] // len(heads)
+    dk2 = sum(pltpu.roll(dk2, n * r, 1) if n else dk2 for n in range(lanes // r))
+
+    @pl.when(jnp.logical_and(pl.program_id(1) == 0, i == 0))
+    def _():
+        shared.dk[...] = jnp.zeros(shared.dk.shape, shared.dk.dtype)
+
+    shared.dk[0, at, :] += dk2 * (q_scale * s_scale)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, delta, *, d, block_q, block_k, window=None):
+                dq_acc, delta, *, d, block_q, block_k, window=None, shared=None):
     """Owns block_q keys, loops over tiles of block_k queries once, and
     yields all three gradients: dk and dv of its keys, and onto dq_acc, the
     float32 (t, heads * d) a head group's grid steps hand on, each query
@@ -682,7 +818,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_
         lambda step, zeros: _tile_loop(step, zeros, None, i * ratio, ratio, after, edge_after=edge),
         (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta),
         i, d, block_q, block_k,
-        lambda j: _visible(diff, j * block_k - i * block_q, window, keys_first=True))
+        lambda j: _visible(diff, j * block_k - i * block_q, window, keys_first=True), shared)
 
 
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
@@ -830,16 +966,21 @@ def flash_causal_attention(q, k, v, *, window=None, block_q=None, block_k=None,
     Tiles come from `flash_tiles`; block_q / block_k override it (the tests'
     way to reach every tile shape at small sizes)."""
     _, t, h, d = q.shape
-    tiles = flash_tiles(h, t, d, q.dtype, window)
-    if block_q or block_k:
-        block_q, block_k = block_q or tiles.block_q, block_k or tiles.block_k
-        if t % block_q or block_q % block_k:
-            raise ValueError(
-                f"block_q ({block_q}) must divide the seq len ({t}) and be a "
-                f"multiple of block_k ({block_k}): a grid step's tile is cut "
-                "into whole tiles of the other operand along the diagonal")
-        tiles = tiles._replace(block_q=block_q, block_k=block_k)
+    tiles = _with_blocks(flash_tiles(h, t, d, q.dtype, window), t, block_q, block_k)
     return _flash(q, k, v, None, None, tiles, interpret)
+
+
+def _with_blocks(tiles, t, block_q, block_k):
+    """`tiles` with a caller's block_q / block_k in the rule's place."""
+    if not (block_q or block_k):
+        return tiles
+    block_q, block_k = block_q or tiles.block_q, block_k or tiles.block_k
+    if t % block_q or block_q % block_k:
+        raise ValueError(
+            f"block_q ({block_q}) must divide the seq len ({t}) and be a "
+            f"multiple of block_k ({block_k}): a grid step's tile is cut "
+            "into whole tiles of the other operand along the diagonal")
+    return tiles._replace(block_q=block_q, block_k=block_k)
 
 
 def xla_causal_attention(q, k, v, window=None):
@@ -854,6 +995,147 @@ def xla_causal_attention(q, k, v, window=None):
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", p, v)
+
+
+# --------------------------------------------------------------------------
+# latent attention
+# --------------------------------------------------------------------------
+
+
+def _latent_fwd_kernel(q_ref, k_ref, v_ref, q2_ref, k2_ref, o_ref, lse_ref, **sizes):
+    _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, shared=_Shared(q2_ref, k2_ref), **sizes)
+
+
+def _latent_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, q2_ref, k2_ref,
+                       dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref, dq_acc, delta, dq2_acc, **sizes):
+    _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta,
+                shared=_Shared(q2_ref, k2_ref, dq2_ref, dk2_ref, dq2_acc), **sizes)
+
+
+def _shared_key_specs(k2, tiles):
+    """Block specs of the shared key (B, t, lanes): the tile a grid step
+    owns, and the whole sequence of its batch row."""
+    _, t, lanes = k2.shape
+    return (pl.BlockSpec((1, tiles.block_q, lanes), lambda b, h, i: (b, i, 0)),
+            pl.BlockSpec((1, t, lanes), lambda b, h, i: (b, 0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("d", "tiles", "interpret"))
+def _latent_forward_call(q, k, v, q2, k2, *, d, tiles, interpret):
+    """o and lse from flash_mla_fwd: q, k, v (B, t, h * d), the heads' own
+    score parts and their values; q2 (B, t, h * r), their second query
+    parts; k2 (B, t, lanes), the key all heads share, repeated to a cut's
+    lanes."""
+    b, t, width = q.shape
+    r = q2.shape[2] * d // width
+    own, own_row, whole, _ = _specs(q, d, tiles)
+    own2 = _specs(q2, r, tiles)[0]
+    return _call(
+        _latent_fwd_kernel, "flash_mla_fwd", q, d, tiles,
+        in_specs=[own, whole, whole, own2, _shared_key_specs(k2, tiles)[1]],
+        out_specs=[own, own_row],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * width // d, 1, t), jnp.float32),
+        ],
+        interpret=interpret, shared=r,
+    )(q, k, v, q2, k2)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def _latent_backward_call(res, do, *, tiles, interpret):
+    """dq, dk, dv, dq2 like their operands and dk2, the shared key's float32
+    gradient summed over the heads, (B, t, lanes), every r-wide group of
+    lanes holding the whole sum, from flash_mla_bwd_fused."""
+    q, k, v, q2, k2, o, lse = res
+    b, t, width = q.shape
+    d = width * b // lse.shape[0]
+    r = q2.shape[2] * d // width
+    own, _, whole, whole_row = _specs(q, d, tiles)
+    own2, _, whole2, _ = _specs(q2, r, tiles)
+    own_key, whole_key = _shared_key_specs(k2, tiles)
+    like_q = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    return _call(
+        _latent_bwd_kernel, "flash_mla_bwd_fused", q, d, tiles,
+        in_specs=[whole, own, own, whole, whole, whole_row, whole2, own_key],
+        out_specs=[own, own, own, own2, whole_key],
+        out_shape=[like_q, like_q, like_q, jax.ShapeDtypeStruct(q2.shape, q2.dtype),
+                   jax.ShapeDtypeStruct(k2.shape, jnp.float32)],
+        interpret=interpret, shared=r,
+        scratch=(pltpu.VMEM((t, tiles.heads * d), jnp.float32),
+                 pltpu.VMEM((tiles.heads, 1, t), jnp.float32),
+                 pltpu.VMEM((t, tiles.heads * r), jnp.float32)),
+    )(q, k, v, o, do, lse, q2, k2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_latent(q, q2, k, k2, v, tiles, interpret):
+    """q, k, v (B, T, H, D), q2 (B, T, H, R), k2 (B, T, R) -> (B, T, H, D)
+    through the latent pair of calls."""
+    return _flash_latent_fwd_rule(q, q2, k, k2, v, tiles, interpret)[0]
+
+
+def _flash_latent_fwd_rule(q, q2, k, k2, v, tiles, interpret):
+    # The operands as the calls read them, under the names of `_flash`'s
+    # residuals and two more. Nothing works on the heads' own parts between
+    # the projections and the call, so every head width stays in its batch
+    # row, (B, T, H * D): what the slices of the projections' outputs write,
+    # and where the output projection reads o.
+    (b, t, h, d), r = q.shape, q2.shape[3]
+    rows = lambda x: x.reshape(b, t, -1)
+    repeated = jnp.tile(k2, (1, 1, _heads(r, tiles.heads * r)[0].width // r))  # a cut's lanes
+    q, k, v, q2, k2 = (checkpoint_name(x, name) for x, name in (
+        (rows(q), "attn_q"), (rows(k), "attn_k"), (rows(v), "attn_v"),
+        (rows(q2), "attn_q_shared"), (repeated, "attn_k_shared")))
+    o, lse = _latent_forward_call(q, k, v, q2, k2, d=d, tiles=tiles, interpret=interpret)
+    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
+    return o.reshape(b, t, h, d), (q, k, v, q2, k2, o, lse)
+
+
+def _flash_latent_bwd_rule(tiles, interpret, res, do):
+    b, t, h, d = do.shape
+    dq, dk, dv, dq2, dk2 = _latent_backward_call(
+        res, do.reshape(b, t, h * d), tiles=tiles, interpret=interpret)
+    r = dq2.shape[2] // h
+    heads = lambda x: x.reshape(b, t, h, -1)
+    return heads(dq), heads(dq2), heads(dk), dk2[..., :r].astype(dq2.dtype), heads(dv)
+
+
+_flash_latent.defvjp(_flash_latent_fwd_rule, _flash_latent_bwd_rule)
+
+
+def flash_latent_attention(q, q_shared, k, k_shared, v, *, block_q=None, block_k=None,
+                           interpret=False):
+    """Causal attention whose scores have two parts (latent attention in its
+    expanded form): q, k (B, T, H, D) a head's own, q_shared (B, T, H, R)
+    against k_shared (B, T, R), one key a token for all heads; scores
+    (q . k + q_shared . k_shared) / sqrt(D + R); v and the result
+    (B, T, H, D). The calls are flash_mla_fwd and flash_mla_bwd_fused; no
+    array of H keys D + R wide is made, and the backward call gives
+    k_shared's gradient summed over the heads. block_q / block_k as
+    `flash_causal_attention`'s."""
+    _, t, h, d = q.shape
+    tiles = _with_blocks(flash_tiles(h, t, d, q.dtype, shared=q_shared.shape[3]), t,
+                         block_q, block_k)
+    return _flash_latent(q, q_shared, k, k_shared, v, tiles, interpret)
+
+
+def xla_latent_attention(q, q_shared, k, k_shared, v):
+    """Plain einsum-softmax form of `flash_latent_attention`."""
+    t = q.shape[1]
+    s = (jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32)
+         + jnp.einsum("bthr,bsr->bhts", q_shared, k_shared, preferred_element_type=jnp.float32))
+    s = s / math.sqrt(q.shape[3] + q_shared.shape[3])
+    s = jnp.where(jnp.tril(jnp.ones((t, t), dtype=bool))[None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
+
+
+def latent_attention(q, q_shared, k, k_shared, v):
+    """As `causal_attention`: the pallas pair on a TPU, XLA's elsewhere."""
+    if attention_path(q.shape[1]) == "flash":
+        return flash_latent_attention(q, q_shared, k, k_shared, v)
+    return xla_latent_attention(q, q_shared, k, k_shared, v)
 
 
 def flash_selected_attention(q, k, v, mask, mask_t, top_k, *, interpret=False):

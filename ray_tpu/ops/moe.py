@@ -351,8 +351,10 @@ class ExpertShare(nn.Module):
     the k experts are the top k of s + b, with b a bias a layer that stands
     in the selection alone (under stop_gradient: the loss never moves it;
     TrainStep does, from the counts of every expert's tokens, which are sown
-    into "moe_router"); the gates are s at the chosen, over their sum + 1e-6,
-    times `scaling`. `hand_up_choices`: return (y, choices) and sow nothing
+    into "moe_router"); the gates are s at the chosen, over their sum +
+    `gate_eps`, times `scaling`. The epsilon is the layer's own number, as
+    its source writes it (LFM2's 1e-6, the default; DeepSeek-V3's 1e-20):
+    one form, not two. `hand_up_choices`: return (y, choices) and sow nothing
     into "choices", for a caller that sows several layers' as one entry.
 
     No assignment is dropped, whatever the imbalance: every one of the
@@ -373,6 +375,7 @@ class ExpertShare(nn.Module):
     router: str = SOFTMAX
     scaling: float = 1.0
     hand_up_choices: bool = False
+    gate_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
@@ -398,7 +401,7 @@ class ExpertShare(nn.Module):
             if self.router == SOFTMAX:
                 gates = (top_p / top_p.sum(-1, keepdims=True)).reshape(n, k)
             else:
-                gates = (top_p / (top_p.sum(-1, keepdims=True) + 1e-6)
+                gates = (top_p / (top_p.sum(-1, keepdims=True) + self.gate_eps)
                          * self.scaling).reshape(n, k)
                 self.sow("moe_router", "rows", chosen.sum((0, 1, 2), dtype=jnp.int32))
             if not self.hand_up_choices:

@@ -113,7 +113,8 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
     """Instantiate the model wired for this mesh: shard_map'd attention on
     more than one device (ring attention iff sp > 1) and the residual
     stream's sharding there (none on one device); config type picks the
-    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum / Granite / Lfm2)."""
+    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum / Granite / Lfm2 /
+    Kanana)."""
     import dataclasses
 
     if mesh is not None and cfg.attn_fn is None and mesh.devices.size > 1 and (
@@ -122,6 +123,7 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
         cfg = dataclasses.replace(cfg, attn_fn=attn_for_mesh(mesh))
     from ray_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEConfig
     from ray_tpu.models.granite import Granite, GraniteConfig
+    from ray_tpu.models.kanana import Kanana, KananaConfig
     from ray_tpu.models.lfm2 import Lfm2, Lfm2Config
     from ray_tpu.models.llama import Llama, LlamaConfig
     from ray_tpu.models.mellum import Mellum, MellumConfig
@@ -137,12 +139,15 @@ def model_for_mesh(cfg, mesh: Optional[Mesh]):
         return Granite(cfg, stream)
     if isinstance(cfg, Lfm2Config):
         return Lfm2(cfg, stream)
+    if isinstance(cfg, KananaConfig):
+        return Kanana(cfg, stream)
     return GPT2(cfg, stream)
 
 
 def default_rules_for(cfg) -> ShardingRules:
     from ray_tpu.models.gpt2_moe import GPT2_MOE_SHARDING_RULES, GPT2MoEConfig
     from ray_tpu.models.granite import GRANITE_SHARDING_RULES, GraniteConfig
+    from ray_tpu.models.kanana import KANANA_SHARDING_RULES, KananaConfig
     from ray_tpu.models.lfm2 import LFM2_SHARDING_RULES, Lfm2Config
     from ray_tpu.models.llama import LLAMA_SHARDING_RULES, LlamaConfig
     from ray_tpu.models.mellum import MELLUM_SHARDING_RULES, MellumConfig
@@ -157,6 +162,8 @@ def default_rules_for(cfg) -> ShardingRules:
         return GRANITE_SHARDING_RULES
     if isinstance(cfg, Lfm2Config):
         return LFM2_SHARDING_RULES
+    if isinstance(cfg, KananaConfig):
+        return KANANA_SHARDING_RULES
     return GPT2_SHARDING_RULES
 
 
@@ -184,6 +191,7 @@ class TrainStep:
     ):
         from ray_tpu.models.gpt2_moe import GPT2MoEConfig
         from ray_tpu.models.granite import GraniteConfig
+        from ray_tpu.models.kanana import KananaConfig
         from ray_tpu.models.lfm2 import Lfm2Config
         from ray_tpu.models.mellum import MellumConfig
         from ray_tpu.ops.moe import SELECTION_BIAS, move_selection_bias, router_metrics
@@ -199,7 +207,8 @@ class TrainStep:
         # which is also what moves the bias.
         self._sown = (["moe_load", "attn_keys"] if isinstance(model_cfg, MellumConfig)
                       else ["ssm_stats"] if isinstance(model_cfg, GraniteConfig)
-                      else ["moe_load", "moe_router"] if isinstance(model_cfg, Lfm2Config)
+                      else ["moe_load", "moe_router"]
+                      if isinstance(model_cfg, (Lfm2Config, KananaConfig))
                       else [])
         if rules is None:
             rules = default_rules_for(model_cfg)

@@ -48,12 +48,15 @@ PASSES = ("fwd", "bwd", "remat", "update")
 CLASSES = ("matmul", "kernel", "copy", "collective", "elementwise")
 # One vocabulary for every family. `attn.core` is the kernels and what feeds
 # them (the q/k/v split, the (B,T,H,D)<->(B,H,T,D) copies, rotary, q/k norm,
-# the indexer); `conv` a gated short convolution's mixing and its two
-# projections; `norm` holds the norms and the residual stream's own ops
+# the indexer); `mla` what stands round a latent attention's kernels (its
+# four projections, the latent's norm, the rotary on the 64: the kernels
+# themselves are `attn.core`); `moe.shared` the expert every token passes
+# through beside the routed ones; `conv` a gated short convolution's mixing
+# and its two projections; `norm` holds the norms and the residual stream's own ops
 # beside them (a block's adds and pins, which XLA fuses with the norms);
 # `optimizer` the clip and the global norm with AdamW.
-GROUPS = ("embed", "attn.proj", "attn.core", "mlp", "moe", "ssm", "conv", "norm", "head",
-          "loss", "optimizer", "collective", "unscoped")
+GROUPS = ("embed", "attn.proj", "attn.core", "mla", "mlp", "moe", "moe.shared", "ssm", "conv",
+          "norm", "head", "loss", "optimizer", "collective", "unscoped")
 TOP_ROWS = 5  # (group, pass) rows in what rides a report and the GCS record
 SCOPE_ROWS = 40  # scope rows printed for a terminal (--json holds them all)
 KIND_ROWS = 20  # kinds of instruction kept, largest first
@@ -142,12 +145,16 @@ def group_of(scope: str, cls: str = "elementwise") -> str:
         return "head"
     if any(p in _EMBED for p in parts):
         return "embed"
+    if "moe.shared" in parts:
+        return "moe.shared"
     if any(p == "moe" or p.startswith("moe.") for p in parts):
         return "moe"
     if any(p == "mamba" or p.startswith("ssm.") for p in parts):
         return "ssm"
     if any(p == "conv" or p.startswith("conv.") for p in parts):
         return "conv"
+    if any(p.startswith("mla.") for p in parts):
+        return "mla"
     if "attn" in parts or any(p.startswith("attn.") for p in parts):
         own = parts[parts.index("attn") + 1:] if "attn" in parts else parts
         return "attn.proj" if own and own[0] in _ATTN_PROJ else "attn.core"

@@ -76,6 +76,21 @@ OP_NAMES = {
         "in_proj/dot_general", "p/h/conv/conv.in_proj/in_proj", "bwd", "conv"),
     "operator_norm": ("jit(train_step)/jvp(Lfm2)/p_0/h_1/operator_norm/rsqrt",
                       "p/h/operator_norm", "fwd", "norm"),
+    "latent_projection": (
+        "jit(train_step)/jvp(Kanana)/p_0/h_1/attn/mla.kv_b/kv_b_proj/dot_general",
+        "p/h/attn/mla.kv_b/kv_b_proj", "fwd", "mla"),
+    "latent_rotary_backward": (
+        "jit(train_step)/transpose(jvp(Kanana))/p_0/jvp(Kanana)/p_0/checkpoint/h_0/attn/mla.rope/mul",
+        "p/h/attn/mla.rope", "bwd", "mla"),
+    "latent_kernel": (
+        "jit(train_step)/jvp(Kanana)/p_0/h_3/attn/attn.core/flash_mla_fwd/pallas_call",
+        "p/h/attn/attn.core/flash_mla_fwd", "fwd", "attn.core"),
+    "shared_expert": (
+        "jit(train_step)/jvp(Kanana)/p_0/h_2/moe.shared/shared/up/dot_general",
+        "p/h/moe.shared/shared/up", "fwd", "moe.shared"),
+    "routed_beside_the_shared_expert": (
+        "jit(train_step)/jvp(Kanana)/p_0/h_2/moe/moe.route/router/dot_general",
+        "p/h/moe/moe.route/router", "fwd", "moe"),
     "period_norm": ("jit(train_step)/jvp(Granite)/p_0/h_5/mixer_norm/rsqrt",
                     "p/h/mixer_norm", "fwd", "norm"),
     "backward_in_a_period": (
@@ -140,6 +155,10 @@ def _tiny(family):
         from ray_tpu.models.lfm2 import Lfm2Config
 
         return Lfm2Config.tiny(num_held=4), True
+    if family == "kanana":
+        from ray_tpu.models.kanana import KananaConfig
+
+        return KananaConfig.tiny(num_held=4), True
     from ray_tpu.models.granite import GraniteConfig
 
     return GraniteConfig.tiny(), True
@@ -153,7 +172,7 @@ def _compiled_text(cfg, t=128):  # an indexed layer packs its mask 128 keys to a
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_moe", "mellum", "mellum_indexed",
-                                    "granite", "lfm2"])
+                                    "granite", "lfm2", "kanana"])
 def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     """The tiny configuration's step, compiled here: every scheduled
     instruction has a group of the one vocabulary and a pass, few are
@@ -172,11 +191,14 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     assert {"fwd", "bwd", "update"} <= passes
     assert ("remat" in passes) == rematted
     groups = {r[3] for r in rows.values()}
-    assert {"embed", "attn.proj", "attn.core", "norm", "head", "loss", "optimizer"} <= groups
+    # a latent attention's projections are `mla`: what stands round its kernels
+    proj = "mla" if family == "kanana" else "attn.proj"
+    assert {"embed", proj, "attn.core", "norm", "head", "loss", "optimizer"} <= groups
     want = {"gpt2": "mlp", "llama": "mlp", "gpt2_moe": "moe", "mellum": "moe",
-            "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv"}[family]
+            "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv",
+            "kanana": "moe.shared"}[family]
     assert want in groups
-    if family == "lfm2":  # dense and routed MLPs in one model
+    if family in ("lfm2", "kanana"):  # dense and routed MLPs in one model
         assert {"mlp", "moe"} <= groups
     assert any(r[2] == "matmul" and r[3] == "head" for r in rows.values())
 

@@ -9,6 +9,10 @@ a row is a batch row, (B, T, H * D), and no layout copy stands beside a
 call; where a head fills its lanes (d=128: mistral, mellum, keye) a row is a
 head, (B * H, T, D), and the transposes beside the calls are the ones XLA
 makes the layout of their producers' outputs (ops/attention.py:_as_rows).
+The latent pair (kanana) keeps every width in its batch row, (B, T, H * 128)
+and (B, T, H * 64): nothing works on a head's own parts between the
+projections and the call, so no transpose stands beside it either; its
+operands and results are held to the two widths and the one shared key.
 """
 
 import json
@@ -43,7 +47,12 @@ WANT = {
         "flash_sel2048_fwd": 4, "flash_sel2048_bwd_fused": 4}),
     "granite4_h_micro_l10.t4096": (1, 4096, 32, 64, {"flash_fwd": 1, BWD: 1}),
     "lfm2_8b_a1b_l5_ep4.t8192": (2, 8192, 32, 64, {"flash_fwd": 1, BWD: 1}),
+    # the width is a head's own score part's and its value's; SHARED has the other
+    "kanana2_30b_l5_ep8.t8192": (2, 8192, 32, 128, {"flash_mla_fwd": 5, "flash_mla_bwd_fused": 5}),
 }
+# the width of the score's second part, whose key all heads share, where a
+# cell's calls are the latent pair
+SHARED = {"kanana2_30b_l5_ep8.t8192": 64}
 # what a trace of the parent's step (PR 41) showed of each call, as
 # bench/trace.py writes a device event's kind: (bh, t, d) results
 OLD_KINDS = {
@@ -81,6 +90,7 @@ READERS = ("flash_attention", "flash_backward", "flash_window", "flash_select")
 
 _CALL = re.compile(r'stablehlo\.custom_call @tpu_custom_call\(.*?kernel_name = "(flash_\w+)".*?\) -> (.*)$',
                    re.M)
+_CALL_TYPES = re.compile(r'kernel_name = "(flash_\w+)".*?: \((.*?)\) -> (.*)$', re.M)
 _TENSOR = re.compile(r"tensor<([\dx]+)x(\w+)>")
 _TRANSPOSE = re.compile(r"stablehlo\.transpose .*?dims = \[0, 2, 1, 3\].*?: \(tensor<([\dx]+)x\w+>\)")
 
@@ -109,6 +119,37 @@ def _kind(name, results):
     return f"{name} custom-call -> ({types})"
 
 
+def _latent_calls(text, rows, t, h, d, r):
+    """The latent pair's operands and results: a head's own score part, its
+    value and the output (rows, T, h * d), the second query part
+    (rows, T, h * r), the shared key and its float32 gradient once a token
+    (a vreg of lanes: the key 128 // r times), the logsumexp's rows. So no
+    array of h keys d + r wide goes in or out, no value wider than d, and no
+    transpose of an array of the calls' sizes stands beside them. The latent
+    pair's own shape function counts them; the causal calls' count nothing."""
+    own, second = f"bf16[{rows},{t},{h * d}]", f"bf16[{rows},{t},{h * r}]"
+    key, row = f"[{rows},{t},128]", f"f32[{rows * h},1,{t}]"
+    want = {"flash_mla_fwd": ([own] * 3 + [second, "bf16" + key], [own, row]),
+            "flash_mla_bwd_fused": ([own] * 5 + [row, second, "bf16" + key],
+                                    [own] * 3 + [second, "f32" + key])}
+    types = lambda text: [f"{ty}[{dims.replace('x', ',')}]" for dims, ty in _TENSOR.findall(text)]
+    found = _CALL_TYPES.findall(text)
+    assert found
+    for name, ins, outs in found:
+        assert (types(ins), types(outs)) == want[name], name
+        kind = f"{name} custom-call -> ({', '.join(types(outs))})"
+        flops, nbytes = shapes.load("flash_mla")(kind, ", ".join(types(ins)))
+        scores = t * t // 2 * rows * h
+        assert flops == 2 * scores * ((d + r + d) if name.endswith("fwd") else 3 * (d + r) + 2 * d)
+        assert nbytes < sum(math.prod(map(int, re.findall(r"\d+", x.split("[")[1]))) * (
+            2 if x.startswith("bf16") else 4) for x in types(ins) + types(outs))
+        for reader in READERS[1:]:  # flash_attention reads by exclusion, in the dense cells alone
+            assert shapes.load(reader)(kind, ", ".join(types(ins))) is None, reader
+    turned = {tuple(map(int, dims.split("x"))) for dims in _TRANSPOSE.findall(text)}
+    sizes = {(rows, t, h, w) for w in (d, r, d + r)} | {(rows, h, t, w) for w in (d, r, d + r)}
+    assert not turned & sizes, turned
+
+
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_cell_s_flash_calls_are_what_the_benchmark_reads(cell, monkeypatch):
     rows, t, h, d, counts = WANT[cell]
@@ -118,6 +159,8 @@ def test_cell_s_flash_calls_are_what_the_benchmark_reads(cell, monkeypatch):
     # (each kernel lowered once, under its own jit, and called a layer)
     assert {k: n for k, n in kernel_tally(text).items() if k.startswith("flash_")} == counts
     assert {name for name, _ in calls} == set(counts)
+    if cell in SHARED:
+        return _latent_calls(text, rows, t, h, d, SHARED[cell])
     wide = f"bf16[{rows},{t},{h * d}]" if d % 128 else f"bf16[{rows * h},{t},{d}]"
     row = f"f32[{rows * h},1,{t}]"
     kinds = {name: f"{name} custom-call -> " + (f"({wide}, {row})" if name.endswith("_fwd")
