@@ -23,6 +23,8 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 
@@ -163,6 +165,9 @@ def route_plan(idx, first_expert, num_held):
         inv (N, k)         the sorted row of each assignment
         held (N, k)        whether the assignment's expert is held here
         group_sizes (num_held,)   rows of each held expert, in order
+        by_token           two (N*k,) arrays: the assignments held here in
+                           token order, then the others, and the sorted
+                           row of each: the sorted rows' way back to tokens
 
     Rows from group_sizes.sum() on belong to experts held elsewhere."""
     n, k = idx.shape
@@ -170,62 +175,218 @@ def route_plan(idx, first_expert, num_held):
     held = (local >= 0) & (local < num_held)
     key = jnp.where(held, local, num_held).reshape(n * k)
     order = jnp.argsort(key, stable=True)
-    inv = jnp.argsort(order).reshape(n, k)
+    inv = jnp.argsort(order)
     group_sizes = (key[:, None] == jnp.arange(num_held)[None, :]).sum(0, dtype=jnp.int32)
-    return order, inv, held, group_sizes
+    # a third sort, with the rows as its load: looking them up afterwards
+    # would be a gather of single integers, slow on a TPU
+    _, *by_token = jax.lax.sort(((key == num_held).astype(jnp.int32), jnp.arange(n * k), inv),
+                                num_keys=1, is_stable=True)
+    return order, inv.reshape(n, k), held, group_sizes, tuple(by_token)
 
 
-# Gather and scatter of rows along the plan, with backward passes that are
-# gathers too: the plan is a permutation, so what autodiff would write as a
-# scatter-add of N*k rows (serial on a TPU) is a gather along its inverse.
-# Rows of experts held elsewhere are never computed: `held` keeps whatever
-# lies there out of both directions.
+def token_order(plan, rows):
+    """The first `rows` sorted rows (every row routed here lies within them)
+    in token order, from a `route_plan`. For each of `rows` slots:
+
+        source (rows,)   the sorted row that stands there
+        mine (rows,)     its assignment (token * k + j), N * k where the
+                         slot is nobody's
+
+    A token's rows are adjacent, in the order of its k choices, and the slots
+    past group_sizes.sum() are nobody's. No row of C moves here."""
+    _, inv, _, group_sizes, (by_token, rows_by_token) = plan
+    here = jnp.arange(rows) < group_sizes.sum()
+    return jnp.where(here, rows_by_token[:rows], 0), jnp.where(here, by_token[:rows], inv.size)
+
+
+# Tokens a grid step of `sum_by_token`'s kernel sums into, and the rows it
+# reads. A step's product is tokens x rows x C whatever meets in it, and the
+# steps are N / tokens + rows / rows' at most, so the MXU's work is (rows *
+# tokens + N * rows') * C a pass: small blocks do less of it in more steps.
+_SUM_TOKENS, _SUM_ROWS = 128, 128
+
+
+def _token_sum_kernel(block_ref, chunk_ref, live_ref, tok_ref, *refs, weighted):
+    """One grid step: the rows of chunk chunk_ref[g] that belong to the
+    tokens of block block_ref[g], each times its weight, added to the block's
+    float32 sums: a product on the MXU against a (tokens, rows) matrix that
+    holds a row's weight at its token and 0 elsewhere. The weight of a row
+    is its token's gate for the row's place j among the token's k choices,
+    taken from the block's own (tokens, k) gates."""
+    j_ref, gates_ref, y_ref, o_ref, acc_ref = refs if weighted else (None, None, *refs)
+    g = pl.program_id(0)
+    block = block_ref[g]
+
+    @pl.when((g == 0) | (block_ref[jnp.maximum(g - 1, 0)] != block))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live_ref[g] == 1)
+    def _():
+        tokens, rows = acc_ref.shape[0], tok_ref.shape[-1]
+        ids = block * tokens + jax.lax.broadcasted_iota(jnp.int32, (tokens, rows), 0)
+        own = tok_ref[...] == ids
+        if weighted:
+            at = jnp.zeros((tokens, rows), jnp.float32)
+            for j in range(gates_ref.shape[1]):
+                at = jnp.where(own & (j_ref[...] == j), gates_ref[:, j:j + 1], at)
+        else:
+            at = jnp.where(own, 1.0, 0.0)
+        y = y_ref[...]
+        if y.dtype != jnp.bfloat16:
+            acc_ref[...] += jnp.dot(at, y.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
+                                    preferred_element_type=jnp.float32)
+            return
+        # A float32 weight is three bf16 parts exactly, a bf16 row times each
+        # is exact in float32 and the MXU sums in float32: no weight is
+        # rounded to one bf16 pass. A weight of 1 is its first part alone.
+        for _ in range(3 if weighted else 1):
+            part = at.astype(jnp.bfloat16)
+            acc_ref[...] += jnp.dot(part, y, preferred_element_type=jnp.float32)
+            at = at - part.astype(jnp.float32)
+
+    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "dtype", "interpret"))
+def _token_sum_call(y, mine, gates, *, n, dtype, interpret):
+    """The kernel over y (rows, C) already in token order, `mine` the
+    assignment at each row: (n, C) in `dtype`. The grid walks the pairs of a
+    block of tokens and a chunk of rows that meet, block by block: both run
+    the same way, so there are N / tokens + rows / rows' pairs at most, and
+    a block with no row takes one step and writes zeros. The pairs are three
+    short arrays of integers made here from the rows' tokens and prefetched,
+    as megablox's group metadata is; an output block stays in VMEM over its
+    pairs. Traced once, under this `jit`, and called a layer and pass."""
+    rows, c = y.shape
+    k = 1 if gates is None else gates.shape[1]
+    tb, r = _SUM_TOKENS, _SUM_ROWS
+    blocks, chunks = n // tb, rows // r
+    tokens = mine // k
+    # rows before each block of tokens, then the chunks a block's rows lie in
+    start = (tokens[None, :] < (jnp.arange(blocks + 1) * tb)[:, None]).sum(1, dtype=jnp.int32)
+    first = jnp.minimum(start[:-1] // r, chunks - 1)
+    last = jnp.minimum(jnp.maximum(start[1:] - 1, start[:-1]) // r, chunks - 1)
+    count = last - first + 1
+    ends = jnp.cumsum(count)
+    g = jnp.arange(blocks + chunks, dtype=jnp.int32)
+    block = jnp.minimum((ends[None, :] <= g[:, None]).sum(1, dtype=jnp.int32), blocks - 1)
+    within = g - (ends - count)[block]
+    chunk = jnp.minimum(first[block] + within, last[block])
+    # the steps past the last pair do nothing, nor does a block with no row
+    live = ((within < count[block]) & (start[1:] > start[:-1])[block]).astype(jnp.int32)
+
+    by_chunk = pl.BlockSpec((None, 1, r), lambda g, block, chunk, live: (chunk[g], 0, 0))
+    operands, specs = [tokens.reshape(chunks, 1, r)], [by_chunk]
+    if gates is not None:
+        operands += [(mine % k).reshape(chunks, 1, r), gates]
+        specs += [by_chunk, pl.BlockSpec((tb, k), lambda g, block, chunk, live: (block[g], 0))]
+    return pl.pallas_call(
+        functools.partial(_token_sum_kernel, weighted=gates is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(blocks + chunks,),
+            in_specs=specs + [pl.BlockSpec((r, c), lambda g, block, chunk, live: (chunk[g], 0))],
+            out_specs=pl.BlockSpec((tb, c), lambda g, block, chunk, live: (block[g], 0)),
+            scratch_shapes=[pltpu.VMEM((tb, c), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, c), dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             vmem_limit_bytes=48 << 20),
+        interpret=interpret, name="moe_token_sum",
+    )(block, chunk, live, *operands, y)
+
+
+def sum_by_token(y, back, n, gates=None, dtype=jnp.float32, *, interpret=None):
+    """For each of n tokens the sum of its rows of y (rows, C), each times
+    its weight: (n, C) in `dtype`, every product and every sum float32.
+    `back` is the buffer's `token_order`; the weight of a row is its
+    assignment's of `gates` (n, k) float32; where gates is None it is 1 and
+    `back` names tokens, not assignments (k = 1). What y holds at a slot
+    that is nobody's, defined or not, stays out (such a slot reads the first
+    sorted row at weight 0, and that row is somebody's wherever any is), and
+    a token with no row gets exact zeros.
+
+    Written from the buffer's side: one gather of the buffer's `rows` rows
+    into token order, then sums of adjacent rows, at most k a token. Both
+    directions of the expert layer that end at the tokens are this
+    (`combine_rows` forward, `dispatch_rows` backward); from the tokens' side
+    they gathered a row for each of the N * k assignments and zeroed those
+    held elsewhere (mellum's cell: 131,072 rows a pass from a buffer of
+    49,152, too large for XLA to hold in VMEM as it holds the other cells').
+
+    On a TPU and at whole blocks the sums are `_token_sum_kernel`'s;
+    elsewhere XLA's sorted segment sum, a scatter-add (serial on a TPU: what
+    the kernel is there for). `interpret` forces the kernel (True: in
+    interpret mode), for the tests."""
+    from ray_tpu.ops.attention import _on_tpu
+
+    source, mine = back
+    rows = y[source]
+    whole = n % _SUM_TOKENS == 0 and y.shape[0] % _SUM_ROWS == 0
+    if interpret is not None or (_on_tpu() and whole):
+        return _token_sum_call(rows, mine, gates, n=n, dtype=jnp.dtype(dtype),
+                               interpret=bool(interpret))
+    rows, k = rows.astype(jnp.float32), 1 if gates is None else gates.shape[1]
+    if gates is not None:  # nobody's slots fall into a segment that is cut off
+        rows = rows * gates.reshape(n * k)[jnp.minimum(mine, n * k - 1)][:, None]
+    return jax.ops.segment_sum(rows, mine // k, n + 1, indices_are_sorted=True)[:n].astype(dtype)
+
+
+# Gathers of rows along the plan, with backward passes that move no more
+# rows than the buffer has either. Towards the buffer (`dispatch_rows`
+# forward, `combine_rows` backward) a gather in the sorted rows' own order;
+# towards the tokens (`combine_rows` forward, `dispatch_rows` backward)
+# `sum_by_token` over the buffer's rows in token order. What autodiff would
+# write as a scatter-add of N*k rows (serial on a TPU) is never one. Rows of
+# experts held elsewhere are never computed: `held` and the token order keep
+# whatever lies there out of both directions.
 
 
 @jax.custom_vjp
-def dispatch_rows(x, order, inv, held):
+def dispatch_rows(x, order, held, back):
     """x (N, C) -> (rows, C): the token of each sorted row. `order` may be
-    cut to the buffer's first rows, with `inv` clipped into it: the rows of
-    experts held here come first, so what is cut is nobody's."""
-    return x[order // inv.shape[1]]
+    cut to the buffer's first rows: the rows of experts held here come
+    first, so what is cut is nobody's. `back`: the buffer's `token_order`."""
+    return x[order // held.shape[1]]
 
 
-def _dispatch_fwd(x, order, inv, held):
-    return dispatch_rows(x, order, inv, held), (inv, held)
+def _dispatch_fwd(x, order, held, back):
+    return dispatch_rows(x, order, held, back), (held, back)
 
 
 def _dispatch_bwd(res, g):
-    inv, held = res
-    dx = jnp.where(held[..., None], g[inv], 0).astype(jnp.float32).sum(1)
-    return dx.astype(g.dtype), None, None, None
+    held, (source, mine) = res
+    back = source, mine // held.shape[1]  # a weight of 1 whatever the choice's place
+    return sum_by_token(g, back, held.shape[0], dtype=g.dtype), None, None, None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def combine_rows(y, gates, order, inv, held):
-    """y (N*k, C) sorted rows, gates (N, k) float32 -> (N, C) float32: each
-    token's held rows, weighted by its gates and summed."""
-    rows = jnp.where(held[..., None], y[inv], 0)
-    return (rows.astype(jnp.float32) * gates[..., None]).sum(1)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def combine_rows(y, gates, order, inv, held, back, dtype=jnp.float32):
+    """y (rows, C) sorted rows, gates (N, k) float32 -> (N, C): each token's
+    held rows, weighted by its gates and summed in float32, in `dtype`.
+    `inv` clipped into the buffer, `back` its `token_order`. A caller that
+    would round the sums to the stream's dtype anyway asks for it here: the
+    values are the same, and the gradient then comes back in that dtype, so
+    the backward pass gathers rows half as wide."""
+    return sum_by_token(y, back, gates.shape[0], gates, dtype)
 
 
-def _combine_fwd(y, gates, order, inv, held):
-    return combine_rows(y, gates, order, inv, held), (y, gates, order, inv, held)
+def _combine_fwd(y, gates, order, inv, held, back, dtype):
+    return combine_rows(y, gates, order, inv, held, back, dtype), (y, gates, order, inv, held)
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(dtype, res, g):
     y, gates, order, inv, held = res
     k = inv.shape[1]
-    # in the sorted rows' own order: one gather of the buffer's rows, where
-    # the forward's way round would gather every assignment's
-    g_rows = g[order // k]
+    # in the sorted rows' own order: one gather of the buffer's rows
+    g_rows = g[order // k].astype(jnp.float32)
     sorted_gates = jnp.where(held, gates, 0).reshape(-1)[order]
     d_y = (g_rows * sorted_gates[:, None]).astype(y.dtype)
     d_gate_rows = (y.astype(jnp.float32) * g_rows).sum(-1)
     d_gates = jnp.where(held, d_gate_rows[inv], 0)
-    return d_y, d_gates, None, None, None
+    return d_y, d_gates, None, None, None, None
 
 
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
@@ -287,22 +448,27 @@ def grouped_matmul(lhs, rhs, group_sizes):
 _ROW_HEADROOM = 1.5
 
 
-def _expert_rows(rows, plan, weights, x, gates):
+def _expert_rows(rows, dtype, plan, weights, x, gates):
     """The held experts on a buffer of the first `rows` sorted rows: x (N, C)
-    -> (N, C) float32. Every row routed here lies within `rows`."""
-    order, inv, held, group_sizes = plan
-    at, back = order[:rows], jnp.minimum(inv, rows - 1)
+    -> (N, C) in `dtype`. Every row routed here lies within `rows`."""
+    order, inv, held, group_sizes, _ = plan
+    at, back = order[:rows], token_order(plan, rows)
     with jax.named_scope("moe.experts"):
-        taken = dispatch_rows(x, at, back, held)
+        taken = dispatch_rows(x, at, held, back)
         hidden = (nn.silu(grouped_matmul(taken, weights["gate"], group_sizes))
                   * grouped_matmul(taken, weights["up"], group_sizes))
         out = grouped_matmul(hidden, weights["down"], group_sizes)
     with jax.named_scope("moe.combine"):
-        return combine_rows(out, gates, at, back, held)
+        return combine_rows(out, gates, at, jnp.minimum(inv, rows - 1), held, back, dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts_in_buffer(rooms, plan, weights, x, gates):
+def _fits(plan, room):
+    """Whether a buffer of `room` rows holds every row routed here."""
+    return plan[3].sum() <= room
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts_in_buffer(rooms, dtype, plan, weights, x, gates):
     """`_expert_rows` on the smaller of two buffers (rooms: rows of the one
     with headroom, rows of the one for every assignment) that holds the rows
     routed here: both are compiled, one runs. The backward pass makes the
@@ -310,23 +476,21 @@ def _experts_in_buffer(rooms, plan, weights, x, gates):
     forward again, as under `nn.remat`): differentiating the `cond` itself
     would have the branch that runs write zeros for everything the other
     one would have kept."""
-    fits = plan[3].sum() <= rooms[0]
-    return jax.lax.cond(fits, functools.partial(_expert_rows, rooms[0]),
-                        functools.partial(_expert_rows, rooms[1]), plan, weights, x, gates)
+    return jax.lax.cond(_fits(plan, rooms[0]), functools.partial(_expert_rows, rooms[0], dtype),
+                        functools.partial(_expert_rows, rooms[1], dtype), plan, weights, x, gates)
 
 
-def _experts_in_buffer_fwd(rooms, plan, weights, x, gates):
-    return _experts_in_buffer(rooms, plan, weights, x, gates), (plan, weights, x, gates)
+def _experts_in_buffer_fwd(rooms, dtype, plan, weights, x, gates):
+    return _experts_in_buffer(rooms, dtype, plan, weights, x, gates), (plan, weights, x, gates)
 
 
-def _experts_in_buffer_bwd(rooms, res, g):
+def _experts_in_buffer_bwd(rooms, dtype, res, g):
     plan, *operands = res
 
     def back(rows, plan, *operands):
-        return jax.vjp(functools.partial(_expert_rows, rows, plan), *operands)[1](g)
+        return jax.vjp(functools.partial(_expert_rows, rows, dtype, plan), *operands)[1](g)
 
-    fits = plan[3].sum() <= rooms[0]
-    grads = jax.lax.cond(fits, functools.partial(back, rooms[0]),
+    grads = jax.lax.cond(_fits(plan, rooms[0]), functools.partial(back, rooms[0]),
                          functools.partial(back, rooms[1]), plan, *operands)
     return (None, *grads)
 
@@ -363,7 +527,12 @@ class ExpertShare(nn.Module):
     a step that routes more here takes the same path over a buffer of all
     tokens x k rows instead (`jax.lax.cond`: both are compiled, one runs).
     The grouped matmuls work on the rows routed here either way; the
-    buffer's size is what the gathers and the elementwise work follow."""
+    buffer's size is what the gathers, the elementwise work and the sums back
+    to the tokens follow: every pass over rows of C, forward and backward,
+    moves the buffer's rows and no more (`sum_by_token`), so a layer that
+    holds a quarter of the experts walks 0.375 of the tokens x k assignments
+    and one that overflowed walks them all. Sows what it walked into
+    "moe_load" beside the row counts (`telemetry/moe_rows_summed_share`)."""
 
     d_model: int
     d_ff: int
@@ -406,9 +575,8 @@ class ExpertShare(nn.Module):
                 self.sow("moe_router", "rows", chosen.sum((0, 1, 2), dtype=jnp.int32))
             if not self.hand_up_choices:
                 self.sow("choices", "experts", idx)
-            order, inv, held, group_sizes = route_plan(
-                idx.reshape(n, k), self.first_expert, num_held)
-            self.sow("moe_load", "rows", group_sizes)
+            plan = route_plan(idx.reshape(n, k), self.first_expert, num_held)
+            self.sow("moe_load", "rows", plan[3])
 
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
         weights = {name: self.param(name, init, shape, jnp.float32)
@@ -416,28 +584,38 @@ class ExpertShare(nn.Module):
                                        ("up", (num_held, C, self.d_ff)),
                                        ("down", (num_held, self.d_ff, C)))}
 
-        plan = (order, inv, held, group_sizes)
         operands = (weights, x.reshape(n, C).astype(self.dtype), gates)
         tile = _GMM_TILING[0]
         room = -(-int(_ROW_HEADROOM * n * k * num_held / self.num_experts) // tile) * tile
         if room < n * k:
-            y = _experts_in_buffer((room, n * k), plan, *operands)
+            y = _experts_in_buffer((room, n * k), x.dtype, plan, *operands)
+            walked = jnp.where(_fits(plan, room), room, n * k)
         else:
-            y = _expert_rows(n * k, plan, *operands)
-        y = y.reshape(B, T, C).astype(x.dtype)
+            y, walked = _expert_rows(n * k, x.dtype, plan, *operands), n * k
+        self.sow("moe_load", "walked", jnp.asarray(walked, jnp.int32))
+        y = y.reshape(B, T, C)
         return (y, idx) if self.hand_up_choices else y
 
 
 def moe_load_metrics(loads, tokens, top_k):
-    """What TrainStep reports of a step's "moe_load" collection (one
-    (num_held,) count of rows a layer): the assignments computed here,
-    their share of all tokens * top_k * layers, and the fullest held
-    expert's rows over the mean's."""
-    rows = jnp.stack(jax.tree.leaves(loads)).astype(jnp.float32)  # (layers, num_held)
-    held = rows.sum()
+    """What TrainStep reports of a step's "moe_load" collection (a layer's
+    (num_held,) count of rows, and the rows of the buffer it took): the
+    assignments computed here, their share of all tokens * top_k * layers,
+    the fullest held expert's rows over the mean's, and the rows that the
+    sums back to the tokens walked over tokens * top_k, mean over the layers
+    (the buffer's headroom over the held share while every layer fits it,
+    0.375 at a quarter held; 1 for a layer that took the buffer of every
+    assignment: whether a step ran the path `sum_by_token` gains on)."""
+    from flax import traverse_util
+
+    sown = traverse_util.flatten_dict(loads)
+    rows, walked = (jnp.stack([v[0] for path, v in sown.items() if path[-1] == name]
+                              ).astype(jnp.float32) for name in ("rows", "walked"))
+    held = rows.sum()  # rows: (layers, num_held)
     return {"moe_rows_held": held,
             "moe_held_share": held / (tokens * top_k * rows.shape[0]),
-            "moe_load_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0)}
+            "moe_load_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0),
+            "moe_rows_summed_share": walked.mean() / (tokens * top_k)}
 
 
 # The selection bias of a SIGMOID router moves by this much a step, towards

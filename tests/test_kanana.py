@@ -339,14 +339,19 @@ def test_the_cell_s_step_runs_the_latent_pair_once_a_layer(monkeypatch):
         lowering_platforms=("tpu",)).as_text()
     calls = kernel_tally(text)
     assert calls.pop("kernel") and "@gmm" in text and "@tgmm" in text
-    assert calls == {"flash_mla_fwd": 5, "flash_mla_bwd_fused": 5}, calls
+    # and the expert layer's sums back to the tokens (PR 44): forward and
+    # backward in four routed layers, each lowered for both buffers
+    assert calls == {"flash_mla_fwd": 5, "flash_mla_bwd_fused": 5,
+                     "moe_token_sum": 4 * 2 * 2}, calls
 
 
 # The lowered step of lfm2_8b_a1b_l5_ep4.t8192, the cell whose router this
-# family shares, as tests/test_mellum.py:_step_text gives it: taken on the
-# parent's tree (PR 42's commit) before this PR touched the epsilon under
-# its gates, which is now a field with the same number as its default.
-LFM2_STEP = "5ecc1aafb492cae675891cf1d24670e2b25af7d44615757eb9e47f6eced948d0"
+# family shares, as tests/test_mellum.py:_step_text gives it. Pinned again in
+# PR 44, which changed every routed cell's step by design (the expert layer's
+# sums back to the tokens walk the buffer's rows, `ops/moe.py:sum_by_token`,
+# and the layer sows the rows it walked): a change to this family's own
+# fields of `ExpertShare` leaves it as it is.
+LFM2_STEP = "dfc909d56a6212a6d019622a8702cda9ffb462675796c7ca34b6891003d2cf11"
 
 
 def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch):

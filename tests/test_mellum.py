@@ -218,13 +218,19 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
 # PR 37, and were pinned again for the next PR that means to leave them
 # alone); the layers
 # whose attention is windowed, of all; the names the rule saves there after
-# the first rung.
+# the first rung. The routed cell's was pinned again in PR 44, which changed
+# its step by design: the expert layer's two passes that end at the tokens
+# sum the buffer's rows in token order (`ops/moe.py:sum_by_token`, a kernel
+# and a third sort of the plan) where they gathered a row for every
+# assignment, the combine's sums leave in the stream's dtype, and the layer
+# sows the rows it walked beside its row counts.
+# The two dense cells' programs never call `ops/moe.py` and stay as they were.
 PINNED_STEPS = {
     "gpt2_small": ("eca64911e99d36ebc7cd2ff68eaea86fb593b0dd00a52d88a7cde4ceb3cec8a5", 32, 1024, 0, 12,
                    ("attn_q", "attn_k", "attn_v", "mlp_up")),
     "mistral_7b_l8": ("0eb48414debed15ee727d223dea32f572416e645ef708a92042b33c41c911472", 1, 8192, 0, 8,
                       ("mlp_up",)),
-    "mellum2_12b_l4_ep4": ("f199ec865398c04b83d05b600fe684119d3c870385234ca2fc08109de29dcfbc", 2, 8192, 3, 4,
+    "mellum2_12b_l4_ep4": ("7e3cf46b16864ea9e025334c23bc05b79d6d4562f024d39b7aa64fff6a520676", 2, 8192, 3, 4,
                            ("attn_q", "attn_k", "attn_v")),
 }
 
@@ -287,8 +293,12 @@ def test_step_reports_its_expert_load_through_the_telemetry():
             float(m["moe_held_share"]) * idx.size * cfg.top_k * cfg.n_layer)
         assert 0.3 < float(m["moe_held_share"]) < 0.7   # 4 of 8 experts held
         assert float(m["moe_load_max_over_mean"]) >= 1.0
+        # 2 x 64 tokens, top-2, 4 of 8 held: the headroom buffer would be one
+        # row tile of 512, every assignment is 256, so the layers take those
+        assert float(m["moe_rows_summed_share"]) == 1.0
         report = _telemetry.auto_report_metrics()
-        for key in ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean"):
+        for key in ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
+                    "moe_rows_summed_share"):
             assert report[f"telemetry/{key}"] == pytest.approx(float(m[key]))
     finally:
         _telemetry.set_current_recorder(None)
@@ -351,3 +361,48 @@ def test_rows_beyond_the_buffer_s_headroom_take_the_whole_buffer(expert_params, 
     g_got = jax.grad(loss(lambda x: _expert_layer(2, 2, x, params)))(x)
     g_want = jax.grad(loss(lambda x: _dense_experts(x, held_only)))(x)
     np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("width,hidden,experts,top_k,held,rows", [
+    (2304, 896, 64, 8, 16, 49152), (2048, 1792, 32, 4, 8, 24576)], ids=["mellum", "lfm2"])
+def test_no_pass_of_the_headroom_branch_moves_a_row_for_every_assignment(
+        width, hidden, experts, top_k, held, rows, monkeypatch):
+    """`_expert_rows` on the buffer with headroom alone, the branch that runs
+    (the whole layer also holds the branch for a step that overflows it,
+    whose buffer is every assignment by design), forward and vjp at the
+    cell's size, lowered for a TPU: no array of rows of C has more rows than
+    the buffer (the parent gathered 16384 x 8 x 2304 rows in `combine_rows`
+    forward and in `dispatch_rows` backward; lfm2 16384 x 4 x 2048), the two
+    sums are the kernel's, and no scatter came in beside the few hundred
+    integers of megablox's group metadata."""
+    import functools
+
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    n = 16384
+    room = -(-int(moe._ROW_HEADROOM * n * top_k * held / experts) // 512) * 512
+    assert rows == room < n * top_k
+
+    def both_passes(idx, weights, x, gates, g):
+        plan = moe.route_plan(idx, 0, held)
+        y, vjp = jax.vjp(functools.partial(moe._expert_rows, rows, jnp.bfloat16, plan), weights, x, gates)
+        return y, vjp(g)
+
+    shape = jax.ShapeDtypeStruct
+    weights = {"gate": shape((held, width, hidden), jnp.float32),
+               "up": shape((held, width, hidden), jnp.float32),
+               "down": shape((held, hidden, width), jnp.float32)}
+    text = jax.jit(both_passes).trace(
+        shape((n, top_k), jnp.int32), weights, shape((n, width), jnp.bfloat16),
+        shape((n, top_k), jnp.float32), shape((n, width), jnp.bfloat16),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    of_rows = {math.prod(map(int, dims.split("x")[:-1]))
+               for dims in re.findall(rf"tensor<((?:\d+x)+){width}x(?:bf16|f32)>", text)}
+    assert max(of_rows) == rows and n in of_rows, sorted(of_rows)
+    assert f"{n}x{top_k}x{width}" not in text and f"{n * top_k}x{width}" not in text
+    assert kernel_tally(text).get("moe_token_sum") == 2, kernel_tally(text)
+    scattered = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \([^)]*\) -> tensor<([^>]*)>', text, re.S)
+    assert scattered and all(
+        math.prod(int(d) for d in s.split("x")[:-1]) < 1024 for s in scattered), scattered

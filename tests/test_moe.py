@@ -171,3 +171,117 @@ def test_moe_model_trains_on_dp_ep_mesh():
         losses.append(float(loss))
     assert all(np.isfinite(l) for l in losses)
     assert losses[-1] < losses[0]
+
+
+# --------------------------------------------------------------------------
+# ExpertShare's two passes that end at the tokens: `sum_by_token` (the
+# buffer's rows in token order, summed by a kernel) against the gathers from
+# the tokens' side that it replaced, kept here as the plain statement.
+# --------------------------------------------------------------------------
+
+
+@jax.custom_vjp
+def _plain_dispatch(x, order, inv, held):
+    """x[order // k] with the backward pass of a gather for every assignment."""
+    return x[order // inv.shape[1]]
+
+
+def _plain_dispatch_fwd(x, order, inv, held):
+    return _plain_dispatch(x, order, inv, held), (inv, held)
+
+
+def _plain_dispatch_bwd(res, g):
+    inv, held = res
+    dx = jnp.where(held[..., None], g[inv], 0).astype(jnp.float32).sum(1)
+    return dx.astype(g.dtype), None, None, None
+
+
+_plain_dispatch.defvjp(_plain_dispatch_fwd, _plain_dispatch_bwd)
+
+
+def _plain_combine(y, gates, inv, held):
+    rows = jnp.where(held[..., None], y[inv], 0)
+    return (rows.astype(jnp.float32) * gates[..., None]).sum(1)
+
+
+def _routing(name, n, k):
+    """(idx (n, k) over 16 experts, experts held, whether the buffer is the
+    one of every assignment) for a case's name."""
+    rng = np.random.default_rng(sum(map(ord, name)) + k)
+    idx = np.stack([rng.permutation(16)[:k] for _ in range(n)])
+    held, whole = {"all": 16, "quarter": 4, "eighth": 2}.get(name, 2), False
+    if name == "none_held":  # the first tokens choose nothing that is held
+        idx[:40] = np.arange(2, 2 + k)
+    elif name == "same_experts":  # every token the same k experts, two of them held
+        idx[:] = np.arange(k)
+    elif name == "overflow":  # every token both held experts: past any headroom
+        idx[:] = np.arange(k)
+        whole = True
+    return jnp.asarray(idx, jnp.int32), held, whole
+
+
+_SUM_CASES = ([(share, k) for k in (4, 6, 8) for share in ("all", "quarter", "eighth")]
+              + [(name, 4) for name in ("none_held", "same_experts", "overflow")])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name,k", _SUM_CASES, ids=[f"{name}_k{k}" for name, k in _SUM_CASES])
+def test_rows_summed_by_token_equal_the_gathers_from_the_tokens_side(name, k, dtype, monkeypatch):
+    """Values and both gradients (x and gates) through dispatch_rows and
+    combine_rows with the kernel in interpret mode, against the plain
+    statement: equal to float32 rounding of a sum of at most k terms. What the
+    buffer holds past the rows routed here is NaN on both sides and reaches
+    nothing; a token with no held row gets exact zeros."""
+    import functools
+
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "sum_by_token", functools.partial(moe.sum_by_token, interpret=True))
+    n, c = 256, 128
+    idx, num_held, whole = _routing(name, n, k)
+    plan = moe.route_plan(idx, 0, num_held)
+    order, inv, held, group_sizes, _ = plan
+    total = int(group_sizes.sum())
+    rows = n * k if whole or num_held == 16 else -(-max(total, 1) // 256) * 256
+    assert total <= rows and (name != "overflow" or total == 2 * n)
+    at, back, clipped = order[:rows], moe.token_order(plan, rows), jnp.minimum(inv, rows - 1)
+    x = jax.random.normal(jax.random.PRNGKey(k), (n, c), jnp.float32).astype(dtype)
+    gates = jax.random.uniform(jax.random.PRNGKey(k + 1), (n, k), jnp.float32, 0.05, 1.0)
+    target = jax.random.normal(jax.random.PRNGKey(k + 2), (n, c), jnp.float32)
+    routed_here = (jnp.arange(rows) < total)[:, None]
+    experts = lambda taken: jnp.where(routed_here, jnp.tanh(taken * 1.5), jnp.nan)
+
+    def new(x, gates):
+        out = experts(moe.dispatch_rows(x, at, held, back))
+        return moe.combine_rows(out, gates, at, clipped, held, back)
+
+    def plain(x, gates):
+        return _plain_combine(experts(_plain_dispatch(x, at, clipped, held)), gates, clipped, held)
+
+    loss = lambda f: lambda x, gates: (f(x, gates) * target).sum()
+    got, want = new(x, gates), plain(x, gates)
+    assert got.dtype == want.dtype == jnp.float32 and bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-6)
+    nobody = ~np.asarray(held).any(1)
+    assert (name != "none_held" or nobody[:40].all()) and not np.asarray(got)[nobody].any()
+    for g_got, g_want in zip(jax.grad(loss(new), (0, 1))(x, gates),
+                             jax.grad(loss(plain), (0, 1))(x, gates)):
+        assert g_got.dtype == g_want.dtype and bool(jnp.isfinite(g_got).all())
+        np.testing.assert_allclose(np.asarray(g_got, np.float32), np.asarray(g_want, np.float32),
+                                   rtol=2e-6, atol=2e-6)
+
+
+def test_without_the_kernel_the_sum_is_the_same_sum():
+    """Where the kernel does not run (no TPU, or sizes that are no whole
+    blocks) the same rows in the same order through XLA's segment sum."""
+    from ray_tpu.ops import moe
+
+    idx, num_held, _ = _routing("quarter", 200, 6)
+    plan = moe.route_plan(idx, 0, num_held)
+    rows = 520
+    back = moe.token_order(plan, rows)
+    y = jax.random.normal(jax.random.PRNGKey(0), (rows, 24), jnp.float32)
+    gates = jax.random.uniform(jax.random.PRNGKey(1), (200, 6), jnp.float32)
+    want = _plain_combine(y, gates, jnp.minimum(plan[1], rows - 1), plan[2])
+    np.testing.assert_allclose(np.asarray(moe.sum_by_token(y, back, 200, gates)),
+                               np.asarray(want), rtol=2e-6, atol=2e-6)

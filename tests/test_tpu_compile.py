@@ -299,33 +299,47 @@ def test_windowed_flash_compiles_at_the_cell_s_shape(one_chip):
     assert not any(k in n for k in KERNELS + ("bwd_dq", "bwd_dkv") for n in names)
 
 
-def test_expert_share_compiles_at_the_cell_s_size(one_chip, monkeypatch):
-    """16 held experts of 64, top-8, on 16,384 tokens of width 2,304: the
-    three grouped matmuls and their six gradients are megablox's kernels
-    under the names the compiler gives them (gmm, tgmm: what the
-    benchmark's moe_gmm metrics look for), once for the buffer with headroom
-    and once for the buffer of every row, and the plan's gathers bring no
-    scatter of rows."""
+@pytest.mark.parametrize("width,hidden,experts,top_k,held,router,parent_bytes", [
+    (2304, 896, 64, 8, 16, "softmax", 59_120_476_160),
+    (2048, 1792, 32, 4, 8, "sigmoid", 40_023_392_256)], ids=["mellum", "lfm2"])
+def test_expert_share_compiles_at_the_cell_s_size(
+        one_chip, monkeypatch, width, hidden, experts, top_k, held, router, parent_bytes):
+    """16 held experts of 64, top-8 (mellum's layer), and 8 of 32, top-4
+    (lfm2's), on 16,384 tokens: the three grouped matmuls and their six
+    gradients are megablox's kernels under the names the compiler gives them
+    (gmm, tgmm: what the benchmark's moe_gmm metrics look for), once for the
+    buffer with headroom and once for the buffer of every row; the sum back
+    to the tokens is `moe_token_sum` in each (the gradient's: the forward's is
+    not part of a gradient), and the plan's gathers bring no scatter of rows.
+    The program reads fewer bytes than the parent's (PR 43's tree, this test's
+    own program compiled there: `cost_analysis()["bytes accessed"]`), though
+    both branches are in it and only the one with headroom lost its gathers of
+    a row for every assignment."""
     from ray_tpu.ops.moe import ExpertShare
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    layer = ExpertShare(2304, 896, 64, 8, 0, 16)
-    x = jax.ShapeDtypeStruct((2, 8192, 2304), jnp.bfloat16, sharding=one_chip)
+    layer = ExpertShare(width, hidden, experts, top_k, 0, held, router=router)
+    x = jax.ShapeDtypeStruct((2, 8192, width), jnp.bfloat16, sharding=one_chip)
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         jax.eval_shape(lambda: layer.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8, 2304), jnp.bfloat16)))["params"])
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, width), jnp.bfloat16)))["params"])
     loss = lambda p, x: layer.apply({"params": p}, x).astype(jnp.float32).sum()
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    text = compiled.as_text()
     names = _CUSTOM_CALL.findall(text)
     kinds = collections.Counter(re.sub(r"[.\d]+$", "", n) for n in names)
-    assert kinds == {"gmm": 2 * 6, "tgmm": 2 * 3}, kinds
+    assert kinds == {"gmm": 2 * 6, "tgmm": 2 * 3, "moe_token_sum": 2}, kinds
     # megablox's group metadata is made with scatters of a few hundred
     # elements; none is as long as the tokens
     scattered = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", text)
     assert all(math.prod(map(int, s.split(","))) < 1024 for s in scattered), scattered
-    # the buffer with headroom: 1.5 x 131,072 x 16/64 rows
-    assert "bf16[49152,2304]" in text and "bf16[131072,2304]" in text
+    # the buffer with headroom, 1.5 x the even load, and the one of every row
+    rows, every = int(1.5 * 16384 * top_k * held / experts), 16384 * top_k
+    assert f"bf16[{rows},{width}]" in text and f"bf16[{every},{width}]" in text
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < parent_bytes, cost["bytes accessed"]
 
 
 def test_selected_flash_compiles_at_the_cell_s_shape(one_chip):
