@@ -62,6 +62,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.models import remat
 from ray_tpu.models.llama import (LLAMA_SHARDING_PATTERNS, LlamaMLP, RMSNorm, apply_rope,
                                   rope_angles)
+from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
@@ -228,7 +229,9 @@ class KananaBlock(nn.Module):
         y, chosen = ExpertShare(
             cfg.n_embd, cfg.expert_dim, cfg.num_experts, cfg.top_k, cfg.first_expert,
             cfg.num_held, cfg.dtype, router=SIGMOID, scaling=cfg.routed_scaling,
-            hand_up_choices=True, gate_eps=cfg.gate_eps, name="moe")(h)
+            hand_up_choices=True, gate_eps=cfg.gate_eps,
+            products_kept=False,  # no plan of this family keeps them: REMAT_RUNGS says why
+            name="moe")(h)
         with jax.named_scope("moe.shared"):
             y = y + SharedExpert(cfg, name="shared")(h)
         return pin(x + y, self.stream), chosen
@@ -241,7 +244,17 @@ class KananaBlock(nn.Module):
 # the four projections before them, the latent's norm and the rotary (20.44
 # ms for 2.21 GiB); the shared expert's gate and up those two matmuls in
 # four layers (2.66 ms for 0.375 GiB); the dense MLP's likewise in one (4.28
-# ms for 0.375 GiB). The expert layer's own residuals have no names.
+# ms for 0.375 GiB). The first rung also holds the expert layers' choices
+# and plans (`moe_plan`: integers, 2.1 MB a layer; 1.9 ms a step). The expert
+# layer's three products (ops/moe.py:KEPT_PRODUCTS) are no rung here: in this
+# cell they spared nothing (my chip run, PR 45, call 7; one process a set, 8
+# steps by the host's clock: 495.87 ms a step with none of them, 496.58 with
+# the gate and the up product, 0.21 GiB, 496.00 with all three, 0.49 GiB).
+# These experts are 768 wide, their twelve forward calls under remat 5.7 ms
+# a step, and the form of the layer that reads kept products costs that
+# much again over the form that keeps none (the rows gathered and silu(gate)
+# * up once more, a pass over each residual, the sums' gather of a kept
+# `moe_out` from HBM): so the layer is told none is kept and takes that form.
 REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v", "attn_q_shared", "attn_k_shared"), 9.3),
                (("shared_up",), 7.1), (("mlp_up",), 11.4))
 
@@ -262,7 +275,10 @@ def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematP
         attn_q_shared=head(cfg.rope_dim),
         attn_k_shared=tokens * max(cfg.rope_dim, 128) * itemsize,  # a vreg of lanes a token
         shared_up=share(2 * tokens * cfg.shared_dim * itemsize, cfg.routed_layers),
-        mlp_up=share(2 * tokens * cfg.intermediate * itemsize, dense))
+        mlp_up=share(2 * tokens * cfg.intermediate * itemsize, dense),
+        moe_plan=share(moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
+                                       cfg.expert_dim, itemsize)[moe.ROUTE_PLAN],
+                       cfg.routed_layers))
     params = (cfg.n_layer * cfg.attention_params() + dense * 3 * d * cfg.intermediate
               + cfg.routed_layers * (d * cfg.num_experts + 3 * d * cfg.shared_dim
                                      + cfg.experts_held * 3 * d * cfg.expert_dim)
@@ -270,7 +286,8 @@ def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematP
     held = remat.held_bytes(
         shape, params=params, width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
-    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
 
 
 def _block_bytes(cfg: KananaConfig, itemsize: int) -> int:

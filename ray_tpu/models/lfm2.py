@@ -61,6 +61,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.models import remat
 from ray_tpu.models.granite import _conv_init
 from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm
+from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.ops.short_conv import gated_short_conv
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -175,6 +176,9 @@ class Lfm2Block(nn.Module):
     kind: str
     routed: bool
     stream: Any = None  # the residual stream's sharding, or None (models/llama.py)
+    # whether the blocks' remat plan keeps any of the expert layer's products
+    # (ops/moe.py:ExpertShare.products_kept)
+    products_kept: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -192,7 +196,8 @@ class Lfm2Block(nn.Module):
         y, chosen = ExpertShare(
             cfg.n_embd, cfg.expert_dim, cfg.num_experts, cfg.top_k, cfg.first_expert,
             cfg.num_held, cfg.dtype, router=SIGMOID, scaling=cfg.routed_scaling,
-            hand_up_choices=True, name="moe")(h)
+            hand_up_choices=True, products_kept=self.products_kept,
+            name="moe")(h)
         return pin(x + y, self.stream), chosen
 
 
@@ -203,9 +208,23 @@ class Lfm2Block(nn.Module):
 # second run and gated_conv_fwd's (11.8 ms for 1.0 GiB); the dense MLP's gate
 # and up those two matmuls' (4.3 ms for 0.44 GiB); the flash kernel's operands
 # the q, k, v projections', the head norms and the rotary (2.1 ms for 0.19
-# GiB). The expert layer's own residuals have no names yet.
+# GiB). The expert layer's three products (ops/moe.py:KEPT_PRODUCTS), a rung
+# each (my chip runs, PR 45, calls 1 and 7; one process a set of names, 8
+# steps by the host's clock, not the benchmark's 40 s window): together they
+# spare 12.76 ms of the 243.35 a step takes with no product kept, for 1.03
+# GiB; each has of that the share it had one at a time (the gate product
+# spares its grouped matmul's second run, 4.10 ms for 0.33 GiB, the up
+# product likewise 4.60, the down product its matmul and silu(gate) * up
+# before it, 4.75 ms for 0.375 GiB, against the same form of the layer with
+# nothing kept, which is 2.7 ms slower than the form a plan without products
+# takes). The price is a step that overflowed its headroom: with the products
+# kept it pays the headroom buffer's forward work on top of its own, 312.62
+# ms against the parent's 289.61 (call 7, every layer forced to overflow).
+# The first rung also holds the expert layers' choices and plans
+# (`moe_plan`: integers, 1.4 MB a layer).
 REMAT_RUNGS = ((("conv_bcu", "conv_y"), 11.8), (("mlp_up",), 9.9),
-               (("attn_q", "attn_k", "attn_v"), 11.1))
+               (("attn_q", "attn_k", "attn_v"), 11.1),
+               (("moe_gate",), 11.9), (("moe_up",), 13.3), (("moe_out",), 12.0))
 
 
 def remat_plan(cfg: Lfm2Config, shape: remat.StepShape, limit) -> remat.RematPlan:
@@ -226,6 +245,8 @@ def remat_plan(cfg: Lfm2Config, shape: remat.StepShape, limit) -> remat.RematPla
         conv_y=share(tokens * d * itemsize, kinds.count(CONV)),
         mlp_up=share(2 * tokens * cfg.intermediate * itemsize // shape.tp, dense))
     routed = cfg.n_layer - dense
+    name_bytes.update({name: share(nbytes, routed) for name, nbytes in moe.named_bytes(
+        tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d, cfg.expert_dim, itemsize).items()})
     params = (sum(cfg.operator_params(kind) for kind in kinds)
               + dense * 3 * d * cfg.intermediate
               + routed * (d * cfg.num_experts + cfg.experts_held * 3 * d * cfg.expert_dim)
@@ -233,7 +254,8 @@ def remat_plan(cfg: Lfm2Config, shape: remat.StepShape, limit) -> remat.RematPla
     held = remat.held_bytes(
         shape, params=params, width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
-    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
 
 
 def _block_bytes(cfg: Lfm2Config, itemsize: int) -> int:
@@ -243,14 +265,17 @@ def _block_bytes(cfg: Lfm2Config, itemsize: int) -> int:
     are compiled, and the one of every row sets the size): the rows gathered
     and the rows given back, d wide, and gate, up and their product,
     expert_dim wide, each with its gradient; and the gradient's rows gathered
-    in float32, in the combine's backward and in the dispatch's. A dense
+    in the combine's backward and in the dispatch's, in the stream's dtype
+    since PR 44 (float32 before: 250 KB a token then, 217 now). A dense
     block: the MLP's gate and up and their gradients. Beside either, the
-    operator's three streams and its output with their gradients. 250 KB a
-    token at the published widths in bf16: the step compiled for a v5e at the
-    benchmark's cell holds 12.24 GiB with the first rung alone where this
-    makes the rule reckon 12.19 (tests/test_tpu_compile.py)."""
+    operator's three streams and its output with their gradients. At the
+    published widths in bf16 the step compiled for a v5e at the benchmark's
+    cell holds 11.84 GiB with the first rung alone, 11.67 with the older
+    rungs and 12.02 with the expert layer's products too, where this makes
+    the rule reckon 11.69, 11.69 and 12.40 (PERF.md section 6, PR 45;
+    tests/test_tpu_compile.py)."""
     operator = 2 * 4 * cfg.n_embd * itemsize
-    experts = cfg.top_k * (itemsize * (4 * cfg.n_embd + 6 * cfg.expert_dim) + 2 * 4 * cfg.n_embd)
+    experts = cfg.top_k * itemsize * (6 * cfg.n_embd + 6 * cfg.expert_dim)
     dense = 4 * cfg.intermediate * itemsize
     return operator + (experts if cfg.n_layer > cfg.num_dense_layers else dense)
 
@@ -261,6 +286,7 @@ class Lfm2Group(nn.Module):
     config: Lfm2Config
     keep: Any  # the blocks' checkpoint policy
     stream: Any = None
+    products_kept: bool = True  # as the blocks'
 
     @nn.compact
     def __call__(self, x):
@@ -268,7 +294,7 @@ class Lfm2Group(nn.Module):
         choices = []
         for i, kind in enumerate(cfg.layer_types):
             x, chosen = nn.remat(Lfm2Block, policy=self.keep)(
-                cfg, kind, cfg.routed(i), self.stream, name=f"h_{i}")(x)
+                cfg, kind, cfg.routed(i), self.stream, self.products_kept, name=f"h_{i}")(x)
             if chosen is not None:
                 choices.append(chosen)
         if choices:
@@ -287,7 +313,8 @@ class Lfm2(nn.Module):
                        embedding_init=nn.initializers.normal(0.02))
         x = emb(idx)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
-        x = Lfm2Group(cfg, keep, self.stream, name="p_0")(x)
+        products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
+        x = Lfm2Group(cfg, keep, self.stream, products, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         # the tied head under models/llama.py's untied one's name (models/
         # granite.py says why): operands in the compute dtype, float32 logits

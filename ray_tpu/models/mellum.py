@@ -39,6 +39,7 @@ from ray_tpu.models import remat
 from ray_tpu.models.llama import (  # noqa: F401
     LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, apply_rope, rope_angles)
 from ray_tpu.ops import indexer
+from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
@@ -210,6 +211,9 @@ class MellumBlock(nn.Module):
     config: MellumConfig
     kind: str
     stream: Any = None  # the residual stream's sharding, or None (models/llama.py)
+    # whether the blocks' remat plan keeps any of the expert layer's products
+    # (ops/moe.py:ExpertShare.products_kept)
+    products_kept: bool = True
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
@@ -222,15 +226,30 @@ class MellumBlock(nn.Module):
             select=Indexer(cfg, name="indexer") if self.kind == INDEXED else None, name="attn")
         x = pin(x + attn(RMSNorm(cfg.rms_eps, name="attn_norm")(x), pos_offset), self.stream)
         moe = ExpertShare(cfg.n_embd, cfg.expert_dim, cfg.num_experts, cfg.top_k,
-                          cfg.first_expert, cfg.num_held, cfg.dtype, name="moe")
+                          cfg.first_expert, cfg.num_held, cfg.dtype,
+                          products_kept=self.products_kept, name="moe")
         return pin(x + moe(RMSNorm(cfg.rms_eps, name="moe_norm")(x)), self.stream)
 
 
-# What a block's remat saves after the flash kernel's output and logsumexp
-# (models/remat.py): the kernel's operands, and the ms of a step they spared
-# for a GiB held in the benchmark's cell on a v5e (PERF.md section 6, PR 33).
-# The expert layer's own residuals have no names yet.
-REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 37.6),)
+# What a block's remat saves after the first rung (the flash kernel's output
+# and logsumexp, and the expert layer's choices and plan, `moe_plan`: integers,
+# 2.6 MB a layer: models/remat.py): the kernel's operands, and the ms of a step
+# they spared for a GiB held in the benchmark's cell on a v5e (PERF.md section
+# 6, PR 33); the expert layer's three products (ops/moe.py:KEPT_PRODUCTS), a
+# rung each so that a step with room for one takes one (my chip runs, PR 45,
+# calls 1 and 7; one process a set of names, 8 steps by the host's clock, not
+# the benchmark's 40 s window; PERF.md section 6): the gate and the up
+# product spare a grouped matmul each under remat, together 2.7 ms of the
+# 355.97 a step takes with no product kept, for 0.33 GiB each (half of the
+# pair's each: the rule adds worths; against the same form of the layer with
+# nothing kept, which is 4.1 ms slower than the form a plan without products
+# takes, they were 5.9 and 6.3 alone and 7.3 together); the down product its
+# matmul and silu(gate) * up before it, 4.9 ms for 0.84 GiB beside the pair.
+# The price is a step that overflowed its headroom: with the pair kept it
+# pays the headroom buffer's forward work on top of its own, 478.19 ms
+# against the parent's 440.55 (call 7, every layer forced to overflow).
+REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 37.6),
+               (("moe_gate",), 4.1), (("moe_up",), 4.1), (("moe_out",), 5.8))
 
 
 def remat_plan(cfg: MellumConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
@@ -241,11 +260,15 @@ def remat_plan(cfg: MellumConfig, shape: remat.StepShape, limit) -> remat.RematP
              + cfg.experts_held * 3 * d * cfg.expert_dim)
     itemsize = jnp.dtype(cfg.dtype).itemsize
     # the expert layer's backward works in buffers of a row an assignment,
-    # every token's top_k of them: 7.5 such buffers in the step compiled for
-    # a v5e at the benchmark's cell and at twice its rows (4.2 and 8.2 GiB)
-    routed = int(7.5 * shape.rows * shape.seq_len * cfg.top_k * d * itemsize)
+    # every token's top_k of them (both of `ExpertShare`'s buffers are
+    # compiled, and the one of every assignment sets the size): 6.5 such
+    # buffers, 7.5 until the gradient's rows were gathered in the stream's
+    # dtype (PR 44; PERF.md section 6, PR 45, has the readings)
+    routed = int(6.5 * shape.rows * shape.seq_len * cfg.top_k * d * itemsize)
     name_bytes = remat.attention_bytes(shape, cfg.n_head, hd, itemsize)
-    first = remat.FIRST_RUNG
+    name_bytes.update(moe.named_bytes(shape.rows * shape.seq_len, cfg.top_k, cfg.experts_held,
+                                      cfg.num_experts, d, cfg.expert_dim, itemsize))
+    first = remat.FIRST_RUNG + (moe.ROUTE_PLAN,)
     indexed = sum(kind == INDEXED for kind in cfg.layer_types)
     if indexed and cfg.index_top_k < shape.seq_len:
         # an indexed layer also holds its selection, the transposed
@@ -277,9 +300,10 @@ class Mellum(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0))(idx)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
         for i, kind in enumerate(cfg.layer_types):
             x = nn.remat(MellumBlock, policy=keep)(
-                cfg, kind, self.stream, name=f"h_{i}")(x, pos_offset)
+                cfg, kind, self.stream, products, name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(x.astype(jnp.float32))

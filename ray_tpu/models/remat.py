@@ -9,7 +9,8 @@ identity.
 
 First rung, always: `attn_out` and `attn_lse`, what only the flash kernel can
 give (and `attn_sel` where a layer selects its keys: the packed mask the
-backward reads, the dearest thing in such a layer to compute twice). They cost one more copy of the stream a layer and spare the kernel's
+backward reads, the dearest thing in such a layer to compute twice; and
+`moe_plan` where a layer routes: the choices and the plan's sorts, integers). They cost one more copy of the stream a layer and spare the kernel's
 second run. Further rungs by a rule: a family states, beside its blocks, its
 other names and what each is worth (`REMAT_RUNGS`: rungs of names that are
 only worth saving together, each with the milliseconds of a step it spared
@@ -21,10 +22,10 @@ trace time in the model's `__call__`, where the batch's shape is static
 a compile). No option selects it and none turns it off.
 
 The constants are calibrated against what a v5e's allocator read of the
-benchmark's four cells' steps with each set of names saved (PERF.md section
-6, PR 33; tests/test_remat.py holds the thirteen readings and the error
-against them), and at shapes no chip ran against the step compiled for a
-described v5e (tests/test_tpu_compile.py).
+benchmark's cells' steps with each set of names saved (PERF.md section 6,
+PR 33, and PR 45 for the four routed cells; tests/test_remat.py holds the
+readings and the error against them), and at shapes no chip ran against the
+step compiled for a described v5e (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations

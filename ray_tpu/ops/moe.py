@@ -23,6 +23,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -440,6 +441,31 @@ def grouped_matmul(lhs, rhs, group_sizes):
     return out.astype(lhs.dtype)
 
 
+def _grouped_matmul_grads(lhs, rhs, group_sizes, g):
+    """The gradients of `grouped_matmul(lhs, rhs, group_sizes)` to lhs and to
+    rhs from g, the gradient of its result, with no forward call beside
+    them: on a TPU `_megablox_grads`, elsewhere `jax.lax.ragged_dot`'s
+    transposes."""
+    if _megablox_fits(lhs.shape[0]):
+        return _megablox_grads(lhs, rhs, group_sizes, g)
+    return jax.vjp(lambda lhs, rhs: grouped_matmul(lhs, rhs, group_sizes), lhs, rhs)[1](g)
+
+
+def _megablox_grads(lhs, rhs, group_sizes, g, interpret=False):
+    """What the rule of `megablox.ops.gmm` runs for g, written out because
+    `jax.vjp` of it would trace the forward call too: `gmm` on the transposed
+    matrices for the rows, `tgmm` for the matrices, in lhs's dtype
+    (tests/test_moe.py holds it to that rule's own results, bit for bit, in
+    interpret mode: a jax whose rule changes shows there)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm  # the kernels, no rule
+
+    d_lhs = gmm(g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype, _GMM_TILING,
+                transpose_rhs=True, interpret=interpret)
+    d_rhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, lhs.dtype, _GMM_TILING,
+                 num_actual_groups=rhs.shape[0], interpret=interpret)
+    return d_lhs, d_rhs.astype(rhs.dtype)
+
+
 # Rows of the buffer the experts work in, over the rows an even routing
 # would send here (rounded up to the row tile). At initialisation the
 # benchmark's deepest layer sends 0.21 to 0.36 of its assignments to a
@@ -448,18 +474,70 @@ def grouped_matmul(lhs, rhs, group_sizes):
 _ROW_HEADROOM = 1.5
 
 
-def _expert_rows(rows, dtype, plan, weights, x, gates):
-    """The held experts on a buffer of the first `rows` sorted rows: x (N, C)
-    -> (N, C) in `dtype`. Every row routed here lies within `rows`."""
-    order, inv, held, group_sizes, _ = plan
-    at, back = order[:rows], token_order(plan, rows)
+# Names of the three products for a block's checkpoint policy
+# (models/remat.py): under `nn.remat` the ones the policy keeps are held from
+# the forward pass, the others are made again by `_experts_in_buffer`'s
+# forward rule. A layer whose family's plan keeps none names none
+# (`ExpertShare.products_kept`).
+KEPT_PRODUCTS = ("moe_gate", "moe_up", "moe_out")
+ROUTE_PLAN = "moe_plan"  # the choices and `route_plan`'s arrays, under one name
+
+
+def buffer_rows(n, top_k, num_held, num_experts):
+    """Rows of the buffer with headroom for a layer of n tokens, or n * top_k
+    where that is no smaller: then every assignment is the one buffer."""
+    tile = _GMM_TILING[0]
+    room = -(-int(_ROW_HEADROOM * n * top_k * num_held / num_experts) // tile) * tile
+    return min(room, n * top_k)
+
+
+def named_bytes(n, top_k, num_held, num_experts, d_model, d_ff, itemsize):
+    """Bytes a layer of n tokens of what `ExpertShare` names for a block's
+    checkpoint policy (models/remat.py): ROUTE_PLAN, the choices and the
+    plan's arrays (five int32 and a bool an assignment), and KEPT_PRODUCTS,
+    rows of the buffer with headroom in the compute dtype (nothing where the
+    one buffer is every assignment: that layer names no product)."""
+    rows = buffer_rows(n, top_k, num_held, num_experts)
+    rows = rows if rows < n * top_k else 0
+    widths = (d_ff, d_ff, d_model)  # KEPT_PRODUCTS' order
+    return {ROUTE_PLAN: n * top_k * (5 * 4 + 1),
+            **{name: rows * width * itemsize for name, width in zip(KEPT_PRODUCTS, widths)}}
+
+
+# The held experts on a buffer of the first `rows` sorted rows (every row
+# routed here lies within them), in the three stages whose results the
+# backward pass reads: the gate and up matrices' products of the rows
+# gathered, the down matrix's product of silu(gate) * up, and the sums back
+# to the tokens.
+
+
+def _gate_up(rows, plan, weights, x):
+    """x (N, C) -> the gate and the up product, (rows, d_ff) each."""
+    order, _, held, group_sizes, _ = plan
     with jax.named_scope("moe.experts"):
-        taken = dispatch_rows(x, at, held, back)
-        hidden = (nn.silu(grouped_matmul(taken, weights["gate"], group_sizes))
-                  * grouped_matmul(taken, weights["up"], group_sizes))
-        out = grouped_matmul(hidden, weights["down"], group_sizes)
+        taken = dispatch_rows(x, order[:rows], held, token_order(plan, rows))
+        return (grouped_matmul(taken, weights["gate"], group_sizes),
+                grouped_matmul(taken, weights["up"], group_sizes))
+
+
+def _down(plan, weights, gate_out, up_out):
+    """The two products -> the down product, (rows, C)."""
+    with jax.named_scope("moe.experts"):
+        return grouped_matmul(nn.silu(gate_out) * up_out, weights["down"], plan[3])
+
+
+def _combine(rows, dtype, plan, out, gates):
+    """The down product -> (N, C) in `dtype`."""
+    order, inv, held, _, _ = plan
     with jax.named_scope("moe.combine"):
-        return combine_rows(out, gates, at, jnp.minimum(inv, rows - 1), held, back, dtype)
+        return combine_rows(out, gates, order[:rows], jnp.minimum(inv, rows - 1), held,
+                            token_order(plan, rows), dtype)
+
+
+def _expert_rows(rows, dtype, plan, weights, x, gates):
+    """x (N, C) -> (N, C) in `dtype`: the three stages on one buffer."""
+    out = _down(plan, weights, *_gate_up(rows, plan, weights, x))
+    return _combine(rows, dtype, plan, out, gates)
 
 
 def _fits(plan, room):
@@ -467,32 +545,90 @@ def _fits(plan, room):
     return plan[3].sum() <= room
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _experts_in_buffer(rooms, dtype, plan, weights, x, gates):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _experts_in_buffer(rooms, dtype, kept, plan, weights, x, gates):
     """`_expert_rows` on the smaller of two buffers (rooms: rows of the one
     with headroom, rows of the one for every assignment) that holds the rows
     routed here: both are compiled, one runs. The backward pass makes the
-    same choice and differentiates the branch it takes inside it (its
-    forward again, as under `nn.remat`): differentiating the `cond` itself
-    would have the branch that runs write zeros for everything the other
-    one would have kept."""
-    return jax.lax.cond(_fits(plan, rooms[0]), functools.partial(_expert_rows, rooms[0], dtype),
-                        functools.partial(_expert_rows, rooms[1], dtype), plan, weights, x, gates)
+    same choice. `kept`: whether anything holds the forward's products for
+    it (`ExpertShare.products_kept`). Where it does, the backward reads in
+    the buffer with headroom the three products the forward wrote and runs
+    no forward grouped matmul; where nothing does, and in a step that
+    overflowed either way, it differentiates `_expert_rows` on the buffer it
+    takes, its forward again. Differentiating the `cond` itself would have
+    the branch that runs write zeros for everything the other one would have
+    kept."""
+    return _experts_in_buffer_fwd(rooms, dtype, kept, plan, weights, x, gates)[0]
 
 
-def _experts_in_buffer_fwd(rooms, dtype, plan, weights, x, gates):
-    return _experts_in_buffer(rooms, dtype, plan, weights, x, gates), (plan, weights, x, gates)
+def _experts_in_buffer_fwd(rooms, dtype, kept, plan, weights, x, gates):
+    """With nothing kept, one `cond` over the two buffers and the operands
+    as the only residuals: a block's remat then has nothing of this layer's
+    to make again, and the backward rule makes what it needs inside the
+    branch it takes.
+
+    With products kept, the three stages run on the buffer with headroom
+    whatever the step routed, outside any `cond`: each product is a value of
+    the rule's own that a policy can keep and the next stage starts from
+    (with the gate and up products kept and the down product not, remat runs
+    the down matmul again and no other), and the sums read the down product
+    where it was written (as an operand of a `cond` it is gathered from HBM:
+    0.4 to 0.75 ms a layer more in three cells, PERF.md section 6, PR 45). A
+    step that overflowed makes all that of the rows that fit, and nobody
+    reads it: its branch of the `cond` starts again from x, the other hands
+    the sums on. So such a step pays the headroom buffer's work on top of
+    the parent's (PERF.md section 6, PR 45, has it measured): the price of
+    the step that fits, which is every step of every cell, reading its
+    products with no `cond` between."""
+    room = rooms[0]
+    if not kept:
+        y = jax.lax.cond(_fits(plan, room), functools.partial(_expert_rows, room, dtype),
+                         functools.partial(_expert_rows, rooms[1], dtype), plan, weights, x, gates)
+        return y, (plan, weights, x, gates)
+    order, inv, held, group_sizes, by_token = plan
+    # the groups cut to the buffer: as they are wherever the rows fit
+    ends = jnp.minimum(jnp.cumsum(group_sizes), room)
+    within = order, inv, held, jnp.diff(ends, prepend=0), by_token
+    gate_name, up_name, out_name = KEPT_PRODUCTS
+    gate_out, up_out = _gate_up(room, within, weights, x)
+    gate_out, up_out = checkpoint_name(gate_out, gate_name), checkpoint_name(up_out, up_name)
+    out = checkpoint_name(_down(within, weights, gate_out, up_out), out_name)
+    y = jax.lax.cond(
+        _fits(plan, room), lambda plan, weights, x, gates, y: y,
+        lambda plan, weights, x, gates, y: _expert_rows(rooms[1], dtype, plan, weights, x, gates),
+        plan, weights, x, gates, _combine(room, dtype, within, out, gates))
+    return y, (plan, weights, x, gates, gate_out, up_out, out)
 
 
-def _experts_in_buffer_bwd(rooms, dtype, res, g):
-    plan, *operands = res
+def _experts_in_buffer_bwd(rooms, dtype, kept, res, g):
+    room = rooms[0]
 
-    def back(rows, plan, *operands):
-        return jax.vjp(functools.partial(_expert_rows, rows, dtype, plan), *operands)[1](g)
+    def read(plan, weights, x, gates, gate_out, up_out, out):
+        """Each stage's transpose at the value the forward computed, last
+        stage first; the rows are gathered again (a gather, cheaper than
+        their bytes held)."""
+        order, inv, held, group_sizes, _ = plan
+        at, back = order[:room], token_order(plan, room)
+        with jax.named_scope("moe.combine"):
+            d_out, d_gates = _combine_bwd(
+                dtype, (out, gates, at, jnp.minimum(inv, room - 1), held), g)[:2]
+        with jax.named_scope("moe.experts"):
+            hidden, products = jax.vjp(lambda gate, up: nn.silu(gate) * up, gate_out, up_out)
+            d_hidden, d_down = _grouped_matmul_grads(hidden, weights["down"], group_sizes, d_out)
+            d_gate_out, d_up_out = products(d_hidden)
+            taken = dispatch_rows(x, at, held, back)
+            by_gate, d_gate = _grouped_matmul_grads(taken, weights["gate"], group_sizes, d_gate_out)
+            by_up, d_up = _grouped_matmul_grads(taken, weights["up"], group_sizes, d_up_out)
+            d_x = _dispatch_bwd((held, back), by_gate + by_up)[0]
+        return {"gate": d_gate, "up": d_up, "down": d_down}, d_x, d_gates
 
-    grads = jax.lax.cond(_fits(plan, rooms[0]), functools.partial(back, rooms[0]),
-                         functools.partial(back, rooms[1]), plan, *operands)
-    return (None, *grads)
+    def again(rows, plan, weights, x, gates, *unread):
+        return jax.vjp(functools.partial(_expert_rows, rows, dtype, plan),
+                       weights, x, gates)[1](g)
+
+    fitting = read if kept else functools.partial(again, room)
+    return (None, *jax.lax.cond(_fits(res[0], room), fitting,
+                                functools.partial(again, rooms[1]), *res))
 
 
 _experts_in_buffer.defvjp(_experts_in_buffer_fwd, _experts_in_buffer_bwd)
@@ -545,6 +681,12 @@ class ExpertShare(nn.Module):
     scaling: float = 1.0
     hand_up_choices: bool = False
     gate_eps: float = 1e-6
+    # Whether anything holds this layer's products (KEPT_PRODUCTS) from its
+    # forward pass to its backward: everything does where nothing is
+    # rematerialised; under a block's `nn.remat` the family hands down what
+    # its plan says (models/remat.py). False: the layer names none and takes
+    # the form that makes them inside its backward (`_experts_in_buffer`).
+    products_kept: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -563,6 +705,10 @@ class ExpertShare(nn.Module):
                 bias = self.param(SELECTION_BIAS, nn.initializers.zeros,
                                   (self.num_experts,), jnp.float32)
                 idx = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), k)[1]
+            # integers, a few MB a layer: a block's remat keeps the choices and
+            # the plan's sorts by name (models/remat.py), and runs the router's
+            # matmul and its scores again for the gates' gradient, no more
+            idx = checkpoint_name(idx, ROUTE_PLAN)
             # the chosen probabilities read through a one-hot product: the
             # gradient of top_k's own values is a scatter, serial on a TPU
             chosen = idx[..., None] == jnp.arange(self.num_experts)
@@ -575,7 +721,8 @@ class ExpertShare(nn.Module):
                 self.sow("moe_router", "rows", chosen.sum((0, 1, 2), dtype=jnp.int32))
             if not self.hand_up_choices:
                 self.sow("choices", "experts", idx)
-            plan = route_plan(idx.reshape(n, k), self.first_expert, num_held)
+            plan = checkpoint_name(
+                route_plan(idx.reshape(n, k), self.first_expert, num_held), ROUTE_PLAN)
             self.sow("moe_load", "rows", plan[3])
 
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
@@ -585,37 +732,45 @@ class ExpertShare(nn.Module):
                                        ("down", (num_held, self.d_ff, C)))}
 
         operands = (weights, x.reshape(n, C).astype(self.dtype), gates)
-        tile = _GMM_TILING[0]
-        room = -(-int(_ROW_HEADROOM * n * k * num_held / self.num_experts) // tile) * tile
+        room = buffer_rows(n, k, num_held, self.num_experts)
         if room < n * k:
-            y = _experts_in_buffer((room, n * k), x.dtype, plan, *operands)
-            walked = jnp.where(_fits(plan, room), room, n * k)
+            y = _experts_in_buffer((room, n * k), x.dtype, self.products_kept, plan, *operands)
+            fits = _fits(plan, room)
+            walked, read = jnp.where(fits, room, n * k), fits & self.products_kept
         else:
-            y, walked = _expert_rows(n * k, x.dtype, plan, *operands), n * k
+            y, walked, read = _expert_rows(n * k, x.dtype, plan, *operands), n * k, False
         self.sow("moe_load", "walked", jnp.asarray(walked, jnp.int32))
+        self.sow("moe_load", "read", jnp.asarray(read, jnp.bool_))
         y = y.reshape(B, T, C)
         return (y, idx) if self.hand_up_choices else y
 
 
 def moe_load_metrics(loads, tokens, top_k):
     """What TrainStep reports of a step's "moe_load" collection (a layer's
-    (num_held,) count of rows, and the rows of the buffer it took): the
-    assignments computed here, their share of all tokens * top_k * layers,
-    the fullest held expert's rows over the mean's, and the rows that the
-    sums back to the tokens walked over tokens * top_k, mean over the layers
-    (the buffer's headroom over the held share while every layer fits it,
-    0.375 at a quarter held; 1 for a layer that took the buffer of every
-    assignment: whether a step ran the path `sum_by_token` gains on)."""
+    (num_held,) count of rows, the rows of the buffer it took, and whether
+    its backward was the one that reads kept products): the assignments
+    computed here, their share of all tokens * top_k * layers, the fullest
+    held expert's rows over the mean's, and the rows that the sums back to
+    the tokens walked over tokens * top_k, mean over the layers (the buffer's
+    headroom over the held share while every layer fits it, 0.375 at a
+    quarter held; 1 for a layer that took the buffer of every assignment:
+    whether a step ran the path `sum_by_token` gains on). And of the layers,
+    the share that were handed `products_kept` by their family's plan and
+    took the buffer with headroom: planned and fitted, which is the form of
+    the backward rule that ran. It does not see what the policy then did:
+    that a kept product is not made again is held by the lowered steps'
+    grouped-matmul counts (tests/test_mellum.py and the families')."""
     from flax import traverse_util
 
     sown = traverse_util.flatten_dict(loads)
-    rows, walked = (jnp.stack([v[0] for path, v in sown.items() if path[-1] == name]
-                              ).astype(jnp.float32) for name in ("rows", "walked"))
+    rows, walked, read = (jnp.stack([v[0] for path, v in sown.items() if path[-1] == name]
+                                    ).astype(jnp.float32) for name in ("rows", "walked", "read"))
     held = rows.sum()  # rows: (layers, num_held)
     return {"moe_rows_held": held,
             "moe_held_share": held / (tokens * top_k * rows.shape[0]),
             "moe_load_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0),
-            "moe_rows_summed_share": walked.mean() / (tokens * top_k)}
+            "moe_rows_summed_share": walked.mean() / (tokens * top_k),
+            "moe_kept_read_share": read.mean()}
 
 
 # The selection bias of a SIGMOID router moves by this much a step, towards

@@ -149,8 +149,9 @@ _EXIT_WAIT_S = 10.0
 # Keys of a step's metrics that the summary carries as they are, from the
 # newest completed step (a scan of several: its last): the rows this
 # program's experts worked on, their share of every token's assignments,
-# the fullest held expert over the mean, and the rows its sums back to the
-# tokens walked over every assignment (ops/moe.py:moe_load_metrics);
+# the fullest held expert over the mean, the rows its sums back to the
+# tokens walked over every assignment, and the share of its layers whose
+# backward read products the remat plan kept (ops/moe.py:moe_load_metrics);
 # the keys a query kept and the compare-and-count passes a block of rows
 # took to select them, means over the layers that select
 # (models/mellum.py:Indexer); a state-space model's most negative log-decay
@@ -161,7 +162,7 @@ _EXIT_WAIT_S = 10.0
 # tokens over the mean's among all the experts of a layer, held here or not
 # (the counts that move the bias; ops/moe.py:router_metrics).
 _STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
-                "moe_rows_summed_share",
+                "moe_rows_summed_share", "moe_kept_read_share",
                 "moe_bias_abs_max", "moe_router_load_max_over_mean",
                 "attn_keys_selected_mean", "attn_select_passes_mean",
                 "ssm_chunk_log_decay_min", "ssm_state_abs_max")

@@ -22,7 +22,8 @@ from ray_tpu.models import kanana, remat
 from ray_tpu.models.kanana import Kanana, KananaConfig
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops import attention, short_conv
-from ray_tpu.ops.moe import SELECTION_BIAS, SELECTION_BIAS_RATE, SIGMOID, ExpertShare
+from ray_tpu.ops.moe import (KEPT_PRODUCTS, SELECTION_BIAS, SELECTION_BIAS_RATE, SIGMOID,
+                             ExpertShare)
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
@@ -316,13 +317,24 @@ def test_remat_plan_of_the_cell():
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     shape = remat.StepShape(2, 8192)
     chosen = kanana.remat_plan(cfg, shape, 15 * GIB)
-    assert chosen.names[:2] == remat.FIRST_RUNG
+    first = remat.FIRST_RUNG + ("moe_plan",)  # the routed layers' choices and plans with it
+    assert chosen.names[:3] == first
+    # every rung of the family; the expert layer's products are none of them
+    # (REMAT_RUNGS says why) and its layers are told so
+    assert set(chosen.names[3:]) == {n for names, _ in kanana.REMAT_RUNGS for n in names}
+    assert not set(chosen.names) & set(KEPT_PRODUCTS)
     assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
     tokens = 2 * 8192
     assert chosen.block_bytes == tokens * (2 * 32 * (2 * 128 + 64 + 2 * 128) * 2
                                            + 6 * 4 * 2048 * 2) == tokens * 172_032
-    assert kanana.remat_plan(cfg, shape, None).names == remat.FIRST_RUNG
-    assert kanana.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == remat.FIRST_RUNG
+    # the choices and the plan: five int32 and a bool an assignment, four
+    # routed layers of five
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kanana, "REMAT_RUNGS", ())
+        assert kanana.remat_plan(cfg, shape, 15 * GIB).layer_bytes == (
+            tokens * 32 * 128 * 2 + tokens * 32 * 4 + tokens * 6 * 21 * 4 // 5)
+    assert kanana.remat_plan(cfg, shape, None).names == first
+    assert kanana.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
 
 
 def test_the_cell_s_step_runs_the_latent_pair_once_a_layer(monkeypatch):
@@ -343,15 +355,24 @@ def test_the_cell_s_step_runs_the_latent_pair_once_a_layer(monkeypatch):
     # backward in four routed layers, each lowered for both buffers
     assert calls == {"flash_mla_fwd": 5, "flash_mla_bwd_fused": 5,
                      "moe_token_sum": 4 * 2 * 2}, calls
+    # the plan keeps none of the expert layer's products and the layer takes
+    # the form that names none: its backward makes them inside the branch it
+    # takes, 6 `gmm` a buffer where the form that reads them has 3 and 6
+    from tests.test_mellum import expert_calls
+
+    assert not set(KEPT_PRODUCTS) & set(remat.traced(cfg).names)
+    assert expert_calls(text, 4) == {"gmm": 18, "tgmm": 6, "moe_token_sum": 4}
 
 
 # The lowered step of lfm2_8b_a1b_l5_ep4.t8192, the cell whose router this
 # family shares, as tests/test_mellum.py:_step_text gives it. Pinned again in
 # PR 44, which changed every routed cell's step by design (the expert layer's
 # sums back to the tokens walk the buffer's rows, `ops/moe.py:sum_by_token`,
-# and the layer sows the rows it walked): a change to this family's own
-# fields of `ExpertShare` leaves it as it is.
-LFM2_STEP = "dfc909d56a6212a6d019622a8702cda9ffb462675796c7ca34b6891003d2cf11"
+# and the layer sows the rows it walked), and in PR 45, by design too (the
+# layer's products and plan are named residuals that its backward reads, and
+# the rule keeps all of them here): a change to this family's own fields of
+# `ExpertShare` leaves it as it is.
+LFM2_STEP = "acf612b0208fd90b770ad35d9fb505bd6e63e5ff66654aa8bc21624d2afe3319"
 
 
 def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch):
@@ -385,7 +406,7 @@ def test_step_reports_the_router_s_two_gauges_through_the_telemetry():
                       "moe_held_share", "moe_load_max_over_mean"):
             assert report[f"telemetry/{gauge}"] == float(m[gauge]), gauge
         plan = ts.telemetry.remat_plan
-        assert plan.names == remat.FIRST_RUNG and plan.limit_bytes is None  # no chip here
+        assert plan.names == remat.FIRST_RUNG + ("moe_plan",) and plan.limit_bytes is None  # no chip
         assert plan.block_bytes > 0
     finally:
         _telemetry.set_current_recorder(None)

@@ -184,18 +184,22 @@ def test_flops_per_token_at_the_cell_s_size():
 def test_remat_plan_of_the_cell_keeps_the_selection():
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     plan = mellum.remat_plan(cfg, remat.StepShape(1, 16384), 15 * GIB)
-    assert plan.names == ("attn_out", "attn_lse", "attn_sel", "attn_q", "attn_k", "attn_v")
+    # the expert layer's choices and plan in the first rung beside the
+    # selection, and its three products after the kernel's operands (PR 45)
+    assert plan.names == ("attn_out", "attn_lse", "moe_plan", "attn_sel", "attn_q", "attn_k",
+                          "attn_v", "moe_gate", "moe_up", "moe_out")
     assert plan.sel_bytes == 4 * 16384 * 512 * 4  # the transposed relation's mask, 4 layers
     assert plan.saved_bytes == 4 * plan.layer_bytes and plan.reckoned_bytes < plan.limit_bytes
     # with no limit the first rung alone, the selection in it
     assert mellum.remat_plan(cfg, remat.StepShape(1, 16384), None).names == \
-        ("attn_out", "attn_lse", "attn_sel")
+        ("attn_out", "attn_lse", "moe_plan", "attn_sel")
     # a sequence of top_k keys or fewer selects nothing and holds no mask
     assert "attn_sel" not in mellum.remat_plan(cfg, remat.StepShape(8, 2048), 15 * GIB).names
     # the other family of this file is as it was
     old = mellum.remat_plan(MellumConfig(num_held=16, vocab_size=24576),
                             remat.StepShape(2, 8192), 15 * GIB)
-    assert old.names == remat.FIRST_RUNG + ("attn_q", "attn_k", "attn_v") and old.sel_bytes == 0
+    assert old.names == remat.FIRST_RUNG + ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate",
+                                            "moe_up") and old.sel_bytes == 0
 
 
 def test_the_cell_s_step_selects_once_a_layer(monkeypatch):
@@ -217,6 +221,12 @@ def test_the_cell_s_step_selects_once_a_layer(monkeypatch):
     ours = {k: n for k, n in calls.items() if k.startswith(("flash_", "index_"))}
     assert ours == {"index_scores": 4, "index_select": 4, "flash_sel2048_fwd": 4,
                     "flash_sel2048_bwd_fused": 4}, calls
+    # the plan keeps the expert layer's three products (PR 45): no grouped
+    # matmul runs again under remat
+    from tests.test_mellum import expert_calls
+
+    assert {"moe_gate", "moe_up", "moe_out"} <= set(remat.traced(ts.model.config).names)
+    assert expert_calls(text, 4) == {"gmm": 15, "tgmm": 6, "moe_token_sum": 4}
 
 
 def test_step_reports_the_keys_a_query_kept_through_the_telemetry():

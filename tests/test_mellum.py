@@ -223,15 +223,18 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
 # sum the buffer's rows in token order (`ops/moe.py:sum_by_token`, a kernel
 # and a third sort of the plan) where they gathered a row for every
 # assignment, the combine's sums leave in the stream's dtype, and the layer
-# sows the rows it walked beside its row counts.
+# sows the rows it walked beside its row counts; and again in PR 45, by
+# design too: the layer's products and its plan are named residuals, its
+# backward reads them where the step's rows fit the buffer with headroom,
+# and the rule keeps the plan, the gate and the up product here.
 # The two dense cells' programs never call `ops/moe.py` and stay as they were.
 PINNED_STEPS = {
     "gpt2_small": ("eca64911e99d36ebc7cd2ff68eaea86fb593b0dd00a52d88a7cde4ceb3cec8a5", 32, 1024, 0, 12,
                    ("attn_q", "attn_k", "attn_v", "mlp_up")),
     "mistral_7b_l8": ("0eb48414debed15ee727d223dea32f572416e645ef708a92042b33c41c911472", 1, 8192, 0, 8,
                       ("mlp_up",)),
-    "mellum2_12b_l4_ep4": ("7e3cf46b16864ea9e025334c23bc05b79d6d4562f024d39b7aa64fff6a520676", 2, 8192, 3, 4,
-                           ("attn_q", "attn_k", "attn_v")),
+    "mellum2_12b_l4_ep4": ("d82142f0174ea0fa1d368ae770c585b12d96368e75dd1626ab30a1230828a850", 2, 8192, 3, 4,
+                           ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate", "moe_up")),
 }
 
 
@@ -280,6 +283,53 @@ def test_old_configurations_lower_to_the_parent_s_step(name, monkeypatch):
     assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
+def expert_calls(text, layers):
+    """Of a lowered step with `layers` routed layers: the call sites of
+    megablox's two kernels (under the names the compiler gives the jitted
+    functions round them) and the runs of `moe_token_sum`, a layer. Both
+    branches of every `cond` are in the text and are counted: of a layer's
+    grouped matmuls 3 forward on the buffer with headroom and 3 in the
+    branch that overflowed, 3 `gmm` and 3 `tgmm` in the backward that reads
+    the products and 6 and 3 in the one that overflowed, and one more `gmm`
+    under remat for each product the plan does not keep."""
+    import collections
+
+    calls = collections.Counter(re.sub(r"_\d+$", "", fn)
+                                for fn in re.findall(r"call @(t?gmm(?:_\d+)?)\(", text))
+    calls["moe_token_sum"] = kernel_tally(text)["moe_token_sum"]
+    assert all(n % layers == 0 for n in calls.values()), calls
+    return {name: n // layers for name, n in calls.items()}
+
+
+def lowered_cell(name, batch, monkeypatch, limit=15 * remat.GIB):
+    """(the cell's own step lowered for a TPU on this box with the rule given
+    `limit`, the plan that trace took)."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: limit)
+    with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
+        sizes = json.load(f)
+    cfg = families.load(sizes["family"]).build(sizes, "bfloat16")
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
+    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct(batch, jnp.int32)
+    text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return text, remat.traced(cfg)
+
+
+@pytest.mark.parametrize("limit_gib,kept,again", [(15, ("moe_gate", "moe_up"), 1), (14, (), 3)])
+def test_a_kept_product_s_forward_matmul_runs_once_a_layer(limit_gib, kept, again, monkeypatch):
+    """The cell's step under the v5e's limit keeps the gate and the up
+    product (the rule has no room for the down product's 0.84 GiB) and under
+    remat runs the down matmul again and no other; under a limit with room
+    for no further rung all three run again, as before PR 45. The route's
+    plan is in the first rung either way."""
+    text, plan = lowered_cell("mellum2_12b_l4_ep4", (2, 8192), monkeypatch, limit_gib * remat.GIB)
+    assert plan.names[:3] == remat.FIRST_RUNG + ("moe_plan",)
+    assert tuple(n for n in plan.names if n.startswith("moe_"))[1:] == kept
+    assert expert_calls(text, 4) == {"gmm": 15 + again, "tgmm": 6, "moe_token_sum": 4}
+
+
 def test_step_reports_its_expert_load_through_the_telemetry():
     cfg = MellumConfig.tiny(num_held=4, dtype=jnp.float32)
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]))
@@ -297,8 +347,11 @@ def test_step_reports_its_expert_load_through_the_telemetry():
         # row tile of 512, every assignment is 256, so the layers take those
         assert float(m["moe_rows_summed_share"]) == 1.0
         report = _telemetry.auto_report_metrics()
+        # and no layer's backward read a kept product: none takes the buffer
+        # with headroom (and a CPU device states no limit to plan under)
+        assert float(m["moe_kept_read_share"]) == 0.0
         for key in ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
-                    "moe_rows_summed_share"):
+                    "moe_rows_summed_share", "moe_kept_read_share"):
             assert report[f"telemetry/{key}"] == pytest.approx(float(m[key]))
     finally:
         _telemetry.set_current_recorder(None)
@@ -335,6 +388,25 @@ def test_shape_functions_of_the_new_kernels():
     assert d_up == up
     assert gmm("fusion fusion -> f32[128,256]", "f32[128,256], f32[128,256]") is None
     assert gmm("gmm custom-call -> bf16[49152,896]", "") is None
+
+
+@pytest.mark.parametrize("limit,read", [(1024 * remat.GIB, 1.0), (None, 0.0)],
+                         ids=["room_for_every_rung", "no_limit_stated"])
+def test_step_says_whether_its_backward_read_kept_products(limit, read, monkeypatch):
+    """2 x 512 tokens, top-2 of 8, two held: every layer fits the buffer with
+    headroom (1,024 rows of 2,048 assignments). Where the plan of the trace
+    keeps the products `moe_kept_read_share` is 1, under a plan that keeps
+    none 0, and the booked plan says which it was."""
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: limit)
+    cfg = MellumConfig.tiny(num_held=2, block_size=512)
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
+    state = ts.init(jax.random.PRNGKey(0))
+    idx, targets = _batch({"vocab_size": cfg.vocab_size}, t=512)
+    _, m = ts.step(state, ts.shard_batch({"idx": idx, "targets": targets}))
+    kept = set(remat.traced(cfg).names) & {"moe_gate", "moe_up", "moe_out"}
+    assert bool(kept) == bool(read) and "moe_plan" in remat.traced(cfg).names
+    assert float(m["moe_rows_summed_share"]) == 0.5
+    assert float(m["moe_kept_read_share"]) == read
 
 
 @pytest.mark.parametrize("routing", ["even", "all_to_the_held_experts"])

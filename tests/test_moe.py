@@ -285,3 +285,132 @@ def test_without_the_kernel_the_sum_is_the_same_sum():
     want = _plain_combine(y, gates, jnp.minimum(plan[1], rows - 1), plan[2])
     np.testing.assert_allclose(np.asarray(moe.sum_by_token(y, back, 200, gates)),
                                np.asarray(want), rtol=2e-6, atol=2e-6)
+
+
+# --------------------------------------------------------------------------
+# The expert layer's backward reads what its forward wrote (PR 45)
+# --------------------------------------------------------------------------
+
+
+def _parent_s_experts_in_buffer():
+    """`_experts_in_buffer` as it stood before PR 45: the forward rule keeps
+    its operands alone and the backward differentiates `_expert_rows`, its
+    forward again, in the branch it takes."""
+    import functools
+
+    from ray_tpu.ops import moe
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+    def experts(rooms, dtype, kept, plan, weights, x, gates):  # `kept`: PR 45's, not read
+        return jax.lax.cond(moe._fits(plan, rooms[0]),
+                            functools.partial(moe._expert_rows, rooms[0], dtype),
+                            functools.partial(moe._expert_rows, rooms[1], dtype),
+                            plan, weights, x, gates)
+
+    def fwd(rooms, dtype, kept, plan, weights, x, gates):
+        return experts(rooms, dtype, kept, plan, weights, x, gates), (plan, weights, x, gates)
+
+    def bwd(rooms, dtype, kept, res, g):
+        plan, *operands = res
+
+        def back(rows, plan, *operands):
+            return jax.vjp(functools.partial(moe._expert_rows, rows, dtype, plan), *operands)[1](g)
+
+        grads = jax.lax.cond(moe._fits(plan, rooms[0]), functools.partial(back, rooms[0]),
+                             functools.partial(back, rooms[1]), plan, *operands)
+        return (None, *grads)
+
+    experts.defvjp(fwd, bwd)
+    return experts
+
+
+# (the names a block's policy keeps, what the family handed the layer as
+# `products_kept`): the read form under each policy, one that keeps nothing
+# of it too, and the form a plan with no product takes
+_KEPT_CASES = [((), True), (("moe_plan",), True), (("moe_plan", "moe_gate", "moe_up"), True),
+               (("moe_out",), True), (("moe_plan", "moe_gate", "moe_up", "moe_out"), True),
+               ((), False), (("moe_plan",), False)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("overflows", [False, True], ids=["fits", "overflows"])
+@pytest.mark.parametrize("router,hand_up", [("softmax", False), ("sigmoid", True)],
+                         ids=["softmax", "sigmoid_hands_up_choices"])
+@pytest.mark.parametrize("kept,products_kept", _KEPT_CASES, ids=[
+    ("-".join(k) or "none") + ("" if p else "-told_none_is_kept") for k, p in _KEPT_CASES])
+def test_gradients_are_the_parent_s_bit_for_bit_whatever_remat_keeps(
+        kept, products_kept, router, hand_up, overflows, dtype, monkeypatch):
+    """`ExpertShare` under `nn.remat` with a policy that keeps these names,
+    against the same layer with the parent's `_experts_in_buffer` (whose
+    backward runs `jax.vjp` of `_expert_rows`): the output and the gradients
+    to the input and to every parameter are equal bit for bit, in the buffer
+    with headroom (1,024 tokens, top-2 of 8, two held: room for 1,024 of
+    2,048 assignments) and where every token goes to both held experts and
+    the step takes the buffer of every assignment; in the form that reads
+    kept products and in the one a layer takes that was told none is kept."""
+    import flax.linen as nn
+
+    from ray_tpu.ops import moe
+
+    layer = nn.remat(moe.ExpertShare, policy=jax.checkpoint_policies.save_only_these_names(*kept))(
+        24, 40, 8, 2, 2, 2, dtype, router=router, hand_up_choices=hand_up,
+        products_kept=products_kept)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 512, 24), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(4), x)["params"]
+    if overflows:
+        x = jnp.abs(x)
+        kernel = jnp.zeros_like(params["router"]["kernel"]).at[:, 2].set(2.0).at[:, 3].set(1.0)
+        params = {**params, "router": {"kernel": kernel}}
+    target = jax.random.normal(jax.random.PRNGKey(5), x.shape, jnp.float32)
+
+    def loss(params, x):
+        y, sown = layer.apply({"params": params}, x.astype(dtype), mutable=["choices", "moe_load",
+                                                                           "moe_router"])
+        y = y[0] if hand_up else y
+        return (y.astype(jnp.float32) * target).sum(), (y, sown["moe_load"])
+
+    run = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+    (_, (y, load)), grads = run(params, x)
+    assert (int(load["walked"][0]) == 2048) == overflows
+    assert bool(load["read"][0]) == (products_kept and not overflows)
+    monkeypatch.setattr(moe, "_experts_in_buffer", _parent_s_experts_in_buffer())
+    (_, (want_y, _)), want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(
+        params, x)
+    np.testing.assert_array_equal(np.asarray(y, np.float32), np.asarray(want_y, np.float32))
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        assert np.abs(np.asarray(ref)).max() > 0 or got.ndim == 1  # the selection bias takes none
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_the_transposes_written_out_are_megablox_s_own_rule(dtype):
+    """`_megablox_grads` (what the backward that reads kept products runs on a
+    TPU, where `jax.vjp` of `megablox.ops.gmm` would trace a forward call
+    beside them) against that `jax.vjp`, both in interpret mode: two row
+    tiles of 512, three groups, one of them empty and rows past the groups'
+    sum, float32 matrices cast to the rows' dtype as `grouped_matmul` casts
+    them. Equal bit for bit on the rows that belong to a group (the others
+    are not defined) and on every matrix: a jax whose rule changes (a dtype,
+    `transpose_rhs`, `group_offset`) fails here, not silently on the chip."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    from ray_tpu.ops import moe
+
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    lhs = jax.random.normal(keys[0], (1024, 128), jnp.float32).astype(dtype)
+    rhs = jax.random.normal(keys[1], (3, 128, 256), jnp.float32)
+    g = jax.random.normal(keys[2], (1024, 256), jnp.float32).astype(dtype)
+    group_sizes = jnp.asarray([600, 0, 300], jnp.int32)
+
+    def forward(lhs, rhs):
+        return megablox.gmm(lhs, rhs.astype(lhs.dtype), group_sizes, lhs.dtype, moe._GMM_TILING,
+                            None, None, False, True)
+
+    want_lhs, want_rhs = jax.vjp(forward, lhs, rhs)[1](g)
+    got_lhs, got_rhs = moe._megablox_grads(lhs, rhs, group_sizes, g, interpret=True)
+    assert got_lhs.dtype == want_lhs.dtype == dtype
+    assert got_rhs.dtype == want_rhs.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got_lhs[:900], np.float32),
+                                  np.asarray(want_lhs[:900], np.float32))
+    np.testing.assert_array_equal(np.asarray(got_rhs), np.asarray(want_rhs))
+    assert float(jnp.abs(want_rhs[1]).max()) == 0.0 and float(jnp.abs(want_rhs[0]).max()) > 0
