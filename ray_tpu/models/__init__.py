@@ -1,1 +1,40 @@
-"""Model zoo: TPU-native flax implementations used by the Train/bench stack."""
+"""Model zoo: TPU-native flax implementations used by the Train/bench stack.
+
+What `TrainStep` (parallel/train_step.py) asks of a model configuration, the
+contract's one home. It reads `family` (the `Family` below: a class attribute
+that the family's own file sets at its foot, no dataclass field, since it is
+no option), `block_size`, `attn_fn` and `use_flash_attention` (`model_for_mesh`
+sets the first where a mesh needs its own attention), `flops_per_token(seq_len)`
+where the family counts its FLOPs, and `lr_warmup_steps` where its recipe has
+any (ROADMAP C15). Everything else a family is, the record says.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Optional, Tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """One model family, as the step that trains it needs to know it."""
+
+    # (cfg, stream) -> nn.Module; `stream` is the residual stream's sharding
+    # on the step's mesh (parallel/mesh.py:stream_sharding) or None
+    module: Callable[[Any, Any], Any]
+    rules: Any  # parallel/mesh.py:ShardingRules of its parameters
+    # the collections its layers sow that the step takes out of the forward
+    # pass, and of those the ones whose leaves are terms of the loss
+    sown: Tuple[str, ...] = ()
+    loss_terms: Tuple[str, ...] = ()
+    # (cfg, sown, params after the update, tokens in the batch) -> the step's
+    # metrics beside loss and grad_norm: scalars, from the reducers that live
+    # beside the layers that sow. The telemetry's summary carries every one as
+    # it is (train/_telemetry.py:StepRecorder.step_gauges).
+    metrics: Optional[Callable[[Any, Any, Any, int], dict]] = None
+    # (leaf name, rule): parameter leaves of that name are none of the
+    # optimizer's (no moment, no decay), and after the update
+    # `rule(params, sown) -> params` moves them from what the step sowed.
+    # Their layer reads them under a stop_gradient: a leaf that optax masks
+    # out of AdamW gets its gradient itself as its update.
+    held_leaf: Optional[Tuple[str, Callable[[Any, Any], Any]]] = None

@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import flax.linen as nn
 import jax
@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import remat
+from ray_tpu.models import Family, remat
 from ray_tpu.models.loss import loss_fn  # noqa: F401
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
@@ -51,6 +51,8 @@ class GPT2Config:
     # attention bound to a mesh (ray_tpu/parallel/train_step.py). Signature
     # (q, k, v) -> out, all (B, T, H, D).
     attn_fn: Any = None
+
+    family: ClassVar[Family]  # what TrainStep asks of it: set at the foot of this file
 
     @classmethod
     def gpt2_124m(cls, **kw):
@@ -218,3 +220,4 @@ GPT2_SHARDING_PATTERNS = [
     (r"ln_", P()),
 ]
 GPT2_SHARDING_RULES = ShardingRules(GPT2_SHARDING_PATTERNS, default=P())
+GPT2Config.family = Family(module=GPT2, rules=GPT2_SHARDING_RULES)

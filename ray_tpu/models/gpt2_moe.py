@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models import Family
 from ray_tpu.models.gpt2 import (
     GPT2Config,
     GPT2_SHARDING_PATTERNS,
@@ -135,3 +136,7 @@ GPT2_MOE_SHARDING_RULES = ShardingRules(
     MOE_SHARDING_PATTERNS + GPT2_SHARDING_PATTERNS,
     default=P(),
 )
+# A capacity-routed layer sows its load-balance and router-z terms (ops/moe.py:
+# MoE, "losses"): the one family whose layers add to the loss.
+GPT2MoEConfig.family = Family(module=GPT2MoE, rules=GPT2_MOE_SHARDING_RULES,
+                              sown=("losses",), loss_terms=("losses",))

@@ -47,14 +47,14 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, ClassVar, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import remat
+from ray_tpu.models import Family, remat
 from ray_tpu.models.llama import (  # noqa: F401
     LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm)
 from ray_tpu.ops.ssd import ssd
@@ -87,6 +87,8 @@ class GraniteConfig:
     dtype: Any = jnp.bfloat16
     use_flash_attention: bool = True
     attn_fn: Any = None  # as LlamaConfig.attn_fn
+
+    family: ClassVar[Family]  # what TrainStep asks of it: set at the foot of this file
 
     @property
     def n_layer(self) -> int:
@@ -336,3 +338,20 @@ GRANITE_SHARDING_RULES = ShardingRules([
     (r"mamba/out_proj/kernel", P(None, "fsdp")),
     (r"mamba/", P()),
 ] + LLAMA_SHARDING_PATTERNS, default=P())
+
+
+def step_metrics(cfg, sown, params, tokens):
+    """`Family.metrics`, of what the Mamba layers sowed (`Mamba2Mixer`): the
+    most negative log-decay of a chunk over layers and heads (how near a
+    chunk's exp is to flushing to zero) and the largest carried-state entry
+    (what a narrower state would have to hold)."""
+    stats = [layer["mamba"] for period in sown["ssm_stats"].values()
+             for layer in period.values()]  # the mamba layers alone sow
+    return {"ssm_chunk_log_decay_min": jnp.min(jnp.stack(
+                [s["chunk_log_decay_min"][0] for s in stats])),
+            "ssm_state_abs_max": jnp.max(jnp.stack(
+                [s["state_abs_max"][0] for s in stats]))}
+
+
+GraniteConfig.family = Family(
+    module=Granite, rules=GRANITE_SHARDING_RULES, sown=("ssm_stats",), metrics=step_metrics)

@@ -51,7 +51,7 @@ bias's rule, the initialisers and the absence of an auxiliary loss are under
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import flax.linen as nn
 import jax
@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import remat
+from ray_tpu.models import Family, remat
 from ray_tpu.models.llama import (LLAMA_SHARDING_PATTERNS, LlamaMLP, RMSNorm, apply_rope,
                                   rope_angles)
 from ray_tpu.ops import moe
@@ -94,6 +94,8 @@ class KananaConfig:
     use_flash_attention: bool = True
     attn_fn: Any = None  # set under a mesh, which the latent pair has no form for yet
     lr_warmup_steps: int = 2000  # as MellumConfig.lr_warmup_steps
+
+    family: ClassVar[Family]  # what TrainStep asks of it: set at the foot of this file
 
     @property
     def mlp_dim(self) -> int:
@@ -357,3 +359,6 @@ KANANA_SHARDING_RULES = ShardingRules([
     (r"shared/down/kernel", P("tp", "fsdp")),
     (r"lm_head$", P("fsdp", "tp")),
 ] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+KananaConfig.family = Family(  # as models/lfm2.py's: the same router
+    module=Kanana, rules=KANANA_SHARDING_RULES, sown=("moe_load", "moe_router"),
+    metrics=moe.step_metrics, held_leaf=moe.SELECTION_BIAS_HELD)

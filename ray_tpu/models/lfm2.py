@@ -50,7 +50,7 @@ bench/configs/lfm2_8b_a1b_l5_ep4.json: the taps are stored (k, d) and not
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, ClassVar, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import remat
+from ray_tpu.models import Family, remat
 from ray_tpu.models.granite import _conv_init
 from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm
 from ray_tpu.ops import moe
@@ -95,6 +95,8 @@ class Lfm2Config:
     # the router sends it, and the routing does not survive the full rate
     # from step 0
     lr_warmup_steps: int = 2000
+
+    family: ClassVar[Family]  # what TrainStep asks of it: set at the foot of this file
 
     @property
     def n_layer(self) -> int:
@@ -330,3 +332,8 @@ LFM2_SHARDING_RULES = ShardingRules([
     (r"conv/out_proj/kernel", P(None, "fsdp")),
     (r"conv/conv_kernel", P()),
 ] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+# A router that selects under a bias sows every expert's tokens beside the
+# held experts' rows: they are a gauge, and what moves the bias.
+Lfm2Config.family = Family(
+    module=Lfm2, rules=LFM2_SHARDING_RULES, sown=("moe_load", "moe_router"),
+    metrics=moe.step_metrics, held_leaf=moe.SELECTION_BIAS_HELD)

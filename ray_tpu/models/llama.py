@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import flax.linen as nn
 import jax
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import remat
+from ray_tpu.models import Family, remat
 from ray_tpu.models.loss import loss_fn  # noqa: F401
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
@@ -64,6 +64,8 @@ class LlamaConfig:
     # Override the attention primitive, e.g. ring attention bound to a mesh.
     # Signature (q, k, v) -> out, all (B, T, H, D) with H == n_head.
     attn_fn: Any = None
+
+    family: ClassVar[Family]  # what TrainStep asks of it: set at the foot of this file
 
     @property
     def head_dim(self) -> int:
@@ -318,3 +320,4 @@ LLAMA_SHARDING_PATTERNS = [
     (r"norm", P()),
 ]
 LLAMA_SHARDING_RULES = ShardingRules(LLAMA_SHARDING_PATTERNS, default=P())
+LlamaConfig.family = Family(module=Llama, rules=LLAMA_SHARDING_RULES)

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, ClassVar, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import remat
+from ray_tpu.models import Family, remat
 from ray_tpu.models.llama import (  # noqa: F401
     LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, apply_rope, rope_angles)
 from ray_tpu.ops import indexer
@@ -110,6 +110,8 @@ class MellumConfig:
     # step 0 the routing collapses within 30 steps (measured on the v5e,
     # PERF.md section 6, PR 29), and with it the step's work.
     lr_warmup_steps: int = 2000
+
+    family: ClassVar[Family]  # what TrainStep asks of it: set at the foot of this file
 
     @property
     def n_layer(self) -> int:
@@ -311,3 +313,24 @@ class Mellum(nn.Module):
 
 MELLUM_SHARDING_RULES = ShardingRules(
     EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+
+
+def step_metrics(cfg, sown, params, tokens):
+    """`Family.metrics`: the expert layers' (a dropless layer adds no term to
+    the loss: what goes out is the rows its held experts worked on), and of
+    the layers that select their keys (`Indexer`; it sows nothing at a length
+    the selection says nothing) the keys a query kept and the compare-and-count
+    passes over its row's scores that finding them took a block of rows, means
+    over those layers."""
+    metrics = moe.step_metrics(cfg, sown, params, tokens)
+    selected = jax.tree_util.tree_leaves_with_path(sown.get("attn_keys", {}))
+    for name, metric in (("selected", "attn_keys_selected_mean"),
+                         ("select_passes", "attn_select_passes_mean")):
+        layers = [x for path, x in selected if jax.tree_util.DictKey(name) in path]
+        if layers:
+            metrics[metric] = sum(layers) / len(layers)
+    return metrics
+
+
+MellumConfig.family = Family(module=Mellum, rules=MELLUM_SHARDING_RULES,
+                             sown=("moe_load", "attn_keys"), metrics=step_metrics)

@@ -635,7 +635,7 @@ _experts_in_buffer.defvjp(_experts_in_buffer_fwd, _experts_in_buffer_bwd)
 
 
 SOFTMAX, SIGMOID = "softmax", "sigmoid"
-SELECTION_BIAS = "expert_bias"  # the leaf's name: no gradient moves it (TrainStep)
+SELECTION_BIAS = "expert_bias"  # the leaf's name: no gradient moves it (SELECTION_BIAS_HELD)
 
 
 class ExpertShare(nn.Module):
@@ -746,7 +746,7 @@ class ExpertShare(nn.Module):
 
 
 def moe_load_metrics(loads, tokens, top_k):
-    """What TrainStep reports of a step's "moe_load" collection (a layer's
+    """What a routed family reports of a step's "moe_load" collection (a layer's
     (num_held,) count of rows, the rows of the buffer it took, and whether
     its backward was the one that reads kept products): the assignments
     computed here, their share of all tokens * top_k * layers, the fullest
@@ -796,7 +796,7 @@ def move_selection_bias(params, router_rows):
 
 
 def router_metrics(params, router_rows):
-    """What TrainStep reports of the SIGMOID layers: the largest selection
+    """What a routed family reports of its SIGMOID layers: the largest selection
     bias, and the fullest expert's tokens over the mean's, over all
     `num_experts` experts of a layer (held here or not)."""
     from flax import traverse_util
@@ -807,6 +807,23 @@ def router_metrics(params, router_rows):
               if path[-1] == SELECTION_BIAS]
     return {"moe_bias_abs_max": jnp.max(jnp.abs(jnp.stack(biases))),
             "moe_router_load_max_over_mean": (rows.max(-1) / jnp.maximum(rows.mean(-1), 1.0)).max()}
+
+
+def step_metrics(cfg, sown, params, tokens):
+    """A routed family's `Family.metrics` (models/__init__.py): what its
+    `ExpertShare` layers sowed, reduced; the router's pair where the layers
+    select under a bias (a SIGMOID router alone sows "moe_router")."""
+    metrics = moe_load_metrics(sown["moe_load"], tokens, cfg.top_k)
+    if "moe_router" in sown:
+        metrics.update(router_metrics(params, sown["moe_router"]))
+    return metrics
+
+
+# A SIGMOID router's family states this as its `Family.held_leaf`: the bias is
+# a leaf of the parameters and none of the optimizer's (no moment is kept for
+# it, nothing decays it), and what moves it is the step's own routing.
+SELECTION_BIAS_HELD = (
+    SELECTION_BIAS, lambda params, sown: move_selection_bias(params, sown["moe_router"]))
 
 
 EXPERT_SHARE_SHARDING_PATTERNS = [

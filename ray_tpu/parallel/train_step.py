@@ -32,11 +32,6 @@ from jax.profiler import TraceAnnotation
 
 from ray_tpu._private import flight_recorder
 from ray_tpu.models import remat
-from ray_tpu.models.gpt2 import (
-    GPT2,
-    GPT2Config,
-    GPT2_SHARDING_RULES,
-)
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.parallel.mesh import (
     ShardingRules,
@@ -112,59 +107,15 @@ def attn_for_mesh(mesh: Mesh, seq_axis: str = "sp"):
 def model_for_mesh(cfg, mesh: Optional[Mesh]):
     """Instantiate the model wired for this mesh: shard_map'd attention on
     more than one device (ring attention iff sp > 1) and the residual
-    stream's sharding there (none on one device); config type picks the
-    family (GPT2 / GPT2MoE with an ep axis / Llama / Mellum / Granite / Lfm2 /
-    Kanana)."""
+    stream's sharding there (none on one device). The module is the one the
+    config's family states (`cfg.family`, models/__init__.py)."""
     import dataclasses
 
     if mesh is not None and cfg.attn_fn is None and mesh.devices.size > 1 and (
         cfg.use_flash_attention or mesh.shape.get("sp", 1) > 1
     ):
         cfg = dataclasses.replace(cfg, attn_fn=attn_for_mesh(mesh))
-    from ray_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEConfig
-    from ray_tpu.models.granite import Granite, GraniteConfig
-    from ray_tpu.models.kanana import Kanana, KananaConfig
-    from ray_tpu.models.lfm2 import Lfm2, Lfm2Config
-    from ray_tpu.models.llama import Llama, LlamaConfig
-    from ray_tpu.models.mellum import Mellum, MellumConfig
-
-    stream = None if mesh is None else stream_sharding(mesh)
-    if isinstance(cfg, GPT2MoEConfig):
-        return GPT2MoE(cfg, stream)
-    if isinstance(cfg, LlamaConfig):
-        return Llama(cfg, stream)
-    if isinstance(cfg, MellumConfig):
-        return Mellum(cfg, stream)
-    if isinstance(cfg, GraniteConfig):
-        return Granite(cfg, stream)
-    if isinstance(cfg, Lfm2Config):
-        return Lfm2(cfg, stream)
-    if isinstance(cfg, KananaConfig):
-        return Kanana(cfg, stream)
-    return GPT2(cfg, stream)
-
-
-def default_rules_for(cfg) -> ShardingRules:
-    from ray_tpu.models.gpt2_moe import GPT2_MOE_SHARDING_RULES, GPT2MoEConfig
-    from ray_tpu.models.granite import GRANITE_SHARDING_RULES, GraniteConfig
-    from ray_tpu.models.kanana import KANANA_SHARDING_RULES, KananaConfig
-    from ray_tpu.models.lfm2 import LFM2_SHARDING_RULES, Lfm2Config
-    from ray_tpu.models.llama import LLAMA_SHARDING_RULES, LlamaConfig
-    from ray_tpu.models.mellum import MELLUM_SHARDING_RULES, MellumConfig
-
-    if isinstance(cfg, GPT2MoEConfig):
-        return GPT2_MOE_SHARDING_RULES
-    if isinstance(cfg, LlamaConfig):
-        return LLAMA_SHARDING_RULES
-    if isinstance(cfg, MellumConfig):
-        return MELLUM_SHARDING_RULES
-    if isinstance(cfg, GraniteConfig):
-        return GRANITE_SHARDING_RULES
-    if isinstance(cfg, Lfm2Config):
-        return LFM2_SHARDING_RULES
-    if isinstance(cfg, KananaConfig):
-        return KANANA_SHARDING_RULES
-    return GPT2_SHARDING_RULES
+    return cfg.family.module(cfg, None if mesh is None else stream_sharding(mesh))
 
 
 class TrainStep:
@@ -178,7 +129,7 @@ class TrainStep:
 
     def __init__(
         self,
-        model_cfg: GPT2Config,
+        model_cfg,  # any family's config: what is read off it, models/__init__.py
         mesh: Mesh,
         *,
         learning_rate: float = 3e-4,
@@ -189,29 +140,12 @@ class TrainStep:
         flops_per_step: Optional[float] = None,
         telemetry: bool = True,
     ):
-        from ray_tpu.models.gpt2_moe import GPT2MoEConfig
-        from ray_tpu.models.granite import GraniteConfig
-        from ray_tpu.models.kanana import KananaConfig
-        from ray_tpu.models.lfm2 import Lfm2Config
-        from ray_tpu.models.mellum import MellumConfig
-        from ray_tpu.ops.moe import SELECTION_BIAS, move_selection_bias, router_metrics
-
-        self._is_moe = isinstance(model_cfg, GPT2MoEConfig)
-        # What a family's layers sow for the telemetry, collections that go
-        # out with the step's metrics. A dropless expert layer adds no term to
-        # the loss: the rows its held experts worked on ("moe_load"), and the
-        # keys a query kept where a layer selects them, with the passes the
-        # selection took ("attn_keys"). A state-space layer: how far a chunk
-        # decays and how large its carried state grows ("ssm_stats"). A router
-        # that selects under a bias: every expert's tokens ("moe_router"),
-        # which is also what moves the bias.
-        self._sown = (["moe_load", "attn_keys"] if isinstance(model_cfg, MellumConfig)
-                      else ["ssm_stats"] if isinstance(model_cfg, GraniteConfig)
-                      else ["moe_load", "moe_router"]
-                      if isinstance(model_cfg, (Lfm2Config, KananaConfig))
-                      else [])
+        # What the family is, in its own file's words (models/__init__.py):
+        # its module and rules, what its layers sow and what becomes of it,
+        # the leaves the optimizer leaves alone.
+        family = model_cfg.family
         if rules is None:
-            rules = default_rules_for(model_cfg)
+            rules = family.rules
         self.model_cfg = model_cfg
         self.mesh = mesh
         self.model = model_for_mesh(model_cfg, mesh)
@@ -227,12 +161,10 @@ class TrainStep:
             learning_rate, b2=beta2, weight_decay=weight_decay,
             mask=lambda params: jax.tree.map(lambda p: p.ndim > 1, params),
         )
-        if "moe_router" in self._sown:
-            # The selection bias is a leaf of the parameters and none of the
-            # optimizer's: no moment is kept for it, nothing decays it, and
-            # what moves it is the step's own routing (below).
+        held, move_held = family.held_leaf or (None, None)
+        if held is not None:
             adamw = optax.masked(adamw, lambda params: jax.tree_util.tree_map_with_path(
-                lambda path, _: path[-1].key != SELECTION_BIAS, params))
+                lambda path, _: path[-1].key != held, params))
         self.optimizer = optax.chain(optax.clip_by_global_norm(grad_clip), adamw)
         self.batch_sharding = batch_sharding(mesh)
 
@@ -269,31 +201,21 @@ class TrainStep:
         # (train/_device_profile.py).
         def train_step(state, batch):
             def loss_of(params):
-                loads = None
-                if self._is_moe:
-                    logits, lstate = self.model.apply(
-                        {"params": params}, batch["idx"], mutable=["losses"]
-                    )
-                    aux = sum(jax.tree.leaves(lstate.get("losses", {})))
-                elif self._sown:
-                    logits, loads = self.model.apply(
-                        {"params": params}, batch["idx"], mutable=self._sown)
-                    aux = 0.0
-                else:
-                    logits = self.model.apply({"params": params}, batch["idx"])
-                    aux = 0.0
+                logits, sown = self.model.apply(
+                    {"params": params}, batch["idx"], mutable=list(family.sown))
+                aux = sum(jax.tree.leaves([sown.get(c, {}) for c in family.loss_terms]), 0.0)
                 with jax.named_scope("loss"):
-                    return loss_fn(logits, batch["targets"]) + aux, loads
+                    return loss_fn(logits, batch["targets"]) + aux, sown
 
-            (loss, loads), grads = jax.value_and_grad(loss_of, has_aux=True)(
+            (loss, sown), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 state["params"])
             with jax.named_scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, state["opt_state"], state["params"]
                 )
                 params = optax.apply_updates(state["params"], updates)
-                if "moe_router" in (loads or {}):
-                    params = move_selection_bias(params, loads["moe_router"])
+                if move_held is not None:
+                    params = move_held(params, sown)
             new_state = {
                 "params": params,
                 "opt_state": opt_state,
@@ -302,28 +224,8 @@ class TrainStep:
             with jax.named_scope("optimizer"):
                 grad_norm = optax.global_norm(grads)
             metrics = {"loss": loss, "grad_norm": grad_norm}
-            if "ssm_stats" in (loads or {}):
-                stats = [layer["mamba"] for period in loads["ssm_stats"].values()
-                         for layer in period.values()]  # the mamba layers alone sow
-                metrics["ssm_chunk_log_decay_min"] = jnp.min(jnp.stack(
-                    [s["chunk_log_decay_min"][0] for s in stats]))
-                metrics["ssm_state_abs_max"] = jnp.max(jnp.stack(
-                    [s["state_abs_max"][0] for s in stats]))
-            elif loads is not None:
-                from ray_tpu.ops.moe import moe_load_metrics
-
-                metrics.update(moe_load_metrics(
-                    loads["moe_load"], batch["idx"].size, model_cfg.top_k))
-                if "moe_router" in loads:
-                    metrics.update(router_metrics(params, loads["moe_router"]))
-                # layers that select their keys: those a query kept, and the
-                # passes over its row's scores that finding them took
-                sown = jax.tree_util.tree_leaves_with_path(loads.get("attn_keys", {}))
-                for name, metric in (("selected", "attn_keys_selected_mean"),
-                                     ("select_passes", "attn_select_passes_mean")):
-                    layers = [x for path, x in sown if jax.tree_util.DictKey(name) in path]
-                    if layers:
-                        metrics[metric] = sum(layers) / len(layers)
+            if family.metrics is not None:
+                metrics.update(family.metrics(model_cfg, sown, params, batch["idx"].size))
             return new_state, metrics
 
         self._step = jax.jit(

@@ -146,28 +146,6 @@ _WATCHER_IDLE_S = 2.0
 _EXIT_WAIT_S = 10.0
 
 
-# Keys of a step's metrics that the summary carries as they are, from the
-# newest completed step (a scan of several: its last): the rows this
-# program's experts worked on, their share of every token's assignments,
-# the fullest held expert over the mean, the rows its sums back to the
-# tokens walked over every assignment, and the share of its layers whose
-# backward read products the remat plan kept (ops/moe.py:moe_load_metrics);
-# the keys a query kept and the compare-and-count passes a block of rows
-# took to select them, means over the layers that select
-# (models/mellum.py:Indexer); a state-space model's most negative log-decay
-# of a chunk over layers and heads (how near a chunk's exp is to flushing to
-# zero) and its largest carried-state entry (what a narrower state would
-# have to hold; models/granite.py:Mamba2Mixer); a router that selects under a
-# bias: the largest bias of any layer and expert, and the fullest expert's
-# tokens over the mean's among all the experts of a layer, held here or not
-# (the counts that move the bias; ops/moe.py:router_metrics).
-_STEP_GAUGES = ("moe_rows_held", "moe_held_share", "moe_load_max_over_mean",
-                "moe_rows_summed_share", "moe_kept_read_share",
-                "moe_bias_abs_max", "moe_router_load_max_over_mean",
-                "attn_keys_selected_mean", "attn_select_passes_mean",
-                "ssm_chunk_log_decay_min", "ssm_state_abs_max")
-
-
 @dataclasses.dataclass(slots=True)
 class _InFlight:
     """One enqueued step program: what to wait on, when it was enqueued,
@@ -240,8 +218,9 @@ class StepRecorder:
         # queue for the driver.
         self.dispatch_s = 0.0
         self.slot_wait_s = 0.0
-        # What the newest completed step said of its expert layers (the
-        # _STEP_GAUGES among its metrics), read when the watcher saw it done.
+        # What the newest completed step said beside its loss and its
+        # gradients' norm (its family's metrics, models/__init__.py:Family),
+        # read when the watcher saw it done.
         self.step_gauges: Dict[str, float] = {}
         # What the newest compiled step program saves across its blocks'
         # remat (models/remat.py:RematPlan), set by TrainStep at a compile.
@@ -380,21 +359,24 @@ class StepRecorder:
                 self._pending_cond.notify_all()
 
     def _read_gauges(self, metrics) -> None:
-        """The completed step's own gauges: scalars of a program that has
-        ended, so reading them waits for nothing."""
+        """The completed step's own gauges, every scalar of its metrics
+        beside the loss and the gradients' norm, as they are (a scan of
+        several steps: its last): scalars of a program that has ended, so
+        reading them waits for nothing."""
         import numpy as np
 
         if not isinstance(metrics, dict):
             return
-        found = {k: float(np.asarray(metrics[k]).reshape(-1)[-1])
-                 for k in _STEP_GAUGES if k in metrics}
+        found = {k: float(np.asarray(v).reshape(-1)[-1])
+                 for k, v in metrics.items() if k not in ("loss", "grad_norm")}
         if found:
             with self._lock:
                 self.step_gauges = found
 
     def _host_delta(self) -> Optional[Tuple[float, ...]]:
         """What the host did to this process since the completion before: a
-        slow step's record says whether the host was slow."""
+        slow step's record says whether the host was slow. The watcher's
+        alone, and a recorder has one watcher at a time."""
         if self._pressure is False:
             return None
         before, now = self._pressure, _host_pressure()

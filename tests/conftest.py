@@ -141,3 +141,42 @@ def ray_start_cluster():
     cluster = Cluster(initialize_head=False)
     yield cluster
     cluster.shutdown()
+
+
+# ------------------------------------------- a described (not attached) TPU
+# tests/test_tpu_compile*.py compile for it. Module-scoped: every file that
+# asks keeps the compilation cache off round its own tests.
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # Such a compile is written to the persistent cache but cannot be read
+    # back without a chip: keep the cache off round these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))

@@ -391,6 +391,26 @@ def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch
     assert hashlib.sha256(text.encode()).hexdigest() == LFM2_STEP
 
 
+# This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
+# rule), as tests/test_mellum.py:_step_text gives it, taken on PR 46's parent's
+# tree before `TrainStep` stopped knowing its families by name.
+KANANA_STEP = "59dc3fe91aa7a076efee48fcbd9ed0f3a2bc15345f453c6c93e5fd0255da388f"
+
+
+def test_the_cell_lowers_to_its_pinned_step(monkeypatch):
+    from tests.test_mellum import _step_text
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
+    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
+    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    text = _step_text(ts, state, {"idx": tok, "targets": tok})
+    assert not set(KEPT_PRODUCTS) & set(remat.traced(cfg).names)
+    assert hashlib.sha256(text.encode()).hexdigest() == KANANA_STEP
+
+
 def test_step_reports_the_router_s_two_gauges_through_the_telemetry():
     cfg = KananaConfig.tiny(num_held=4)
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]))

@@ -100,6 +100,31 @@ def test_trainstep_with_moe_config_on_ep_mesh():
     assert losses[-1] < losses[0]
 
 
+# The step of the one family whose layers add a term to the loss (the
+# "losses" collection), `GPT2MoEConfig.tiny_moe()` on dp=2 x ep=2, as
+# tests/test_mellum.py:_step_text gives it: taken on PR 46's parent's tree
+# before `TrainStep` stopped knowing its families by name. No cell runs it.
+# Plain attention: a shard_map over two axes prints them as a frozenset, whose
+# order is the interpreter's hash seed's.
+GPT2_MOE_STEP = "08ff1237632ef3b4257b0dbba5e78cb7b7c928d367f27dbac74e0dea3c11eac8"
+
+
+def test_the_capacity_routed_step_lowers_to_its_pinned_step():
+    import hashlib
+
+    from ray_tpu.models.gpt2_moe import GPT2MoEConfig
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.train_step import TrainStep
+    from tests.test_mellum import _step_text
+
+    ts = TrainStep(GPT2MoEConfig.tiny_moe(use_flash_attention=False),
+                   make_mesh({"dp": 2, "ep": 2}, devices=jax.devices()[:4]), telemetry=False)
+    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((4, 64), jnp.int32)
+    text = _step_text(ts, state, {"idx": tok, "targets": tok})
+    assert hashlib.sha256(text.encode()).hexdigest() == GPT2_MOE_STEP
+
+
 def test_moe_model_trains_on_dp_ep_mesh():
     """8 virtual devices as dp=2 x ep=4: one full fwd/bwd/update step of the
     MoE transformer with experts sharded over 'ep', and sharded forward

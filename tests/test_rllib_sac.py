@@ -53,6 +53,10 @@ def test_sac_update_shapes():
         assert np.isfinite(aux[key]), aux
 
 
+# slow: a convergence rate (up to 350 training iterations inside 180 s) on a
+# box that six workers load; it ran into its own limit in the driver's runs on
+# three trees. No benchmark cell runs this library (ROADMAP C9, C17).
+@pytest.mark.slow
 def test_sac_learns_pendulum(rl_cluster):
     """SAC reaches clearly-better-than-random on Pendulum-v1 (random policy
     averages about -1200; the threshold proves the twin-critic +

@@ -272,9 +272,12 @@ class PipelinedLoop:
         self.rec.settle(30.0)  # the watcher books it; a loaded box may be slow
 
     def finish(self):
-        """Complete what is still in flight, so no watcher is left waiting."""
+        """Complete what is still in flight and see it booked, so no watcher
+        is left waiting, and none books a step inside the next test (where
+        `_host_pressure` may be that test's own: it read two for one)."""
         while self.in_flight:
             self.in_flight.pop(0).complete()
+        self.rec.settle(30.0)
 
 
 @pytest.mark.fast
