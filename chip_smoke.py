@@ -186,20 +186,23 @@ def _check_flash_vs_xla(shape, seed, on_tpu):
             "tiles": flash_tiles(h, t, d, jnp.bfloat16)._asdict()}
 
 
-def _check_ssd_vs_chunked(seed, on_tpu):
+def _check_ssd_vs_chunked(seed, on_tpu, groups=1):
     """ops/ssd.py's two kernels against the same chunked form in jax.numpy
-    at the benchmark's widths (64 heads of 64, state 128, chunks of 256) on a
-    quarter of its sequence, same seed: the output, the chunk states and the
-    six gradients, as max-abs error over the reference's max-abs value."""
+    at a benchmark cell's widths (64 heads of 64, state 128; one group of B
+    and C and chunks of 256, granite's, or eight groups and chunks of 128,
+    nemotron's) on 1,024 positions, same seed: the output, the chunk states
+    and the six gradients, as max-abs error over the reference's max-abs
+    value."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops import ssd
 
-    b, t, h, p, n, chunk = (1, 1024, 64, 64, 128, 256) if on_tpu else (1, 64, 4, 32, 16, 16)
+    b, t, h, p, n, chunk = ((1, 1024, 64, 64, 128, 256 if groups == 1 else 128) if on_tpu
+                            else (1, 64, 4 * groups, 32, 16, 16))
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     x, w = (jax.random.normal(k, (b, t, h, p), jnp.bfloat16) for k in ks[:2])
-    bm, cm = (jax.random.normal(k, (b, t, 1, n), jnp.bfloat16) for k in ks[2:4])
+    bm, cm = (jax.random.normal(k, (b, t, groups, n), jnp.bfloat16) for k in ks[2:4])
     dt = jax.nn.softplus(jax.random.normal(ks[4], (b, t, h)) - 3.0)
     a = -jnp.exp(jax.random.uniform(ks[5], (h,), minval=0.0, maxval=2.77))
     d = jnp.ones((h,))
@@ -225,9 +228,68 @@ def _check_ssd_vs_chunked(seed, on_tpu):
         errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
     if max(errs.values()) > ATTN_REL_TOL:
         raise RuntimeError(f"ssd kernels vs chunked form beyond {ATTN_REL_TOL}: {errs}")
-    return {"shape": [b, t, h, p, n], "chunk": chunk, "rel_err": errs,
-            "heads_a_slab_and_a_grid_step": list(ssd.head_tile(h, p)),
-            "ssd_path": ssd.ssd_path(t, h, p, 1, chunk)}
+    return {"shape": [b, t, h, p, n], "groups": groups, "chunk": chunk, "rel_err": errs,
+            "heads_a_slab_and_a_grid_step": list(ssd.head_tile(h, p, groups)),
+            "ssd_path": ssd.ssd_path(t, h, p, groups, chunk)}
+
+
+def _check_relu2_experts_vs_plain(seed, on_tpu):
+    """ops/moe.py's `ExpertShare` with experts of two matrices under relu
+    squared (`moe.RELU2`; on a TPU megablox's grouped matmuls at the
+    benchmark's widths: a 2,688-wide stream, experts 1,856 wide, 8 held of
+    128, top 6, on 4,096 tokens) against every token through every held
+    expert in jax.numpy, weighted by the layer's own gates where chosen: the
+    output and the gradients of the input and of both matrices, as max-abs
+    error over the reference's max-abs value."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.moe import RELU2, SIGMOID, ExpertShare
+
+    d, ff, experts, held, k, tokens = ((2688, 1856, 128, 8, 6, 4096) if on_tpu
+                                       else (64, 48, 8, 4, 2, 256))
+    layer = ExpertShare(d, ff, experts, k, 0, held, router=SIGMOID, scaling=2.5, gate_eps=1e-20,
+                        hand_up_choices=True, form=RELU2)
+    kx, kp, kw = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(kx, (1, tokens, d), jnp.bfloat16)
+    w = jax.random.normal(kw, (1, tokens, d), jnp.float32)
+    params = layer.init(kp, x)["params"]
+    if sorted(params) != ["down", "expert_bias", "router", "up"]:
+        raise RuntimeError(f"experts of two matrices hold {sorted(params)}")
+    idx = layer.apply({"params": params}, x)[1]
+
+    def plain(up, down, x):
+        scores = jax.nn.sigmoid(x.astype(jnp.float32) @ params["router"]["kernel"])
+        chosen = jnp.take_along_axis(scores, idx, -1)
+        gates = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * 2.5
+        y = jnp.zeros(x.shape, jnp.float32)
+        for e in range(held):
+            hidden = jnp.square(jax.nn.relu(jnp.dot(
+                x, up[e].astype(x.dtype), preferred_element_type=jnp.float32)))
+            out = jnp.dot(hidden.astype(x.dtype), down[e].astype(x.dtype),
+                          preferred_element_type=jnp.float32)
+            y = y + jnp.where(idx == e, gates, 0.0).sum(-1)[..., None] * out
+        return y
+
+    def run(form):
+        def loss(up, down, x):
+            y = form(up, down, x).astype(jnp.float32)
+            return (y * w).sum(), y
+        grads, y = jax.jit(jax.grad(loss, argnums=range(3), has_aux=True))(
+            params["up"], params["down"], x)
+        return (y, *grads)
+
+    system = run(lambda up, down, x: layer.apply(
+        {"params": {**params, "up": up, "down": down}}, x)[0])
+    errs = {}
+    for name, got, want in zip(("y", "d_up", "d_down", "dx"), system, run(plain)):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"relu2 experts {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"relu2 experts vs the plain form beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": [tokens, d, ff], "held_of": [held, experts], "top_k": k, "rel_err": errs}
 
 
 def _check_gated_conv_vs_plain(seed, on_tpu):
@@ -430,7 +492,7 @@ def _flash_calls_by_cell(on_tpu):
     the scan's outputs (`ssm_y`) and twice where it does not; a layer that is
     a gated short convolution (`conv`) has gated_conv_bwd once, and
     gated_conv_fwd once where the plan saves its output (`conv_y`), else
-    twice."""
+    twice; a layer that is an expert layer alone (`experts`) has none."""
     import collections
 
     import jax
@@ -458,10 +520,11 @@ def _flash_calls_by_cell(on_tpu):
             kinds[k.removesuffix(attention.LEGACY_NAMES).rsplit("_bwd_", 1)[-1]] += n
         layer_kinds = list(getattr(cfg, "layer_types", ()))
         scans, convs = layer_kinds.count("mamba"), layer_kinds.count("conv")
+        mixers_alone = layer_kinds.count("experts")
         saved = remat.traced(cfg).names
         scan_fwd = scans * (1 if "ssm_y" in saved else 2)
         conv_fwd = convs * (1 if "conv_y" in saved else 2)
-        if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans - convs
+        if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans - convs - mixers_alone
                            and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans
                            and found["gated_conv_fwd"] == conv_fwd
                            and found["gated_conv_bwd"] == convs):
@@ -508,6 +571,8 @@ def one_chip_loop(config):
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
         for shape in config["attn_shapes"]]
     report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
+    report["ssd_vs_chunked_at_eight_groups"] = _check_ssd_vs_chunked(config["seed"], on_tpu, 8)
+    report["relu2_experts_vs_plain"] = _check_relu2_experts_vs_plain(config["seed"], on_tpu)
     report["gated_conv_vs_plain"] = _check_gated_conv_vs_plain(config["seed"], on_tpu)
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()

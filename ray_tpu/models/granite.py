@@ -173,9 +173,14 @@ def _conv_init(key, shape, dtype=jnp.float32):
 class Mamba2Mixer(nn.Module):
     """(B, T, d) -> (B, T, d): the module docstring's `mamba` layer. Sows
     into "ssm_stats" the most negative log-decay of a chunk and the largest
-    entry of a carried state (TrainStep's telemetry)."""
+    entry of a carried state (TrainStep's telemetry). `config` is a
+    GraniteConfig or any config with its `ssm_*` fields, `n_embd`, `rms_eps`
+    and `dtype`; `norm_groups`: the gated norm is taken over each of this
+    many equal parts of the H P channels on its own (models/nemotron_h.py: a
+    part for each group of B and C), with one weight over all."""
 
-    config: GraniteConfig
+    config: Any
+    norm_groups: int = 1
 
     @nn.compact
     def __call__(self, u):
@@ -208,7 +213,8 @@ class Mamba2Mixer(nn.Module):
             self.sow("ssm_stats", "state_abs_max",
                      jax.lax.stop_gradient(jnp.abs(states).max()))
         with jax.named_scope("ssm.gate"):
-            y = RMSNorm(cfg.rms_eps, name="norm")(y.reshape(b, t, inner) * nn.silu(z))
+            y = RMSNorm(cfg.rms_eps, self.norm_groups, name="norm")(
+                y.reshape(b, t, inner) * nn.silu(z))
         with jax.named_scope("ssm.out_proj"):
             return dense(cfg.n_embd, "out_proj")(y)
 
@@ -282,15 +288,20 @@ def remat_plan(cfg: GraniteConfig, shape: remat.StepShape, limit) -> remat.Remat
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
 
 
-def _block_bytes(cfg: GraniteConfig, itemsize: int) -> int:
-    """What a Mamba block's backward works in, bytes a token, from its
+def mixer_bytes(cfg, itemsize: int) -> int:
+    """What a Mamba mixer's backward works in, bytes a token, from its
     widths: the input projection's output in the compute dtype; the
     convolution's output, the scan's output and the gated norm's input in
-    that and in float32; the MLP's gate and up and their gradients (154 KB at
-    the published widths in bf16)."""
+    that and in float32."""
     return (itemsize * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
-            + (itemsize + 4) * (cfg.ssm_conv_dim + 2 * cfg.ssm_inner)
-            + itemsize * 4 * cfg.intermediate)
+            + (itemsize + 4) * (cfg.ssm_conv_dim + 2 * cfg.ssm_inner))
+
+
+def _block_bytes(cfg: GraniteConfig, itemsize: int) -> int:
+    """What a Mamba block's backward works in, bytes a token: the mixer's
+    (`mixer_bytes`) and the MLP's gate and up and their gradients (154 KB at
+    the published widths in bf16)."""
+    return mixer_bytes(cfg, itemsize) + itemsize * 4 * cfg.intermediate
 
 
 class GranitePeriod(nn.Module):
@@ -333,11 +344,13 @@ class Granite(nn.Module):
             return logits / cfg.logits_scaling
 
 
-GRANITE_SHARDING_RULES = ShardingRules([
+MAMBA_SHARDING_PATTERNS = [
     (r"mamba/in_proj/kernel", P("fsdp", None)),
     (r"mamba/out_proj/kernel", P(None, "fsdp")),
     (r"mamba/", P()),
-] + LLAMA_SHARDING_PATTERNS, default=P())
+]
+GRANITE_SHARDING_RULES = ShardingRules(MAMBA_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS,
+                                       default=P())
 
 
 def step_metrics(cfg, sown, params, tokens):

@@ -118,11 +118,15 @@ def rms_norm(x, weight, eps):
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
+    groups: int = 1  # equal parts of the last axis, each normed on its own; one weight over all
 
     @nn.compact
     def __call__(self, x):
         w = self.param("weight", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        return rms_norm(x, w.astype(x.dtype), self.eps)
+        if self.groups == 1:
+            return rms_norm(x, w.astype(x.dtype), self.eps)
+        parts = lambda v: v.reshape(*v.shape[:-1], self.groups, -1)
+        return rms_norm(parts(x), parts(w.astype(x.dtype)), self.eps).reshape(x.shape)
 
 
 def rope_angles(head_dim: int, theta: float, positions, inv_freq=None):

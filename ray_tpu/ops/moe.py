@@ -12,13 +12,19 @@ Layout: expert weights carry a leading E dim sharded on 'ep'
 (``MOE_SHARDING_RULES``); tokens stay sharded on dp/sp. Under pjit the
 dispatch einsum becomes the a2a scatter and the combine einsum the a2a
 gather, riding ICI.
+
+All of the above is `MoE`'s alone. `ExpertShare`, further down, is one
+chip's share of a dropless expert layer (no capacity, nothing dropped), its
+experts of one of two forms (`ExpertForm`): SwiGLU, or two matrices under an
+activation.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+import operator
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -154,7 +160,10 @@ class MoE(nn.Module):
 # grouped matmuls, and each token gets its rows back weighted by its gates.
 # The router is as wide as the layer (every expert, held here or not); the
 # gates are renormalised over all k chosen; what the experts held elsewhere
-# would add is left out, and nothing here stands in for them.
+# would add is left out, and nothing here stands in for them. An expert is
+# of one of two forms (`ExpertForm`): SwiGLU, W_down (silu(W_gate u) * W_up
+# u), three matrices, or two matrices under an activation, W_down act(W_up
+# u), here relu squared: the same stages with a product fewer.
 
 
 def route_plan(idx, first_expert, num_held):
@@ -474,12 +483,29 @@ def _megablox_grads(lhs, rhs, group_sizes, g, interpret=False):
 _ROW_HEADROOM = 1.5
 
 
-# Names of the three products for a block's checkpoint policy
-# (models/remat.py): under `nn.remat` the ones the policy keeps are held from
-# the forward pass, the others are made again by `_experts_in_buffer`'s
-# forward rule. A layer whose family's plan keeps none names none
-# (`ExpertShare.products_kept`).
-KEPT_PRODUCTS = ("moe_gate", "moe_up", "moe_out")
+class ExpertForm(NamedTuple):
+    """What an expert computes of a row u: W_down hidden(W_m u for each m of
+    `matrices`), d_model -> d_ff -> d_model. The weights' tree holds a leaf
+    for each of `matrices` and "down", and no other."""
+
+    matrices: Tuple[str, ...]
+    hidden: Callable  # the products of `matrices`, in order -> the down matrix's operand
+
+    @property
+    def products(self) -> Tuple[str, ...]:
+        """Names of the layer's products for a block's checkpoint policy
+        (models/remat.py), a matrix each, the down matrix's last: under
+        `nn.remat` the ones the policy keeps are held from the forward pass,
+        the others are made again by `_experts_in_buffer`'s forward rule. A
+        layer whose family's plan keeps none names none
+        (`ExpertShare.products_kept`)."""
+        return tuple(f"moe_{m}" for m in self.matrices) + ("moe_out",)
+
+
+SWIGLU = ExpertForm(("gate", "up"), lambda gate, up: nn.silu(gate) * up)
+RELU2 = ExpertForm(("up",), lambda up: jnp.square(nn.relu(up)))
+
+KEPT_PRODUCTS = SWIGLU.products  # ("moe_gate", "moe_up", "moe_out")
 ROUTE_PLAN = "moe_plan"  # the choices and `route_plan`'s arrays, under one name
 
 
@@ -491,39 +517,39 @@ def buffer_rows(n, top_k, num_held, num_experts):
     return min(room, n * top_k)
 
 
-def named_bytes(n, top_k, num_held, num_experts, d_model, d_ff, itemsize):
+def named_bytes(n, top_k, num_held, num_experts, d_model, d_ff, itemsize, form=SWIGLU):
     """Bytes a layer of n tokens of what `ExpertShare` names for a block's
     checkpoint policy (models/remat.py): ROUTE_PLAN, the choices and the
-    plan's arrays (five int32 and a bool an assignment), and KEPT_PRODUCTS,
-    rows of the buffer with headroom in the compute dtype (nothing where the
-    one buffer is every assignment: that layer names no product)."""
+    plan's arrays (five int32 and a bool an assignment), and the form's
+    products, rows of the buffer with headroom in the compute dtype (nothing
+    where the one buffer is every assignment: that layer names no product)."""
     rows = buffer_rows(n, top_k, num_held, num_experts)
     rows = rows if rows < n * top_k else 0
-    widths = (d_ff, d_ff, d_model)  # KEPT_PRODUCTS' order
+    widths = (d_ff,) * len(form.matrices) + (d_model,)  # the products' order
     return {ROUTE_PLAN: n * top_k * (5 * 4 + 1),
-            **{name: rows * width * itemsize for name, width in zip(KEPT_PRODUCTS, widths)}}
+            **{name: rows * width * itemsize for name, width in zip(form.products, widths)}}
 
 
 # The held experts on a buffer of the first `rows` sorted rows (every row
 # routed here lies within them), in the three stages whose results the
-# backward pass reads: the gate and up matrices' products of the rows
-# gathered, the down matrix's product of silu(gate) * up, and the sums back
-# to the tokens.
+# backward pass reads: the form's first matrices' products of the rows
+# gathered (gate and up, or up alone), the down matrix's product of what the
+# form makes of them, and the sums back to the tokens.
 
 
-def _gate_up(rows, plan, weights, x):
-    """x (N, C) -> the gate and the up product, (rows, d_ff) each."""
+def _gate_up(form, rows, plan, weights, x):
+    """x (N, C) -> the products of the form's first matrices, (rows, d_ff)
+    each: the gate and the up product, or the up product alone."""
     order, _, held, group_sizes, _ = plan
     with jax.named_scope("moe.experts"):
         taken = dispatch_rows(x, order[:rows], held, token_order(plan, rows))
-        return (grouped_matmul(taken, weights["gate"], group_sizes),
-                grouped_matmul(taken, weights["up"], group_sizes))
+        return tuple(grouped_matmul(taken, weights[m], group_sizes) for m in form.matrices)
 
 
-def _down(plan, weights, gate_out, up_out):
-    """The two products -> the down product, (rows, C)."""
+def _down(form, plan, weights, *products):
+    """The first stage's products -> the down product, (rows, C)."""
     with jax.named_scope("moe.experts"):
-        return grouped_matmul(nn.silu(gate_out) * up_out, weights["down"], plan[3])
+        return grouped_matmul(form.hidden(*products), weights["down"], plan[3])
 
 
 def _combine(rows, dtype, plan, out, gates):
@@ -534,9 +560,9 @@ def _combine(rows, dtype, plan, out, gates):
                             token_order(plan, rows), dtype)
 
 
-def _expert_rows(rows, dtype, plan, weights, x, gates):
+def _expert_rows(form, rows, dtype, plan, weights, x, gates):
     """x (N, C) -> (N, C) in `dtype`: the three stages on one buffer."""
-    out = _down(plan, weights, *_gate_up(rows, plan, weights, x))
+    out = _down(form, plan, weights, *_gate_up(form, rows, plan, weights, x))
     return _combine(rows, dtype, plan, out, gates)
 
 
@@ -545,23 +571,24 @@ def _fits(plan, room):
     return plan[3].sum() <= room
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _experts_in_buffer(rooms, dtype, kept, plan, weights, x, gates):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _experts_in_buffer(form, rooms, dtype, kept, plan, weights, x, gates):
     """`_expert_rows` on the smaller of two buffers (rooms: rows of the one
     with headroom, rows of the one for every assignment) that holds the rows
     routed here: both are compiled, one runs. The backward pass makes the
     same choice. `kept`: whether anything holds the forward's products for
     it (`ExpertShare.products_kept`). Where it does, the backward reads in
-    the buffer with headroom the three products the forward wrote and runs
-    no forward grouped matmul; where nothing does, and in a step that
+    the buffer with headroom the products the forward wrote (three of a
+    SwiGLU, two of an expert of two matrices) and runs no forward grouped
+    matmul; where nothing does, and in a step that
     overflowed either way, it differentiates `_expert_rows` on the buffer it
     takes, its forward again. Differentiating the `cond` itself would have
     the branch that runs write zeros for everything the other one would have
     kept."""
-    return _experts_in_buffer_fwd(rooms, dtype, kept, plan, weights, x, gates)[0]
+    return _experts_in_buffer_fwd(form, rooms, dtype, kept, plan, weights, x, gates)[0]
 
 
-def _experts_in_buffer_fwd(rooms, dtype, kept, plan, weights, x, gates):
+def _experts_in_buffer_fwd(form, rooms, dtype, kept, plan, weights, x, gates):
     """With nothing kept, one `cond` over the two buffers and the operands
     as the only residuals: a block's remat then has nothing of this layer's
     to make again, and the backward rule makes what it needs inside the
@@ -582,48 +609,52 @@ def _experts_in_buffer_fwd(rooms, dtype, kept, plan, weights, x, gates):
     products with no `cond` between."""
     room = rooms[0]
     if not kept:
-        y = jax.lax.cond(_fits(plan, room), functools.partial(_expert_rows, room, dtype),
-                         functools.partial(_expert_rows, rooms[1], dtype), plan, weights, x, gates)
+        y = jax.lax.cond(_fits(plan, room), functools.partial(_expert_rows, form, room, dtype),
+                         functools.partial(_expert_rows, form, rooms[1], dtype),
+                         plan, weights, x, gates)
         return y, (plan, weights, x, gates)
     order, inv, held, group_sizes, by_token = plan
     # the groups cut to the buffer: as they are wherever the rows fit
     ends = jnp.minimum(jnp.cumsum(group_sizes), room)
     within = order, inv, held, jnp.diff(ends, prepend=0), by_token
-    gate_name, up_name, out_name = KEPT_PRODUCTS
-    gate_out, up_out = _gate_up(room, within, weights, x)
-    gate_out, up_out = checkpoint_name(gate_out, gate_name), checkpoint_name(up_out, up_name)
-    out = checkpoint_name(_down(within, weights, gate_out, up_out), out_name)
+    *names, out_name = form.products
+    products = tuple(checkpoint_name(product, name) for product, name in zip(
+        _gate_up(form, room, within, weights, x), names))
+    out = checkpoint_name(_down(form, within, weights, *products), out_name)
     y = jax.lax.cond(
         _fits(plan, room), lambda plan, weights, x, gates, y: y,
-        lambda plan, weights, x, gates, y: _expert_rows(rooms[1], dtype, plan, weights, x, gates),
+        lambda plan, weights, x, gates, y: _expert_rows(
+            form, rooms[1], dtype, plan, weights, x, gates),
         plan, weights, x, gates, _combine(room, dtype, within, out, gates))
-    return y, (plan, weights, x, gates, gate_out, up_out, out)
+    return y, (plan, weights, x, gates, *products, out)
 
 
-def _experts_in_buffer_bwd(rooms, dtype, kept, res, g):
+def _experts_in_buffer_bwd(form, rooms, dtype, kept, res, g):
     room = rooms[0]
 
-    def read(plan, weights, x, gates, gate_out, up_out, out):
+    def read(plan, weights, x, gates, *kept_products):
         """Each stage's transpose at the value the forward computed, last
         stage first; the rows are gathered again (a gather, cheaper than
         their bytes held)."""
+        *products, out = kept_products
         order, inv, held, group_sizes, _ = plan
         at, back = order[:room], token_order(plan, room)
         with jax.named_scope("moe.combine"):
             d_out, d_gates = _combine_bwd(
                 dtype, (out, gates, at, jnp.minimum(inv, room - 1), held), g)[:2]
         with jax.named_scope("moe.experts"):
-            hidden, products = jax.vjp(lambda gate, up: nn.silu(gate) * up, gate_out, up_out)
+            hidden, pull = jax.vjp(form.hidden, *products)
             d_hidden, d_down = _grouped_matmul_grads(hidden, weights["down"], group_sizes, d_out)
-            d_gate_out, d_up_out = products(d_hidden)
+            d_products = pull(d_hidden)
             taken = dispatch_rows(x, at, held, back)
-            by_gate, d_gate = _grouped_matmul_grads(taken, weights["gate"], group_sizes, d_gate_out)
-            by_up, d_up = _grouped_matmul_grads(taken, weights["up"], group_sizes, d_up_out)
-            d_x = _dispatch_bwd((held, back), by_gate + by_up)[0]
-        return {"gate": d_gate, "up": d_up, "down": d_down}, d_x, d_gates
+            by, d_weights = zip(*(
+                _grouped_matmul_grads(taken, weights[m], group_sizes, d_product)
+                for m, d_product in zip(form.matrices, d_products)))
+            d_x = _dispatch_bwd((held, back), functools.reduce(operator.add, by))[0]
+        return {**dict(zip(form.matrices, d_weights)), "down": d_down}, d_x, d_gates
 
     def again(rows, plan, weights, x, gates, *unread):
-        return jax.vjp(functools.partial(_expert_rows, rows, dtype, plan),
+        return jax.vjp(functools.partial(_expert_rows, form, rows, dtype, plan),
                        weights, x, gates)[1](g)
 
     fitting = read if kept else functools.partial(again, room)
@@ -639,9 +670,10 @@ SELECTION_BIAS = "expert_bias"  # the leaf's name: no gradient moves it (SELECTI
 
 
 class ExpertShare(nn.Module):
-    """(B, T, C) -> (B, T, C): the part of a top-k-of-`num_experts` SwiGLU
-    expert layer that experts first_expert .. first_expert + num_held - 1
-    compute (all of them where num_held is None). Sows the (B, T, k) choices
+    """(B, T, C) -> (B, T, C): the part of a top-k-of-`num_experts` expert
+    layer that experts first_expert .. first_expert + num_held - 1 compute
+    (all of them where num_held is None), each expert of `form` (SWIGLU, or
+    two matrices under an activation: RELU2). Sows the (B, T, k) choices
     over the whole layer into "choices" (bench/families/__init__.py) and the
     held experts' row counts into "moe_load" (TrainStep's telemetry).
 
@@ -687,6 +719,7 @@ class ExpertShare(nn.Module):
     # its plan says (models/remat.py). False: the layer names none and takes
     # the form that makes them inside its backward (`_experts_in_buffer`).
     products_kept: bool = True
+    form: ExpertForm = SWIGLU
 
     @nn.compact
     def __call__(self, x):
@@ -727,18 +760,18 @@ class ExpertShare(nn.Module):
 
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
         weights = {name: self.param(name, init, shape, jnp.float32)
-                   for name, shape in (("gate", (num_held, C, self.d_ff)),
-                                       ("up", (num_held, C, self.d_ff)),
+                   for name, shape in (*((m, (num_held, C, self.d_ff)) for m in self.form.matrices),
                                        ("down", (num_held, self.d_ff, C)))}
 
         operands = (weights, x.reshape(n, C).astype(self.dtype), gates)
         room = buffer_rows(n, k, num_held, self.num_experts)
         if room < n * k:
-            y = _experts_in_buffer((room, n * k), x.dtype, self.products_kept, plan, *operands)
+            y = _experts_in_buffer(
+                self.form, (room, n * k), x.dtype, self.products_kept, plan, *operands)
             fits = _fits(plan, room)
             walked, read = jnp.where(fits, room, n * k), fits & self.products_kept
         else:
-            y, walked, read = _expert_rows(n * k, x.dtype, plan, *operands), n * k, False
+            y, walked, read = _expert_rows(self.form, n * k, x.dtype, plan, *operands), n * k, False
         self.sow("moe_load", "walked", jnp.asarray(walked, jnp.int32))
         self.sow("moe_load", "read", jnp.asarray(read, jnp.bool_))
         y = y.reshape(B, T, C)
@@ -826,7 +859,7 @@ SELECTION_BIAS_HELD = (
     SELECTION_BIAS, lambda params, sown: move_selection_bias(params, sown["moe_router"]))
 
 
-EXPERT_SHARE_SHARDING_PATTERNS = [
+EXPERT_SHARE_SHARDING_PATTERNS = [  # either form's: one of two matrices has no `gate`
     (r"moe/router/kernel", P()),
     (r"moe/(gate|up)$", P("ep", "fsdp", "tp")),
     (r"moe/down$", P("ep", "tp", "fsdp")),

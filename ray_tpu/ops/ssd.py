@@ -36,10 +36,15 @@ the one residual the backward needs beyond the inputs. The backward walks
 the chunks last to first with the state's cotangent in the scratch and makes
 L again. Heads are narrower than a vector's 128 lanes and are worked on
 128 / P at a time (a slab): one matmul then serves the slab for everything
-but the product with L, which is a head's own and is selected by lane. The
-kernels take what the one family that calls them has (models/granite.py):
-one group, heads of a width that divides 128; any other shape runs the
-jax.numpy form.
+but the product with L, which is a head's own and is selected by lane. B
+and C reach the calls as (b, T, G N), every group's side by side, and a grid
+step reads its own group's N columns: the heads of a grid step lie within
+one group (a group's heads are whole slabs, and a step takes as many slabs
+as divide a group's), so C B^T is still made once a step, and each step's
+part of dB and dC is summed over its group's steps and no further. The
+kernels take heads of a width that divides 128 in such groups
+(models/granite.py: one group; models/nemotron_h.py: eight, a grid step's
+eight heads one group); any other shape runs the jax.numpy form.
 
 The cumulative sum is made outside the kernels (`chunk_log_decay`), and the
 calls take Delta and c as two inputs: XLA's own rules carry c's cotangent
@@ -123,13 +128,14 @@ def ssd_chunked(x, dt, cs, B, C, D, chunk):
 # --------------------------------------------------------------------------
 
 
-def head_tile(h, p):
+def head_tile(h, p, groups=1):
     """(heads a slab, heads a grid step): a slab is 128 lanes of heads, and a
-    grid step takes up to _SLABS slabs."""
+    grid step takes up to _SLABS slabs, all of one group of h / groups heads."""
     per = _LANES // p
-    if p >= _LANES or _LANES % p or h % per:
-        raise ValueError(f"{h} heads of width {p} do not fill slabs of {_LANES} lanes")
-    slabs = max(s for s in range(1, _SLABS + 1) if (h // per) % s == 0)
+    if p >= _LANES or _LANES % p or h % groups or (h // groups) % per:
+        raise ValueError(f"{h} heads of width {p} in {groups} groups do not fill slabs of "
+                         f"{_LANES} lanes a group")
+    slabs = max(s for s in range(1, _SLABS + 1) if (h // groups // per) % s == 0)
     return per, per * slabs
 
 
@@ -296,14 +302,16 @@ def _tiled(v, tile):
     return cols, cols.swapaxes(2, 3)
 
 
-def _specs(chunks, chunk, tile, p, n, reverse):
+def _specs(chunks, chunk, tile, p, n, reverse, steps_a_group=None):
     """Block specs of a call's operands, by grid (batch, head tile, chunk);
-    `reverse` walks the chunks last to first."""
+    `reverse` walks the chunks last to first. `steps_a_group`: head tiles
+    that share a group's N columns of B and C (None: one group, all)."""
     at = (lambda k: chunks - 1 - k) if reverse else (lambda k: k)
+    group = (lambda j: 0) if steps_a_group is None else (lambda j: j // steps_a_group)
     wide = pl.BlockSpec((1, chunk, tile * p), lambda i, j, k: (i, at(k), j))
     cols = pl.BlockSpec((1, 1, chunk, tile), lambda i, j, k: (i, j, at(k), 0))
     rows = pl.BlockSpec((1, 1, tile, chunk), lambda i, j, k: (i, j, 0, at(k)))
-    shared = pl.BlockSpec((1, chunk, n), lambda i, j, k: (i, at(k), 0))
+    shared = pl.BlockSpec((1, chunk, n), lambda i, j, k: (i, at(k), group(j)))
     skip = pl.BlockSpec((1, tile * p), lambda i, j, k: (0, j))
     state = pl.BlockSpec((1, 1, tile * p, n), lambda i, j, k: (i, at(k), j, 0))
     own_shared = pl.BlockSpec((1, 1, chunk, n), lambda i, j, k: (i, j, at(k), 0))
@@ -314,21 +322,33 @@ def _specs(chunks, chunk, tile, p, n, reverse):
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _side_by_side(v):
+    """(b, T, G, N) -> (b, T, G N): the groups' columns side by side."""
+    b, t, g, n = v.shape
+    return v[:, :, 0] if g == 1 else v.reshape(b, t, g * n)
+
+
+def _steps_a_group(h, tile, groups):
+    """`_specs`' steps_a_group of h heads in `groups` groups, `tile` a step."""
+    return None if groups == 1 else h // groups // tile
+
+
 def _operands(x, dt, cs, B, C, D, tile):
     """What both calls read, as the kernels take it: x with its heads along
-    the lanes, Delta by columns, c by columns and by rows, B and C of the one
-    group, D a lane."""
+    the lanes, Delta by columns, c by columns and by rows, B and C with their
+    groups side by side, D a lane."""
     b, t, h, p = x.shape
     csc, csr = _tiled(cs, tile)
-    return (x.reshape(b, t, h * p), _tiled(dt, tile)[0], csc, csr, B[:, :, 0], C[:, :, 0],
-            jnp.repeat(D.astype(jnp.float32), p)[None])
+    return (x.reshape(b, t, h * p), _tiled(dt, tile)[0], csc, csr, _side_by_side(B),
+            _side_by_side(C), jnp.repeat(D.astype(jnp.float32), p)[None])
 
 
 def _fwd_call(x, dt, cs, B, C, D, chunk, interpret):
     b, t, h, p = x.shape
-    n = B.shape[3]
-    per, tile = head_tile(h, p)
-    wide, cols, rows, shared, skip, state, _, _ = _specs(t // chunk, chunk, tile, p, n, False)
+    g, n = B.shape[2:]
+    per, tile = head_tile(h, p, g)
+    wide, cols, rows, shared, skip, state, _, _ = _specs(
+        t // chunk, chunk, tile, p, n, False, _steps_a_group(h, tile, g))
     y, states = pl.pallas_call(
         functools.partial(_fwd_kernel, p=p, per=per),
         grid=(b, h // tile, t // chunk),
@@ -344,10 +364,10 @@ def _fwd_call(x, dt, cs, B, C, D, chunk, interpret):
 
 def _bwd_call(x, dt, cs, B, C, D, states, dy, chunk, interpret):
     b, t, h, p = x.shape
-    n, nc = B.shape[3], t // chunk
-    per, tile = head_tile(h, p)
+    (g, n), nc = B.shape[2:], t // chunk
+    per, tile = head_tile(h, p, g)
     wide, cols, rows, shared, skip, state, own_shared, lane_sum = _specs(
-        nc, chunk, tile, p, n, True)
+        nc, chunk, tile, p, n, True, _steps_a_group(h, tile, g))
     f32 = jnp.float32
     tiles = h // tile
     operands = _operands(x, dt, cs, B, C, D, tile)
@@ -367,7 +387,9 @@ def _bwd_call(x, dt, cs, B, C, D, states, dy, chunk, interpret):
         compiler_params=_PARAMS, interpret=interpret, name="ssd_bwd",
     )(operands[0], dy.reshape(b, t, h * p), *operands[1:], states.reshape(b, nc, h * p, n))
     untile = lambda cols: cols.transpose(0, 2, 1, 3).reshape(b, t, h)
-    over_tiles = lambda v: v.sum(1)[:, :, None]  # every tile's part of dB and dC adds up
+    # every tile's part of dB and dC adds up, within its group
+    over_tiles = lambda v: v.sum(1)[:, :, None] if g == 1 else (
+        v.reshape(b, g, tiles // g, t, n).sum(2).swapaxes(1, 2))
     return (dx.reshape(b, t, h, p), untile(ddt), untile(dcc) + untile(dcr.swapaxes(2, 3)),
             over_tiles(dB).astype(B.dtype), over_tiles(dC).astype(C.dtype),
             dD.reshape(-1, h, p).sum((0, 2)).astype(D.dtype))
@@ -396,10 +418,11 @@ _ssd.defvjp(_ssd_fwd_rule, _ssd_bwd_rule)
 
 def ssd_path(seq_len: int, heads: int, width: int, groups: int, chunk: int) -> str:
     """"pallas" or "xla" for a scan of these sizes on this process's backend:
-    the kernels where the chunks are whole vectors of lanes, the heads fill
-    slabs of them and B and C are one group's."""
-    fits = (chunk % _LANES == 0 and seq_len % chunk == 0 and groups == 1
-            and width < _LANES and _LANES % width == 0 and heads % (_LANES // width) == 0)
+    the kernels where the chunks are whole vectors of lanes and the heads of
+    each of B's and C's groups fill slabs of them."""
+    fits = (chunk % _LANES == 0 and seq_len % chunk == 0 and heads % groups == 0
+            and width < _LANES and _LANES % width == 0
+            and heads // groups % (_LANES // width) == 0)
     return "pallas" if _on_tpu() and fits else "xla"
 
 

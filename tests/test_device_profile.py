@@ -91,6 +91,20 @@ OP_NAMES = {
     "routed_beside_the_shared_expert": (
         "jit(train_step)/jvp(Kanana)/p_0/h_2/moe/moe.route/router/dot_general",
         "p/h/moe/moe.route/router", "fwd", "moe"),
+    "mixer_only_scan": (
+        "jit(train_step)/jvp(NemotronH)/p_0/h_0/mamba/ssm.scan/ssd_fwd/pallas_call",
+        "p/h/mamba/ssm.scan/ssd_fwd", "fwd", "ssm"),
+    "mixer_only_attention": (
+        "jit(train_step)/jvp(NemotronH)/p_0/h_5/attn/wq/dot_general", "p/h/attn/wq", "fwd",
+        "attn.proj"),
+    "mixer_only_experts_backward": (
+        "jit(train_step)/transpose(jvp(NemotronH))/p_0/jvp(NemotronH)/p_0/checkpoint/h_1/moe/"
+        "moe.experts/tgmm/pallas_call", "p/h/moe/moe.experts/tgmm", "bwd", "moe"),
+    "mixer_only_shared_expert": (
+        "jit(train_step)/jvp(NemotronH)/p_0/h_1/moe.shared/shared/down/dot_general",
+        "p/h/moe.shared/shared/down", "fwd", "moe.shared"),
+    "mixer_only_block_norm": ("jit(train_step)/jvp(NemotronH)/p_0/h_8/norm/rsqrt",
+                              "p/h/norm", "fwd", "norm"),
     "period_norm": ("jit(train_step)/jvp(Granite)/p_0/h_5/mixer_norm/rsqrt",
                     "p/h/mixer_norm", "fwd", "norm"),
     "backward_in_a_period": (
@@ -159,6 +173,10 @@ def _tiny(family):
         from ray_tpu.models.kanana import KananaConfig
 
         return KananaConfig.tiny(num_held=4), True
+    if family == "nemotron_h":
+        from ray_tpu.models.nemotron_h import NemotronHConfig
+
+        return NemotronHConfig.tiny(num_held=4), True
     from ray_tpu.models.granite import GraniteConfig
 
     return GraniteConfig.tiny(), True
@@ -172,7 +190,7 @@ def _compiled_text(cfg, t=128):  # an indexed layer packs its mask 128 keys to a
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_moe", "mellum", "mellum_indexed",
-                                    "granite", "lfm2", "kanana"])
+                                    "granite", "lfm2", "kanana", "nemotron_h"])
 def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     """The tiny configuration's step, compiled here: every scheduled
     instruction has a group of the one vocabulary and a pass, few are
@@ -196,10 +214,17 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     assert {"embed", proj, "attn.core", "norm", "head", "loss", "optimizer"} <= groups
     want = {"gpt2": "mlp", "llama": "mlp", "gpt2_moe": "moe", "mellum": "moe",
             "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv",
-            "kanana": "moe.shared"}[family]
+            "kanana": "moe.shared", "nemotron_h": "moe.shared"}[family]
     assert want in groups
     if family in ("lfm2", "kanana"):  # dense and routed MLPs in one model
         assert {"mlp", "moe"} <= groups
+    if family == "nemotron_h":  # blocks that are a mixer alone: no block has an `mlp` scope
+        assert {"ssm", "moe", "moe.shared"} <= groups and "mlp" not in groups
+        scopes = {r[0] for r in rows.values()}
+        for scope in ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate", "ssm.out_proj",
+                      "moe.route", "moe.experts", "moe.combine", "moe.shared", "attn"):
+            assert any(scope in s.split("/") for s in scopes), scope
+        assert not [n for n in unscoped if rows[n][0].startswith("p")], unscoped  # none a block's
     assert any(r[2] == "matmul" and r[3] == "head" for r in rows.values())
 
 

@@ -49,6 +49,8 @@ WANT = {
     "lfm2_8b_a1b_l5_ep4.t8192": (2, 8192, 32, 64, {"flash_fwd": 1, BWD: 1}),
     # the width is a head's own score part's and its value's; SHARED has the other
     "kanana2_30b_l5_ep8.t8192": (2, 8192, 32, 128, {"flash_mla_fwd": 5, "flash_mla_bwd_fused": 5}),
+    # one attention layer of nine: 2 key-value heads repeated to the 32 query heads
+    "nemotron3_nano_l9_ep16.t8192": (2, 8192, 32, 128, {"flash_fwd": 1, BWD: 1}),
 }
 # the width of the score's second part, whose key all heads share, where a
 # cell's calls are the latent pair
@@ -83,6 +85,10 @@ OLD_KINDS = {
     "lfm2_8b_a1b_l5_ep4.t8192": {
         "flash_fwd": "flash_fwd custom-call -> (bf16[64,8192,64], f32[64,1,8192])",
         BWD: f"{BWD} custom-call -> (bf16[64,8192,64], bf16[64,8192,64], bf16[64,8192,64])"},
+    # no parent ran this cell: its own first trace's (my chip run, PR 47, call 2)
+    "nemotron3_nano_l9_ep16.t8192": {
+        "flash_fwd": "flash_fwd custom-call -> (bf16[64,8192,128], f32[64,1,8192])",
+        BWD: f"{BWD} custom-call -> (bf16[64,8192,128], bf16[64,8192,128], bf16[64,8192,128])"},
 }
 # the shape function that each per-kernel roofline of bench/layer_metrics
 # names for a call it matches

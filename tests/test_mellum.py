@@ -458,7 +458,8 @@ def test_no_pass_of_the_headroom_branch_moves_a_row_for_every_assignment(
 
     def both_passes(idx, weights, x, gates, g):
         plan = moe.route_plan(idx, 0, held)
-        y, vjp = jax.vjp(functools.partial(moe._expert_rows, rows, jnp.bfloat16, plan), weights, x, gates)
+        y, vjp = jax.vjp(functools.partial(moe._expert_rows, moe.SWIGLU, rows, jnp.bfloat16, plan),
+                         weights, x, gates)
         return y, vjp(g)
 
     shape = jax.ShapeDtypeStruct

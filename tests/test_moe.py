@@ -325,21 +325,22 @@ def _parent_s_experts_in_buffer():
 
     from ray_tpu.ops import moe
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-    def experts(rooms, dtype, kept, plan, weights, x, gates):  # `kept`: PR 45's, not read
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+    def experts(form, rooms, dtype, kept, plan, weights, x, gates):  # `kept`: PR 45's, not read
         return jax.lax.cond(moe._fits(plan, rooms[0]),
-                            functools.partial(moe._expert_rows, rooms[0], dtype),
-                            functools.partial(moe._expert_rows, rooms[1], dtype),
+                            functools.partial(moe._expert_rows, form, rooms[0], dtype),
+                            functools.partial(moe._expert_rows, form, rooms[1], dtype),
                             plan, weights, x, gates)
 
-    def fwd(rooms, dtype, kept, plan, weights, x, gates):
-        return experts(rooms, dtype, kept, plan, weights, x, gates), (plan, weights, x, gates)
+    def fwd(form, rooms, dtype, kept, plan, weights, x, gates):
+        return experts(form, rooms, dtype, kept, plan, weights, x, gates), (plan, weights, x, gates)
 
-    def bwd(rooms, dtype, kept, res, g):
+    def bwd(form, rooms, dtype, kept, res, g):
         plan, *operands = res
 
         def back(rows, plan, *operands):
-            return jax.vjp(functools.partial(moe._expert_rows, rows, dtype, plan), *operands)[1](g)
+            return jax.vjp(functools.partial(moe._expert_rows, form, rows, dtype, plan),
+                           *operands)[1](g)
 
         grads = jax.lax.cond(moe._fits(plan, rooms[0]), functools.partial(back, rooms[0]),
                              functools.partial(back, rooms[1]), plan, *operands)
@@ -357,6 +358,7 @@ _KEPT_CASES = [((), True), (("moe_plan",), True), (("moe_plan", "moe_gate", "moe
                ((), False), (("moe_plan",), False)]
 
 
+@pytest.mark.parametrize("form", ["SWIGLU", "RELU2"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("overflows", [False, True], ids=["fits", "overflows"])
 @pytest.mark.parametrize("router,hand_up", [("softmax", False), ("sigmoid", True)],
@@ -364,7 +366,7 @@ _KEPT_CASES = [((), True), (("moe_plan",), True), (("moe_plan", "moe_gate", "moe
 @pytest.mark.parametrize("kept,products_kept", _KEPT_CASES, ids=[
     ("-".join(k) or "none") + ("" if p else "-told_none_is_kept") for k, p in _KEPT_CASES])
 def test_gradients_are_the_parent_s_bit_for_bit_whatever_remat_keeps(
-        kept, products_kept, router, hand_up, overflows, dtype, monkeypatch):
+        kept, products_kept, router, hand_up, overflows, dtype, form, monkeypatch):
     """`ExpertShare` under `nn.remat` with a policy that keeps these names,
     against the same layer with the parent's `_experts_in_buffer` (whose
     backward runs `jax.vjp` of `_expert_rows`): the output and the gradients
@@ -372,16 +374,19 @@ def test_gradients_are_the_parent_s_bit_for_bit_whatever_remat_keeps(
     with headroom (1,024 tokens, top-2 of 8, two held: room for 1,024 of
     2,048 assignments) and where every token goes to both held experts and
     the step takes the buffer of every assignment; in the form that reads
-    kept products and in the one a layer takes that was told none is kept."""
+    kept products and in the one a layer takes that was told none is kept;
+    for SwiGLU experts and for experts of two matrices under relu squared
+    (which have no gate product: a policy that names one keeps nothing by it)."""
     import flax.linen as nn
 
     from ray_tpu.ops import moe
 
     layer = nn.remat(moe.ExpertShare, policy=jax.checkpoint_policies.save_only_these_names(*kept))(
         24, 40, 8, 2, 2, 2, dtype, router=router, hand_up_choices=hand_up,
-        products_kept=products_kept)
+        products_kept=products_kept, form=getattr(moe, form))
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 512, 24), jnp.float32)
     params = layer.init(jax.random.PRNGKey(4), x)["params"]
+    assert ("gate" in params) == (form == "SWIGLU") and {"up", "down"} <= set(params)
     if overflows:
         x = jnp.abs(x)
         kernel = jnp.zeros_like(params["router"]["kernel"]).at[:, 2].set(2.0).at[:, 3].set(1.0)

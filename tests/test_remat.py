@@ -1,5 +1,5 @@
 """models/remat.py: which residuals a block's remat saves, as a pure function
-of the step's shapes and the chips' bytes_limit. Over seven of the benchmark's
+of the step's shapes and the chips' bytes_limit. Over eight of the benchmark's
 cells and over a limit swept downward: the names are the first rung and
 rungs of the family's own, never fewer than the first rung, never worth
 more as the limit falls; what the rule reckons is held to what the chip's
@@ -28,6 +28,7 @@ V5E_LIMIT = 16909336064
 ATTN = ("attn_q", "attn_k", "attn_v")
 MLP = ("mlp_up",)
 GATE_UP, OUT = ("moe_gate", "moe_up"), ("moe_out",)  # ops/moe.py:KEPT_PRODUCTS
+UP_OUT = ("moe_up", "moe_out")  # ops/moe.py:RELU2.products
 CONV, LATENT, SHARED = ("conv_bcu", "conv_y"), ATTN + ("attn_q_shared", "attn_k_shared"), ("shared_up",)
 # cell: configuration, (B, T) of its traffic, and the names the rule takes
 # on a v5e after the first rung (the four routed cells' since PR 45, which
@@ -41,6 +42,9 @@ CELLS = {
     "lfm2_8b_a1b_l5_ep4.t8192": ("lfm2_8b_a1b_l5_ep4", (2, 8192), CONV + MLP + ATTN + GATE_UP + OUT),
     # no product: in this cell they spared nothing (models/kanana.py:REMAT_RUNGS)
     "kanana2_30b_l5_ep8.t8192": ("kanana2_30b_l5_ep8", (2, 8192), LATENT + SHARED + MLP),
+    # experts of two matrices: no gate product; the scan's outputs spared
+    # nothing at chunks of 128 (models/nemotron_h.py:REMAT_RUNGS)
+    "nemotron3_nano_l9_ep16.t8192": ("nemotron3_nano_l9_ep16", (2, 8192), SHARED + ATTN + UP_OUT),
 }
 # (cell, names saved after the first rung): the allocator's peak in GiB of
 # that step on a v5e (my chip runs, PR 33, calls 1-4: PERF.md section 6; one
@@ -70,6 +74,14 @@ READINGS = {
     ("lfm2_8b_a1b_l5_ep4.t8192", CONV + MLP + ATTN): 11.296,
     ("lfm2_8b_a1b_l5_ep4.t8192", CONV + MLP + ATTN + GATE_UP + OUT): 12.020,
     ("kanana2_30b_l5_ep8.t8192", LATENT + SHARED + MLP): 12.899,
+    # my chip runs, PR 47, calls 3 and 5: one process a set of names, forced,
+    # the step alone (the benchmark's own `hbm_peak_gib` in this cell is the
+    # reference comparison's peak, 13.54)
+    ("nemotron3_nano_l9_ep16.t8192", ()): 12.268,
+    ("nemotron3_nano_l9_ep16.t8192", SHARED): 12.316,
+    ("nemotron3_nano_l9_ep16.t8192", ATTN): 12.352,
+    ("nemotron3_nano_l9_ep16.t8192", UP_OUT): 12.254,
+    ("nemotron3_nano_l9_ep16.t8192", SHARED + ATTN + UP_OUT): 12.824,
 }
 # The reckoning against those readings: at most 0.35 GiB under (mistral, the
 # first rung alone) and 0.84 over (gpt2_small: its 16 bytes a parameter and
@@ -190,6 +202,7 @@ UNSEEN = {
     "keye_vl2_30b_l4_ep8.t16384": dict(num_experts=32),
     "lfm2_8b_a1b_l5_ep4.t8192": dict(num_experts=16),
     "kanana2_30b_l5_ep8.t8192": dict(n_routed_experts=32),
+    "nemotron3_nano_l9_ep16.t8192": dict(n_routed_experts=16),
 }
 
 

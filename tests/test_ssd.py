@@ -1,7 +1,9 @@
 """ops/ssd.py: the chunked form and, in interpret mode, the two kernels
 against the recurrence itself, one time step after another: values and the
 gradients of x, dt, A, B, C and D, at a T of several chunks, at two chunk
-sizes, with a head slow enough to carry state across every chunk."""
+sizes, with a head slow enough to carry state across every chunk, at one,
+two and eight groups of B and C (a grid step's heads a whole group, and a
+part of one)."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +16,7 @@ from ray_tpu.ops.ssd import ssd
 B_, T, H, P, N = 2, 64, 8, 32, 16
 
 
-def _inputs(groups=1, seed=0):
+def _inputs(groups=1, seed=0, H=H, P=P):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     x = jax.random.normal(ks[0], (B_, T, H, P), jnp.float32)
     # head 0 decays by exp(-0.002) a step, the last by about exp(-2.5): one
@@ -32,7 +34,7 @@ def step_by_step(x, dt, A, Bm, Cm, D, carry_every=None):
     """S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T, y_t = S_t C_t + D x_t;
     with `carry_every` the state is dropped at every such step (what a
     chunked form that lost its carry would compute)."""
-    g = Bm.shape[2]
+    (H, P), g = x.shape[2:], Bm.shape[2]
     rep = lambda v: jnp.repeat(v, H // g, axis=2)
     Bh, Ch = rep(Bm), rep(Cm)
 
@@ -51,10 +53,17 @@ def _value_and_grads(fn, args, w):
     return jax.value_and_grad(lambda *a: jnp.vdot(fn(*a), w), argnums=range(6))(*args)
 
 
-@pytest.mark.parametrize("path,chunk,groups", [
-    ("chunked", 16, 2), ("chunked", 8, 1), ("kernels", 8, 1), ("kernels", 16, 1)])
-def test_against_the_recurrence(path, chunk, groups):
-    args, w = _inputs(groups)
+# heads and their width beside the groups: 8 of 32 in two groups are a slab
+# a group and a grid step; 16 of 64 in eight are a slab a group likewise (the
+# cell of models/nemotron_h.py in small: a step's heads one group); 20 of 64
+# in two are five slabs a group, which a step takes one at a time, so five
+# steps share a group's columns and their parts of dB and dC add up
+@pytest.mark.parametrize("path,chunk,groups,heads,width", [
+    ("chunked", 16, 2, H, P), ("chunked", 8, 1, H, P), ("kernels", 8, 1, H, P),
+    ("kernels", 16, 1, H, P), ("kernels", 16, 2, H, P), ("kernels", 16, 8, 16, 64),
+    ("kernels", 32, 2, 20, 64), ("chunked", 16, 8, 16, 64)])
+def test_against_the_recurrence(path, chunk, groups, heads, width):
+    args, w = _inputs(groups, H=heads, P=width)
     interpret = True if path == "kernels" else None
     got = lambda *a: ssd(*a, chunk, interpret=interpret)[0]
     with jax.default_matmul_precision("highest"):
@@ -80,9 +89,34 @@ def test_the_comparison_sees_the_carried_state():
     assert float(jnp.abs(states[:, -1, 0]).max()) > 0.1  # still held at the last chunk
 
 
-def test_kernels_equal_the_chunked_form_in_bf16():
+@pytest.mark.parametrize("groups", [2, 8])
+def test_a_head_reads_its_own_group(groups):
+    """What every head reading group 0's B and C would compute is far from
+    what the kernels give, in the output and in every later group's dB and
+    dC (which would be zero: nobody read them)."""
+    args, w = _inputs(groups, H=16, P=64)
+    x, dt, A, Bm, Cm, D = args
+    first = lambda v: jnp.broadcast_to(v[:, :, :1], v.shape)
+    fn = lambda *a: ssd(*a, 16, interpret=True)[0]
+    with jax.default_matmul_precision("highest"):
+        _, grads = _value_and_grads(fn, args, w)
+        y = fn(*args)
+        wrong = step_by_step(x, dt, A, first(Bm), first(Cm), D)
+        right = step_by_step(*args)
+    heads = 16 // groups  # a group's
+    np.testing.assert_allclose(y[:, :, :heads], wrong[:, :, :heads], rtol=2e-4, atol=2e-4)
+    assert float(jnp.abs(y - wrong)[:, :, heads:].max()) > 0.5
+    assert float(jnp.abs(y - right).max()) < 1e-3
+    for d in grads[3:5]:  # dB, dC
+        assert d.shape == Bm.shape
+        per_group = jnp.abs(d).max((0, 1, 3))
+        assert (np.asarray(per_group) > 0.05).all(), per_group
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+def test_kernels_equal_the_chunked_form_in_bf16(groups):
     """Same roundings in both: bf16 operands, float32 sums and decays."""
-    args, w = _inputs()
+    args, w = _inputs(groups, H=16 if groups > 1 else H, P=64 if groups > 1 else P)
     x, dt, A, Bm, Cm, D = args
     args = (x.astype(jnp.bfloat16), dt, A, Bm.astype(jnp.bfloat16), Cm.astype(jnp.bfloat16), D)
     (y, s), (yk, sk) = ssd(*args, 16), ssd(*args, 16, interpret=True)
@@ -121,12 +155,22 @@ def test_path_and_tiles(monkeypatch):
     assert ssd_mod.ssd_path(4096, 64, 64, 1, 256) == "xla"  # no TPU here
     monkeypatch.setattr(ssd_mod, "_on_tpu", lambda: True)
     assert ssd_mod.ssd_path(4096, 64, 64, 1, 256) == "pallas"
-    # what the kernels do not take runs the jax.numpy form: two groups, heads
-    # a vector wide, a chunk that is no whole vector, a ragged sequence
-    for sizes in ((4096, 64, 64, 2, 256), (4096, 64, 128, 1, 256), (4096, 64, 64, 1, 64),
-                  (4000, 64, 64, 1, 256), (4096, 3, 64, 1, 256)):
+    # groups whose heads are whole slabs: two of 32 heads, eight of 8 at a chunk of 128
+    assert ssd_mod.ssd_path(4096, 64, 64, 2, 256) == "pallas"
+    assert ssd_mod.ssd_path(8192, 64, 64, 8, 128) == "pallas"
+    # what the kernels do not take runs the jax.numpy form: a group of one
+    # head (half a slab), groups that do not divide the heads, heads a vector
+    # wide, a chunk that is no whole vector, a ragged sequence
+    for sizes in ((4096, 64, 64, 64, 256), (4096, 64, 64, 3, 256), (4096, 64, 128, 1, 256),
+                  (4096, 64, 64, 1, 64), (4000, 64, 64, 1, 256), (4096, 3, 64, 1, 256)):
         assert ssd_mod.ssd_path(*sizes) == "xla", sizes
     assert ssd_mod.head_tile(64, 64) == (2, 8)
+    # a grid step's heads lie in one group: all eight of a group of eight,
+    # four slabs of a group of 32, one slab of a group of five slabs
+    assert ssd_mod.head_tile(64, 64, 8) == (2, 8) and ssd_mod.head_tile(64, 64, 2) == (2, 8)
+    assert ssd_mod.head_tile(20, 64, 2) == (2, 2) and ssd_mod.head_tile(64, 64, 16) == (2, 4)
+    with pytest.raises(ValueError, match="do not fill slabs"):
+        ssd_mod.head_tile(64, 64, 64)
     assert ssd_mod.head_tile(8, 32) == (4, 8) and ssd_mod.head_tile(6, 64) == (2, 6)
     with pytest.raises(ValueError, match="do not fill slabs"):
         ssd_mod.head_tile(4, 128)
