@@ -331,6 +331,85 @@ def _check_gated_conv_vs_plain(seed, on_tpu):
             "rows_a_tile": short_conv._tile(t), "conv_path": short_conv.conv_path(d, k)}
 
 
+def _check_causal_conv_vs_plain(seed, on_tpu):
+    """ops/short_conv.py's Mamba pair against the plain lines the mixer ran
+    before it (the splits and `causal_conv_plain`) at the two cells' shapes,
+    x where the cells have it (after 4,096 lanes of z, before 64 of dt) and y
+    cut where they cut it (x | B | C), 4 taps, same seed: y and the gradients
+    of x, the taps and the bias as max-abs error over the reference's max-abs
+    value, and the neighbours' gradients, which pass through untouched; the
+    same over the rows at the edges of the runs of rows alone (the first k-1
+    of every run, which read the rows handed over, and the last k-1 before
+    it, whose gradient reads them), where a lost hand-over would show and the
+    whole array's maximum could hide it; and the ms of a forward call and of
+    a forward and backward pair, each form."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import short_conv
+
+    k, out = 4, []
+    for b, t, at, c, more, cuts in (
+            ((1, 4096, 4096, 4352, 64, (4096, 4224)), (2, 8192, 4096, 6144, 64, (4096, 5120)))
+            if on_tpu else ((2, 40, 256, 256, 64, (128,)),)):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        wide = jax.random.normal(ks[0], (b, t, at + c + more), jnp.bfloat16)
+        w = jax.random.uniform(ks[1], (k, c), jnp.float32, -0.5, 0.5)
+        bias = 0.3 * jax.random.normal(ks[2], (c,), jnp.float32)
+        d_out = jax.random.normal(ks[3], wide.shape, jnp.float32)
+        cut = short_conv._cut(t, c, cuts)
+        at_edge = np.flatnonzero((np.arange(t) % cut.rows < k - 1)
+                                 | (np.arange(t) % cut.rows >= cut.rows - k + 1))
+
+        def run(form):
+            def loss(wide, w, bias):
+                outs = jnp.concatenate(form(wide, w, bias), axis=-1)
+                return (outs.astype(jnp.float32) * d_out).sum(), outs
+
+            fwd = jax.jit(form)
+            both = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+            grads, outs = both(wide, w, bias)
+            ms = {}
+            for name, fn in (("fwd", fwd), ("fwd_and_bwd", both)):
+                jax.block_until_ready(fn(wide, w, bias))
+                t0 = time.perf_counter()
+                jax.block_until_ready([fn(wide, w, bias) for _ in range(10)])
+                ms[name] = 1e2 * (time.perf_counter() - t0)
+            return (outs, *grads), ms
+
+        def plain_lines(wide, w, bias):
+            left, x, right = jnp.split(wide, [at, at + c], axis=-1)
+            y = short_conv.causal_conv_plain(x, w, bias)
+            return (left, *jnp.split(y, cuts, axis=-1), right)
+
+        kernels, kernels_ms = run(lambda wide, w, bias: short_conv.causal_conv_within(
+            wide, w, bias, at, cuts, interpret=None if on_tpu else True))
+        plain, plain_ms = run(plain_lines)
+        errs, edge_errs = {}, {}
+        for name, got, want in zip(("y", "dx", "dw", "dbias"), kernels, plain):
+            got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+            if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+                raise RuntimeError(f"causal conv {name}: bad shape or non-finite values")
+            if got.ndim == 3:
+                beside = np.r_[0:at, at + c:at + c + more]
+                if not bool((got[..., beside] == want[..., beside]).all()):
+                    raise RuntimeError(f"causal conv {name}: the lanes beside x are not the plain "
+                                       f"form's at {wide.shape}")
+                got, want = got[..., at:at + c], want[..., at:at + c]
+                edge_errs[name] = float(jnp.abs(got - want)[:, at_edge].max()
+                                        / jnp.abs(want)[:, at_edge].max())
+            errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        if max(*errs.values(), *edge_errs.values()) > ATTN_REL_TOL:
+            raise RuntimeError(f"causal conv kernels vs the plain lines at {wide.shape} beyond "
+                               f"{ATTN_REL_TOL}: {errs}, at the edges {edge_errs}")
+        out.append({"shape": list(wide.shape), "x_at": [at, at + c], "y_cut_at": list(cuts),
+                    "taps": k, "rel_err": errs, "rel_err_at_edges": edge_errs,
+                    "cut": cut._asdict(), "kernels_ms": kernels_ms, "plain_ms": plain_ms,
+                    "conv_path": short_conv.conv_path(c, k, short_conv._EDGE)})
+    return out
+
+
 def _check_flash_mla_vs_plain(seed, on_tpu):
     """ops/attention.py's latent pair against the plain form in float32 at
     the benchmark's head widths (32 heads, scores 128 + 64 deep, values 128)
@@ -492,7 +571,9 @@ def _flash_calls_by_cell(on_tpu):
     the scan's outputs (`ssm_y`) and twice where it does not; a layer that is
     a gated short convolution (`conv`) has gated_conv_bwd once, and
     gated_conv_fwd once where the plan saves its output (`conv_y`), else
-    twice; a layer that is an expert layer alone (`experts`) has none."""
+    twice; a layer that is an expert layer alone (`experts`) has none. A
+    `mamba` layer's convolution (ops/short_conv.py) has causal_conv_bwd once
+    and causal_conv_fwd twice: no plan names its output."""
     import collections
 
     import jax
@@ -527,7 +608,9 @@ def _flash_calls_by_cell(on_tpu):
         if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans - convs - mixers_alone
                            and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans
                            and found["gated_conv_fwd"] == conv_fwd
-                           and found["gated_conv_bwd"] == convs):
+                           and found["gated_conv_bwd"] == convs
+                           and found["causal_conv_fwd"] == 2 * scans
+                           and found["causal_conv_bwd"] == scans):
             raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans and "
                                f"{convs} convolutions, calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:
@@ -574,6 +657,7 @@ def one_chip_loop(config):
     report["ssd_vs_chunked_at_eight_groups"] = _check_ssd_vs_chunked(config["seed"], on_tpu, 8)
     report["relu2_experts_vs_plain"] = _check_relu2_experts_vs_plain(config["seed"], on_tpu)
     report["gated_conv_vs_plain"] = _check_gated_conv_vs_plain(config["seed"], on_tpu)
+    report["causal_conv_vs_plain"] = _check_causal_conv_vs_plain(config["seed"], on_tpu)
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()

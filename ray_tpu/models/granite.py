@@ -31,8 +31,11 @@ published sizes, a power of two and so exact in bf16.
     out = W_out y                      H P -> d, no bias
 
 The recurrence is ops/ssd.py (its chunked form at `ssm_chunk`; pallas
-kernels ssd_fwd and ssd_bwd on a TPU); the convolution, the gate and the
-norm are XLA's. Departures from the published code, all under `assumed` in
+kernels ssd_fwd and ssd_bwd on a TPU); the convolution under its bias and
+silu is ops/short_conv.py's (pallas kernels causal_conv_fwd and
+causal_conv_bwd on a TPU, reading xBC where W_in wrote it; the same lines
+in jax.numpy elsewhere); the gate and the norm are XLA's. Departures from
+the published code, all under `assumed` in
 bench/configs/granite4_h_micro_l10.json: the convolution's kernel is stored
 (K, channels) and not (channels, 1, K); no clamp on Delta (the family's
 `time_step_limit` is (0, inf)); Mamba-2's own initialisation of A_log,
@@ -57,6 +60,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.models import Family, remat
 from ray_tpu.models.llama import (  # noqa: F401
     LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm)
+from ray_tpu.ops.short_conv import causal_conv_within
 from ray_tpu.ops.ssd import ssd
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
@@ -192,14 +196,11 @@ class Mamba2Mixer(nn.Module):
         dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
         with jax.named_scope("ssm.in_proj"):
             zxbcdt = dense(inner + conv_dim + h, "in_proj")(u)
-            z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
         with jax.named_scope("ssm.conv"):
             w = self.param("conv_kernel", _conv_init, (k, conv_dim), jnp.float32)
             bias = self.param("conv_bias", nn.initializers.zeros, (conv_dim,), jnp.float32)
-            padded = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-            conv = bias + sum(padded[:, i:i + t] * w[i] for i in range(k))
-            xbc = nn.silu(conv).astype(cfg.dtype)
-            x, bm, cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+            z, x, bm, cm, dt = causal_conv_within(zxbcdt, w, bias, inner,
+                                                   (inner, inner + g * n))
         with jax.named_scope("ssm.scan"):
             dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
             a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)
@@ -255,10 +256,12 @@ class GraniteBlock(nn.Module):
 # the scan's output and chunk states spare ssd_fwd's second run (7.9 ms for
 # 0.56 GiB), the MLP's gate and up those two matmuls' (14.0 ms for 1.25 GiB).
 # The input projection's and the convolution's outputs were tried as a third
-# rung and are not named: 3.2 ms for 0.88 GiB alone, and 0.9 ms *slower*
-# beside the scan's. At the cell's shape the rule's bookkeeping (16 bytes a
-# parameter: 11.5 of the 13.5 GiB) has room for one of the two and takes
-# the MLP's.
+# rung in PR 36, when the convolution was XLA's padded, shifted float32
+# slices, and are not named: 3.2 ms for 0.88 GiB alone, and 0.9 ms *slower*
+# beside the scan's; the convolution's second run is a kernel's 0.11 ms a
+# layer since PR 48, less still to spare. At the cell's shape the rule's
+# bookkeeping (16 bytes a parameter: 11.5 of the 13.5 GiB) has room for one
+# of the two and takes the MLP's.
 REMAT_RUNGS = ((("ssm_y", "ssm_states"), 14.1), (("mlp_up",), 11.2))
 
 

@@ -12,8 +12,9 @@ The equations, with d the hidden size and x the residual stream:
     loss   = mean cross-entropy of the next token             float32
 
 `M` (models/granite.py's `Mamba2Mixer`; the recurrence is ops/ssd.py, pallas
-kernels ssd_fwd and ssd_bwd on a TPU), H heads of P, G groups of B and C,
-state N, inner width H P (not `expand` x d):
+kernels ssd_fwd and ssd_bwd on a TPU, the convolution ops/short_conv.py,
+causal_conv_fwd and causal_conv_bwd there), H heads of P, G groups of B and
+C, state N, inner width H P (not `expand` x d):
 
     [z | xBC | dt] = W_in u          d -> H P + (H P + 2 G N) + H, no bias
     xBC <- silu(conv(xBC))           depthwise, causal, K taps, with bias

@@ -39,6 +39,37 @@ def test_scan_kernels_compile_at_eight_groups_and_the_cell_s_shape(one_chip):
     assert states < c.memory_analysis().temp_size_in_bytes < 4 * states
 
 
+def test_convolution_kernels_compile_at_the_cell_s_shape(one_chip):
+    """nemotron3_nano_l9_ep16.t8192's Mamba layers' convolution: 6144 channels
+    over (2, 8192) tokens and 4 taps with a bias under
+    silu, read where the input projection wrote them (after 4,096 lanes of z,
+    before 64 of dt) and written as x, B and C, forward and backward, each a
+    pallas call under its name;
+    the backward writes x's gradient into the buffer that holds its
+    neighbours' (no copy of it beside the call), and nothing is left for the
+    backward but the operands."""
+    from ray_tpu.ops import short_conv
+
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    wide = shape((2, 8192, 4096 + 6144 + 64), jnp.bfloat16)
+    taps, bias = shape((4, 6144), jnp.float32), shape((6144,), jnp.float32)
+    # a block is a tile's rows of all of x, and the calls cut y themselves
+    assert short_conv._cut(8192, 6144, (4096, 5120)) == (
+        256, 6144, 64, (4096, 5120))
+
+    def loss(wide, taps, bias):
+        outs = short_conv.causal_conv_within(wide, taps, bias, 4096, (4096, 5120), interpret=False)
+        # kept: the forward call is not dead code
+        return sum(v.astype(jnp.float32).sum() for v in outs), outs
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(wide, taps, bias).compile()
+    text = c.as_text()
+    names = _CUSTOM_CALL.findall(text)
+    assert len(names) == 2 and sum("causal_conv_fwd" in n for n in names) == 1 \
+        and sum("causal_conv_bwd" in n for n in names) == 1, names
+    # the gradient's buffer is the call's own result: XLA put no copy before it
+    assert "causal_conv_bwd" in text and "output_to_operand_aliasing" in text
+
 @pytest.mark.parametrize("products_kept", [True, False], ids=["products_kept", "none_kept"])
 def test_experts_of_two_matrices_compile_at_the_cell_s_size(one_chip, monkeypatch, products_kept):
     """8 held experts of 128, top-6, two matrices 1,856 wide (14.5 vectors of
@@ -72,14 +103,14 @@ def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     v5e: the program holds less than the 13.5 GiB the rule is held to and
     within the error the reckoning has shown of what it reckoned
     (tests/test_remat.py: 0.35 GiB under to 0.85 over), the four Mamba layers
-    run the scan's kernels (no einsum form of it), the attention layer the
-    plain causal pair, the expert layers megablox's, and the bias's update is
+    run the scan's kernels (no einsum form of it) and the convolution's, the
+    attention layer the plain causal pair, the expert layers megablox's, and the bias's update is
     part of the one program."""
     from ray_tpu.models import remat
-    from ray_tpu.ops import ssd
+    from ray_tpu.ops import short_conv, ssd
 
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    for mod in (attention, ssd, short_conv):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
     cfg = cell_config("nemotron3_nano_l9_ep16")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
@@ -92,6 +123,7 @@ def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     kinds = _kinds(c.as_text())
     scans = {k: n for k, n in kinds.items() if "ssd_" in k}
     assert sorted(scans.values()) == [4, 4 if "ssm_y" in plan.names else 8], kinds
+    assert (kinds["causal_conv_fwd"], kinds["causal_conv_bwd"]) == (8, 4), kinds
     flash = {k: n for k, n in kinds.items() if "flash" in k}
     assert sorted(flash.values()) == [1, 1] and not [k for k in flash if "mla" in k or "win" in k]
     assert kinds["gmm"] and kinds["tgmm"] and kinds["moe_token_sum"]
