@@ -410,6 +410,60 @@ def _check_causal_conv_vs_plain(seed, on_tpu):
     return out
 
 
+def _check_gated_norm_vs_plain(seed, on_tpu):
+    """ops/gated_norm.py's pair against the lines the mixer ran before it
+    (`gated_norm_plain`: the gate in bf16, the norm over a (..., 8, 512) view)
+    at nemotron3_nano_l9_ep16.t8192's shape, y (2, 8192, 4096) in 8 groups and
+    z where the cell has it (the first 4,096 lanes of [z | xBC | dt]), same
+    seed, the first group's values a thousand times the last's: the output
+    and the gradients of y, z and the weight as max-abs error over the
+    reference's max-abs value, the first and the last group each on its own
+    (a sum that leaked across a group's edge would show in the small one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import gated_norm
+
+    b, t, c, more, groups = (2, 8192, 4096, 6208, 8) if on_tpu else (2, 40, 256, 192, 2)
+    eps, width = 1e-5, c // groups
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    scale = jnp.repeat(jnp.logspace(1.5, -1.5, groups), width)
+    y = (jax.random.normal(ks[0], (b, t, c)) * scale).astype(jnp.bfloat16)
+    wide = jax.random.normal(ks[1], (b, t, c + more), jnp.bfloat16)
+    weight = 1 + 0.1 * jax.random.normal(ks[2], (c,), jnp.float32)
+    d_out = jax.random.normal(ks[3], (b, t, c), jnp.float32)
+
+    def run(form):
+        def loss(y, wide, weight):
+            out = form(y, wide[..., :c], weight, wide)
+            return (out.astype(jnp.float32) * d_out).sum(), out
+
+        grads, out = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))(y, wide, weight)
+        return (out, *grads)
+
+    kernels = run(lambda y, z, weight, wide: gated_norm.gated_norm(
+        y, z, weight, eps, groups, within=(wide, 0), interpret=None if on_tpu else True))
+    plain = run(lambda y, z, weight, wide: gated_norm.gated_norm_plain(
+        y, z, weight, eps, groups))
+    errs = {}
+    for name, got, want in zip(("out", "dy", "dz", "dweight"), kernels, plain):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"gated norm {name}: bad shape or non-finite values")
+        if name == "dz":
+            if bool(got[..., c:].any()):
+                raise RuntimeError("gated norm: a gradient in the lanes beside z")
+            got, want = got[..., :c], want[..., :c]
+        for part, at in (("first", slice(0, width)), ("last", slice(c - width, c))):
+            errs[f"{name}_{part}_group"] = float(
+                jnp.abs(got[..., at] - want[..., at]).max() / jnp.abs(want[..., at]).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"gated norm kernels vs the plain lines at {y.shape} beyond "
+                           f"{ATTN_REL_TOL}: {errs}")
+    return {"shape": list(y.shape), "groups": groups, "z_within": list(wide.shape),
+            "rel_err": errs, "norm_path": gated_norm.norm_path(width)}
+
+
 def _check_flash_mla_vs_plain(seed, on_tpu):
     """ops/attention.py's latent pair against the plain form in float32 at
     the benchmark's head widths (32 heads, scores 128 + 64 deep, values 128)
@@ -573,7 +627,9 @@ def _flash_calls_by_cell(on_tpu):
     gated_conv_fwd once where the plan saves its output (`conv_y`), else
     twice; a layer that is an expert layer alone (`experts`) has none. A
     `mamba` layer's convolution (ops/short_conv.py) has causal_conv_bwd once
-    and causal_conv_fwd twice: no plan names its output."""
+    and causal_conv_fwd twice: no plan names its output. A `mamba` layer of
+    more than one group norms its gated output a group at a time
+    (ops/gated_norm.py): gated_norm_bwd once and gated_norm_fwd twice."""
     import collections
 
     import jax
@@ -605,12 +661,15 @@ def _flash_calls_by_cell(on_tpu):
         saved = remat.traced(cfg).names
         scan_fwd = scans * (1 if "ssm_y" in saved else 2)
         conv_fwd = convs * (1 if "conv_y" in saved else 2)
+        by_group = scans if getattr(cfg, "ssm_groups", 1) > 1 else 0
         if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans - convs - mixers_alone
                            and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans
                            and found["gated_conv_fwd"] == conv_fwd
                            and found["gated_conv_bwd"] == convs
                            and found["causal_conv_fwd"] == 2 * scans
-                           and found["causal_conv_bwd"] == scans):
+                           and found["causal_conv_bwd"] == scans
+                           and found["gated_norm_fwd"] == 2 * by_group
+                           and found["gated_norm_bwd"] == by_group):
             raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans and "
                                f"{convs} convolutions, calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:
@@ -658,6 +717,7 @@ def one_chip_loop(config):
     report["relu2_experts_vs_plain"] = _check_relu2_experts_vs_plain(config["seed"], on_tpu)
     report["gated_conv_vs_plain"] = _check_gated_conv_vs_plain(config["seed"], on_tpu)
     report["causal_conv_vs_plain"] = _check_causal_conv_vs_plain(config["seed"], on_tpu)
+    report["gated_norm_vs_plain"] = _check_gated_norm_vs_plain(config["seed"], on_tpu)
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()

@@ -34,8 +34,11 @@ The recurrence is ops/ssd.py (its chunked form at `ssm_chunk`; pallas
 kernels ssd_fwd and ssd_bwd on a TPU); the convolution under its bias and
 silu is ops/short_conv.py's (pallas kernels causal_conv_fwd and
 causal_conv_bwd on a TPU, reading xBC where W_in wrote it; the same lines
-in jax.numpy elsewhere); the gate and the norm are XLA's. Departures from
-the published code, all under `assumed` in
+in jax.numpy elsewhere); the gate and the norm over all channels at once,
+this family's, are XLA's, which fuses them into their neighbours (a mixer
+told to norm by group, models/nemotron_h.py's, runs ops/gated_norm.py's
+pair on a TPU: a pass of its own that reads each group where it lies).
+Departures from the published code, all under `assumed` in
 bench/configs/granite4_h_micro_l10.json: the convolution's kernel is stored
 (K, channels) and not (channels, 1, K); no clamp on Delta (the family's
 `time_step_limit` is (0, inf)); Mamba-2's own initialisation of A_log,
@@ -181,7 +184,9 @@ class Mamba2Mixer(nn.Module):
     GraniteConfig or any config with its `ssm_*` fields, `n_embd`, `rms_eps`
     and `dtype`; `norm_groups`: the gated norm is taken over each of this
     many equal parts of the H P channels on its own (models/nemotron_h.py: a
-    part for each group of B and C), with one weight over all."""
+    part for each group of B and C), with one weight over all; more than one
+    makes gate and norm one call of ops/gated_norm.py, which reads z where
+    the input projection wrote it."""
 
     config: Any
     norm_groups: int = 1
@@ -214,8 +219,11 @@ class Mamba2Mixer(nn.Module):
             self.sow("ssm_stats", "state_abs_max",
                      jax.lax.stop_gradient(jnp.abs(states).max()))
         with jax.named_scope("ssm.gate"):
-            y = RMSNorm(cfg.rms_eps, self.norm_groups, name="norm")(
-                y.reshape(b, t, inner) * nn.silu(z))
+            norm = RMSNorm(cfg.rms_eps, self.norm_groups, name="norm")
+            y = y.reshape(b, t, inner)
+            # one group: lines XLA fuses into their neighbours; more: a pass of its own
+            y = (norm(y * nn.silu(z)) if self.norm_groups == 1 else
+                 norm(y, gate=z, within=(zxbcdt, 0)))
         with jax.named_scope("ssm.out_proj"):
             return dense(cfg.n_embd, "out_proj")(y)
 

@@ -121,12 +121,17 @@ class RMSNorm(nn.Module):
     groups: int = 1  # equal parts of the last axis, each normed on its own; one weight over all
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, gate=None, within=None):
+        """The norm of x, or with `gate` of x * silu(gate) (a Mamba mixer's
+        grouped norm: ops/gated_norm.py, which says what `within` is)."""
         w = self.param("weight", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        if self.groups == 1:
+        if self.groups == 1 and gate is None:
             return rms_norm(x, w.astype(x.dtype), self.eps)
-        parts = lambda v: v.reshape(*v.shape[:-1], self.groups, -1)
-        return rms_norm(parts(x), parts(w.astype(x.dtype)), self.eps).reshape(x.shape)
+        from ray_tpu.ops.gated_norm import gated_norm, norm_by_group
+
+        if gate is None:
+            return norm_by_group(x, w, self.eps, self.groups)
+        return gated_norm(x, gate, w, self.eps, self.groups, within)
 
 
 def rope_angles(head_dim: int, theta: float, positions, inv_freq=None):

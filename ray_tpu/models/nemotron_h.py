@@ -13,8 +13,9 @@ The equations, with d the hidden size and x the residual stream:
 
 `M` (models/granite.py's `Mamba2Mixer`; the recurrence is ops/ssd.py, pallas
 kernels ssd_fwd and ssd_bwd on a TPU, the convolution ops/short_conv.py,
-causal_conv_fwd and causal_conv_bwd there), H heads of P, G groups of B and
-C, state N, inner width H P (not `expand` x d):
+causal_conv_fwd and causal_conv_bwd there, the gate and the norm a group at
+a time ops/gated_norm.py, gated_norm_fwd and gated_norm_bwd there), H heads
+of P, G groups of B and C, state N, inner width H P (not `expand` x d):
 
     [z | xBC | dt] = W_in u          d -> H P + (H P + 2 G N) + H, no bias
     xBC <- silu(conv(xBC))           depthwise, causal, K taps, with bias

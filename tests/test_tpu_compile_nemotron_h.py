@@ -1,7 +1,8 @@
 """models/nemotron_h.py's cell compiled for a described TPU v5e, as
 tests/test_tpu_compile.py and with no chip: the scan's two kernels at eight
-groups and `nemotron3_nano_l9_ep16.t8192`'s shape, its expert layer of two
-matrices 1,856 wide through megablox, and the cell's whole step."""
+groups and `nemotron3_nano_l9_ep16.t8192`'s shape, the convolution's and the
+gated norm's pairs there, its expert layer of two matrices 1,856 wide through
+megablox, and the cell's whole step."""
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,35 @@ def test_convolution_kernels_compile_at_the_cell_s_shape(one_chip):
     # the gradient's buffer is the call's own result: XLA put no copy before it
     assert "causal_conv_bwd" in text and "output_to_operand_aliasing" in text
 
+
+def test_gated_norm_kernels_compile_at_the_cell_s_shape(one_chip):
+    """nemotron3_nano_l9_ep16.t8192's Mamba layers' gated norm: 4,096 channels
+    in 8 groups of 512 over (2, 8192) tokens, z read where the input
+    projection wrote it (the first 4,096 of 10,304 lanes), forward and
+    backward, each a pallas call under its name that takes the wide array
+    itself; nothing is left for the backward but the operands, and no array
+    is shaped (..., 8, 512)."""
+    from ray_tpu.ops import gated_norm
+
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    y, wide = shape((2, 8192, 4096), jnp.bfloat16), shape((2, 8192, 10304), jnp.bfloat16)
+    weight = shape((4096,), jnp.float32)
+
+    def loss(y, wide, weight):
+        out = gated_norm.gated_norm(y, wide[..., :4096], weight, 1e-5, 8, within=(wide, 0),
+                                    interpret=False)
+        return out.astype(jnp.float32).sum(), out  # kept: the forward call is not dead code
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(y, wide, weight).compile()
+    text = c.as_text()
+    calls = [line for line in text.splitlines() if _CUSTOM_CALL.match(line)]
+    assert len(calls) == 2 and sum("gated_norm_fwd" in n for n in calls) == 1 \
+        and sum("gated_norm_bwd" in n for n in calls) == 1, calls
+    assert all("operand_layout_constraints={bf16[2,8192,4096]{2,1,0}, bf16[2,8192,10304]{2,1,0}"
+               in line for line in calls), calls
+    assert "8192,8,512" not in text
+
+
 @pytest.mark.parametrize("products_kept", [True, False], ids=["products_kept", "none_kept"])
 def test_experts_of_two_matrices_compile_at_the_cell_s_size(one_chip, monkeypatch, products_kept):
     """8 held experts of 128, top-6, two matrices 1,856 wide (14.5 vectors of
@@ -103,13 +133,14 @@ def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     v5e: the program holds less than the 13.5 GiB the rule is held to and
     within the error the reckoning has shown of what it reckoned
     (tests/test_remat.py: 0.35 GiB under to 0.85 over), the four Mamba layers
-    run the scan's kernels (no einsum form of it) and the convolution's, the
-    attention layer the plain causal pair, the expert layers megablox's, and the bias's update is
+    run the scan's kernels (no einsum form of it), the convolution's and the
+    gated norm's, the attention layer the plain causal pair, the expert layers megablox's, and the bias's update is
     part of the one program."""
     from ray_tpu.models import remat
-    from ray_tpu.ops import short_conv, ssd
+    from ray_tpu.ops import gated_norm, short_conv, ssd
+    from ray_tpu.train._device_profile import scope_table
 
-    for mod in (attention, ssd, short_conv):
+    for mod in (attention, ssd, short_conv, gated_norm):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
     cfg = cell_config("nemotron3_nano_l9_ep16")
@@ -124,6 +155,16 @@ def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     scans = {k: n for k, n in kinds.items() if "ssd_" in k}
     assert sorted(scans.values()) == [4, 4 if "ssm_y" in plan.names else 8], kinds
     assert (kinds["causal_conv_fwd"], kinds["causal_conv_bwd"]) == (8, 4), kinds
+    assert (kinds["gated_norm_fwd"], kinds["gated_norm_bwd"]) == (8, 4), kinds
+    # under the mixer's gate the two kernels stand, and nothing that moves an
+    # array of y's size: the norm's view by group was a float32 copy of it,
+    # forward and backward (PERF.md section 6, PR 49)
+    gate = [(which, cls, kind) for scope, which, cls, _, kind in scope_table(
+        c.as_text())["rows"].values() if "ssm.gate" in scope]
+    assert sorted({(which, kind.split()[0]) for which, cls, kind in gate if cls == "kernel"}) == [
+        ("bwd", "gated_norm_bwd"), ("fwd", "gated_norm_fwd"), ("remat", "gated_norm_fwd")]
+    assert not [row for row in gate if "[2,8192,4096]" in row[2] and (
+        row[1] == "copy" or row[2].split()[0] in ("copy", "reshape", "transpose", "fusion"))], gate
     flash = {k: n for k, n in kinds.items() if "flash" in k}
     assert sorted(flash.values()) == [1, 1] and not [k for k in flash if "mla" in k or "win" in k]
     assert kinds["gmm"] and kinds["tgmm"] and kinds["moe_token_sum"]
