@@ -52,13 +52,17 @@ LOSS_REL_TOL = 2e-2   # |loss_mesh - loss_one_device| / loss_one_device, per ste
 TELEMETRY_REL_TOL = 2e-2  # the program's step seconds, tokens/s and MFU against the blocked steps
 GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
 
-# attn_shapes: the flash check runs at both head widths the benchmark's cells use
+# attn_shapes: the flash check runs at both head widths the benchmark's cells
+# use; windowed_shapes: and under the two cells' windows at their window
+# layers' shape, (B, T, H, D) and the window
 FULL = {"model": None, "B": 16, "T": 1024,
-        "attn_shapes": [(16, 1024, 12, 64), (1, 2048, 8, 128)]}
+        "attn_shapes": [(16, 1024, 12, 64), (1, 2048, 8, 128)],
+        "windowed_shapes": [((2, 8192, 32, 128), 1024), ((2, 8192, 32, 128), 2048)]}
 TINY = {
     "model": {"vocab_size": 257, "block_size": 256, "n_layer": 2, "n_head": 4,
               "n_embd": 64},
     "B": 4, "T": 256, "attn_shapes": [(2, 256, 2, 64), (1, 256, 2, 128)],
+    "windowed_shapes": [((1, 512, 2, 128), 100), ((1, 512, 2, 128), 256)],
 }
 
 
@@ -149,10 +153,13 @@ def _check_losses(losses):
         raise RuntimeError(f"loss did not fall: {losses}")
 
 
-def _check_flash_vs_xla(shape, seed, on_tpu):
+def _check_flash_vs_xla(shape, seed, on_tpu, window=None):
     """flash_causal_attention against xla_causal_attention at one (B, T, H, D),
-    same seed: the output and the three gradients, as max-abs error over the
-    reference's max-abs value, beside the tiles the kernel chose."""
+    same seed, under `window` where given: the output and the three
+    gradients, as max-abs error over the reference's max-abs value, beside
+    the tiles the kernel chose. The reference holds a head's (T, T) scores
+    in float32, so it goes a few heads of a batch row at a time where all
+    at once would pass 1 GiB."""
     import jax
     import jax.numpy as jnp
 
@@ -164,16 +171,26 @@ def _check_flash_vs_xla(shape, seed, on_tpu):
     q, k, v, w = (jax.random.normal(key, shape, jnp.bfloat16)
                   for key in (kq, kk, kv, kw))
 
-    def run(attn):
-        def loss(q, k, v):
-            return (attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32)).sum()
+    def both(attn):
+        """attn's output and three gradients under the loss sum(o * w), one
+        compiled function for every call at one shape."""
+        def run(q, k, v, w):
+            def loss(q, k, v):
+                return (attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32)).sum()
 
-        return jax.jit(
-            lambda q, k, v: (attn(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
-        )(q, k, v)
+            return (attn(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
 
-    flash = run(functools.partial(flash_causal_attention, interpret=not on_tpu))
-    ref = run(xla_causal_attention)
+        return jax.jit(run)
+
+    flash = both(functools.partial(flash_causal_attention, window=window, interpret=not on_tpu))(
+        q, k, v, w)
+    b, t, h, _ = shape
+    at_once = max(1, min(h, (1 << 30) // (4 * t * t)))
+    reference = both(functools.partial(xla_causal_attention, window=window))
+    parts = [[reference(*(x[row:row + 1, :, first:first + at_once] for x in (q, k, v, w)))
+              for first in range(0, h, at_once)] for row in range(b)]
+    ref = [jnp.concatenate([jnp.concatenate([part[n] for part in row], axis=2) for row in parts])
+           for n in range(4)]
     errs = {}
     for name, a, b in zip(("out", "dq", "dk", "dv"), flash, ref):
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
@@ -181,10 +198,9 @@ def _check_flash_vs_xla(shape, seed, on_tpu):
             raise RuntimeError(f"flash {name}: bad shape or non-finite values")
         errs[name] = float(jnp.abs(a - b).max() / jnp.abs(b).max())
     if max(errs.values()) > ATTN_REL_TOL:
-        raise RuntimeError(f"flash vs xla at {shape} beyond {ATTN_REL_TOL}: {errs}")
-    _, t, h, d = shape
-    return {"shape": list(shape), "rel_err": errs,
-            "tiles": flash_tiles(h, t, d, jnp.bfloat16)._asdict()}
+        raise RuntimeError(f"flash vs xla at {shape}, window {window}, beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": list(shape), "window": window, "rel_err": errs,
+            "tiles": flash_tiles(h, t, shape[3], jnp.bfloat16, window)._asdict()}
 
 
 def _check_ssd_vs_chunked(seed, on_tpu, groups=1):
@@ -570,18 +586,21 @@ def _check_gated_attention(seed, on_tpu):
 
 
 def _windowed_flash_plan():
-    """The tiles of the windowed flash call of the benchmark's window layers,
-    (2 x 32, 8192, 128) under a window of 1,024, beside the causal call's at
-    that shape, and the path `causal_attention` takes there."""
+    """The tiles of the windowed flash calls of the benchmark's window layers,
+    (2 x 32, 8192, 128) under windows of 1,024 and of 2,048, the sub-tiles
+    their masked tiles are cut into and the scores a query can see over
+    those a head's call computes (`flash_scores`), beside the causal call's
+    at that shape, and the path `causal_attention` takes there."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import attention_path, flash_tiles
 
-    h, t, d, window = 32, 8192, 128, 1024
-    return {"shape": [2 * h, t, d], "window": window,
-            "tiles": flash_tiles(h, t, d, jnp.bfloat16, window)._asdict(),
-            "causal_tiles": flash_tiles(h, t, d, jnp.bfloat16)._asdict(),
-            "attention_path": attention_path(t)}
+    h, t, d = 32, 8192, 128
+    plan = {"shape": [2 * h, t, d], "attention_path": attention_path(t)}
+    for name, window in (("causal", None), ("window_1024", 1024), ("window_2048", 2048)):
+        tiles = flash_tiles(h, t, d, jnp.bfloat16, window)
+        plan[name] = {"tiles": tiles._asdict(), **_needed_shares(tiles, t)}
+    return plan
 
 
 def _selected_flash_plan():
@@ -696,6 +715,7 @@ def _flash_calls_by_cell(on_tpu):
     more than one group norms its gated output a group at a time
     (ops/gated_norm.py): gated_norm_bwd once and gated_norm_fwd twice."""
     import collections
+    from unittest import mock
 
     import jax
     import jax.numpy as jnp
@@ -705,13 +725,16 @@ def _flash_calls_by_cell(on_tpu):
     from ray_tpu.parallel.mesh import kernel_tally, make_mesh
     from ray_tpu.parallel.train_step import TrainStep
 
-    calls = {}
+    calls, shares = {}, {}
     for name, cfg, shape in _cells():
         ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
         state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
         tok = jax.ShapeDtypeStruct((shape.rows, shape.seq_len), jnp.int32)
-        text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
-            lowering_platforms=("tpu",)).as_text()
+        # the tiles of each kind of call, as the step's trace asks the rule for them
+        with mock.patch.object(attention, "flash_tiles", wraps=attention.flash_tiles) as rule:
+            text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
+                lowering_platforms=("tpu",)).as_text()
+        shares[name] = _scores_needed_share(rule.call_args_list)
         found = kernel_tally(text)
         calls[name] = dict(sorted(found.items()))
         fwd = sum(n for k, n in found.items() if k.startswith("flash_") and k.endswith("_fwd"))
@@ -739,7 +762,40 @@ def _flash_calls_by_cell(on_tpu):
                                f"{convs} convolutions, calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:
             raise RuntimeError(f"{name}: a backward call for one gradient alone: {calls[name]}")
-    return calls
+    return calls, shares
+
+
+def _scores_needed_share(asked):
+    """Of the calls to `flash_tiles` that a step's trace made, by kind of
+    flash call as its name says it: the scores a query can see over the
+    scores the call computes (`attention.flash_scores`), a number of the
+    call's shapes; 1 is a call that computes no score it masks."""
+    import inspect
+
+    from ray_tpu.ops import attention
+
+    shares = {}
+    for call in asked:
+        args = inspect.signature(attention.flash_tiles).bind(*call.args, **call.kwargs).arguments
+        tiles = attention.flash_tiles(*call.args, **call.kwargs)
+        kind = ("flash_mla" if args.get("shared") else
+                f"flash_sel{tiles.select}" if tiles.select else
+                f"flash_win{tiles.window}" if tiles.window else "flash")
+        shares[kind] = {"tile": [tiles.block_q, tiles.block_k],
+                        "sub_tile": [tiles.sub_fwd, tiles.sub_bwd], **_needed_shares(tiles, args["t"])}
+    return shares
+
+
+def _needed_shares(tiles, t):
+    """The scores a query can see over the scores a head's forward and
+    backward call with `tiles` compute (`attention.flash_scores`)."""
+    from ray_tpu.ops.attention import flash_scores
+
+    shares = {}
+    for call, backward in (("forward", False), ("backward", True)):
+        computed, needed = flash_scores(tiles, t, backward)
+        shares[call] = needed / computed
+    return {"scores_needed": needed, "needed_share": shares}
 
 
 def one_chip_loop(config):
@@ -776,7 +832,9 @@ def one_chip_loop(config):
     del state
     report["flash_vs_xla"] = [
         _check_flash_vs_xla(shape, config["seed"], on_tpu)
-        for shape in config["attn_shapes"]]
+        for shape in config["attn_shapes"]] + [
+        _check_flash_vs_xla(shape, config["seed"], on_tpu, window)
+        for shape, window in config["windowed_shapes"]]
     report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
     report["ssd_vs_chunked_at_eight_groups"] = _check_ssd_vs_chunked(config["seed"], on_tpu, 8)
     report["relu2_experts_vs_plain"] = _check_relu2_experts_vs_plain(config["seed"], on_tpu)
@@ -789,7 +847,7 @@ def one_chip_loop(config):
     report["selected_flash"] = _selected_flash_plan()
     report["index_select_vs_top_k"] = _check_selection(config["seed"], on_tpu)
     report["remat_plans"] = _remat_plans()
-    report["flash_calls_by_cell"] = _flash_calls_by_cell(on_tpu)
+    report["flash_calls_by_cell"], report["flash_scores_needed_share"] = _flash_calls_by_cell(on_tpu)
     report["compile_cache_entries_after"] = _cache_entries(report["compile_cache_dir"])
     train.report(report)
 

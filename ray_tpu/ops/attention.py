@@ -71,12 +71,29 @@ the mask, and the shared key's gradient is summed over heads and tiles in
 float32 and cast once, outside the call.
 
 Tiles: `flash_tiles(h, t, d, dtype, window)` chooses the tile a grid step
-owns, the tile it loops over and the heads it takes at once from the call's
-shape, and reckons the VMEM the call needs. The causal mask is built only on
-tiles the diagonal crosses; tiles wholly above it are never visited. With a
+owns, the tile it loops over, the heads it takes at once and the sub-tile
+from the call's shape, and reckons the VMEM the call needs. Tiles wholly
+above the diagonal are never visited; those it crosses are masked. With a
 window (a query sees its last `window` keys, itself included) the same holds
 at the other edge: tiles wholly behind the window are never visited, those
-its trailing edge crosses are masked, those between are plain.
+its trailing edge crosses are masked, those between are plain. A masked tile
+is cut into sub-tiles (`FlashTiles.sub_fwd`, `.sub_bwd`: 128 x 128) and goes
+strip by strip of its rows (queries in the forward, keys in the backward): a strip's rows
+against the columns of the sub-tiles in which a query sees a key, side by
+side in one matmul, so the matmuls, the exp and, in the forward, one update
+of a row's running max and sum cover those and no sub-tile that is wholly
+hidden (`_strips`; which they are is static: a masked tile's distance from
+the diagonal is, and only whether the sequence holds an edge tile is not).
+The mask itself is built on the sub-tiles an edge runs through and on no
+other. The hidden entries came to exp(NEG_INF - m) = 0 exactly, so the
+mathematics is the same; a row's rescale may fall at another moment. Without
+the cut a 1,024 tile on the diagonal computed twice the scores it needs and a
+windowed call 1.5 times (`flash_scores` counts both for a call's tiles); a
+windowed tile was held to half the window for that, and is the causal call's
+since (PERF.md section 6, PR 51). A strip costs a pass something whatever its
+width, the forward more than the backward (its strips each end in a row
+update), so the backward cuts a tile of two sub-tiles a side or more and the
+forward one of eight (`_CUT_FROM`, with what the chip read).
 
 The reference framework has no attention kernels at all (its data plane is torch);
 this op is what its GPU stack gets from flash-attn. Ring attention
@@ -136,8 +153,15 @@ MIB = 1 << 20
 # that the matmuls hide the softmax and a grid step its fixed cost.
 _TILE_ELEMS = 512 * 512
 _MAX_BLOCK = 1024  # rows of a tile, the one a grid step owns or loops over
-# Largest tile of a windowed call, as a share of its window.
-_WINDOW_TILE = 0.5
+# Rows and columns of the sub-tiles a masked tile is cut into: a vreg's lanes,
+# the MXU's own width. On the chip (PERF.md section 6, PR 51) 128 read as 256
+# or better in every call, and 512 worse.
+_SUB_TILE = 128
+# Sub-tiles a side from which a pass cuts its masked tiles (same place): the
+# backward gained at every size, 19% of its call at two a side (`gpt2_small.
+# t256`); the forward lost 4% of its call there and 11% at four a side (a
+# windowed call at tiles of 512) and gained 17-19% at eight.
+_CUT_FROM = {"fwd": 8, "bwd": 2}
 # What the compiler may use without being asked (the scoped default on a
 # v5e) and what the rule will ask for at most (of 128 MiB there).
 _VMEM_SCOPED = 16 * MIB
@@ -149,13 +173,22 @@ class FlashTiles(NamedTuple):
     in flash_bwd_fused), block_k rows of the tiles it loops over (keys,
     resp. queries), heads per grid step; `window` keys a
     query sees, itself included (None: every key before it); `select` keys
-    a query sees of those before it, named by a mask (None: no mask)."""
+    a query sees of those before it, named by a mask (None: no mask);
+    `sub_fwd` and `sub_bwd` rows and columns of the sub-tiles that the
+    forward and the backward call cut a tile into which the diagonal or a
+    window's trailing edge crosses (None: such a tile is computed whole)."""
 
     block_q: int
     block_k: int
     heads: int
     window: Optional[int] = None
     select: Optional[int] = None
+    sub_fwd: Optional[int] = None
+    sub_bwd: Optional[int] = None
+
+    def cut(self, sub):
+        """These tiles with both calls' masked tiles in sub-tiles of `sub`."""
+        return self._replace(sub_fwd=sub, sub_bwd=sub)
 
 
 def _lanes(d):
@@ -221,18 +254,18 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
     from the shape alone: the largest square tile, a multiple of 128 that
     divides t, up to _MAX_BLOCK (a tile step's matmuls must be long enough
     to hide its softmax, whose per-row bookkeeping costs the same for a
-    narrow tile as for a wide one); where a head is less than _TILE_ELEMS of
+    narrow tile as for a wide one), its masked tiles cut into sub-tiles
+    (`_sub_tiles`); where a head is less than _TILE_ELEMS of
     scores, several heads a grid step (a grid step's fixed cost is what a
     short call pays), of `_legal_heads` the most that stay within
     _TILE_ELEMS, else the fewest: 128 // d where heads are narrower than a
     vreg; one where a head fills its lanes. Heads and then the tile shrink
     until `_vmem_bytes` reckons that the call fits the VMEM budget.
 
-    With a `window` shorter than t the call is a windowed one: a tile of b
-    rows visits b + window + b scores a row where window are needed (the
-    diagonal tile and the one on the window's edge are half masked), so
-    the tile is at most _WINDOW_TILE of the window. A window of t or more
-    is the causal call.
+    With a `window` shorter than t the call is a windowed one, with the
+    causal call's tile: of the b + window + b scores a row that a tile of b
+    rows visits where window are needed, the cut leaves about a sub-tile a
+    side whatever b is. A window of t or more is the causal call.
 
     With a selection of `select` keys a query, fewer than t, the call is a
     selected one: the tile it loops over lies within one bit of the packed
@@ -257,8 +290,7 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
         select = None
     if select is not None and window is not None:
         raise ValueError("a call takes a window or a selection, not both")
-    cap = _MAX_BLOCK if window is None else min(_MAX_BLOCK, int(window * _WINDOW_TILE))
-    block = _divisor(t, cap)
+    block = _divisor(t, _MAX_BLOCK)
     if shared is not None and (d % 128 or window is not None or select is not None):
         raise ValueError("a latent call is causal, and its heads' own parts fill whole vregs")
     legal = _legal_heads(h, d if shared is None else shared)
@@ -274,7 +306,7 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
     while over_budget() and block > 128:
         block = _divisor(t, block - 1)
     if select is None:
-        return FlashTiles(block, block, heads, window)
+        return FlashTiles(block, block, heads, window, None, *_sub_tiles(block, block))
     from ray_tpu.ops.indexer import mask_width
 
     width = mask_width(t)
@@ -284,6 +316,45 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
     while heads > legal[0] and over_budget(_select_vmem_bytes, inner):
         heads = legal[legal.index(heads) - 1]
     return FlashTiles(block, inner, heads, None, select)
+
+
+def _sub_tiles(block_q, block_k):
+    """(forward's, backward's) rows and columns of the sub-tiles that a
+    causal or windowed call's masked tiles are cut into, from the tile's
+    shape: _SUB_TILE where the tile is _CUT_FROM of them a side or more; else
+    None, the pass computes the tile whole."""
+    if block_q % _SUB_TILE or block_k % _SUB_TILE:
+        return None, None
+    a_side = max(block_q, block_k) // _SUB_TILE
+    return tuple(_SUB_TILE if a_side >= _CUT_FROM[call] else None for call in ("fwd", "bwd"))
+
+
+def flash_scores(tiles: FlashTiles, t: int, backward: bool = False):
+    """(computed, needed): the scores (entries of QK^T) that one head's
+    forward call with `tiles`, or its backward call, computes over a
+    sequence of t, by the bounds the kernels loop over (`_before`,
+    `_strips`), and those in which a query sees a key. The backward call
+    visits the forward's tiles with keys for rows, which is the same count
+    where tiles are square, as `flash_tiles` gives them, and is counted so.
+    A selected call computes every tile at or below the diagonal, whole."""
+    block_q, block_k, _, window, select = tiles[:5]
+    sub = tiles.sub_bwd if backward else tiles.sub_fwd
+    ratio = block_q // block_k
+    tile = block_q * block_k
+    computed = 0
+    for i in range(t // block_q):
+        if select is not None:
+            computed += (i + 1) * ratio * tile
+            continue
+        (first, last), edge = _before(i * ratio, window, block_q, block_k)
+        computed += (last - first) * tile
+        for off in [s * block_k for s in range(ratio)] + [off for _, off, there in edge if there]:
+            if sub is None:
+                computed += tile
+            else:
+                computed += sub * sub * sum(len(crossed) for _, crossed in
+                                            _strips(off, block_q, block_k, sub, window))
+    return computed, sum(min(row + 1, window or select or t) for row in range(t))
 
 
 def _select_vmem_bytes(tiles, t, d, itemsize):
@@ -327,6 +398,9 @@ def _call(kernel, name, like, d, tiles, in_specs, out_specs, out_shape, interpre
         # names and shapes only
         name = name.replace("flash_", f"flash_win{tiles.window}_", 1)
         kernel = functools.partial(kernel, window=tiles.window)
+    sub = tiles.sub_fwd if name.endswith("_fwd") else tiles.sub_bwd
+    if sub is not None:
+        kernel = functools.partial(kernel, sub=sub)
     if name == "flash_bwd_fused":  # neither selected nor windowed
         name += LEGACY_NAMES
     return pl.pallas_call(
@@ -439,28 +513,32 @@ def _split_scale(d):
     return (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
 
 
-def _tile_loop(step, carry, plain, diag_start, diag_tiles, plain_after=None,
-               edge=None, edge_after=None):
-    """step(j, carry, masked) over a grid step's tiles: the (lo, hi) range
-    `plain` without the mask, then the `diag_tiles` tiles from diag_start on,
-    which the diagonal crosses, with it (a static count, unrolled), then
-    the range `plain_after` without. A windowed call has tiles that the
-    window's trailing edge crosses as well, masked like the diagonal's: the
-    range `edge` before `plain`, `edge_after` after `plain_after`. A range
-    may be None."""
+def _tile_loop(step, carry, plain, diag_start, diag, plain_after=None, edge=(), edge_after=()):
+    """step(j, carry, masked, off) over a grid step's tiles: the (lo, hi)
+    range `plain` without the mask, then the tiles from diag_start on, which
+    the diagonal crosses, with it (`diag`, their offsets from the diagonal: a
+    static count, unrolled), then the range `plain_after` without. A
+    windowed call has tiles that the window's trailing edge crosses as well,
+    masked like the diagonal's: `edge` before `plain`, `edge_after` after
+    `plain_after`, each a list of (j, off, there): how far such a tile lies
+    from the diagonal is static, whether the sequence holds it (`there`) is
+    not. A range may be None."""
     unmasked = functools.partial(step, masked=False)
-    masked = functools.partial(step, masked=True)
-    if edge is not None:
-        carry = jax.lax.fori_loop(*edge, masked, carry)
+
+    def edges(tiles, carry):
+        for j, off, there in tiles:
+            carry = jax.lax.cond(there, functools.partial(step, j, masked=True, off=off),
+                                 lambda c: c, carry)
+        return carry
+
+    carry = edges(edge, carry)
     if plain is not None:
         carry = jax.lax.fori_loop(*plain, unmasked, carry)
-    for s in range(diag_tiles):
-        carry = step(diag_start + s, carry, masked=True)
+    for s, off in enumerate(diag):
+        carry = step(diag_start + s, carry, masked=True, off=off)
     if plain_after is not None:
         carry = jax.lax.fori_loop(*plain_after, unmasked, carry)
-    if edge_after is not None:
-        carry = jax.lax.fori_loop(*edge_after, masked, carry)
-    return carry
+    return edges(edge_after, carry)
 
 
 def _band(window, block_q, block_k):
@@ -473,17 +551,162 @@ def _band(window, block_q, block_k):
 
 
 def _before(diag, window, block_q, block_k):
-    """(plain, edge) ranges of the tiles before tile `diag`, the diagonal's
-    first: without a window all of them plain; with one the nearest that lie
-    wholly inside it plain, those its trailing edge cuts masked, the rest
-    not visited. `_tile_loop` takes the edge's first: a row they hide wholly
-    adds exp(0) terms under a running max of NEG_INF, which the rescale of
-    the row's first visible tile (alpha = 0) takes out again."""
+    """(plain, edge) of the tiles before tile `diag`, the diagonal's first,
+    as `_tile_loop` takes them: without a window all of them plain; with one
+    the nearest that lie wholly inside it plain, those its trailing edge
+    cuts masked, farthest first, the rest not visited. `_tile_loop` takes the
+    edge's first: a row they hide wholly adds exp(0) terms under a running
+    max of NEG_INF, which the rescale of the row's first visible tile
+    (alpha = 0) takes out again."""
     if window is None:
-        return (0, diag), None
+        return (0, diag), []
     inside, any_inside = _band(window, block_q, block_k)
-    first_plain = jnp.maximum(diag - inside, 0)
-    return (first_plain, diag), (jnp.maximum(diag - any_inside, 0), first_plain)
+    # a Python number stays one: `flash_scores` walks these bounds with ints
+    first_plain = max(diag - inside, 0) if isinstance(diag, int) else jnp.maximum(diag - inside, 0)
+    return ((first_plain, diag),
+            [(diag - e, -e * block_k, diag - e >= 0) for e in range(any_inside, inside, -1)])
+
+
+def _after(first, last, window, block_q, block_k):
+    """(plain, edge) of the tiles from `first`, the first after the
+    diagonal's, up to `last`, as `_before`: the nearest first."""
+    if window is None:
+        return (first, last), []
+    inside, any_inside = _band(window, block_q, block_k)
+    return ((first, jnp.minimum(first + inside, last)),
+            [(first + x, block_q + x * block_k, first + x < last) for x in range(inside, any_inside)])
+
+
+def _strips(off, rows, cols, sub, window, keys_first=False):
+    """A masked tile of rows x cols entries, `off` from the diagonal (as
+    `_visible` has it), cut into sub-tiles of sub x sub: for each strip of
+    `sub` rows, (first, crossed) of the sub-tiles that hold an entry a query
+    sees: they lie side by side, from sub-tile `first` of the strip on, and
+    crossed[n] says whether an edge runs through the n-th of them, which is
+    then masked (`_visible` with `off - (a - b) * sub` for sub-tile b of
+    strip a); the others are seen whole. A strip that sees nothing in the
+    tile has no sub-tile. Row less column is lo .. hi over what is seen, and
+    (a - b - 1) * sub + 1 .. (a - b + 1) * sub - 1 over a sub-tile."""
+    if keys_first:
+        lo, hi = -math.inf if window is None else off - window + 1, off
+    else:
+        lo, hi = off, math.inf if window is None else off + window - 1
+    strips = []
+    for a in range(rows // sub):
+        span = [(b, (a - b - 1) * sub + 1, (a - b + 1) * sub - 1) for b in range(cols // sub)]
+        seen = [(b, least < lo or most > hi) for b, least, most in span if most >= lo and least <= hi]
+        strips.append((seen[0][0] if seen else 0, [crossed for _, crossed in seen]))
+    return strips
+
+
+class _Cut(NamedTuple):
+    """How a causal or windowed call's kernel cuts its masked tiles:
+    sub-tiles of sub x sub of a rows x cols tile; `diff`, row less column of
+    a sub-tile's entries, made once a grid step."""
+
+    sub: int
+    rows: int
+    cols: int
+    window: Optional[int]
+    keys_first: bool
+    diff: object
+
+    @classmethod
+    def of(cls, sub, rows, cols, window, keys_first=False):
+        """None where the tiles are left whole."""
+        if sub is None:
+            return None
+        return cls(sub, rows, cols, window, keys_first, _row_minus_col(sub, sub))
+
+    def strips(self, j, off):
+        """Of tile j, `off` from the diagonal: (rows, columns, mask) of each
+        strip, the strip's rows of the tile as a slice, the columns it sees
+        any of as rows of a ref's sequence axis (None: it sees none), and
+        what masks a list of its score tiles, (sub, columns) each."""
+        sub = self.sub
+        plan = _strips(off, self.rows, self.cols, sub, self.window, self.keys_first)
+        for a, (first, crossed) in enumerate(plan):
+            rows = slice(a * sub, (a + 1) * sub)
+            if not crossed:
+                yield rows, None, None
+                continue
+            at = pl.ds(pl.multiple_of(j * self.cols + first * sub, sub), len(crossed) * sub)
+            yield rows, at, functools.partial(self._mask, off - (a - first) * sub, crossed)
+
+    def _mask(self, off, crossed, ss):
+        """The score tiles `ss` of a strip with the sub-tiles that an edge
+        crosses masked; `off` is the first's offset from the diagonal."""
+        if not any(crossed):
+            return ss
+        sub = self.sub
+        seen = {n: _visible(self.diff, off + n * sub, self.window, self.keys_first)
+                for n, cut in enumerate(crossed) if cut}
+        # runs of sub-tiles seen whole stay one piece
+        bounds = sorted({0, len(crossed)} | set(seen) | {n + 1 for n in seen})
+        pieces = list(zip(bounds, bounds[1:]))
+
+        def masked(s):
+            parts = [s[:, lo * sub:hi * sub] for lo, hi in pieces]
+            parts = [jnp.where(seen[lo], part, NEG_INF) if lo in seen else part
+                     for (lo, _), part in zip(pieces, parts)]
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+        return [masked(s) for s in ss]
+
+
+def _stage_by_stage(updates):
+    """The results of `updates` in their order, each a carry as it is or a
+    generator that yields after each of its stages and returns one: the
+    generators run in step, every one's first stage before any one's second,
+    so a cut tile's strips reach the scheduler as a whole tile's heads do."""
+    running = {n: u for n, u in enumerate(updates) if hasattr(u, "send")}
+    results = list(updates)
+    while running:
+        for n, update in list(running.items()):
+            try:
+                next(update)
+            except StopIteration as done:
+                results[n] = done.value
+                del running[n]
+    return results
+
+
+def _tile_step(update, held, visible, cut, block_k, fresh=None):
+    """step(j, carry, masked, off) of a grid step over tile j of block_k
+    columns, from `update(held, at, mask, carry)`, a generator of stages
+    (`_stage_by_stage`) that works columns `at` of the tile into `carry` for
+    the rows of the operands the grid step holds (`held`, lists a head):
+    the whole tile at once, masked by `visible(j)` where `masked`; with `cut`
+    (`_Cut`) a masked tile whose offset from the diagonal `off` is given
+    strip by strip of its rows, each strip's rows of `held` and of the
+    carry against the columns it sees any of, and a strip that sees none
+    keeps what it has (`fresh(rows)` where the carry is None, a row's first
+    tile)."""
+
+    def whole(j, ss):
+        seen = visible(j)
+        return [jnp.where(seen, s, NEG_INF) for s in ss]
+
+    def step(j, carry, masked=True, off=None):
+        if not masked or cut is None or off is None:
+            mask = functools.partial(whole, j) if masked else None
+            return _stage_by_stage([update(held, _rows(j, block_k), mask, carry)])[0]
+        strips = []
+        for rows, at, mask in cut.strips(j, off):
+            old = None if carry is None else tuple(tuple(x[rows] for x in c) for c in carry)
+            if at is None:
+                strips.append(fresh(cut.sub) if old is None else old)
+            else:
+                strips.append(update([[x[rows] for x in xs] for xs in held], at, mask, old))
+        return _by_strips(_stage_by_stage(strips))
+
+    return step
+
+
+def _by_strips(parts):
+    """The strips' results, each as a carry (a tuple a head of arrays whose
+    rows are the strip's), as one carry of whole tiles."""
+    return tuple(tuple(jnp.concatenate(xs, axis=0) for xs in zip(*head)) for head in zip(*parts))
 
 
 def _visible(diff, off, window, keys_first=False):
@@ -547,15 +770,20 @@ def _score_depth(d, heads, shared):
 # --------------------------------------------------------------------------
 
 
-def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible, shared=None):
-    """(heads, step) of a forward grid step: step(j, carry, masked) takes the
-    online softmax of each of its heads, carry[h] = (m, l, acc), over the
+def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible, shared=None, cut=None):
+    """(heads, step) of a forward grid step: step(j, carry, masked, off) takes
+    the online softmax of each of its heads, carry[h] = (m, l, acc), over the
     tile of block_k keys j; a carry of None is a row's first tile, with
     nothing to rescale. q is held a head, its neighbours' lanes zeroed
     (`_Head.alone`); acc is as wide as the head's cut of v and whole in the
     head's own lanes. `visible(j)` is the tile's mask. With `shared`
     (`_Shared`), a head's scores are the sum of two products, the second of
-    its part of shared.q with the tile of shared.k."""
+    its part of shared.q with the tile of shared.k. With `cut` (`_Cut`) a
+    masked tile whose offset from the diagonal `off` is given goes strip by
+    strip of its rows: a strip's queries against the keys of the sub-tiles
+    they see any of, one update of the strip's (m, l, acc), the mask built on
+    the sub-tiles an edge crosses alone; a strip that sees no key of the
+    tile keeps what it has."""
     heads = _heads(d, q_ref.shape[2])
     q_scale, s_scale = _split_scale(_score_depth(d, heads, shared))
     qs = [head.alone(q) for head, q in zip(heads, _cuts(q_ref, heads))]
@@ -564,33 +792,42 @@ def _fwd_step(q_ref, k_ref, v_ref, d, block_k, visible, shared=None):
     if q_scale != 1.0:
         qs, qs2 = ([q * q_scale for q in xs] for xs in (qs, qs2))
 
-    def step(j, carry, masked=True):
-        rows = _rows(j, block_k)
+    def update(held, rows, mask, carry):
+        # stage by stage over the heads, not head by head, and (each `yield`
+        # ends a stage, `_stage_by_stage`) over the strips of a cut tile: the
+        # order the scheduler is given is the order it keeps where it is free
+        # to choose
+        qs, qs2 = held
         ks, vs = _cuts(k_ref, heads, rows), _cuts(v_ref, heads, rows)
-        # stage by stage over the heads, not head by head: the order the
-        # scheduler is given is the order it keeps where it is free to choose
-        ss = [_dot(q, k, _NT) for q, k in zip(qs, ks)]  # (block_q, block_k) float32
+        yield
+        ss = [_dot(q, k, _NT) for q, k in zip(qs, ks)]  # (queries, keys) float32
         if shared is not None:
             k2 = shared.k[0, rows, :]
             ss = [s + _dot(q2, k2, _NT) for s, q2 in zip(ss, qs2)]
+        yield
         if s_scale != 1.0:
             ss = [s * s_scale for s in ss]
-        if masked:
-            seen = visible(j)
-            ss = [jnp.where(seen, s, NEG_INF) for s in ss]
+        if mask is not None:
+            ss = mask(ss)
+        yield
         ms = [jnp.max(s, axis=-1, keepdims=True) for s in ss]
         if carry is not None:
             ms = [jnp.maximum(m, m_new) for (m, _, _), m_new in zip(carry, ms)]
+        yield
         ps = [jnp.exp(s - m) for s, m in zip(ss, ms)]
+        yield
         ls = [jnp.sum(p, axis=-1, keepdims=True) for p in ps]
+        yield
         accs = [_dot(p.astype(v.dtype), v, _NN) for p, v in zip(ps, vs)]
+        yield
         if carry is not None:
             alphas = [jnp.exp(m - m_new) for (m, _, _), m_new in zip(carry, ms)]
             ls = [l * alpha + l_new for (_, l, _), alpha, l_new in zip(carry, alphas, ls)]
             accs = [acc * alpha + acc_new for (_, _, acc), alpha, acc_new in zip(carry, alphas, accs)]
         return tuple(zip(ms, ls, accs))
 
-    return heads, step
+    return heads, _tile_step(update, (qs, qs2), visible, cut, block_k,
+                             functools.partial(_fwd_init, heads))
 
 
 def _fwd_init(heads, block_q):
@@ -612,7 +849,7 @@ def _fwd_write(o_ref, lse_ref, heads, carry):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, window=None,
-                shared=None):
+                sub=None, shared=None):
     ratio = block_q // block_k
     i = _own_tile(k_ref.shape[1], block_q)
     # entry (r, c) of q tile i against k tile j is visible iff
@@ -620,15 +857,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, win
     diff = _row_minus_col(block_q, block_k)
     heads, step = _fwd_step(
         q_ref, k_ref, v_ref, d, block_k,
-        lambda j: _visible(diff, j * block_k - i * block_q, window), shared)
+        lambda j: _visible(diff, j * block_k - i * block_q, window), shared,
+        _Cut.of(sub, block_q, block_k, window))
 
     # k tiles 0 .. i*ratio-1 lie below the diagonal, the next `ratio` cross
     # it. Tile 0 is every row's first; it holds key 0, which every row sees.
+    diag = [s * block_k for s in range(ratio)]
     if isinstance(i, int):  # the only q tile: no tile lies below the diagonal
-        carry = _tile_loop(step, step(0, None, masked=True), None, 1, ratio - 1)
+        carry = _tile_loop(step, step(0, None, masked=True, off=0), None, 1, diag[1:])
     else:
         plain, edge = _before(i * ratio, window, block_q, block_k)
-        carry = _tile_loop(step, _fwd_init(heads, block_q), plain, i * ratio, ratio, edge=edge)
+        carry = _tile_loop(step, _fwd_init(heads, block_q), plain, i * ratio, diag, edge=edge)
     _fwd_write(o_ref, lse_ref, heads, carry)
 
 
@@ -659,7 +898,7 @@ def _forward_call(q, k, v, mask=None, *, d, tiles, interpret):
 
 
 def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, block_k, visible,
-              shared=None):
+              shared=None, cut=None):
     """(heads, step) of the backward grid step that owns key tile i:
     step(j, carry, masked) over the tile of block_k queries j puts the tile's
     part of each head's dk and dv onto carry[h] = (dk, dv), as wide as the
@@ -677,7 +916,11 @@ def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, 
     product, of the tile of shared.k that the step owns, held a head like k,
     with the heads' parts of shared.q; a third entry of carry[h] sums the
     head's dS^T times those parts, whole in the head's own lanes, and
-    shared.dq_acc takes dS times the key as dq_acc takes dS K."""
+    shared.dq_acc takes dS times the key as dq_acc takes dS K. With `cut`
+    (`_Cut`) a masked tile whose offset from the diagonal `off` is given
+    goes strip by strip of its keys, as the forward's goes by its queries: a
+    strip's keys against the queries of the sub-tiles that see any of them,
+    the five matmuls and the exp on those alone."""
     heads = _heads(d, k_ref.shape[2])
     q_scale, s_scale = _split_scale(_score_depth(d, heads, shared))
     ks = [head.alone(k) for head, k in zip(heads, _cuts(k_ref, heads))]
@@ -713,32 +956,40 @@ def _bwd_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_acc, delta, i, d, 
             shared.dq_acc[...] = jnp.zeros(shared.dq_acc.shape, shared.dq_acc.dtype)
         jax.lax.fori_loop(0, q_ref.shape[1] // block_k, fill, 0)
 
-    def step(j, carry, masked=True):
-        at = _rows(j, block_k)
+    def update(held, at, mask, carry):
+        # stage by stage over the heads and the strips, as the forward's step
+        ks, vs, ks2 = held
         qs, dos = _cuts(q_ref, heads, at), _cuts(do_ref, heads, at)
-        # stage by stage over the heads, as the forward's step
-        ss = [_dot(k, q, _NT) for k, q in zip(ks, qs)]  # (block_q keys, block_k queries)
+        yield
+        ss = [_dot(k, q, _NT) for k, q in zip(ks, qs)]  # (keys, queries) float32
         if shared is not None:
             qs2 = _cuts(shared.q, parts, at)
             ss = [s + _dot(k2, q2, _NT) for s, k2, q2 in zip(ss, ks2, qs2)]
+        yield
         if s_scale != 1.0:
             ss = [s * s_scale for s in ss]
-        if masked:
-            seen = visible(j)
-            ss = [jnp.where(seen, s, NEG_INF) for s in ss]
-        ps = [jnp.exp(s - lse_ref[h, :, at]) for h, s in enumerate(ss)]  # less a (1, block_k) row
+        if mask is not None:
+            ss = mask(ss)
+        yield
+        ps = [jnp.exp(s - lse_ref[h, :, at]) for h, s in enumerate(ss)]  # less a (1, queries) row
+        yield
         dvs = [dv + _dot(p.astype(do.dtype), do, _NN) for (_, dv, *_), p, do in zip(carry, ps, dos)]
+        yield
         dss = [(p * (_dot(v, do, _NT) - delta[h, :, at])).astype(do.dtype)
                for h, (p, v, do) in enumerate(zip(ps, vs, dos))]
+        yield
         dks = [dk + _dot(ds, q, _NN) for (dk, *_), ds, q in zip(carry, dss, qs)]
+        yield
         _sum_dq(dq_acc, at, heads, dss, ks)
         if shared is None:
             return tuple(zip(dks, dvs))
+        yield
         dks2 = [dk2 + _dot(ds, q2, _NN) for (_, _, dk2), ds, q2 in zip(carry, dss, qs2)]
+        yield
         _sum_dq(shared.dq_acc, at, parts, dss, ks2)
         return tuple(zip(dks, dvs, dks2))
 
-    return heads, step
+    return heads, _tile_step(update, (ks, vs, ks2), visible, cut, block_k)
 
 
 def _sum_dq(dq_acc, at, heads, dss, ks):
@@ -752,12 +1003,12 @@ def _sum_dq(dq_acc, at, heads, dss, ks):
         dq_acc[at, first:last] += part
 
 
-def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None):
+def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None, cut=None):
     """A backward grid step: `loop(step, carry)` over its tiles from zeroed
     dk and dv, then its results. No later key tile is seen by the queries
     of tile i, so their rows of dq are whole."""
     *ins, dq_ref, dk_ref, dv_ref, dq_acc, delta = refs
-    heads, step = _bwd_step(*ins, dq_acc, delta, i, d, block_k, visible, shared)
+    heads, step = _bwd_step(*ins, dq_acc, delta, i, d, block_k, visible, shared, cut)
     zeros = tuple((jnp.zeros((block_q, head.width), jnp.float32),) * 2 for head in heads)
     if shared is not None:
         lanes = shared.k.shape[2]
@@ -791,7 +1042,7 @@ def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, delta, *, d, block_q, block_k, window=None, shared=None):
+                dq_acc, delta, *, d, block_q, block_k, window=None, sub=None, shared=None):
     """Owns block_q keys, loops over tiles of block_k queries once, and
     yields all three gradients: dk and dv of its keys, and onto dq_acc, the
     float32 (t, heads * d) a head group's grid steps hand on, each query
@@ -805,20 +1056,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_
     # q tiles before i*ratio see none of these keys, the next `ratio` cross
     # the diagonal, the rest see all of them (with a window: the nearest do,
     # then come those its trailing edge cuts, the rest see none)
-    after = edge = None
+    after, edge = None, ()
     if not isinstance(i, int):
-        first, last = (i + 1) * ratio, seq_len // block_k
-        if window is None:
-            after = (first, last)
-        else:
-            inside, any_inside = _band(window, block_q, block_k)
-            last_plain = jnp.minimum(first + inside, last)
-            after, edge = (first, last_plain), (last_plain, jnp.minimum(first + any_inside, last))
+        after, edge = _after((i + 1) * ratio, seq_len // block_k, window, block_q, block_k)
+    diag = [s * block_k for s in range(ratio)]
     _bwd_run(
-        lambda step, zeros: _tile_loop(step, zeros, None, i * ratio, ratio, after, edge_after=edge),
+        lambda step, zeros: _tile_loop(step, zeros, None, i * ratio, diag, after, edge_after=edge),
         (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta),
         i, d, block_q, block_k,
-        lambda j: _visible(diff, j * block_k - i * block_q, window, keys_first=True), shared)
+        lambda j: _visible(diff, j * block_k - i * block_q, window, keys_first=True), shared,
+        _Cut.of(sub, block_q, block_k, window, keys_first=True))
 
 
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
@@ -980,7 +1227,8 @@ def _with_blocks(tiles, t, block_q, block_k):
             f"block_q ({block_q}) must divide the seq len ({t}) and be a "
             f"multiple of block_k ({block_k}): a grid step's tile is cut "
             "into whole tiles of the other operand along the diagonal")
-    return tiles._replace(block_q=block_q, block_k=block_k)
+    sub_fwd, sub_bwd = _sub_tiles(block_q, block_k)
+    return tiles._replace(block_q=block_q, block_k=block_k, sub_fwd=sub_fwd, sub_bwd=sub_bwd)
 
 
 def xla_causal_attention(q, k, v, window=None):

@@ -358,8 +358,10 @@ def test_remat_plan_of_the_cell():
 
 
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
-# rule), as tests/test_mellum.py:_step_text gives it, taken on PR 50's tree.
-AFMOE_STEP = "34e88092ea6ad1afd6fa951955dd0c1c48dd4a636c310645d5f9b107a33787a9"
+# rule), as tests/test_mellum.py:_step_text gives it, taken on PR 50's tree;
+# PR 51 moved it by design: the flash calls cut their masked tiles into sub-tiles of 128 (`FlashTiles.sub_fwd`, `.sub_bwd`),
+# the windowed call's tile stays 1,024, now the causal call's by the rule.
+AFMOE_STEP = "2221aa836183716f0674ff7538cdc63d0b9a04efe79755e4557354e4cd3a7020"
 
 
 def test_the_cell_s_step_runs_the_windowed_pair_in_four_layers_and_the_causal_in_one(monkeypatch):

@@ -39,8 +39,9 @@ def _with_grads(attn, q, k, v, w):
     return (o, *vjp(w.astype(o.dtype)))
 
 
-# (b, h, t, d, dtype, block_q, block_k, heads a grid step, scale of q); None:
-# the rule's. Operands are (b, t, h, d), random and so distinct a head and a
+# (b, h, t, d, dtype, block_q, block_k, heads a grid step, scale of q[, the
+# sub-tile that masked tiles are cut into]); None: the rule's; tiles given
+# here without a sub-tile are computed whole. Operands are (b, t, h, d), random and so distinct a head and a
 # batch row: a block index map that takes another head's lanes or another
 # row's shows in every result.
 CASES = {
@@ -72,19 +73,36 @@ CASES = {
     "d128_a_head_a_row": (2, 4, 256, 128, BF16, 128, 128, 1, 1),
     "d128_a_head_a_row_float32": (2, 4, 256, 128, F32, 128, 128, 1, 1),
     "d256_a_head_a_row": (2, 2, 256, 256, F32, 128, 128, 1, 1),
+    # masked tiles cut into sub-tiles of 128 x 128 (`FlashTiles.cut`), the
+    # empty ones skipped: the one-tile call, whose strips start from nothing;
+    # tiles of two and four sub-tiles a side; block_q over block_k, with the
+    # sub-tile a whole tile of keys; narrow heads; and the rule's own choice
+    # at a real tile, 1,024 in sub-tiles of 128 over two tiles, one head
+    "cut_the_one_tile_of_512": (1, 2, 512, 64, F32, 512, 512, 2, 1, 128),
+    "cut_the_one_tile_of_512_bf16": (2, 4, 512, 64, BF16, 512, 512, 2, 1, 128),
+    "cut_tiles_of_256_d128": (1, 2, 512, 128, F32, 256, 256, 1, 1, 128),
+    "cut_sub_tiles_of_256_in_512": (1, 1, 1024, 128, F32, 512, 512, 1, 1, 256),
+    "cut_block_q_over_block_k": (1, 2, 512, 64, F32, 512, 256, 2, 1, 128),
+    "cut_block_q_four_block_k_the_sub_tile": (1, 2, 512, 64, F32, 512, 128, 2, 1, 128),
+    "cut_four_heads_of_32_a_vreg": (2, 8, 256, 32, F32, 256, 256, 4, 1, 128),
+    "cut_a_pair_of_64_two_groups": (2, 4, 512, 64, F32, 256, 256, 2, 1, 128),
+    "cut_large_scores_float32": (1, 2, 512, 64, F32, 256, 256, 2, 40, 128),
+    "cut_t2048_d128_the_rules_tiles": (1, 1, 2048, 128, BF16, None, None, None, 1),
 }
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_flash_matches_float32_reference(case):
-    b, h, t, d, dtype, block_q, block_k, heads, q_scale = CASES[case]
+    b, h, t, d, dtype, block_q, block_k, heads, q_scale, *sub = CASES[case]
     keys = jax.random.split(jax.random.PRNGKey(len(case) + t), 4)
     q, k, v, w = (jax.random.normal(key, (b, t, h, d), F32) for key in keys)
     q, k, v, w = ((q * q_scale).astype(dtype), k.astype(dtype), v.astype(dtype),
                   w.astype(dtype))
     tiles = flash_tiles(h, t, d, dtype)
-    tiles = FlashTiles(block_q or tiles.block_q, block_k or tiles.block_k,
-                       heads or tiles.heads)
+    if block_q:
+        tiles = FlashTiles(block_q, block_k, heads).cut((sub or [None])[0])
+    if case == "cut_t2048_d128_the_rules_tiles":
+        assert tiles == FlashTiles(1024, 1024, 1).cut(128)
     got = _with_grads(lambda q, k, v: attention._flash(q, k, v, None, None, tiles, True),
                       q, k, v, w)
     want = _with_grads(_reference, *(x.astype(F32) for x in (q, k, v, w)))
@@ -151,6 +169,10 @@ def test_tile_rule_is_legal_for(shape, dtype):
     for block in (tiles.block_q, tiles.block_k):
         assert block % 128 == 0 and t % block == 0
     assert tiles.block_q % tiles.block_k == 0
+    # masked tiles are cut in sub-tiles of 128: by the backward where a tile
+    # is two of them a side or more, by the forward where it is eight
+    assert tiles.sub_bwd == (128 if tiles.block_k >= 256 else None)
+    assert tiles.sub_fwd == (128 if tiles.block_k >= 1024 else None)
     # a block's last dimension: whole vregs of 128 lanes, or the whole row
     assert h % tiles.heads == 0
     assert tiles.heads * d % 128 == 0 or tiles.heads == h
@@ -163,12 +185,12 @@ def test_tile_rule_is_legal_for(shape, dtype):
 def test_tile_rule_takes_narrow_heads_a_vreg_at_a_time():
     """128 // d heads where one head is a tile step's worth of scores; more
     where the call is short; one where a head fills its lanes."""
-    assert flash_tiles(12, 1024, 64, BF16) == FlashTiles(1024, 1024, 2)
-    assert flash_tiles(12, 256, 64, BF16) == FlashTiles(256, 256, 4)
+    assert flash_tiles(12, 1024, 64, BF16) == FlashTiles(1024, 1024, 2).cut(128)
+    assert flash_tiles(12, 256, 64, BF16) == FlashTiles(256, 256, 4, sub_bwd=128)
     assert flash_tiles(32, 8192, 64, BF16).heads == 2
     assert flash_tiles(16, 1024, 32, BF16).heads == 4
     assert flash_tiles(2, 1024, 32, BF16).heads == 2  # the whole row, 64 lanes
-    assert flash_tiles(32, 16384, 128, BF16) == FlashTiles(1024, 1024, 1)
+    assert flash_tiles(32, 16384, 128, BF16) == FlashTiles(1024, 1024, 1).cut(128)
     # two 64-wide heads in a vreg are booked as the one vreg they are
     pair = attention._vmem_bytes(FlashTiles(1024, 1024, 2), 1024, 64, 2)
     one_wide = attention._vmem_bytes(FlashTiles(1024, 1024, 1), 1024, 128, 2)

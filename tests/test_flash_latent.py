@@ -56,7 +56,9 @@ def _errors(got, want):
             for name, g, r in zip(NAMES, got, want)}
 
 
-# (b, h, t, dtype, block_q, block_k, heads a grid step); None: the rule's
+# (b, h, t, dtype, block_q, block_k, heads a grid step[, the sub-tile that
+# masked tiles are cut into]); None: the rule's (a tile of 384 goes in
+# sub-tiles of 128); tiles given without a sub-tile are computed whole
 CASES = {
     "one_tile": (1, 2, 128, F32, None, None, None),
     "one_tile_of_256_bf16": (2, 2, 256, BF16, 256, 256, 2),
@@ -65,18 +67,22 @@ CASES = {
     "block_q_over_block_k": (1, 2, 512, F32, 256, 128, 2),
     "four_heads_a_grid_step": (1, 4, 256, F32, 128, 128, 4),
     "two_groups_of_two_three_tiles_bf16": (1, 4, 384, BF16, 128, 128, 2),
+    "cut_one_tile_of_256": (2, 2, 256, F32, 256, 256, 2, 128),
+    "cut_two_tiles_of_256_two_groups": (1, 4, 512, F32, 256, 256, 2, 128),
+    "cut_block_q_over_block_k_bf16": (1, 2, 512, BF16, 512, 256, 2, 128),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_latent_pair_matches_the_expanded_form(case):
-    b, h, t, dtype, block_q, block_k, heads = CASES[case]
+    b, h, t, dtype, block_q, block_k, heads, *sub = CASES[case]
     ops, w = _operands(b, t, h, dtype)
     tiles = flash_tiles(h, t, D, dtype, shared=R)
     if case == "t384_the_rule_s_one_tile":
-        assert tiles[:2] == (384, 384)  # no power of two: three vregs of rows
+        # no power of two: three vregs of rows, which the backward cuts
+        assert tiles[:2] == (384, 384) and (tiles.sub_fwd, tiles.sub_bwd) == (None, 128)
     if block_q:
-        tiles = FlashTiles(block_q, block_k, heads)
+        tiles = FlashTiles(block_q, block_k, heads).cut(sub[0] if sub else None)
     got = _with_grads(lambda *a: attention._flash_latent(*a, tiles, True), ops, w)
     want = _with_grads(_expanded, [x.astype(F32) for x in ops], w)
     assert got[0].dtype == dtype and got[4].shape == (b, t, R)
@@ -133,7 +139,7 @@ def test_tile_rule_for_the_cell_and_what_it_refuses():
     1,024; a window, a selection or an own part that straddles vregs is no
     latent call."""
     tiles = flash_tiles(32, 8192, D, BF16, shared=R)
-    assert tiles == FlashTiles(512, 512, 2)
+    assert tiles == FlashTiles(512, 512, 2, sub_bwd=128)
     need = (attention._vmem_bytes(tiles, 8192, D, 2)
             + attention._shared_vmem_bytes(tiles, 8192, R, 2))
     assert need <= attention._VMEM_BUDGET

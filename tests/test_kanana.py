@@ -371,8 +371,8 @@ def test_the_cell_s_step_runs_the_latent_pair_once_a_layer(monkeypatch):
 # and the layer sows the rows it walked), and in PR 45, by design too (the
 # layer's products and plan are named residuals that its backward reads, and
 # the rule keeps all of them here): a change to this family's own fields of
-# `ExpertShare` leaves it as it is.
-LFM2_STEP = "acf612b0208fd90b770ad35d9fb505bd6e63e5ff66654aa8bc21624d2afe3319"
+# `ExpertShare` leaves it as it is. PR 51 moved it by design: the flash calls cut their masked tiles into sub-tiles of 128 (`FlashTiles.sub_fwd`, `.sub_bwd`).
+LFM2_STEP = "3a885a42dd011ada07fe943ab4be9f46a4747f238ed1fe7a2635db61b5946382"
 
 
 def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch):
@@ -393,8 +393,9 @@ def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch
 
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
 # rule), as tests/test_mellum.py:_step_text gives it, taken on PR 46's parent's
-# tree before `TrainStep` stopped knowing its families by name.
-KANANA_STEP = "59dc3fe91aa7a076efee48fcbd9ed0f3a2bc15345f453c6c93e5fd0255da388f"
+# tree before `TrainStep` stopped knowing its families by name; PR 51 moved it by design: the flash calls cut their masked tiles into sub-tiles of 128 (`FlashTiles.sub_fwd`, `.sub_bwd`),
+# the latent pair's among them.
+KANANA_STEP = "92c5695310fceb6cf972dd1b5c1276f49eaf2f40ed3d71452bbe376ff13dd563"
 
 
 def test_the_cell_lowers_to_its_pinned_step(monkeypatch):

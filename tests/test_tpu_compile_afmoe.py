@@ -17,10 +17,11 @@ from tests._tpu_compile import GIB, _CUSTOM_CALL, _kinds, _live_bytes, _loss, _q
 def test_windowed_kernels_compile_at_the_cell_s_shape(one_chip):
     """trinity_mini_l5_ep16.t8192's window layers: 32 heads of 128 over (2,
     8192) tokens under a window of 2,048 keys, forward and backward, each a
-    pallas call that says the window in its name; a tile is half the window
-    (the rule's `_WINDOW_TILE`: 1,024, the causal call's too), one head a grid step."""
+    pallas call that says the window in its name; the tile is the causal
+    call's, 1,024, its masked tiles cut in sub-tiles of 128 (PR 51), one head a
+    grid step."""
     tiles = attention.flash_tiles(32, 8192, 128, jnp.bfloat16, 2048)
-    assert tiles == attention.FlashTiles(1024, 1024, 1, 2048), tiles
+    assert tiles == attention.FlashTiles(1024, 1024, 1, 2048).cut(128), tiles
     attn = lambda q, k, v: attention.flash_causal_attention(q, k, v, window=2048)
     c = jax.jit(jax.grad(_loss(attn), argnums=(0, 1, 2))).lower(
         *_qkv((2, 8192, 32, 128), one_chip)).compile()
