@@ -61,9 +61,20 @@ bytes, hex-encoded at dump time) and a short detail string/number.
   train.step                     a step program completed on the device:
                                  (optimizer steps so far, seconds from the
                                  completion before it to its own); a step
-                                 flagged slow: (seconds, then what the host
-                                 did over it: seconds on a run queue,
-                                 stolen, waiting on I/O), where /proc says
+                                 flagged slow: (seconds, the median step's,
+                                 the longest gap between two wake-ups of
+                                 the host heartbeat over it, the process's
+                                 CPU seconds over that gap, seconds from
+                                 the gap's end to the completion, the
+                                 collector's pauses summed, the longest
+                                 and its generation, whether the device
+                                 had finished the next step by then (1, 0,
+                                 -1: none in flight), the cause as its
+                                 index in SLOW_STEP_CAUSES, then seconds on
+                                 a run queue, stolen, waiting on I/O where
+                                 /proc says): names and a reader in
+                                 train/_telemetry.py, SLOW_STEP_DETAIL and
+                                 slow_step_from_detail
   train.dispatch                 TrainStep enqueued a step program:
                                  (step number, optimizer steps in it) —
                                  "did step N ever start" for a hung mesh

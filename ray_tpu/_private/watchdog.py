@@ -297,12 +297,16 @@ class StallWatchdog:
             f"train step {int(slow.get('step', 0))} took "
             f"{slow.get('duration_s', 0):.3f}s — "
             f"{slow.get('ratio', 0):.1f}x the trailing median "
-            f"({slow.get('median_s', 0):.3f}s)",
+            f"({slow.get('median_s', 0):.3f}s); cause {slow.get('cause', 'unknown')} "
+            f"(host gap {slow.get('host_gap_s', 0):.3f}s with "
+            f"{slow.get('host_gap_cpu_s', 0):.3f}s of CPU, collector "
+            f"{slow.get('gc_pause_s', 0):.3f}s, next step done "
+            f"{slow.get('next_done', -1):.0f})",
             node_id=self.core.node_id.hex() if self.core.node_id else "",
             worker_id=self.core.worker_id.hex(),
         )
         incident["slow_step"] = {
-            k: float(v) for k, v in slow.items()}
+            k: v if isinstance(v, str) else float(v) for k, v in slow.items()}
         path = capture_incident_profile(self.core, "slow_step")
         if path:
             incident["profile_path"] = path

@@ -34,7 +34,14 @@ The same boundaries are spans on the profiler's clock
 a device-trace window holds what the host did beside the device ops:
 ``ray_tpu.train_step.shard_batch``, ``.dispatch`` with its children ``.jit``
 and ``.record``, ``.wait`` (the watcher), and ``ray_tpu.train.report`` with
-``.slot_wait``. Each carries ``step``.
+``.slot_wait``. Each carries ``step``. Beside them two spans say what the host
+did to the worker's interpreter, on whichever thread it happened:
+``ray_tpu.host.heartbeat`` (one from each wake-up of a 10 ms sleeper to the
+next: a long one is a stretch in which no Python ran) and ``ray_tpu.host.gc``
+(one a collection, with its ``generation``). A step flagged slow carries the
+same two readings over its own interval, whether the device had already
+finished the step after it, and the cause those numbers name
+(``slow_step_cause``).
 
 Metric names are a stability contract — see ``ray_tpu/util/metrics.py``.
 """
@@ -44,6 +51,7 @@ from __future__ import annotations
 import atexit
 import contextlib
 import dataclasses
+import gc
 import logging
 import os
 import statistics
@@ -52,7 +60,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -88,16 +96,16 @@ def peak_flops_per_device(device_kind: str) -> Optional[float]:
     return None
 
 
-def trace_span(name: str, step: Optional[int] = None):
+def trace_span(name: str, step: Optional[int] = None, **stats):
     """A span on the profiler's clock, with the step number as the identifier
     its spans share. A process that never imported jax has no profiler and
     gets a null context."""
     jax = sys.modules.get("jax")
     if jax is None:
         return contextlib.nullcontext()
-    if step is None:
-        return jax.profiler.TraceAnnotation(name)
-    return jax.profiler.TraceAnnotation(name, step=step)
+    if step is not None:
+        stats["step"] = step
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
 def _finished(handle) -> bool:
@@ -125,7 +133,10 @@ def _host_pressure() -> Optional[Tuple[float, float, float]]:
     such file (gVisor). The watcher reads it at each completion, so a step
     that was slow says whether the host was: two small reads a step, on a
     thread that is otherwise blocked; a watcher whose read found nothing
-    does not ask again (``StepRecorder._host_delta``)."""
+    does not ask again (``StepRecorder._host_delta``). Where /proc is
+    absent the heartbeat stands in (``_HostWatch``): a process kept off its
+    CPUs, which here reads as run-queue wait or steal, reads there as
+    ``host_gap_s`` with ``host_gap_cpu_s`` near zero; iowait has no stand-in."""
     try:
         with open("/proc/self/schedstat") as f:
             waited = int(f.read().split()[1]) / 1e9
@@ -139,11 +150,218 @@ def _host_pressure() -> Optional[Tuple[float, float, float]]:
 # The watcher thread ends after this long with nothing in flight, so an
 # abandoned recorder leaves no thread behind; the next dispatch starts one.
 _WATCHER_IDLE_S = 2.0
+# The heartbeat thread's sleep between two wake-ups: what a host gap is
+# resolved to. It lives as long as the watcher does.
+_HEARTBEAT_S = 0.010
 # At interpreter exit a watcher is given this long to see its steps complete
 # (the runtime's own exit waits for them too). CPython ends a daemon thread
 # that returns from a wait while the interpreter is finalizing by unwinding it,
 # and inside the runtime's C++ frames that aborts the process.
 _EXIT_WAIT_S = 10.0
+
+
+class _Paused(NamedTuple):
+    """What a step's interval held of the host, as the watcher hands it to
+    record_step: the longest stretch between two wake-ups of the heartbeat,
+    the process's CPU seconds over that stretch, how long before the
+    completion was seen it ended (0: it was still open), the collector's
+    pauses summed, the longest of them and its generation (-1: none), and
+    whether the device had finished the step after this one by then (1 / 0;
+    -1: not asked, of a step that came on time, or none was in flight)."""
+
+    host_gap_s: float = 0.0
+    host_gap_cpu_s: float = 0.0
+    host_gap_end_s: float = 0.0
+    gc_pause_s: float = 0.0
+    gc_longest_s: float = 0.0
+    gc_generation: float = -1.0
+    next_done: float = -1.0
+
+
+SLOW_STEP_CAUSES = ("unknown", "gc", "host_frozen", "interpreter_held",
+                    "completion_late", "device")
+# A flagged step's train.step flight event, numbers in this order (the cause
+# as its index above), then _host_pressure's three where /proc has them.
+SLOW_STEP_DETAIL = ("duration_s", "median_s", *_Paused._fields, "cause")
+_HOST_PRESSURE = ("sched_wait_s", "steal_s", "iowait_s")
+
+
+def slow_step_cause(excess_s: float, host_gap_s: float, host_gap_cpu_s: float,
+                    gc_pause_s: float, next_done: float) -> str:
+    """Why a step completed ``excess_s`` later than the median one, as far as
+    the numbers of its own interval say. The collector's pauses cover most of
+    the excess: ``gc``. A heartbeat that woke late by most of it: the process
+    did not run (``host_frozen``: CPU over the gap under a tenth of it; a
+    stopped sandbox, a hypervisor) or a thread of its own held the interpreter
+    (``interpreter_held``: CPU burned, no collection to speak of). No gap to
+    speak of: the device had already finished the next step, so this one's
+    completion was delivered late (``completion_late``), or it had not, and
+    the chip itself took long (``device``). A wake-up counts as late from
+    twice the heartbeat's sleep, the most a sound host reads."""
+    late = host_gap_s - _HEARTBEAT_S
+    if excess_s <= 0:
+        return "unknown"
+    if gc_pause_s >= 0.5 * excess_s:
+        return "gc"
+    if late >= max(0.5 * excess_s, _HEARTBEAT_S):
+        if host_gap_cpu_s < 0.1 * host_gap_s:
+            return "host_frozen"
+        return "interpreter_held" if gc_pause_s < 0.1 * excess_s else "unknown"
+    if late < max(0.1 * excess_s, _HEARTBEAT_S) and next_done >= 0:
+        return "completion_late" if next_done else "device"
+    return "unknown"
+
+
+def slow_step_from_detail(detail) -> Optional[Dict[str, Any]]:
+    """The numbers of a ``train.step`` flight event under their names, as a
+    worker's flight file holds them; None for a step that was not flagged
+    (its detail is its seconds alone)."""
+    if not isinstance(detail, (list, tuple)) or len(detail) < len(SLOW_STEP_DETAIL):
+        return None
+    out = dict(zip(SLOW_STEP_DETAIL + _HOST_PRESSURE, map(float, detail)))
+    out["cause"] = SLOW_STEP_CAUSES[int(out["cause"])]
+    return out
+
+
+class _GcPauses:
+    """The process's one ``gc.callbacks`` hook: every collection timed, opened
+    as span ``ray_tpu.host.gc`` with its generation on the thread it runs on,
+    and handed to the host watches that are open. In ``gc.callbacks`` while any
+    watch is; a collection runs with the interpreter lock held and none starts
+    inside another, so one open slot is enough. The threads a collection kept
+    waiting ask for the lock meanwhile, and the interpreter hands it over at
+    the first bytecode of the hook's ``stop`` call: a watcher often runs before
+    the collection that delayed it is booked, so ``open`` says which one is
+    under way and since when (``_HostWatch.take`` counts it from there), and
+    a pause's reading holds that hand-over too."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._watches: list = []
+        self.open: Optional[Tuple[int, int]] = None  # started (ns), generation
+        self._span = None
+
+    def add(self, watch: "_HostWatch") -> None:
+        with self._lock:
+            self._watches.append(watch)
+            if len(self._watches) == 1:
+                gc.callbacks.append(self._hook)
+
+    def remove(self, watch: "_HostWatch") -> None:
+        with self._lock:
+            if watch in self._watches:
+                self._watches.remove(watch)
+                if not self._watches:
+                    gc.callbacks.remove(self._hook)
+                    # one under way on another thread (the lock is handed
+                    # over inside the hook) ends unheard: the next watch
+                    # must not find it still open
+                    self._close()
+
+    def _close(self) -> None:
+        span, self._span, self.open = self._span, None, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def _hook(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            step = self._watches[0].step if self._watches else None
+            span = trace_span("ray_tpu.host.gc", step, generation=info["generation"])
+            span.__enter__()
+            self._span, self.open = span, (time.perf_counter_ns(), info["generation"])
+        elif self.open is not None:
+            started, generation = self.open
+            took = time.perf_counter_ns() - started
+            for watch in self._watches:
+                watch.collections.append((started, took, generation))
+            self._close()  # after the booking: take() looks at `open` first
+
+
+_gc_pauses = _GcPauses()
+
+
+class _HostWatch:
+    """What the host did to this process's interpreter over the step interval
+    that is open: a heartbeat thread that sleeps ``_HEARTBEAT_S`` inside span
+    ``ray_tpu.host.heartbeat`` and at each wake-up reads the wall clock and the
+    process's CPU time, keeping the longest stretch between two wake-ups (a
+    stretch of wall time with no CPU time is a process that did not run; with
+    CPU time, a thread of its own that held the interpreter lock), and the
+    collections ``_GcPauses`` timed. One a watcher thread, started and ended
+    with it; the watcher ``take``s the interval's readings at each completion.
+    ``perf_counter`` is the recorder's own ``monotonic`` on Linux."""
+
+    def __init__(self, step: Optional[int], pauses: Optional[_GcPauses] = None):
+        self.step = step  # the step the watcher waits on: the spans carry it
+        self._pauses = pauses or _gc_pauses  # the process's, but for a test's own
+        # (started, nanoseconds, generation) a collection; the hook appends,
+        # take() pops
+        self.collections: deque = deque(maxlen=4096)
+        # the collection take() counted while it was under way and how much of
+        # it, and the newest one it found booked
+        self._counted_open = (0, 0)
+        self._booked = 0
+        self._lock = threading.Lock()
+        self._stopped = False
+        self._beat_ns = time.perf_counter_ns()  # the newest wake-up
+        self._beat_cpu_ns = time.process_time_ns()
+        self._gap = (0, 0, 0)  # the interval's longest: ns, CPU ns, when it ended
+        self._thread = threading.Thread(
+            target=self._beat, name="train-host-heartbeat", daemon=True)
+        self._pauses.add(self)
+        self._thread.start()
+
+    def _beat(self) -> None:
+        while not self._stopped:
+            # a span lasts from one wake-up to the next
+            with trace_span("ray_tpu.host.heartbeat", self.step):
+                now, cpu = time.perf_counter_ns(), time.process_time_ns()
+                with self._lock:
+                    if now - self._beat_ns > self._gap[0]:
+                        self._gap = (now - self._beat_ns, cpu - self._beat_cpu_ns, now)
+                    self._beat_ns, self._beat_cpu_ns = now, cpu
+                time.sleep(_HEARTBEAT_S)
+
+    def take(self, next_done: float) -> _Paused:
+        """The open interval's readings, and a new interval. A wake-up that
+        is still due counts from its last one to now (the watcher may run
+        before the heartbeat does when both were held),
+        and the heartbeat then measures its next stretch from here, so one
+        pause is not two intervals'. Likewise a collection that is not booked
+        yet counts from its start to now, and its booking later less that:
+        less what was counted and nothing else, so no pause is lost to a
+        thread switch between a clock's reading and its keeping."""
+        under_way = self._pauses.open  # before the booked ones: it may be booked meanwhile
+        now, cpu = time.perf_counter_ns(), time.process_time_ns()
+        with self._lock:
+            (gap, gap_cpu, ended), self._gap = self._gap, (0, 0, 0)
+            if now - self._beat_ns > gap:
+                gap, gap_cpu, ended = now - self._beat_ns, cpu - self._beat_cpu_ns, now
+                self._beat_ns, self._beat_cpu_ns = now, cpu
+        found = []
+        while self.collections:
+            self._booked, took, gen = self.collections.popleft()
+            if self._booked == self._counted_open[0]:
+                took -= self._counted_open[1]
+            found.append((took, gen))
+        # one that began after the newest booked one: `open` may still name a
+        # collection that is booked (the hook held before it clears it), and
+        # others may have come and gone since it was read
+        if under_way is not None and under_way[0] > self._booked:
+            started, gen = under_way
+            counted = self._counted_open[1] if started == self._counted_open[0] else 0
+            found.append((now - started - counted, gen))
+            self._counted_open = (started, now - started)
+        paused = sum(took for took, _ in found)
+        longest, generation = max(found, default=(0, -1))
+        return _Paused(gap / 1e9, gap_cpu / 1e9, (now - ended) / 1e9 if ended else 0.0,
+                       paused / 1e9, longest / 1e9, float(generation), next_done)
+
+    def stop(self, join_s: Optional[float] = None) -> None:
+        self._stopped = True
+        self._pauses.remove(self)
+        if join_s is not None:
+            self._thread.join(join_s)
 
 
 @dataclasses.dataclass(slots=True)
@@ -233,6 +451,7 @@ class StepRecorder:
         self._pending: deque = deque()
         self._pending_cond = threading.Condition()
         self._watcher: Optional[threading.Thread] = None
+        self._host: Optional[_HostWatch] = None  # the watcher's, as long as it lives
         self._closing = False
         self._last_done = self._start
         # _host_pressure() at the newest completion; False once a read found
@@ -261,7 +480,12 @@ class StepRecorder:
         self._recent_steps: deque = deque(maxlen=32)
         self._median_cache: Optional[float] = None  # refreshed every 8 steps
         self._steps_since_median = 0
-        self._slow_step: Optional[Dict[str, float]] = None
+        self._slow_step: Optional[Dict[str, Any]] = None
+        # steps flagged so far, and the longest host gap and the longest single
+        # collection any step's interval has held
+        self.slow_steps = 0
+        self.host_gap_max_s = 0.0
+        self.gc_pause_max_s = 0.0
         # Compile-storm detection: the jit-cache-miss bookkeeping above
         # already *knows* every recompilation; this turns
         # "many compiles long after warmup" — the unstable-shapes/dtypes
@@ -308,8 +532,10 @@ class StepRecorder:
         with self._pending_cond:
             self._pending.append(entry)
             if self._watcher is None:
+                self._host = _HostWatch(entry.step)
                 self._watcher = threading.Thread(
-                    target=self._watch, name="train-step-watcher", daemon=True)
+                    target=self._watch, args=(self._host,),
+                    name="train-step-watcher", daemon=True)
                 self._watcher.start()
                 atexit.register(self._stop_watcher)
             self._pending_cond.notify_all()
@@ -317,9 +543,10 @@ class StepRecorder:
     def clock(self) -> float:
         return self._clock()
 
-    def _watch(self) -> None:
+    def _watch(self, host: _HostWatch) -> None:
         """Wait on the oldest step in flight, book it at its completion, and
-        again; the thread ends when nothing has been in flight for a while."""
+        again; the thread ends when nothing has been in flight for a while,
+        and its heartbeat with it."""
         import jax
 
         while True:
@@ -327,26 +554,34 @@ class StepRecorder:
                 if not self._pending and not self._closing:
                     self._pending_cond.wait(_WATCHER_IDLE_S)
                 if not self._pending:
-                    self._watcher = None
+                    self._watcher = self._host = None
+                    host.stop()
                     atexit.unregister(self._stop_watcher)
                     return
                 entry = self._pending[0]
+            host.step = entry.step
             try:
                 with trace_span("ray_tpu.train_step.wait", entry.step):
                     jax.block_until_ready(entry.handle)
                 done = self._clock()
+                # from the completion before it, or from its own dispatch
+                # where the device had run dry by then
+                took = done - max(entry.started, self._last_done)
+                # before anything else, of a step that is late already: had
+                # the device gone on by now
+                late = not entry.compile_step and self._over_the_factor(
+                    took / max(entry.steps, 1))
+                paused = host.take(self._next_done() if late else -1.0)
                 self._read_gauges(entry.handle)
-                host = self._host_delta()
+                host_delta = self._host_delta()
                 if entry.compile_step:
                     self.record_step(entry.enqueued - entry.started,
                                      steps=entry.steps, compile_step=True)
                 else:
-                    # from the completion before it, or from its own
-                    # dispatch where the device had run dry by then
                     self.record_step(
-                        done - max(entry.started, self._last_done),
-                        steps=entry.steps, tokens=entry.tokens,
-                        examples=entry.examples, flops=entry.flops, host=host)
+                        took, steps=entry.steps, tokens=entry.tokens,
+                        examples=entry.examples, flops=entry.flops,
+                        host=host_delta, paused=paused)
             except Exception:
                 # the loop's own wait on this step raises the same error;
                 # the step is not booked and the clock restarts at the next
@@ -357,6 +592,22 @@ class StepRecorder:
                 self._last_done = done
                 self._pending.popleft()
                 self._pending_cond.notify_all()
+
+    def _over_the_factor(self, per_step_s: float) -> bool:
+        """Whether a step of that length is a slow one: over
+        profile_slow_step_factor x the trailing median, once there is one."""
+        med = self._median_cache
+        return bool(self._slow_factor > 0 and med and per_step_s > self._slow_factor * med)
+
+    def _next_done(self) -> float:
+        """Whether the step after the oldest in flight has completed too: 1
+        says the device went on while this thread had not heard of the first
+        (a late completion, not a slow device), -1 that no other is in flight.
+        Asked of a late step alone: it reads every leaf of a program's output
+        with the interpreter held, a millisecond where the leaves are many."""
+        with self._pending_cond:
+            after = self._pending[1] if len(self._pending) > 1 else None
+        return -1.0 if after is None else float(_finished(after.handle))
 
     def _read_gauges(self, metrics) -> None:
         """The completed step's own gauges, every scalar of its metrics
@@ -388,10 +639,12 @@ class StepRecorder:
     def _stop_watcher(self) -> None:
         with self._pending_cond:
             self._closing = True
-            watcher = self._watcher
+            watcher, host = self._watcher, self._host
             self._pending_cond.notify_all()
         if watcher is not None:
             watcher.join(_EXIT_WAIT_S)
+        if host is not None:
+            host.stop(join_s=1.0)  # a watcher that ended has stopped it already
 
     def settle(self, timeout_s: float = 5.0) -> None:
         """Return once every step whose program has completed (or failed) is
@@ -424,6 +677,7 @@ class StepRecorder:
         compile_step: bool = False,
         start_wall: Optional[float] = None,
         host: Optional[Tuple[float, float, float]] = None,
+        paused: Optional[_Paused] = None,
     ) -> None:
         """Record ``steps`` finished optimizer steps that took ``duration_s``
         in total, to completion (a call timed to its return at enqueue goes
@@ -433,7 +687,9 @@ class StepRecorder:
         ``flops`` is the model FLOPs of these steps where the caller knows
         them; otherwise flops_per_step or flops_per_token x tokens. ``host``
         is what ``_host_pressure`` read over these steps (seconds on a run
-        queue, stolen, waiting on I/O): a step flagged slow carries it."""
+        queue, stolen, waiting on I/O) and ``paused`` what the watcher's
+        ``_HostWatch`` read (``_Paused``): a step flagged slow carries both,
+        and the cause they name."""
         duration_s = max(0.0, float(duration_s))
         from ray_tpu._private import flight_recorder as _fr
 
@@ -442,14 +698,22 @@ class StepRecorder:
             self._last_step_at = self._clock()
             per_step = duration_s / max(steps, 1)
             med = self._median_cache
-            slow = (not compile_step and self._slow_factor > 0 and med is not None
-                    and med > 0 and per_step > self._slow_factor * med)
+            slow = not compile_step and self._over_the_factor(per_step)
+            if paused is not None:
+                self.host_gap_max_s = max(self.host_gap_max_s, paused.host_gap_s)
+                self.gc_pause_max_s = max(self.gc_pause_max_s, paused.gc_longest_s)
             # numbers, not text: nothing is formatted before a dump
             detail = duration_s
             if compile_step and self.remat_plan is not None:
                 detail = (duration_s, *self.remat_plan)
-            elif slow and host is not None:
-                detail = (duration_s, *host)
+            elif slow:
+                # a caller that timed the step itself had no watcher beside it
+                paused = paused or _Paused()
+                cause = slow_step_cause(
+                    duration_s - med * steps, paused.host_gap_s, paused.host_gap_cpu_s,
+                    paused.gc_pause_s, paused.next_done)
+                detail = (per_step, med, *paused,
+                          float(SLOW_STEP_CAUSES.index(cause)), *(host or ()))
             _fr.record("train.compile" if compile_step else "train.step",
                        self.steps, detail)
             if compile_step:
@@ -477,18 +741,20 @@ class StepRecorder:
                 # refreshes every 8 steps — a per-step O(1) compare, not a
                 # per-step sort (this path runs at millisecond step times).
                 if slow:
+                    self.slow_steps += 1
+                    # why, as far as the process can see: what its own
+                    # heartbeat, collector and queue say, and the host's
+                    # share of the step where /proc keeps it
                     self._slow_step = {
                         "step": self.steps,
                         "duration_s": per_step,
                         "median_s": med,
                         "ratio": per_step / med,
                         "time": self._wall(),
+                        **paused._asdict(),
+                        "cause": cause,
+                        **dict(zip(_HOST_PRESSURE, host or ())),
                     }
-                    if host is not None:
-                        # why, as far as the process can see: the host's
-                        # share of the step (absent off Linux)
-                        self._slow_step.update(zip(
-                            ("sched_wait_s", "steal_s", "iowait_s"), host))
                 self._recent_steps.append(per_step)
                 self._steps_since_median += 1
                 if (self._steps_since_median >= 8
@@ -525,11 +791,14 @@ class StepRecorder:
                 return None
             return self._clock() - self._last_step_at
 
-    def pop_slow_step(self) -> Optional[Dict[str, float]]:
+    def pop_slow_step(self) -> Optional[Dict[str, Any]]:
         """Latest pending slow-step flag (step slower than
-        profile_slow_step_factor x trailing median), cleared on read. The
-        watchdog polls this and answers with an automatic cluster-profile
-        capture + ``slow_step`` incident."""
+        profile_slow_step_factor x trailing median), cleared on read: its
+        seconds, the median's, ``_Paused``'s readings over its interval,
+        ``cause`` (``slow_step_cause``, the one text among numbers) and
+        ``_host_pressure``'s three where /proc has them. The watchdog polls
+        this and answers with an automatic cluster-profile capture +
+        ``slow_step`` incident."""
         with self._lock:
             out, self._slow_step = self._slow_step, None
             return out
@@ -604,6 +873,9 @@ class StepRecorder:
                 "compiles": self.compiles,
                 "dispatch_time_s": round(self.dispatch_s, 6),
                 "slot_wait_time_s": round(self.slot_wait_s, 6),
+                "slow_steps": self.slow_steps,
+                "host_gap_max_s": round(self.host_gap_max_s, 6),
+                "gc_pause_max_s": round(self.gc_pause_max_s, 6),
                 **self.step_gauges,
             }
         out["goodput"] = round(self.goodput(), 6)
@@ -841,7 +1113,11 @@ class DeviceTraceController:
 
                 path = self._trace_dir()
                 os.makedirs(path, exist_ok=True)
-                jax.profiler.start_trace(path)
+                # the program's spans and the device's lines, no Python
+                # frames: nothing reads them and they bury the host threads
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(path, profiler_options=options)
             except Exception:
                 return
             self._active = True

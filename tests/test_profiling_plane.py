@@ -279,6 +279,11 @@ def test_slow_step_triggers_incident_with_profile(monkeypatch, shutdown_only):
         assert found, "slow_step incident never published"
         inc = found[0]
         assert "median" in inc["detail"]
+        # why, as far as the recorder could see: a caller that timed the step
+        # itself had no watcher beside it, so the cause is not known
+        assert "cause unknown" in inc["detail"]
+        assert inc["slow_step"]["cause"] == "unknown" and inc["slow_step"]["next_done"] == -1.0
+        assert inc["slow_step"]["host_gap_s"] == inc["slow_step"]["gc_pause_s"] == 0.0
         # the incident carries the capture path, and the capture is a
         # loadable merged trace with CPU samples
         path = inc.get("profile_path")

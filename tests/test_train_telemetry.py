@@ -1,7 +1,9 @@
 """Step-level training telemetry (train/_telemetry.py): recorder math with
 a fake clock, the post-warmup jit-compile storm and its promotion to a
 jit_cache_miss_storm incident by the watchdog, the completion clock with
-handles whose readiness the test controls, the model configs' FLOP counts,
+handles whose readiness the test controls, what a slow step's record says of
+the host (the heartbeat's gap, the collector's pauses, the device's run-ahead,
+the cause they name), the model configs' FLOP counts,
 metric export through util.metrics, HBM absent-on-CPU, TrainStep
 integration, session.report auto-attach, the program's spans in a real
 profiler trace, and SPAN events landing in the timeline dump.
@@ -10,6 +12,7 @@ CPU-only (JAX_PLATFORMS=cpu via conftest); everything here rides the fast
 marker — the cluster tests use the tiniest possible model/loops.
 """
 
+import gc
 import glob
 import json
 import os
@@ -327,7 +330,8 @@ def test_a_slow_step_says_what_the_host_did_to_it(monkeypatch, waited):
     steal and iowait at every completion; a step five times the median is
     flagged with the three deltas over that step, zeros where the host did
     nothing (the device or the program was slow), and its flight-recorder
-    event carries them."""
+    event carries them after the numbers every flagged step has, under the
+    names `slow_step_from_detail` gives them back by."""
     from ray_tpu._private import flight_recorder
     from ray_tpu.train import _telemetry
 
@@ -352,9 +356,18 @@ def test_a_slow_step_says_what_the_host_did_to_it(monkeypatch, waited):
     assert slow["ratio"] == pytest.approx(5.0) and slow["duration_s"] == pytest.approx(0.5)
     assert slow["sched_wait_s"] == pytest.approx(waited)
     assert slow["steal_s"] == pytest.approx(waited / 8) and slow["iowait_s"] == 0.0
-    assert all(isinstance(v, float) for v in slow.values() if v is not slow["step"])
+    assert slow["cause"] in _telemetry.SLOW_STEP_CAUSES
+    assert all(isinstance(v, float) for k, v in slow.items() if k not in ("step", "cause"))
     last = [e for e in flight_recorder.dump() if e.get("event") == "train.step"][-1]
-    assert last["b"] == pytest.approx((0.5, waited, waited / 8, 0.0))
+    assert len(last["b"]) == len(_telemetry.SLOW_STEP_DETAIL) + 3
+    assert last["b"][:2] == pytest.approx((0.5, 0.1))
+    assert last["b"][-3:] == pytest.approx((waited, waited / 8, 0.0))
+    # through a worker's flight file and back: every number of the flag
+    again = _telemetry.slow_step_from_detail(json.loads(json.dumps(last))["b"])
+    assert again == {k: slow[k] for k in again}
+    assert set(slow) - set(again) == {"step", "ratio", "time"}
+    before = [e for e in flight_recorder.dump() if e.get("event") == "train.step"][-2]
+    assert _telemetry.slow_step_from_detail(before["b"]) is None  # its seconds alone
     # the step after it is not slow and carries nothing
     loop.dispatch()
     loop.complete_at(2.6)
@@ -382,6 +395,340 @@ def test_a_slow_step_off_linux_carries_no_host_numbers(monkeypatch):
     assert not {"sched_wait_s", "steal_s", "iowait_s"} & set(slow)
     loop.finish()
     assert len(reads) == 1  # a kernel that says nothing is asked once, not at every step
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("cause, numbers", [
+    # excess, host_gap_s, host_gap_cpu_s, gc_pause_s, next_done
+    ("gc", (2.0, 1.9, 1.9, 1.8, 1.0)),
+    ("host_frozen", (2.0, 2.0, 0.01, 0.0, 1.0)),
+    ("interpreter_held", (2.0, 1.5, 1.4, 0.05, 0.0)),
+    ("completion_late", (2.0, 0.012, 0.001, 0.0, 1.0)),
+    ("device", (2.0, 0.018, 0.002, 0.01, 0.0)),
+    ("unknown", (2.0, 0.6, 0.5, 0.3, 1.0)),
+])
+def test_the_cause_of_a_slow_step_is_a_rule_over_its_numbers(cause, numbers):
+    from ray_tpu.train import _telemetry
+
+    assert _telemetry.slow_step_cause(*numbers) == cause
+    assert cause in _telemetry.SLOW_STEP_CAUSES
+
+
+@pytest.mark.fast
+def test_the_rule_s_edges():
+    from ray_tpu.train._telemetry import slow_step_cause
+
+    # a sound host's heartbeat reads up to twice its sleep: no gap, whatever the excess
+    assert slow_step_cause(0.012, 0.019, 0.0, 0.0, 0.0) == "device"
+    # a gap that covers a fifth of the excess names nothing
+    assert slow_step_cause(2.0, 0.41, 0.4, 0.0, 1.0) == "unknown"
+    # a held interpreter with a fifth of the excess in collections is not told from them
+    assert slow_step_cause(2.0, 1.5, 1.5, 0.4, 1.0) == "unknown"
+    # no gap and no other step in flight: nothing to tell late from slow by
+    assert slow_step_cause(2.0, 0.011, 0.0, 0.0, -1.0) == "unknown"
+    assert slow_step_cause(0.0, 0.0, 0.0, 0.0, 1.0) == "unknown"
+
+
+def _steps_on_the_real_clock(rec, n, step_s=0.02):
+    """A loop that keeps one step in flight, each complete `step_s` after the
+    one before: the recorder's own clock and the host's instruments beside it."""
+    hs = [Handle()]
+    rec.dispatched(hs[0], started=rec.clock(), tokens=10)
+    for _ in range(n):
+        hs.append(Handle())
+        rec.dispatched(hs[-1], started=rec.clock(), tokens=10)
+        time.sleep(step_s)
+        hs.pop(0).complete()
+        rec.settle(30.0)
+    return hs
+
+
+def _paused_step(rec, hs, pause):
+    """One more step, with `pause()` run on this thread while it is in flight."""
+    hs.append(Handle())
+    rec.dispatched(hs[-1], started=rec.clock(), tokens=10)
+    pause()
+    hs.pop(0).complete()
+    rec.settle(30.0)
+    return rec.pop_slow_step()
+
+
+class _Bookings:
+    """What `_GcPauses` hands a watch, kept as it comes: the hook's own
+    reading of every collection, for a test to hold the intervals' against."""
+
+    step = None
+
+    def __init__(self):
+        self.collections = []
+
+
+def test_a_thread_that_holds_the_interpreter_shows_as_a_gap_with_cpu_burned():
+    """A thread of the process's own inside one C call that never lets go of
+    the interpreter lock (a sum over a range) for some tenths of a second: the
+    heartbeat cannot wake, the process burns CPU all the while, no collection
+    runs, and the step that was in flight says so. (On a box whose other
+    processes take its CPUs the process is itself held up, which is the
+    other cause and no fault: three tries.)"""
+    from ray_tpu.train import _telemetry
+
+    t0 = time.perf_counter()
+    sum(range(3_000_000))
+    n = int(3_000_000 * 0.4 / (time.perf_counter() - t0))
+
+    def hold():
+        t = threading.Thread(target=lambda: sum(range(n)))
+        t.start()
+        t.join(60)
+        assert not t.is_alive()
+
+    rec = StepRecorder(devices=[], emit_spans=False, emit_metrics=False)
+    gc.disable()  # no collection of another test's heap beside it
+    try:
+        hs = _steps_on_the_real_clock(rec, 16)
+        rec.pop_slow_step()  # a loaded box may have held a step of the sixteen up
+        for _ in range(3):
+            slow = _paused_step(rec, hs, hold)
+            if slow is not None and slow["cause"] == "interpreter_held":
+                break
+    finally:
+        gc.enable()
+        hs.pop(0).complete()
+        rec.settle(30.0)
+    assert slow is not None and slow["cause"] == "interpreter_held", slow
+    assert slow["duration_s"] > 0.1 and slow["host_gap_s"] > 0.1
+    assert slow["host_gap_cpu_s"] > 0.1 * slow["host_gap_s"]
+    assert slow["gc_pause_s"] == 0.0 and slow["gc_generation"] == -1.0
+    assert slow["next_done"] == 0.0  # the step after it was in flight, and not done
+    s = rec.summary()
+    assert s["slow_steps"] >= 1 and s["host_gap_max_s"] >= round(slow["host_gap_s"], 6)
+    assert s["gc_pause_max_s"] == 0.0
+
+
+def test_a_forced_collection_shows_as_a_pause_the_heartbeat_confirms():
+    """gc.collect() over a heap of several hundred thousand cycles while a
+    step is in flight: the hook times it, the heartbeat's gap over the same
+    stretch agrees with the hook's reading within 20% (and the heartbeat's
+    own resolution), and the step's cause is the collector."""
+    from ray_tpu.train import _telemetry
+
+    rec = StepRecorder(devices=[], emit_spans=False, emit_metrics=False)
+    booked = _Bookings()  # what the hook itself timed, beside the watcher's reading of it
+    _telemetry._gc_pauses.add(booked)
+    gc.disable()  # the one collection is the test's own
+    try:
+        heap = []
+        for _ in range(800_000):
+            cell = []
+            cell.append(cell)
+            heap.append(cell)
+        hs = _steps_on_the_real_clock(rec, 16)
+        rec.pop_slow_step()  # a loaded box may have held a step of the sixteen up
+        for attempt in range(3):  # and may wake the heartbeat late
+            slow = _paused_step(rec, hs, gc.collect)
+            if (slow is not None and slow["cause"] == "gc"
+                    and abs(slow["host_gap_s"] - slow["gc_longest_s"]) <= (
+                        0.2 * slow["gc_longest_s"] + 2 * _telemetry._HEARTBEAT_S)):
+                break
+        else:
+            raise AssertionError(f"the heartbeat's gap and the hook's reading disagree: {slow}")
+        assert slow["gc_generation"] == 2.0 and slow["gc_pause_s"] >= slow["gc_longest_s"] > 0.03
+    finally:
+        gc.enable()
+        _telemetry._gc_pauses.remove(booked)
+        hs.pop(0).complete()
+        rec.settle(30.0)
+        del heap
+    # the hook's own timing: the forced collections were generation 2's, one an
+    # attempt, and the last one's length is what the flag and the summary hold
+    forced = [took for _, took, generation in booked.collections if generation == 2]
+    assert len(forced) == attempt + 1
+    assert slow["gc_longest_s"] == pytest.approx(forced[-1] / 1e9, rel=0.2)
+    assert rec.summary()["gc_pause_max_s"] >= round(slow["gc_longest_s"], 6)
+
+
+@pytest.mark.fast
+def test_a_collection_still_under_way_counts_up_to_now_and_its_booking_the_rest():
+    """The watcher often runs before the collection that delayed it is booked
+    (the interpreter hands the lock over at the hook's `stop` call): the
+    interval takes the pause from its start to now, and the booking that
+    follows adds only what came after."""
+    from ray_tpu.train import _telemetry
+
+    pauses = _telemetry._GcPauses()
+    watch = _telemetry._HostWatch(step=7, pauses=pauses)
+    try:
+        assert pauses._watches == [watch] and pauses._hook in gc.callbacks
+        watch.take(-1.0)
+        started = time.perf_counter_ns()
+        pauses.open = (started, 2)  # a generation-2 collection begins
+        time.sleep(0.05)
+        first = watch.take(1.0)._asdict()
+        assert 0.05 <= first["gc_pause_s"] < 0.5 and first["gc_generation"] == 2.0
+        assert first["gc_longest_s"] == first["gc_pause_s"]
+        # booked 20 ms later, with a small collection after it
+        took = round(first["gc_pause_s"] * 1e9) + 20_000_000
+        watch.collections.append((started, took, 2))
+        watch.collections.append((started + took + 10_000_000, 1_000_000, 0))
+        pauses.open = None
+        second = watch.take(0.0)._asdict()
+        assert second["gc_pause_s"] == pytest.approx(0.020 + 0.001, abs=1e-6)
+        assert watch.take(0.0).gc_pause_s == 0.0  # and nothing a third time
+    finally:
+        watch.stop(join_s=5.0)
+    assert pauses._watches == [] and pauses._hook not in gc.callbacks
+    assert not watch._thread.is_alive()
+
+
+@pytest.mark.fast
+def test_a_collection_the_last_watch_left_open_is_not_the_next_watch_s():
+    """The last watch goes while a collection's start has been seen and its
+    stop has not (the hook is out of gc.callbacks by then): the next watch
+    finds nothing under way, and its first interval holds no pause."""
+    from ray_tpu.train import _telemetry
+
+    pauses = _telemetry._GcPauses()
+    first = _telemetry._HostWatch(step=1, pauses=pauses)
+    pauses._hook("start", {"generation": 2})
+    assert pauses.open is not None and pauses._span is not None
+    first.stop(join_s=5.0)
+    assert pauses.open is None and pauses._span is None
+    pauses._hook("stop", {"generation": 2})  # a stop that does arrive books nothing
+    second = _telemetry._HostWatch(step=2, pauses=pauses)
+    try:
+        time.sleep(0.02)
+        taken = second.take(-1.0)
+        assert taken.gc_pause_s == 0.0 and taken.gc_generation == -1.0
+        assert not second.collections
+    finally:
+        second.stop(join_s=5.0)
+
+
+def test_no_pause_is_lost_or_counted_twice_under_switching_threads():
+    """Three threads collect as fast as they can while this one takes the
+    interval's readings, the interpreter switching threads every 10 us: the
+    pauses the intervals took add up to the hook's own readings to the
+    nanosecond, whichever side of a collection's booking each take fell on."""
+    import sys
+
+    from ray_tpu.train import _telemetry
+
+    pauses = _telemetry._GcPauses()
+    watch = _telemetry._HostWatch(step=None, pauses=pauses)
+    booked = _Bookings()
+    pauses.add(booked)
+    stop = threading.Event()
+
+    def collect():
+        while not stop.is_set():
+            junk = [[] for _ in range(50)]
+            junk[0].append(junk)
+            gc.collect(0)
+
+    workers = [threading.Thread(target=collect) for _ in range(3)]
+    taken_ns = under_way = 0
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for w in workers:
+            w.start()
+        deadline = time.monotonic() + 1.0
+        while time.monotonic() < deadline:
+            under_way += pauses.open is not None
+            taken_ns += round(watch.take(-1.0).gc_pause_s * 1e9)
+    finally:
+        stop.set()
+        for w in workers:
+            w.join(30)
+        sys.setswitchinterval(interval)
+        watch.stop(join_s=5.0)
+    assert not any(w.is_alive() for w in workers)
+    taken_ns += round(watch.take(-1.0).gc_pause_s * 1e9)
+    booked_ns = sum(took for _, took, _ in booked.collections)
+    assert len(booked.collections) > 10 and booked_ns > 0
+    assert taken_ns == booked_ns, (taken_ns, booked_ns, under_way)
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("next_first, cause", [(True, "completion_late"), (False, "device")])
+def test_run_ahead_tells_a_late_completion_from_a_slow_device(next_first, cause):
+    """The step after the slow one is complete when the watcher's wait on the
+    slow one returns (the device went on, the host heard late), or is not
+    (the device took long): no host gap either way."""
+    clk = FakeClock()
+    rec = _recorder(clk)
+    asked, next_done = [], rec._next_done
+    rec._next_done = lambda: asked.append(rec.steps) or next_done()
+    loop = PipelinedLoop(clk, rec)
+    loop.dispatch()
+    for k in range(1, 21):
+        loop.dispatch()
+        loop.complete_at(0.1 * k)
+    assert asked == []  # a step that comes on time asks nothing of the one after it
+    loop.dispatch()
+    if next_first:
+        loop.in_flight[1].complete()
+    loop.complete_at(12.0)  # ten seconds late: far over what a loaded box's heartbeat reads
+    slow = rec.pop_slow_step()
+    assert asked == [20]
+    assert slow["next_done"] == float(next_first) and slow["cause"] == cause, slow
+    assert slow["host_gap_s"] < 1.0 and slow["gc_pause_s"] < 1.0
+    loop.finish()
+
+
+@pytest.mark.fast
+def test_a_step_timed_by_its_caller_is_flagged_with_no_host_numbers():
+    clk = FakeClock()
+    rec = _recorder(clk)
+    for _ in range(8):
+        rec.record_step(0.01)
+    rec.record_step(0.2)
+    slow = rec.pop_slow_step()
+    assert slow["cause"] == "unknown" and slow["next_done"] == -1.0
+    assert slow["host_gap_s"] == slow["gc_pause_s"] == 0.0
+    assert {"slow_steps", "host_gap_max_s", "gc_pause_max_s"} <= set(rec.summary())
+    assert rec.summary()["slow_steps"] == 1
+
+
+def test_two_recorders_come_and_go_and_leave_no_heartbeat_and_no_hook(monkeypatch):
+    """While either recorder has a watcher the process has one hook in
+    gc.callbacks and a heartbeat a watcher; when the watchers have ended
+    (nothing in flight for _WATCHER_IDLE_S) both are gone, and a recorder
+    stopped at exit leaves none either."""
+    from ray_tpu.train import _telemetry
+
+    def beats():
+        return [t for t in threading.enumerate() if t.name == "train-host-heartbeat"]
+
+    def hooks():
+        return [c for c in gc.callbacks if getattr(c, "__self__", None) is _telemetry._gc_pauses]
+
+    def gone(what):
+        deadline = time.monotonic() + 10
+        while what() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        return not what()
+
+    assert gone(beats) and not hooks()  # an earlier test's watcher has idled out
+    monkeypatch.setattr(_telemetry, "_WATCHER_IDLE_S", 0.05)
+    clk = FakeClock()
+    first, second = _recorder(clk), _recorder(clk)
+    loops = [PipelinedLoop(clk, first), PipelinedLoop(clk, second)]
+    for loop in loops:
+        loop.dispatch()
+    assert len(beats()) == 2 and len(hooks()) == 1
+    loops[0].complete_at(0.1)
+    assert gone(lambda: first._watcher)
+    assert gone(lambda: len(beats()) > 1) and len(hooks()) == 1  # the second's still run
+    loops[1].complete_at(0.2)
+    assert gone(lambda: second._watcher) and gone(beats) and not hooks()
+    assert first._host is None and second._host is None
+    # and again: a new watcher brings both back, _stop_watcher ends both at once
+    loops[0].dispatch()
+    assert len(beats()) == 1 and len(hooks()) == 1
+    loops[0].complete_at(0.3)
+    first._stop_watcher()
+    assert not beats() and not hooks() and first._watcher is None
 
 
 def test_host_pressure_reads_this_machine():
@@ -638,6 +985,7 @@ def test_program_spans_in_a_device_trace_window(monkeypatch, tmp_path):
             # begins, whatever the load (step 4's ends as the window closes)
             jax.block_until_ready(state)
             ts.telemetry.settle(30.0)
+            time.sleep(2.5 * _telemetry._HEARTBEAT_S)  # a tiny step is shorter than a tick
             report({"loss": 0.0})
             session.reports.get_nowait()
             session.ack()
@@ -649,10 +997,23 @@ def test_program_spans_in_a_device_trace_window(monkeypatch, tmp_path):
     by_name = {}
     for name, start, end, step, line in spans:
         # the watcher may still be on its (instant) wait for step 1, the
-        # compile call, when the window opens
-        first = 1 if name == "ray_tpu.train_step.wait" else 2
+        # compile call, when the window opens, and its heartbeat with it; a
+        # collection carries the step the watcher was on
+        first = 2 if name.startswith(("ray_tpu.train_step.", "ray_tpu.train.")) else 1
+        if name == "ray_tpu.train_step.wait":
+            first = 1
         assert isinstance(step, int) and first <= step <= 4, (name, step)
         by_name.setdefault(name.removeprefix("ray_tpu."), {})[step] = (start, end, line)
+    # the heartbeat ticks all through the window on a thread of its own, a
+    # span from one wake-up to the next
+    beats = sorted((start, end, line) for n, start, end, _, line in spans
+                   if n == "ray_tpu.host.heartbeat")
+    assert len(beats) >= 3 and len({line for _, _, line in beats}) == 1
+    assert all(end - start >= 0.9 * _telemetry._HEARTBEAT_S * 1e9 for start, end, _ in beats)
+    assert all(b[0] >= a[1] for a, b in zip(beats, beats[1:]))
+    assert beats[0][2] not in {line for n, _, _, _, line in spans if n != "ray_tpu.host.heartbeat"}
+    by_name.pop("host.heartbeat")
+    by_name.pop("host.gc", None)  # a collection may or may not fall in three tiny steps
     assert sorted(by_name) == [
         "train.report", "train.report.slot_wait", "train_step.dispatch",
         "train_step.jit", "train_step.record", "train_step.shard_batch",
