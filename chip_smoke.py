@@ -481,6 +481,62 @@ def _check_gated_norm_vs_plain(seed, on_tpu):
             "rel_err": errs, "norm_path": gated_norm.norm_path(width)}
 
 
+def _check_qk_prep_vs_plain(seed, on_tpu):
+    """ops/qk_prep.py's pair against `LlamaAttention`'s plain lines (the norm
+    a head, rotate-half, the repeat of the key-value heads and `_as_rows`) in
+    float32 at `highest`, at the three engaged cells' shapes, q (32 heads)
+    and k (4 heads to 32 rows), normed and not, same seed: the rows and the
+    gradients of x and the weight as max-abs error over the reference's
+    max-abs value."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import apply_rope, rms_norm, rope_angles
+    from ray_tpu.ops import attention, qk_prep
+
+    eps, out = 1e-6, []
+    shapes = ((2, 8192), (1, 16384)) if on_tpu else ((2, 40),)
+    for (b, t), (heads, rep), norm in ((s, h, n) for s in shapes for h in ((32, 1), (4, 8))
+                                       for n in (True, False)):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        x = jax.random.normal(ks[0], (b, t, heads * 128), jnp.bfloat16)
+        weight = 1 + 0.1 * jax.random.normal(ks[1], (128,), jnp.float32)
+        d_rows = jax.random.normal(ks[2], (b * heads * rep, t, 128), jnp.float32)
+        ang = rope_angles(128, 10000.0, jnp.arange(t))
+
+        def plain(x, weight):
+            q = x.astype(jnp.float32).reshape(b, t, heads, 128)
+            q = apply_rope(rms_norm(q, weight, eps) if norm else q, ang)
+            return attention._as_rows(jnp.broadcast_to(
+                q[:, :, :, None, :], (b, t, heads, rep, 128)).reshape(b, t, heads * rep, 128))
+
+        def kernels(x, weight):
+            return qk_prep.qk_prep(x, weight if norm else None, qk_prep.rope_tables(ang), rep=rep,
+                                   eps=eps, interpret=not on_tpu)
+
+        def run(form):
+            def loss(x, weight):
+                rows = form(x, weight)
+                return (rows.astype(jnp.float32) * d_rows).sum(), rows
+
+            with jax.default_matmul_precision("highest"):
+                grads, rows = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(x, weight)
+            return (rows, *grads)
+
+        errs = {}
+        for name, got, want in zip(("rows", "dx", "dweight"), run(kernels), run(plain)):
+            got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+            if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+                raise RuntimeError(f"qk_prep {name}: bad shape or non-finite values")
+            if norm or name != "dweight":
+                errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        if max(errs.values()) > ATTN_REL_TOL:
+            raise RuntimeError(f"qk_prep kernels vs the plain lines at {x.shape}, rep {rep}, "
+                               f"norm {norm} beyond {ATTN_REL_TOL}: {errs}")
+        out.append({"shape": list(x.shape), "rep": rep, "norm": norm, "rel_err": errs})
+    return out
+
+
 def _check_flash_mla_vs_plain(seed, on_tpu):
     """ops/attention.py's latent pair against the plain form in float32 at
     the benchmark's head widths (32 heads, scores 128 + 64 deep, values 128)
@@ -697,6 +753,16 @@ def _remat_plans():
     return plans
 
 
+# The cells whose attention layers take ops/qk_prep.py's pair as
+# `_flash_calls_by_cell` lowers them, and how many of their layers do
+# (`LlamaAttention.__call__` says when): every other cell 0. The four-chip
+# cell is lowered there on one device, where no `attn_fn` stands and its eight
+# rotary layers take the pair; under its own mesh the layer is handed
+# `attn_fn` and runs the plain lines (tests/test_mellum.py pins that step).
+QK_PREP_LAYERS = {"mellum2_12b_l4_ep4.t8192": 4, "keye_vl2_30b_l4_ep8.t16384": 4,
+                  "trinity_mini_l5_ep16.t8192": 5, "mistral_7b_l8.fsdp4_t8192": 8}
+
+
 def _flash_calls_by_cell(on_tpu):
     """The pallas calls of each benchmark cell's own step by name, lowered
     here from shapes alone (nothing is placed on the chip; a cell on four
@@ -713,7 +779,10 @@ def _flash_calls_by_cell(on_tpu):
     `mamba` layer's convolution (ops/short_conv.py) has causal_conv_bwd once
     and causal_conv_fwd twice: no plan names its output. A `mamba` layer of
     more than one group norms its gated output a group at a time
-    (ops/gated_norm.py): gated_norm_bwd once and gated_norm_fwd twice."""
+    (ops/gated_norm.py): gated_norm_bwd once and gated_norm_fwd twice. A
+    layer of `LlamaAttention` at heads of 128 that norms or turns q and k
+    (QK_PREP_LAYERS) has qk_prep_bwd twice, q's and k's, and qk_prep_fwd
+    twice where the plan saves `attn_q` and `attn_k`, else four times."""
     import collections
     from unittest import mock
 
@@ -750,6 +819,7 @@ def _flash_calls_by_cell(on_tpu):
         scan_fwd = scans * (1 if "ssm_y" in saved else 2)
         conv_fwd = convs * (1 if "conv_y" in saved else 2)
         by_group = scans if getattr(cfg, "ssm_groups", 1) > 1 else 0
+        prepped = 2 * QK_PREP_LAYERS.get(name, 0)
         if on_tpu and not (fwd == kinds["fused"] == cfg.n_layer - scans - convs - mixers_alone
                            and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans
                            and found["gated_conv_fwd"] == conv_fwd
@@ -757,7 +827,9 @@ def _flash_calls_by_cell(on_tpu):
                            and found["causal_conv_fwd"] == 2 * scans
                            and found["causal_conv_bwd"] == scans
                            and found["gated_norm_fwd"] == 2 * by_group
-                           and found["gated_norm_bwd"] == by_group):
+                           and found["gated_norm_bwd"] == by_group
+                           and found["qk_prep_bwd"] == prepped and found["qk_prep_fwd"] ==
+                           prepped * (1 if {"attn_q", "attn_k"} <= set(saved) else 2)):
             raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans and "
                                f"{convs} convolutions, calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:
@@ -841,6 +913,7 @@ def one_chip_loop(config):
     report["gated_conv_vs_plain"] = _check_gated_conv_vs_plain(config["seed"], on_tpu)
     report["causal_conv_vs_plain"] = _check_causal_conv_vs_plain(config["seed"], on_tpu)
     report["gated_norm_vs_plain"] = _check_gated_norm_vs_plain(config["seed"], on_tpu)
+    report["qk_prep_vs_plain"] = _check_qk_prep_vs_plain(config["seed"], on_tpu)
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["gated_attention_vs_plain"] = _check_gated_attention(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()

@@ -134,6 +134,15 @@ class RMSNorm(nn.Module):
         return gated_norm(x, gate, w, self.eps, self.groups, within)
 
 
+class _NormWeight(nn.Module):
+    """An RMSNorm's leaf alone, `<name>/weight` (width,) float32: for a layer
+    whose norm a kernel computes (`LlamaAttention._on_rows`)."""
+
+    @nn.compact
+    def __call__(self, width):
+        return self.param("weight", nn.initializers.ones, (width,), jnp.float32)
+
+
 def rope_angles(head_dim: int, theta: float, positions, inv_freq=None):
     """(T,) int positions -> (T, head_dim//2) fp32 angles; `inv_freq`
     (head_dim//2 floats) in place of the plain theta^(-2i/head_dim)."""
@@ -193,12 +202,39 @@ class LlamaAttention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         hd = cfg.head_dim
+        from ray_tpu.ops.attention import attention_path
+
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=cfg.dtype, name=name)
-        q = dense(cfg.n_head * hd, "wq")(x).reshape(B, T, cfg.n_head, hd)
-        k = dense(cfg.n_kv_head * hd, "wk")(x).reshape(B, T, cfg.n_kv_head, hd)
+        # heads of one vreg's 128 lanes that a layer norms or turns on their way
+        # into the flash calls stay where the projections wrote them: `_on_rows`
+        on_rows = (cfg.attn_fn is None and cfg.use_flash_attention and hd == 128
+                   and (self.qk_norm or self.rotary) and attention_path(T) == "flash")
+        heads = (lambda a, n: a) if on_rows else (lambda a, n: a.reshape(B, T, n, hd))
+        q = heads(dense(cfg.n_head * hd, "wq")(x), cfg.n_head)
+        k = heads(dense(cfg.n_kv_head * hd, "wk")(x), cfg.n_kv_head)
         v = dense(cfg.n_kv_head * hd, "wv")(x).reshape(B, T, cfg.n_kv_head, hd)
         if self.gate:
             g = checkpoint_name(dense(cfg.n_head * hd, "wg")(x), "attn_gate")
+        # a window as long as the sequence holds all of it
+        window = self.window if self.window is not None and self.window < T else None
+        if on_rows:
+            y = self._on_rows(x, q, k, v, pos_offset, window)
+        else:
+            y = self._on_heads(x, q, k, v, pos_offset, window)
+        y = y.reshape(B, T, cfg.n_head * hd)
+        if self.gate:
+            with jax.named_scope("attn.gate"):
+                open_ = jax.nn.sigmoid(g.astype(jnp.float32))
+                self.sow("attn_gate", "mean", open_.mean())
+                y = (y.astype(jnp.float32) * open_).astype(y.dtype)
+        return dense(C, "wo")(y)
+
+    @nn.nowrap  # no scope of its own: the layer's scopes are what they were
+    def _on_heads(self, x, q, k, v, pos_offset, window):
+        """The plain form, q, k, v and the result (B, T, H, D): the norm, the
+        rotary and the repeat of the key-value heads as XLA compiles them."""
+        cfg = self.config
+        B, T, _, hd = q.shape
         if self.qk_norm:
             with jax.named_scope("attn.qk_norm"):
                 q = RMSNorm(cfg.rms_eps, name="q_norm")(q)
@@ -222,8 +258,6 @@ class LlamaAttention(nn.Module):
             v = jnp.broadcast_to(v[:, :, :, None, :], (B, T, cfg.n_kv_head, rep, hd)
                                  ).reshape(B, T, cfg.n_head, hd)
 
-        # a window as long as the sequence holds all of it
-        window = self.window if self.window is not None and self.window < T else None
         if chosen is not None:
             if cfg.attn_fn is not None:
                 raise NotImplementedError("attention over selected keys runs on one device")
@@ -246,13 +280,37 @@ class LlamaAttention(nn.Module):
             att = jnp.where(mask[None, None], att, -1e30)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhts,bshd->bthd", att, v)
-        y = y.reshape(B, T, cfg.n_head * hd)
-        if self.gate:
-            with jax.named_scope("attn.gate"):
-                open_ = jax.nn.sigmoid(g.astype(jnp.float32))
-                self.sow("attn_gate", "mean", open_.mean())
-                y = (y.astype(jnp.float32) * open_).astype(y.dtype)
-        return dense(C, "wo")(y)
+        return y
+
+    @nn.nowrap
+    def _on_rows(self, x, q, k, v, pos_offset, window):
+        """The same through ops/qk_prep.py on a TPU: q and k (B, T, heads *
+        D) as `wq` and `wk` wrote them go normed, turned and repeated into
+        the rows the flash calls take, a pass each; the norms' leaves are
+        the plain form's."""
+        from ray_tpu.ops.attention import _as_rows, flash_attention_rows
+        from ray_tpu.ops.qk_prep import qk_prep, rope_tables
+
+        cfg = self.config
+        B, T, _, hd = v.shape
+        rep = cfg.n_head // cfg.n_kv_head
+        chosen = None if self.select is None else self.select(x, pos_offset)
+        w_q = w_k = tables = None
+        if self.qk_norm:
+            w_q, w_k = (_NormWeight(name=name)(hd) for name in ("q_norm", "k_norm"))
+        if self.rotary:
+            with jax.named_scope("attn.rope"):
+                ang = rope_angles(hd, cfg.rope_theta, jnp.arange(T) + pos_offset, self.inv_freq)
+                tables = rope_tables(ang, self.rope_scale)
+        with jax.named_scope("attn.qk_norm" if self.qk_norm else "attn.rope"):
+            q = qk_prep(q, w_q, tables, eps=cfg.rms_eps, scale=self.q_scale)
+            k = qk_prep(k, w_k, tables, rep=rep, eps=cfg.rms_eps)
+        v = _as_rows(jnp.broadcast_to(v[:, :, :, None, :], (B, T, cfg.n_kv_head, rep, hd)
+                                      ).reshape(B, T, cfg.n_head, hd))
+        if chosen is None:
+            return flash_attention_rows(q, k, v, cfg.n_head, window=window)
+        with jax.named_scope("attn.selected"):
+            return flash_attention_rows(q, k, v, cfg.n_head, select=chosen)
 
 
 class LlamaMLP(nn.Module):

@@ -1180,21 +1180,26 @@ def _flash(q, k, v, mask, mask_t, tiles, interpret):
     return _flash_fwd_rule(q, k, v, mask, mask_t, tiles, interpret)[0]
 
 
-def _flash_fwd_rule(q, k, v, mask, mask_t, tiles, interpret):
+def _rows_forward(rows, mask, mask_t, d, tiles, interpret):
+    """(o, residuals) of the forward call on q, k and v in rows (`_as_rows`;
+    any iterable, taken one operand at a time)."""
     # The backward's residuals by name, for a checkpoint policy to save
     # across a block's remat (models/remat.py): what only the kernel can
     # give, and its operands as it reads them (`_as_rows`). `attn_sel` is
     # what the backward needs of a selection, the transposed relation's
     # mask: saved, the indexer and the selection run once a layer. A name
     # that no policy asks for is an identity.
-    q4 = q.shape
-    q, k, v = (checkpoint_name(_as_rows(x), name) for x, name in
-               ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
+    q, k, v = (checkpoint_name(x, name) for x, name in zip(rows, ("attn_q", "attn_k", "attn_v")))
     if mask_t is not None:
         mask_t = checkpoint_name(mask_t, "attn_sel")
-    o, lse = _forward_call(q, k, v, mask, d=q4[3], tiles=tiles, interpret=interpret)
+    o, lse = _forward_call(q, k, v, mask, d=d, tiles=tiles, interpret=interpret)
     o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
-    return _as_heads(o, q4), (q, k, v, o, lse, mask_t)
+    return o, (q, k, v, o, lse, mask_t)
+
+
+def _flash_fwd_rule(q, k, v, mask, mask_t, tiles, interpret):
+    o, res = _rows_forward(map(_as_rows, (q, k, v)), mask, mask_t, q.shape[3], tiles, interpret)
+    return _as_heads(o, q.shape), res
 
 
 def _flash_bwd_rule(tiles, interpret, res, do):
@@ -1204,6 +1209,41 @@ def _flash_bwd_rule(tiles, interpret, res, do):
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_rows(q, k, v, mask, mask_t, tiles, interpret):
+    """`_flash` on heads of 128 lanes already in rows, (B * H, T, D) in and
+    out: no transpose on either side of either call."""
+    return _flash_rows_fwd_rule(q, k, v, mask, mask_t, tiles, interpret)[0]
+
+
+def _flash_rows_fwd_rule(q, k, v, mask, mask_t, tiles, interpret):
+    return _rows_forward((q, k, v), mask, mask_t, q.shape[2], tiles, interpret)
+
+
+def _flash_rows_bwd_rule(tiles, interpret, res, do):
+    *res, mask_t = res
+    return (*_backward_call(tuple(res), do, mask_t, tiles=tiles, interpret=interpret), None, None)
+
+
+_flash_rows.defvjp(_flash_rows_fwd_rule, _flash_rows_bwd_rule)
+
+
+def flash_attention_rows(q, k, v, heads, *, window=None, select=None, interpret=False):
+    """q/k/v (B * heads, T, D), a head of whole vregs a row as `_as_rows`
+    gives it (ops/qk_prep.py writes q and k so) -> (B, T, heads, D): the
+    causal call, over the last `window` keys alone, or with `select` =
+    (mask, mask_t, top_k) `flash_selected_attention`'s."""
+    rows, t, d = q.shape
+    mask, mask_t, top_k = select or (None, None, t)
+    if top_k >= t:
+        mask = mask_t = None
+        tiles = flash_tiles(heads, t, d, q.dtype, window)
+    else:
+        tiles = flash_tiles(heads, t, d, q.dtype, select=top_k)
+    o = _flash_rows(q, k, v, mask, mask_t, tiles, interpret)
+    return _as_heads(o, (rows // heads, t, heads, d))
 
 
 def flash_causal_attention(q, k, v, *, window=None, block_q=None, block_k=None,

@@ -207,7 +207,12 @@ def test_the_cell_s_step_selects_once_a_layer(monkeypatch):
     each the indexer's scores, the selection and the forward kernel once (the
     blocks' remat keeps `attn_sel`, `attn_out`, `attn_lse`), the one
     backward call, every call under the name that says k; no causal call
-    and none named for dq or dkv alone."""
+    and none named for dq or dkv alone; q and k of every layer through
+    ops/qk_prep.py's pair (normed and turned), the forward calls once a layer
+    since the plan keeps `attn_q` and `attn_k`, and q and k nowhere a float32
+    array on heads."""
+    from tests.test_qk_prep import heads_stay_where_written
+
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
@@ -218,9 +223,11 @@ def test_the_cell_s_step_selects_once_a_layer(monkeypatch):
         lowering_platforms=("tpu",)).as_text()
     assert "attn_sel" in remat.traced(ts.model.config).names
     calls = kernel_tally(text)
-    ours = {k: n for k, n in calls.items() if k.startswith(("flash_", "index_"))}
+    ours = {k: n for k, n in calls.items() if k.startswith(("flash_", "index_", "qk_prep_"))}
     assert ours == {"index_scores": 4, "index_select": 4, "flash_sel2048_fwd": 4,
-                    "flash_sel2048_bwd_fused": 4}, calls
+                    "flash_sel2048_bwd_fused": 4, "qk_prep_fwd": 8, "qk_prep_bwd": 8}, calls
+    assert {"attn_q", "attn_k"} <= set(remat.traced(ts.model.config).names)
+    heads_stay_where_written(text, 1, 16384, cfg.n_head, cfg.n_kv_head)
     # the plan keeps the expert layer's three products (PR 45): no grouped
     # matmul runs again under remat
     from tests.test_mellum import expert_calls
@@ -284,10 +291,11 @@ def test_the_fourth_old_cell_lowers_to_the_parent_s_step(monkeypatch):
 
 
 # The cell's own lowered step (B=1 x T=16384, one chip, a v5e's limit for the
-# remat rule) as tests/test_mellum.py:_step_text gives it, taken on PR 46's
-# parent's tree before `TrainStep` stopped knowing its families by name: a
-# change that means to leave this cell's program alone is held to it.
-KEYE_STEP = "707ba358f52d7356a1e04e9e83c7a45646ee28350bfa2bdce6165bab0c3e95dd"
+# remat rule) as tests/test_mellum.py:_step_text gives it, taken in PR 53,
+# which moved it by design (q and k go through ops/qk_prep.py's pair into the
+# selected calls on rows): a change that means to leave this cell's program
+# alone is held to it.
+KEYE_STEP = "994a12ac2c6cd4182a3e6de549151dded0d883b93b3c6105c8538ed31a85538f"
 
 
 def test_the_cell_lowers_to_its_pinned_step(monkeypatch):

@@ -231,13 +231,17 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
 # All three were pinned again in PR 51, which moved every flash call by
 # design: masked tiles are cut into sub-tiles of 128 (`FlashTiles.sub_fwd`, `.sub_bwd`) and
 # the windowed call takes the causal call's tile, 1,024 where it was 512.
+# The routed cell's again in PR 53, by design: q and k of its four layers (heads
+# of 128, turned, not normed) go through ops/qk_prep.py's pair into the flash
+# calls on rows: the last number, the layers that take the pair; the two dense
+# cells (gpt2's own attention; mistral's `attn_fn` under its mesh) take it in none.
 PINNED_STEPS = {
     "gpt2_small": ("b747484d7c5664494e19fcc6d7ed0bf5bfb55b05683d899de6fd65410e14e8ac", 32, 1024, 0, 12,
-                   ("attn_q", "attn_k", "attn_v", "mlp_up")),
+                   ("attn_q", "attn_k", "attn_v", "mlp_up"), 0),
     "mistral_7b_l8": ("8c8546e49b310caa025c93dccdea5374e541ea0a078d453c8a73679f2b3f8515", 1, 8192, 0, 8,
-                      ("mlp_up",)),
-    "mellum2_12b_l4_ep4": ("b16f165f7eaa8966d483f6465ffb2b1ad02871b1f40c84a4cc503eab73f0c828", 2, 8192, 3, 4,
-                           ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate", "moe_up")),
+                      ("mlp_up",), 0),
+    "mellum2_12b_l4_ep4": ("fb573ffce3fe857290ec62b1fe37857d2e5596d3ebabf476a068cc60a411783f", 2, 8192, 3, 4,
+                           ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate", "moe_up"), 4),
 }
 
 
@@ -262,7 +266,9 @@ def test_old_configurations_lower_to_the_parent_s_step(name, monkeypatch):
     layer's calls carry the window in their name. And the program is the
     pinned one: a change that means to leave the cells' programs alone is
     held to it."""
-    want, rows, seq_len, windowed, layers, saved = PINNED_STEPS[name]
+    from tests.test_qk_prep import heads_stay_where_written
+
+    want, rows, seq_len, windowed, layers, saved, prepped = PINNED_STEPS[name]
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
         sizes = json.load(f)
@@ -283,6 +289,12 @@ def test_old_configurations_lower_to_the_parent_s_step(name, monkeypatch):
     tail = {"fwd": "", "bwd_fused": "_flash_bwd_dq_flash_bwd_dkv"}
     assert calls == {**{f"flash_{k}{tail[k]}": n for k, n in kernels.items()},
                      **{win + k: windowed for k in kernels if windowed}}
+    # q's and k's call a layer that takes ops/qk_prep.py's pair: the forward's
+    # once, the plan keeps `attn_q` and `attn_k` there
+    tally = kernel_tally(text)
+    assert tally["qk_prep_fwd"] == tally["qk_prep_bwd"] == 2 * prepped
+    if prepped:
+        heads_stay_where_written(text, rows, seq_len, cfg.n_head, cfg.n_kv_head)
     assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
