@@ -578,23 +578,26 @@ def _check_flash_mla_vs_plain(seed, on_tpu):
 
 
 def _check_kda_vs_plain(seed, on_tpu):
-    """ops/kda.py's two kernels (bf16 operands, the gate made inside them)
+    """ops/kda.py's two kernels (bf16 operands, q and k as a convolution
+    leaves them: the heads' l2 norms and the gate made inside the kernels)
     against the recurrence step by step in float32 at `highest` matmul
-    precision, at the benchmark cell's shape (2, 8192, 32, 128), same seed:
-    the output, the state after the last token and the seven gradients (q, k,
-    v, the gate's pre-activation, A_log, dt_bias, beta), as max-abs error
-    over the reference's max-abs value; and the same for the chunked form in
-    jax.numpy on an eighth of the tokens (what a backend without the kernels
-    runs)."""
+    precision on operands normed in float32, at the benchmark cell's shape
+    (2, 8192, 32, 128), same seed: the output, the state after the last token
+    and the seven gradients (q, k, v, the gate's pre-activation, A_log,
+    dt_bias, beta), as max-abs error over the reference's max-abs value; and
+    the same for the chunked form in jax.numpy on an eighth of the tokens
+    (what a backend without the kernels runs, the norms before it)."""
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models.kimi_linear import L2_EPS
     from ray_tpu.ops import kda
 
     b, t, h, d = (2, 8192, 32, 128) if on_tpu else (1, 128, 2, 128)
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
-    l2 = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q, k = (l2(jax.random.normal(key, (b, t, h, d))).astype(jnp.bfloat16) for key in ks[:2])
+    # silu of a unit normal, as the convolution's output: lengths of 4 to 7 a head
+    q, k = (jax.nn.silu(jax.random.normal(key, (b, t, h, d))).astype(jnp.bfloat16)
+            for key in ks[:2])
     v, f, w = (jax.random.normal(key, (b, t, h, d), jnp.bfloat16) for key in ks[2:5])
     a_log = jnp.log(jax.random.uniform(ks[5], (h,), minval=1.0, maxval=16.0))
     dt = jnp.exp(jax.random.uniform(ks[6], (h, d), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
@@ -612,16 +615,17 @@ def _check_kda_vs_plain(seed, on_tpu):
 
     def gated(interpret):
         def form(*ops):
-            o, _, last = kda.kda_gated(*ops, interpret=interpret)
+            o, _, last = kda.kda_gated(*ops, l2_eps=L2_EPS, interpret=interpret)
             return o, last.swapaxes(-1, -2)
         return form
 
     def plain(q, k, v, f, a_log, dt_bias, beta):
-        return kda.kda_plain(q, k, v, kda.gate_log_decay(f, a_log, dt_bias), beta)
+        return kda.kda_plain(kda.l2norm(q, L2_EPS), kda.l2norm(k, L2_EPS), v,
+                             kda.gate_log_decay(f, a_log, dt_bias), beta)
 
     def chunked(q, k, v, f, a_log, dt_bias, beta):
-        o, _, last = kda.kda_chunked(q, k, v, kda.gate_log_decay(f, a_log, dt_bias), beta,
-                                     kda.CHUNK)
+        o, _, last = kda.kda_chunked(kda.l2norm(q, L2_EPS), kda.l2norm(k, L2_EPS), v,
+                                     kda.gate_log_decay(f, a_log, dt_bias), beta, kda.CHUNK)
         return o, last.swapaxes(-1, -2)
 
     names = ("o", "state", "dq", "dk", "dv", "df", "dA_log", "ddt_bias", "dbeta")

@@ -22,7 +22,14 @@ KDA mixer (`KimiDeltaAttention`), H heads of `kda_head_dim` (d_k = d_v = 128):
                                              a TPU, its bias a constant zero);
                                              the three matrices lie side by
                                              side in one leaf, `qkv_proj`, and
-                                             the three filters in `conv_kernel`
+                                             the three filters in `conv_kernel`;
+                                             l2norm_head(u) = u / sqrt(sum of a
+                                             head's 128 squares + `L2_EPS`) in
+                                             float32, rounded to the compute
+                                             dtype: computed by ops/kda.py from
+                                             the convolution's q and k, inside
+                                             kda_fwd and kda_bwd on a TPU and
+                                             before the chunked form elsewhere
     g = -exp(A_log_head) * softplus(W_f2 (W_f1 h) + dt_bias)
                                              (T, H, 128) float32, <= 0; the
                                              low rank is `kda_rank`;
@@ -231,21 +238,17 @@ class KimiDeltaAttention(nn.Module):
             w = self.param("conv_kernel", _conv_init, (cfg.kda_conv, 3 * inner), f32)
             _, q, k, v, _ = causal_conv_within(qkv, w, jnp.zeros((3 * inner,), f32), 0,
                                                (inner, 2 * inner))
-
-            def l2norm(u):
-                u = u.reshape(b, t, h, dk).astype(f32)
-                return (u * jax.lax.rsqrt(jnp.square(u).sum(-1, keepdims=True) + L2_EPS)
-                        ).astype(cfg.dtype)
-
-            q, k, v = l2norm(q), l2norm(k), v.reshape(b, t, h, dk)
+            q, k, v = (u.reshape(b, t, h, dk) for u in (q, k, v))
         with jax.named_scope("kda.gate"):
             a_log = self.param("A_log", _a_log_init, (h,), f32)
             dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), f32).reshape(h, dk)
             f = dense(inner, "f_b_proj")(dense(cfg.kda_rank, "f_a_proj")(x)).reshape(b, t, h, dk)
             beta = jax.nn.sigmoid(dense(h, "b_proj")(x).astype(f32))
         with jax.named_scope("kda.scan"):
-            # g = -exp(A_log) softplus(f + dt_bias): made inside the kernels where they run
-            o, _, last = kda.kda_gated(q, k, v, f, a_log, dt_bias, beta, cfg.kda_chunk)
+            # g = -exp(A_log) softplus(f + dt_bias) and the l2 norms of a head's q and k:
+            # made inside the kernels where they run
+            o, _, last = kda.kda_gated(q, k, v, f, a_log, dt_bias, beta, cfg.kda_chunk,
+                                       l2_eps=L2_EPS)
             # gauges of values no gradient is asked through: stopped before they are
             # made, or the gate's dead backward keeps f (128 MB a layer at the
             # benchmark's cell) from the forward pass to its own
@@ -316,17 +319,19 @@ class KimiLinearBlock(nn.Module):
 # and logsumexp, and the expert layers' choices and plans, `moe_plan`): the
 # delta rule's output and chunk states, which spare kda_fwd's second run,
 # 17.3 ms a KDA layer in the benchmark's cell on a v5e (my chip run, PR 54,
-# call 2: the traced step's four `remat` calls) for 0.625 GiB a layer (the
-# states 0.5 of it): 27.7 ms a GiB, not measured as a step's difference,
-# because the cell has no room for it: its step holds 13.32 GiB with the
-# first rung alone and the rule takes nothing more there. It is stated for
+# call 2: the traced step's four `remat` calls; 17.9 since the kernels norm
+# q and k, PR 55) for 0.625 GiB a layer (the states 0.5 of it): 27.7 ms a
+# GiB, not measured as a step's difference, because the cell has no room
+# for it: its step holds 12.44 GiB with the first rung alone (13.32 before
+# PR 55), the rule reckons 12.68, the rung is 2.5 GiB and the limit 13.5,
+# so the rule takes nothing more there. It is stated for
 # a shape that has the room (fewer layers, a shorter sequence, state split
 # over chips). The latent layer's operands, the shared expert's and the
 # dense MLP's products are rungs in models/kanana.py at these widths and
 # none here: they are one layer's, four layers' and one layer's, under 10 ms
-# of a 755 ms step by kanana's readings, the reckoning would take them as
-# free (smaller than the room the gradients take later) and the step
-# compiled for a v5e then holds 14.08 GiB, 0.65 over the reckoning
+# of a step by kanana's readings, the reckoning would take them as free
+# (smaller than the room the gradients take later) and the step compiled
+# for a v5e then held 14.08 GiB, 0.65 over the reckoning (PR 54's tree)
 # (tests/test_tpu_compile_kimi_linear.py holds the program as it is to 0.35).
 # The expert layer's three products are no rung, as in models/kanana.py.
 REMAT_RUNGS = ((("kda_out", "kda_states"), 27.7),)
@@ -369,19 +374,22 @@ def _block_bytes(cfg: KimiLinearConfig, itemsize: int) -> int:
     """What the largest half of a block's backward works in, bytes a token,
     from its widths (models/kanana.py's reckoning; a block's halves are
     rematerialised apart, so it is the larger and not the sum). A KDA half's:
-    the projection's three streams, the convolution's three, the normed q and
-    k, o and the gated o (ten arrays H x 128 wide in the compute dtype), each
-    with its gradient, three more of that width in float32 in the passes
-    round the kernels, and the chunk states (H x 128 x 128 float32 a chunk):
-    240 KB a token at the published widths in bf16. A routed half's: the
-    expert layer's buffers of a row an assignment that are as wide as the
-    stream; a dense one's the MLP's gate and up with their gradients. The
-    step compiled for a v5e at the benchmark's cell holds 13.78 GiB by the
-    compiler's count with the first rung alone and the chip's allocator read
-    13.32 (my chip run, PR 54, call 2), where this makes the rule reckon
-    13.43 (tests/test_remat.py, tests/test_tpu_compile_kimi_linear.py)."""
+    the projection's three streams, the convolution's three, o and the gated
+    o (eight arrays H x 128 wide in the compute dtype), each with its
+    gradient, two more of that width in float32 in the head norm and its gate
+    (`kda.norm`, XLA's), and the chunk states (H x 128 x 128 float32 a
+    chunk): 192 KB a token at the published widths in bf16. Until PR 55 the
+    normed q and k were arrays too, with their gradients and a third float32
+    pass round the kernels: 240 KB. A routed half's: the expert layer's
+    buffers of a row an assignment that are as wide as the stream; a dense
+    one's the MLP's gate and up with their gradients. The step compiled for
+    a v5e at the benchmark's cell holds 12.93 GiB by the compiler's count with
+    the first rung alone (13.78 before PR 55) and the chip's allocator read
+    12.44 (my chip run, PR 55, call 1; 13.32 before), where this makes the
+    rule reckon 12.68 (tests/test_remat.py,
+    tests/test_tpu_compile_kimi_linear.py)."""
     inner = cfg.kda_inner if KDA in cfg.layer_types else 0
-    mixer = (2 * 10 * itemsize + 3 * 4) * inner + 4 * inner * cfg.kda_head_dim // cfg.kda_chunk
+    mixer = (2 * 8 * itemsize + 2 * 4) * inner + 4 * inner * cfg.kda_head_dim // cfg.kda_chunk
     experts = cfg.top_k * 4 * cfg.n_embd * itemsize
     dense = 4 * cfg.intermediate * itemsize
     return max(mixer, experts if cfg.routed_layers else dense)

@@ -69,10 +69,25 @@ and the channels' dt_bias, make g = -a softplus(f + dt_bias) in float32 where
 they use it and give df, da and ddt_bias (the last two summed over a
 sequence's chunks in an output block that stays in VMEM), so no float32 array
 of g's size is written either: at (2, 8192, 32, 128) each is 268 MB, and a
-block's backward held four of them. The kernels take heads whose K and V are
-128 (a vector's lanes) and T padded to whole chunks (k = 0, beta = 0, g = 0
-leave a state as it was); any other shape, and any backend but a TPU, runs
-`kda_chunked`, differentiated by JAX.
+block's backward held four of them. Told `l2_eps`, `kda_gated` takes q and k
+as the layer's convolution wrote them, before the norm of a head (`l2norm`:
+u / sqrt(sum of the head's squares + eps), float32, rounded once to the
+compute dtype), and the kernels make that norm too: a grid step's q and k
+blocks are (C, 128) with one head's channels on the lanes, so the sum is a
+lane sum of what the step already holds in float32, the rounding is where
+XLA's was (the forward is that of the norm in jax.numpy and then the kernels,
+bit for bit in interpret mode), and the backward kernel ends with the norm's
+vjp on its float32 dq and dk, du = r (dn - n sum(dn n)) with r the rsqrt and
+n = u r unrounded, so dq and dk come out as the gradients of what came in.
+XLA normed on (B, T, H, 128) in float32, which under the TPU's tiling is no
+bitcast of the (B, T, H x 128) the convolution writes and the kernels read:
+seven float32 relayouts and passes a layer and pass, 105 of 754 ms of a step
+of the benchmark's cell (PERF.md section 6, PR 55). `kda` itself takes q and
+k normed, as `kda_plain` and `kda_chunked` do. The kernels take heads whose K
+and V are 128 (a vector's lanes) and T padded to whole chunks (k = 0,
+beta = 0, g = 0 leave a state as it was, and a k of zeros norms to zeros);
+any other shape, and any backend but a TPU, runs `kda_chunked`,
+differentiated by JAX, the norms in jax.numpy before it.
 
 Not here (PERF.md section 7): a state reset at a packed document's boundary,
 an initial state handed in, the pair under a mesh.
@@ -399,10 +414,34 @@ def _log_decay(g_ref, a_ref, dtb_ref, gated):
     return -a_ref[...] * _softplus(x), x
 
 
+def _normed(u, eps):
+    """(n, r) of u whose last axis is one head: r = rsqrt(the sum of the
+    head's squares + eps) and n = u r, both float32."""
+    uf = u.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(uf * uf, axis=-1, keepdims=True) + eps)
+    return uf * r, r
+
+
+def l2norm(u, eps):
+    """u over the root of (the sum of its last axis' squares + eps), in
+    float32 and rounded once to u's dtype: the layer's norm of a head's q and
+    k (fla's l2norm), as the kernels make it of a block and as `kda_gated`
+    makes it where they do not run."""
+    return _normed(u, eps)[0].astype(u.dtype)
+
+
+def _norm_pulled(dn, u, eps):
+    """`l2norm`'s vjp at u on the float32 cotangent dn of the normed block,
+    from n and r before n's rounding."""
+    nf, r = _normed(u, eps)
+    return r * (dn - nf * jnp.sum(dn * nf, axis=-1, keepdims=True))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, a_ref, dtb_ref, beta_ref, o_ref, st_ref, fin_ref,
-                s_acc, *, scale, gated):
+                s_acc, *, scale, gated, l2_eps):
     """One chunk of one head: o of the chunk, the state at its start written
-    out, the state at its end left in s_acc."""
+    out, the state at its end left in s_acc. With `l2_eps` q and k come as the
+    convolution wrote them and are normed here."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -412,8 +451,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, a_ref, dtb_ref, beta_ref, o_ref, st_
     St = s_acc[...]
     st_ref[0, 0] = St
     g, _ = _log_decay(g_ref, a_ref, dtb_ref, gated)
-    o, nxt = _chunk_fwd(q_ref[0], k_ref[0], v_ref[0], g, beta_ref[0, 0], St, scale,
-                        _tpu_roll, _dot_3pass)
+    q, k = q_ref[0], k_ref[0]
+    if l2_eps is not None:
+        q, k = l2norm(q, l2_eps), l2norm(k, l2_eps)
+    o, nxt = _chunk_fwd(q, k, v_ref[0], g, beta_ref[0, 0], St, scale, _tpu_roll, _dot_3pass)
     o_ref[0] = o.astype(o_ref.dtype)
     s_acc[...] = nxt
 
@@ -424,10 +465,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, a_ref, dtb_ref, beta_ref, o_ref, st_
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, a_ref, dtb_ref, beta_ref, do_ref, st_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, da_ref, ddtb_ref, dbeta_ref, ds_acc,
-                *, scale, gated):
+                *, scale, gated, l2_eps):
     """One chunk of one head, chunks last to first: the state's cotangent at
     the chunk's end in ds_acc on entry and at its start on exit; the rate's
-    and dt_bias's gradients summed over the chunks in their output blocks."""
+    and dt_bias's gradients summed over the chunks in their output blocks.
+    With `l2_eps` q and k are normed here and dq, dk are the gradients of what
+    came in."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -437,9 +480,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, a_ref, dtb_ref, beta_ref, do_ref, st
         ddtb_ref[...] = jnp.zeros(ddtb_ref.shape, ddtb_ref.dtype)
 
     g, x = _log_decay(g_ref, a_ref, dtb_ref, gated)
+    q, k = q_ref[0], k_ref[0]
+    if l2_eps is not None:
+        q, k = l2norm(q, l2_eps), l2norm(k, l2_eps)
     dq, dk, dv, dg, dbeta, d_start = _chunk_bwd(
-        q_ref[0], k_ref[0], v_ref[0], g, beta_ref[0, 0], st_ref[0, 0], do_ref[0],
-        ds_acc[...], scale, _tpu_roll, _dot_3pass)
+        q, k, v_ref[0], g, beta_ref[0, 0], st_ref[0, 0], do_ref[0], ds_acc[...], scale,
+        _tpu_roll, _dot_3pass)
+    if l2_eps is not None:
+        dq, dk = _norm_pulled(dq, q_ref[0], l2_eps), _norm_pulled(dk, k_ref[0], l2_eps)
     dq_ref[0], dk_ref[0] = dq.astype(dq_ref.dtype), dk.astype(dk_ref.dtype)
     dv_ref[0], dbeta_ref[0, 0] = dv.astype(dv_ref.dtype), dbeta
     ds_acc[...] = d_start
@@ -479,12 +527,12 @@ def _operands(q, k, v, g, a, dtb, beta):
             dtb.astype(_F32).reshape(1, h * kd), beta.astype(_F32).swapaxes(1, 2)[..., None])
 
 
-def _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, interpret):
+def _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, l2_eps, interpret):
     b, t, h, kd = q.shape
     vd, nc = v.shape[-1], t // chunk
     keys, values, lane, _, rate, state, final = _specs(nc, chunk, kd, vd, False)
     o, states, last = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=kd ** -0.5, gated=gated),
+        functools.partial(_fwd_kernel, scale=kd ** -0.5, gated=gated, l2_eps=l2_eps),
         grid=(b, h, nc),
         in_specs=[keys, keys, values, keys, lane, lane, rate],
         out_specs=[values, state, final],
@@ -497,12 +545,12 @@ def _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, interpret):
     return o.reshape(b, t, h, vd), states.reshape(b, nc, h, vd, kd), last.reshape(b, h, vd, kd)
 
 
-def _bwd_call(q, k, v, g, a, dtb, beta, states, do, chunk, gated, interpret):
+def _bwd_call(q, k, v, g, a, dtb, beta, states, do, chunk, gated, l2_eps, interpret):
     b, t, h, kd = q.shape
     vd, nc = v.shape[-1], t // chunk
     keys, values, lane, lane_sum, rate, state, _ = _specs(nc, chunk, kd, vd, True)
     dq, dk, dv, dg, da, ddtb, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=kd ** -0.5, gated=gated),
+        functools.partial(_bwd_kernel, scale=kd ** -0.5, gated=gated, l2_eps=l2_eps),
         grid=(b, h, nc),
         in_specs=[keys, keys, values, keys, lane, lane, rate, values, state],
         out_specs=[keys, keys, values, keys, lane_sum, lane_sum, rate],
@@ -524,22 +572,22 @@ def _bwd_call(q, k, v, g, a, dtb, beta, states, do, chunk, gated, interpret):
             dbeta[..., 0].swapaxes(1, 2).astype(beta.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _kda(q, k, v, g, a, dtb, beta, chunk, gated, interpret):
-    return _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _kda(q, k, v, g, a, dtb, beta, chunk, gated, l2_eps, interpret):
+    return _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, l2_eps, interpret)
 
 
-def _kda_fwd_rule(q, k, v, g, a, dtb, beta, chunk, gated, interpret):
+def _kda_fwd_rule(q, k, v, g, a, dtb, beta, chunk, gated, l2_eps, interpret):
     # what is dear to compute again and cheap to hold, by name for a remat
     # policy (models/remat.py), as ops/ssd.py names ssm_y, ssm_states
-    o, states, last = _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, interpret)
+    o, states, last = _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, l2_eps, interpret)
     o, states = checkpoint_name(o, "kda_out"), checkpoint_name(states, "kda_states")
     return (o, states, last), (q, k, v, g, a, dtb, beta, states)
 
 
-def _kda_bwd_rule(chunk, gated, interpret, res, cot):
+def _kda_bwd_rule(chunk, gated, l2_eps, interpret, res, cot):
     do = cot[0]  # the states are handed out for a gauge; nothing differentiates them
-    return _bwd_call(*res, do, chunk, gated, interpret)
+    return _bwd_call(*res, do, chunk, gated, l2_eps, interpret)
 
 
 _kda.defvjp(_kda_fwd_rule, _kda_bwd_rule)
@@ -557,6 +605,10 @@ def kda_path(seq_len: int, key_dim: int, value_dim: int, chunk: int = CHUNK) -> 
 
 
 def _padded(t, chunk, *arrays):
+    """The arrays with T padded with zeros to whole chunks. A padded step has
+    k = 0, beta = 0 and g = 0, which leave a state as it was; where the
+    kernels norm q and k themselves, a row of zeros norms to zeros (0 times
+    eps ** -0.5) and its gradient is finite."""
     if chunk & (chunk - 1) or chunk < 2:
         raise ValueError(f"a chunk is a power of two of steps, not {chunk}")
     pad = -t % chunk
@@ -568,14 +620,15 @@ def kda(q, k, v, g, beta, chunk=CHUNK, *, interpret=None):
     """o (b, T, H, V) in v's dtype, the float32 states at each chunk's start,
     transposed, (b, chunks, H, V, K), and the state after the last step,
     (b, H, V, K): the module docstring's recurrence from q and k (b, T, H, K;
-    the layer's, normed), v (b, T, H, V), the log decays g (b, T, H, K; <= 0,
+    the layer's, normed: `kda_gated` is the entry that norms), v (b, T, H, V),
+    the log decays g (b, T, H, K; <= 0,
     float32) and beta (b, T, H). T is padded to whole chunks. `interpret`
     forces the kernels (True: in interpret mode), for the tests."""
     t, (h, kd) = q.shape[1], q.shape[2:]
     q, k, v, g, beta = _padded(t, chunk, q, k, v, g.astype(_F32), beta)
     if interpret is not None or kda_path(t, kd, v.shape[-1], chunk) == "pallas":
         o, states, last = _kda(q, k, v, g, jnp.ones((h,), _F32), jnp.zeros((h, kd), _F32), beta,
-                               chunk, False, bool(interpret))
+                               chunk, False, None, bool(interpret))
     else:
         o, states, last = kda_chunked(q, k, v, g, beta, chunk)
     return o[:, :t], states, last
@@ -587,17 +640,23 @@ def gate_log_decay(f, a_log, dt_bias):
     return -jnp.exp(a_log.astype(_F32))[:, None] * jax.nn.softplus(f.astype(_F32) + dt_bias)
 
 
-def kda_gated(q, k, v, f, a_log, dt_bias, beta, chunk=CHUNK, *, interpret=None):
+def kda_gated(q, k, v, f, a_log, dt_bias, beta, chunk=CHUNK, *, l2_eps=None, interpret=None):
     """`kda` with the log decays made from the gate's pre-activation f (b, T,
     H, K; any float dtype), A_log (H,) and dt_bias (H, K): `gate_log_decay`,
     inside the kernels where they run (no float32 array of g's size is
-    written), before the chunked form elsewhere."""
+    written), before the chunked form elsewhere. With `l2_eps` q and k are the
+    layer's before their norm (the convolution's outputs) and `l2norm` is
+    applied to a head of each with that eps: inside the kernels too, on the
+    block a grid step holds (no float32 array of q's size is written, and dq,
+    dk come out as the gradients of what came in), before `kda` elsewhere."""
     t, (h, kd) = q.shape[1], q.shape[2:]
     if interpret is None and kda_path(t, kd, v.shape[-1], chunk) != "pallas":
+        if l2_eps is not None:
+            q, k = l2norm(q, l2_eps), l2norm(k, l2_eps)
         return kda(q, k, v, gate_log_decay(f, a_log, dt_bias), beta, chunk)
     q, k, v, beta = _padded(t, chunk, q, k, v, beta)
     if q.shape[1] != t:  # a padded step's g is 0: softplus of something very negative
         f = jnp.pad(f, ((0, 0), (0, q.shape[1] - t), (0, 0), (0, 0)), constant_values=-3e4)
     o, states, last = _kda(q, k, v, f, jnp.exp(a_log.astype(_F32)), dt_bias, beta, chunk, True,
-                           bool(interpret))
+                           l2_eps, bool(interpret))
     return o[:, :t], states, last

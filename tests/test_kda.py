@@ -111,32 +111,80 @@ def test_kernels_in_interpret_mode_are_both_forms(dtype, chunk, t):
             assert _rel(a, b) < tol, name
 
 
+L2_EPS = 1e-6
+
+
+def _before_the_norm(q, k):
+    """q and k as a convolution leaves them: no unit length, a head's lengths
+    a hundred apart, and some steps' k all zeros."""
+    b, t, h, d = q.shape
+    by_head = jnp.logspace(-1, 1, h)[:, None]
+    ks = jax.random.split(jax.random.PRNGKey(31), 2)
+    q = (q.astype(F32) * by_head * jnp.exp(jax.random.normal(ks[0], (b, t, h, 1)))).astype(q.dtype)
+    k = (k.astype(F32) * by_head[::-1] * jnp.exp(jax.random.normal(ks[1], (b, t, h, 1)))
+         ).astype(k.dtype)
+    return q, k.at[:, 7].set(0).at[0, 70:73, 1].set(0)
+
+
+@pytest.mark.parametrize("norm", ["handed_in", "inside"])
 @pytest.mark.parametrize("dtype", [F32, jnp.bfloat16])
-def test_the_gate_made_inside_the_kernels(dtype):
+def test_the_gate_made_inside_the_kernels(dtype, norm):
     """`kda_gated` under `interpret=True`: g = -exp(A_log) softplus(f +
     dt_bias) made in the kernels from f in the operands' dtype, against the
     recurrence on `gate_log_decay`'s g: values, the last state and the seven
-    gradients, A_log's and dt_bias's summed over the chunks in the call."""
+    gradients, A_log's and dt_bias's summed over the chunks in the call.
+    With the norm inside (`l2_eps`), q and k come in as a convolution leaves
+    them: against the recurrence on operands normed in jax.numpy, and against
+    today's order, the norm in jax.numpy and then the kernels: the forward
+    to the bit, the gradients of q and k one rounding of the cotangent
+    apart; T is no whole number of chunks, so the padded steps' k is zero,
+    and so are some steps' inside the sequence."""
     b, t, h, d = 2, 100, 2, 128
     q, k, v, _, beta = _operands(b, t, h, d, d, seed=21, dtype=dtype)
+    eps = None if norm == "handed_in" else L2_EPS
+    if eps is not None:
+        q, k = _before_the_norm(q, k)
     f = jax.random.normal(jax.random.PRNGKey(3), (b, t, h, d)).astype(dtype)
     a_log = jnp.log(jnp.array([1.5, 7.0]))
     dt_bias = -3.0 + 0.5 * jax.random.normal(jax.random.PRNGKey(4), (h, d))
     ops = (q, k, v, f, a_log, dt_bias, beta)
     tol = 3e-5 if dtype == F32 else 2e-2
+    normed = (lambda u: u) if eps is None else (lambda u: kda.l2norm(u, eps))
     plain = lambda q, k, v, f, a_log, dt_bias, beta: kda.kda_plain(
-        q, k, v, kda.gate_log_decay(f, a_log, dt_bias), beta)
+        normed(q), normed(k), v, kda.gate_log_decay(f, a_log, dt_bias), beta)
+    inside = lambda *o, **kw: kda.kda_gated(*o, l2_eps=eps, **kw)
     with jax.default_matmul_precision("highest"):
         want, state = plain(*ops)
-        got, _, last = kda.kda_gated(*ops, interpret=True)
+        got, states, last = inside(*ops, interpret=True)
         assert _rel(got, want) < tol and _rel(last.swapaxes(-1, -2), state) < tol
         # off the TPU the same call runs the chunked form on the same g
-        assert _rel(kda.kda_gated(*ops)[0], want) < tol
+        assert _rel(inside(*ops)[0], want) < tol
         w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-        ours = _grads(lambda *o: kda.kda_gated(*o, interpret=True), ops, w, range(7))
-        for name, a, b in zip("q k v f A_log dt_bias beta".split(), ours,
-                              _grads(plain, ops, w, range(7))):
-            assert a.shape == b.shape and _rel(a, b) < tol, name
+        ours = _grads(lambda *o: inside(*o, interpret=True), ops, w, range(7))
+        names = "q k v f A_log dt_bias beta".split()
+        wanted = _grads(plain, ops, w, range(7))
+        for name, a, b in zip(names, ours, wanted):
+            assert a.shape == b.shape and np.isfinite(np.asarray(a, np.float32)).all(), name
+            assert _rel(a, b) < tol, name
+        if eps is None:
+            return
+        # today's order: XLA norms, the kernels take the normed pair
+        outside = lambda q, k, *o, **kw: kda.kda_gated(normed(q), normed(k), *o, **kw)
+        for a, b in zip((got, states, last), outside(*ops, interpret=True)):
+            np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+        for name, a, b in zip(names, ours, _grads(lambda *o: outside(*o, interpret=True),
+                                                  ops, w, range(7))):
+            if name in ("q", "k"):  # the norm's vjp on a float32 cotangent, not a rounded one
+                assert _rel(a, b) < (1e-6 if dtype == F32 else 1e-2), name
+            else:
+                np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                              np.asarray(b, np.float32), err_msg=name)
+        # and the chunked form, which norms before it (any backend but a TPU), with its gradients
+        for name, a, b in zip(names, _grads(inside, ops, w, range(7)), wanted):
+            assert _rel(a, b) < tol, name
+        # a row of zeros norms to zeros; its gradient is dn / sqrt(eps)
+        assert not np.asarray(normed(k)[:, 7], np.float32).any()
+        assert np.asarray(ours[1][:, 7], np.float32).any()
 
 
 def test_the_state_is_carried_across_a_call_s_chunks():

@@ -125,18 +125,24 @@ DROPPED = {
 }
 
 
-@pytest.mark.parametrize("what", sorted(DROPPED) + ["carry", "solve", "conv", "latent_rotary",
-                                                    "shared_expert", "scaling"])
+@pytest.mark.parametrize("what", sorted(DROPPED) + ["k_norm", "carry", "solve", "conv",
+                                                    "latent_rotary", "shared_expert", "scaling"])
 def test_the_comparison_catches_what_is_dropped(seeded, what, monkeypatch):
     """A program whose delta rule decays by head or not at all, takes beta as
-    one, drops the carried state at a chunk's edge or the solve's
-    off-diagonal, whose convolution is left out, whose
+    one, takes q at twice its normed length (the operands `kda.kda` is handed
+    are normed: off the TPU `kda_gated` norms before it) or k as the
+    convolution left it, drops the carried state at a chunk's edge or the
+    solve's off-diagonal, whose convolution is left out, whose
     latent layer turns its 64, or which leaves out the shared expert or the
     routed scaling is outside the loss's tolerance of the test above."""
     sizes, params, idx, targets, held, ref_loss, _ = seeded
     cfg = FAMILY.build(sizes, "float32")
     if what in DROPPED:
         monkeypatch.setattr(kda, "kda", _changed_kda(DROPPED[what]))
+    elif what == "k_norm":  # q normed, k handed on as the convolution left it
+        real = kda.kda_gated
+        monkeypatch.setattr(kda, "kda_gated", lambda q, k, *rest, l2_eps: real(
+            kda.l2norm(q, l2_eps), k, *rest))
     elif what == "carry":
         cfg = dataclasses.replace(cfg, kda_chunk=32)
         real = kda._chunk_fwd
@@ -296,9 +302,8 @@ def test_parameters_of_the_cell():
     assert 3 * 8192 * 32 * 320 / flops == pytest.approx(0.109, abs=1e-3)
 
 
-def _cell_step_text(monkeypatch):
-    from tests.test_mellum import _step_text
-
+def _cell_step(monkeypatch):
+    """(cfg, the cell's step traced for a TPU on this box under a v5e's limit)."""
     for mod in (attention, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     monkeypatch.setattr(kda, "_on_tpu", lambda: True)
@@ -307,13 +312,32 @@ def _cell_step_text(monkeypatch):
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
-    return cfg, _step_text(ts, state, {"idx": tok, "targets": tok})
+    return cfg, ts._step.trace(state, {"idx": tok, "targets": tok})
+
+
+def _float32_under(jaxpr, scope, size, outer=""):
+    """What the equations of a jaxpr under the named scope `scope`, those of
+    the jaxprs inside them too (a remat's, a custom rule's, a kernel's body),
+    give in float32 with `size` entries or more: (primitive, shape)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        if scope in stack:
+            found += [(eqn.primitive.name, v.aval.shape) for v in eqn.outvars
+                      if getattr(v.aval, "dtype", None) == jnp.float32 and v.aval.size >= size]
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += _float32_under(inner, scope, size, stack)
+    return found
 
 
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
-# rule), as tests/test_mellum.py:_step_text gives it, taken on PR 54's own tree:
-# the program the chip runs of PERF.md section 6 were made with.
-KIMI_LINEAR_STEP = "286e7f945d6ef2a7802ef892565b4950fdbb5b8416d2cfec8857acad73ca41b3"
+# rule), as tests/test_mellum.py:_step_text gives it, taken on PR 55's own tree
+# (the l2 norms of q and k inside kda_fwd and kda_bwd): the program the chip
+# runs of PERF.md section 6 were made with.
+KIMI_LINEAR_STEP = "6944a91058016339e208c34627effdb963ad7f9cdaa93346547cdf7851aad582"
 
 
 def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monkeypatch):
@@ -321,8 +345,15 @@ def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monke
     each with kda_bwd once and kda_fwd as often as the remat plan runs it
     (once where it holds `kda_out` and `kda_states`, twice where not), the
     convolution pair a KDA layer, the latent pair once in the one latent
-    layer, megablox's calls in four routed layers."""
-    cfg, text = _cell_step_text(monkeypatch)
+    layer, megablox's calls in four routed layers. Between the convolution
+    and the delta rule nothing is float32 at q's size, forward, second
+    forward or backward: the heads' l2 norms are the kernels' (PR 55; the
+    parent's step held 104 such results under `kda.conv`, 26 a layer), while the
+    head norm of o under `kda.norm` still is XLA's and still has them."""
+    from tests.test_mellum import _traced_text
+
+    cfg, traced = _cell_step(monkeypatch)
+    text = _traced_text(traced)
     calls = kernel_tally(text)
     assert calls.pop("kernel") and "@gmm" in text and "@tgmm" in text
     plan = remat.traced(cfg)
@@ -331,6 +362,9 @@ def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monke
                      "causal_conv_bwd": 4, "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1,
                      "moe_token_sum": 4 * 2 * 2}, calls
     assert not set(KEPT_PRODUCTS) & set(plan.names)
+    q_size = 2 * 8192 * cfg.kda_inner
+    assert not _float32_under(traced.jaxpr.jaxpr, "kda.conv", q_size)
+    assert _float32_under(traced.jaxpr.jaxpr, "kda.norm", q_size)
     assert hashlib.sha256(text.encode()).hexdigest() == KIMI_LINEAR_STEP
 
 
@@ -344,11 +378,11 @@ def test_remat_plan_of_the_cell():
     # beside 8.98 GiB of state the delta rule's outputs (2.5 GiB over four layers) have no room
     assert chosen.names == first and not set(chosen.names) & set(KEPT_PRODUCTS)
     assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
-    # the chip's allocator read 13.318 GiB of this step (my chip run, PR 54, call 2)
-    assert chosen.reckoned_bytes / GIB == pytest.approx(13.43, abs=0.01)
+    # the chip's allocator read 12.436 GiB of this step (my chip run, PR 55, call 1; 13.318 before)
+    assert chosen.reckoned_bytes / GIB == pytest.approx(12.68, abs=0.01)
     tokens = 2 * 8192
-    assert chosen.block_bytes == tokens * ((2 * 10 * 2 + 3 * 4) * 4096 + 4 * 4096 * 128 // 64) \
-        == tokens * 245_760
+    assert chosen.block_bytes == tokens * ((2 * 8 * 2 + 2 * 4) * 4096 + 4 * 4096 * 128 // 64) \
+        == tokens * 196_608
     # the first rung: the latent layer's output and logsumexp, one layer of five, and
     # the choices and the plan: five int32 and a bool an assignment, four routed layers of five
     assert chosen.layer_bytes == (tokens * 32 * 128 * 2 // 5 + tokens * 32 * 4 // 5

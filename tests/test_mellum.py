@@ -250,7 +250,10 @@ def _step_text(ts, state, batch):
     (it holds source lines) taken out, and the step's jaxpr, which holds the
     kernels' bodies as equations (and the checkpoint policy as a function's
     repr: its address is taken out)."""
-    traced = ts._step.trace(state, batch)
+    return _traced_text(ts._step.trace(state, batch))
+
+
+def _traced_text(traced):
     lowered = traced.lower(lowering_platforms=("tpu",)).as_text()
     return (re.sub(r'\\22body\\22: \\22[^\\]*\\22', "body", lowered)
             + re.sub(r" at 0x[0-9a-f]+", "", str(traced.jaxpr)))

@@ -12,8 +12,8 @@ from tests._tpu_compile import _CUSTOM_CALL
 def test_delta_rule_kernels_compile_at_the_cell_s_shape(one_chip):
     """kimi_linear_l5_ep32.t8192's KDA layers: 32 heads of 128 over (2, 8192)
     positions in chunks of 64, forward and backward, each a pallas call under
-    its name; what the forward leaves for the backward is the chunk states,
-    537 MB."""
+    its name, the gate and the heads' l2 norms made inside as the model asks;
+    what the forward leaves for the backward is the chunk states, 537 MB."""
     from ray_tpu.ops import kda
 
     shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
@@ -22,7 +22,7 @@ def test_delta_rule_kernels_compile_at_the_cell_s_shape(one_chip):
     beta = shape((2, 8192, 32), jnp.float32)
 
     def loss(*ops):
-        return kda.kda_gated(*ops, interpret=False)[0].astype(jnp.float32).sum()
+        return kda.kda_gated(*ops, l2_eps=1e-6, interpret=False)[0].astype(jnp.float32).sum()
 
     c = jax.jit(jax.grad(loss, argnums=range(7))).lower(
         head, head, head, head, a_log, dt_bias, beta).compile()
@@ -40,16 +40,21 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     the rule takes the first rung alone at this shape (the delta rule's
     outputs do not fit beside 8.98 GiB of state), the program holds within
     the error the reckoning has shown of what it reckoned (tests/test_remat.py:
-    0.35 GiB under to 0.85 over; the chip's allocator read 13.32 GiB of this
+    0.35 GiB under to 0.85 over; the chip's allocator read 12.44 GiB of this
     step), four KDA layers run kda_bwd once and kda_fwd twice, the
-    convolution's pair beside them, one layer the latent pair, and the
-    bias's update is part of the one program."""
+    convolution's pair beside them, one layer the latent pair, the bias's
+    update is part of the one program, and under `kda.conv` the compiled
+    step has no float32 array of q's size in either layout (PR 55: the
+    heads' l2 norms are the kernels'; what stays there beside the
+    convolution's calls is the bf16 split into q, k and v and the three
+    gradients put side by side)."""
     import numpy as np
     from jax.sharding import Mesh
 
     from ray_tpu.models import remat
     from ray_tpu.ops import attention, kda, short_conv
     from ray_tpu.parallel.train_step import TrainStep
+    from ray_tpu.train._device_profile import scope_table
     from tests._tpu_compile import GIB, _kinds, _live_bytes, _step_args, cell_config
 
     for mod in (attention, kda, short_conv):
@@ -69,3 +74,8 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
         "kda_fwd": 8, "kda_bwd": 4, "causal_conv_fwd": 8, "causal_conv_bwd": 4,
         "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1}, kinds
     assert kinds["gmm"] and kinds["tgmm"]
+    conv = [kind for scope, _, _, _, kind in scope_table(c.as_text())["rows"].values()
+            if "kda.conv" in scope]
+    assert len(conv) > 12 and not [kind for kind in conv if any(
+        shape in kind for shape in ("f32[2,8192,4096]", "f32[2,8192,32,128]",
+                                    "f32[2048,8,32,128]"))], conv
