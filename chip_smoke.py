@@ -653,6 +653,54 @@ def _check_kda_vs_plain(seed, on_tpu):
     return report
 
 
+def _check_sscan_vs_recurrence(seed, on_tpu):
+    """ops/selective_scan.py's two kernels (bf16 u, B and C, float32 steps)
+    against the recurrence step by step in float32 on the same operands, at
+    the benchmark cell's widths (5,120 channels of 16 states) over 1,024
+    positions, eight chunks, with steps and rates as the model's
+    initialisation gives them (states carried across every chunk), same
+    seed: the output, the chunk states and the six gradients, as max-abs
+    error over the reference's max-abs value. This holds what the cell's
+    scalar loss averages away (a lost carry, decays in bf16: PERF.md section
+    7)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import selective_scan as ss
+
+    b, t, c, n = (1, 1024, 5120, 16) if on_tpu else (1, 256, 128, 16)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    u, w = (jax.random.normal(k, (b, t, c), jnp.bfloat16) for k in ks[:2])
+    bm, cm = (jax.random.normal(k, (b, t, n), jnp.bfloat16) for k in ks[2:4])
+    delta = jnp.exp(jax.random.uniform(ks[4], (b, t, c), minval=jnp.log(1e-3),
+                                       maxval=jnp.log(1e-1)))
+    a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (c, n))
+    d = 1 + 0.1 * jax.random.normal(ks[5], (c,))
+
+    def run(form, ops):
+        def loss(*ops):
+            y, states = form(*ops)
+            return (y.astype(jnp.float32) * w.astype(jnp.float32)).sum(), (y, states)
+
+        grads, out = jax.jit(jax.grad(loss, argnums=range(6), has_aux=True))(*ops)
+        return (*out, *grads)
+
+    ops = (u, delta, a, bm, cm, d)
+    kernels = run(lambda *ops: ss.selective_scan(*ops, interpret=not on_tpu), ops)
+    plain = run(ss.selective_scan_plain, [x.astype(jnp.float32) for x in ops])
+    errs = {}
+    for name, got, want in zip(("y", "states", "du", "ddelta", "dA", "dB", "dC", "dD"),
+                               kernels, plain):
+        got = got.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"sscan {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"sscan kernels vs the recurrence beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": [b, t, c, n], "chunk": ss.chunk_of(t), "rel_err": errs,
+            "scan_path": ss.scan_path(t, c, n)}
+
+
 def _check_gated_attention(seed, on_tpu):
     """models/llama.py's `LlamaAttention` as models/afmoe.py's blocks tell it
     (a norm over each head of q and k, the output gated by sigmoid(W_g x)),
@@ -858,7 +906,11 @@ def _flash_calls_by_cell(on_tpu):
     (ops/gated_norm.py): gated_norm_bwd once and gated_norm_fwd twice. A
     layer that is a delta rule over a state (`kda`) has kda_bwd once, kda_fwd
     once where the plan saves the chunk states (`kda_states`), else twice,
-    and the convolution's pair as a `mamba` layer's. A
+    and the convolution's pair as a `mamba` layer's. A `mamba` layer of
+    a family whose scan's decay differs by state (a configuration with
+    `ssm_rank`: Mamba-1) has sscan_bwd once, and sscan_fwd once where the
+    plan saves `sscan_y`, in place of ssd's pair; a gated memory unit
+    (`gmu`) has no call of its own. A
     layer of `LlamaAttention` at heads of 128 that norms or turns q and k
     (QK_PREP_LAYERS) has qk_prep_bwd twice, q's and k's, and qk_prep_fwd
     twice where the plan saves `attn_q` and `attn_k`, else four times."""
@@ -894,17 +946,21 @@ def _flash_calls_by_cell(on_tpu):
         layer_kinds = list(getattr(cfg, "layer_types", ()))
         scans, convs = layer_kinds.count("mamba"), layer_kinds.count("conv")
         mixers_alone = layer_kinds.count("experts")
-        deltas = layer_kinds.count("kda")
+        deltas, units = layer_kinds.count("kda"), layer_kinds.count("gmu")
+        selective = scans if hasattr(cfg, "ssm_rank") else 0  # ops/selective_scan.py's pair
         saved = remat.traced(cfg).names
-        scan_fwd = scans * (1 if "ssm_y" in saved else 2)
+        scan_fwd = (scans - selective) * (1 if "ssm_y" in saved else 2)
         conv_fwd = convs * (1 if "conv_y" in saved else 2)
         by_group = scans if getattr(cfg, "ssm_groups", 1) > 1 else 0
         prepped = 2 * QK_PREP_LAYERS.get(name, 0)
         if on_tpu and not (fwd == kinds["fused"] == (cfg.n_layer - scans - convs - mixers_alone
-                                                     - deltas)
+                                                     - deltas - units)
+                           and found["sscan_fwd"] == selective * (1 if "sscan_y" in saved else 2)
+                           and found["sscan_bwd"] == selective
                            and found["kda_fwd"] == deltas * (1 if "kda_states" in saved else 2)
                            and found["kda_bwd"] == deltas
-                           and found["ssd_fwd"] == scan_fwd and found["ssd_bwd"] == scans
+                           and found["ssd_fwd"] == scan_fwd
+                           and found["ssd_bwd"] == scans - selective
                            and found["gated_conv_fwd"] == conv_fwd
                            and found["gated_conv_bwd"] == convs
                            and found["causal_conv_fwd"] == 2 * (scans + deltas)
@@ -1000,6 +1056,7 @@ def one_chip_loop(config):
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["gated_attention_vs_plain"] = _check_gated_attention(config["seed"], on_tpu)
     report["kda_vs_plain"] = _check_kda_vs_plain(config["seed"], on_tpu)
+    report["sscan_vs_recurrence"] = _check_sscan_vs_recurrence(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()
     report["index_select_vs_top_k"] = _check_selection(config["seed"], on_tpu)

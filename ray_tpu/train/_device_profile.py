@@ -53,13 +53,16 @@ CLASSES = ("matmul", "kernel", "copy", "collective", "elementwise")
 # themselves are `attn.core`); `moe.shared` the expert every token passes
 # through beside the routed ones; `kda` a delta-rule mixer whole
 # (models/kimi_linear.py: `kda.in_proj`, `.conv`, `.gate`, `.scan`, `.norm`,
-# `.out_proj`); `conv` a gated short convolution's mixing
+# `.out_proj`); `gmu` a gated memory unit, which gates another layer's scan
+# output by this layer's stream, and `attn.cross` a layer that attends over
+# another layer's keys and values, its projections and its kernels
+# (models/phi4_flash.py); `conv` a gated short convolution's mixing
 # and its two projections; `norm` holds the norms and the residual stream's own ops
 # beside them (a block's adds and pins, which XLA fuses with the norms; `norm.post`,
 # a sublayer's output normed before its add: models/afmoe.py);
 # `optimizer` the clip and the global norm with AdamW.
-GROUPS = ("embed", "attn.proj", "attn.core", "mla", "mlp", "moe", "moe.shared", "ssm", "kda",
-          "conv", "norm", "head", "loss", "optimizer", "collective", "unscoped")
+GROUPS = ("embed", "attn.proj", "attn.core", "attn.cross", "mla", "mlp", "moe", "moe.shared",
+          "ssm", "gmu", "kda", "conv", "norm", "head", "loss", "optimizer", "collective", "unscoped")
 TOP_ROWS = 5  # (group, pass) rows in what rides a report and the GCS record
 SCOPE_ROWS = 40  # scope rows printed for a terminal (--json holds them all)
 KIND_ROWS = 20  # kinds of instruction kept, largest first
@@ -129,7 +132,7 @@ def scope_of(op_name: str) -> Tuple[str, str]:
 
 _EMBED = frozenset(("wte", "wpe", "tok_emb"))
 _HEAD = frozenset(("lm_head", "wte.attend", "tok_emb.attend"))
-_ATTN_PROJ = frozenset(("c_attn", "c_proj", "wq", "wk", "wv", "wo"))
+_ATTN_PROJ = frozenset(("c_attn", "c_proj", "wq", "wk", "wv", "wo", "qkv"))
 _BLOCK = frozenset(("h", "p"))
 
 
@@ -157,6 +160,10 @@ def group_of(scope: str, cls: str = "elementwise") -> str:
         return "moe"
     if any(p == "mamba" or p.startswith("ssm.") for p in parts):
         return "ssm"
+    if any(p == "gmu" or p.startswith("gmu.") for p in parts):
+        return "gmu"  # a gated memory unit: another layer's scan output gated by this stream
+    if "cross" in parts:
+        return "attn.cross"  # a layer that reads another layer's K and V: its projections and core
     if any(p == "kda" or p.startswith("kda.") for p in parts):
         return "kda"  # a delta-rule mixer whole: its projections, convolution, gates, scan, norm
     if any(p == "conv" or p.startswith("conv.") for p in parts):

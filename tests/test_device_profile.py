@@ -123,6 +123,26 @@ OP_NAMES = {
                      "jit(_take)/gather", "h/moe/moe.experts", "fwd", "moe"),
     "cond_branch": ("jit(train_step)/jvp(Mellum)/h_0/moe/branch_1_fun/moe.combine/gather",
                     "h/moe/moe.combine", "fwd", "moe"),
+    "memory_unit_gate": (
+        "jit(train_step)/jvp(Phi4Flash)/p_0/h_3/gmu/gmu.gate/mul", "p/h/gmu/gmu.gate", "fwd", "gmu"),
+    "memory_unit_backward": (
+        "jit(train_step)/transpose(jvp(Phi4Flash))/p_0/jvp(Phi4Flash)/p_0/checkpoint/h_3/gmu/"
+        "gmu.out_proj/out_proj/dot_general", "p/h/gmu/gmu.out_proj/out_proj", "bwd", "gmu"),
+    "cross_attention_kernel": (
+        "jit(train_step)/jvp(Phi4Flash)/p_0/h_4/cross/attn.cross/flash_fwd/pallas_call",
+        "p/h/cross/attn.cross/flash_fwd", "fwd", "attn.cross"),
+    "cross_attention_query": (
+        "jit(train_step)/jvp(Phi4Flash)/p_0/h_4/cross/wq/dot_general", "p/h/cross/wq", "fwd",
+        "attn.cross"),
+    "self_attention_fused_projection": (
+        "jit(train_step)/jvp(Phi4Flash)/p_0/h_2/attn/qkv/dot_general", "p/h/attn/qkv", "fwd",
+        "attn.proj"),
+    "self_attention_difference": (
+        "jit(train_step)/transpose(jvp(Phi4Flash))/p_0/jvp(Phi4Flash)/p_0/checkpoint/"
+        "rematted_computation/h_0/attn/attn.diff/rsqrt", "p/h/attn/attn.diff", "remat", "attn.core"),
+    "selective_scan_kernel": (
+        "jit(train_step)/jvp(Phi4Flash)/p_0/h_1/mamba/ssm.scan/sscan_fwd/pallas_call",
+        "p/h/mamba/ssm.scan/sscan_fwd", "fwd", "ssm"),
     "loop_body": ("jit(train_step)/jvp(Mellum)/h_0/attn/indexer/attn.select/while/body/add",
                   "h/attn/indexer/attn.select", "fwd", "attn.core"),
 }
@@ -185,6 +205,10 @@ def _tiny(family):
         from ray_tpu.models.kimi_linear import KimiLinearConfig
 
         return KimiLinearConfig.tiny(num_held=4), True
+    if family == "phi4_flash":
+        from ray_tpu.models.phi4_flash import Phi4FlashConfig
+
+        return Phi4FlashConfig.tiny(), True
     from ray_tpu.models.granite import GraniteConfig
 
     return GraniteConfig.tiny(), True
@@ -199,7 +223,7 @@ def _compiled_text(cfg, t=128):  # an indexed layer packs its mask 128 keys to a
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_moe", "mellum", "mellum_indexed",
                                     "granite", "lfm2", "kanana", "nemotron_h", "afmoe",
-                                    "kimi_linear"])
+                                    "kimi_linear", "phi4_flash"])
 def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     """The tiny configuration's step, compiled here: every scheduled
     instruction has a group of the one vocabulary and a pass, few are
@@ -224,8 +248,18 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     want = {"gpt2": "mlp", "llama": "mlp", "gpt2_moe": "moe", "mellum": "moe",
             "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv",
             "kanana": "moe.shared", "nemotron_h": "moe.shared", "afmoe": "moe.shared",
-            "kimi_linear": "kda"}[family]
+            "kimi_linear": "kda", "phi4_flash": "gmu"}[family]
     assert want in groups
+    if family == "phi4_flash":  # five kinds of block: each kind's scopes, the cross layer apart
+        scopes = {r[0] for r in rows.values()}
+        for scope in ("ssm.in_proj", "ssm.conv", "ssm.x_proj", "ssm.dt", "ssm.scan", "ssm.gate",
+                      "ssm.out_proj", "gmu.in_proj", "gmu.gate", "gmu.out_proj", "attn.window",
+                      "attn.full", "attn.cross", "attn.diff"):
+            assert any(scope in s.split("/") for s in scopes), scope
+        assert {"ssm", "gmu", "attn.cross", "mlp"} <= groups
+        assert {dp.group_of(s) for s in scopes if "attn.cross" in s.split("/")} == {"attn.cross"}
+        assert {dp.group_of(s) for s in scopes if "attn.full" in s.split("/")} == {"attn.core"}
+        assert not [n for n in unscoped if rows[n][0].startswith("p")], unscoped  # none a block's
     if family == "kimi_linear":  # the delta-rule mixer by its six scopes, beside a latent layer
         scopes = {r[0] for r in rows.values()}
         for scope in ("kda.in_proj", "kda.conv", "kda.gate", "kda.scan", "kda.norm",

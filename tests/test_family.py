@@ -97,7 +97,8 @@ def test_a_family_stated_in_a_test_file_trains_through_the_step():
         _telemetry.set_current_recorder(None)
 
 
-FAMILY_WORDS = {"moe_load", "moe_router", "attn_keys", "attn_gate", "ssm_stats", "kda_stats", "losses",
+FAMILY_WORDS = {"moe_load", "moe_router", "attn_keys", "attn_gate", "ssm_stats", "kda_stats", "attn_stats",
+                "losses",
                 SELECTION_BIAS}
 
 

@@ -57,6 +57,11 @@ WANT = {
     # one latent layer of five, at kanana's widths with nothing turned; the four KDA
     # layers' calls are no flash calls (tests/test_kimi_linear.py tallies them)
     "kimi_linear_l5_ep32.t8192": (2, 8192, 32, 128, {"flash_mla_fwd": 1, "flash_mla_bwd_fused": 1}),
+    # differential attention's maps as 40 heads of 128 (q and k 64 lanes beside 64 zeros):
+    # a window layer, and a full and a cross layer on one K/V (bench/shape_functions/
+    # flash_diff.py counts them by the model's widths; tests/test_bench_phi4_flash.py)
+    "phi4_mini_flash_l5.t16384": (1, 16384, 40, 128, {
+        "flash_fwd": 2, BWD: 2, "flash_win512_fwd": 1, "flash_win512_bwd_fused": 1}),
 }
 # the width of the score's second part, whose key all heads share, where a
 # cell's calls are the latent pair
@@ -103,6 +108,14 @@ OLD_KINDS = {
             "flash_win2048_fwd custom-call -> (bf16[64,8192,128], f32[64,1,8192])",
         "flash_win2048_bwd_fused": "flash_win2048_bwd_fused custom-call -> "
                                    "(bf16[64,8192,128], bf16[64,8192,128], bf16[64,8192,128])"},
+    # nor this one: its own first trace's (my chip run, PR 57, call 1)
+    "phi4_mini_flash_l5.t16384": {
+        "flash_fwd": "flash_fwd custom-call -> (bf16[40,16384,128], f32[40,1,16384])",
+        BWD: f"{BWD} custom-call -> "
+             "(bf16[40,16384,128], bf16[40,16384,128], bf16[40,16384,128])",
+        "flash_win512_fwd": "flash_win512_fwd custom-call -> (bf16[40,16384,128], f32[40,1,16384])",
+        "flash_win512_bwd_fused": "flash_win512_bwd_fused custom-call -> "
+                                  "(bf16[40,16384,128], bf16[40,16384,128], bf16[40,16384,128])"},
 }
 # the shape function that each per-kernel roofline of bench/layer_metrics
 # names for a call it matches

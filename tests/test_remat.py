@@ -31,6 +31,7 @@ GATE_UP, OUT = ("moe_gate", "moe_up"), ("moe_out",)  # ops/moe.py:KEPT_PRODUCTS
 UP_OUT = ("moe_up", "moe_out")  # ops/moe.py:RELU2.products
 CONV, LATENT, SHARED = ("conv_bcu", "conv_y"), ATTN + ("attn_q_shared", "attn_k_shared"), ("shared_up",)
 GATE = ("attn_gate",)  # models/llama.py:LlamaAttention's gate projection (models/afmoe.py)
+SSCAN = ("sscan_y", "sscan_states")  # ops/selective_scan.py's output and chunk states
 # cell: configuration, (B, T) of its traffic, and the names the rule takes
 # on a v5e after the first rung (the four routed cells' since PR 45, which
 # named the expert layer's products and fitted the `block` term again)
@@ -52,6 +53,8 @@ CELLS = {
     # the first rung alone: 8.98 GiB of state, each block's halves under a remat of their own
     # and an expert layer's buffers of every assignment leave no rung room (models/kimi_linear.py)
     "kimi_linear_l5_ep32.t8192": ("kimi_linear_l5_ep32", (2, 8192), ()),
+    # one Mamba-1 layer's scan output and states (0.2 GiB); the five MLPs' products (3.1) have no room
+    "phi4_mini_flash_l5.t16384": ("phi4_mini_flash_l5", (1, 16384), SSCAN),
 }
 # (cell, names saved after the first rung): the allocator's peak in GiB of
 # that step on a v5e (my chip runs, PR 33, calls 1-4: PERF.md section 6; one
@@ -105,6 +108,8 @@ READINGS = {
     # my chip run, PR 55, call 1: two untraced seeds alike (the traced run 12.573); 13.318 before
     # PR 55 took the normed q and k and the float32 passes round them out of a KDA half
     ("kimi_linear_l5_ep32.t8192", ()): 12.436,
+    # my chip run, PR 57, call 1 (the traced run: the step's own live bytes and reservation)
+    ("phi4_mini_flash_l5.t16384", SSCAN): 12.082,
 }
 # The reckoning against those readings: at most 0.35 GiB under (mistral, the
 # first rung alone) and 0.84 over (gpt2_small: its 16 bytes a parameter and
@@ -228,6 +233,7 @@ UNSEEN = {
     "nemotron3_nano_l9_ep16.t8192": dict(n_routed_experts=16),
     "trinity_mini_l5_ep16.t8192": dict(num_experts=16),
     "kimi_linear_l5_ep32.t8192": dict(num_experts=16),
+    "phi4_mini_flash_l5.t16384": dict(num_hidden_layers=7, layers_kept=[15, 16, 17, 18, 19, 20, 21]),
 }
 
 
