@@ -202,13 +202,15 @@ def test_host_freeze_does_not_kill_the_node():
         ray_tpu.shutdown()
 
 
-@pytest.mark.timeout(300)
-@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("chips", [
+    # 165 s: the one-chip loop runs every kernel's check against the plain
+    # lines in interpret mode, each of which its own test file holds, fast
+    pytest.param(1, marks=[pytest.mark.slow, pytest.mark.timeout(300)]), 4])
 def test_chip_smoke_rehearsal_passes_and_never_says_ok(chips):
     """--rehearse drives the smoke's control flow on CPU devices (tiny model,
     pallas in interpret mode) through init -> TPU lease -> JaxTrainer ->
-    TrainStep; it must pass here before chip time is spent, and must never
-    end in the ok line."""
+    TrainStep; it must pass here before chip time is spent (`-m "slow or not
+    slow"` for the one-chip loop too), and must never end in the ok line."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--rehearse",

@@ -526,6 +526,7 @@ def _tiny_gpt2_step():
     return ts, state, {"idx": idx, "targets": np.roll(idx, -1, 1)}
 
 
+@pytest.mark.usefixtures("compiled_afresh")  # the profile joins the trace to the HLO the compile stored
 def test_a_window_ends_in_a_profile(monkeypatch, tmp_path, shutdown_only):
     """request_device_trace round three steps of a tiny TrainStep (forced on
     the CPU): device_profile.json beside the trace, the GCS record with its
@@ -610,6 +611,7 @@ def test_no_profile_work_and_no_file_while_no_window_is_armed(monkeypatch, tmp_p
     assert not any(dp.PROFILE_FILE in files for _, _, files in os.walk(tmp_path))
 
 
+@pytest.mark.usefixtures("compiled_afresh")  # the profile joins the trace to the HLO the compile stored
 def test_the_tool_reduces_a_trace_taken_by_anyone(tmp_path, capsys):
     """A trace started by the caller (as the benchmark's --trace 1 run
     does), reduced by `python -m ray_tpu.train._device_profile <dir>`."""

@@ -129,7 +129,6 @@ def run() -> dict:
     return results
 
 
-@pytest.mark.timeout(600)
 def test_envelope_quick_floors():
     r = run()
 

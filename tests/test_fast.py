@@ -133,22 +133,38 @@ def test_placement_group(fast_cluster):
 
 
 def test_multi_node_spread(fast_cluster):
-    # Quiesce first: stragglers from earlier probes (the wait test's slow
-    # task) skew placement and make the spill assertion flaky.
-    deadline = time.time() + 30
-    while time.time() < deadline:
-        if ray_tpu.available_resources().get("CPU", 0) >= 4.0:
-            break
-        time.sleep(0.5)
+    """Six 1-CPU tasks on two 2-CPU nodes: each holds its CPU until both
+    nodes have reported, so a node's third task has to spill to the other.
+    No sleep stands in for "concurrently": under a loaded host the tasks
+    of a timed version ran one after another on one node."""
+
+    @ray_tpu.remote(num_cpus=0)
+    class Seen:
+        def __init__(self):
+            self.nodes = set()
+
+        def add(self, node):
+            self.nodes.add(node)
+
+        def count(self):
+            return len(self.nodes)
 
     @ray_tpu.remote(num_cpus=1)
-    def node_of():
-        time.sleep(2)  # hold the CPU so the tasks must run concurrently
-        return ray_tpu.get_runtime_context().get_node_id()
+    def node_of(seen):
+        node = ray_tpu.get_runtime_context().get_node_id()
+        ray_tpu.get(seen.add.remote(node))
+        deadline = time.time() + 90  # a spill that never comes fails the test, not hangs it
+        while ray_tpu.get(seen.count.remote()) < 2 and time.time() < deadline:
+            time.sleep(0.1)
+        return node
 
-    # 6 concurrent 1-CPU tasks must spill across both 2-CPU nodes
-    nodes = set(ray_tpu.get([node_of.remote() for _ in range(6)]))
+    seen = Seen.remote()
+    # alive before any task asks for it: a worker that looks an actor up while
+    # it is still pending has twice waited out a 30 s poll for the news
+    assert ray_tpu.get(seen.count.remote()) == 0
+    nodes = set(ray_tpu.get([node_of.remote(seen) for _ in range(6)]))
     assert len(nodes) == 2, nodes
+    ray_tpu.kill(seen)
 
 
 def test_runtime_env_env_vars(fast_cluster):

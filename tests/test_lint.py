@@ -4,12 +4,17 @@ Each rule is exercised against a SYNTHETIC mini-repo (its own contract
 files + seeded violations) so the assertions pin exact rule ids and
 file:line anchors, independent of the real package's contents; the tier-1
 test at the bottom then runs the full linter over the real ray_tpu/ and
-asserts zero non-baseline findings — the same gate CI runs.
+asserts zero non-baseline findings — the same gate CI runs. Below that, the
+tests' own harness (tests/conftest.py) is held to what it says of itself.
 """
 
 import json
 import os
+import shutil
+import subprocess
+import sys
 import textwrap
+from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
@@ -426,3 +431,90 @@ def test_cli_json_and_exit_codes(tmp_path, capsys):
     with pytest.raises(SystemExit) as ei:
         scripts.main(["lint", "--root", REPO_ROOT, "--no-baseline"])
     assert ei.value.code == 1
+
+
+# ------------------------------------------------- the tests' own harness
+
+
+def _pytest(cwd, *args):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+    return subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider", "-p", "no:randomly",
+         "-p", "no:xdist", *args],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=170)
+
+
+def _scratch_suite(tmp_path, **files):
+    """A directory under tests/conftest.py's rules, outside the tree."""
+    shutil.copy(os.path.join(REPO_ROOT, "tests", "conftest.py"), tmp_path)
+    for name, text in files.items():
+        _write(str(tmp_path), name + ".py", text)
+    return str(tmp_path)
+
+
+def test_collection_is_one_list_and_the_described_tpu_files_lead():
+    """Every xdist worker must collect the same list, and `--dist loadfile`
+    hands files out in its order: the files that compile for the described
+    TPU come first, the rest in the alphabet's order, twice alike."""
+    with ThreadPoolExecutor(2) as pool:
+        runs = list(pool.map(lambda _: _pytest(REPO_ROOT, "--collect-only", "tests/"), range(2)))
+    ids = [[line for line in r.stdout.splitlines() if "::" in line] for r in runs]
+    assert ids[0] == ids[1] and len(ids[0]) > 1900, runs[0].stdout[-2000:] + runs[0].stderr[-2000:]
+    files = list(dict.fromkeys(line.split("::")[0] for line in ids[0]))
+    assert files.index("tests/test_tpu_compile.py") < files.index("tests/test_actors.py")
+    lead = [f for f in files if f.startswith("tests/test_tpu_compile")]
+    assert files[:len(lead)] == lead == sorted(lead)
+    assert files[len(lead):] == sorted(files[len(lead):])
+
+
+def test_a_long_deadline_without_the_slow_mark_is_refused_at_collection(tmp_path):
+    suite = _scratch_suite(tmp_path, test_scratch='''\
+        import pytest
+
+        @pytest.mark.timeout(300)
+        def test_asks_for_more_than_the_default():
+            pass
+
+        @pytest.mark.slow
+        @pytest.mark.timeout(300)
+        def test_may_ask_being_slow():
+            pass
+        ''', test_other='''\
+        import pytest
+
+        @pytest.mark.timeout(170)
+        def test_within_the_default():
+            pass
+        ''')
+    r = _pytest(suite, "--collect-only", "--continue-on-collection-errors", ".")
+    assert r.returncode != 0
+    assert "ERROR collecting test_scratch.py" in r.stdout, r.stdout[-2000:]
+    assert ("test_scratch.py::test_asks_for_more_than_the_default asks for a deadline of 300 s"
+            in r.stdout), r.stdout[-2000:]
+    assert "test_may_ask_being_slow asks" not in r.stdout
+    assert "test_other.py::test_within_the_default" in r.stdout  # the other file is collected
+
+
+def test_the_topo_fixture_puts_xla_s_optimiser_back_and_takes_it_away_after(tmp_path):
+    """tests/conftest.py turns most of XLA's optimisations off for programs
+    a test runs once; the bytes and tallies pinned for the described TPU are
+    the optimised program's, so `topo` turns them on round its module."""
+    suite = _scratch_suite(tmp_path, test_a_described='''\
+        import jax
+
+        def test_before():
+            assert jax.config._read("jax_disable_most_optimizations") is True
+
+        def test_inside(topo):
+            assert jax.config._read("jax_disable_most_optimizations") is False
+            assert jax.config._read("jax_enable_compilation_cache") is False
+        ''', test_b_after='''\
+        import jax
+
+        def test_after():
+            assert jax.config._read("jax_disable_most_optimizations") is True
+        ''')
+    r = _pytest(suite, "test_a_described.py", "test_b_after.py")
+    if "no v5e:2x2 topology can be described here" in r.stdout + r.stderr or " skipped" in r.stdout:
+        pytest.skip("no v5e:2x2 topology can be described here")
+    assert r.returncode == 0 and "3 passed" in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]

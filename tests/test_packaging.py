@@ -10,7 +10,6 @@ import sys
 import pytest
 
 
-@pytest.mark.timeout(600)
 def test_wheel_install_and_smoke(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     wheel_dir = tmp_path / "wheels"

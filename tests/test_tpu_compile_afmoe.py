@@ -2,7 +2,7 @@
 tests/test_tpu_compile.py and with no chip: the windowed pair at window 2,048
 and `trinity_mini_l5_ep16.t8192`'s shape, ops/qk_prep.py's pair at the shapes
 of the three cells that take it, and the cell's whole step with the
-plan the rule takes (and, outside tier-1, with the first rung alone)."""
+plan the rule takes and with the first rung alone (`-m slow`)."""
 
 import jax
 import jax.numpy as jnp
@@ -59,18 +59,18 @@ def test_qk_prep_pair_compiles_at_the_cells_shapes(one_chip, b, t):
                 and sum("qk_prep_bwd" in n for n in names) == 1, (heads, rep, norm, rotary, names)
 
 
+@pytest.mark.slow  # 150 s a rung: the lowered step's tally and hash are tests/test_afmoe.py's AFMOE_STEP, its bytes tests/test_remat.py's, fast
 @pytest.mark.timeout(600)
-@pytest.mark.parametrize("rungs", [
-    "the_plan_taken", pytest.param("the_first_rung_alone", marks=pytest.mark.slow)])
+@pytest.mark.parametrize("rungs", ["the_plan_taken", "the_first_rung_alone"])
 def test_afmoe_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, rungs):
     """trinity_mini_l5_ep16.t8192's whole step compiled for the described v5e,
     with the plan the rule takes under a v5e's limit and with the first rung
-    alone (75 s each: the second is marked slow): the program holds less than
-    the 13.5 GiB the rule is held to and within the error the reckoning has
-    shown of what it reckoned (tests/test_remat.py: 0.35 GiB under to 0.85
-    over); four layers run the windowed pair at window 2,048 and one the
-    causal pair, the expert layers megablox's; no instruction of a block is
-    laid to no scope, and the new scopes are in the table."""
+    alone: the program holds less than the 13.5 GiB the rule is held to and
+    within the error the reckoning has shown of what it reckoned
+    (tests/test_remat.py: 0.35 GiB under to 0.85 over); four layers run the
+    windowed pair at window 2,048 and one the causal pair, the expert layers
+    megablox's; no instruction of a block is laid to no scope, and the new
+    scopes are in the table."""
     from ray_tpu.models import afmoe, remat
     from ray_tpu.train._device_profile import scope_table
 

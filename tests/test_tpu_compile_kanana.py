@@ -36,7 +36,7 @@ def test_latent_kernels_compile_at_the_cell_s_shape(one_chip):
     assert "[2,8192,32,192]" not in c.as_text() and "[2,8192,6144]" not in c.as_text()
 
 
-@pytest.mark.slow  # 50 s, outside tier-1 since PR 57 (the suite's time limit): the lowered step's hash is tests/test_kanana.py's KANANA_STEP, fast
+@pytest.mark.slow  # 50 s: the lowered step's hash is tests/test_kanana.py's KANANA_STEP, fast
 @pytest.mark.timeout(600)
 def test_kanana_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     """kanana2_30b_l5_ep8.t8192's whole step compiled for the described v5e:

@@ -35,7 +35,7 @@ def test_gated_conv_kernels_compile_at_the_cell_s_shape(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 2 * 8192 * 2048 * 2
 
 
-@pytest.mark.slow  # 100 s, outside tier-1 since PR 57 (the suite's time limit): the lowered step's hash is tests/test_kanana.py's LFM2_STEP, fast
+@pytest.mark.slow  # 100 s: the lowered step's hash is tests/test_kanana.py's LFM2_STEP, fast
 @pytest.mark.timeout(600)
 def test_lfm2_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     """lfm2_8b_a1b_l5_ep4.t8192's whole step compiled for the described v5e:

@@ -17,10 +17,9 @@ from tests._tpu_compile import (GIB, KERNELS, _CUSTOM_CALL, _kinds, _live_bytes,
                                 _step_args, cell_config)
 
 
+@pytest.mark.slow  # 120 and 100 s: the lowered step's hash is tests/test_mellum.py's PINNED_STEPS["mellum2_12b_l4_ep4"], its bytes tests/test_remat.py's, fast
 @pytest.mark.timeout(600)
-# twice the cell's rows is outside tier-1 since PR 50 (100 s; the suite's time limit): -m slow
-@pytest.mark.parametrize("rows,kept,grouped", [
-    (2, ("moe_gate", "moe_up"), 16), pytest.param(4, (), 18, marks=pytest.mark.slow)])
+@pytest.mark.parametrize("rows,kept,grouped", [(2, ("moe_gate", "moe_up"), 16), (4, (), 18)])
 def test_mellum_step_holds_what_the_rule_s_block_term_books(topo, monkeypatch, rows, kept, grouped):
     """mellum2_12b_l4_ep4.t8192's whole step compiled for the described v5e
     at the cell's rows and at twice them, the `block` term as PR 45 fitted

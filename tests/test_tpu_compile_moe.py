@@ -39,7 +39,7 @@ def _expert_layer(name, one_chip, **fields):
 @pytest.mark.parametrize("products_kept", [True, False], ids=["products_kept", "none_kept"])
 @pytest.mark.parametrize("name,pr43_bytes,pr44_bytes,kept_bytes", [
     ("mellum", 59_120_476_160, 52_494_639_104, 58_500_816_896),
-    # outside tier-1 since PR 50 (100 s for the pair; the suite's time limit): -m slow
+    # 100 s for the pair, `-m slow`: mellum's pair is the same layer's code at another size, fast
     pytest.param("lfm2", 40_023_392_256, 36_307_546_112, 40_541_192_192, marks=pytest.mark.slow)])
 def test_expert_share_compiles_at_the_cell_s_size(
         one_chip, monkeypatch, name, pr43_bytes, pr44_bytes, kept_bytes, products_kept):
@@ -84,7 +84,7 @@ def test_expert_share_compiles_at_the_cell_s_size(
         assert booked == pr44_bytes < pr43_bytes, booked
 
 
-@pytest.mark.slow  # outside tier-1 since PR 50 (80 s; the suite's time limit)
+@pytest.mark.slow  # 80 s: test_expert_share_compiles_at_the_cell_s_size[mellum-…] compiles the same layer and books its bytes, fast
 @pytest.mark.parametrize("name,pr44_bytes", [("mellum", 33_753_649_152), ("lfm2", 24_842_141_696)])
 def test_expert_share_s_step_that_fits_reads_fewer_bytes_than_pr_44_s(
         one_chip, monkeypatch, name, pr44_bytes):

@@ -101,7 +101,7 @@ def test_gated_norm_kernels_compile_at_the_cell_s_shape(one_chip):
 
 
 @pytest.mark.parametrize("products_kept", [
-    True,  # the cell's; the other form is outside tier-1 since PR 50 (45 s; the suite's time limit)
+    True,  # the cell's, and the other form's fast twin (45 s: `-m slow`)
     pytest.param(False, marks=pytest.mark.slow)], ids=["products_kept", "none_kept"])
 def test_experts_of_two_matrices_compile_at_the_cell_s_size(one_chip, monkeypatch, products_kept):
     """8 held experts of 128, top-6, two matrices 1,856 wide (14.5 vectors of
@@ -129,6 +129,7 @@ def test_experts_of_two_matrices_compile_at_the_cell_s_size(one_chip, monkeypatc
     assert f"bf16[{16384 * 6},2688]" in text
 
 
+@pytest.mark.slow  # 125 s: the lowered step's tally is tests/test_nemotron_h.py::test_the_cell_s_step_runs_the_scan_s_kernels_and_two_matrices_an_expert, its bytes tests/test_remat.py's, fast
 @pytest.mark.timeout(600)
 def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     """nemotron3_nano_l9_ep16.t8192's whole step compiled for the described

@@ -967,6 +967,13 @@ def test_program_spans_in_a_device_trace_window(monkeypatch, tmp_path):
         TrainContext, init_session, report, shutdown_session,
     )
 
+    # an earlier test's recorder beats on for _WATCHER_IDLE_S after its last
+    # step, longer than this one's compiles take since PR 58: the window is
+    # to hold one heartbeat's line, this recorder's
+    for t in threading.enumerate():
+        if t.name == "train-host-heartbeat":
+            t.join(10 * _telemetry._WATCHER_IDLE_S)
+            assert not t.is_alive()
     session = init_session(TrainContext(0, 1, 0, 1, "127.0.0.1"), None,
                            pipeline_depth=4)
     try:

@@ -4,8 +4,8 @@ copy-free chunked transfer.
 The acceptance contract is structural, not timing-based: the put path and
 the chunk send path must never materialize an out-of-band buffer as Python
 bytes — asserted here by buffer identity (np.shares_memory) and by the
-"_oob" landed-in-place markers of the RPC layer (the smoke at the bottom of
-this file is abbreviated timing, a floor and not a measurement).
+"_oob" landed-in-place markers of the RPC layer, at the put-bandwidth
+rep's own size too (the bottom of this file).
 """
 
 import asyncio
@@ -324,27 +324,33 @@ def test_pull_integrity_across_chunk_boundaries(two_nodes_small_chunks):
     assert ray_tpu.get(digest.remote(ref), timeout=120) == want
 
 
-# ------------------------------------------------------- bandwidth smoke
+# ------------------------------------------------------ the bench's put size
 
 
-def test_put_bandwidth_smoke(ray_start_regular):
-    """Abbreviated put-bandwidth rep (tier-1-safe): one warm put plus a
-    short timed run. The floor is deliberately loose — the structural
-    zero-copy assertions above catch copy regressions deterministically;
-    this only trips on a catastrophic slowdown of the fast path."""
-    import time
+def test_large_puts_take_the_single_copy_path_every_time(ray_start_regular, monkeypatch):
+    """The put-bandwidth rep's 64 MiB array, put again and again: every put
+    hands write_blob one raw buffer that aliases the user's array and a
+    pickle of a few hundred bytes beside it, and the store gives the last
+    one back whole after the earlier ones went. What a rate on this host's
+    clock guarded (a copy come back into the fast path) is held by the
+    buffer's identity; a CPU run gives no rate."""
+    captured = []
+    orig = serialization.write_blob
 
-    big = np.zeros(64 * 1024 * 1024 // 8, dtype=np.float64)  # 64 MiB
-    gib = big.nbytes / (1 << 30)
-    ray_tpu.put(big)  # warm: page-faults the store region once
-    count = 0
-    t0 = time.perf_counter()
-    while True:
-        ray_tpu.put(big)
-        count += 1
-        dt = time.perf_counter() - t0
-        if dt >= 1.0 or count >= 64:
-            break
-    rate = count * gib / dt
-    # this box: ~5-6 GiB/s zero-copy, ~1.4 GiB/s with the old double copy
-    assert rate > 0.2, f"put bandwidth collapsed: {rate:.2f} GiB/s"
+    def spy(dest, pickle_bytes, buffers):
+        captured.append((len(pickle_bytes), list(buffers)))
+        return orig(dest, pickle_bytes, buffers)
+
+    big = np.arange(64 * 1024 * 1024 // 8, dtype=np.float64)  # 64 MiB
+    big_bytes = big.view(np.uint8)
+    monkeypatch.setattr(serialization, "write_blob", spy)
+    for _ in range(4):
+        ref = ray_tpu.put(big)  # the one before it goes with its only reference
+    monkeypatch.undo()
+    assert len(captured) == 4
+    for in_band, buffers in captured:
+        assert in_band < 4096 and len(buffers) == 1, (in_band, len(buffers))
+        raw = memoryview(buffers[0]).cast("B")
+        assert raw.nbytes == big.nbytes
+        assert np.shares_memory(np.frombuffer(raw, dtype=np.uint8), big_bytes)
+    assert np.array_equal(ray_tpu.get(ref), big)
