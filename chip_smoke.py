@@ -491,7 +491,7 @@ def _check_qk_prep_vs_plain(seed, on_tpu):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import apply_rope, rms_norm, rope_angles
+    from ray_tpu.models.layers import apply_rope, rms_norm, rope_angles
     from ray_tpu.ops import attention, qk_prep
 
     eps, out = 1e-6, []
@@ -716,7 +716,7 @@ def _check_gated_attention(seed, on_tpu):
     import jax.numpy as jnp
 
     from bench import families
-    from ray_tpu.models.llama import LlamaAttention
+    from ray_tpu.models.layers import LlamaAttention
     from ray_tpu.parallel.mesh import make_mesh
     from ray_tpu.parallel.train_step import TrainStep
 

@@ -7,6 +7,10 @@ no option), `block_size`, `attn_fn` and `use_flash_attention` (`model_for_mesh`
 sets the first where a mesh needs its own attention), `flops_per_token(seq_len)`
 where the family counts its FLOPs, and `lr_warmup_steps` where its recipe has
 any (ROADMAP C15). Everything else a family is, the record says.
+
+A family's file holds a family: of this package it imports the package, `remat`,
+`loss` and `layers` (what several families run), and no other family's file
+(`gpt2_moe`, a variant of `gpt2`, imports it); tests/test_family.py holds it.
 """
 
 from __future__ import annotations

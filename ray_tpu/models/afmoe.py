@@ -14,7 +14,7 @@ The equations, with d the hidden size, H query heads, G key-value heads of
                                      float32 sums
     loss   = mean cross-entropy of the next token             float32
 
-attention (models/llama.py's `LlamaAttention` with `qk_norm`, `gate`, and by
+attention (models/layers.py's `LlamaAttention` with `qk_norm`, `gate`, and by
 kind `window` and `rotary`; ops/attention.py's flash_win<window>_* pair in a
 `sliding_attention` layer, the causal pair in a `full_attention` one):
 
@@ -32,11 +32,11 @@ ffn of the first `num_dense_layers` layers: W_down (silu(W_gate m) * W_up m),
 SIGMOID router (models/lfm2.py says what it computes; here top 8 of 128, the
 gates over their sum + 1e-20, times `route_scale`), of which this program
 computes `num_held` experts from `first_expert` on, plus the shared expert
-(models/kanana.py's `SharedExpert`), whole on every chip and counted once
+(models/layers.py's `SharedExpert`), whole on every chip and counted once
 when shares are summed.
 
 All blocks are one parameter group, `p_0`, which sows its routed blocks'
-choices stacked, (routed blocks, B, T, top_k): models/lfm2.py says why. What
+choices stacked, (routed blocks, B, T, top_k): `layers.sow_choices`. What
 the published keys do not say (the gate, the q/k norm, rotary by kind, the
 four norms, sqrt(d) on the embedding), the bias's rule and the initialisers
 are under `assumed` in bench/configs/trinity_mini_l5_ep16.json.
@@ -53,9 +53,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.kanana import SharedExpert
-from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LlamaAttention, LlamaMLP, RMSNorm, SharedExpert
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -226,7 +225,7 @@ def remat_plan(cfg: AfmoeConfig, shape: remat.StepShape, limit) -> remat.RematPl
     layer's bytes n_layer times."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
-    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     dense = cfg.n_layer - cfg.routed_layers
     name_bytes = remat.attention_bytes(shape, cfg.n_head, cfg.head_dim, itemsize)
     name_bytes.update(
@@ -284,10 +283,8 @@ class AfmoeGroup(nn.Module):
         for i, kind in enumerate(cfg.layer_types):
             x, chosen = nn.remat(AfmoeBlock, policy=self.keep)(
                 cfg, kind, i >= cfg.num_dense_layers, self.stream, name=f"h_{i}")(x, pos_offset)
-            if chosen is not None:
-                choices.append(chosen)
-        if choices:
-            self.sow("choices", "experts", jnp.stack(choices))
+            choices.append(chosen)
+        layers.sow_choices(self, choices)
         return x
 
 
@@ -303,22 +300,16 @@ class Afmoe(nn.Module):
         scale = math.sqrt(cfg.n_embd)
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0 / scale))(idx) * scale
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         x = AfmoeGroup(cfg, keep, self.stream, name="p_0")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        # operands in the compute dtype, float32 logits (models/lfm2.py's head, untied)
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.n_embd, cfg.vocab_size), jnp.float32)
-        with jax.named_scope("lm_head"):
-            return jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
+        return layers.untied_head(self, cfg, x)
 
 
-AFMOE_SHARDING_RULES = ShardingRules([
-    (r"attn/wg/kernel", P("fsdp", "tp")),  # column parallel, as wq
-    (r"shared/(gate|up)/kernel", P("fsdp", "tp")),
-    (r"shared/down/kernel", P("tp", "fsdp")),
-    (r"lm_head$", P("fsdp", "tp")),
-] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+AFMOE_SHARDING_RULES = ShardingRules(
+    [(r"attn/wg/kernel", P("fsdp", "tp"))]  # column parallel, as wq
+    + layers.SHARED_EXPERT_SHARDING_PATTERNS + layers.UNTIED_HEAD_SHARDING_PATTERNS
+    + EXPERT_SHARE_SHARDING_PATTERNS + layers.LLAMA_SHARDING_PATTERNS, default=P())
 
 
 def step_metrics(cfg, sown, params, tokens):

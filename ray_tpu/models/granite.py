@@ -20,24 +20,9 @@ flash kernel fixes 1/sqrt(head_dim) (ops/attention.py:_split_scale), so q is
 scaled by attention_multiplier * sqrt(head_dim) before the call: 1/8 at the
 published sizes, a power of two and so exact in bf16.
 
-`mamba` layers (Mamba-2, Dao & Gu 2024), H heads of P, G groups, state N:
-
-    [z | xBC | dt] = W_in u            d -> H P + (H P + 2 G N) + H, no bias
-    xBC <- silu(conv(xBC))             depthwise, causal, kernel K, with bias
-    x (T, H, P), B (T, G, N), C (T, G, N) = split(xBC)
-    Delta = softplus(dt + dt_bias)     (H);  A = -exp(A_log)  (H)
-    S_t = exp(Delta_t A) S_{t-1} + Delta_t x_t B_t^T;   y_t = S_t C_t + D x_t
-    y <- RMSNorm(y * silu(z))          over all H P channels, the gate first
-    out = W_out y                      H P -> d, no bias
-
-The recurrence is ops/ssd.py (its chunked form at `ssm_chunk`; pallas
-kernels ssd_fwd and ssd_bwd on a TPU); the convolution under its bias and
-silu is ops/short_conv.py's (pallas kernels causal_conv_fwd and
-causal_conv_bwd on a TPU, reading xBC where W_in wrote it; the same lines
-in jax.numpy elsewhere); the gate and the norm over all channels at once,
-this family's, are XLA's, which fuses them into their neighbours (a mixer
-told to norm by group, models/nemotron_h.py's, runs ops/gated_norm.py's
-pair on a TPU: a pass of its own that reads each group where it lies).
+`mamba` layers: models/layers.py's `Mamba2Mixer`, which states the equations
+and what computes them; here one group of B and C, and the gated norm over
+all H P channels at once.
 Departures from the published code, all under `assumed` in
 bench/configs/granite4_h_micro_l10.json: the convolution's kernel is stored
 (K, channels) and not (channels, 1, K); no clamp on Delta (the family's
@@ -60,11 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.llama import (  # noqa: F401
-    LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm)
-from ray_tpu.ops.short_conv import causal_conv_within
-from ray_tpu.ops.ssd import ssd
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LlamaAttention, LlamaMLP, Mamba2Mixer, RMSNorm
 from ray_tpu.parallel.mesh import ShardingRules, pin
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -161,73 +143,6 @@ class GraniteConfig:
         return cls(**base)
 
 
-def _a_log_init(key, shape, dtype=jnp.float32):
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """The inverse softplus of a step drawn log-uniformly from 1e-3 to 1e-1."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(1e-1)))
-    return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def _conv_init(key, shape, dtype=jnp.float32):
-    """torch's conv1d default: uniform in +-1/sqrt(fan_in), fan_in the K taps."""
-    bound = 1 / math.sqrt(shape[0])
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
-class Mamba2Mixer(nn.Module):
-    """(B, T, d) -> (B, T, d): the module docstring's `mamba` layer. Sows
-    into "ssm_stats" the most negative log-decay of a chunk and the largest
-    entry of a carried state (TrainStep's telemetry). `config` is a
-    GraniteConfig or any config with its `ssm_*` fields, `n_embd`, `rms_eps`
-    and `dtype`; `norm_groups`: the gated norm is taken over each of this
-    many equal parts of the H P channels on its own (models/nemotron_h.py: a
-    part for each group of B and C), with one weight over all; more than one
-    makes gate and norm one call of ops/gated_norm.py, which reads z where
-    the input projection wrote it."""
-
-    config: Any
-    norm_groups: int = 1
-
-    @nn.compact
-    def __call__(self, u):
-        cfg = self.config
-        b, t, _ = u.shape
-        h, p, g, n, k = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
-                         cfg.ssm_conv)
-        inner, conv_dim = cfg.ssm_inner, cfg.ssm_conv_dim
-        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
-        with jax.named_scope("ssm.in_proj"):
-            zxbcdt = dense(inner + conv_dim + h, "in_proj")(u)
-        with jax.named_scope("ssm.conv"):
-            w = self.param("conv_kernel", _conv_init, (k, conv_dim), jnp.float32)
-            bias = self.param("conv_bias", nn.initializers.zeros, (conv_dim,), jnp.float32)
-            z, x, bm, cm, dt = causal_conv_within(zxbcdt, w, bias, inner,
-                                                   (inner, inner + g * n))
-        with jax.named_scope("ssm.scan"):
-            dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
-            a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)
-            skip = self.param("D", nn.initializers.ones, (h,), jnp.float32)
-            delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            y, states = ssd(x.reshape(b, t, h, p), delta, -jnp.exp(a_log),
-                            bm.reshape(b, t, g, n), cm.reshape(b, t, g, n), skip, cfg.ssm_chunk)
-            chunk = min(cfg.ssm_chunk, t)
-            log_decay = (delta * -jnp.exp(a_log)).reshape(b, t // chunk, chunk, h).sum(2)
-            self.sow("ssm_stats", "chunk_log_decay_min", jax.lax.stop_gradient(log_decay.min()))
-            self.sow("ssm_stats", "state_abs_max",
-                     jax.lax.stop_gradient(jnp.abs(states).max()))
-        with jax.named_scope("ssm.gate"):
-            norm = RMSNorm(cfg.rms_eps, self.norm_groups, name="norm")
-            y = y.reshape(b, t, inner)
-            # one group: lines XLA fuses into their neighbours; more: a pass of its own
-            y = (norm(y * nn.silu(z)) if self.norm_groups == 1 else
-                 norm(y, gate=z, within=(zxbcdt, 0)))
-        with jax.named_scope("ssm.out_proj"):
-            return dense(cfg.n_embd, "out_proj")(y)
-
-
 def _add_scaled(x, factor, branch):
     """x + factor * branch, summed in float32 and rounded once. In bf16 the
     published 0.22 is 0.21973: every branch 0.12% short, which the loss of a
@@ -282,7 +197,7 @@ def remat_plan(cfg: GraniteConfig, shape: remat.StepShape, limit) -> remat.Remat
     tokens = shape.rows * shape.seq_len
     kinds = cfg.layer_types
     attn, mamba = kinds.count(ATTENTION), kinds.count(MAMBA)
-    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     name_bytes = {name: share(nbytes, attn) for name, nbytes in remat.attention_bytes(
         shape, cfg.n_head, cfg.head_dim, itemsize).items()}
     chunks = -(-shape.seq_len // cfg.ssm_chunk)
@@ -299,20 +214,11 @@ def remat_plan(cfg: GraniteConfig, shape: remat.StepShape, limit) -> remat.Remat
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
 
 
-def mixer_bytes(cfg, itemsize: int) -> int:
-    """What a Mamba mixer's backward works in, bytes a token, from its
-    widths: the input projection's output in the compute dtype; the
-    convolution's output, the scan's output and the gated norm's input in
-    that and in float32."""
-    return (itemsize * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
-            + (itemsize + 4) * (cfg.ssm_conv_dim + 2 * cfg.ssm_inner))
-
-
 def _block_bytes(cfg: GraniteConfig, itemsize: int) -> int:
     """What a Mamba block's backward works in, bytes a token: the mixer's
     (`mixer_bytes`) and the MLP's gate and up and their gradients (154 KB at
     the published widths in bf16)."""
-    return mixer_bytes(cfg, itemsize) + itemsize * 4 * cfg.intermediate
+    return layers.mixer_bytes(cfg, itemsize) + itemsize * 4 * cfg.intermediate
 
 
 class GranitePeriod(nn.Module):
@@ -343,7 +249,7 @@ class Granite(nn.Module):
         emb = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                        embedding_init=nn.initializers.normal(1.0 / cfg.embedding_multiplier))
         x = emb(idx) * cfg.embedding_multiplier
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         for i in range(cfg.n_layer // cfg.period):
             x = GranitePeriod(cfg, keep, self.stream, name=f"p_{i}")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
@@ -355,27 +261,9 @@ class Granite(nn.Module):
             return logits / cfg.logits_scaling
 
 
-MAMBA_SHARDING_PATTERNS = [
-    (r"mamba/in_proj/kernel", P("fsdp", None)),
-    (r"mamba/out_proj/kernel", P(None, "fsdp")),
-    (r"mamba/", P()),
-]
-GRANITE_SHARDING_RULES = ShardingRules(MAMBA_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS,
-                                       default=P())
+GRANITE_SHARDING_RULES = ShardingRules(
+    layers.MAMBA_SHARDING_PATTERNS + layers.LLAMA_SHARDING_PATTERNS, default=P())
 
 
-def step_metrics(cfg, sown, params, tokens):
-    """`Family.metrics`, of what the Mamba layers sowed (`Mamba2Mixer`): the
-    most negative log-decay of a chunk over layers and heads (how near a
-    chunk's exp is to flushing to zero) and the largest carried-state entry
-    (what a narrower state would have to hold)."""
-    stats = [layer["mamba"] for period in sown["ssm_stats"].values()
-             for layer in period.values()]  # the mamba layers alone sow
-    return {"ssm_chunk_log_decay_min": jnp.min(jnp.stack(
-                [s["chunk_log_decay_min"][0] for s in stats])),
-            "ssm_state_abs_max": jnp.max(jnp.stack(
-                [s["state_abs_max"][0] for s in stats]))}
-
-
-GraniteConfig.family = Family(
-    module=Granite, rules=GRANITE_SHARDING_RULES, sown=("ssm_stats",), metrics=step_metrics)
+GraniteConfig.family = Family(module=Granite, rules=GRANITE_SHARDING_RULES, sown=("ssm_stats",),
+                              metrics=layers.ssm_step_metrics)

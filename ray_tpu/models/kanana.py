@@ -11,31 +11,10 @@ The equations, with d the hidden size, H heads and x the residual stream:
                                      float32 sums
     loss   = mean cross-entropy of the next token             float32
 
-attention (ops/attention.py's latent pair, flash_mla_fwd and
-flash_mla_bwd_fused on a TPU), a head's widths `nope_dim` (128), `rope_dim`
-(64) and `v_dim` (128), the latent `kv_latent` (512) wide:
-
-    q = W_q h                        d -> H x (nope + rope); a head's q is
-                                     [q_nope ; q_pe]
-    [c ; k_pe] = W_kva h             d -> latent + rope; k_pe is one key a
-                                     token, for all heads, and is not normed
-    c <- RMSNorm(c)
-    [k_nope ; v] = W_kvb c           latent -> H x (nope + v), a head's
-                                     [k_nope ; v]
-    rotary on q_pe and k_pe alone, interleaved: the rope_dim entries are read
-    as adjacent pairs (put apart, then the half-split rotation), theta
-    `rope_theta`, no scaling
-    scores of head h: (q_nope_h . k_nope_h + q_pe_h . k_pe) / sqrt(nope + rope),
-    causal softmax; o_h = P_h v_h
-    out = W_o o                      H x v -> d; no bias anywhere
-
-The mixer serves a second family since PR 54: models/kimi_linear.py's latent
-layers are this `LatentAttention` told `rotary=False` (no position at all;
-the default, and this family's every layer, turns them as above).
-
-This is the expanded form, training's; the absorbed form (scores against the
-latent itself) is decode's and is not here. No array of H keys nope + rope
-wide is made: the kernels add the two products tile by tile.
+attention: models/layers.py's `LatentAttention`, which states the equations
+and what computes them, as it stands by default (every layer turns q_pe and
+k_pe); a head's widths `nope_dim` (128), `rope_dim` (64) and `v_dim` (128),
+the latent `kv_latent` (512) wide.
 
 ffn of the first `num_dense_layers` layers: W_down (silu(W_gate h) * W_up h),
 `intermediate` wide. Of the others: ops/moe.py's `ExpertShare` with the
@@ -47,7 +26,7 @@ under a share it is whole on every chip, and counted once when shares are
 summed.
 
 All blocks are one parameter group, `p_0`, which sows its routed blocks'
-choices stacked, (routed blocks, B, T, top_k): models/lfm2.py says why. The
+choices stacked, (routed blocks, B, T, top_k): `layers.sow_choices`. The
 bias's rule, the initialisers and the absence of an auxiliary loss are under
 `assumed` in bench/configs/kanana2_30b_l5_ep8.json.
 """
@@ -60,12 +39,10 @@ from typing import Any, ClassVar, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.llama import (LLAMA_SHARDING_PATTERNS, LlamaMLP, RMSNorm, apply_rope,
-                                  rope_angles)
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LatentAttention, LlamaMLP, RMSNorm, SharedExpert
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -152,75 +129,6 @@ class KananaConfig:
         return cls(**base)
 
 
-def pairs_apart(x):
-    """(..., 2 n) read as n adjacent pairs -> (..., 2 n) with the pairs'
-    first entries in the first half and their second in the second: the
-    order in which the half-split rotation turns each pair (the source's
-    `rope_interleave`). Queries and keys take the same order, so their
-    products are those of the pairs where they lay."""
-    *lead, width = x.shape
-    return x.reshape(*lead, width // 2, 2).swapaxes(-1, -2).reshape(*lead, width)
-
-
-class LatentAttention(nn.Module):
-    """(B, T, d) -> (B, T, d): the module docstring's attention. `config` is
-    a KananaConfig or any config with its attention's fields. With `rotary`
-    off nothing turns q_pe and k_pe: the `rope_dim` entries are plain
-    coordinates, k_pe still one key a token for all heads
-    (models/kimi_linear.py, `mla_use_nope`); the kernels are the same."""
-
-    config: Any
-    rotary: bool = True
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        if cfg.attn_fn is not None:
-            raise NotImplementedError("latent attention runs on one device")
-        B, T, C = x.shape
-        H, nope, rope = cfg.n_head, cfg.nope_dim, cfg.rope_dim
-        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
-        with jax.named_scope("mla.q"):
-            q = dense(H * (nope + rope), "q_proj")(x).reshape(B, T, H, nope + rope)
-            q, q_pe = q[..., :nope], q[..., nope:]
-        with jax.named_scope("mla.kv_a"):
-            latent = dense(cfg.kv_latent + rope, "kv_a_proj")(x)
-            latent, k_pe = latent[..., :cfg.kv_latent], latent[..., cfg.kv_latent:]
-        with jax.named_scope("mla.kv_norm"):
-            latent = RMSNorm(cfg.rms_eps, name="kv_a_norm")(latent)
-        with jax.named_scope("mla.kv_b"):
-            kv = dense(H * (nope + cfg.v_dim), "kv_b_proj")(latent).reshape(
-                B, T, H, nope + cfg.v_dim)
-            k, v = kv[..., :nope], kv[..., nope:]
-        if self.rotary:
-            with jax.named_scope("mla.rope"):
-                angles = rope_angles(rope, cfg.rope_theta, jnp.arange(T))
-                q_pe = apply_rope(pairs_apart(q_pe), angles)
-                k_pe = apply_rope(pairs_apart(k_pe)[:, :, None], angles)[:, :, 0]
-        with jax.named_scope("attn.core"):
-            if cfg.use_flash_attention:
-                from ray_tpu.ops.attention import latent_attention
-            else:
-                from ray_tpu.ops.attention import xla_latent_attention as latent_attention
-            y = latent_attention(q, q_pe, k, k_pe, v)
-        with jax.named_scope("mla.o"):
-            return dense(C, "o_proj")(y.reshape(B, T, H * cfg.v_dim))
-
-
-class SharedExpert(nn.Module):
-    """The SwiGLU every token passes through beside its routed experts."""
-
-    config: KananaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
-        gate, up = (checkpoint_name(dense(cfg.shared_dim, name)(x), "shared_up")
-                    for name in ("gate", "up"))
-        return dense(cfg.n_embd, "down")(nn.silu(gate) * up)
-
-
 class KananaBlock(nn.Module):
     """A block and the choices of its expert layer, (x, (B, T, top_k)); a
     block with a dense MLP hands up None."""
@@ -278,7 +186,7 @@ def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematP
     layer's bytes n_layer times."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
-    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     head = lambda width: tokens * cfg.n_head * width * itemsize
     dense = cfg.n_layer - cfg.routed_layers
     name_bytes = dict(
@@ -334,10 +242,8 @@ class KananaGroup(nn.Module):
         for i in range(cfg.n_layer):
             x, chosen = nn.remat(KananaBlock, policy=self.keep)(
                 cfg, i >= cfg.num_dense_layers, self.stream, name=f"h_{i}")(x)
-            if chosen is not None:
-                choices.append(chosen)
-        if choices:
-            self.sow("choices", "experts", jnp.stack(choices))
+            choices.append(chosen)
+        layers.sow_choices(self, choices)
         return x
 
 
@@ -350,25 +256,16 @@ class Kanana(nn.Module):
         cfg = self.config
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(0.02))(idx)
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         x = KananaGroup(cfg, keep, self.stream, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        # operands in the compute dtype, float32 logits (models/lfm2.py's head, untied)
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.n_embd, cfg.vocab_size), jnp.float32)
-        with jax.named_scope("lm_head"):
-            return jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
+        return layers.untied_head(self, cfg, x)
 
 
-KANANA_SHARDING_RULES = ShardingRules([
-    (r"attn/q_proj/kernel", P("fsdp", "tp")),
-    (r"attn/kv_a_proj/kernel", P("fsdp", None)),  # the latent and the shared key stay whole
-    (r"attn/kv_b_proj/kernel", P(None, "tp")),
-    (r"attn/o_proj/kernel", P("tp", "fsdp")),
-    (r"shared/(gate|up)/kernel", P("fsdp", "tp")),
-    (r"shared/down/kernel", P("tp", "fsdp")),
-    (r"lm_head$", P("fsdp", "tp")),
-] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+KANANA_SHARDING_RULES = ShardingRules(
+    layers.LATENT_SHARDING_PATTERNS + layers.SHARED_EXPERT_SHARDING_PATTERNS
+    + layers.UNTIED_HEAD_SHARDING_PATTERNS + EXPERT_SHARE_SHARDING_PATTERNS
+    + layers.LLAMA_SHARDING_PATTERNS, default=P())
 KananaConfig.family = Family(  # as models/lfm2.py's: the same router
     module=Kanana, rules=KANANA_SHARDING_RULES, sown=("moe_load", "moe_router"),
     metrics=moe.step_metrics, held_leaf=moe.SELECTION_BIAS_HELD)

@@ -42,7 +42,7 @@ KDA mixer (`KimiDeltaAttention`), H heads of `kda_head_dim` (d_k = d_v = 128):
                                              the norm over a head's 128 alone,
                                              one weight of that width
 
-Latent mixer: models/kanana.py's `LatentAttention` told `rotary=False`
+Latent mixer: models/layers.py's `LatentAttention` told `rotary=False`
 (`mla_use_nope`): the `rope_dim` entries of q_pe and k_pe are plain
 coordinates, k_pe still one key a token for all heads; scale (nope + rope)^-1/2.
 
@@ -51,11 +51,11 @@ ffn of the first `num_dense_layers` layers: W_down (silu(W_gate h) * W_up h),
 SIGMOID router (top 8 of 256 on sigmoid(logits) + bias, one group; the gates
 the chosen sigmoids over their sum + 1e-20, times `routed_scaling`), of which
 this program computes `num_held` experts from `first_expert` on, plus the
-shared expert (models/kanana.py's `SharedExpert`), whole on every chip and
+shared expert (models/layers.py's `SharedExpert`), whole on every chip and
 counted once when shares are summed.
 
 All blocks are one parameter group, `p_0`, which sows its routed blocks'
-choices stacked, (routed blocks, B, T, top_k): models/kanana.py says why.
+choices stacked, (routed blocks, B, T, top_k): `layers.sow_choices`.
 What the published keys do not say (the layer lists' numbering, the low
 rank, the convolution's form, l2norm's eps, A_log's and dt_bias's
 initialisers, the bias's rule) is under `assumed` in
@@ -72,10 +72,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.granite import _a_log_init, _conv_init, _dt_bias_init
-from ray_tpu.models.kanana import LatentAttention, SharedExpert
-from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaMLP, RMSNorm
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LatentAttention, LlamaMLP, RMSNorm, SharedExpert
 from ray_tpu.ops import kda, moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.ops.short_conv import causal_conv_within
@@ -235,13 +233,13 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("kda.in_proj"):
             qkv = dense(3 * inner, "qkv_proj")(x)
         with jax.named_scope("kda.conv"):
-            w = self.param("conv_kernel", _conv_init, (cfg.kda_conv, 3 * inner), f32)
+            w = self.param("conv_kernel", layers.conv_init, (cfg.kda_conv, 3 * inner), f32)
             _, q, k, v, _ = causal_conv_within(qkv, w, jnp.zeros((3 * inner,), f32), 0,
                                                (inner, 2 * inner))
             q, k, v = (u.reshape(b, t, h, dk) for u in (q, k, v))
         with jax.named_scope("kda.gate"):
-            a_log = self.param("A_log", _a_log_init, (h,), f32)
-            dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), f32).reshape(h, dk)
+            a_log = self.param("A_log", layers.a_log_init, (h,), f32)
+            dt_bias = self.param("dt_bias", layers.dt_bias_init, (inner,), f32).reshape(h, dk)
             f = dense(inner, "f_b_proj")(dense(cfg.kda_rank, "f_a_proj")(x)).reshape(b, t, h, dk)
             beta = jax.nn.sigmoid(dense(h, "b_proj")(x).astype(f32))
         with jax.named_scope("kda.scan"):
@@ -345,7 +343,7 @@ def remat_plan(cfg: KimiLinearConfig, shape: remat.StepShape, limit) -> remat.Re
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
     n_kda, n_mla = cfg.layer_types.count(KDA), cfg.layer_types.count(MLA)
-    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     head = lambda width: share(tokens * cfg.n_head * width * itemsize, n_mla)
     dense = cfg.n_layer - cfg.routed_layers
     chunks = -(-shape.seq_len // cfg.kda_chunk)
@@ -410,10 +408,8 @@ class KimiLinearGroup(nn.Module):
         for i, kind in enumerate(cfg.layer_types):
             x, chosen = KimiLinearBlock(cfg, kind, i >= cfg.num_dense_layers, self.keep,
                                         self.stream, name=f"h_{i}")(x)
-            if chosen is not None:
-                choices.append(chosen)
-        if choices:
-            self.sow("choices", "experts", jnp.stack(choices))
+            choices.append(chosen)
+        layers.sow_choices(self, choices)
         return x
 
 
@@ -426,14 +422,10 @@ class KimiLinear(nn.Module):
         cfg = self.config
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(0.02))(idx)
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         x = KimiLinearGroup(cfg, keep, self.stream, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        # operands in the compute dtype, float32 logits (models/lfm2.py's head, untied)
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.n_embd, cfg.vocab_size), jnp.float32)
-        with jax.named_scope("lm_head"):
-            return jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
+        return layers.untied_head(self, cfg, x)
 
 
 KIMI_LINEAR_SHARDING_RULES = ShardingRules([
@@ -441,14 +433,9 @@ KIMI_LINEAR_SHARDING_RULES = ShardingRules([
     (r"kda/(f_a_proj|g_a_proj|b_proj)/kernel", P("fsdp", None)),
     (r"kda/o_proj/kernel", P("tp", "fsdp")),
     (r"kda/(conv_kernel|dt_bias)$", P()),
-    (r"attn/q_proj/kernel", P("fsdp", "tp")),
-    (r"attn/kv_a_proj/kernel", P("fsdp", None)),  # the latent and the shared key stay whole
-    (r"attn/kv_b_proj/kernel", P(None, "tp")),
-    (r"attn/o_proj/kernel", P("tp", "fsdp")),
-    (r"shared/(gate|up)/kernel", P("fsdp", "tp")),
-    (r"shared/down/kernel", P("tp", "fsdp")),
-    (r"lm_head$", P("fsdp", "tp")),
-] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+] + layers.LATENT_SHARDING_PATTERNS + layers.SHARED_EXPERT_SHARDING_PATTERNS
+    + layers.UNTIED_HEAD_SHARDING_PATTERNS + EXPERT_SHARE_SHARDING_PATTERNS
+    + layers.LLAMA_SHARDING_PATTERNS, default=P())
 
 
 def step_metrics(cfg, sown, params, tokens):

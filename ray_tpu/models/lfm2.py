@@ -38,7 +38,7 @@ All blocks are one parameter group, `p_0` (`p_0/h_0` ..): the kinds of block
 differ in structure, and what takes gradients a group at a time
 (bench/worker.py, a pipeline stage) asks the groups for one structure. The
 group sows one entry into "choices": its routed blocks' indices stacked,
-(routed blocks, B, T, top_k).
+(routed blocks, B, T, top_k) (`layers.sow_choices`).
 
 Each block is under nn.remat with the plan of models/remat.py; the operator's
 named scopes (conv.in_proj, conv.mix, conv.out_proj) reach every op's
@@ -58,9 +58,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.granite import _conv_init
-from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, LlamaMLP, RMSNorm
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LlamaAttention, LlamaMLP, RMSNorm
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.ops.short_conv import gated_short_conv
@@ -164,7 +163,8 @@ class ShortConv(nn.Module):
         with jax.named_scope("conv.in_proj"):
             bcu = checkpoint_name(dense(3 * cfg.n_embd, "in_proj")(h), "conv_bcu")
         with jax.named_scope("conv.mix"):
-            w = self.param("conv_kernel", _conv_init, (cfg.conv_taps, cfg.n_embd), jnp.float32)
+            w = self.param("conv_kernel", layers.conv_init, (cfg.conv_taps, cfg.n_embd),
+                           jnp.float32)
             y = checkpoint_name(gated_short_conv(bcu, w), "conv_y")
         with jax.named_scope("conv.out_proj"):
             return dense(cfg.n_embd, "out_proj")(y)
@@ -237,7 +237,7 @@ def remat_plan(cfg: Lfm2Config, shape: remat.StepShape, limit) -> remat.RematPla
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
     kinds = cfg.layer_types
-    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     name_bytes = {name: share(nbytes, kinds.count(ATTENTION))
                   for name, nbytes in remat.attention_bytes(
                       shape, cfg.n_head, cfg.head_dim, itemsize).items()}
@@ -297,10 +297,8 @@ class Lfm2Group(nn.Module):
         for i, kind in enumerate(cfg.layer_types):
             x, chosen = nn.remat(Lfm2Block, policy=self.keep)(
                 cfg, kind, cfg.routed(i), self.stream, self.products_kept, name=f"h_{i}")(x)
-            if chosen is not None:
-                choices.append(chosen)
-        if choices:
-            self.sow("choices", "experts", jnp.stack(choices))
+            choices.append(chosen)
+        layers.sow_choices(self, choices)
         return x
 
 
@@ -314,7 +312,7 @@ class Lfm2(nn.Module):
         emb = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                        embedding_init=nn.initializers.normal(0.02))
         x = emb(idx)
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
         x = Lfm2Group(cfg, keep, self.stream, products, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
@@ -331,7 +329,7 @@ LFM2_SHARDING_RULES = ShardingRules([
     (r"conv/in_proj/kernel", P("fsdp", None)),
     (r"conv/out_proj/kernel", P(None, "fsdp")),
     (r"conv/conv_kernel", P()),
-] + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+] + EXPERT_SHARE_SHARDING_PATTERNS + layers.LLAMA_SHARDING_PATTERNS, default=P())
 # A router that selects under a bias sows every expert's tokens beside the
 # held experts' rows: they are a gauge, and what moves the bias.
 Lfm2Config.family = Family(

@@ -2,7 +2,7 @@
 place (Mellum 2: sliding-window and full attention mixed 3:1, every MLP a
 top-8-of-64 SwiGLU expert layer, no shared expert).
 
-Built from models/llama.py's pieces: RMSNorm, rotate-half rotary, the
+Built from models/layers.py's pieces: RMSNorm, rotate-half rotary, the
 grouped-query projections and the attention call are `LlamaAttention`, told
 per layer what its kind changes: the window, and the rotary table (plain for
 `sliding_attention`; YaRN, with its factor on cos and sin, for
@@ -35,9 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.llama import (  # noqa: F401
-    LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm, apply_rope, rope_angles)
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LlamaAttention, RMSNorm, apply_rope, rope_angles
 from ray_tpu.ops import indexer
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, ExpertShare
@@ -301,7 +300,7 @@ class Mellum(nn.Module):
         # tokens route alike and the experts' load swings with the data.
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0))(idx)
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
         for i, kind in enumerate(cfg.layer_types):
             x = nn.remat(MellumBlock, policy=keep)(
@@ -312,7 +311,7 @@ class Mellum(nn.Module):
 
 
 MELLUM_SHARDING_RULES = ShardingRules(
-    EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS, default=P())
+    EXPERT_SHARE_SHARDING_PATTERNS + layers.LLAMA_SHARDING_PATTERNS, default=P())
 
 
 def step_metrics(cfg, sown, params, tokens):
@@ -326,9 +325,9 @@ def step_metrics(cfg, sown, params, tokens):
     selected = jax.tree_util.tree_leaves_with_path(sown.get("attn_keys", {}))
     for name, metric in (("selected", "attn_keys_selected_mean"),
                          ("select_passes", "attn_select_passes_mean")):
-        layers = [x for path, x in selected if jax.tree_util.DictKey(name) in path]
-        if layers:
-            metrics[metric] = sum(layers) / len(layers)
+        sowed = [x for path, x in selected if jax.tree_util.DictKey(name) in path]
+        if sowed:
+            metrics[metric] = sum(sowed) / len(sowed)
     return metrics
 
 

@@ -11,22 +11,10 @@ The equations, with d the hidden size and x the residual stream:
                                      float32 sums
     loss   = mean cross-entropy of the next token             float32
 
-`M` (models/granite.py's `Mamba2Mixer`; the recurrence is ops/ssd.py, pallas
-kernels ssd_fwd and ssd_bwd on a TPU, the convolution ops/short_conv.py,
-causal_conv_fwd and causal_conv_bwd there, the gate and the norm a group at
-a time ops/gated_norm.py, gated_norm_fwd and gated_norm_bwd there), H heads
-of P, G groups of B and C, state N, inner width H P (not `expand` x d):
-
-    [z | xBC | dt] = W_in u          d -> H P + (H P + 2 G N) + H, no bias
-    xBC <- silu(conv(xBC))           depthwise, causal, K taps, with bias
-    x (T, H, P), B (T, G, N), C (T, G, N) = split(xBC)
-    head h reads B and C of group h // (H / G)
-    Delta = softplus(dt + dt_bias);  A = -exp(A_log)
-    S_t = exp(Delta_t A) S_{t-1} + Delta_t x_t B_t^T;   y_t = S_t C_t + D x_t
-    y <- RMSNorm_g(y * silu(z))      the gate first, then an RMS norm over
-                                     each group's H P / G channels on its
-                                     own, one weight of H P
-    out = W_out y                    H P -> d, no bias
+`M`: models/layers.py's `Mamba2Mixer`, which states the equations and what
+computes them: H heads of P, inner width H P (not `expand` x d), eight
+groups of B and C, and the gated norm over each group's H P / G channels on
+its own (`norm_groups`).
 
 `*`: `LlamaAttention(rotary=False)`: `n_head` query and `n_kv_head` key-value
 heads of `head_dim` (heads x head_dim is not d), causal softmax of q k^T /
@@ -43,7 +31,7 @@ whole on every chip, and counted once when shares are summed.
 
 All blocks are one parameter group, `p_0` (`p_0/h_0` ..), of three unlike
 structures; the group sows one entry into "choices", its expert layers'
-indices stacked, (expert layers, B, T, top_k): models/lfm2.py says why.
+indices stacked, (expert layers, B, T, top_k): `layers.sow_choices`.
 Each block is under nn.remat with the plan of models/remat.py. What the
 published config leaves open (no clamp on Delta, Mamba-2's own initialisers,
 the bias's rule, no auxiliary loss) is under `assumed` in
@@ -58,12 +46,10 @@ from typing import Any, ClassVar, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, granite, remat
-from ray_tpu.models.granite import Mamba2Mixer
-from ray_tpu.models.llama import LLAMA_SHARDING_PATTERNS, LlamaAttention, RMSNorm
+from ray_tpu.models import Family, layers, remat
+from ray_tpu.models.layers import LlamaAttention, Mamba2Mixer, RMSNorm, SharedExpert
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, RELU2, SIGMOID, ExpertShare
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -167,20 +153,6 @@ class NemotronHConfig:
         return cls(**base)
 
 
-class SharedExpert(nn.Module):
-    """The expert of two matrices every token passes through beside its
-    routed ones."""
-
-    config: NemotronHConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
-        up = checkpoint_name(dense(cfg.shared_dim, "up")(x), "shared_up")
-        return dense(cfg.n_embd, "down")(RELU2.hidden(up))
-
-
 class NemotronHBlock(nn.Module):
     """A block and the choices of its expert layer, (x, (B, T, top_k)); a
     block of another kind hands up None."""
@@ -209,7 +181,7 @@ class NemotronHBlock(nn.Module):
                 hand_up_choices=True, gate_eps=cfg.gate_eps, products_kept=self.products_kept,
                 form=RELU2, name="moe")(u)
             with jax.named_scope("moe.shared"):
-                mixed = mixed + SharedExpert(cfg, name="shared")(u)
+                mixed = mixed + SharedExpert(cfg, RELU2, name="shared")(u)
         return pin(x + mixed, self.stream), chosen
 
 
@@ -267,14 +239,14 @@ def count_params(cfg: NemotronHConfig) -> int:
 
 def _block_bytes(cfg: NemotronHConfig, itemsize: int) -> int:
     """What the largest block's backward works in, bytes a token, from its
-    widths: a Mamba block's as models/granite.py:_block_bytes says, with no
-    MLP after it; an expert block's buffers of a row an assignment that are
+    widths: a Mamba block's mixer (`layers.mixer_bytes`), with no MLP after
+    it; an expert block's buffers of a row an assignment that are
     as wide as the stream (models/kanana.py:_block_bytes) and the shared
     expert's up product and what relu squared makes of it, each with its
     gradient; an attention block's four operands of the kernel and its
     output, each with its gradient."""
     blocks = {
-        MAMBA: granite.mixer_bytes(cfg, itemsize),
+        MAMBA: layers.mixer_bytes(cfg, itemsize),
         EXPERTS: cfg.top_k * 4 * cfg.n_embd * itemsize + 4 * cfg.shared_dim * itemsize,
         ATTENTION: 2 * 4 * cfg.n_head * cfg.head_dim * itemsize}
     return max(blocks[kind] for kind in set(cfg.layer_types))
@@ -295,10 +267,8 @@ class NemotronHGroup(nn.Module):
         for i, kind in enumerate(cfg.layer_types):
             x, chosen = nn.remat(NemotronHBlock, policy=self.keep)(
                 cfg, kind, self.stream, self.products_kept, name=f"h_{i}")(x)
-            if chosen is not None:
-                choices.append(chosen)
-        if choices:
-            self.sow("choices", "experts", jnp.stack(choices))
+            choices.append(chosen)
+        layers.sow_choices(self, choices)
         return x
 
 
@@ -311,29 +281,23 @@ class NemotronH(nn.Module):
         cfg = self.config
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(0.02))(idx)
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         products = any(n in RELU2.products for n in remat.traced(cfg).names)
         x = NemotronHGroup(cfg, keep, self.stream, products, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        # operands in the compute dtype, float32 logits (models/kanana.py's head)
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.n_embd, cfg.vocab_size), jnp.float32)
-        with jax.named_scope("lm_head"):
-            return jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
+        return layers.untied_head(self, cfg, x)
 
 
-NEMOTRON_H_SHARDING_RULES = ShardingRules([
-    (r"shared/up/kernel", P("fsdp", "tp")),
-    (r"shared/down/kernel", P("tp", "fsdp")),
-    (r"lm_head$", P("fsdp", "tp")),
-] + granite.MAMBA_SHARDING_PATTERNS + EXPERT_SHARE_SHARDING_PATTERNS + LLAMA_SHARDING_PATTERNS,
-    default=P())
+NEMOTRON_H_SHARDING_RULES = ShardingRules(
+    layers.SHARED_EXPERT_SHARDING_PATTERNS + layers.UNTIED_HEAD_SHARDING_PATTERNS
+    + layers.MAMBA_SHARDING_PATTERNS + EXPERT_SHARE_SHARDING_PATTERNS
+    + layers.LLAMA_SHARDING_PATTERNS, default=P())
 
 
 def step_metrics(cfg, sown, params, tokens):
     """`Family.metrics`: what the Mamba layers sowed and what the expert
     layers sowed, each by its own reducer."""
-    return {**granite.step_metrics(cfg, sown, params, tokens),
+    return {**layers.ssm_step_metrics(cfg, sown, params, tokens),
             **moe.step_metrics(cfg, sown, params, tokens)}
 
 

@@ -90,8 +90,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models import Family, remat
-from ray_tpu.models.granite import _conv_init, _dt_bias_init
+from ray_tpu.models import Family, layers, remat
 from ray_tpu.ops.selective_scan import chunk_of, selective_scan
 from ray_tpu.ops.short_conv import causal_conv_within
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -267,13 +266,13 @@ class Mamba1Mixer(nn.Module):
         with jax.named_scope("ssm.in_proj"):
             uz = dense(2 * c, "in_proj")(x)
         with jax.named_scope("ssm.conv"):
-            w = self.param("conv_kernel", _conv_init, (cfg.ssm_conv, c), f32)
+            w = self.param("conv_kernel", layers.conv_init, (cfg.ssm_conv, c), f32)
             bias = self.param("conv_bias", nn.initializers.zeros, (c,), f32)
             _, u, z = causal_conv_within(uz, w, bias)
         with jax.named_scope("ssm.x_proj"):
             rank, bm, cm = jnp.split(dense(r + 2 * n, "x_proj")(u), [r, r + n], axis=-1)
         with jax.named_scope("ssm.dt"):  # the steps stay float32 into the scan
-            delta = jax.nn.softplus(Affine(c, cfg.dtype, _dt_kernel_init, _dt_bias_init,
+            delta = jax.nn.softplus(Affine(c, cfg.dtype, _dt_kernel_init, layers.dt_bias_init,
                                            name="dt_proj")(rank))
         with jax.named_scope("ssm.scan"):
             a = -jnp.exp(self.param("A_log", _a_log_init, (c, n), f32))
@@ -453,7 +452,7 @@ def remat_plan(cfg: Phi4FlashConfig, shape: remat.StepShape, limit) -> remat.Rem
     tokens = shape.rows * shape.seq_len
     kinds = cfg.layer_types
     attn, mamba = sum(k in (WINDOW, FULL, CROSS) for k in kinds), kinds.count(MAMBA)
-    share = lambda nbytes, layers: nbytes * layers // cfg.n_layer
+    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     # the calls' heads are n_head of twice the model's width
     name_bytes = {name: share(nbytes, attn) for name, nbytes in remat.attention_bytes(
         shape, cfg.n_head, 2 * cfg.head_dim, itemsize).items()}
@@ -514,7 +513,7 @@ class Phi4Flash(nn.Module):
         emb = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                        embedding_init=nn.initializers.normal(0.02))
         x = emb(idx)
-        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)  # as models/llama.py's
+        keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         x = Phi4FlashGroup(cfg, keep, self.stream, name="p_0")(x)
         x = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=cfg.dtype, name="final_norm")(x)
         # the tied head in float32 under the untied one's name (models/granite.py)
@@ -537,16 +536,10 @@ PHI4_FLASH_SHARDING_RULES = ShardingRules([
 
 def step_metrics(cfg, sown, params, tokens):
     """`Family.metrics`: of what the Mamba layers sowed the most negative
-    log-decay of a chunk and the largest carried-state entry (models/
-    granite.py's two gauges, under its names); lambda's range over the
+    log-decay of a chunk and the largest carried-state entry
+    (`layers.ssm_step_metrics`, Mamba-2's two gauges); lambda's range over the
     attention layers; and the bytes that cross blocks beside the stream."""
-    layers = [layer for group in sown.get("ssm_stats", {}).values() for layer in group.values()]
-    metrics = {}
-    if layers:
-        metrics["ssm_chunk_log_decay_min"] = jnp.min(jnp.stack(
-            [s["mamba"]["chunk_log_decay_min"][0] for s in layers]))
-        metrics["ssm_state_abs_max"] = jnp.max(jnp.stack(
-            [s["mamba"]["state_abs_max"][0] for s in layers]))
+    metrics = layers.ssm_step_metrics(cfg, sown, params, tokens)
     lams = [mixer["lambda"][0] for group in sown.get("attn_stats", {}).values()
             for layer in group.values() for mixer in layer.values()]
     if lams:

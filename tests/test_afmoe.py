@@ -20,7 +20,7 @@ import pytest
 from bench import families
 from ray_tpu.models import afmoe, remat
 from ray_tpu.models.afmoe import FULL, SLIDING, AfmoeBlock, AfmoeConfig
-from ray_tpu.models.kanana import SharedExpert
+from ray_tpu.models.layers import SharedExpert
 from ray_tpu.models.llama import LlamaAttention
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops import attention
@@ -114,7 +114,7 @@ def test_the_reference_s_own_choices_are_the_system_s(seeded):
 
 
 def _attention_told(**told):
-    """models/llama.py's `LlamaAttention` with some of what the block tells it
+    """models/layers.py's `LlamaAttention` with some of what the block tells it
     told otherwise."""
     return lambda cfg, **kw: LlamaAttention(cfg, **{**kw, **told})
 

@@ -102,11 +102,8 @@ FAMILY_WORDS = {"moe_load", "moe_router", "attn_keys", "attn_gate", "ssm_stats",
                 SELECTION_BIAS}
 
 
-@pytest.mark.parametrize("path", ["ray_tpu/parallel/train_step.py", "ray_tpu/train/_telemetry.py"])
-def test_the_layers_above_the_families_name_none_of_them(path):
-    """Neither file imports a family's module (models/remat.py and
-    models/loss.py are none), and none of its string constants is, whole, a
-    collection's name or the held leaf's (docstrings may speak of them)."""
+def _imported(path):
+    """The file's syntax tree and every module or name it imports, dotted."""
     with open(os.path.join(ROOT, path)) as f:
         tree = ast.parse(f.read())
     imported = set()
@@ -115,9 +112,38 @@ def test_the_layers_above_the_families_name_none_of_them(path):
             imported |= {a.name for a in node.names}
         elif isinstance(node, ast.ImportFrom) and node.module:
             imported |= {node.module} | {f"{node.module}.{a.name}" for a in node.names}
+    return tree, imported
+
+
+@pytest.mark.parametrize("path", ["ray_tpu/parallel/train_step.py", "ray_tpu/train/_telemetry.py"])
+def test_the_layers_above_the_families_name_none_of_them(path):
+    """Neither file imports a family's module (models/remat.py and
+    models/loss.py are none), and none of its string constants is, whole, a
+    collection's name or the held leaf's (docstrings may speak of them)."""
+    tree, imported = _imported(path)
     families = {m for m in imported if m.startswith("ray_tpu.models.") and not m.startswith(
         ("ray_tpu.models.remat", "ray_tpu.models.loss"))}
     assert not families
     strings = {n.value for n in ast.walk(tree)
                if isinstance(n, ast.Constant) and isinstance(n.value, str)}
     assert not strings & FAMILY_WORDS
+
+
+MODEL_FILES = sorted(f[:-3] for f in os.listdir(os.path.join(ROOT, "ray_tpu", "models"))
+                     if f.endswith(".py") and f != "__init__.py")
+SHARED = {"remat", "loss", "layers"}  # what any family's file may import of ray_tpu.models
+VARIANTS = {"gpt2_moe": {"gpt2"}}  # a variant of a family imports that family: the one
+
+
+@pytest.mark.parametrize("name", sorted(set(MODEL_FILES) - {"remat", "loss"}))
+def test_a_family_s_file_imports_no_other_family_s(name):
+    """models/__init__.py's rule: a family's file imports `ray_tpu.models`
+    itself, `.remat`, `.loss` and `.layers`, and no other family's file,
+    however the import is spelt, and no private name of any; `layers.py`,
+    which they all import, imports no family."""
+    _, imported = _imported(f"ray_tpu/models/{name}.py")
+    modules = {m.split(".")[2] for m in imported if m.startswith("ray_tpu.models.")}
+    allowed = SHARED - {"layers"} if name == "layers" else SHARED | VARIANTS.get(name, set())
+    assert not (modules & set(MODEL_FILES)) - allowed
+    assert not [m for m in imported
+                if m.startswith("ray_tpu.models.") and m.rsplit(".", 1)[1].startswith("_")]

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from bench import families
-from ray_tpu.models import kanana, remat
+from ray_tpu.models import kanana, layers, remat
 from ray_tpu.models.kanana import Kanana, KananaConfig
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops import attention, short_conv
@@ -142,9 +142,9 @@ def test_the_comparison_catches_what_is_dropped(seeded, what, monkeypatch):
     if what in DROPPED:
         monkeypatch.setattr(attention, "latent_attention", _changed_attention(DROPPED[what]))
     elif what == "rotary":
-        monkeypatch.setattr(kanana, "apply_rope", lambda x, angles: x)
+        monkeypatch.setattr(layers, "apply_rope", lambda x, angles: x)
     elif what == "pairs":
-        monkeypatch.setattr(kanana, "pairs_apart", lambda x: x)
+        monkeypatch.setattr(layers, "pairs_apart", lambda x: x)
     elif what == "shared_expert":
         params = jax.tree_util.tree_map_with_path(
             lambda path, p: jnp.zeros_like(p) if "shared" in jax.tree_util.keystr(path)
@@ -196,7 +196,7 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
         scaling=cfg.routed_scaling, gate_eps=cfg.gate_eps)
     p = make(0, None).init(jax.random.PRNGKey(7), x)["params"]
     p[SELECTION_BIAS] = 0.3 * jax.random.normal(jax.random.PRNGKey(8), (8,))
-    shared = kanana.SharedExpert(cfg)
+    shared = layers.SharedExpert(cfg)
     p_shared = shared.init(jax.random.PRNGKey(9), x)["params"]
     with jax.default_matmul_precision("highest"):
         routed, own = FAMILY._routed_mlp(x, p, sizes, None)

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from bench import families
-from ray_tpu.models import kanana, kimi_linear, remat
+from ray_tpu.models import kanana, kimi_linear, layers, remat
 from ray_tpu.models.kimi_linear import KDA, MLA, KimiLinear, KimiLinearConfig
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.ops import attention, kda, short_conv
@@ -155,7 +155,7 @@ def test_the_comparison_catches_what_is_dropped(seeded, what, monkeypatch):
             None, *jnp.split(jax.nn.silu(wide), cuts, axis=-1), None))
     elif what == "latent_rotary":
         monkeypatch.setattr(kimi_linear, "LatentAttention",
-                            lambda cfg, rotary, name: kanana.LatentAttention(cfg, name=name))
+                            lambda cfg, rotary, name: layers.LatentAttention(cfg, name=name))
     elif what == "shared_expert":
         params = jax.tree_util.tree_map_with_path(
             lambda path, p: jnp.zeros_like(p) if "shared" in jax.tree_util.keystr(path)
@@ -174,9 +174,9 @@ def test_the_latent_mixer_with_and_without_rotary(rotary):
     written out, and the two apart."""
     cfg = kanana.KananaConfig.tiny(dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, cfg.n_embd))
-    layer = kanana.LatentAttention(cfg, rotary=rotary)
+    layer = layers.LatentAttention(cfg, rotary=rotary)
     p = layer.init(jax.random.PRNGKey(1), x)["params"]
-    assert sorted(p) == sorted(kanana.LatentAttention(cfg).init(jax.random.PRNGKey(1), x)["params"])
+    assert sorted(p) == sorted(layers.LatentAttention(cfg).init(jax.random.PRNGKey(1), x)["params"])
     sizes = {"num_attention_heads": cfg.n_head, "qk_nope_head_dim": cfg.nope_dim,
              "qk_rope_head_dim": cfg.rope_dim, "kv_lora_rank": cfg.kv_latent,
              "v_head_dim": cfg.v_dim, "rms_norm_eps": cfg.rms_eps, "rope_theta": cfg.rope_theta}
@@ -206,7 +206,7 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
         headroom=kimi_linear.EXPERT_HEADROOM)
     p = make(0, None).init(jax.random.PRNGKey(7), x)["params"]
     p[SELECTION_BIAS] = 0.3 * jax.random.normal(jax.random.PRNGKey(8), (8,))
-    shared = kanana.SharedExpert(cfg)
+    shared = layers.SharedExpert(cfg)
     p_shared = shared.init(jax.random.PRNGKey(9), x)["params"]
     with jax.default_matmul_precision("highest"):
         routed, own = FAMILY._routed_mlp(x, p, sizes, None)

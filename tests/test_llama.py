@@ -11,14 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.llama import (
-    LlamaConfig,
-    apply_rope,
-    forward,
-    init_params,
-    num_params,
-    rope_angles,
-)
+from ray_tpu.models.layers import apply_rope, rope_angles
+from ray_tpu.models.llama import LlamaConfig, forward, init_params, num_params
 from ray_tpu.parallel.mesh import make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 

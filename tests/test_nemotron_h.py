@@ -9,6 +9,7 @@ cell and the cell's lowered step."""
 
 import contextlib
 import dataclasses
+import hashlib
 import json
 import os
 
@@ -18,11 +19,11 @@ import numpy as np
 import pytest
 
 from bench import families
-from ray_tpu.models import granite, nemotron_h, remat
-from ray_tpu.models.llama import RMSNorm
+from ray_tpu.models import layers, nemotron_h, remat
+from ray_tpu.models.layers import RMSNorm, SharedExpert
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.models.nemotron_h import (ATTENTION, EXPERTS, MAMBA, NemotronH, NemotronHBlock,
-                                       NemotronHConfig, SharedExpert, count_params, layer_types)
+                                       NemotronHConfig, count_params, layer_types)
 from ray_tpu.ops import attention, gated_norm, moe, short_conv, ssd
 from ray_tpu.ops.moe import RELU2, SELECTION_BIAS, SELECTION_BIAS_RATE, SIGMOID, ExpertShare
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
@@ -159,7 +160,7 @@ def test_the_comparison_catches_what_is_dropped(seeded, what, monkeypatch):
     sizes, params, idx, targets, held, ref_loss, _ = seeded
     cfg = FAMILY.build(sizes, "float32")
     if what == "group_zero":
-        monkeypatch.setattr(granite, "ssd", _group_zero(granite.ssd))
+        monkeypatch.setattr(layers, "ssd", _group_zero(layers.ssd))
     elif what == "one_norm":
         real_mixer = nemotron_h.Mamba2Mixer
         monkeypatch.setattr(nemotron_h, "Mamba2Mixer", lambda cfg, norm_groups, name: real_mixer(
@@ -260,9 +261,12 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     p = make(0, None).init(jax.random.PRNGKey(7), x)["params"]
     assert sorted(p) == ["down", SELECTION_BIAS, "router", "up"]  # no gate matrix
     p[SELECTION_BIAS] = 0.3 * jax.random.normal(jax.random.PRNGKey(8), (16,))
-    shared = SharedExpert(cfg)
+    shared = SharedExpert(cfg, RELU2)
     p_shared = shared.init(jax.random.PRNGKey(9), x)["params"]
+    # the one class's leaves are its form's: no gate matrix here, one by default
     assert sorted(p_shared) == ["down", "up"]
+    assert sorted(jax.eval_shape(SharedExpert(cfg).init, jax.random.PRNGKey(9), x)["params"]) \
+        == ["down", "gate", "up"]
     with jax.default_matmul_precision("highest"):
         routed, own = FAMILY._routed(x, p, sizes, None)
         want = routed + FAMILY._relu2(x, p_shared["up"]["kernel"], p_shared["down"]["kernel"])
@@ -414,7 +418,7 @@ def test_remat_plan_of_the_cell():
     # the expert block's is the largest: a row an assignment as wide as the
     # stream four times over, and the shared expert's up product four times
     assert chosen.block_bytes == tokens * (6 * 4 * 2688 * 2 + 4 * 3712 * 2) == tokens * 158_720
-    assert granite.mixer_bytes(cfg, 2) == 2 * 10_304 + 6 * (6144 + 2 * 4096) == 106_624
+    assert layers.mixer_bytes(cfg, 2) == 2 * 10_304 + 6 * (6144 + 2 * 4096) == 106_624
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(nemotron_h, "REMAT_RUNGS", ())
         # one attention layer's output and logsumexp, four expert layers'
@@ -425,6 +429,15 @@ def test_remat_plan_of_the_cell():
     assert nemotron_h.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
 
 
+# This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
+# rule), as tests/test_mellum.py:_traced_text gives it, taken on PR 58's tree
+# (7ae9f96) before PR 59 moved the layers it shares into models/layers.py and
+# made its shared expert `SharedExpert(form=RELU2)`: the one cell's program no
+# test held until then. PR 51 last moved it by design: the flash calls cut
+# their masked tiles into sub-tiles of 128 (`FlashTiles.sub_fwd`, `.sub_bwd`).
+NEMOTRON_H_STEP = "2349b488b86a75b45e3b74f451d0562c09c96971a321da6c08a3ff8ea0488bf8"
+
+
 def test_the_cell_s_step_runs_the_scan_s_kernels_and_two_matrices_an_expert(monkeypatch):
     """The cell's own step lowered for a TPU on this box: the four Mamba
     layers on the `pallas` path (ssd_bwd once each; ssd_fwd once where the
@@ -432,8 +445,9 @@ def test_the_cell_s_step_runs_the_scan_s_kernels_and_two_matrices_an_expert(monk
     pair of ops/short_conv.py and the gated norm's of ops/gated_norm.py beside
     them, their forwards twice: no plan names their outputs), one attention
     layer with the causal flash pair, four expert layers whose forward is two
-    `gmm` a buffer and not three."""
-    from tests.test_mellum import expert_calls
+    `gmm` a buffer and not three. And the program is the pinned one: a change
+    that means to leave this cell's program alone is held to it."""
+    from tests.test_mellum import _traced_text, expert_calls
 
     for mod in (attention, ssd, short_conv, gated_norm):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
@@ -445,8 +459,7 @@ def test_the_cell_s_step_runs_the_scan_s_kernels_and_two_matrices_an_expert(monk
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
-    text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
-        lowering_platforms=("tpu",)).as_text()
+    text = _traced_text(ts._step.trace(state, {"idx": tok, "targets": tok}))
     names = remat.traced(cfg).names
     calls = kernel_tally(text)
     assert calls.pop("kernel") and "@gmm" in text and "@tgmm" in text
@@ -468,6 +481,7 @@ def test_the_cell_s_step_runs_the_scan_s_kernels_and_two_matrices_an_expert(monk
                                          "moe_token_sum": 4}
     else:
         assert expert_calls(text, 4) == {"gmm": 2 * 2 + 2 * 4, "tgmm": 2 * 2, "moe_token_sum": 4}
+    assert hashlib.sha256(text.encode()).hexdigest() == NEMOTRON_H_STEP
 
 
 def test_step_reports_both_kinds_of_gauge_through_the_telemetry():

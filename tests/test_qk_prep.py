@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.llama import LlamaAttention, LlamaConfig, rope_angles
+from ray_tpu.models.layers import rope_angles
+from ray_tpu.models.llama import LlamaAttention, LlamaConfig
 from ray_tpu.ops import attention
 from ray_tpu.ops.qk_prep import qk_prep, rope_tables
 

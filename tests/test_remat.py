@@ -30,7 +30,7 @@ MLP = ("mlp_up",)
 GATE_UP, OUT = ("moe_gate", "moe_up"), ("moe_out",)  # ops/moe.py:KEPT_PRODUCTS
 UP_OUT = ("moe_up", "moe_out")  # ops/moe.py:RELU2.products
 CONV, LATENT, SHARED = ("conv_bcu", "conv_y"), ATTN + ("attn_q_shared", "attn_k_shared"), ("shared_up",)
-GATE = ("attn_gate",)  # models/llama.py:LlamaAttention's gate projection (models/afmoe.py)
+GATE = ("attn_gate",)  # models/layers.py:LlamaAttention's gate projection (models/afmoe.py)
 SSCAN = ("sscan_y", "sscan_states")  # ops/selective_scan.py's output and chunk states
 # cell: configuration, (B, T) of its traffic, and the names the rule takes
 # on a v5e after the first rung (the four routed cells' since PR 45, which
