@@ -616,7 +616,7 @@ def _check_kda_vs_plain(seed, on_tpu):
     def gated(interpret):
         def form(*ops):
             o, _, last = kda.kda_gated(*ops, l2_eps=L2_EPS, interpret=interpret)
-            return o, last.swapaxes(-1, -2)
+            return o.reshape(ops[2].shape), last.swapaxes(-1, -2)  # o comes (b, T, H x 128)
         return form
 
     def plain(q, k, v, f, a_log, dt_bias, beta):
@@ -651,6 +651,59 @@ def _check_kda_vs_plain(seed, on_tpu):
         run(chunked, part, w[:, :n]),
         run(plain, as_f32(part), w[:, :n]), "chunked form")
     return report
+
+
+def _check_kda_norm_vs_plain(seed, on_tpu):
+    """ops/kda_norm.py's pair (bf16 o and z, the float32 weight of one head)
+    against the mixer's plain lines in float32 (`kda_norm_plain`: RMSNorm
+    over a (..., 32, 128) view, the weight, the sigmoid gate) at
+    kimi_linear_l5_ep32.t8192's shape, o and z (2, 8192, 32 x 128), same
+    seed, the first head's values a thousand times the last's: y and the
+    gradients of o, z and the weight as max-abs error over the reference's
+    max-abs value, the first and the last head each on its own beside the
+    whole (a sum that leaked across a head's edge would show in the small
+    one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import kda_norm
+
+    b, t, h, d = (2, 8192, 32, 128) if on_tpu else (2, 40, 4, 128)
+    eps = 1e-5
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    scale = jnp.repeat(jnp.logspace(1.5, -1.5, h), d)
+    o = (jax.random.normal(ks[0], (b, t, h * d)) * scale).astype(jnp.bfloat16)
+    z = (2 * jax.random.normal(ks[1], (b, t, h * d))).astype(jnp.bfloat16)
+    weight = 1 + 0.1 * jax.random.normal(ks[2], (d,), jnp.float32)
+    dy = jax.random.normal(ks[3], (b, t, h * d), jnp.float32)
+
+    def run(form, o, z):
+        def loss(o, z, weight):
+            y = form(o, z, weight)
+            return (y.astype(jnp.float32) * dy).sum(), y
+
+        grads, y = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))(o, z, weight)
+        return (y, *grads)
+
+    kernels = run(lambda o, z, w: kda_norm.kda_norm(
+        o, z, w, eps, interpret=None if on_tpu else True), o, z)
+    plain = run(lambda o, z, w: kda_norm.kda_norm_plain(o, z, w, eps),
+                o.astype(jnp.float32), z.astype(jnp.float32))
+    errs = {}
+    for name, got, want in zip(("y", "do", "dz", "dweight"), kernels, plain):
+        got = got.astype(jnp.float32)
+        if got.shape != want.shape or not bool(jnp.isfinite(got).all()):
+            raise RuntimeError(f"kda norm {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        if name != "dweight":
+            for part, at in (("first", slice(0, d)), ("last", slice((h - 1) * d, h * d))):
+                errs[f"{name}_{part}_head"] = float(
+                    jnp.abs(got[..., at] - want[..., at]).max() / jnp.abs(want[..., at]).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"kda norm kernels vs the plain lines at {o.shape} beyond "
+                           f"{ATTN_REL_TOL}: {errs}")
+    return {"shape": list(o.shape), "heads": h, "rel_err": errs,
+            "norm_path": kda_norm.norm_path(d)}
 
 
 def _check_sscan_vs_recurrence(seed, on_tpu):
@@ -906,7 +959,9 @@ def _flash_calls_by_cell(on_tpu):
     (ops/gated_norm.py): gated_norm_bwd once and gated_norm_fwd twice. A
     layer that is a delta rule over a state (`kda`) has kda_bwd once, kda_fwd
     once where the plan saves the chunk states (`kda_states`), else twice,
-    and the convolution's pair as a `mamba` layer's. A `mamba` layer of
+    the head norm's pair after it (ops/kda_norm.py: kda_norm_bwd once and
+    kda_norm_fwd twice, no plan names its output) and the convolution's pair
+    as a `mamba` layer's. A `mamba` layer of
     a family whose scan's decay differs by state (a configuration with
     `ssm_rank`: Mamba-1) has sscan_bwd once, and sscan_fwd once where the
     plan saves `sscan_y`, in place of ssd's pair; a gated memory unit
@@ -959,6 +1014,8 @@ def _flash_calls_by_cell(on_tpu):
                            and found["sscan_bwd"] == selective
                            and found["kda_fwd"] == deltas * (1 if "kda_states" in saved else 2)
                            and found["kda_bwd"] == deltas
+                           and found["kda_norm_fwd"] == 2 * deltas
+                           and found["kda_norm_bwd"] == deltas
                            and found["ssd_fwd"] == scan_fwd
                            and found["ssd_bwd"] == scans - selective
                            and found["gated_conv_fwd"] == conv_fwd
@@ -1056,6 +1113,7 @@ def one_chip_loop(config):
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
     report["gated_attention_vs_plain"] = _check_gated_attention(config["seed"], on_tpu)
     report["kda_vs_plain"] = _check_kda_vs_plain(config["seed"], on_tpu)
+    report["kda_norm_vs_plain"] = _check_kda_norm_vs_plain(config["seed"], on_tpu)
     report["sscan_vs_recurrence"] = _check_sscan_vs_recurrence(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()
     report["selected_flash"] = _selected_flash_plan()

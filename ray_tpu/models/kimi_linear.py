@@ -41,6 +41,10 @@ KDA mixer (`KimiDeltaAttention`), H heads of `kda_head_dim` (d_k = d_v = 128):
     y = W_o (RMSNorm_head(o) * w_norm * sigmoid(W_g2 (W_g1 h)))
                                              the norm over a head's 128 alone,
                                              one weight of that width
+                                             (ops/kda_norm.py: kda_norm_fwd and
+                                             kda_norm_bwd on a TPU, on o as
+                                             kda_fwd wrote it, (B, T, H x 128);
+                                             the plain lines elsewhere)
 
 Latent mixer: models/layers.py's `LatentAttention` told `rotary=False`
 (`mla_use_nope`): the `rope_dim` entries of q_pe and k_pe are plain
@@ -73,8 +77,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import Family, layers, remat
-from ray_tpu.models.layers import LatentAttention, LlamaMLP, RMSNorm, SharedExpert
-from ray_tpu.ops import kda, moe
+from ray_tpu.models.layers import LatentAttention, LlamaMLP, NormWeight, RMSNorm, SharedExpert
+from ray_tpu.ops import kda, kda_norm, moe
 from ray_tpu.ops.moe import EXPERT_SHARE_SHARDING_PATTERNS, SIGMOID, ExpertShare
 from ray_tpu.ops.short_conv import causal_conv_within
 from ray_tpu.parallel.mesh import ShardingRules, pin
@@ -217,7 +221,8 @@ class KimiLinearConfig:
 class KimiDeltaAttention(nn.Module):
     """(B, T, d) -> (B, T, d): the module docstring's KDA mixer. Sows into
     "kda_stats" the mean decay of a step, the mean beta, the RMS of the state
-    after the last token and which path the delta rule took (1: the kernels)."""
+    after the last token and which path the delta rule and the head norm
+    each took (1: the kernels)."""
 
     config: KimiLinearConfig
 
@@ -256,11 +261,11 @@ class KimiDeltaAttention(nn.Module):
             stat("beta_mean", still(beta).mean())
             stat("state_rms", jnp.sqrt(jnp.square(still(last)).mean()))
             stat("path_pallas", jnp.float32(kda.kda_path(t, dk, dk, cfg.kda_chunk) == "pallas"))
+            stat("norm_path_pallas", jnp.float32(kda_norm.norm_path(dk) == "pallas"))
         with jax.named_scope("kda.norm"):
             gate = dense(inner, "g_b_proj")(dense(cfg.kda_rank, "g_a_proj")(x))
-            o = RMSNorm(cfg.rms_eps, name="o_norm")(o)  # over a head's 128, one weight
-            y = (o.reshape(b, t, inner).astype(f32) * jax.nn.sigmoid(gate.astype(f32))
-                 ).astype(cfg.dtype)
+            # over a head's 128, one weight; o stays (B, T, H x 128) from kda_fwd to W_o
+            y = kda_norm.kda_norm(o, gate, NormWeight(name="o_norm")(dk), cfg.rms_eps)
         with jax.named_scope("kda.out_proj"):
             return dense(cfg.n_embd, "o_proj")(y)
 
@@ -320,9 +325,9 @@ class KimiLinearBlock(nn.Module):
 # call 2: the traced step's four `remat` calls; 17.9 since the kernels norm
 # q and k, PR 55) for 0.625 GiB a layer (the states 0.5 of it): 27.7 ms a
 # GiB, not measured as a step's difference, because the cell has no room
-# for it: its step holds 12.44 GiB with the first rung alone (13.32 before
-# PR 55), the rule reckons 12.68, the rung is 2.5 GiB and the limit 13.5,
-# so the rule takes nothing more there. It is stated for
+# for it: its step holds 12.04 GiB with the first rung alone (12.44 before
+# PR 60, 13.32 before PR 55), the rule reckons 12.18, the rung is 2.5 GiB
+# and the limit 13.5, so the rule takes nothing more there. It is stated for
 # a shape that has the room (fewer layers, a shorter sequence, state split
 # over chips). The latent layer's operands, the shared expert's and the
 # dense MLP's products are rungs in models/kanana.py at these widths and
@@ -374,20 +379,21 @@ def _block_bytes(cfg: KimiLinearConfig, itemsize: int) -> int:
     rematerialised apart, so it is the larger and not the sum). A KDA half's:
     the projection's three streams, the convolution's three, o and the gated
     o (eight arrays H x 128 wide in the compute dtype), each with its
-    gradient, two more of that width in float32 in the head norm and its gate
-    (`kda.norm`, XLA's), and the chunk states (H x 128 x 128 float32 a
-    chunk): 192 KB a token at the published widths in bf16. Until PR 55 the
-    normed q and k were arrays too, with their gradients and a third float32
-    pass round the kernels: 240 KB. A routed half's: the expert layer's
-    buffers of a row an assignment that are as wide as the stream; a dense
-    one's the MLP's gate and up with their gradients. The step compiled for
-    a v5e at the benchmark's cell holds 12.93 GiB by the compiler's count with
-    the first rung alone (13.78 before PR 55) and the chip's allocator read
-    12.44 (my chip run, PR 55, call 1; 13.32 before), where this makes the
-    rule reckon 12.68 (tests/test_remat.py,
-    tests/test_tpu_compile_kimi_linear.py)."""
+    gradient, and the chunk states (H x 128 x 128 float32 a chunk): 160 KB a
+    token at the published widths in bf16. Until PR 60 the head norm and its
+    gate were XLA's and held two more arrays of that width in float32
+    (192 KB); until PR 55 the normed q and k were arrays too, with their
+    gradients and a third float32 pass round the kernels (240 KB). A routed
+    half's: the expert layer's buffers of a row an assignment that are as
+    wide as the stream; a dense one's the MLP's gate and up with their
+    gradients. The step compiled for a v5e at the benchmark's cell holds 12.30
+    GiB by the compiler's count with the first rung alone (12.93 before PR 60,
+    13.78 before PR 55) and the chip's allocator read 12.04 of the step
+    itself (live bytes and reservation; my chip run, PR 60, call 2; 12.44
+    before, 13.32 before PR 55), where this makes the rule reckon 12.18
+    (tests/test_remat.py, tests/test_tpu_compile_kimi_linear.py)."""
     inner = cfg.kda_inner if KDA in cfg.layer_types else 0
-    mixer = (2 * 8 * itemsize + 2 * 4) * inner + 4 * inner * cfg.kda_head_dim // cfg.kda_chunk
+    mixer = 2 * 8 * itemsize * inner + 4 * inner * cfg.kda_head_dim // cfg.kda_chunk
     experts = cfg.top_k * 4 * cfg.n_embd * itemsize
     dense = 4 * cfg.intermediate * itemsize
     return max(mixer, experts if cfg.routed_layers else dense)
@@ -442,11 +448,11 @@ def step_metrics(cfg, sown, params, tokens):
     """`Family.metrics`: the expert layers' and the router's (ops/moe.py), and
     of what the KDA layers sowed the means over layers: a step's decay
     exp(g), beta, the RMS of the state after a sequence's last token, and the
-    share of layers whose delta rule ran the kernels."""
+    shares of layers whose delta rule and whose head norm ran the kernels."""
     metrics = moe.step_metrics(cfg, sown, params, tokens)
     stats = [layer["kda"] for period in sown.get("kda_stats", {}).values()
              for layer in period.values()]  # the KDA layers alone sow
-    for name in ("decay_mean", "beta_mean", "state_rms", "path_pallas"):
+    for name in ("decay_mean", "beta_mean", "state_rms", "path_pallas", "norm_path_pallas"):
         if stats:
             metrics[f"kda_{name}"] = jnp.mean(jnp.stack([s[name][0] for s in stats]))
     return metrics

@@ -59,9 +59,10 @@ class RMSNorm(nn.Module):
         return gated_norm(x, gate, w, self.eps, self.groups, within)
 
 
-class _NormWeight(nn.Module):
+class NormWeight(nn.Module):
     """An RMSNorm's leaf alone, `<name>/weight` (width,) float32: for a layer
-    whose norm a kernel computes (`LlamaAttention._on_rows`)."""
+    whose norm a kernel computes (`LlamaAttention._on_rows`,
+    models/kimi_linear.py's `KimiDeltaAttention`)."""
 
     @nn.compact
     def __call__(self, width):
@@ -222,7 +223,7 @@ class LlamaAttention(nn.Module):
         chosen = None if self.select is None else self.select(x, pos_offset)
         w_q = w_k = tables = None
         if self.qk_norm:
-            w_q, w_k = (_NormWeight(name=name)(hd) for name in ("q_norm", "k_norm"))
+            w_q, w_k = (NormWeight(name=name)(hd) for name in ("q_norm", "k_norm"))
         if self.rotary:
             with jax.named_scope("attn.rope"):
                 ang = rope_angles(hd, cfg.rope_theta, jnp.arange(T) + pos_offset, self.inv_freq)

@@ -542,7 +542,8 @@ def _fwd_call(q, k, v, g, a, dtb, beta, chunk, gated, l2_eps, interpret):
         scratch_shapes=[pltpu.VMEM((vd, kd), _F32)],
         compiler_params=_PARAMS, interpret=interpret, name="kda_fwd",
     )(*_operands(q, k, v, g, a, dtb, beta))
-    return o.reshape(b, t, h, vd), states.reshape(b, nc, h, vd, kd), last.reshape(b, h, vd, kd)
+    # o as the kernel wrote it, (b, T, H * V): the (b, T, H, V) view is a copy on the chip
+    return o, states.reshape(b, nc, h, vd, kd), last.reshape(b, h, vd, kd)
 
 
 def _bwd_call(q, k, v, g, a, dtb, beta, states, do, chunk, gated, l2_eps, interpret):
@@ -563,8 +564,7 @@ def _bwd_call(q, k, v, g, a, dtb, beta, states, do, chunk, gated, l2_eps, interp
                    jax.ShapeDtypeStruct((b, h, t, 1), _F32)],
         scratch_shapes=[pltpu.VMEM((vd, kd), _F32)],
         compiler_params=_PARAMS, interpret=interpret, name="kda_bwd",
-    )(*_operands(q, k, v, g, a, dtb, beta), do.reshape(b, t, h * vd),
-      states.reshape(b, nc, h * vd, kd))
+    )(*_operands(q, k, v, g, a, dtb, beta), do, states.reshape(b, nc, h * vd, kd))
     heads = lambda x, w: x.reshape(b, t, h, w)
     return (heads(dq, kd), heads(dk, kd), heads(dv, vd), heads(dg, kd),
             da.reshape(b, h, kd).sum((0, 2)).astype(a.dtype),
@@ -617,7 +617,9 @@ def _padded(t, chunk, *arrays):
 
 
 def kda(q, k, v, g, beta, chunk=CHUNK, *, interpret=None):
-    """o (b, T, H, V) in v's dtype, the float32 states at each chunk's start,
+    """o (b, T, H * V) in v's dtype, heads along the lanes as `kda_fwd` writes
+    it and ops/kda_norm.py reads it (its cotangent comes back so too), the
+    float32 states at each chunk's start,
     transposed, (b, chunks, H, V, K), and the state after the last step,
     (b, H, V, K): the module docstring's recurrence from q and k (b, T, H, K;
     the layer's, normed: `kda_gated` is the entry that norms), v (b, T, H, V),
@@ -631,6 +633,7 @@ def kda(q, k, v, g, beta, chunk=CHUNK, *, interpret=None):
                                chunk, False, None, bool(interpret))
     else:
         o, states, last = kda_chunked(q, k, v, g, beta, chunk)
+        o = o.reshape(*o.shape[:2], -1)
     return o[:, :t], states, last
 
 

@@ -27,6 +27,21 @@ def _operands(b, t, h, kd, vd, seed=0, decay=0.3, beta_shift=0.0, dtype=F32):
     return q, k, v, g, beta
 
 
+def _by_head(form):
+    """`kda.kda` or `kda.kda_gated` with o by head, (b, T, H, V), as the
+    recurrence gives it: the entries hand o on as the kernels write it,
+    (b, T, H * V)."""
+    def heads(q, k, v, *rest, **kw):
+        o, *others = form(q, k, v, *rest, **kw)
+        assert o.shape == (*v.shape[:2], v.shape[2] * v.shape[3])
+        return (o.reshape(v.shape), *others)
+
+    return heads
+
+
+kda_by_head, gated_by_head = _by_head(kda.kda), _by_head(kda.kda_gated)
+
+
 def _rel(got, want):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     return float(np.abs(got - want).max() / np.abs(want).max())
@@ -41,10 +56,10 @@ def _both_forms(ops, w, chunk):
     """(o, last state, five gradients) of the chunked form and of the
     recurrence: one compile a shape and chunk, whatever the values."""
     with jax.default_matmul_precision("highest"):
-        got, states, last = kda.kda(*ops, chunk=chunk)
+        got, states, last = kda_by_head(*ops, chunk=chunk)
         want, state = kda.kda_plain(*ops)
         return ((got, last.swapaxes(-1, -2), states,
-                 _grads(lambda *o: kda.kda(*o, chunk=chunk), ops, w, range(5))),
+                 _grads(lambda *o: kda_by_head(*o, chunk=chunk), ops, w, range(5))),
                 (want, state, _grads(kda.kda_plain, ops, w, range(5))))
 
 
@@ -98,13 +113,13 @@ def test_kernels_in_interpret_mode_are_both_forms(dtype, chunk, t):
     tol = 2e-5 if dtype == F32 else 2e-2
     with jax.default_matmul_precision("highest"):
         want, state = kda.kda_plain(*ops)
-        got, states, last = kda.kda(*ops, chunk=chunk, interpret=True)
-        chunked, states_c, last_c = kda.kda(*ops, chunk=chunk)
+        got, states, last = kda_by_head(*ops, chunk=chunk, interpret=True)
+        chunked, states_c, last_c = kda_by_head(*ops, chunk=chunk)
         assert got.dtype == dtype and states.dtype == F32
         assert _rel(got, want) < tol and _rel(got, chunked) < tol / 4
         assert _rel(states, states_c) < tol / 4 and _rel(last, last_c) < tol / 4
         w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-        ours = _grads(lambda *o: kda.kda(*o, chunk=chunk, interpret=True), ops, w, range(5))
+        ours = _grads(lambda *o: kda_by_head(*o, chunk=chunk, interpret=True), ops, w, range(5))
         plain = _grads(kda.kda_plain, ops, w, range(5))
         for name, a, b in zip("q k v g beta".split(), ours, plain):
             assert a.dtype == (F32 if name in ("g", "beta") else dtype)
@@ -152,7 +167,7 @@ def test_the_gate_made_inside_the_kernels(dtype, norm):
     normed = (lambda u: u) if eps is None else (lambda u: kda.l2norm(u, eps))
     plain = lambda q, k, v, f, a_log, dt_bias, beta: kda.kda_plain(
         normed(q), normed(k), v, kda.gate_log_decay(f, a_log, dt_bias), beta)
-    inside = lambda *o, **kw: kda.kda_gated(*o, l2_eps=eps, **kw)
+    inside = lambda *o, **kw: gated_by_head(*o, l2_eps=eps, **kw)
     with jax.default_matmul_precision("highest"):
         want, state = plain(*ops)
         got, states, last = inside(*ops, interpret=True)
@@ -169,7 +184,7 @@ def test_the_gate_made_inside_the_kernels(dtype, norm):
         if eps is None:
             return
         # today's order: XLA norms, the kernels take the normed pair
-        outside = lambda q, k, *o, **kw: kda.kda_gated(normed(q), normed(k), *o, **kw)
+        outside = lambda q, k, *o, **kw: gated_by_head(normed(q), normed(k), *o, **kw)
         for a, b in zip((got, states, last), outside(*ops, interpret=True)):
             np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
         for name, a, b in zip(names, ours, _grads(lambda *o: outside(*o, interpret=True),
@@ -192,13 +207,13 @@ def test_the_state_is_carried_across_a_call_s_chunks():
     steps, and a call whose chunks each start from nothing is far off."""
     ops = _operands(1, 96, 2, 16, 8, seed=5, decay=0.05)
     with jax.default_matmul_precision("highest"):
-        _, states, _ = kda.kda(*ops, chunk=32)
+        _, states, _ = kda_by_head(*ops, chunk=32)
         for n in (1, 2):
             _, want = kda.kda_plain(*(a[:, :32 * n] for a in ops))
             assert _rel(states[:, n].swapaxes(-1, -2), want) < 2e-5
         assert not np.asarray(states[:, 0]).any()
         want, _ = kda.kda_plain(*ops)
-        alone = jnp.concatenate([kda.kda(*(a[:, at:at + 32] for a in ops), chunk=32)[0]
+        alone = jnp.concatenate([kda_by_head(*(a[:, at:at + 32] for a in ops), chunk=32)[0]
                                  for at in (0, 32, 64)], axis=1)
     assert _rel(alone[:, :32], want[:, :32]) < 2e-5 and _rel(alone, want) > 0.1
 
@@ -209,4 +224,4 @@ def test_which_path_a_shape_takes(monkeypatch):
     assert kda.kda_path(8192, 128, 128) == kda.kda_path(100, 128, 128, 32) == "pallas"
     assert kda.kda_path(8192, 64, 128) == kda.kda_path(8192, 128, 128, 16) == "xla"
     with pytest.raises(ValueError, match="power of two"):
-        kda.kda(*_operands(1, 8, 1, 8, 8), chunk=6)
+        kda_by_head(*_operands(1, 8, 1, 8, 8), chunk=6)

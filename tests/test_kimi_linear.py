@@ -7,7 +7,9 @@ cell's parameters, the remat rule's plan, the cell's lowered step (its
 kernels tallied, its hash pinned) and the gauges through the telemetry."""
 
 import contextlib
+import copy
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -21,7 +23,7 @@ from bench import families
 from ray_tpu.models import kanana, kimi_linear, layers, remat
 from ray_tpu.models.kimi_linear import KDA, MLA, KimiLinear, KimiLinearConfig
 from ray_tpu.models.loss import loss_fn
-from ray_tpu.ops import attention, kda, short_conv
+from ray_tpu.ops import attention, kda, kda_norm, short_conv
 from ray_tpu.ops.moe import KEPT_PRODUCTS, SELECTION_BIAS, SIGMOID, ExpertShare
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
@@ -104,6 +106,38 @@ def test_the_reference_s_own_choices_are_the_system_s(seeded):
     assert own.shape == held.shape
     share = (held[..., :, None] == own[..., None, :]).any(-1).mean((1, 2, 3))
     assert (np.asarray(share) > 0.99).all(), share
+
+
+def test_the_head_norm_s_pair_in_the_model_is_the_plain_lines(seeded, monkeypatch):
+    """At two heads of 128 (a width the pair takes; the rehearsal's is 16) the
+    model with `kda_norm_fwd` / `kda_norm_bwd` forced in interpret mode
+    against the model on the plain lines, float32: the loss and every
+    gradient within the tolerances the reference is held to above, the gate's
+    projections, W_o and `o_norm/weight` among them."""
+    sizes, _, idx, targets, _, _, _ = seeded
+    sizes = copy.deepcopy(sizes)
+    sizes["linear_attn_config"].update(head_dim=128, num_heads=2)
+    cfg = FAMILY.build(sizes, "float32")
+    assert (cfg.kda_heads, cfg.kda_head_dim) == (2, 128)
+    params = KimiLinear(cfg).init(jax.random.PRNGKey(2), idx)["params"]
+    params = jax.tree.map(lambda p: p + 0.05 * jax.random.normal(
+        jax.random.PRNGKey(p.size), p.shape) if p.ndim == 1 else p, params)
+    assert params["p_0"]["h_0"]["kda"]["o_norm"]["weight"].shape == (128,)
+    value_and_grads = lambda: jax.value_and_grad(lambda p: _loss(cfg, p, idx, targets))(params)
+    with jax.default_matmul_precision("highest"):
+        plain_loss, plain = value_and_grads()
+        calls, real = [], kda_norm._kda_norm
+        monkeypatch.setattr(kda_norm, "_kda_norm", lambda *a: (calls.append(a[0].shape), real(*a))[1])
+        monkeypatch.setattr(kda_norm, "kda_norm", functools.partial(kda_norm.kda_norm,
+                                                                    interpret=True))
+        loss, grads = value_and_grads()
+    assert calls and set(calls) == {(2, 96, 256)}  # the four KDA layers took the pair
+    assert abs(float(loss) - float(plain_loss)) <= 1e-5 * float(plain_loss)
+    flat, plain_flat = (dict(jax.tree_util.tree_flatten_with_path(g)[0]) for g in (grads, plain))
+    for path, g in flat.items():
+        scale = float(jnp.abs(plain_flat[path]).max())
+        np.testing.assert_allclose(g, plain_flat[path], rtol=0, atol=3e-4 * scale + 1e-30,
+                                   err_msg=jax.tree_util.keystr(path))
 
 
 def _changed_kda(change):
@@ -280,6 +314,7 @@ def test_parameters_of_the_cell():
         3 * 2304 * 4096, 4096 * 2304, 2304 * 128, 128 * 4096, 2304 * 32]
     # and the filters, dt_bias, A_log and the head norm's weight
     assert count(mixer) == cfg.kda_params() + 4 * 3 * 4096 + 4096 + 32 + 128 == 39_514_272
+    assert mixer["o_norm"]["weight"].shape == (128,) and list(mixer["o_norm"]) == ["weight"]
     assert count(blocks["h_3"]["attn"]) == cfg.latent_params() + 512 == 29_114_880
     assert count(blocks["h_0"]["mlp"]) == 3 * 2304 * 9216
     assert count(blocks["h_1"]["moe"]) == 2304 * 256 + 256 + 8 * 3 * 2304 * 1024
@@ -306,7 +341,8 @@ def _cell_step(monkeypatch):
     """(cfg, the cell's step traced for a TPU on this box under a v5e's limit)."""
     for mod in (attention, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    for mod in (kda, kda_norm):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
@@ -315,41 +351,52 @@ def _cell_step(monkeypatch):
     return cfg, ts._step.trace(state, {"idx": tok, "targets": tok})
 
 
-def _float32_under(jaxpr, scope, size, outer=""):
+def _results_under(jaxpr, scope, keep, outer=""):
     """What the equations of a jaxpr under the named scope `scope`, those of
     the jaxprs inside them too (a remat's, a custom rule's, a kernel's body),
-    give in float32 with `size` entries or more: (primitive, shape)."""
+    give that `keep(aval)` holds of: (primitive, shape)."""
     found = []
     for eqn in jaxpr.eqns:
         stack = f"{outer}/{eqn.source_info.name_stack}"
         if scope in stack:
-            found += [(eqn.primitive.name, v.aval.shape) for v in eqn.outvars
-                      if getattr(v.aval, "dtype", None) == jnp.float32 and v.aval.size >= size]
+            found += [(eqn.primitive.name, v.aval.shape) for v in eqn.outvars if keep(v.aval)]
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    found += _float32_under(inner, scope, size, stack)
+                    found += _results_under(inner, scope, keep, stack)
     return found
+
+
+def _float32_under(jaxpr, scope, size):
+    """Those in float32 with `size` entries or more."""
+    return _results_under(jaxpr, scope, lambda aval: getattr(aval, "dtype", None) == jnp.float32
+                          and aval.size >= size)
 
 
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
 # rule), as tests/test_mellum.py:_step_text gives it, taken on PR 55's own tree
 # (the l2 norms of q and k inside kda_fwd and kda_bwd): the program the chip
-# runs of PERF.md section 6 were made with.
-KIMI_LINEAR_STEP = "6944a91058016339e208c34627effdb963ad7f9cdaa93346547cdf7851aad582"
+# runs of PERF.md section 6 were made with; PR 60's since (the head norm and
+# its gate a kernel pair on o as kda_fwd wrote it).
+KIMI_LINEAR_STEP = "43a646488e4acd2fed6d5331e03b0c620f3a8ff1025a45ce67d945ac0760d44b"
 
 
 def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monkeypatch):
     """The cell's own step lowered for a TPU on this box: four KDA layers,
     each with kda_bwd once and kda_fwd as often as the remat plan runs it
     (once where it holds `kda_out` and `kda_states`, twice where not), the
+    head norm's pair after it (forward twice: no plan holds its output), the
     convolution pair a KDA layer, the latent pair once in the one latent
     layer, megablox's calls in four routed layers. Between the convolution
     and the delta rule nothing is float32 at q's size, forward, second
     forward or backward: the heads' l2 norms are the kernels' (PR 55; the
-    parent's step held 104 such results under `kda.conv`, 26 a layer), while the
-    head norm of o under `kda.norm` still is XLA's and still has them."""
+    parent's step held 104 such results under `kda.conv`, 26 a layer), and
+    since PR 60 nothing between the delta rule and W_o either: the head norm
+    and its gate are `kda_norm_fwd` / `kda_norm_bwd` on o as `kda_fwd` wrote
+    it, and no (B, T, H, 128) view of o, y or their cotangents is an equation
+    under `kda.norm` (tests/test_tpu_compile_kimi_linear.py holds the compiled
+    step's `kda.scan` to the same)."""
     from tests.test_mellum import _traced_text
 
     cfg, traced = _cell_step(monkeypatch)
@@ -358,13 +405,16 @@ def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monke
     assert calls.pop("kernel") and "@gmm" in text and "@tgmm" in text
     plan = remat.traced(cfg)
     runs = 1 if "kda_states" in plan.names else 2
-    assert calls == {"kda_fwd": 4 * runs, "kda_bwd": 4, "causal_conv_fwd": 4 * 2,
+    assert calls == {"kda_fwd": 4 * runs, "kda_bwd": 4, "kda_norm_fwd": 4 * 2, "kda_norm_bwd": 4,
+                     "causal_conv_fwd": 4 * 2,
                      "causal_conv_bwd": 4, "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1,
                      "moe_token_sum": 4 * 2 * 2}, calls
     assert not set(KEPT_PRODUCTS) & set(plan.names)
     q_size = 2 * 8192 * cfg.kda_inner
     assert not _float32_under(traced.jaxpr.jaxpr, "kda.conv", q_size)
-    assert _float32_under(traced.jaxpr.jaxpr, "kda.norm", q_size)
+    assert not _float32_under(traced.jaxpr.jaxpr, "kda.norm", q_size)
+    assert not _results_under(traced.jaxpr.jaxpr, "kda.norm",
+                              lambda aval: getattr(aval, "shape", None) == (2, 8192, 32, 128))
     assert hashlib.sha256(text.encode()).hexdigest() == KIMI_LINEAR_STEP
 
 
@@ -378,11 +428,13 @@ def test_remat_plan_of_the_cell():
     # beside 8.98 GiB of state the delta rule's outputs (2.5 GiB over four layers) have no room
     assert chosen.names == first and not set(chosen.names) & set(KEPT_PRODUCTS)
     assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
-    # the chip's allocator read 12.436 GiB of this step (my chip run, PR 55, call 1; 13.318 before)
-    assert chosen.reckoned_bytes / GIB == pytest.approx(12.68, abs=0.01)
+    # the chip's allocator read 12.044 GiB of this step (my chip run, PR 60, call 2; 12.436 before)
+    assert chosen.reckoned_bytes / GIB == pytest.approx(12.18, abs=0.01)
     tokens = 2 * 8192
-    assert chosen.block_bytes == tokens * ((2 * 8 * 2 + 2 * 4) * 4096 + 4 * 4096 * 128 // 64) \
-        == tokens * 196_608
+    # eight bf16 arrays 4,096 wide with their gradients and the chunk states; the two float32
+    # ones of the head norm and its gate went with PR 60 (196,608 before)
+    assert chosen.block_bytes == tokens * (2 * 8 * 2 * 4096 + 4 * 4096 * 128 // 64) \
+        == tokens * 163_840
     # the first rung: the latent layer's output and logsumexp, one layer of five, and
     # the choices and the plan: five int32 and a bool an assignment, four routed layers of five
     assert chosen.layer_bytes == (tokens * 32 * 128 * 2 // 5 + tokens * 32 * 4 // 5
@@ -403,9 +455,10 @@ def test_step_reports_the_kda_gauges_through_the_telemetry():
         jax.block_until_ready(m)
         assert 0.5 < float(m["kda_decay_mean"]) < 1.0 and 0.3 < float(m["kda_beta_mean"]) < 0.7
         assert float(m["kda_state_rms"]) > 0 and float(m["kda_path_pallas"]) == 0.0  # no TPU here
+        assert float(m["kda_norm_path_pallas"]) == 0.0
         report = _telemetry.auto_report_metrics()
         for gauge in ("kda_decay_mean", "kda_beta_mean", "kda_state_rms", "kda_path_pallas",
-                      "moe_bias_abs_max", "moe_rows_held", "moe_held_share"):
+                      "kda_norm_path_pallas", "moe_bias_abs_max", "moe_rows_held", "moe_held_share"):
             assert report[f"telemetry/{gauge}"] == float(m[gauge]), gauge
         plan = ts.telemetry.remat_plan
         assert plan.names == remat.FIRST_RUNG + ("moe_plan",) and plan.limit_bytes is None  # no chip

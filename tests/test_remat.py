@@ -105,9 +105,11 @@ READINGS = {
     ("trinity_mini_l5_ep16.t8192", SHARED): 12.009,
     ("trinity_mini_l5_ep16.t8192", MLP): 12.350,
     ("trinity_mini_l5_ep16.t8192", ATTN + GATE + SHARED + MLP): 13.583,
-    # my chip run, PR 55, call 1: two untraced seeds alike (the traced run 12.573); 13.318 before
-    # PR 55 took the normed q and k and the float32 passes round them out of a KDA half
-    ("kimi_linear_l5_ep32.t8192", ()): 12.436,
+    # my chip run, PR 60, call 2: the step's own live bytes and reservation, three untraced seeds
+    # alike (the run's peak, 12.423, is the reference comparison's since); 12.436 before PR 60
+    # made the head norm and its gate a kernel pair, 13.318 before PR 55 took the normed q and k
+    # and the float32 passes round them out of a KDA half
+    ("kimi_linear_l5_ep32.t8192", ()): 12.044,
     # my chip run, PR 57, call 1 (the traced run: the step's own live bytes and reservation)
     ("phi4_mini_flash_l5.t16384", SSCAN): 12.082,
 }

@@ -1,6 +1,7 @@
 """models/kimi_linear.py's cell compiled for a described TPU v5e, as
 tests/test_tpu_compile.py and with no chip: the delta rule's two kernels at
-`kimi_linear_l5_ep32.t8192`'s shape, and the cell's whole step."""
+`kimi_linear_l5_ep32.t8192`'s shape, the head norm's pair there, and the
+cell's whole step."""
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,29 @@ def test_delta_rule_kernels_compile_at_the_cell_s_shape(one_chip):
     assert states < c.memory_analysis().temp_size_in_bytes < 4 * states
 
 
+def test_head_norm_s_pair_compiles_at_the_cell_s_shape(one_chip):
+    """ops/kda_norm.py's two calls on o and z (2, 8192, 32 x 128) bf16, each a
+    pallas call under its name, nothing kept between them but o and z, and
+    no array of o's size beside the operands and results (the weight's
+    gradient is (2, 8, 128) float32 partial sums)."""
+    from ray_tpu.ops import kda_norm
+
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    wide, weight = shape((2, 8192, 4096), jnp.bfloat16), shape((128,), jnp.float32)
+
+    def loss(o, z, w):
+        return kda_norm.kda_norm(o, z, w, 1e-5, interpret=False).astype(jnp.float32).sum()
+
+    c = jax.jit(jax.grad(loss, argnums=range(3))).lower(wide, wide, weight).compile()
+    names = _CUSTOM_CALL.findall(c.as_text())
+    assert sorted(n.split(".")[0] for n in names) == ["kda_norm_bwd"], names  # y is not asked for
+    c = jax.jit(jax.value_and_grad(loss, argnums=range(3))).lower(wide, wide, weight).compile()
+    names = _CUSTOM_CALL.findall(c.as_text())
+    assert sorted(n.split(".")[0] for n in names) == ["kda_norm_bwd", "kda_norm_fwd"], names
+    assert "[2,8192,32,128]" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 * 2 * 8192 * 4096 * 2  # y, and its cotangent
+
+
 @pytest.mark.slow  # 60 s: the lowered step's tally and hash are tests/test_kimi_linear.py's, fast
 @pytest.mark.timeout(600)
 def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
@@ -40,24 +64,28 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     the rule takes the first rung alone at this shape (the delta rule's
     outputs do not fit beside 8.98 GiB of state), the program holds within
     the error the reckoning has shown of what it reckoned (tests/test_remat.py:
-    0.35 GiB under to 0.85 over; the chip's allocator read 12.44 GiB of this
-    step), four KDA layers run kda_bwd once and kda_fwd twice, the
+    0.35 GiB under to 0.85 over; the chip's allocator read 12.04 GiB of this
+    step), four KDA layers run kda_bwd once and kda_fwd twice, the head
+    norm's pair after them (kda_norm_bwd once, kda_norm_fwd twice), the
     convolution's pair beside them, one layer the latent pair, the bias's
     update is part of the one program, and under `kda.conv` the compiled
     step has no float32 array of q's size in either layout (PR 55: the
     heads' l2 norms are the kernels'; what stays there beside the
     convolution's calls is the bf16 split into q, k and v and the three
-    gradients put side by side)."""
+    gradients put side by side), and since PR 60 under `kda.norm` and
+    `kda.scan` no result shaped (2, 8192, 32, 128) in bf16 or float32: o goes
+    from kda_fwd through the head norm's pair to W_o, and its cotangent back
+    into kda_bwd, as (2, 8192, 4096)."""
     import numpy as np
     from jax.sharding import Mesh
 
     from ray_tpu.models import remat
-    from ray_tpu.ops import attention, kda, short_conv
+    from ray_tpu.ops import attention, kda, kda_norm, short_conv
     from ray_tpu.parallel.train_step import TrainStep
     from ray_tpu.train._device_profile import scope_table
     from tests._tpu_compile import GIB, _kinds, _live_bytes, _step_args, cell_config
 
-    for mod in (attention, kda, short_conv):
+    for mod in (attention, kda, kda_norm, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
     cfg = cell_config("kimi_linear_l5_ep32")
@@ -71,11 +99,16 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     kinds = _kinds(c.as_text())
     assert {k: n for k, n in kinds.items() if "kda" in k or "conv" in k or "flash" in k} == {
-        "kda_fwd": 8, "kda_bwd": 4, "causal_conv_fwd": 8, "causal_conv_bwd": 4,
+        "kda_fwd": 8, "kda_bwd": 4, "kda_norm_fwd": 8, "kda_norm_bwd": 4,
+        "causal_conv_fwd": 8, "causal_conv_bwd": 4,
         "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1}, kinds
     assert kinds["gmm"] and kinds["tgmm"]
-    conv = [kind for scope, _, _, _, kind in scope_table(c.as_text())["rows"].values()
-            if "kda.conv" in scope]
+    rows = scope_table(c.as_text())["rows"].values()
+    conv = [kind for scope, _, _, _, kind in rows if "kda.conv" in scope]
     assert len(conv) > 12 and not [kind for kind in conv if any(
         shape in kind for shape in ("f32[2,8192,4096]", "f32[2,8192,32,128]",
                                     "f32[2048,8,32,128]"))], conv
+    after = [kind for scope, _, _, _, kind in rows if "kda.norm" in scope or "kda.scan" in scope]
+    assert len(after) > 100 and not [kind for kind in after if any(
+        shape in kind for shape in ("f32[2,8192,32,128]", "bf16[2,8192,32,128]",
+                                    "f32[2,8192,4096]"))], after
