@@ -54,15 +54,18 @@ GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
 
 # attn_shapes: the flash check runs at both head widths the benchmark's cells
 # use; windowed_shapes: and under the two cells' windows at their window
-# layers' shape, (B, T, H, D) and the window
+# layers' shape, (B, T, H, D) and the window; bd_shapes: and over a doubled
+# stream under the block-diffusion mask, (B, 2T, H, D) and the block length
 FULL = {"model": None, "B": 16, "T": 1024,
         "attn_shapes": [(16, 1024, 12, 64), (1, 2048, 8, 128)],
-        "windowed_shapes": [((2, 8192, 32, 128), 1024), ((2, 8192, 32, 128), 2048)]}
+        "windowed_shapes": [((2, 8192, 32, 128), 1024), ((2, 8192, 32, 128), 2048)],
+        "bd_shapes": [((1, 16384, 32, 128), 4)]}  # the cell's own: sdar_30b_a3b_l5_ep8.t8192
 TINY = {
     "model": {"vocab_size": 257, "block_size": 256, "n_layer": 2, "n_head": 4,
               "n_embd": 64},
     "B": 4, "T": 256, "attn_shapes": [(2, 256, 2, 64), (1, 256, 2, 128)],
     "windowed_shapes": [((1, 512, 2, 128), 100), ((1, 512, 2, 128), 256)],
+    "bd_shapes": [((1, 512, 2, 128), 4)],
 }
 
 
@@ -153,9 +156,10 @@ def _check_losses(losses):
         raise RuntimeError(f"loss did not fall: {losses}")
 
 
-def _check_flash_vs_xla(shape, seed, on_tpu, window=None):
+def _check_flash_vs_xla(shape, seed, on_tpu, window=None, blocks=None):
     """flash_causal_attention against xla_causal_attention at one (B, T, H, D),
-    same seed, under `window` where given: the output and the three
+    same seed, under `window` where given, or with `blocks` over a doubled
+    stream under the block-diffusion mask: the output and the three
     gradients, as max-abs error over the reference's max-abs value, beside
     the tiles the kernel chose. The reference holds a head's (T, T) scores
     in float32, so it goes a few heads of a batch row at a time where all
@@ -182,11 +186,11 @@ def _check_flash_vs_xla(shape, seed, on_tpu, window=None):
 
         return jax.jit(run)
 
-    flash = both(functools.partial(flash_causal_attention, window=window, interpret=not on_tpu))(
-        q, k, v, w)
+    flash = both(functools.partial(flash_causal_attention, window=window, blocks=blocks,
+                                   interpret=not on_tpu))(q, k, v, w)
     b, t, h, _ = shape
     at_once = max(1, min(h, (1 << 30) // (4 * t * t)))
-    reference = both(functools.partial(xla_causal_attention, window=window))
+    reference = both(functools.partial(xla_causal_attention, window=window, blocks=blocks))
     parts = [[reference(*(x[row:row + 1, :, first:first + at_once] for x in (q, k, v, w)))
               for first in range(0, h, at_once)] for row in range(b)]
     ref = [jnp.concatenate([jnp.concatenate([part[n] for part in row], axis=2) for row in parts])
@@ -198,9 +202,10 @@ def _check_flash_vs_xla(shape, seed, on_tpu, window=None):
             raise RuntimeError(f"flash {name}: bad shape or non-finite values")
         errs[name] = float(jnp.abs(a - b).max() / jnp.abs(b).max())
     if max(errs.values()) > ATTN_REL_TOL:
-        raise RuntimeError(f"flash vs xla at {shape}, window {window}, beyond {ATTN_REL_TOL}: {errs}")
-    return {"shape": list(shape), "window": window, "rel_err": errs,
-            "tiles": flash_tiles(h, t, shape[3], jnp.bfloat16, window)._asdict()}
+        raise RuntimeError(f"flash vs xla at {shape}, window {window}, blocks {blocks}, "
+                           f"beyond {ATTN_REL_TOL}: {errs}")
+    return {"shape": list(shape), "window": window, "blocks": blocks, "rel_err": errs,
+            "tiles": flash_tiles(h, t, shape[3], jnp.bfloat16, window, blocks=blocks)._asdict()}
 
 
 def _check_ssd_vs_chunked(seed, on_tpu, groups=1):
@@ -937,7 +942,8 @@ def _remat_plans():
 # rotary layers take the pair; under its own mesh the layer is handed
 # `attn_fn` and runs the plain lines (tests/test_mellum.py pins that step).
 QK_PREP_LAYERS = {"mellum2_12b_l4_ep4.t8192": 4, "keye_vl2_30b_l4_ep8.t16384": 4,
-                  "trinity_mini_l5_ep16.t8192": 5, "mistral_7b_l8.fsdp4_t8192": 8}
+                  "trinity_mini_l5_ep16.t8192": 5, "mistral_7b_l8.fsdp4_t8192": 8,
+                  "sdar_30b_a3b_l5_ep8.t8192": 5}
 
 
 def _flash_calls_by_cell(on_tpu):
@@ -1047,6 +1053,7 @@ def _scores_needed_share(asked):
         args = inspect.signature(attention.flash_tiles).bind(*call.args, **call.kwargs).arguments
         tiles = attention.flash_tiles(*call.args, **call.kwargs)
         kind = ("flash_mla" if args.get("shared") else
+                f"flash_bd{tiles.blocks}" if tiles.blocks else
                 f"flash_sel{tiles.select}" if tiles.select else
                 f"flash_win{tiles.window}" if tiles.window else "flash")
         shares[kind] = {"tile": [tiles.block_q, tiles.block_k],
@@ -1103,6 +1110,9 @@ def one_chip_loop(config):
         for shape in config["attn_shapes"]] + [
         _check_flash_vs_xla(shape, config["seed"], on_tpu, window)
         for shape, window in config["windowed_shapes"]]
+    report["flash_bd_vs_xla"] = [
+        _check_flash_vs_xla(shape, config["seed"], on_tpu, blocks=blocks)
+        for shape, blocks in config["bd_shapes"]]
     report["ssd_vs_chunked"] = _check_ssd_vs_chunked(config["seed"], on_tpu)
     report["ssd_vs_chunked_at_eight_groups"] = _check_ssd_vs_chunked(config["seed"], on_tpu, 8)
     report["relu2_experts_vs_plain"] = _check_relu2_experts_vs_plain(config["seed"], on_tpu)

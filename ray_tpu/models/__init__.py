@@ -6,7 +6,8 @@ that the family's own file sets at its foot, no dataclass field, since it is
 no option), `block_size`, `attn_fn` and `use_flash_attention` (`model_for_mesh`
 sets the first where a mesh needs its own attention), `flops_per_token(seq_len)`
 where the family counts its FLOPs, and `lr_warmup_steps` where its recipe has
-any (ROADMAP C15). Everything else a family is, the record says.
+any (ROADMAP C15). Everything else a family is, the record says: its objective
+too, where it is not the next token's.
 
 A family's file holds a family: of this package it imports the package, `remat`,
 `loss` and `layers` (what several families run), and no other family's file
@@ -17,6 +18,21 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Any, Callable, Optional, Tuple
+
+import jax
+
+from ray_tpu.models.loss import loss_fn
+
+
+def next_token_objective(model, params, batch, step):
+    """`Family.objective` of every family but one: the mean next-token loss of
+    `model.apply(params, batch["idx"])` against batch["targets"] and the loss
+    terms the layers sowed. The step's count is none of its."""
+    family = model.config.family
+    logits, sown = model.apply({"params": params}, batch["idx"], mutable=list(family.sown))
+    aux = sum(jax.tree.leaves([sown.get(c, {}) for c in family.loss_terms]), 0.0)
+    with jax.named_scope("loss"):
+        return loss_fn(logits, batch["targets"]) + aux, sown
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,3 +58,9 @@ class Family:
     # Their layer reads them under a stop_gradient: a leaf that optax masks
     # out of AdamW gets its gradient itself as its update.
     held_leaf: Optional[Tuple[str, Callable[[Any, Any], Any]]] = None
+    # (model, params, batch, the step's count) -> (loss, sown): what the step
+    # differentiates. It applies the model itself, takes out what `sown`
+    # names and adds its loss terms under the scope `loss`; the count,
+    # `state["step"]` on the device, is what an objective that draws noise
+    # draws a step's from (models/sdar.py).
+    objective: Callable[[Any, Any, Any, Any], Tuple[Any, Any]] = next_token_objective

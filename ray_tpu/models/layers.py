@@ -111,7 +111,11 @@ class LlamaAttention(nn.Module):
     the kernel's output element by element before the output projection,
     wo(y * sigmoid(W_g x)), the product in float32. The projection is the
     named residual `attn_gate` (models/remat.py), and the layer sows the
-    sigmoid's mean into "attn_gate": 0.5 at initialisation."""
+    sigmoid's mean into "attn_gate": 0.5 at initialisation. `blocks`: the
+    layer's input is the doubled stream [noised | clean] of a block-diffusion
+    step (models/sdar.py), (B, 2T, C): both halves carry positions 0 .. T-1,
+    and a query sees what ops/attention.py's `block_diffusion_mask` shows it
+    with blocks of this length."""
 
     config: Any
     window: Optional[int] = None
@@ -122,6 +126,7 @@ class LlamaAttention(nn.Module):
     rotary: bool = True
     q_scale: float = 1.0
     gate: bool = False
+    blocks: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
@@ -134,7 +139,7 @@ class LlamaAttention(nn.Module):
         # heads of one vreg's 128 lanes that a layer norms or turns on their way
         # into the flash calls stay where the projections wrote them: `_on_rows`
         on_rows = (cfg.attn_fn is None and cfg.use_flash_attention and hd == 128
-                   and (self.qk_norm or self.rotary) and attention_path(T) == "flash")
+                   and (self.qk_norm or self.rotary) and attention_path(T, self.blocks) == "flash")
         heads = (lambda a, n: a) if on_rows else (lambda a, n: a.reshape(B, T, n, hd))
         q = heads(dense(cfg.n_head * hd, "wq")(x), cfg.n_head)
         k = heads(dense(cfg.n_kv_head * hd, "wk")(x), cfg.n_kv_head)
@@ -155,6 +160,13 @@ class LlamaAttention(nn.Module):
                 y = (y.astype(jnp.float32) * open_).astype(y.dtype)
         return dense(C, "wo")(y)
 
+    @nn.nowrap
+    def _positions(self, T, pos_offset):
+        """The positions the rotary turns by; a doubled stream's halves carry
+        the same ones."""
+        at = jnp.arange(T)
+        return (at if self.blocks is None else at % (T // 2)) + pos_offset
+
     @nn.nowrap  # no scope of its own: the layer's scopes are what they were
     def _on_heads(self, x, q, k, v, pos_offset, window):
         """The plain form, q, k, v and the result (B, T, H, D): the norm, the
@@ -169,8 +181,8 @@ class LlamaAttention(nn.Module):
 
         if self.rotary:
             with jax.named_scope("attn.rope"):
-                positions = jnp.arange(T) + pos_offset
-                ang = rope_angles(hd, cfg.rope_theta, positions, self.inv_freq)
+                ang = rope_angles(hd, cfg.rope_theta, self._positions(T, pos_offset),
+                                  self.inv_freq)
                 q = apply_rope(q, ang, self.rope_scale)
                 k = apply_rope(k, ang, self.rope_scale)
         if self.q_scale != 1.0:
@@ -191,6 +203,13 @@ class LlamaAttention(nn.Module):
 
             with jax.named_scope("attn.selected"):
                 y = selected_attention(q, k, v, *chosen)
+        elif self.blocks is not None:
+            if cfg.attn_fn is not None:
+                raise NotImplementedError("a doubled stream's attention runs on one device")
+            from ray_tpu.ops.attention import causal_attention
+
+            with jax.named_scope("attn.flash_bd"):
+                y = causal_attention(q, k, v, blocks=self.blocks)
         elif cfg.attn_fn is not None:
             y = cfg.attn_fn(q, k, v) if window is None else cfg.attn_fn(q, k, v, window=window)
         elif cfg.use_flash_attention:
@@ -226,13 +245,17 @@ class LlamaAttention(nn.Module):
             w_q, w_k = (NormWeight(name=name)(hd) for name in ("q_norm", "k_norm"))
         if self.rotary:
             with jax.named_scope("attn.rope"):
-                ang = rope_angles(hd, cfg.rope_theta, jnp.arange(T) + pos_offset, self.inv_freq)
+                ang = rope_angles(hd, cfg.rope_theta, self._positions(T, pos_offset),
+                                  self.inv_freq)
                 tables = rope_tables(ang, self.rope_scale)
         with jax.named_scope("attn.qk_norm" if self.qk_norm else "attn.rope"):
             q = qk_prep(q, w_q, tables, eps=cfg.rms_eps, scale=self.q_scale)
             k = qk_prep(k, w_k, tables, rep=rep, eps=cfg.rms_eps)
         v = _as_rows(jnp.broadcast_to(v[:, :, :, None, :], (B, T, cfg.n_kv_head, rep, hd)
                                       ).reshape(B, T, cfg.n_head, hd))
+        if self.blocks is not None:
+            with jax.named_scope("attn.flash_bd"):
+                return flash_attention_rows(q, k, v, cfg.n_head, blocks=self.blocks)
         if chosen is None:
             return flash_attention_rows(q, k, v, cfg.n_head, window=window)
         with jax.named_scope("attn.selected"):

@@ -107,12 +107,32 @@ two). This first version visits every tile at or below the diagonal and
 masks each with its bits; a tile the selection leaves empty is computed and
 comes to nothing.
 
+A fourth kind is not causal: the sequence is the doubled stream
+[noised | clean] of a block-diffusion step (models/sdar.py), 2T positions in
+blocks of L, and a query sees what `block_diffusion_mask` shows: a noised
+query its own block, both ways, and the clean blocks before it; a clean
+query the clean blocks up to its own; no clean query a noised key. Of the
+(2T)^2 scores T^2 + T L are seen: one quadrant is dead, two are block
+triangles and one a band L wide. It is the causal kernels' tile loop, cut,
+online softmax and fused backward over other tiles (`_bd_forward_tiles`,
+`_bd_backward_tiles`): tiles are square and divide T, so a tile lies in one
+half and a masked tile pairs equal positions of two halves; L divides a
+sub-tile, so every edge runs through the sub-tiles on a masked tile's own
+diagonal, as a band (a tile of a half against itself) or a stair (a tile of
+queries against the clean keys of its positions), built from the blocks'
+distance alone (`_Blocks`). No tile the mask hides wholly is visited. The
+backward's dq is one block for the whole stream, resident while a head
+group's grid steps run: a clean key tile's grid step is the last that two
+query tiles see, its own and the noised tile of the same positions.
+
 The two pallas calls are named flash_fwd and flash_bwd_fused,
 flash_win<window>_fwd and flash_win<window>_bwd_fused where the call has a
 window shorter than its sequence, flash_sel<k>_fwd and
 flash_sel<k>_bwd_fused where a selection of k keys a query, fewer than the
-sequence has, says what is seen, and flash_mla_fwd and flash_mla_bwd_fused
-where the scores have a second part whose key all heads share. (Until PR 35 the backward was two calls,
+sequence has, says what is seen, flash_mla_fwd and flash_mla_bwd_fused
+where the scores have a second part whose key all heads share, and
+flash_bd<L>_fwd and flash_bd<L>_bwd_fused over a doubled stream in blocks
+of L. (Until PR 35 the backward was two calls,
 flash_bwd_dq and flash_bwd_dkv, which both computed QK^T, dO V^T and exp.)
 The name reaches the compiled instruction and the profiler's trace (wrapped by
 the transformations it went through, e.g. transpose_jvp_flash_bwd_fused_), on
@@ -176,7 +196,9 @@ class FlashTiles(NamedTuple):
     a query sees of those before it, named by a mask (None: no mask);
     `sub_fwd` and `sub_bwd` rows and columns of the sub-tiles that the
     forward and the backward call cut a tile into which the diagonal or a
-    window's trailing edge crosses (None: such a tile is computed whole)."""
+    window's trailing edge crosses (None: such a tile is computed whole);
+    `blocks`, the block length of a block-diffusion call, whose sequence is
+    a doubled stream [noised | clean] (None: one sequence, causal)."""
 
     block_q: int
     block_k: int
@@ -185,6 +207,7 @@ class FlashTiles(NamedTuple):
     select: Optional[int] = None
     sub_fwd: Optional[int] = None
     sub_bwd: Optional[int] = None
+    blocks: Optional[int] = None
 
     def cut(self, sub):
         """These tiles with both calls' masked tiles in sub-tiles of `sub`."""
@@ -249,7 +272,8 @@ def _legal_heads(h, d):
 
 
 def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
-                select: Optional[int] = None, shared: Optional[int] = None) -> FlashTiles:
+                select: Optional[int] = None, shared: Optional[int] = None,
+                blocks: Optional[int] = None) -> FlashTiles:
     """Tiles for a causal flash call on (B, t, h, d) inputs of `dtype`,
     from the shape alone: the largest square tile, a multiple of 128 that
     divides t, up to _MAX_BLOCK (a tile step's matmuls must be long enough
@@ -278,7 +302,21 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
     is the width of a head's own score part and of its values, a grid step's
     heads are those whose second parts fill whole vregs side by side
     (128 // shared at least), and `_shared_vmem_bytes` counts against the
-    budget."""
+    budget.
+
+    With `blocks`, the block length of a block-diffusion step, the call is
+    over a doubled stream, t = 2T positions [noised | clean]
+    (`block_diffusion_mask`): the tile is square and divides T, so that none
+    lies in both halves, the block length divides a sub-tile, so that every
+    edge of the mask runs through the sub-tiles on a tile's own diagonal, and
+    the backward call's dq is one block for the whole stream
+    (`_bd_bwd_kernel`), which counts against the budget."""
+    if blocks is not None:
+        if window is not None or select is not None or shared is not None:
+            raise ValueError("a block-diffusion call takes no window, selection or shared key")
+        if blocks < 1 or _SUB_TILE % blocks or t % 256:
+            raise ValueError(f"block length {blocks} over a stream of {t}: the length divides "
+                             f"{_SUB_TILE} and each half of the stream is a multiple of 128")
     if t % 128:
         raise ValueError(f"seq len {t} is not a multiple of 128")
     if window is not None and window < 1:
@@ -290,7 +328,8 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
         select = None
     if select is not None and window is not None:
         raise ValueError("a call takes a window or a selection, not both")
-    block = _divisor(t, _MAX_BLOCK)
+    span = t if blocks is None else t // 2  # what a tile divides
+    block = _divisor(span, _MAX_BLOCK)
     if shared is not None and (d % 128 or window is not None or select is not None):
         raise ValueError("a latent call is causal, and its heads' own parts fill whole vregs")
     legal = _legal_heads(h, d if shared is None else shared)
@@ -298,15 +337,16 @@ def flash_tiles(h: int, t: int, d: int, dtype, window: Optional[int] = None,
 
     def over_budget(reckon=_vmem_bytes, inner=None):
         tiles = (block, inner or block, heads)
-        more = 0 if shared is None else _shared_vmem_bytes(tiles, t, shared, itemsize)
+        more = (_shared_vmem_bytes(tiles, t, shared, itemsize) if shared is not None else
+                _bd_vmem_bytes(tiles, t, d, itemsize) if blocks is not None else 0)
         return reckon(tiles, t, d, itemsize) + more > _VMEM_BUDGET
 
     while over_budget() and heads > legal[0]:
         heads = legal[legal.index(heads) - 1]
     while over_budget() and block > 128:
-        block = _divisor(t, block - 1)
+        block = _divisor(span, block - 1)
     if select is None:
-        return FlashTiles(block, block, heads, window, None, *_sub_tiles(block, block))
+        return FlashTiles(block, block, heads, window, None, *_sub_tiles(block, block), blocks)
     from ray_tpu.ops.indexer import mask_width
 
     width = mask_width(t)
@@ -342,6 +382,21 @@ def flash_scores(tiles: FlashTiles, t: int, backward: bool = False):
     ratio = block_q // block_k
     tile = block_q * block_k
     computed = 0
+    if tiles.blocks is not None:
+        half, length = t // (2 * block_q), tiles.blocks
+        for i in range(2 * half):
+            for loop in _bd_backward_tiles(i, half) if backward else (_bd_forward_tiles(i, half),):
+                for plain in (loop.get("plain"), loop.get("plain_after")):
+                    computed += (plain[1] - plain[0]) * tile if plain else 0
+                masked = [off for _, off, there in loop.get("edge", ()) if there] + loop["diag"]
+                for off in masked:
+                    computed += tile if sub is None else sub * sub * sum(
+                        len(crossed) for _, crossed in
+                        _strips(0, block_q, block_k, sub, 1 if off == _BAND else None, backward))
+        # a noised query sees its own block and the clean blocks before it, a
+        # clean one the clean blocks up to its own: 2 * length * (b + 1) a pair
+        # of queries in block b
+        return computed, sum(2 * length * (row // length + 1) for row in range(t // 2))
     for i in range(t // block_q):
         if select is not None:
             computed += (i + 1) * ratio * tile
@@ -355,6 +410,13 @@ def flash_scores(tiles: FlashTiles, t: int, backward: bool = False):
                 computed += sub * sub * sum(len(crossed) for _, crossed in
                                             _strips(off, block_q, block_k, sub, window))
     return computed, sum(min(row + 1, window or select or t) for row in range(t))
+
+
+def _bd_vmem_bytes(tiles, t, d, itemsize):
+    """What a block-diffusion call adds to `_vmem_bytes`: the backward call's
+    dq is one block for the whole stream, double-buffered, where a causal
+    call writes the tile a grid step owns."""
+    return 2 * (t - tiles[0]) * _lanes(tiles[2] * d) * itemsize
 
 
 def _select_vmem_bytes(tiles, t, d, itemsize):
@@ -393,6 +455,10 @@ def _call(kernel, name, like, d, tiles, in_specs, out_specs, out_shape, interpre
     if tiles.select is not None:
         name = name.replace("flash_", f"flash_sel{tiles.select}_", 1)
         vmem = _select_vmem_bytes(tiles, t, d, like.dtype.itemsize)
+    if tiles.blocks is not None:
+        name = name.replace("flash_", f"flash_bd{tiles.blocks}_", 1)  # as a windowed call's
+        kernel = functools.partial(kernel, blocks=tiles.blocks)
+        vmem += _bd_vmem_bytes(tiles, t, d, like.dtype.itemsize)
     if tiles.window is not None:
         # a windowed call says so, and how wide: a shape function sees
         # names and shapes only
@@ -602,7 +668,10 @@ def _strips(off, rows, cols, sub, window, keys_first=False):
 class _Cut(NamedTuple):
     """How a causal or windowed call's kernel cuts its masked tiles:
     sub-tiles of sub x sub of a rows x cols tile; `diff`, row less column of
-    a sub-tile's entries, made once a grid step."""
+    a sub-tile's entries, made once a grid step. With `blocks` (`_Blocks`) the
+    call is a block-diffusion one: a masked tile's `off` names its mask
+    (`_BAND`, `_STAIR`), whose edges run through the sub-tiles on the tile's
+    own diagonal alone, and `diff` is the row's block less the column's."""
 
     sub: int
     rows: int
@@ -610,13 +679,15 @@ class _Cut(NamedTuple):
     window: Optional[int]
     keys_first: bool
     diff: object
+    blocks: object = None
 
     @classmethod
-    def of(cls, sub, rows, cols, window, keys_first=False):
+    def of(cls, sub, rows, cols, window, keys_first=False, blocks=None):
         """None where the tiles are left whole."""
         if sub is None:
             return None
-        return cls(sub, rows, cols, window, keys_first, _row_minus_col(sub, sub))
+        diff = _row_minus_col(sub, sub) if blocks is None else blocks.gap(sub)
+        return cls(sub, rows, cols, window, keys_first, diff, blocks)
 
     def strips(self, j, off):
         """Of tile j, `off` from the diagonal: (rows, columns, mask) of each
@@ -624,23 +695,30 @@ class _Cut(NamedTuple):
         any of as rows of a ref's sequence axis (None: it sees none), and
         what masks a list of its score tiles, (sub, columns) each."""
         sub = self.sub
-        plan = _strips(off, self.rows, self.cols, sub, self.window, self.keys_first)
+        if self.blocks is None:
+            seen = lambda at: _visible(self.diff, at, self.window, self.keys_first)
+            plan = _strips(off, self.rows, self.cols, sub, self.window, self.keys_first)
+        else:  # a band is a window of one sub-tile, a stair the causal cut, both on the diagonal
+            kind, off = off, 0
+            seen = lambda at: self.blocks.seen(self.diff, kind, j)
+            plan = _strips(0, self.rows, self.cols, sub, 1 if kind == _BAND else None,
+                           self.keys_first)
         for a, (first, crossed) in enumerate(plan):
             rows = slice(a * sub, (a + 1) * sub)
             if not crossed:
                 yield rows, None, None
                 continue
             at = pl.ds(pl.multiple_of(j * self.cols + first * sub, sub), len(crossed) * sub)
-            yield rows, at, functools.partial(self._mask, off - (a - first) * sub, crossed)
+            yield rows, at, functools.partial(self._mask, seen, off - (a - first) * sub, crossed)
 
-    def _mask(self, off, crossed, ss):
+    def _mask(self, seen, off, crossed, ss):
         """The score tiles `ss` of a strip with the sub-tiles that an edge
-        crosses masked; `off` is the first's offset from the diagonal."""
+        crosses masked by `seen(a sub-tile's offset from the diagonal)`;
+        `off` is the first's."""
         if not any(crossed):
             return ss
         sub = self.sub
-        seen = {n: _visible(self.diff, off + n * sub, self.window, self.keys_first)
-                for n, cut in enumerate(crossed) if cut}
+        seen = {n: seen(off + n * sub) for n, cut in enumerate(crossed) if cut}
         # runs of sub-tiles seen whole stay one piece
         bounds = sorted({0, len(crossed)} | set(seen) | {n + 1 for n in seen})
         pieces = list(zip(bounds, bounds[1:]))
@@ -652,6 +730,88 @@ class _Cut(NamedTuple):
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
         return [masked(s) for s in ss]
+
+
+# The two masks of a block-diffusion call's tiles, in `off`'s place: a tile of
+# one half against its own positions of the same half is a band (a query sees
+# its own block), a tile of queries against the clean keys of its own
+# positions a stair (the blocks before a noised query's, up to a clean one's).
+_BAND, _STAIR = "band", "stair"
+
+
+class _Blocks(NamedTuple):
+    """What a block-diffusion call's grid step knows of its mask
+    (`block_diffusion_mask`): blocks of `length` positions, `half` tiles in
+    each half of the doubled stream, `own` the tile it owns, rows of a score
+    tile queries, or keys with `keys_first`. Tiles are square and divide a
+    half, so a masked tile pairs positions i and j of equal offsets in their
+    halves, and what it shows depends on `gap`, blk(row) - blk(column),
+    alone."""
+
+    length: int
+    half: int
+    own: object
+    keys_first: bool = False
+
+    def gap(self, size):
+        """Row's block less column's of a (size, size) tile on its diagonal."""
+        return (jax.lax.broadcasted_iota(jnp.int32, (size, size), 0) // self.length
+                - jax.lax.broadcasted_iota(jnp.int32, (size, size), 1) // self.length)
+
+    def seen(self, gap, kind, j):
+        """Which entries of looped-over tile j, or of a sub-tile on its
+        diagonal, a query sees. Band: its own block. Stair: the key's block
+        lies before the query's, or is it where the query is clean."""
+        if kind == _BAND:
+            return gap == 0
+        clean = ((j if self.keys_first else self.own) >= self.half).astype(jnp.int32)
+        return gap < clean if self.keys_first else gap > -clean
+
+    def whole(self, j, size):
+        """The mask of masked tile j, (size, size), taken whole: a band where
+        the keys' tile is noised, else a stair. Which is the grid step's to
+        say, so the two are one comparison of `gap` with bounds chosen among
+        scalars (Mosaic selects no vector of booleans by a scalar)."""
+        gap = self.gap(size)
+        noised_keys = (self.own if self.keys_first else j) < self.half
+        clean = ((j if self.keys_first else self.own) >= self.half).astype(jnp.int32)
+        far = jnp.int32(self.half * size)  # beyond any gap
+        least, most = (-far, clean - 1) if self.keys_first else (1 - clean, far)
+        return (gap >= jnp.where(noised_keys, 0, least)) & (gap <= jnp.where(noised_keys, 0, most))
+
+
+def _pick(cond, a, b):
+    """a where cond, else b: of a grid step's own numbers, or of Python's
+    (`flash_scores` walks a call's bounds with ints)."""
+    return (a if cond else b) if isinstance(cond, bool) else jnp.where(cond, a, b)
+
+
+def _bd_forward_tiles(i, half):
+    """The tiles of keys that query tile i of a block-diffusion call visits,
+    as `_tile_loop` takes them (`half` tiles in each half of the stream). A
+    noised tile: its own, under the band, first (every row sees itself
+    there, so no later tile meets a running max of NEG_INF), then the clean
+    tiles before its positions, plain, and the clean tile of its positions
+    under the stair. A clean tile: the clean tiles before it and its own under
+    the stair. No tile of noised keys but a noised tile's own is ever visited,
+    and no clean tile after a query's positions."""
+    noised = i < half
+    diag = _pick(noised, i + half, i)
+    return dict(plain=(half, diag), diag_start=diag, diag=[_STAIR], edge=[(i, _BAND, noised)])
+
+
+def _bd_backward_tiles(i, half):
+    """The tiles of queries that see key tile i, two `_tile_loop`s one after
+    the other. A noised tile of keys: the noised queries of its own positions,
+    under the band, and no other. A clean one, of the positions of noised tile
+    c = i - half: tile c under the stair and the noised tiles after it, plain;
+    then tile i under the stair and the clean tiles after it, plain."""
+    clean, noised, c = i >= half, i < half, i - half
+    ranged = lambda lo, hi: (_pick(clean, lo, 0), _pick(clean, hi, 0))  # empty for a noised tile
+    return (dict(plain=None, diag_start=0, diag=[], plain_after=ranged(c + 1, half),
+                 edge=[(i, _BAND, noised), (c, _STAIR, clean)]),
+            dict(plain=None, diag_start=0, diag=[], plain_after=ranged(i + 1, 2 * half),
+                 edge=[(i, _STAIR, clean)]))
 
 
 def _stage_by_stage(updates):
@@ -880,8 +1040,9 @@ def _forward_call(q, k, v, mask=None, *, d, tiles, interpret):
     own, own_row, whole, _ = _specs(q, d, tiles)
     masks = () if mask is None else (mask,)
     b, t, width = q.shape
+    kernel = _fwd_kernel if tiles.blocks is None else _bd_fwd_kernel
     return _call(
-        _fwd_kernel if mask is None else _sel_fwd_kernel, "flash_fwd", q, d, tiles,
+        kernel if mask is None else _sel_fwd_kernel, "flash_fwd", q, d, tiles,
         in_specs=[own, whole, whole, *(_mask_spec(q, m, tiles) for m in masks)],
         out_specs=[own, own_row],
         out_shape=[
@@ -1003,10 +1164,11 @@ def _sum_dq(dq_acc, at, heads, dss, ks):
         dq_acc[at, first:last] += part
 
 
-def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None, cut=None):
+def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None, cut=None, write_dq=None):
     """A backward grid step: `loop(step, carry)` over its tiles from zeroed
     dk and dv, then its results. No later key tile is seen by the queries
-    of tile i, so their rows of dq are whole."""
+    of tile i, so their rows of dq are whole; a call of which that does not
+    hold writes dq itself, `write_dq(dq_ref, dq_acc, the factor on it)`."""
     *ins, dq_ref, dk_ref, dv_ref, dq_acc, delta = refs
     heads, step = _bwd_step(*ins, dq_acc, delta, i, d, block_k, visible, shared, cut)
     zeros = tuple((jnp.zeros((block_q, head.width), jnp.float32),) * 2 for head in heads)
@@ -1015,7 +1177,10 @@ def _bwd_run(loop, refs, i, d, block_q, block_k, visible, shared=None, cut=None)
         zeros = tuple(z + (jnp.zeros((block_q, lanes), jnp.float32),) for z in zeros)
     carry = loop(step, zeros)
     q_scale, s_scale = _split_scale(_score_depth(d, heads, shared))
-    dq_ref[0] = (dq_acc[_rows(i, block_q), :] * s_scale).astype(dq_ref.dtype)
+    if write_dq is None:
+        dq_ref[0] = (dq_acc[_rows(i, block_q), :] * s_scale).astype(dq_ref.dtype)
+    else:
+        write_dq(dq_ref, dq_acc, s_scale)
     for ref, parts, scale in ((dk_ref, [c[0] for c in carry], q_scale * s_scale),  # 1/sqrt(d)
                               (dv_ref, [c[1] for c in carry], 1.0)):
         for (first, last), x in _side_by_side(heads, parts).items():
@@ -1079,17 +1244,82 @@ def _backward_call(res, do, mask_t=None, *, tiles, interpret):
     own, _, whole, whole_row = _specs(q, d, tiles)
     like_q = jax.ShapeDtypeStruct(q.shape, q.dtype)
     masks = () if mask_t is None else (mask_t,)
+    kernel = _bwd_kernel if tiles.blocks is None else _bd_bwd_kernel
     return _call(
-        _bwd_kernel if mask_t is None else _sel_bwd_kernel, "flash_bwd_fused", q, d, tiles,
+        kernel if mask_t is None else _sel_bwd_kernel, "flash_bwd_fused", q, d, tiles,
         in_specs=[whole, own, own, whole, whole, whole_row,
                   *(_mask_spec(q, m, tiles) for m in masks)],
-        out_specs=[own, own, own],
+        # a block-diffusion call's dq is one block (`_bd_bwd_kernel`)
+        out_specs=[own if tiles.blocks is None else whole, own, own],
         out_shape=[like_q, like_q, like_q],
         interpret=interpret,
         # dq's accumulator and sum(o * dO): float32, the whole sequence of a grid step's heads
         scratch=(pltpu.VMEM((t, tiles.heads * d), jnp.float32),
                  pltpu.VMEM((tiles.heads, 1, t), jnp.float32)),
     )(q, k, v, o, do, lse, *masks)
+
+
+# --------------------------------------------------------------------------
+# block diffusion: a doubled stream under `block_diffusion_mask`
+# --------------------------------------------------------------------------
+
+
+def _bd_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, block_q, block_k, blocks, sub=None):
+    """`_fwd_kernel` over the doubled stream of a block-diffusion step: the
+    tiles of `_bd_forward_tiles`, the masks of `_Blocks`. Of the (2T)^2
+    scores the quadrant of clean queries against noised keys is never
+    visited, the noised quadrant on its diagonal alone."""
+    i = pl.program_id(2)
+    mask = _Blocks(blocks, k_ref.shape[1] // (2 * block_k), i)
+    heads, step = _fwd_step(q_ref, k_ref, v_ref, d, block_k, lambda j: mask.whole(j, block_q),
+                            cut=_Cut.of(sub, block_q, block_k, None, blocks=mask))
+    carry = _tile_loop(step, _fwd_init(heads, block_q), **_bd_forward_tiles(i, mask.half))
+    _fwd_write(o_ref, lse_ref, heads, carry)
+
+
+def _bd_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+                   dq_acc, delta, *, d, block_q, block_k, blocks, sub=None):
+    """`_bwd_kernel` over the doubled stream: the tiles of
+    `_bd_backward_tiles`. dq_ref is one block for the whole stream, which
+    stays in VMEM while a head group's grid steps run: the grid step of
+    clean key tile i is the last that the clean queries of tile i see, and
+    the last that the noised queries of the same positions see, and writes
+    the rows of both; a noised key tile's writes none."""
+    i = pl.program_id(2)
+    mask = _Blocks(blocks, q_ref.shape[1] // (2 * block_k), i, keys_first=True)
+
+    def loop(step, carry):
+        for tiles in _bd_backward_tiles(i, mask.half):
+            carry = _tile_loop(step, carry, **tiles)
+        return carry
+
+    def write_dq(dq_ref, dq_acc, scale):
+        @pl.when(i >= mask.half)
+        def _():
+            for j in (i - mask.half, i):
+                at = _rows(j, block_q)
+                dq_ref[0, at, :] = (dq_acc[at, :] * scale).astype(dq_ref.dtype)
+
+    _bwd_run(
+        loop, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta),
+        i, d, block_q, block_k, lambda j: mask.whole(j, block_q),
+        cut=_Cut.of(sub, block_q, block_k, None, keys_first=True, blocks=mask), write_dq=write_dq)
+
+
+def block_diffusion_mask(t, length):
+    """(t, t) bool, t = 2T: which keys a query of the doubled stream
+    [noised | clean] of a block-diffusion step sees. Both halves hold
+    positions 0 .. T-1 in blocks of `length`, blk(i) = i // length:
+
+        noised q i, noised k j : blk(j) == blk(i)   the block itself, both directions
+        noised q i, clean  k j : blk(j) <  blk(i)   the clean past, strictly
+        clean  q i, clean  k j : blk(j) <= blk(i)   block-causal
+        clean  q i, noised k j : never"""
+    at = jnp.arange(t)
+    clean, blk = at >= t // 2, at % (t // 2) // length
+    q_clean, k_clean, q_blk, k_blk = clean[:, None], clean[None, :], blk[:, None], blk[None, :]
+    return jnp.where(k_clean, jnp.where(q_clean, k_blk <= q_blk, k_blk < q_blk),
+                     ~q_clean & (k_blk == q_blk))
 
 
 # --------------------------------------------------------------------------
@@ -1230,30 +1460,36 @@ def _flash_rows_bwd_rule(tiles, interpret, res, do):
 _flash_rows.defvjp(_flash_rows_fwd_rule, _flash_rows_bwd_rule)
 
 
-def flash_attention_rows(q, k, v, heads, *, window=None, select=None, interpret=False):
+def flash_attention_rows(q, k, v, heads, *, window=None, select=None, blocks=None,
+                         interpret=False):
     """q/k/v (B * heads, T, D), a head of whole vregs a row as `_as_rows`
     gives it (ops/qk_prep.py writes q and k so) -> (B, T, heads, D): the
-    causal call, over the last `window` keys alone, or with `select` =
-    (mask, mask_t, top_k) `flash_selected_attention`'s."""
+    causal call, over the last `window` keys alone, with `select` =
+    (mask, mask_t, top_k) `flash_selected_attention`'s, or with `blocks`
+    the block-diffusion call (`flash_causal_attention`)."""
     rows, t, d = q.shape
     mask, mask_t, top_k = select or (None, None, t)
     if top_k >= t:
         mask = mask_t = None
-        tiles = flash_tiles(heads, t, d, q.dtype, window)
+        tiles = flash_tiles(heads, t, d, q.dtype, window, blocks=blocks)
     else:
         tiles = flash_tiles(heads, t, d, q.dtype, select=top_k)
     o = _flash_rows(q, k, v, mask, mask_t, tiles, interpret)
     return _as_heads(o, (rows // heads, t, heads, d))
 
 
-def flash_causal_attention(q, k, v, *, window=None, block_q=None, block_k=None,
+def flash_causal_attention(q, k, v, *, window=None, blocks=None, block_q=None, block_k=None,
                            interpret=False):
     """q/k/v: (B, T, H, D) → (B, T, H, D); fused causal attention, with
-    `window` over the last `window` keys alone (the query's own included).
-    Tiles come from `flash_tiles`; block_q / block_k override it (the tests'
-    way to reach every tile shape at small sizes)."""
+    `window` over the last `window` keys alone (the query's own included);
+    with `blocks` the T positions are the doubled stream [noised | clean] of
+    a block-diffusion step with blocks of that length, and a query sees what
+    `block_diffusion_mask` shows (the calls are flash_bd<blocks>_fwd and
+    flash_bd<blocks>_bwd_fused). Tiles come from `flash_tiles`; block_q /
+    block_k override it (the tests' way to reach every tile shape at small
+    sizes)."""
     _, t, h, d = q.shape
-    tiles = _with_blocks(flash_tiles(h, t, d, q.dtype, window), t, block_q, block_k)
+    tiles = _with_blocks(flash_tiles(h, t, d, q.dtype, window, blocks=blocks), t, block_q, block_k)
     return _flash(q, k, v, None, None, tiles, interpret)
 
 
@@ -1262,6 +1498,9 @@ def _with_blocks(tiles, t, block_q, block_k):
     if not (block_q or block_k):
         return tiles
     block_q, block_k = block_q or tiles.block_q, block_k or tiles.block_k
+    if tiles.blocks is not None and (block_q != block_k or t // 2 % block_q):
+        raise ValueError(f"tiles of {block_q} x {block_k}: a block-diffusion call's are square "
+                         f"and divide each half of its stream of {t}")
     if t % block_q or block_q % block_k:
         raise ValueError(
             f"block_q ({block_q}) must divide the seq len ({t}) and be a "
@@ -1271,13 +1510,16 @@ def _with_blocks(tiles, t, block_q, block_k):
     return tiles._replace(block_q=block_q, block_k=block_k, sub_fwd=sub_fwd, sub_bwd=sub_bwd)
 
 
-def xla_causal_attention(q, k, v, window=None):
+def xla_causal_attention(q, k, v, window=None, blocks=None):
     """Plain einsum-softmax reference path, (B, T, H, D) → (B, T, H, D); XLA
     fuses it adequately on TPU."""
     t, d = q.shape[1], q.shape[3]
     s = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32)
     s = s / math.sqrt(d)
-    mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+    if blocks is not None:
+        mask = block_diffusion_mask(t, blocks)
+    else:
+        mask = jnp.tril(jnp.ones((t, t), dtype=bool))
     if window is not None and window < t:
         mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)
     s = jnp.where(mask[None, None], s, NEG_INF)
@@ -1464,23 +1706,29 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def attention_path(seq_len: int) -> str:
+def attention_path(seq_len: int, blocks: Optional[int] = None) -> str:
     """Which path `causal_attention` takes for this sequence length on this
-    process's backend: "flash" (the pallas kernel) or "xla"."""
+    process's backend: "flash" (the pallas kernel) or "xla". A doubled
+    stream in `blocks` takes the kernels where each half is whole tiles and
+    a sub-tile whole blocks."""
+    if blocks is not None and (seq_len % 256 or _SUB_TILE % blocks):
+        return "xla"
     if _on_tpu() and seq_len >= 256 and seq_len % 128 == 0:
         return "flash"
     return "xla"
 
 
-def causal_attention(q, k, v, window=None):
+def causal_attention(q, k, v, window=None, blocks=None):
     """q/k/v (B, T, H, D) → (B, T, H, D), as the models hold them; `window`
-    keys a query sees, itself included (None: all before it).
+    keys a query sees, itself included (None: all before it); `blocks`, the
+    block length where the T positions are a block-diffusion step's doubled
+    stream (`block_diffusion_mask`).
 
     Uses the pallas flash kernel on TPU for sequences long enough to matter;
     XLA path elsewhere (CPU tests, tiny shapes). A Mosaic kernel cannot be
     partitioned by the compiler: under a multi-device mesh call it through
     `parallel.train_step.attn_for_mesh` (shard_map over batch and heads).
     """
-    if attention_path(q.shape[1]) == "flash":
-        return flash_causal_attention(q, k, v, window=window)
-    return xla_causal_attention(q, k, v, window)
+    if attention_path(q.shape[1], blocks) == "flash":
+        return flash_causal_attention(q, k, v, window=window, blocks=blocks)
+    return xla_causal_attention(q, k, v, window, blocks)

@@ -32,7 +32,6 @@ from jax.profiler import TraceAnnotation
 
 from ray_tpu._private import flight_recorder
 from ray_tpu.models import remat
-from ray_tpu.models.loss import loss_fn
 from ray_tpu.parallel.mesh import (
     ShardingRules,
     batch_sharding,
@@ -193,22 +192,16 @@ class TrainStep:
         self._init = jax.jit(train_init, out_shardings=self.state_shardings)
 
         # The jitted functions are named for what they are (the trace's
-        # "XLA Modules" line reads jit_train_step), and the loss and the
-        # optimizer (with the gradients' global norm) are scopes beside the
-        # ones flax gives the model's modules: every instruction of the
+        # "XLA Modules" line reads jit_train_step), and the loss (which the
+        # family's objective opens: models/__init__.py) and the optimizer
+        # (with the gradients' global norm) are scopes beside the ones flax
+        # gives the model's modules: every instruction of the
         # compiled step carries its scope and its pass in its op_name, and a
         # device-trace window is reduced to ms a step by them
         # (train/_device_profile.py).
         def train_step(state, batch):
-            def loss_of(params):
-                logits, sown = self.model.apply(
-                    {"params": params}, batch["idx"], mutable=list(family.sown))
-                aux = sum(jax.tree.leaves([sown.get(c, {}) for c in family.loss_terms]), 0.0)
-                with jax.named_scope("loss"):
-                    return loss_fn(logits, batch["targets"]) + aux, sown
-
-            (loss, sown), grads = jax.value_and_grad(loss_of, has_aux=True)(
-                state["params"])
+            (loss, sown), grads = jax.value_and_grad(family.objective, argnums=1, has_aux=True)(
+                self.model, state["params"], batch, state["step"])
             with jax.named_scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, state["opt_state"], state["params"]

@@ -130,7 +130,8 @@ def scope_of(op_name: str) -> Tuple[str, str]:
     return "/".join(scope), which
 
 
-_EMBED = frozenset(("wte", "wpe", "tok_emb"))
+# and what makes the embedding's input: a diffusion step's noise (models/sdar.py)
+_EMBED = frozenset(("wte", "wpe", "tok_emb", "sdar.noise"))
 _HEAD = frozenset(("lm_head", "wte.attend", "tok_emb.attend"))
 _ATTN_PROJ = frozenset(("c_attn", "c_proj", "wq", "wk", "wv", "wo", "qkv"))
 _BLOCK = frozenset(("h", "p"))

@@ -209,6 +209,10 @@ def _tiny(family):
         from ray_tpu.models.phi4_flash import Phi4FlashConfig
 
         return Phi4FlashConfig.tiny(), True
+    if family == "sdar":
+        from ray_tpu.models.sdar import SDARConfig
+
+        return SDARConfig.tiny(num_held=4), True
     from ray_tpu.models.granite import GraniteConfig
 
     return GraniteConfig.tiny(), True
@@ -223,7 +227,7 @@ def _compiled_text(cfg, t=128):  # an indexed layer packs its mask 128 keys to a
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_moe", "mellum", "mellum_indexed",
                                     "granite", "lfm2", "kanana", "nemotron_h", "afmoe",
-                                    "kimi_linear", "phi4_flash"])
+                                    "kimi_linear", "phi4_flash", "sdar"])
 def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     """The tiny configuration's step, compiled here: every scheduled
     instruction has a group of the one vocabulary and a pass, few are
@@ -248,8 +252,11 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     want = {"gpt2": "mlp", "llama": "mlp", "gpt2_moe": "moe", "mellum": "moe",
             "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv",
             "kanana": "moe.shared", "nemotron_h": "moe.shared", "afmoe": "moe.shared",
-            "kimi_linear": "kda", "phi4_flash": "gmu"}[family]
+            "kimi_linear": "kda", "phi4_flash": "gmu", "sdar": "moe"}[family]
     assert want in groups
+    if family == "sdar":  # the objective is the family's own: its noise, its mask, its loss
+        scopes = {part for r in rows.values() for part in r[0].split("/")}
+        assert {"sdar.noise", "attn.flash_bd", "loss.diffusion"} <= scopes
     if family == "phi4_flash":  # five kinds of block: each kind's scopes, the cross layer apart
         scopes = {r[0] for r in rows.values()}
         for scope in ("ssm.in_proj", "ssm.conv", "ssm.x_proj", "ssm.dt", "ssm.scan", "ssm.gate",

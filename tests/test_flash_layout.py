@@ -62,6 +62,9 @@ WANT = {
     # flash_diff.py counts them by the model's widths; tests/test_bench_phi4_flash.py)
     "phi4_mini_flash_l5.t16384": (1, 16384, 40, 128, {
         "flash_fwd": 2, BWD: 2, "flash_win512_fwd": 1, "flash_win512_bwd_fused": 1}),
+    # T is the doubled stream's, [noised | clean] of 8,192 tokens: the block-diffusion pair
+    "sdar_30b_a3b_l5_ep8.t8192": (1, 16384, 32, 128, {
+        "flash_bd4_fwd": 5, "flash_bd4_bwd_fused": 5}),
 }
 # the width of the score's second part, whose key all heads share, where a
 # cell's calls are the latent pair
@@ -116,10 +119,15 @@ OLD_KINDS = {
         "flash_win512_fwd": "flash_win512_fwd custom-call -> (bf16[40,16384,128], f32[40,1,16384])",
         "flash_win512_bwd_fused": "flash_win512_bwd_fused custom-call -> "
                                   "(bf16[40,16384,128], bf16[40,16384,128], bf16[40,16384,128])"},
+    # nor this one: its own first trace's (my chip run, PR 61, call 1)
+    "sdar_30b_a3b_l5_ep8.t8192": {
+        "flash_bd4_fwd": "flash_bd4_fwd custom-call -> (bf16[32,16384,128], f32[32,1,16384])",
+        "flash_bd4_bwd_fused": "flash_bd4_bwd_fused custom-call -> "
+                               "(bf16[32,16384,128], bf16[32,16384,128], bf16[32,16384,128])"},
 }
 # the shape function that each per-kernel roofline of bench/layer_metrics
 # names for a call it matches
-READERS = ("flash_attention", "flash_backward", "flash_window", "flash_select")
+READERS = ("flash_attention", "flash_backward", "flash_window", "flash_select", "flash_blockdiff")
 
 _CALL = re.compile(r'stablehlo\.custom_call @tpu_custom_call\(.*?kernel_name = "(flash_\w+)".*?\) -> (.*)$',
                    re.M)

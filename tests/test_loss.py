@@ -54,11 +54,17 @@ def test_loss_is_the_log_softmax_form_s_value_and_gradient(case, dtype):
 
 
 def test_every_family_s_step_runs_the_one_loss():
-    """`TrainStep` (every configuration's step), the pipeline schedule and
-    the routed GPT-2 call the same function object, and `gpt2` and `llama`
-    hand out that one under its old name (bench/tests/ asks them for it)."""
-    from ray_tpu.models import gpt2, gpt2_moe, llama
-    from ray_tpu.parallel import pipeline, train_step
+    """The next-token objective (`Family.objective` of every configuration's
+    step but the diffusion family's), the pipeline schedule and the routed
+    GPT-2 call the same function object, and `gpt2` and `llama` hand out that
+    one under its old name (bench/tests/ asks them for it)."""
+    from ray_tpu import models
+    from ray_tpu.models import gpt2, gpt2_moe, llama, sdar
+    from ray_tpu.parallel import pipeline
 
-    for module in (gpt2, gpt2_moe, llama, pipeline, train_step):
+    for module in (gpt2, gpt2_moe, llama, pipeline, models):
         assert module.loss_fn is loss_fn, module.__name__
+    for family in (gpt2, gpt2_moe, llama):
+        config = next(v for v in vars(family).values() if hasattr(v, "family"))
+        assert config.family.objective is models.next_token_objective
+    assert sdar.SDARConfig.family.objective is sdar.objective

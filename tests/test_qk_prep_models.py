@@ -57,7 +57,7 @@ def test_a_tiny_model_through_the_pair_is_the_plain_lines_model(family, dtype, t
     calls, real = [], qp.qk_prep
     # the layer asks the path by its name in the module; ops/moe.py and ops/indexer.py, which
     # have no interpret mode to be told of here, ask `_on_tpu` and a name bound at import
-    monkeypatch.setattr(attention, "attention_path", lambda t: "flash")
+    monkeypatch.setattr(attention, "attention_path", lambda t, blocks=None: "flash")
     monkeypatch.setattr(qp, "qk_prep", lambda *a, **kw: (
         calls.append(kw.get("rep", 1)), real(*a, **kw, interpret=True))[1])
     monkeypatch.setattr(attention, "flash_attention_rows", functools.partial(

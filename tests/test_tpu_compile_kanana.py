@@ -26,7 +26,7 @@ def test_latent_kernels_compile_at_the_cell_s_shape(one_chip):
         o = attention.flash_latent_attention(*ops)
         return o.astype(jnp.float32).sum(), o  # o kept: the forward call is not dead code
 
-    assert attention.flash_tiles(32, 8192, 128, jnp.bfloat16, shared=64) == (512, 512, 2, None, None, None, 128)
+    assert attention.flash_tiles(32, 8192, 128, jnp.bfloat16, shared=64) == (512, 512, 2, None, None, None, 128, None)
     c = jax.jit(jax.grad(loss, argnums=range(5), has_aux=True)).lower(*ops).compile()
     names = _CUSTOM_CALL.findall(c.as_text())
     assert len(names) == 2 and sum("flash_mla_fwd" in n for n in names) == 1 \
