@@ -955,7 +955,9 @@ def _flash_calls_by_cell(on_tpu):
     is `...bwd_dq` or `...bwd_dkv`; a layer that is a scan over a state
     (`mamba` in the configuration's `layer_types`) has ssd_bwd once in place
     of the flash pair, and ssd_fwd once where the step's remat plan saves
-    the scan's outputs (`ssm_y`) and twice where it does not; a layer that is
+    the scan's outputs (`ssm_y`: in as many of those layers as the rung's
+    depth says, models/remat.py, and so for every name below) and twice where
+    it does not; a layer that is
     a gated short convolution (`conv`) has gated_conv_bwd once, and
     gated_conv_fwd once where the plan saves its output (`conv_y`), else
     twice; a layer that is an expert layer alone (`experts`) has none. A
@@ -1009,16 +1011,16 @@ def _flash_calls_by_cell(on_tpu):
         mixers_alone = layer_kinds.count("experts")
         deltas, units = layer_kinds.count("kda"), layer_kinds.count("gmu")
         selective = scans if hasattr(cfg, "ssm_rank") else 0  # ops/selective_scan.py's pair
-        saved = remat.traced(cfg).names
-        scan_fwd = (scans - selective) * (1 if "ssm_y" in saved else 2)
-        conv_fwd = convs * (1 if "conv_y" in saved else 2)
+        kept = remat.traced(cfg).depth  # the layers that save a name run its kernel once
+        scan_fwd = 2 * (scans - selective) - kept("ssm_y")
+        conv_fwd = 2 * convs - kept("conv_y")
         by_group = scans if getattr(cfg, "ssm_groups", 1) > 1 else 0
         prepped = 2 * QK_PREP_LAYERS.get(name, 0)
         if on_tpu and not (fwd == kinds["fused"] == (cfg.n_layer - scans - convs - mixers_alone
                                                      - deltas - units)
-                           and found["sscan_fwd"] == selective * (1 if "sscan_y" in saved else 2)
+                           and found["sscan_fwd"] == 2 * selective - kept("sscan_y")
                            and found["sscan_bwd"] == selective
-                           and found["kda_fwd"] == deltas * (1 if "kda_states" in saved else 2)
+                           and found["kda_fwd"] == 2 * deltas - kept("kda_states")
                            and found["kda_bwd"] == deltas
                            and found["kda_norm_fwd"] == 2 * deltas
                            and found["kda_norm_bwd"] == deltas
@@ -1030,8 +1032,9 @@ def _flash_calls_by_cell(on_tpu):
                            and found["causal_conv_bwd"] == scans + deltas
                            and found["gated_norm_fwd"] == 2 * by_group
                            and found["gated_norm_bwd"] == by_group
-                           and found["qk_prep_bwd"] == prepped and found["qk_prep_fwd"] ==
-                           prepped * (1 if {"attn_q", "attn_k"} <= set(saved) else 2)):
+                           and found["qk_prep_bwd"] == prepped
+                           and found["qk_prep_fwd"] == (
+                               prepped and 2 * (prepped - kept("attn_q")))):
             raise RuntimeError(f"{name}: {cfg.n_layer} layers, {scans} of them scans and "
                                f"{convs} convolutions, calls {calls[name]}")
         if kinds["dq"] or kinds["dkv"]:

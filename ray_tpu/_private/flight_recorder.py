@@ -82,8 +82,9 @@ bytes, hex-encoded at dump time) and a short detail string/number.
                                  (optimizer steps so far, seconds), or
                                  where the model's blocks are under remat
                                  (seconds, names saved across it, their
-                                 bytes a layer, in all, the step's
-                                 reckoned bytes, the limit held to):
+                                 bytes in each layer, in all, the step's
+                                 reckoned bytes, the limit held to, ...,
+                                 each rung's depth, the names a layer):
                                  models/remat.py:RematPlan
   train.device_profile           a device-trace window was reduced
                                  (train/_device_profile.py): (steps in it,

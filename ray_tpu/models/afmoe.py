@@ -209,7 +209,10 @@ class AfmoeBlock(nn.Module):
 # together 429.55 ms at 13.58 GiB, over what the
 # rule is held to: on a v5e it takes the first two, 434.42 ms at 13.04 GiB
 # (call 8, the committed program with the buffers at 2.0: 429.01 ms at 13.044
-# GiB, the first rung alone 453.46 at 11.978).
+# GiB, the first rung alone 453.46 at 11.978), and since PR 62 the shared
+# expert's in the last two routed layers of four beside them (what the
+# room that is left holds: models/remat.py's depths; 0.125 GiB that cost a
+# step 0.15% on the chip, PERF.md section 6, PR 62).
 # The expert layer's three products (ops/moe.py:KEPT_PRODUCTS) are no rung,
 # as in models/kanana.py and for its reason: experts 1,024 wide on 1,024 rows
 # each are cheaper made again than read back in the form that reads them
@@ -221,21 +224,20 @@ REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 8.3), (("attn_gate",), 12.1),
 def remat_plan(cfg: AfmoeConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times."""
+    bytes are one layer's, and `made_in` says which layers make it."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
-    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     dense = cfg.n_layer - cfg.routed_layers
     name_bytes = remat.attention_bytes(shape, cfg.n_head, cfg.head_dim, itemsize)
     name_bytes.update(
         attn_gate=name_bytes["attn_out"],
-        shared_up=share(2 * tokens * cfg.shared_dim * itemsize, cfg.routed_layers),
-        mlp_up=share(2 * tokens * cfg.intermediate * itemsize, dense),
-        moe_plan=share(moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
-                                       cfg.expert_dim, itemsize,
-                                       headroom=EXPERT_HEADROOM)[moe.ROUTE_PLAN],
-                       cfg.routed_layers))
+        shared_up=2 * tokens * cfg.shared_dim * itemsize,
+        mlp_up=2 * tokens * cfg.intermediate * itemsize,
+        moe_plan=moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
+                                 cfg.expert_dim, itemsize,
+                                 headroom=EXPERT_HEADROOM)[moe.ROUTE_PLAN])
+    routed = range(cfg.num_dense_layers, cfg.n_layer)
+    made_in = dict(shared_up=routed, moe_plan=routed, mlp_up=range(dense))
     norms = 4 * d + 2 * cfg.head_dim
     params = (cfg.n_layer * (cfg.attention_params() + norms) + dense * 3 * d * cfg.intermediate
               + cfg.routed_layers * (d * cfg.num_experts + 3 * d * cfg.shared_dim
@@ -245,7 +247,7 @@ def remat_plan(cfg: AfmoeConfig, shape: remat.StepShape, limit) -> remat.RematPl
         shape, params=params, width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
-                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,), made_in)
 
 
 def _block_bytes(cfg: AfmoeConfig, itemsize: int) -> int:
@@ -273,7 +275,7 @@ class AfmoeGroup(nn.Module):
     """Every block of the model, each under nn.remat: the one parameter group."""
 
     config: AfmoeConfig
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # the blocks' checkpoint policies, one a layer
     stream: Any = None
 
     @nn.compact
@@ -281,7 +283,7 @@ class AfmoeGroup(nn.Module):
         cfg = self.config
         choices = []
         for i, kind in enumerate(cfg.layer_types):
-            x, chosen = nn.remat(AfmoeBlock, policy=self.keep)(
+            x, chosen = nn.remat(AfmoeBlock, policy=self.keep[i])(
                 cfg, kind, i >= cfg.num_dense_layers, self.stream, name=f"h_{i}")(x, pos_offset)
             choices.append(chosen)
         layers.sow_choices(self, choices)

@@ -182,7 +182,7 @@ class GPT2(nn.Module):
         # what of REMAT_RUNGS the chip's memory allows.
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         for i in range(cfg.n_layer):
-            x = nn.remat(Block, policy=keep)(cfg, self.stream, name=f"h_{i}")(x, deterministic)
+            x = nn.remat(Block, policy=keep[i])(cfg, self.stream, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         # weight-tied head
         logits = wte.attend(x.astype(jnp.float32))

@@ -183,27 +183,31 @@ class GraniteBlock(nn.Module):
 # slices, and are not named: 3.2 ms for 0.88 GiB alone, and 0.9 ms *slower*
 # beside the scan's; the convolution's second run is a kernel's 0.11 ms a
 # layer since PR 48, less still to spare. At the cell's shape the rule's
-# bookkeeping (16 bytes a parameter: 11.5 of the 13.5 GiB) has room for one
-# of the two and takes the MLP's.
+# bookkeeping (16 bytes a parameter: 11.5 of the 13.5 GiB) has no room for
+# both whole: until PR 62 it took the MLP's whole and no scan's; since then
+# (models/remat.py's depths) the scan's in the last eight Mamba layers of nine
+# and the MLP's in the last nine layers of ten, which by the worths below
+# spares more and on the chip cost 1.4 ms a step (-0.72%: the scan's worth
+# dates from PR 36's program and spares nothing a step today; these worths
+# are due a new reading, ROADMAP.md A7 b, PERF.md section 6, PR 62).
 REMAT_RUNGS = ((("ssm_y", "ssm_states"), 14.1), (("mlp_up",), 11.2))
 
 
 def remat_plan(cfg: GraniteConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times."""
+    bytes are one layer's, and `made_in` says which layers make it."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
     kinds = cfg.layer_types
-    attn, mamba = kinds.count(ATTENTION), kinds.count(MAMBA)
-    share = lambda nbytes, count: nbytes * count // cfg.n_layer
-    name_bytes = {name: share(nbytes, attn) for name, nbytes in remat.attention_bytes(
-        shape, cfg.n_head, cfg.head_dim, itemsize).items()}
+    mamba = remat.layers_of(kinds, MAMBA)
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, cfg.head_dim, itemsize)
+    made_in = dict.fromkeys(name_bytes, remat.layers_of(kinds, ATTENTION))
+    made_in.update(ssm_y=mamba, ssm_states=mamba)
     chunks = -(-shape.seq_len // cfg.ssm_chunk)
     name_bytes.update(
-        ssm_y=share(tokens * cfg.ssm_inner * itemsize, mamba),
-        ssm_states=share(shape.rows * chunks * cfg.ssm_inner * cfg.ssm_state * 4, mamba),
+        ssm_y=tokens * cfg.ssm_inner * itemsize,
+        ssm_states=shape.rows * chunks * cfg.ssm_inner * cfg.ssm_state * 4,
         mlp_up=2 * tokens * cfg.intermediate * itemsize // shape.tp)
     vectors = sum(2 * d + (cfg.ssm_conv_dim * (cfg.ssm_conv + 1) + 3 * cfg.ssm_heads
                            + cfg.ssm_inner if kind == MAMBA else 0) for kind in kinds) + d
@@ -211,7 +215,7 @@ def remat_plan(cfg: GraniteConfig, shape: remat.StepShape, limit) -> remat.Remat
         shape, params=cfg.matmul_params() + vectors, width=d, vocab=cfg.vocab_size,
         n_layer=cfg.n_layer, itemsize=itemsize,
         block=_block_bytes(cfg, itemsize) * tokens if mamba else 0)
-    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit, made_in=made_in)
 
 
 def _block_bytes(cfg: GraniteConfig, itemsize: int) -> int:
@@ -225,14 +229,14 @@ class GranitePeriod(nn.Module):
     """One period of the layer pattern, each block under nn.remat."""
 
     config: GraniteConfig
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # its blocks' checkpoint policies, one a layer
     stream: Any = None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
         for i, kind in enumerate(cfg.layer_types[:cfg.period]):
-            x = nn.remat(GraniteBlock, policy=self.keep)(
+            x = nn.remat(GraniteBlock, policy=self.keep[i])(
                 cfg, kind, self.stream, name=f"h_{i}")(x)
         return x
 
@@ -251,7 +255,8 @@ class Granite(nn.Module):
         x = emb(idx) * cfg.embedding_multiplier
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         for i in range(cfg.n_layer // cfg.period):
-            x = GranitePeriod(cfg, keep, self.stream, name=f"p_{i}")(x)
+            x = GranitePeriod(cfg, keep[i * cfg.period:(i + 1) * cfg.period], self.stream,
+                              name=f"p_{i}")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         # the tied head in float32, as models/llama.py's untied one, and under
         # that one's name: written at the model's top level it would carry no

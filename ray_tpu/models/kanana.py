@@ -182,11 +182,9 @@ REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v", "attn_q_shared", "attn_k_shared")
 def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times."""
+    bytes are one layer's, and `made_in` says which layers make it."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
-    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     head = lambda width: tokens * cfg.n_head * width * itemsize
     dense = cfg.n_layer - cfg.routed_layers
     name_bytes = dict(
@@ -194,11 +192,12 @@ def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematP
         attn_q=head(cfg.nope_dim), attn_k=head(cfg.nope_dim), attn_v=head(cfg.v_dim),
         attn_q_shared=head(cfg.rope_dim),
         attn_k_shared=tokens * max(cfg.rope_dim, 128) * itemsize,  # a vreg of lanes a token
-        shared_up=share(2 * tokens * cfg.shared_dim * itemsize, cfg.routed_layers),
-        mlp_up=share(2 * tokens * cfg.intermediate * itemsize, dense),
-        moe_plan=share(moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
-                                       cfg.expert_dim, itemsize)[moe.ROUTE_PLAN],
-                       cfg.routed_layers))
+        shared_up=2 * tokens * cfg.shared_dim * itemsize,
+        mlp_up=2 * tokens * cfg.intermediate * itemsize,
+        moe_plan=moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
+                                 cfg.expert_dim, itemsize)[moe.ROUTE_PLAN])
+    routed = range(cfg.num_dense_layers, cfg.n_layer)
+    made_in = dict(shared_up=routed, moe_plan=routed, mlp_up=range(dense))
     params = (cfg.n_layer * cfg.attention_params() + dense * 3 * d * cfg.intermediate
               + cfg.routed_layers * (d * cfg.num_experts + 3 * d * cfg.shared_dim
                                      + cfg.experts_held * 3 * d * cfg.expert_dim)
@@ -207,7 +206,7 @@ def remat_plan(cfg: KananaConfig, shape: remat.StepShape, limit) -> remat.RematP
         shape, params=params, width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
-                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,), made_in)
 
 
 def _block_bytes(cfg: KananaConfig, itemsize: int) -> int:
@@ -232,7 +231,7 @@ class KananaGroup(nn.Module):
     """Every block of the model, each under nn.remat: the one parameter group."""
 
     config: KananaConfig
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # the blocks' checkpoint policies, one a layer
     stream: Any = None
 
     @nn.compact
@@ -240,7 +239,7 @@ class KananaGroup(nn.Module):
         cfg = self.config
         choices = []
         for i in range(cfg.n_layer):
-            x, chosen = nn.remat(KananaBlock, policy=self.keep)(
+            x, chosen = nn.remat(KananaBlock, policy=self.keep[i])(
                 cfg, i >= cfg.num_dense_layers, self.stream, name=f"h_{i}")(x)
             choices.append(chosen)
         layers.sow_choices(self, choices)

@@ -324,12 +324,12 @@ class KimiLinearBlock(nn.Module):
 # 17.3 ms a KDA layer in the benchmark's cell on a v5e (my chip run, PR 54,
 # call 2: the traced step's four `remat` calls; 17.9 since the kernels norm
 # q and k, PR 55) for 0.625 GiB a layer (the states 0.5 of it): 27.7 ms a
-# GiB, not measured as a step's difference, because the cell has no room
-# for it: its step holds 12.04 GiB with the first rung alone (12.44 before
-# PR 60, 13.32 before PR 55), the rule reckons 12.18, the rung is 2.5 GiB
-# and the limit 13.5, so the rule takes nothing more there. It is stated for
-# a shape that has the room (fewer layers, a shorter sequence, state split
-# over chips). The latent layer's operands, the shared expert's and the
+# GiB. The cell has no room for all four layers': its step holds 12.04 GiB
+# with the first rung alone (12.44 before PR 60, 13.32 before PR 55), the
+# rule reckons 12.18, the rung is 2.5 GiB and the limit 13.5. Since PR 62
+# the rule takes a rung by depth (models/remat.py) and saves them there in
+# the last three KDA layers of four (1.875 GiB; it reckons 12.94): kda_fwd
+# runs five times a step, not eight. The latent layer's operands, the shared expert's and the
 # dense MLP's products are rungs in models/kanana.py at these widths and
 # none here: they are one layer's, four layers' and one layer's, under 10 ms
 # of a step by kanana's readings, the reckoning would take them as free
@@ -343,23 +343,22 @@ REMAT_RUNGS = ((("kda_out", "kda_states"), 27.7),)
 def remat_plan(cfg: KimiLinearConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times."""
+    bytes are one layer's, and `made_in` says which layers make it."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
-    n_kda, n_mla = cfg.layer_types.count(KDA), cfg.layer_types.count(MLA)
-    share = lambda nbytes, count: nbytes * count // cfg.n_layer
-    head = lambda width: share(tokens * cfg.n_head * width * itemsize, n_mla)
+    kda, mla = (remat.layers_of(cfg.layer_types, kind) for kind in (KDA, MLA))
+    n_kda = len(kda)
     dense = cfg.n_layer - cfg.routed_layers
     chunks = -(-shape.seq_len // cfg.kda_chunk)
     name_bytes = dict(
-        attn_out=head(cfg.v_dim), attn_lse=share(tokens * cfg.n_head * 4, n_mla),
-        kda_out=share(tokens * cfg.kda_inner * itemsize, n_kda),
-        kda_states=share(shape.rows * chunks * cfg.kda_inner * cfg.kda_head_dim * 4, n_kda),
-        moe_plan=share(moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
-                                       cfg.expert_dim, itemsize,
-                                       headroom=EXPERT_HEADROOM)[moe.ROUTE_PLAN],
-                       cfg.routed_layers))
+        attn_out=tokens * cfg.n_head * cfg.v_dim * itemsize, attn_lse=tokens * cfg.n_head * 4,
+        kda_out=tokens * cfg.kda_inner * itemsize,
+        kda_states=shape.rows * chunks * cfg.kda_inner * cfg.kda_head_dim * 4,
+        moe_plan=moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
+                                 cfg.expert_dim, itemsize,
+                                 headroom=EXPERT_HEADROOM)[moe.ROUTE_PLAN])
+    made_in = dict(attn_out=mla, attn_lse=mla, kda_out=kda, kda_states=kda,
+                   moe_plan=range(cfg.num_dense_layers, cfg.n_layer))
     params = (cfg.mixer_params() + n_kda * cfg.kda_conv * 3 * cfg.kda_inner
               + dense * 3 * d * cfg.intermediate
               + cfg.routed_layers * (d * cfg.num_experts + 3 * d * cfg.shared_dim
@@ -370,7 +369,7 @@ def remat_plan(cfg: KimiLinearConfig, shape: remat.StepShape, limit) -> remat.Re
         n_layer=2 * cfg.n_layer,  # a copy of the stream each half of a block
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
-                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,), made_in)
 
 
 def _block_bytes(cfg: KimiLinearConfig, itemsize: int) -> int:
@@ -404,7 +403,7 @@ class KimiLinearGroup(nn.Module):
     parameter group."""
 
     config: KimiLinearConfig
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # the blocks' checkpoint policies, one a layer
     stream: Any = None
 
     @nn.compact
@@ -412,7 +411,7 @@ class KimiLinearGroup(nn.Module):
         cfg = self.config
         choices = []
         for i, kind in enumerate(cfg.layer_types):
-            x, chosen = KimiLinearBlock(cfg, kind, i >= cfg.num_dense_layers, self.keep,
+            x, chosen = KimiLinearBlock(cfg, kind, i >= cfg.num_dense_layers, self.keep[i],
                                         self.stream, name=f"h_{i}")(x)
             choices.append(chosen)
         layers.sow_choices(self, choices)

@@ -232,23 +232,23 @@ REMAT_RUNGS = ((("conv_bcu", "conv_y"), 11.8), (("mlp_up",), 9.9),
 def remat_plan(cfg: Lfm2Config, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times."""
+    bytes are one layer's, and `made_in` says which layers make it."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
     kinds = cfg.layer_types
-    share = lambda nbytes, count: nbytes * count // cfg.n_layer
-    name_bytes = {name: share(nbytes, kinds.count(ATTENTION))
-                  for name, nbytes in remat.attention_bytes(
-                      shape, cfg.n_head, cfg.head_dim, itemsize).items()}
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, cfg.head_dim, itemsize)
+    made_in = dict.fromkeys(name_bytes, remat.layers_of(kinds, ATTENTION))
     dense = min(cfg.num_dense_layers, cfg.n_layer)
     name_bytes.update(
-        conv_bcu=share(3 * tokens * d * itemsize, kinds.count(CONV)),
-        conv_y=share(tokens * d * itemsize, kinds.count(CONV)),
-        mlp_up=share(2 * tokens * cfg.intermediate * itemsize // shape.tp, dense))
+        conv_bcu=3 * tokens * d * itemsize, conv_y=tokens * d * itemsize,
+        mlp_up=2 * tokens * cfg.intermediate * itemsize // shape.tp)
+    made_in.update(dict.fromkeys(("conv_bcu", "conv_y"), remat.layers_of(kinds, CONV)),
+                   mlp_up=range(dense))
     routed = cfg.n_layer - dense
-    name_bytes.update({name: share(nbytes, routed) for name, nbytes in moe.named_bytes(
-        tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d, cfg.expert_dim, itemsize).items()})
+    products = moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
+                               cfg.expert_dim, itemsize)
+    name_bytes.update(products)
+    made_in.update(dict.fromkeys(products, range(dense, cfg.n_layer)))
     params = (sum(cfg.operator_params(kind) for kind in kinds)
               + dense * 3 * d * cfg.intermediate
               + routed * (d * cfg.num_experts + cfg.experts_held * 3 * d * cfg.expert_dim)
@@ -257,7 +257,7 @@ def remat_plan(cfg: Lfm2Config, shape: remat.StepShape, limit) -> remat.RematPla
         shape, params=params, width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
-                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,), made_in)
 
 
 def _block_bytes(cfg: Lfm2Config, itemsize: int) -> int:
@@ -286,17 +286,17 @@ class Lfm2Group(nn.Module):
     """Every block of the model, each under nn.remat: the one parameter group."""
 
     config: Lfm2Config
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # the blocks' checkpoint policies, one a layer
     stream: Any = None
-    products_kept: bool = True  # as the blocks'
+    products_kept: Any = ()  # as the blocks', one a layer
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
         choices = []
         for i, kind in enumerate(cfg.layer_types):
-            x, chosen = nn.remat(Lfm2Block, policy=self.keep)(
-                cfg, kind, cfg.routed(i), self.stream, self.products_kept, name=f"h_{i}")(x)
+            x, chosen = nn.remat(Lfm2Block, policy=self.keep[i])(
+                cfg, kind, cfg.routed(i), self.stream, self.products_kept[i], name=f"h_{i}")(x)
             choices.append(chosen)
         layers.sow_choices(self, choices)
         return x
@@ -313,7 +313,7 @@ class Lfm2(nn.Module):
                        embedding_init=nn.initializers.normal(0.02))
         x = emb(idx)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
-        products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
+        products = remat.traced(cfg).saved_in(*moe.KEPT_PRODUCTS)
         x = Lfm2Group(cfg, keep, self.stream, products, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         # the tied head under models/llama.py's untied one's name (models/

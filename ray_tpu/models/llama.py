@@ -117,7 +117,10 @@ class LlamaBlock(nn.Module):
 # Mistral-7B's widths on a v5e (PERF.md section 6, PR 33): the outputs of
 # `gate` and `up` spare those matmuls' second run (and under fsdp their
 # kernels' second gather), the kernel's operands the q, k, v projections',
-# the rotary embedding and the repeat of the key-value heads.
+# the rotary embedding and the repeat of the key-value heads. On a v5e the
+# cell's step has no room for both whole: until PR 62 it saved `mlp_up` whole
+# and no operand; since (models/remat.py's depths) the operands in the last
+# seven layers of eight and `mlp_up` in the last six, +1.47% on the chip.
 REMAT_RUNGS = ((("mlp_up",), 30.7), (("attn_q", "attn_k", "attn_v"), 38.9))
 
 
@@ -143,7 +146,7 @@ class Llama(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb")(idx)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
         for i in range(cfg.n_layer):
-            x = nn.remat(LlamaBlock, policy=keep)(cfg, self.stream, name=f"h_{i}")(x, pos_offset)
+            x = nn.remat(LlamaBlock, policy=keep[i])(cfg, self.stream, name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           name="lm_head")(x.astype(jnp.float32))

@@ -249,6 +249,10 @@ class MellumBlock(nn.Module):
 # The price is a step that overflowed its headroom: with the pair kept it
 # pays the headroom buffer's forward work on top of its own, 478.19 ms
 # against the parent's 440.55 (call 7, every layer forced to overflow).
+# On a v5e the cell has no room for all three products: until PR 62 it kept
+# the gate's and the up's whole; since (models/remat.py's depths) the down
+# product in the last three layers of four and the gate's in the last two,
+# +0.1 to +0.4% on the chip (PERF.md section 6, PR 62).
 REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 37.6),
                (("moe_gate",), 4.1), (("moe_up",), 4.1), (("moe_out",), 5.8))
 
@@ -270,7 +274,7 @@ def remat_plan(cfg: MellumConfig, shape: remat.StepShape, limit) -> remat.RematP
     name_bytes.update(moe.named_bytes(shape.rows * shape.seq_len, cfg.top_k, cfg.experts_held,
                                       cfg.num_experts, d, cfg.expert_dim, itemsize))
     first = remat.FIRST_RUNG + (moe.ROUTE_PLAN,)
-    indexed = sum(kind == INDEXED for kind in cfg.layer_types)
+    indexed = remat.layers_of(cfg.layer_types, INDEXED)
     if indexed and cfg.index_top_k < shape.seq_len:
         # an indexed layer also holds its selection, the transposed
         # relation's packed mask (int32 words: what the one backward call
@@ -279,13 +283,13 @@ def remat_plan(cfg: MellumConfig, shape: remat.StepShape, limit) -> remat.RematP
         # scores a query, read once by the selection
         layer += cfg.index_params()
         first += ("attn_sel",)
-        name_bytes["attn_sel"] = (shape.rows * shape.seq_len
-                                  * max(128, shape.seq_len // 32) * 4) * indexed // cfg.n_layer
+        name_bytes["attn_sel"] = shape.rows * shape.seq_len * max(128, shape.seq_len // 32) * 4
         routed = max(routed, 2 * shape.rows * shape.seq_len * shape.seq_len * 4)
     held = remat.held_bytes(
         shape, params=cfg.n_layer * layer + 2 * cfg.vocab_size * d, width=d,
         vocab=cfg.vocab_size, n_layer=cfg.n_layer, itemsize=itemsize, block=routed)
-    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit, first)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit, first,
+                      {"attn_sel": indexed})
 
 
 class Mellum(nn.Module):
@@ -301,10 +305,10 @@ class Mellum(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0))(idx)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
-        products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
+        products = remat.traced(cfg).saved_in(*moe.KEPT_PRODUCTS)
         for i, kind in enumerate(cfg.layer_types):
-            x = nn.remat(MellumBlock, policy=keep)(
-                cfg, kind, self.stream, products, name=f"h_{i}")(x, pos_offset)
+            x = nn.remat(MellumBlock, policy=keep[i])(
+                cfg, kind, self.stream, products[i], name=f"h_{i}")(x, pos_offset)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(x.astype(jnp.float32))

@@ -207,22 +207,21 @@ REMAT_RUNGS = ((("shared_up",), 23.4), (("attn_q", "attn_k", "attn_v"), 9.6),
 def remat_plan(cfg: NemotronHConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times."""
+    bytes are one layer's, and `made_in` says which layers make it."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
-    share = lambda nbytes, kind: nbytes * cfg.count(kind) // cfg.n_layer
-    name_bytes = {name: share(nbytes, ATTENTION) for name, nbytes in remat.attention_bytes(
-        shape, cfg.n_head, cfg.head_dim, itemsize).items()}
-    name_bytes["shared_up"] = share(tokens * cfg.shared_dim * itemsize, EXPERTS)
-    name_bytes.update({name: share(nbytes, EXPERTS) for name, nbytes in moe.named_bytes(
-        tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d, cfg.expert_dim, itemsize,
-        RELU2).items()})
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, cfg.head_dim, itemsize)
+    made_in = dict.fromkeys(name_bytes, remat.layers_of(cfg.layer_types, ATTENTION))
+    name_bytes["shared_up"] = tokens * cfg.shared_dim * itemsize
+    name_bytes.update(moe.named_bytes(tokens, cfg.top_k, cfg.experts_held, cfg.num_experts, d,
+                                      cfg.expert_dim, itemsize, RELU2))
+    made_in.update(dict.fromkeys(name_bytes.keys() - made_in.keys(),
+                                 remat.layers_of(cfg.layer_types, EXPERTS)))
     held = remat.held_bytes(
         shape, params=count_params(cfg), width=d, vocab=cfg.vocab_size, n_layer=cfg.n_layer,
         itemsize=itemsize, block=_block_bytes(cfg, itemsize) * tokens)
     return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit,
-                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,))
+                      remat.FIRST_RUNG + (moe.ROUTE_PLAN,), made_in)
 
 
 def count_params(cfg: NemotronHConfig) -> int:
@@ -256,17 +255,17 @@ class NemotronHGroup(nn.Module):
     """Every block of the model, each under nn.remat: the one parameter group."""
 
     config: NemotronHConfig
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # the blocks' checkpoint policies, one a layer
     stream: Any = None
-    products_kept: bool = True  # as the blocks'
+    products_kept: Any = ()  # as the blocks', one a layer
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
         choices = []
         for i, kind in enumerate(cfg.layer_types):
-            x, chosen = nn.remat(NemotronHBlock, policy=self.keep)(
-                cfg, kind, self.stream, self.products_kept, name=f"h_{i}")(x)
+            x, chosen = nn.remat(NemotronHBlock, policy=self.keep[i])(
+                cfg, kind, self.stream, self.products_kept[i], name=f"h_{i}")(x)
             choices.append(chosen)
         layers.sow_choices(self, choices)
         return x
@@ -282,7 +281,7 @@ class NemotronH(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(0.02))(idx)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
-        products = any(n in RELU2.products for n in remat.traced(cfg).names)
+        products = remat.traced(cfg).saved_in(*RELU2.products)
         x = NemotronHGroup(cfg, keep, self.stream, products, name="p_0")(x)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         return layers.untied_head(self, cfg, x)

@@ -427,8 +427,10 @@ class Phi4FlashBlock(nn.Module):
 # difference) for 0.195 GiB (y 160 MiB, the states 40). The MLP's product
 # spares its matmul's second run: 12.4 ms a layer in the same trace (`mlp
 # remat` 61.9 ms over five layers) for 0.625 GiB, 19.8 ms a GiB; at this
-# family's cell the plan has no room for it (3.1 GiB over five layers beside
-# 8.60 GiB of state), and it is stated for a shape that has. The memory
+# family's cell the plan has no room for all five layers' (3.1 GiB beside
+# 8.60 GiB of state), and since PR 62, when the rule took to saving a rung
+# in as many layers as there is room for (models/remat.py), it saves the
+# last three's (1.875 GiB; it reckons 13.30 of 13.5). The memory
 # layer's y is held as m whatever is saved: its name costs that layer
 # nothing more.
 REMAT_RUNGS = ((("sscan_y", "sscan_states"), 15.6), (("mlp_up",), 19.8))
@@ -445,27 +447,25 @@ def carried_bytes(cfg: Phi4FlashConfig, tokens: int, itemsize: int) -> int:
 def remat_plan(cfg: Phi4FlashConfig, shape: remat.StepShape, limit) -> remat.RematPlan:
     """What the blocks of a step of this shape save across remat, under a
     chip's `limit` of bytes: a pure function of its arguments. A name's
-    bytes are its layers' mean over all layers, since the rule counts a
-    layer's bytes n_layer times. m and K, V are booked with the layers'
+    bytes are one layer's, and `made_in` says which layers make it. m and K, V are booked with the layers'
     inputs, in `Held.always`."""
     d, itemsize = cfg.n_embd, jnp.dtype(cfg.dtype).itemsize
     tokens = shape.rows * shape.seq_len
     kinds = cfg.layer_types
-    attn, mamba = sum(k in (WINDOW, FULL, CROSS) for k in kinds), kinds.count(MAMBA)
-    share = lambda nbytes, count: nbytes * count // cfg.n_layer
     # the calls' heads are n_head of twice the model's width
-    name_bytes = {name: share(nbytes, attn) for name, nbytes in remat.attention_bytes(
-        shape, cfg.n_head, 2 * cfg.head_dim, itemsize).items()}
+    name_bytes = remat.attention_bytes(shape, cfg.n_head, 2 * cfg.head_dim, itemsize)
+    made_in = dict.fromkeys(name_bytes, remat.layers_of(kinds, WINDOW, FULL, CROSS))
+    made_in.update(dict.fromkeys(("sscan_y", "sscan_states"), remat.layers_of(kinds, MAMBA)))
     chunks = -(-shape.seq_len // chunk_of(shape.seq_len))
     name_bytes.update(
-        sscan_y=share(tokens * cfg.ssm_inner * itemsize, mamba),
-        sscan_states=share(shape.rows * chunks * cfg.ssm_inner * cfg.ssm_state * 4, mamba),
+        sscan_y=tokens * cfg.ssm_inner * itemsize,
+        sscan_states=shape.rows * chunks * cfg.ssm_inner * cfg.ssm_state * 4,
         mlp_up=2 * tokens * cfg.intermediate * itemsize // shape.tp)
     held = remat.held_bytes(shape, params=cfg.params(), width=d, vocab=cfg.vocab_size,
                             n_layer=cfg.n_layer, itemsize=itemsize,
                             block=_block_bytes(cfg, itemsize) * tokens)
     held = held._replace(always=held.always + carried_bytes(cfg, tokens, itemsize))
-    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit)
+    return remat.plan(REMAT_RUNGS, name_bytes, cfg.n_layer, held, limit, made_in=made_in)
 
 
 def _block_bytes(cfg: Phi4FlashConfig, itemsize: int) -> int:
@@ -487,7 +487,7 @@ class Phi4FlashGroup(nn.Module):
     group (the blocks' structures differ, and m and K, V cross them)."""
 
     config: Phi4FlashConfig
-    keep: Any  # the blocks' checkpoint policy
+    keep: Any  # the blocks' checkpoint policies, one a layer
     stream: Any = None
 
     @nn.compact
@@ -498,7 +498,7 @@ class Phi4FlashGroup(nn.Module):
         memory = jnp.zeros((b, t, 0), cfg.dtype)
         kv = (jnp.zeros((b, t, 0), cfg.dtype),) * 2
         for i, (kind, index) in enumerate(zip(cfg.layer_types, cfg.layers_kept)):
-            x, memory, kv = nn.remat(Phi4FlashBlock, policy=self.keep)(
+            x, memory, kv = nn.remat(Phi4FlashBlock, policy=self.keep[i])(
                 cfg, kind, index, self.stream, name=f"h_{i}")(x, memory, kv)
         return x
 

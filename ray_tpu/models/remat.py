@@ -14,12 +14,16 @@ backward reads, the dearest thing in such a layer to compute twice; and
 second run. Further rungs by a rule: a family states, beside its blocks, its
 other names and what each is worth (`REMAT_RUNGS`: rungs of names that are
 only worth saving together, each with the milliseconds of a step it spared
-for a GiB held), and `plan` takes the rungs that spare most among those
-whose reckoned total stays under the chips' `bytes_limit` less a margin. It
-is a reckoning from shapes, as ops/attention.py's `flash_tiles` is, made at
-trace time in the model's `__call__`, where the batch's shape is static
-(`block_policy`); `traced` hands the plan to whoever books it (TrainStep at
-a compile). No option selects it and none turns it off.
+for a GiB held), and `plan` gives each rung a depth: in how many of the
+layers that make its names they are saved, the last of them first, since
+their backward runs first and lets go of them before most gradients exist.
+Under the chips' `bytes_limit` less a margin it takes the depths that spare
+most by the rungs' stated worths (ms a GiB x the GiB held at that depth): a
+rung too large for every layer is saved in some. It is a reckoning from
+shapes, as ops/attention.py's `flash_tiles` is, made at trace time in the
+model's `__call__`, where the batch's shape is static (`block_policy`, a
+policy a layer); `traced` hands the plan to whoever books it (TrainStep at a
+compile). No option selects it and none turns it off.
 
 The constants are calibrated against what a v5e's allocator read of the
 benchmark's cells' steps with each set of names saved (PERF.md section 6,
@@ -30,8 +34,9 @@ step compiled for a described v5e (tests/test_tpu_compile.py).
 
 from __future__ import annotations
 
+import bisect
 import itertools
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 
@@ -62,20 +67,36 @@ class StepShape(NamedTuple):
 
 
 class RematPlan(NamedTuple):
-    """`names` saved across remat; the bytes they hold a layer and over all
-    layers on one chip; the step's reckoned total with them; the limit that
-    total was held to (None: no chip said one, first rung alone); of the
-    saved bytes those of `attn_sel`, a layer's selection of keys (0 where no
-    layer selects); and what the family stated one block's backward works
-    in (`Held.block`; 0 where it states none)."""
+    """`names` saved across remat in any layer; the bytes saved in each layer
+    and over all layers on one chip; the step's reckoned total with them; the
+    limit that total was held to (None: no chip said one, first rung alone);
+    of the saved bytes those of `attn_sel`, a layer's selection of keys (0
+    where no layer selects); what the family stated one block's backward
+    works in (`Held.block`; 0 where it states none); each rung's depth,
+    (its names, the layers they are saved in, the layers that make them), in
+    the order the family states its rungs; and the names each layer's policy
+    saves: all of `names` but a rung's that the layer makes and is not to
+    save (a name a layer does not make is an identity there, so layers that
+    save all they make share one policy)."""
 
     names: Tuple[str, ...]
-    layer_bytes: int
+    layer_bytes: Tuple[int, ...]
     saved_bytes: int
     reckoned_bytes: int
     limit_bytes: Optional[int]
     sel_bytes: int = 0
     block_bytes: int = 0
+    depths: Tuple[Tuple[Tuple[str, ...], int, int], ...] = ()
+    by_layer: Tuple[Tuple[str, ...], ...] = ()
+
+    def saved_in(self, *names: str) -> Tuple[bool, ...]:
+        """Of each layer, whether its policy saves any of `names`."""
+        return tuple(any(n in saved for n in names) for saved in self.by_layer)
+
+    def depth(self, name: str) -> int:
+        """In how many layers `name` is saved: its rung's depth (0 where no
+        rung has it)."""
+        return next((k for rung, k, _ in self.depths if name in rung), 0)
 
 
 def step_shape(batch_shape, axis_sizes) -> StepShape:
@@ -158,47 +179,110 @@ def attention_bytes(shape: StepShape, n_head: int, head_dim: int, itemsize: int)
             "attn_lse": tokens * n_head * 4 // shape.tp}
 
 
+def layers_of(kinds, *of) -> Tuple[int, ...]:
+    """The layers of a model whose layers are of these `kinds` that are one of
+    `of`: what a hybrid family's `made_in` says of a name."""
+    return tuple(i for i, kind in enumerate(kinds) if kind in of)
+
+
 def plan(rungs, name_bytes: Dict[str, int], n_layer: int, held: Held,
-         limit: Optional[int], first_rung: Tuple[str, ...] = FIRST_RUNG) -> RematPlan:
+         limit: Optional[int], first_rung: Tuple[str, ...] = FIRST_RUNG,
+         made_in: Optional[Dict[str, Sequence[int]]] = None) -> RematPlan:
     """The first rung (`first_rung`: a family whose attention selects its keys
-    holds the selection there too, `attn_sel`), and of `rungs` (each `(names, ms a step spared for a
-    GiB held)`) the set that spares most among those whose reckoned total
-    (`Held.total`) stays under `_LIMIT_SHARE` of `limit`; with no limit the
-    first rung alone. The first rung is taken whatever the limit: it is one
-    more copy of the stream a layer, whatever the shape."""
+    holds the selection there too, `attn_sel`) in every layer, and each of
+    `rungs` (`(names, ms a step spared for a GiB held)`) at a depth: saved in
+    the last k of the layers that make its names, 0 <= k <= all of them.
+    `name_bytes` are a name's bytes in one layer that makes it, `made_in` the
+    layers that make a name (every one of `n_layer` where it says none). Held
+    to a reckoned total (`Held.total` of the bytes saved) under `_LIMIT_SHARE`
+    of `limit`: of the depths that stay under it those that spare most
+    (`_depths`); with no limit the first rung alone. The first rung
+    is taken whatever the limit: it is one more copy of the stream a layer,
+    whatever the shape."""
+    makers = lambda name: (made_in or {}).get(name, range(n_layer))
     room = None if limit is None else int(limit * _LIMIT_SHARE)
-    first = sum(name_bytes[n] for n in first_rung)
-    rung_bytes = [sum(name_bytes[n] for n in names) for names, _ in rungs]
+    first = sum(name_bytes[n] * len(makers(n)) for n in first_rung)
+    # a rung's layers, the last first, and the bytes it holds at each depth
+    rung_layers = [sorted({i for n in names for i in makers(n)}, reverse=True)
+                   for names, _ in rungs]
+    at_depth = [[0, *itertools.accumulate(
+        sum(name_bytes[n] for n in names if i in makers(n)) for i in layers)]
+        for (names, _), layers in zip(rungs, rung_layers)]
+    depths = _depths([rate for _, rate in rungs], at_depth,
+                     lambda saved: room is not None and held.total(first + saved) <= room)
+    names = first_rung + tuple(n for (names, _), k in zip(rungs, depths) if k for n in names)
+    # a layer's policy leaves out a rung that it makes and is not to save
+    left_out = [set(layers[k:]) for layers, k in zip(rung_layers, depths)]
+    by_layer = tuple(
+        tuple(n for n in names if not any(
+            n in rung and i in out for (rung, _), out in zip(rungs, left_out)))
+        for i in range(n_layer))
+    layer_bytes = tuple(sum(name_bytes[n] for n in saved if i in makers(n))
+                        for i, saved in enumerate(by_layer))
+    sel = name_bytes["attn_sel"] * len(makers("attn_sel")) if "attn_sel" in names else 0
+    return RematPlan(names, layer_bytes, sum(layer_bytes), held.total(sum(layer_bytes)), room,
+                     sel, held.block,
+                     tuple((rung, k, len(layers))
+                           for (rung, _), k, layers in zip(rungs, depths, rung_layers)),
+                     by_layer)
 
-    def layer_bytes(chosen):
-        return first + sum(rung_bytes[i] for i in chosen)
 
-    fitting = [()] + [
-        chosen for k in range(1, len(rungs) + 1)
-        for chosen in itertools.combinations(range(len(rungs)), k)
-        if room is not None and held.total(n_layer * layer_bytes(chosen)) <= room]
-    taken = max(fitting, key=lambda chosen: sum(rungs[i][1] * rung_bytes[i] for i in chosen))
-    names = first_rung + tuple(n for i in taken for n in rungs[i][0])
-    layer = layer_bytes(taken)
-    return RematPlan(names, layer, n_layer * layer, held.total(n_layer * layer), room,
-                     n_layer * name_bytes.get("attn_sel", 0) if "attn_sel" in names else 0,
-                     held.block)
+def _depths(rates, at_depth, fits) -> Tuple[int, ...]:
+    """A depth for each rung, `at_depth[r][k]` the bytes rung r holds at depth
+    k, `rates[r]` what a byte of it spares and `fits(bytes)` whether so many
+    have room (it holds of every fewer if of any): of all the depths that
+    fit, those that spare most; among equals the deeper in the rung that
+    spares more a byte. One search over every rung's every depth, whole rungs
+    among them, so what a plan spares never rises as the room falls and never
+    falls below what whole rungs alone would spare there. The rungs in the
+    order of what a byte spares, each from its deepest depth down; a branch
+    is left where the room still free, filled with the rungs still to come
+    in that order and cut anywhere, would not pass the best found."""
+    order = sorted(range(len(rates)), key=lambda r: -rates[r])
+    # the most bytes that fit (-1: none do, and nothing is saved beyond the first rung)
+    room = bisect.bisect_left(range(sum(by_depth[-1] for by_depth in at_depth) + 1), True,
+                              key=lambda saved: not fits(saved)) - 1
+    best = [0.0, (0,) * len(rates)]
+
+    def at_most(at, free):
+        spared = 0.0
+        for r in order[at:]:
+            spared += rates[r] * min(free, at_depth[r][-1])
+            free -= min(free, at_depth[r][-1])
+        return spared
+
+    def search(at, free, spared, depths):
+        if spared > best[0]:
+            best[:] = spared, depths
+        if at == len(order) or spared + at_most(at, free) <= best[0]:
+            return
+        r = order[at]
+        for k in reversed(range(len(at_depth[r]))):
+            if at_depth[r][k] <= free:
+                search(at + 1, free - at_depth[r][k], spared + rates[r] * at_depth[r][k],
+                       depths[:r] + (k,) + depths[r + 1:])
+
+    search(0, room, 0.0, best[1])
+    return best[1]
 
 
 _traced = None  # (the configuration, its RematPlan) of the newest trace
 
 
 def block_policy(family_plan, cfg, batch_shape, stream):
-    """The checkpoint policy of `nn.remat` round the blocks of a model of
-    `cfg` on a (B, T) batch: it saves the names of the family's plan
-    (`family_plan(cfg, StepShape, limit)`) for the batch's part on one chip
-    of the stream's mesh, under those chips' limit. Called where the model
-    is traced; `traced` hands the plan on."""
+    """The checkpoint policies of `nn.remat` round the blocks of a model of
+    `cfg` on a (B, T) batch, one a layer: layer i's saves the names the
+    family's plan (`family_plan(cfg, StepShape, limit)`) saves in layer i
+    (`RematPlan.by_layer`), for the batch's part on one chip of the stream's
+    mesh, under those chips' limit. Called where the model is traced;
+    `traced` hands the plan on."""
     global _traced
     sizes = {} if stream is None else stream.mesh.shape
     chosen = family_plan(cfg, step_shape(batch_shape, sizes), chip_limit(stream))
     _traced = (cfg, chosen)
-    return jax.checkpoint_policies.save_only_these_names(*chosen.names)
+    policies = {names: jax.checkpoint_policies.save_only_these_names(*names)
+                for names in set(chosen.by_layer)}
+    return tuple(policies[names] for names in chosen.by_layer)
 
 
 def traced(cfg) -> Optional[RematPlan]:

@@ -232,9 +232,10 @@ class SDAR(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="tok_emb",
                      embedding_init=nn.initializers.normal(1.0))(stream)
         keep = remat.block_policy(remat_plan, cfg, idx.shape, self.stream)
-        products = any(n in moe.KEPT_PRODUCTS for n in remat.traced(cfg).names)
+        products = remat.traced(cfg).saved_in(*moe.KEPT_PRODUCTS)
         for i in range(cfg.n_layer):
-            x = nn.remat(SDARBlock, policy=keep)(cfg, self.stream, products, name=f"h_{i}")(x)
+            x = nn.remat(SDARBlock, policy=keep[i])(
+                cfg, self.stream, products[i], name=f"h_{i}")(x)
         if self.is_initializing():  # the mask token's row follows the routers just drawn
             routers = [self.get_variable("params", f"h_{i}")["moe"]["router"]["kernel"]
                        for i in range(cfg.n_layer)]

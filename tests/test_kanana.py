@@ -327,12 +327,15 @@ def test_remat_plan_of_the_cell():
     tokens = 2 * 8192
     assert chosen.block_bytes == tokens * (2 * 32 * (2 * 128 + 64 + 2 * 128) * 2
                                            + 6 * 4 * 2048 * 2) == tokens * 172_032
-    # the choices and the plan: five int32 and a bool an assignment, four
-    # routed layers of five
+    # the latent pair's output and logsumexp in every layer; the choices and
+    # the plan, five int32 and a bool an assignment, in the four routed layers
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(kanana, "REMAT_RUNGS", ())
+        pair = tokens * 32 * 128 * 2 + tokens * 32 * 4
         assert kanana.remat_plan(cfg, shape, 15 * GIB).layer_bytes == (
-            tokens * 32 * 128 * 2 + tokens * 32 * 4 + tokens * 6 * 21 * 4 // 5)
+            (pair,) + (pair + tokens * 6 * 21,) * 4)
+    # every rung whole: a depth is out of the layers that make the rung's names
+    assert [(k, of) for _, k, of in chosen.depths] == [(5, 5), (4, 4), (1, 1)]
     assert kanana.remat_plan(cfg, shape, None).names == first
     assert kanana.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
 

@@ -189,7 +189,8 @@ def test_remat_plan_of_the_cell_keeps_the_selection():
     assert plan.names == ("attn_out", "attn_lse", "moe_plan", "attn_sel", "attn_q", "attn_k",
                           "attn_v", "moe_gate", "moe_up", "moe_out")
     assert plan.sel_bytes == 4 * 16384 * 512 * 4  # the transposed relation's mask, 4 layers
-    assert plan.saved_bytes == 4 * plan.layer_bytes and plan.reckoned_bytes < plan.limit_bytes
+    assert plan.saved_bytes == 4 * plan.layer_bytes[0] == sum(plan.layer_bytes)
+    assert plan.reckoned_bytes < plan.limit_bytes and all(k == of == 4 for _, k, of in plan.depths)
     # with no limit the first rung alone, the selection in it
     assert mellum.remat_plan(cfg, remat.StepShape(1, 16384), None).names == \
         ("attn_out", "attn_lse", "moe_plan", "attn_sel")
@@ -199,7 +200,7 @@ def test_remat_plan_of_the_cell_keeps_the_selection():
     old = mellum.remat_plan(MellumConfig(num_held=16, vocab_size=24576),
                             remat.StepShape(2, 8192), 15 * GIB)
     assert old.names == remat.FIRST_RUNG + ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate",
-                                            "moe_up") and old.sel_bytes == 0
+                                            "moe_out") and old.sel_bytes == 0
 
 
 def test_the_cell_s_step_selects_once_a_layer(monkeypatch):

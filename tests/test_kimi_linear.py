@@ -378,14 +378,17 @@ def _float32_under(jaxpr, scope, size):
 # rule), as tests/test_mellum.py:_step_text gives it, taken on PR 55's own tree
 # (the l2 norms of q and k inside kda_fwd and kda_bwd): the program the chip
 # runs of PERF.md section 6 were made with; PR 60's since (the head norm and
-# its gate a kernel pair on o as kda_fwd wrote it).
-KIMI_LINEAR_STEP = "43a646488e4acd2fed6d5331e03b0c620f3a8ff1025a45ce67d945ac0760d44b"
+# its gate a kernel pair on o as kda_fwd wrote it); PR 62's since, by design: the remat rule
+# takes a rung by depth (models/remat.py), and the last three KDA layers of four save the
+# delta rule's outputs, which no layer saved before: kda_fwd is called five times, not eight.
+KIMI_LINEAR_STEP = "7c32f5545eb2b75645e503ba4077b4f0e4bc0763e2d9cb28a0cf62bbd7bcd947"
 
 
 def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monkeypatch):
     """The cell's own step lowered for a TPU on this box: four KDA layers,
     each with kda_bwd once and kda_fwd as often as the remat plan runs it
-    (once where it holds `kda_out` and `kda_states`, twice where not), the
+    (once where a layer holds `kda_out` and `kda_states`, the last three of
+    the four at this shape under a v5e's limit, twice where not, the first), the
     head norm's pair after it (forward twice: no plan holds its output), the
     convolution pair a KDA layer, the latent pair once in the one latent
     layer, megablox's calls in four routed layers. Between the convolution
@@ -404,8 +407,8 @@ def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monke
     calls = kernel_tally(text)
     assert calls.pop("kernel") and "@gmm" in text and "@tgmm" in text
     plan = remat.traced(cfg)
-    runs = 1 if "kda_states" in plan.names else 2
-    assert calls == {"kda_fwd": 4 * runs, "kda_bwd": 4, "kda_norm_fwd": 4 * 2, "kda_norm_bwd": 4,
+    assert plan.depth("kda_states") == 3
+    assert calls == {"kda_fwd": 4 + (4 - 3), "kda_bwd": 4, "kda_norm_fwd": 4 * 2, "kda_norm_bwd": 4,
                      "causal_conv_fwd": 4 * 2,
                      "causal_conv_bwd": 4, "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1,
                      "moe_token_sum": 4 * 2 * 2}, calls
@@ -425,22 +428,34 @@ def test_remat_plan_of_the_cell():
     shape = remat.StepShape(2, 8192)
     chosen = kimi_linear.remat_plan(cfg, shape, 15 * GIB)
     first = remat.FIRST_RUNG + ("moe_plan",)  # the routed layers' choices and plans with it
-    # beside 8.98 GiB of state the delta rule's outputs (2.5 GiB over four layers) have no room
-    assert chosen.names == first and not set(chosen.names) & set(KEPT_PRODUCTS)
+    # beside 8.98 GiB of state the delta rule's outputs (2.5 GiB over four layers) have room in
+    # the last three KDA layers (in none until PR 62, when a rung was every layer's or none's)
+    assert chosen.names == first + ("kda_out", "kda_states")
+    assert not set(chosen.names) & set(KEPT_PRODUCTS)
+    assert chosen.depths == ((("kda_out", "kda_states"), 3, 4),)
+    assert cfg.layer_types == ("kda", "kda", "kda", "mla", "kda")
+    assert chosen.saved_in("kda_states") == (False, True, True, True, True)  # the fourth makes none
     assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
-    # the chip's allocator read 12.044 GiB of this step (my chip run, PR 60, call 2; 12.436 before)
-    assert chosen.reckoned_bytes / GIB == pytest.approx(12.18, abs=0.01)
+    # with the first rung alone 12.18, of which the chip's allocator read 12.044 GiB (my chip
+    # run, PR 60, call 2; 12.436 before)
+    assert chosen.reckoned_bytes / GIB == pytest.approx(12.94, abs=0.01)
+    assert kimi_linear.remat_plan(cfg, shape, None).reckoned_bytes / GIB == pytest.approx(
+        12.18, abs=0.01)
     tokens = 2 * 8192
     # eight bf16 arrays 4,096 wide with their gradients and the chunk states; the two float32
     # ones of the head norm and its gate went with PR 60 (196,608 before)
     assert chosen.block_bytes == tokens * (2 * 8 * 2 * 4096 + 4 * 4096 * 128 // 64) \
         == tokens * 163_840
-    # the first rung: the latent layer's output and logsumexp, one layer of five, and
-    # the choices and the plan: five int32 and a bool an assignment, four routed layers of five
-    assert chosen.layer_bytes == (tokens * 32 * 128 * 2 // 5 + tokens * 32 * 4 // 5
-                                  + tokens * 8 * 21 * 4 // 5)
+    # the first rung: the latent layer's output and logsumexp, and in the four routed layers
+    # the choices and the plan: five int32 and a bool an assignment; the rung: a KDA layer's
+    # output and its chunk states (a float32 (128, 4096) a chunk of 64)
+    routed = tokens * 8 * 21
+    kda_layer = tokens * 4096 * 2 + 2 * 128 * 4096 * 128 * 4
+    assert chosen.layer_bytes == (0, routed + kda_layer, routed + kda_layer,
+                                  routed + tokens * 32 * 128 * 2 + tokens * 32 * 4,
+                                  routed + kda_layer)
     roomy = kimi_linear.remat_plan(cfg, remat.StepShape(1, 4096), 15 * GIB)
-    assert roomy.names == first + ("kda_out", "kda_states")  # where a shape has the room
+    assert roomy.names == chosen.names and roomy.depth("kda_out") == 4  # where a shape has the room
     assert kimi_linear.remat_plan(cfg, shape, None).names == first
     assert kimi_linear.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
 

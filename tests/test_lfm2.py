@@ -348,17 +348,23 @@ def test_remat_plan_of_the_cell():
     # since PR 44 the gradient's rows are gathered in the stream's dtype
     assert chosen.block_bytes == tokens * (2 * 4 * 2048 * 2 + 4 * 2 * (6 * 2048 + 6 * 1792)) \
         == tokens * 217_088
-    # a layer's mean: four conv layers' streams and outputs, one dense MLP's
-    # gate and up, one attention layer's five arrays; four routed layers'
-    # choices and plans (five int32 and a bool an assignment) and their
-    # products on the buffer with headroom (1.5 x a quarter of the 4
-    # assignments a token: 24,576 rows)
+    # a conv layer's three streams and output, the attention layer's five
+    # arrays; the dense layer's MLP's gate and up, a routed layer's choices
+    # and plans (five int32 and a bool an assignment) and its products on the
+    # buffer with headroom (1.5 x a quarter of the 4 assignments a token:
+    # 24,576 rows)
     rows = 24576
     assert rows == moe.buffer_rows(tokens, 4, 8, 32)
-    assert chosen.layer_bytes == (4 * tokens * 4 * 2048 * 2 // 5 + 2 * tokens * 7168 * 2 // 5
-                                  + 4 * (tokens * 2048 * 2 // 5) + tokens * 32 * 4 // 5
-                                  + 4 * tokens * 4 * 21 // 5
-                                  + 2 * (4 * rows * 1792 * 2 // 5) + 4 * rows * 2048 * 2 // 5)
+    conv = 4 * tokens * 2048 * 2
+    attn = 4 * tokens * 2048 * 2 + tokens * 32 * 4
+    routed = tokens * 4 * 21 + 2 * rows * 1792 * 2 + rows * 2048 * 2
+    kinds = cfg.layer_types
+    assert kinds.count("conv") == 4 and kinds[0] == "conv"
+    assert chosen.layer_bytes == tuple(
+        (conv if kind == "conv" else attn) + (routed if i else 2 * tokens * 7168 * 2)
+        for i, kind in enumerate(kinds))
+    # every rung whole: a depth is out of the layers that make the rung's names
+    assert [(k, of) for _, k, of in chosen.depths] == [(4, 4), (1, 1), (1, 1), (4, 4), (4, 4), (4, 4)]
     assert lfm2.remat_plan(cfg, shape, None).names == first
     assert lfm2.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
 

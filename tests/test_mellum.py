@@ -24,7 +24,7 @@ from ray_tpu.models import mellum, remat
 from ray_tpu.models.loss import loss_fn
 from ray_tpu.models.mellum import Mellum, MellumConfig, YarnScaling, yarn_inv_freq
 from ray_tpu.ops import attention
-from ray_tpu.ops.moe import ExpertShare
+from ray_tpu.ops.moe import KEPT_PRODUCTS, ExpertShare
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
@@ -235,13 +235,20 @@ def test_sowing_leaves_the_step_program_as_it_was(monkeypatch):
 # of 128, turned, not normed) go through ops/qk_prep.py's pair into the flash
 # calls on rows: the last number, the layers that take the pair; the two dense
 # cells (gpt2's own attention; mistral's `attn_fn` under its mesh) take it in none.
+# PR 62 moved mistral's and the routed cell's by design: the remat rule takes
+# a rung by depth (models/remat.py), and on a v5e mistral's last seven blocks
+# of eight save the flash calls' operands and the last six `mlp_up`, where
+# all eight saved `mlp_up` and none the operands; the routed cell's last three
+# of four save the down product and the last two the gate's, where all four
+# saved the gate's and the up's; gpt2_small's plan takes both its rungs
+# whole: its step stayed as it was, text for text.
 PINNED_STEPS = {
     "gpt2_small": ("b747484d7c5664494e19fcc6d7ed0bf5bfb55b05683d899de6fd65410e14e8ac", 32, 1024, 0, 12,
                    ("attn_q", "attn_k", "attn_v", "mlp_up"), 0),
-    "mistral_7b_l8": ("8c8546e49b310caa025c93dccdea5374e541ea0a078d453c8a73679f2b3f8515", 1, 8192, 0, 8,
-                      ("mlp_up",), 0),
-    "mellum2_12b_l4_ep4": ("fb573ffce3fe857290ec62b1fe37857d2e5596d3ebabf476a068cc60a411783f", 2, 8192, 3, 4,
-                           ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate", "moe_up"), 4),
+    "mistral_7b_l8": ("3ddd2599f56564cf7dae0a92780c17a26bbb4ed8e9b2f5bb87813491109d0611", 1, 8192, 0, 8,
+                      ("mlp_up", "attn_q", "attn_k", "attn_v"), 0),
+    "mellum2_12b_l4_ep4": ("7997c3c52f0dc9e02641d8b96723a1e061cdcbac7d269ca835c140ab9a24b5a9", 2, 8192, 3, 4,
+                           ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate", "moe_out"), 4),
 }
 
 
@@ -335,17 +342,28 @@ def lowered_cell(name, batch, monkeypatch, limit=15 * remat.GIB):
     return text, remat.traced(cfg)
 
 
-@pytest.mark.parametrize("limit_gib,kept,again", [(15, ("moe_gate", "moe_up"), 1), (14, (), 3)])
+@pytest.mark.parametrize("limit_gib,kept,again", [
+    (15, {"moe_gate": 2, "moe_up": 0, "moe_out": 3}, [3, 2, 1, 1]),
+    (14.5, {"moe_gate": 2, "moe_up": 0, "moe_out": 1}, [3, 3, 2, 1]),
+    (16, {"moe_gate": 4, "moe_up": 4, "moe_out": 4}, [0, 0, 0, 0]),
+    (14, {"moe_gate": 0, "moe_up": 0, "moe_out": 0}, [3, 3, 3, 3])])
 def test_a_kept_product_s_forward_matmul_runs_once_a_layer(limit_gib, kept, again, monkeypatch):
-    """The cell's step under the v5e's limit keeps the gate and the up
-    product (the rule has no room for the down product's 0.84 GiB) and under
-    remat runs the down matmul again and no other; under a limit with room
-    for no further rung all three run again, as before PR 45. The route's
-    plan is in the first rung either way."""
-    text, plan = lowered_cell("mellum2_12b_l4_ep4", (2, 8192), monkeypatch, limit_gib * remat.GIB)
+    """The cell's step under the v5e's limit keeps the down product (5.8 ms
+    a GiB) in the last three layers of four and the gate's (4.1) in the last
+    two (the rule takes a rung by depth, models/remat.py), and a layer runs
+    again under remat what it does not keep: three matmuls in the first
+    layer, two in the second, one in the last two; with half a GiB less the
+    down product in the last layer alone; with room for every
+    product no layer runs any again; under a limit with room for
+    no further rung all three run again in every layer, as before PR 45. The
+    route's plan is in the first rung either way."""
+    text, plan = lowered_cell("mellum2_12b_l4_ep4", (2, 8192), monkeypatch,
+                              int(limit_gib * remat.GIB))
     assert plan.names[:3] == remat.FIRST_RUNG + ("moe_plan",)
-    assert tuple(n for n in plan.names if n.startswith("moe_"))[1:] == kept
-    assert expert_calls(text, 4) == {"gmm": 15 + again, "tgmm": 6, "moe_token_sum": 4}
+    assert {name: plan.depth(name) for name in KEPT_PRODUCTS} == kept
+    assert [sum(name not in names for name in KEPT_PRODUCTS) for names in plan.by_layer] == again
+    calls = expert_calls(text, 1)
+    assert calls == {"gmm": 4 * 15 + sum(again), "tgmm": 4 * 6, "moe_token_sum": 4 * 4}
 
 
 def test_step_reports_its_expert_load_through_the_telemetry():

@@ -421,10 +421,13 @@ def test_remat_plan_of_the_cell():
     assert layers.mixer_bytes(cfg, 2) == 2 * 10_304 + 6 * (6144 + 2 * 4096) == 106_624
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(nemotron_h, "REMAT_RUNGS", ())
-        # one attention layer's output and logsumexp, four expert layers'
-        # choices and plans (five int32 and a bool an assignment), of nine
+        # the one attention layer's output and logsumexp, the four expert layers'
+        # choices and plans (five int32 and a bool an assignment); a Mamba layer's nothing
+        routed, pair = tokens * 6 * 21, tokens * 32 * 128 * 2 + tokens * 32 * 4
         assert nemotron_h.remat_plan(cfg, shape, 15 * GIB).layer_bytes == (
-            (tokens * 32 * 128 * 2) // 9 + (tokens * 32 * 4) // 9 + tokens * 6 * 21 * 4 // 9)
+            0, routed, 0, routed, 0, pair, routed, 0, routed)  # MEMEM*EME
+    # every rung whole: a depth is out of the layers that make the rung's names
+    assert [(k, of) for _, k, of in chosen.depths] == [(4, 4), (1, 1), (4, 4)]
     assert nemotron_h.remat_plan(cfg, shape, None).names == first
     assert nemotron_h.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
 

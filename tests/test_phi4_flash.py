@@ -9,6 +9,7 @@ through the telemetry."""
 import hashlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -214,8 +215,10 @@ def _cell_step(monkeypatch):
 
 # This family's own cell (B=1 x T=16384, one chip, a v5e's limit for the remat
 # rule), as tests/test_mellum.py:_step_text gives it, taken on PR 57's own
-# tree: the program the chip runs of PERF.md section 6 were made with.
-PHI4_FLASH_STEP = "df3c2272575865f5323fced0be47efc2be3d09effeb3c04158009686aa8a4878"
+# tree: the program the chip runs of PERF.md section 6 were made with; PR 62's
+# since, by design: the remat rule takes a rung by depth (models/remat.py), and
+# the last three layers of five save their MLP's product, which no layer saved before.
+PHI4_FLASH_STEP = "dd9b0175c8d1952f04fb8de424a1edd6c1a8e0cf7544b478ea33b43eadd6f3af"
 
 
 def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monkeypatch):
@@ -224,7 +227,9 @@ def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monke
     (once where it holds `sscan_y` and `sscan_states`), the convolution's
     pair beside it; the window layer the windowed flash pair once, the full
     and the cross layer the causal pair once each: three flash layers, every
-    forward once (the first rung holds their outputs)."""
+    forward once (the first rung holds their outputs). The MLPs' `gate_up`
+    matmul, (1, 16384, 20480) out, runs once in the three layers that save its
+    product and twice in the two that do not: 10 - 3 times."""
     from tests.test_mellum import _traced_text
 
     cfg, traced = _cell_step(monkeypatch)
@@ -236,6 +241,8 @@ def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monke
                      "causal_conv_bwd": 1, "flash_fwd": 2,
                      "flash_bwd_fused" + attention.LEGACY_NAMES: 2,
                      "flash_win512_fwd": 1, "flash_win512_bwd_fused": 1}, calls
+    assert remat.traced(cfg).depth("mlp_up") == 3
+    assert len(re.findall(r"dot_general.*-> tensor<1x16384x20480xbf16>", text)) == 10 - 3
     assert hashlib.sha256(text.encode()).hexdigest() == PHI4_FLASH_STEP
 
 
@@ -247,19 +254,25 @@ def test_remat_plan_of_the_cell():
     shape = remat.StepShape(1, 16384)
     chosen = phi4_flash.remat_plan(cfg, shape, 15 * GIB)
     # beside 8.60 GiB of state the scan's output and states (0.2 GiB) have
-    # room, the MLPs' products (3.1 GiB over five layers) have none
-    assert chosen.names == remat.FIRST_RUNG + ("sscan_y", "sscan_states")
+    # room, and of the MLPs' products (3.1 GiB over five layers) the last
+    # three layers' (none until PR 62, when a rung was every layer's or none's)
+    assert chosen.names == remat.FIRST_RUNG + ("sscan_y", "sscan_states", "mlp_up")
+    assert chosen.depths == ((("sscan_y", "sscan_states"), 1, 1), (("mlp_up",), 3, 5))
+    assert chosen.saved_in("mlp_up") == (False, False, True, True, True)
     assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
-    assert chosen.reckoned_bytes / GIB == pytest.approx(12.66, abs=0.01)
+    assert chosen.reckoned_bytes / GIB == pytest.approx(13.30, abs=0.01)
     tokens = 16384
     assert phi4_flash.carried_bytes(cfg, tokens, 2) == tokens * 2 * (5120 + 2 * 1280) \
         == 251_658_240  # the issue's 252 MB
-    # three attention layers' outputs (40 heads of 128) and logsumexps, one
-    # Mamba layer's y and chunk states, of five layers
-    assert chosen.layer_bytes == (tokens * 5120 * 2 * 3 // 5 + tokens * 40 * 4 * 3 // 5
-                                  + tokens * 5120 * 2 // 5 + 128 * 5120 * 16 * 4 // 5)
+    # an attention layer's output (40 heads of 128) and logsumexp, the Mamba
+    # layer's y and chunk states, the gated memory unit's nothing; the last
+    # three layers' MLP's product (gate and up, 8,192 wide each)
+    attn, product = tokens * 5120 * 2 + tokens * 40 * 4, 2 * tokens * 10240 * 2
+    assert cfg.layer_types == ("window", "mamba", "full", "gmu", "cross")
+    assert chosen.layer_bytes == (attn, tokens * 5120 * 2 + 128 * 5120 * 16 * 4, attn + product,
+                                  product, attn + product)
     roomy = phi4_flash.remat_plan(cfg, remat.StepShape(1, 4096), 15 * GIB)
-    assert roomy.names == chosen.names + ("mlp_up",)  # where a shape has the room
+    assert roomy.names == chosen.names and roomy.depth("mlp_up") == 5  # where a shape has the room
     assert phi4_flash.remat_plan(cfg, shape, None).names == remat.FIRST_RUNG
     self_decoder = Phi4FlashConfig.tiny(layers_kept=(14, 15))
     assert phi4_flash.carried_bytes(self_decoder, tokens, 2) == 0

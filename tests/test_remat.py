@@ -1,13 +1,16 @@
-"""models/remat.py: which residuals a block's remat saves, as a pure function
-of the step's shapes and the chips' bytes_limit. Over nine of the benchmark's
-cells and over a limit swept downward: the names are the first rung and
-rungs of the family's own, never fewer than the first rung, never worth
-more as the limit falls; what the rule reckons is held to what the chip's
-allocator read; a shape it has never seen gets fewer names, not a total
-over the limit; and every process of a mesh reckons with the same limit.
+"""models/remat.py: which residuals a block's remat saves and in how many
+layers, as a pure function of the step's shapes and the chips' bytes_limit.
+Over the benchmark's cells and over a limit swept downward: the names are the
+first rung and rungs of the family's own, each at a depth (the last k of the
+layers that make its names), never fewer than the first rung, never worth
+more as the limit falls and never less than the whole rungs that fit there;
+what the rule reckons is held to what the chip's allocator read; a shape it
+has never seen gets fewer names, not a total over the limit; and every
+process of a mesh reckons with the same limit.
 """
 
 import importlib
+import itertools
 import json
 import os
 import types
@@ -32,29 +35,51 @@ UP_OUT = ("moe_up", "moe_out")  # ops/moe.py:RELU2.products
 CONV, LATENT, SHARED = ("conv_bcu", "conv_y"), ATTN + ("attn_q_shared", "attn_k_shared"), ("shared_up",)
 GATE = ("attn_gate",)  # models/layers.py:LlamaAttention's gate projection (models/afmoe.py)
 SSCAN = ("sscan_y", "sscan_states")  # ops/selective_scan.py's output and chunk states
-# cell: configuration, (B, T) of its traffic, and the names the rule takes
-# on a v5e after the first rung (the four routed cells' since PR 45, which
-# named the expert layer's products and fitted the `block` term again)
+SSM = ("ssm_y", "ssm_states")  # ops/ssd.py's output and chunk states
+KDA = ("kda_out", "kda_states")  # ops/kda.py's output and chunk states
+# cell: configuration, (B, T) of its traffic, the names the rule takes on a
+# v5e after the first rung (the four routed cells' since PR 45, which named
+# the expert layer's products and fitted the `block` term again), and each of
+# the family's rungs' depth there, in the family's order: (the layers it is
+# saved in, the layers that make its names). Since PR 62 a rung too large for
+# every layer is saved in the last of them that there is room for.
 CELLS = {
-    "gpt2_small.t256": ("gpt2_small", (128, 256), ATTN + MLP),
-    "gpt2_small.t1024": ("gpt2_small", (32, 1024), ATTN + MLP),
-    "mistral_7b_l8.fsdp4_t8192": ("mistral_7b_l8", (4, 8192), MLP),
-    "mellum2_12b_l4_ep4.t8192": ("mellum2_12b_l4_ep4", (2, 8192), ATTN + GATE_UP),
-    "keye_vl2_30b_l4_ep8.t16384": ("keye_vl2_30b_l4_ep8", (1, 16384), ATTN + GATE_UP + OUT),
-    "lfm2_8b_a1b_l5_ep4.t8192": ("lfm2_8b_a1b_l5_ep4", (2, 8192), CONV + MLP + ATTN + GATE_UP + OUT),
+    "gpt2_small.t256": ("gpt2_small", (128, 256), ATTN + MLP, ((12, 12), (12, 12))),
+    "gpt2_small.t1024": ("gpt2_small", (32, 1024), ATTN + MLP, ((12, 12), (12, 12))),
+    # the operands (38.9 ms a GiB) in the last seven layers of eight and the MLPs' products
+    # (30.7) in the last six, where the rule before PR 62 took the MLPs' whole and no operand
+    "mistral_7b_l8.fsdp4_t8192": ("mistral_7b_l8", (4, 8192), MLP + ATTN, ((6, 8), (7, 8))),
+    # the down product (5.8 ms a GiB) in the last three layers of four and the gate's (4.1) in
+    # the last two, where the rule before PR 62 took the gate's and the up's whole and no down's
+    "mellum2_12b_l4_ep4.t8192": ("mellum2_12b_l4_ep4", (2, 8192), ATTN + GATE_UP[:1] + OUT,
+                                 ((4, 4), (2, 4), (0, 4), (3, 4))),
+    "keye_vl2_30b_l4_ep8.t16384": ("keye_vl2_30b_l4_ep8", (1, 16384), ATTN + GATE_UP + OUT,
+                                   ((4, 4),) * 4),
+    # the scan's outputs (14.1 ms a GiB) in the last eight Mamba layers of nine and the MLPs'
+    # products (11.2) in the last nine layers of ten, where the rule before PR 62 took the MLPs' whole
+    "granite4_h_micro_l10.t4096": ("granite4_h_micro_l10", (1, 4096), SSM + MLP, ((8, 9), (9, 10))),
+    "lfm2_8b_a1b_l5_ep4.t8192": ("lfm2_8b_a1b_l5_ep4", (2, 8192), CONV + MLP + ATTN + GATE_UP + OUT,
+                                 ((4, 4), (1, 1), (1, 1), (4, 4), (4, 4), (4, 4))),
     # no product: in this cell they spared nothing (models/kanana.py:REMAT_RUNGS)
-    "kanana2_30b_l5_ep8.t8192": ("kanana2_30b_l5_ep8", (2, 8192), LATENT + SHARED + MLP),
+    "kanana2_30b_l5_ep8.t8192": ("kanana2_30b_l5_ep8", (2, 8192), LATENT + SHARED + MLP,
+                                 ((5, 5), (4, 4), (1, 1))),
     # experts of two matrices: no gate product; the scan's outputs spared
     # nothing at chunks of 128 (models/nemotron_h.py:REMAT_RUNGS)
-    "nemotron3_nano_l9_ep16.t8192": ("nemotron3_nano_l9_ep16", (2, 8192), SHARED + ATTN + UP_OUT),
-    # the shared expert's and the dense MLP's products beside them read 13.58
-    # GiB on the chip, over what the rule is held to (models/afmoe.py:REMAT_RUNGS)
-    "trinity_mini_l5_ep16.t8192": ("trinity_mini_l5_ep16", (2, 8192), ATTN + GATE),
-    # the first rung alone: 8.98 GiB of state, each block's halves under a remat of their own
-    # and an expert layer's buffers of every assignment leave no rung room (models/kimi_linear.py)
-    "kimi_linear_l5_ep32.t8192": ("kimi_linear_l5_ep32", (2, 8192), ()),
-    # one Mamba-1 layer's scan output and states (0.2 GiB); the five MLPs' products (3.1) have no room
-    "phi4_mini_flash_l5.t16384": ("phi4_mini_flash_l5", (1, 16384), SSCAN),
+    "nemotron3_nano_l9_ep16.t8192": ("nemotron3_nano_l9_ep16", (2, 8192), SHARED + ATTN + UP_OUT,
+                                     ((4, 4), (1, 1), (4, 4))),
+    # the shared expert's and the dense MLP's products whole beside them read 13.58
+    # GiB on the chip, over what the rule is held to (models/afmoe.py:REMAT_RUNGS):
+    # the shared expert's in the last two routed layers of four
+    "trinity_mini_l5_ep16.t8192": ("trinity_mini_l5_ep16", (2, 8192), ATTN + GATE + SHARED,
+                                   ((5, 5), (5, 5), (2, 4), (0, 1))),
+    # 8.98 GiB of state, each block's halves under a remat of their own and an expert layer's
+    # buffers of every assignment: the delta rule's outputs in the last three KDA layers of four
+    "kimi_linear_l5_ep32.t8192": ("kimi_linear_l5_ep32", (2, 8192), KDA, ((3, 4),)),
+    # one Mamba-1 layer's scan output and states (0.2 GiB), and of the five MLPs' products
+    # (3.1 GiB) the last three layers'
+    "phi4_mini_flash_l5.t16384": ("phi4_mini_flash_l5", (1, 16384), SSCAN + MLP, ((1, 1), (3, 5))),
+    "sdar_30b_a3b_l5_ep8.t8192": ("sdar_30b_a3b_l5_ep8", (1, 8192), ATTN + GATE_UP + OUT,
+                                  ((5, 5),) * 4),
 }
 # (cell, names saved after the first rung): the allocator's peak in GiB of
 # that step on a v5e (my chip runs, PR 33, calls 1-4: PERF.md section 6; one
@@ -119,6 +144,33 @@ READINGS = {
 # cells' within 0.25 under (mellum) and 0.57 over (kanana: the logits' moment
 # beside every gradient's room, which a step still in its forward does not hold).
 TOLERANCE_GIB = 0.85
+# (cell, each of the family's rungs' depth, in its order): the allocator's
+# reading in GiB of the step whose plan saves each rung in the last that many
+# of its layers, the step's own live bytes and reservation (my chip runs,
+# PR 62: the benchmark's own runs, one process a plan; PERF.md section 6).
+# The plans the rule takes on a v5e since it takes a rung by depth, and the
+# same cells' at the depths it took before (`READINGS` above, whole rungs).
+AT_DEPTH = {
+    # call 1, parent and change in turn, three seeds each alike to the MiB: 12.044 at depth 0
+    # (`READINGS`), and the compile for the described v5e 12.30 / 12.33 / 12.95 / 13.575 at
+    # depths 0 to 3: the reckoning is 0.67 under the chip here, its third moment's doing
+    # (PERF.md section 7, "Open after PR 62")
+    ("kimi_linear_l5_ep32.t8192", (3,)): 13.616,
+    # call 1 likewise (12.082 with no MLP's product; the compile 12.125 / 12.53 at 0 and 3 layers)
+    ("phi4_mini_flash_l5.t16384", (1, 3)): 12.540,
+    # call 6, four chips, parent and change in turn at two seeds, alike to the MiB: the operands
+    # in the last two layers of eight beside `mlp_up` whole (12.332 without them there)
+    ("mistral_7b_l8.fsdp4_t8192", (8, 2)): 12.701,
+    # call 7, four chips: the plan the rule takes, `mlp_up` in six layers and the operands in seven
+    ("mistral_7b_l8.fsdp4_t8192", (6, 7)): 12.752,
+    # call 2, two seeds alike to the MiB: the down product in three layers of four, the gate's
+    # in two and no up's (13.367 with the gate's and the up's whole on the same chip)
+    ("mellum2_12b_l4_ep4.t8192", (4, 2, 0, 3)): 13.382,
+    # call 2: the shared expert's products in the last two routed layers of four beside the
+    # operands and the gate's projection whole (13.121 without them on the same chip)
+    ("trinity_mini_l5_ep16.t8192", (5, 5, 2, 0)): 13.165,
+    ("sdar_30b_a3b_l5_ep8.t8192", (5, 5, 5, 5)): 13.222,  # ledger, PR 61: the run's peak
+}
 
 
 def _cell(name, **changed):
@@ -141,43 +193,143 @@ def _first(family, cfg, shape):
     return names
 
 
+def _made(plan, first, names):
+    """The layers that make `names`, from a plan that saves them whole: those
+    that hold more than the first rung's bytes."""
+    return [i for i, (held, least) in enumerate(zip(plan.layer_bytes, first.layer_bytes))
+            if held > least]
+
+
 @pytest.mark.parametrize("name", CELLS)
-def test_names_are_rungs_of_the_family_and_their_worth_falls_with_the_limit(name, monkeypatch):
+def test_depths_are_of_the_family_s_rungs_and_their_worth_falls_with_the_limit(name, monkeypatch):
+    """Over a limit swept from 64 GiB down to a quarter: every rung's depth is
+    at most the layers that make its names and the layers that save it are the
+    last of those; what the plan spares by the rungs' stated worths (ms a GiB
+    x the GiB held at that depth) never rises as the limit falls and never
+    falls below what the subset of whole rungs that spares most at that limit
+    does (the rule before PR 62, so no plan is worth less than it was); and no
+    rung has room for a layer more."""
     family, cfg, shape = _cell(name)
     rungs = family.REMAT_RUNGS
     first = family.remat_plan(cfg, shape, None)
     assert first.names == _first(family, cfg, shape)
     assert ("moe_plan" in first.names) == hasattr(cfg, "top_k")
-    worth = {}  # of a rung's names: ms a GiB x the bytes it holds a layer
+    made, held = {}, {}  # of a rung's names: the layers that make them, their bytes in each
     for rung in rungs:
-        monkeypatch.setattr(family, "REMAT_RUNGS", (rung,))
-        alone = family.remat_plan(cfg, shape, 1024 * GIB)
+        alone = _alone(family, cfg, shape, rung, monkeypatch)
         assert alone.names == first.names + rung[0]
-        worth[rung[0]] = rung[1] * (alone.layer_bytes - first.layer_bytes)
+        made[rung[0]] = _made(alone, first, rung[0])
+        held[rung[0]] = [a - b for a, b in zip(alone.layer_bytes, first.layer_bytes)]
+    assert first.depths == tuple((names, 0, len(made[names])) for names, _ in rungs)
+    # every subset of whole rungs: its reckoned total and what it spares
+    subsets = []
+    for k in range(len(rungs) + 1):
+        for chosen in itertools.combinations(rungs, k):
+            monkeypatch.setattr(family, "REMAT_RUNGS", chosen)
+            whole = family.remat_plan(cfg, shape, 1024 * GIB)
+            subsets.append((whole.reckoned_bytes,
+                            sum(rate * sum(held[names]) for names, rate in chosen)))
     monkeypatch.setattr(family, "REMAT_RUNGS", rungs)
     spared = []
     for quarter_gib in range(4 * 64, 0, -1):  # 64 GiB down to a quarter
-        plan = family.remat_plan(cfg, shape, quarter_gib * GIB // 4)
-        taken = [names for names, _ in rungs if set(names) <= set(plan.names)]
-        assert plan.names == first.names + tuple(n for names in taken for n in names)
-        assert plan.saved_bytes == cfg.n_layer * plan.layer_bytes
-        if taken:
-            assert plan.reckoned_bytes <= plan.limit_bytes < quarter_gib * GIB // 4
-        spared.append(sum(worth[names] for names in taken))
-    assert spared == sorted(spared, reverse=True)
-    assert spared[0] == sum(worth.values()) and spared[-1] == 0
+        limit = quarter_gib * GIB // 4
+        plan = family.remat_plan(cfg, shape, limit)
+        assert [names for names, _, _ in plan.depths] == [names for names, _ in rungs]
+        assert plan.names == first.names + tuple(
+            n for names, k, _ in plan.depths if k for n in names)
+        worth, layer_bytes = 0.0, list(first.layer_bytes)
+        for (names, k, of), (_, rate) in zip(plan.depths, rungs):
+            assert 0 <= k <= of == len(made[names])
+            assert plan.depth(names[0]) == k
+            for i in made[names][of - k:]:  # the last k of the layers that make them
+                layer_bytes[i] += held[names][i]
+                worth += rate * held[names][i]
+                assert set(names) <= set(plan.by_layer[i])
+            for i in made[names][:of - k]:
+                assert not set(names) & set(plan.by_layer[i])
+        assert plan.layer_bytes == tuple(layer_bytes)
+        assert plan.saved_bytes == sum(plan.layer_bytes)
+        if plan.names != first.names:
+            assert plan.reckoned_bytes <= plan.limit_bytes < limit
+        fit_whole = max(w for reckoned, w in subsets if not w or reckoned <= plan.limit_bytes)
+        assert worth >= fit_whole * (1 - 1e-12)  # sums of floats, in two orders
+        for r, (names, k, of) in enumerate(plan.depths):  # no rung has room for a layer more
+            if k < of:
+                monkeypatch.setattr(remat, "_depths", lambda *a, r=r: tuple(
+                    d + (at == r) for at, (_, d, _) in enumerate(plan.depths)))
+                assert family.remat_plan(cfg, shape, limit).reckoned_bytes > plan.limit_bytes
+                monkeypatch.undo()
+                monkeypatch.setattr(family, "REMAT_RUNGS", rungs)
+        spared.append(worth)
+    assert all(more >= less * (1 - 1e-12) for more, less in zip(spared, spared[1:]))
+    assert spared[0] == pytest.approx(max(w for _, w in subsets), rel=1e-12)
+    assert spared[-1] == 0
 
 
-def test_of_two_rungs_that_do_not_fit_together_the_one_that_spares_more_is_taken():
+def _alone(family, cfg, shape, rung, monkeypatch):
+    """The family's plan with `rung` its only one, whole."""
+    with monkeypatch.context() as patch:
+        patch.setattr(family, "REMAT_RUNGS", (rung,))
+        return family.remat_plan(cfg, shape, 1024 * GIB)
+
+
+def test_a_rung_too_large_for_every_layer_is_saved_in_the_last_of_them():
     """Mistral-7B's cell: the operands spare more a byte, `mlp_up` more of
-    the step; both do not fit a v5e, and the rule takes `mlp_up`. With room
-    for the operands alone it takes those, where a prefix would take none."""
+    the step; both do not fit a v5e whole, where the rule before PR 62 took
+    `mlp_up` whole and no operand. It takes the operands in the last seven
+    layers of eight and `mlp_up` in the last six, which spares more by the
+    family's stated worths than `mlp_up` whole with the operands in two; with
+    less room fewer layers of `mlp_up`, with room for all of both, both."""
     family, cfg, shape = _cell("mistral_7b_l8.fsdp4_t8192")
-    per_gib = dict((names, worth) for names, worth in family.REMAT_RUNGS)
-    assert per_gib[ATTN] > per_gib[MLP]
-    assert family.remat_plan(cfg, shape, V5E_LIMIT).names == remat.FIRST_RUNG + MLP
-    assert family.remat_plan(cfg, shape, 13 * GIB).names == remat.FIRST_RUNG + ATTN
-    assert family.remat_plan(cfg, shape, 24 * GIB).names == remat.FIRST_RUNG + MLP + ATTN
+    (_, per_mlp), (_, per_attn) = family.REMAT_RUNGS
+    assert per_attn > per_mlp
+    plan = family.remat_plan(cfg, shape, 15 * GIB)
+    assert plan.depths == ((MLP, 6, 8), (ATTN, 7, 8))
+    assert plan.names == remat.FIRST_RUNG + MLP + ATTN
+    assert plan.by_layer == (
+        remat.FIRST_RUNG, remat.FIRST_RUNG + ATTN) + (plan.names,) * 6
+    assert plan.saved_in(*ATTN) == (False,) + (True,) * 7
+    assert plan.saved_in(*MLP) == (False,) * 2 + (True,) * 6
+    operand = 8192 * 32 * 128 * 2  # a chip's row of 8,192 tokens, 32 heads of 128 in bf16
+    assert plan.layer_bytes[1] - plan.layer_bytes[0] == 3 * operand
+    mlp_up = plan.layer_bytes[7] - plan.layer_bytes[1]
+    assert mlp_up == 2 * 8192 * 14336 * 2  # the gate's and the up's columns
+    worth = lambda mlp, attn: per_mlp * mlp * mlp_up + per_attn * attn * 3 * operand
+    assert worth(6, 7) > worth(8, 2) > worth(8, 0)
+    assert family.remat_plan(cfg, shape, 13 * GIB).depths == ((MLP, 2, 8), (ATTN, 7, 8))
+    assert family.remat_plan(cfg, shape, 24 * GIB).depths == ((MLP, 8, 8), (ATTN, 8, 8))
+
+
+def test_a_name_s_layers_are_the_family_s_to_say():
+    """A hybrid family's name is made in some layers only (`made_in`): its
+    bytes are counted in those, its depth is out of those, and the layers
+    that save it are the last of those."""
+    held = remat.Held(always=0, grads=0, logits=0, head=0, block=0)
+    rungs = ((("scan_y",), 10.0), (("mlp_up",), 1.0))
+    name_bytes = {"attn_out": 4, "attn_lse": 1, "scan_y": 100, "mlp_up": 10}
+    made_in = {"attn_out": [2], "attn_lse": [2], "scan_y": [0, 1, 3, 4]}
+    limit = lambda room: -(-room * 10 // 9)  # `_LIMIT_SHARE` of it is `room`
+    whole = remat.plan(rungs, name_bytes, 5, held, limit(455), made_in=made_in)
+    assert whole.depths == ((("scan_y",), 4, 4), (("mlp_up",), 5, 5))
+    assert whole.layer_bytes == (110, 110, 15, 110, 110) and whole.saved_bytes == 455
+    assert len(set(whole.by_layer)) == 1  # one policy: what a layer does not make is an identity
+    # no room for all: the scans, which spare more, whole and the last four MLPs'
+    plan = remat.plan(rungs, name_bytes, 5, held, limit(454), made_in=made_in)
+    assert plan.depths == ((("scan_y",), 4, 4), (("mlp_up",), 4, 5))
+    assert plan.layer_bytes == (100, 110, 15, 110, 110)
+    # no room for every scan: the MLPs' whole, and the scans' in layers 4, 3 and 1
+    plan = remat.plan(rungs, name_bytes, 5, held, limit(5 + 300 + 50), made_in=made_in)
+    assert plan.depths == ((("scan_y",), 3, 4), (("mlp_up",), 5, 5))
+    assert plan.layer_bytes == (10, 110, 15, 110, 110)
+    assert plan.saved_in("scan_y") == (False, True, True, True, True)  # layer 2 makes none
+    assert plan.depth("scan_y") == 3 and plan.depth("mlp_up") == 5 and plan.depth("attn_out") == 0
+    # room for neither whole, nor for one scan: the last four MLPs'
+    plan = remat.plan(rungs, name_bytes, 5, held, limit(5 + 45), made_in=made_in)
+    assert plan.depths == ((("scan_y",), 0, 4), (("mlp_up",), 4, 5))
+    assert plan.layer_bytes == (0, 10, 15, 10, 10)
+    # the first rung whatever the limit
+    assert remat.plan(rungs, name_bytes, 5, held, limit(3), made_in=made_in).layer_bytes == (
+        0, 0, 5, 0, 0)
 
 
 @pytest.mark.parametrize("name,saved", sorted(READINGS))
@@ -189,7 +341,27 @@ def test_reckoned_bytes_are_held_to_the_chip_s_reading(name, saved, monkeypatch)
         rung for rung in family.REMAT_RUNGS if set(rung[0]) <= set(saved)))
     plan = family.remat_plan(cfg, shape, 1024 * GIB)
     assert set(plan.names) == set(_first(family, cfg, shape) + saved)
+    assert all(k == of for _, k, of in plan.depths)
     assert abs(plan.reckoned_bytes / GIB - READINGS[name, saved]) <= TOLERANCE_GIB
+
+
+# The cell whose plan on a v5e has no reading here: granite's step read 11.195 GiB with
+# the scan's outputs in eight layers and the MLPs' products in nine (my chip runs, PR 62,
+# call 2; 10.90 with the MLPs' whole and no scan's), and its reckoning stands 1.0 to 2.3 GiB over the chip whatever is saved, outside
+# `TOLERANCE_GIB` on the safe side: tests/test_granite.py holds it to that and says why.
+NOT_READ = {"granite4_h_micro_l10.t4096"}
+
+
+@pytest.mark.parametrize("name,depths", sorted(AT_DEPTH))
+def test_reckoned_bytes_at_a_depth_are_held_to_the_chip_s_reading(name, depths, monkeypatch):
+    """The rule's reckoning of a cell's step with each rung saved in the last
+    so many of its layers, against what a v5e's allocator read of that step:
+    `Held.total` of the bytes those layers save."""
+    family, cfg, shape = _cell(name)
+    monkeypatch.setattr(remat, "_depths", lambda rates, at_depth, fits: depths)
+    plan = family.remat_plan(cfg, shape, 1024 * GIB)
+    assert tuple(k for _, k, _ in plan.depths) == depths
+    assert abs(plan.reckoned_bytes / GIB - AT_DEPTH[name, depths]) <= TOLERANCE_GIB
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -198,8 +370,17 @@ def test_on_a_v5e_the_rule_takes_what_the_chip_runs_were_made_with(name):
     for limit in (V5E_LIMIT, 16909334528):
         plan = family.remat_plan(cfg, shape, limit // GIB * GIB)
         assert plan.names == _first(family, cfg, shape) + CELLS[name][2]
-        assert READINGS[name, CELLS[name][2]] < 14.0
+        assert tuple((k, of) for _, k, of in plan.depths) == CELLS[name][3]
         assert plan.reckoned_bytes <= plan.limit_bytes == int(15 * GIB * 0.9)
+        # What the chip's allocator read of that plan's step: under 14.0 GiB in every cell, as
+        # before the rule took a rung by depth. It now fills the room it is given in more
+        # cells (five reckon over 13.4 of the 13.5), and the fullest reading is kimi_linear's
+        # 13.62, which leaves 2.1 GiB of the chip's 15.75 where the cells' steps are asked to
+        # leave 1.5.
+        depths = tuple(k for k, _ in CELLS[name][3])
+        read = AT_DEPTH.get((name, depths), READINGS.get((name, CELLS[name][2])))
+        assert (read is None) == (name in NOT_READ)
+        assert read is None or read < 14.0
 
 
 @pytest.mark.parametrize("rows,seq_len", [(1, 16384), (2, 8192), (1, 32768)])
@@ -215,11 +396,12 @@ def test_an_indexed_layer_holds_one_mask_of_its_selection(rows, seq_len):
     plan = mellum.remat_plan(cfg, remat.StepShape(rows, seq_len), 1024 * GIB)
     one_mask = rows * seq_len * max(128, seq_len // 32) * 4
     assert "attn_sel" in plan.names and plan.sel_bytes == cfg.n_layer * one_mask
+    assert len(plan.layer_bytes) == cfg.n_layer and len(set(plan.layer_bytes)) == 1
     first = mellum.remat_plan(cfg, remat.StepShape(rows, seq_len), None)
     assert first.names == remat.FIRST_RUNG + ("moe_plan", "attn_sel")
     heads = rows * seq_len * cfg.n_head
     route = rows * seq_len * cfg.top_k * 21  # the choices and the plan: five int32 and a bool
-    assert first.layer_bytes == heads * cfg.head_dim * 2 + heads * 4 + one_mask + route
+    assert first.layer_bytes == (heads * cfg.head_dim * 2 + heads * 4 + one_mask + route,) * 4
 
 
 # shapes no chip run was made at: a deeper model or more experts held, at
@@ -236,6 +418,9 @@ UNSEEN = {
     "trinity_mini_l5_ep16.t8192": dict(num_experts=16),
     "kimi_linear_l5_ep32.t8192": dict(num_experts=16),
     "phi4_mini_flash_l5.t16384": dict(num_hidden_layers=7, layers_kept=[15, 16, 17, 18, 19, 20, 21]),
+    "granite4_h_micro_l10.t4096": dict(num_hidden_layers=20, layer_types=["mamba"] * 9 + ["attention"]
+                                       + ["mamba"] * 9 + ["attention"]),
+    "sdar_30b_a3b_l5_ep8.t8192": dict(num_experts=32),
 }
 
 
@@ -286,7 +471,7 @@ def test_a_mesh_whose_first_device_is_another_process_s_gives_the_local_plan(mon
         assert limit == 15 * GIB
         plans.append(family.remat_plan(cfg, shape, limit))
     assert plans[0] == plans[1] == plans[2]
-    assert plans[0].names == remat.FIRST_RUNG + MLP
+    assert plans[0].names == remat.FIRST_RUNG + MLP + ATTN and plans[0].depth("attn_q") == 7
 
 
 def test_a_chip_that_cannot_say_its_limit_raises_and_a_cpu_device_has_none(monkeypatch):
@@ -335,4 +520,6 @@ def test_the_plan_a_compile_took_is_in_the_flight_recorder_and_the_summary():
     assert ts.telemetry.summary()["remat_saved_bytes"] == plan.saved_bytes > 0
     compiles = [e for e in flight_recorder.get_recorder().dump() if e["event"] == "train.compile"]
     seconds, *booked = compiles[-1]["b"]
-    assert seconds > 0 and tuple(booked[0]) == plan.names and booked[1:] == list(plan[1:])
+    # (seconds, names, bytes a layer, in all, reckoned, limit, ..., the depths, the names a layer)
+    assert seconds > 0 and tuple(booked) == plan
+    assert plan.depths == ((ATTN, 0, 2), (MLP, 0, 2)) and plan.by_layer == (plan.names,) * 2

@@ -1,7 +1,8 @@
 """models/kimi_linear.py's cell compiled for a described TPU v5e, as
 tests/test_tpu_compile.py and with no chip: the delta rule's two kernels at
-`kimi_linear_l5_ep32.t8192`'s shape, the head norm's pair there, and the
-cell's whole step."""
+`kimi_linear_l5_ep32.t8192`'s shape, the head norm's pair there, the
+cell's whole step, and a smaller step whose plan saves the delta rule's
+outputs in some KDA layers and not in all."""
 
 import jax
 import jax.numpy as jnp
@@ -59,13 +60,20 @@ def test_head_norm_s_pair_compiles_at_the_cell_s_shape(one_chip):
 
 @pytest.mark.slow  # 60 s: the lowered step's tally and hash are tests/test_kimi_linear.py's, fast
 @pytest.mark.timeout(600)
-def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
+@pytest.mark.parametrize("layers,read_gib", [("last", 13.575), ("first", 13.88)])
+def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, layers, read_gib):
     """kimi_linear_l5_ep32.t8192's whole step compiled for the described v5e:
-    the rule takes the first rung alone at this shape (the delta rule's
-    outputs do not fit beside 8.98 GiB of state), the program holds within
-    the error the reckoning has shown of what it reckoned (tests/test_remat.py:
-    0.35 GiB under to 0.85 over; the chip's allocator read 12.04 GiB of this
-    step), four KDA layers run kda_bwd once and kda_fwd twice, the head
+    the rule takes the first rung and the delta rule's outputs in the last
+    three KDA layers of four at this shape (all four's do not fit beside 8.98
+    GiB of state), and the program holds what my compile of PR 62 read, 13.575
+    GiB: 0.635 over the reckoning, where every case before the rule took a
+    rung by depth stood within 0.35 (this one's band is its own, stated
+    below; the chip's allocator read 13.616 of this step). With the first
+    three KDA layers saving in their place (`first`: no rule takes those) the
+    same step holds 0.3 GiB more, which is why the rule takes the last: their
+    backward runs first and lets go of them before most gradients exist. Four
+    KDA layers run kda_bwd once, the
+    three that save kda_fwd once and the other twice, the head
     norm's pair after them (kda_norm_bwd once, kda_norm_fwd twice), the
     convolution's pair beside them, one layer the latent pair, the bias's
     update is part of the one program, and under `kda.conv` the compiled
@@ -76,6 +84,8 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     `kda.scan` no result shaped (2, 8192, 32, 128) in bf16 or float32: o goes
     from kda_fwd through the head norm's pair to W_o, and its cotangent back
     into kda_bwd, as (2, 8192, 4096)."""
+    import builtins
+
     import numpy as np
     from jax.sharding import Mesh
 
@@ -88,18 +98,31 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     for mod in (attention, kda, kda_norm, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    if layers == "first":  # the rule's layers taken from the other end: `plan` sorts them, the last first
+        monkeypatch.setattr(remat, "sorted", lambda of, key=None, reverse=False: builtins.sorted(
+            of, key=key), raising=False)
     cfg = cell_config("kimi_linear_l5_ep32")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (2, 8192))).compile()
     plan = remat.traced(cfg)
-    assert plan.names == remat.FIRST_RUNG + ("moe_plan",)
+    assert plan.names == remat.FIRST_RUNG + ("moe_plan", "kda_out", "kda_states")
+    assert plan.depth("kda_states") == 3
+    # layers kda, kda, kda, mla, kda: the fourth makes none, and what a layer does not make it may save
+    assert plan.saved_in("kda_states") == {"last": (False, True, True, True, True),
+                                           "first": (True, True, True, True, False)}[layers]
     live = _live_bytes(c)
     assert live < 14.0 * GIB, c.memory_analysis()
     assert plan.reckoned_bytes <= 13.5 * GIB
-    assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
+    # 13.575 GiB where the rule reckons 12.94: with the outputs saved in the last 0, 1, 2 and 3
+    # layers the compiler counts 12.30, 12.33, 12.95 and 13.575, the rule 12.18, 12.18, 12.32
+    # and 12.94 (my compiles, PR 62): past the first layer's the compiled step holds every saved
+    # byte beside its fullest moment, where `Held.total` lets the gradients' room take them
+    # (PERF.md section 7). The case is held to its reading, not to a wider band for all.
+    assert abs(live / GIB - read_gib) <= 0.05, (plan, c.memory_analysis())
+    assert (live - plan.reckoned_bytes <= 0.70 * GIB) == (layers == "last")
     kinds = _kinds(c.as_text())
     assert {k: n for k, n in kinds.items() if "kda" in k or "conv" in k or "flash" in k} == {
-        "kda_fwd": 8, "kda_bwd": 4, "kda_norm_fwd": 8, "kda_norm_bwd": 4,
+        "kda_fwd": 8 - 3, "kda_bwd": 4, "kda_norm_fwd": 8, "kda_norm_bwd": 4,
         "causal_conv_fwd": 8, "causal_conv_bwd": 4,
         "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1}, kinds
     assert kinds["gmm"] and kinds["tgmm"]
@@ -112,3 +135,41 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     assert len(after) > 100 and not [kind for kind in after if any(
         shape in kind for shape in ("f32[2,8192,32,128]", "bf16[2,8192,32,128]",
                                     "f32[2,8192,4096]"))], after
+
+
+def test_a_step_at_a_depth_compiles_with_the_second_kda_fwd_where_nothing_is_saved(
+        topo, monkeypatch):
+    """Three KDA layers at the cell's widths on (1, 2048) tokens (40 s where
+    the cell's five layers take 60 and are `slow`), under a limit with room
+    for the delta rule's outputs in the last two layers and not in the first
+    (models/remat.py's depths): the step compiled for the described v5e
+    calls kda_fwd 3 + (3 - 2) times and kda_bwd three, and holds no more
+    than the rule reckoned."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ray_tpu.models import kimi_linear, remat
+    from ray_tpu.ops import attention, kda, kda_norm, short_conv
+    from ray_tpu.parallel.train_step import TrainStep
+    from tests._tpu_compile import GIB, _kinds, _live_bytes, _step_args, cell_config
+
+    for mod in (attention, kda, kda_norm, short_conv):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(cell_config("kimi_linear_l5_ep32"), layer_types=("kda",) * 3)
+    shape = remat.step_shape((1, 2048), {})
+    whole = kimi_linear.remat_plan(cfg, shape, 64 * GIB)
+    assert whole.depth("kda_states") == 3
+    limit = next(limit for limit in range(whole.reckoned_bytes * 10 // 9, 0, -(1 << 24))
+                 if kimi_linear.remat_plan(cfg, shape, limit).depth("kda_states") == 2)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: limit)
+    ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
+    c = ts._step.lower(*_step_args(ts, (1, 2048))).compile()
+    plan = remat.traced(cfg)
+    assert plan.depth("kda_states") == 2 and plan.saved_in("kda_states") == (False, True, True)
+    kinds = _kinds(c.as_text())
+    assert {k: n for k, n in kinds.items() if k.startswith("kda")} == {
+        "kda_fwd": 3 + (3 - 2), "kda_bwd": 3, "kda_norm_fwd": 6, "kda_norm_bwd": 3}, kinds
+    assert -0.85 * GIB <= _live_bytes(c) - plan.reckoned_bytes <= 0.35 * GIB, (
+        plan, c.memory_analysis())
