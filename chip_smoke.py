@@ -48,6 +48,7 @@ import time
 
 # Bounds the script holds the run to (bf16 inputs, fp32 accumulation).
 ATTN_REL_TOL = 2e-2   # max|flash - xla| / max|xla|, output and dq, dk, dv
+LAYER_REL_TOL = 3e-2  # a whole bf16 layer against its float32 lines: the latent layer read 0.009-0.018 (PR 63)
 LOSS_REL_TOL = 2e-2   # |loss_mesh - loss_one_device| / loss_one_device, per step
 TELEMETRY_REL_TOL = 2e-2  # the program's step seconds, tokens/s and MFU against the blocked steps
 GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
@@ -580,6 +581,66 @@ def _check_flash_mla_vs_plain(seed, on_tpu):
         raise RuntimeError(f"latent pair vs plain form beyond {ATTN_REL_TOL}: {errs}")
     return {"shape": [b, t, h, d, r], "rel_err": errs,
             "tiles_of_the_cell": list(attention.flash_tiles(32, 8192, d, jnp.bfloat16, shared=r))}
+
+
+def _check_latent_layer_vs_plain(seed, on_tpu):
+    """models/layers.py's `LatentAttention` in bf16 (its projections cut on
+    their weights, ops/attention.py's latent pair between them) at the
+    benchmark cell's shape, (2, 8192, 2048) through 32 heads, with the rotary
+    (kanana's layers) and without (kimi_linear's one), against the plain lines
+    of bench/families/kanana.py and kimi_linear.py in float32 at `highest`
+    precision on the same leaves, same seed: the output and the gradients of
+    the input and of the five leaves, as max-abs error over the reference's
+    max-abs value."""
+    import jax
+    import jax.numpy as jnp
+
+    from bench import families
+    from ray_tpu.models.layers import LatentAttention
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(root, "bench", "configs", "kanana2_30b_l5_ep8.json")) as f:
+        sizes = json.load(f)
+    if not on_tpu:
+        sizes.update(sizes["rehearsal"])
+    kanana = families.load("kanana")
+    plain = {True: kanana._attention, False: families.load("kimi_linear")._latent}
+    cfg = kanana.build(sizes, "bfloat16")
+    b, t = (2, 8192) if on_tpu else (1, 128)
+    kx, kp, kw = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(kx, (b, t, cfg.n_embd), jnp.bfloat16)
+    w = jax.random.normal(kw, (b, t, cfg.n_embd), jnp.float32)
+
+    def run(form, params, x):
+        def loss(params, x):
+            y = form(params, x).astype(jnp.float32)
+            return (y * w).sum(), y
+
+        (d_params, dx), y = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+        return {"y": y, "dx": dx, **{f"d_{name}": leaf for name, leaves in d_params.items()
+                                     for leaf in leaves.values()}}  # a leaf a name
+
+    report = {"shape": [b, t, cfg.n_embd]}
+    for rotary in (True, False):
+        layer = LatentAttention(cfg, rotary=rotary)
+        params = jax.tree.map(  # the norm's weight off one; scores far enough from flat
+            lambda p: p + 0.05 * jax.random.normal(kw, p.shape) if p.ndim == 1 else 2.0 * p,
+            layer.init(kp, x)["params"])
+        system = run(lambda params, x: layer.apply({"params": params}, x), params, x)
+        with jax.default_matmul_precision("highest"):
+            want = run(lambda params, x: plain[rotary](x, params, sizes),
+                       params, x.astype(jnp.float32))
+        errs = {}
+        for name, ref in want.items():
+            got = system[name].astype(jnp.float32)
+            if got.shape != ref.shape or not bool(jnp.isfinite(got).all()):
+                raise RuntimeError(f"latent layer {name}: bad shape or non-finite values")
+            errs[name] = float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
+        if max(errs.values()) > LAYER_REL_TOL:
+            raise RuntimeError(f"latent layer (rotary {rotary}) vs the plain lines beyond "
+                               f"{LAYER_REL_TOL}: {errs}")
+        report["rotary" if rotary else "no_rotary"] = errs
+    return report
 
 
 def _check_kda_vs_plain(seed, on_tpu):
@@ -1124,6 +1185,7 @@ def one_chip_loop(config):
     report["gated_norm_vs_plain"] = _check_gated_norm_vs_plain(config["seed"], on_tpu)
     report["qk_prep_vs_plain"] = _check_qk_prep_vs_plain(config["seed"], on_tpu)
     report["flash_mla_vs_plain"] = _check_flash_mla_vs_plain(config["seed"], on_tpu)
+    report["latent_layer_vs_plain"] = _check_latent_layer_vs_plain(config["seed"], on_tpu)
     report["gated_attention_vs_plain"] = _check_gated_attention(config["seed"], on_tpu)
     report["kda_vs_plain"] = _check_kda_vs_plain(config["seed"], on_tpu)
     report["kda_norm_vs_plain"] = _check_kda_norm_vs_plain(config["seed"], on_tpu)

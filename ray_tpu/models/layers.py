@@ -415,6 +415,37 @@ def pairs_apart(x):
     return x.reshape(*lead, width // 2, 2).swapaxes(-1, -2).reshape(*lead, width)
 
 
+class DenseParts(nn.Module):
+    """`nn.Dense(heads * sum(widths), use_bias=False)`'s leaf under the same
+    name (`kernel`: the shape, dtype, initialiser and key path are the
+    Dense's, so a seed gives the same weights), applied a part at a time. A
+    head's columns are its parts side by side, `widths` wide; part i of the
+    result is x @ (that part's columns of every head), (..., heads *
+    widths[i]). The cut is made on the weight, whose rows are the stream's
+    width and not the tokens: each matmul writes the array its reader takes
+    and nothing slices, pads or adds a (B, T, .) array, forward or backward.
+    `order[i]`, where given, reorders part i's columns within a head (a
+    function of (..., width) arrays: `pairs_apart`)."""
+
+    heads: int
+    widths: tuple
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x, order=None):
+        order, per_head = order or {}, sum(self.widths)
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.heads * per_head), jnp.float32)
+        by_head = kernel.astype(self.dtype).reshape(-1, self.heads, per_head)
+        x, parts, at = x.astype(self.dtype), [], 0
+        for i, width in enumerate(self.widths):
+            cut = order.get(i, lambda columns: columns)(by_head[..., at:at + width])
+            parts.append(jax.lax.dot_general(x, cut.reshape(-1, self.heads * width),
+                                             (((x.ndim - 1,), (0,)), ((), ()))))
+            at += width
+        return parts
+
+
 class LatentAttention(nn.Module):
     """(B, T, d) -> (B, T, d): attention that reads its keys and values
     through a latent (MLA; HF `modeling_deepseek_v3.py`), H heads of widths
@@ -454,24 +485,25 @@ class LatentAttention(nn.Module):
             raise NotImplementedError("latent attention runs on one device")
         B, T, C = x.shape
         H, nope, rope = cfg.n_head, cfg.nope_dim, cfg.rope_dim
-        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+        parts = lambda heads, widths, name: DenseParts(heads, widths, cfg.dtype, name=name)
+        # the 64 that the rotary turns leave their matmuls pairs apart: the
+        # order is made on the weight's columns, and here alone
+        apart = {1: pairs_apart} if self.rotary else {}
         with jax.named_scope("mla.q"):
-            q = dense(H * (nope + rope), "q_proj")(x).reshape(B, T, H, nope + rope)
-            q, q_pe = q[..., :nope], q[..., nope:]
+            q, q_pe = parts(H, (nope, rope), "q_proj")(x, apart)
+            q, q_pe = q.reshape(B, T, H, nope), q_pe.reshape(B, T, H, rope)
         with jax.named_scope("mla.kv_a"):
-            latent = dense(cfg.kv_latent + rope, "kv_a_proj")(x)
-            latent, k_pe = latent[..., :cfg.kv_latent], latent[..., cfg.kv_latent:]
+            latent, k_pe = parts(1, (cfg.kv_latent, rope), "kv_a_proj")(x, apart)
         with jax.named_scope("mla.kv_norm"):
             latent = RMSNorm(cfg.rms_eps, name="kv_a_norm")(latent)
         with jax.named_scope("mla.kv_b"):
-            kv = dense(H * (nope + cfg.v_dim), "kv_b_proj")(latent).reshape(
-                B, T, H, nope + cfg.v_dim)
-            k, v = kv[..., :nope], kv[..., nope:]
+            k, v = parts(H, (nope, cfg.v_dim), "kv_b_proj")(latent)
+            k, v = k.reshape(B, T, H, nope), v.reshape(B, T, H, cfg.v_dim)
         if self.rotary:
             with jax.named_scope("mla.rope"):
                 angles = rope_angles(rope, cfg.rope_theta, jnp.arange(T))
-                q_pe = apply_rope(pairs_apart(q_pe), angles)
-                k_pe = apply_rope(pairs_apart(k_pe)[:, :, None], angles)[:, :, 0]
+                q_pe = apply_rope(q_pe, angles)
+                k_pe = apply_rope(k_pe[:, :, None], angles)[:, :, 0]
         with jax.named_scope("attn.core"):
             if cfg.use_flash_attention:
                 from ray_tpu.ops.attention import latent_attention
@@ -479,7 +511,8 @@ class LatentAttention(nn.Module):
                 from ray_tpu.ops.attention import xla_latent_attention as latent_attention
             y = latent_attention(q, q_pe, k, k_pe, v)
         with jax.named_scope("mla.o"):
-            return dense(C, "o_proj")(y.reshape(B, T, H * cfg.v_dim))
+            return nn.Dense(C, use_bias=False, dtype=cfg.dtype, name="o_proj")(
+                y.reshape(B, T, H * cfg.v_dim))
 
 
 LATENT_SHARDING_PATTERNS = [

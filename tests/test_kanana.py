@@ -12,6 +12,7 @@ import hashlib
 import json
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -283,6 +284,117 @@ def test_latent_attention_under_a_mesh_says_so():
         Kanana(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
+class SlicedLatent(nn.Module):
+    """The latent layer as PR 62 had it, written out: four `nn.Dense`, each
+    writing its parts side by side a head, the parts sliced out of the (B, T,
+    .) results and the 64 put pairs apart on the activation. What
+    `layers.LatentAttention` cuts on the weights is held to this."""
+
+    config: KananaConfig
+    rotary: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        B, T, C = x.shape
+        H, nope, rope = cfg.n_head, cfg.nope_dim, cfg.rope_dim
+        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+        q = dense(H * (nope + rope), "q_proj")(x).reshape(B, T, H, nope + rope)
+        q, q_pe = q[..., :nope], q[..., nope:]
+        latent = dense(cfg.kv_latent + rope, "kv_a_proj")(x)
+        latent, k_pe = latent[..., :cfg.kv_latent], latent[..., cfg.kv_latent:]
+        latent = layers.RMSNorm(cfg.rms_eps, name="kv_a_norm")(latent)
+        kv = dense(H * (nope + cfg.v_dim), "kv_b_proj")(latent).reshape(B, T, H, nope + cfg.v_dim)
+        k, v = kv[..., :nope], kv[..., nope:]
+        if self.rotary:
+            angles = layers.rope_angles(rope, cfg.rope_theta, jnp.arange(T))
+            q_pe = layers.apply_rope(layers.pairs_apart(q_pe), angles)
+            k_pe = layers.apply_rope(layers.pairs_apart(k_pe)[:, :, None], angles)[:, :, 0]
+        y = attention.latent_attention(q, q_pe, k, k_pe, v)
+        return dense(C, "o_proj")(y.reshape(B, T, H * cfg.v_dim))
+
+
+def _latent_pair(rotary, dtype=jnp.float32):
+    cfg = KananaConfig.tiny(dtype=dtype)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, cfg.n_embd), dtype)
+    return x, layers.LatentAttention(cfg, rotary=rotary), SlicedLatent(cfg, rotary=rotary)
+
+
+@pytest.mark.parametrize("rotary", [True, False])
+def test_the_latent_layer_s_tree_is_four_dense_s(rotary):
+    """The leaves the layer cuts at use are `nn.Dense`'s: the same paths,
+    shapes, dtypes and, at a key, values, so a seed gives the weights it
+    gave and the reference reads the tree it read."""
+    x, cut, sliced = _latent_pair(rotary)
+    got, want = (m.init(jax.random.PRNGKey(1), x)["params"] for m in (cut, sliced))
+    flat, want_flat = (dict(jax.tree_util.tree_flatten_with_path(t)[0]) for t in (got, want))
+    assert list(flat) == list(want_flat) and len(flat) == 5
+    assert {jax.tree_util.keystr(path): leaf.shape for path, leaf in flat.items()} == {
+        "['kv_a_norm']['weight']": (32,), "['kv_a_proj']['kernel']": (64, 32 + 8),
+        "['kv_b_proj']['kernel']": (32, 4 * (16 + 16)), "['o_proj']['kernel']": (4 * 16, 64),
+        "['q_proj']['kernel']": (64, 4 * (16 + 8))}  # `KananaConfig.tiny`'s widths
+    for path, leaf in flat.items():
+        assert leaf.dtype == want_flat[path].dtype == jnp.float32
+        np.testing.assert_array_equal(leaf, want_flat[path], err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("rotary", [True, False])
+def test_the_cut_on_the_weights_hands_the_pair_what_the_slices_did(rotary, dtype, monkeypatch):
+    """q, q_pe, k, k_pe and v as `latent_attention` is handed them equal the
+    sliced form's to the bit, in the layouts the calls take: an entry is the
+    dot product it was, whichever matmul holds its column. The CPU sums a
+    column in an order that depends on the matrix's width, so the stream,
+    the weights and the normed latent are put on a grid of eighths, where
+    every sum is exact in float32 and an entry can differ only by being
+    another column's."""
+    x, cut, sliced = _latent_pair(rotary, dtype)
+    grid = lambda a, to: jnp.round(to * a) / to
+    x = grid(x.astype(jnp.float32), 2).astype(dtype)
+    params = sliced.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.tree.map(lambda p: p if p.ndim == 1 else grid(
+        jax.random.normal(jax.random.PRNGKey(p.size), p.shape) / 2, 8), params)
+    norm = layers.rms_norm
+    monkeypatch.setattr(layers, "rms_norm", lambda x, w, eps: grid(norm(x, w, eps), 8))
+    handed = []
+    real = attention.latent_attention
+    monkeypatch.setattr(attention, "latent_attention",
+                        lambda *ops: handed.append(ops) or real(*ops))
+    want_y, got_y = (m.apply({"params": params}, x) for m in (sliced, cut))
+    (want, got), cfg = handed, cut.config
+    assert [op.shape for op in got] == [
+        (2, 24, 4, cfg.nope_dim), (2, 24, 4, cfg.rope_dim), (2, 24, 4, cfg.nope_dim),
+        (2, 24, cfg.rope_dim), (2, 24, 4, cfg.v_dim)]
+    for name, g, w in zip(("q", "q_pe", "k", "k_pe", "v"), got, want):
+        assert g.dtype == w.dtype == dtype and float(jnp.abs(w.astype(jnp.float32)).max()) > 1
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    np.testing.assert_array_equal(got_y, want_y)
+
+
+@pytest.mark.parametrize("rotary", [True, False])
+def test_the_cut_layer_s_output_and_gradients_are_the_sliced_form_s(rotary):
+    """The output, the input's gradient and every leaf's (the four matrices
+    and the latent's norm) in float32: the backward sums two matmuls into dx
+    and into the latent's gradient where it summed one, 1e-6 of each one's
+    largest entry."""
+    x, cut, sliced = _latent_pair(rotary)
+    params = sliced.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.tree.map(lambda p: p + 0.05 * jax.random.normal(
+        jax.random.PRNGKey(p.size), p.shape) if p.ndim == 1 else 2.0 * p, params)
+    w = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    loss = lambda m: lambda p, x: (m.apply({"params": p}, x) * w).sum()
+    with jax.default_matmul_precision("highest"):
+        got, want = ((m.apply({"params": params}, x),
+                      *jax.grad(loss(m), argnums=(0, 1))(params, x)) for m in (cut, sliced))
+    flat, want_flat = (dict(jax.tree_util.tree_flatten_with_path(g)[0]) for g in (got, want))
+    assert len(flat) == 1 + 5 + 1
+    for path, g in flat.items():
+        scale = float(jnp.abs(want_flat[path]).max())
+        assert scale > 0, path
+        np.testing.assert_allclose(g, want_flat[path], rtol=0, atol=1e-6 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 def test_parameters_of_the_cell():
     """The count of ISSUE 43 and PERF.md section 4 by the program's own shapes."""
     sizes = _sizes(rehearse=False)
@@ -344,14 +456,8 @@ def test_the_cell_s_step_runs_the_latent_pair_once_a_layer(monkeypatch):
     """The cell's own step lowered for a TPU on this box: five layers, each
     with flash_mla_fwd and flash_mla_bwd_fused once (the first rung saves the
     output and the logsumexp), no plain causal call, megablox's calls."""
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
-    cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
-    ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
-    state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
-    tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
-    text = ts._step.trace(state, {"idx": tok, "targets": tok}).lower(
-        lowering_platforms=("tpu",)).as_text()
+    cfg, traced = _cell_step(monkeypatch)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
     calls = kernel_tally(text)
     assert calls.pop("kernel") and "@gmm" in text and "@tgmm" in text
     # and the expert layer's sums back to the tokens (PR 44): forward and
@@ -397,22 +503,39 @@ def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
 # rule), as tests/test_mellum.py:_step_text gives it, taken on PR 46's parent's
 # tree before `TrainStep` stopped knowing its families by name; PR 51 moved it by design: the flash calls cut their masked tiles into sub-tiles of 128 (`FlashTiles.sub_fwd`, `.sub_bwd`),
-# the latent pair's among them.
-KANANA_STEP = "92c5695310fceb6cf972dd1b5c1276f49eaf2f40ed3d71452bbe376ff13dd563"
+# the latent pair's among them; PR 63 moved it by design: the latent layer cuts its
+# projections on their weights (models/layers.py:DenseParts), so each matmul writes what
+# the latent pair reads.
+KANANA_STEP = "170bafe97d95fb1c34c43092ca728cd6640b9a8b54c0261869aa7a1a14ae07b9"
 
 
-def test_the_cell_lowers_to_its_pinned_step(monkeypatch):
-    from tests.test_mellum import _step_text
-
+def _cell_step(monkeypatch):
+    """(cfg, the cell's step traced for a TPU on this box under a v5e's limit)."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
-    text = _step_text(ts, state, {"idx": tok, "targets": tok})
+    return cfg, ts._step.trace(state, {"idx": tok, "targets": tok})
+
+
+def test_the_cell_lowers_to_its_pinned_step(monkeypatch):
+    from tests.test_mellum import _traced_text
+
+    cfg, traced = _cell_step(monkeypatch)
     assert not set(KEPT_PRODUCTS) & set(remat.traced(cfg).names)
-    assert hashlib.sha256(text.encode()).hexdigest() == KANANA_STEP
+    assert hashlib.sha256(_traced_text(traced).encode()).hexdigest() == KANANA_STEP
+
+
+def test_the_cell_s_latent_layers_cut_their_projections_on_the_weights(monkeypatch):
+    from tests.test_kimi_linear import latent_layers_are_cut_on_their_weights
+    from tests.test_mellum import _traced_text
+
+    _, traced = _cell_step(monkeypatch)
+    # the first block's dense MLP is 6,144 wide too
+    latent_layers_are_cut_on_their_weights(traced, _traced_text(traced), layers=5,
+                                           elsewhere=((2, 8192, 6144),))
 
 
 def test_step_reports_the_router_s_two_gauges_through_the_telemetry():

@@ -354,12 +354,13 @@ def _cell_step(monkeypatch):
 def _results_under(jaxpr, scope, keep, outer=""):
     """What the equations of a jaxpr under the named scope `scope`, those of
     the jaxprs inside them too (a remat's, a custom rule's, a kernel's body),
-    give that `keep(aval)` holds of: (primitive, shape)."""
+    give that `keep(aval)` holds of: (primitive, shape, name stack)."""
     found = []
     for eqn in jaxpr.eqns:
         stack = f"{outer}/{eqn.source_info.name_stack}"
         if scope in stack:
-            found += [(eqn.primitive.name, v.aval.shape) for v in eqn.outvars if keep(v.aval)]
+            found += [(eqn.primitive.name, v.aval.shape, stack) for v in eqn.outvars
+                      if keep(v.aval)]
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
@@ -374,14 +375,54 @@ def _float32_under(jaxpr, scope, size):
                           and aval.size >= size)
 
 
+# What a latent layer's projections wrote at the cells' shape while each
+# wrote its parts side by side a head, q's 32 x 192 and k | v's 32 x 256, and
+# what the backward padded and summed back into them.
+FUSED_BY_A_LATENT_LAYER = ((2, 8192, 32, 192), (2, 8192, 6144), (2, 8192, 32, 256),
+                           (2, 8192, 8192))
+
+
+def latent_layers_are_cut_on_their_weights(traced, text, layers, elsewhere=()):
+    """Holds a cell's traced step, and its text (`_traced_text`: lowered and
+    as a jaxpr), to `layers.LatentAttention`'s cut (PR 63): under the layer's
+    scopes no equation gives an array of a fused projection's shape, and the
+    whole text names none in either notation (but those in `elsewhere`,
+    which another layer of the cell makes); the forward's matmuls under
+    `q_proj` write H x 128 and H x 64, those under `kv_b_proj` H x 128
+    twice, each the array the latent call reads."""
+    jaxpr = traced.jaxpr.jaxpr
+    for shape in FUSED_BY_A_LATENT_LAYER:
+        for scope in ("mla.", "attn.core"):
+            assert not _results_under(
+                jaxpr, scope, lambda aval: getattr(aval, "shape", None) == shape), (scope, shape)
+        if shape not in elsewhere:
+            assert "x".join(map(str, shape)) + "x" not in text, shape
+            assert f"[{','.join(map(str, shape))}]" not in text, shape
+    widths = lambda scope: sorted(
+        shape[-1] for op, shape, stack in _results_under(jaxpr, scope, lambda aval: True)
+        if op == "dot_general" and "transpose(" not in stack)
+    assert widths("mla.q/q_proj") == layers * [2048] + layers * [4096]
+    assert widths("mla.kv_b/kv_b_proj") == 2 * layers * [4096]
+    assert widths("mla.kv_a/kv_a_proj") == layers * [64] + layers * [512]
+
+
+def test_the_cell_s_latent_layer_cuts_its_projections_on_the_weights(monkeypatch):
+    from tests.test_mellum import _traced_text
+
+    _, traced = _cell_step(monkeypatch)
+    latent_layers_are_cut_on_their_weights(traced, _traced_text(traced), layers=1)
+
+
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
 # rule), as tests/test_mellum.py:_step_text gives it, taken on PR 55's own tree
 # (the l2 norms of q and k inside kda_fwd and kda_bwd): the program the chip
 # runs of PERF.md section 6 were made with; PR 60's since (the head norm and
 # its gate a kernel pair on o as kda_fwd wrote it); PR 62's since, by design: the remat rule
 # takes a rung by depth (models/remat.py), and the last three KDA layers of four save the
-# delta rule's outputs, which no layer saved before: kda_fwd is called five times, not eight.
-KIMI_LINEAR_STEP = "7c32f5545eb2b75645e503ba4077b4f0e4bc0763e2d9cb28a0cf62bbd7bcd947"
+# delta rule's outputs, which no layer saved before: kda_fwd is called five times, not eight;
+# PR 63's since, by design: the latent layer cuts its projections on their weights
+# (models/layers.py:DenseParts), so each matmul writes what the latent pair reads.
+KIMI_LINEAR_STEP = "7ea9941a19c0e04750ed9ef3240acfb6d01fb9fc562067a7a74cff1168b5a20c"
 
 
 def test_the_cell_s_step_tallies_its_kernels_and_lowers_to_its_pinned_step(monkeypatch):

@@ -10,7 +10,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import GIB, _CUSTOM_CALL, _kinds, _live_bytes, _step_args, cell_config
+from tests._tpu_compile import (GIB, _CUSTOM_CALL, _bytes_accessed, _kinds, _live_bytes, _step_args,
+                                cell_config)
 
 
 def test_latent_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -43,8 +44,12 @@ def test_kanana_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     the rule takes every rung at this shape, the program holds less than the
     13.5 GiB the rule is held to and within the error the reckoning has shown
     of what it reckoned (tests/test_remat.py: 0.35 GiB under to 0.85 over),
-    five layers run each latent kernel once and no plain causal call, and
-    the bias's update is part of the one program."""
+    five layers run each latent kernel once and no plain causal call, the
+    bias's update is part of the one program, and the step's `bytes accessed`
+    stand under PR 62's step's (321.50 GB by the same compile) by what the
+    latent layers' cut on their weights took out of one layer alone, 3.9 GB,
+    five times, less 2 GB of tolerance (PR 63 read 295.37: the second forward
+    and the residuals' passes went with them)."""
     from ray_tpu.models import remat
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
@@ -59,6 +64,7 @@ def test_kanana_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     live = _live_bytes(c)
     assert live < 13.5 * GIB, c.memory_analysis()
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
+    assert _bytes_accessed(c) / 1e9 <= 321.50 - 5 * 3.9 + 2.0, _bytes_accessed(c)
     kinds = _kinds(c.as_text())
     latent = {k: n for k, n in kinds.items() if "flash" in k}
     assert sorted(latent.values()) == [5, 5] and all("flash_mla_" in k for k in latent), kinds

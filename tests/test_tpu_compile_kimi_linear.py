@@ -60,15 +60,17 @@ def test_head_norm_s_pair_compiles_at_the_cell_s_shape(one_chip):
 
 @pytest.mark.slow  # 60 s: the lowered step's tally and hash are tests/test_kimi_linear.py's, fast
 @pytest.mark.timeout(600)
-@pytest.mark.parametrize("layers,read_gib", [("last", 13.575), ("first", 13.88)])
+@pytest.mark.parametrize("layers,read_gib", [("last", 13.647), ("first", 13.94)])
 def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, layers, read_gib):
     """kimi_linear_l5_ep32.t8192's whole step compiled for the described v5e:
     the rule takes the first rung and the delta rule's outputs in the last
     three KDA layers of four at this shape (all four's do not fit beside 8.98
-    GiB of state), and the program holds what my compile of PR 62 read, 13.575
-    GiB: 0.635 over the reckoning, where every case before the rule took a
+    GiB of state), and the program holds what my compile of PR 63 read, 13.647
+    GiB (PR 62's read 13.575: since PR 63 the latent layer cuts its
+    projections on their weights, and the step compiled holds 0.07 GiB
+    more): 0.705 over the reckoning, where every case before the rule took a
     rung by depth stood within 0.35 (this one's band is its own, stated
-    below; the chip's allocator read 13.616 of this step). With the first
+    below; the chip's allocator read 13.616 of PR 62's step). With the first
     three KDA layers saving in their place (`first`: no rule takes those) the
     same step holds 0.3 GiB more, which is why the rule takes the last: their
     backward runs first and lets go of them before most gradients exist. Four
@@ -113,13 +115,14 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     live = _live_bytes(c)
     assert live < 14.0 * GIB, c.memory_analysis()
     assert plan.reckoned_bytes <= 13.5 * GIB
-    # 13.575 GiB where the rule reckons 12.94: with the outputs saved in the last 0, 1, 2 and 3
-    # layers the compiler counts 12.30, 12.33, 12.95 and 13.575, the rule 12.18, 12.18, 12.32
-    # and 12.94 (my compiles, PR 62): past the first layer's the compiled step holds every saved
+    # 13.647 GiB where the rule reckons 12.94: with the outputs saved in the last 0, 1, 2 and 3
+    # layers the compiler counted 12.30, 12.33, 12.95 and 13.575, the rule 12.18, 12.18, 12.32
+    # and 12.94 (my compiles, PR 62; PR 63's step holds 0.07 more at depth 3, 0.06 with `first`):
+    # past the first layer's the compiled step holds every saved
     # byte beside its fullest moment, where `Held.total` lets the gradients' room take them
     # (PERF.md section 7). The case is held to its reading, not to a wider band for all.
     assert abs(live / GIB - read_gib) <= 0.05, (plan, c.memory_analysis())
-    assert (live - plan.reckoned_bytes <= 0.70 * GIB) == (layers == "last")
+    assert (live - plan.reckoned_bytes <= 0.75 * GIB) == (layers == "last")
     kinds = _kinds(c.as_text())
     assert {k: n for k, n in kinds.items() if "kda" in k or "conv" in k or "flash" in k} == {
         "kda_fwd": 8 - 3, "kda_bwd": 4, "kda_norm_fwd": 8, "kda_norm_bwd": 4,
