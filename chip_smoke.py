@@ -58,13 +58,14 @@ GOODPUT_FLOOR = 0.9   # productive share of the wall time after the compile call
 # layers' shape, (B, T, H, D) and the window; bd_shapes: and over a doubled
 # stream under the block-diffusion mask, (B, 2T, H, D) and the block length
 FULL = {"model": None, "B": 16, "T": 1024,
-        "attn_shapes": [(16, 1024, 12, 64), (1, 2048, 8, 128)],
+        "attn_shapes": [(16, 1024, 12, 64), (1, 2048, 8, 128),
+                        (2, 8192, 16, 256)],  # heads of two vregs: qwen3_next_80b_l5_ep32.t8192's
         "windowed_shapes": [((2, 8192, 32, 128), 1024), ((2, 8192, 32, 128), 2048)],
         "bd_shapes": [((1, 16384, 32, 128), 4)]}  # the cell's own: sdar_30b_a3b_l5_ep8.t8192
 TINY = {
     "model": {"vocab_size": 257, "block_size": 256, "n_layer": 2, "n_head": 4,
               "n_embd": 64},
-    "B": 4, "T": 256, "attn_shapes": [(2, 256, 2, 64), (1, 256, 2, 128)],
+    "B": 4, "T": 256, "attn_shapes": [(2, 256, 2, 64), (1, 256, 2, 128), (1, 256, 2, 256)],
     "windowed_shapes": [((1, 512, 2, 128), 100), ((1, 512, 2, 128), 256)],
     "bd_shapes": [((1, 512, 2, 128), 4)],
 }
@@ -643,6 +644,36 @@ def _check_latent_layer_vs_plain(seed, on_tpu):
     return report
 
 
+def _rule_outputs_and_grads(form, ops, w):
+    """(o, the last state, the seven gradients) of a delta rule's `form` on
+    its seven operands under the loss sum(o * w): one compiled function."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(*ops):
+        o, last = form(*ops)
+        return (o.astype(jnp.float32) * w.astype(jnp.float32)).sum(), (o, last)
+
+    grads, out = jax.jit(jax.grad(loss, argnums=range(7), has_aux=True))(*ops)
+    return (*out, *grads)
+
+
+def _rule_errors(rule, names, got, want, what):
+    """Each of `names` as max-abs error over the reference's max-abs value;
+    raises past ATTN_REL_TOL or on a bad shape or a non-finite value."""
+    import jax.numpy as jnp
+
+    errs = {}
+    for name, g, r in zip(names, got, want):
+        g = g.astype(jnp.float32)
+        if g.shape != r.shape or not bool(jnp.isfinite(g).all()):
+            raise RuntimeError(f"{rule} {what} {name}: bad shape or non-finite values")
+        errs[name] = float(jnp.abs(g - r).max() / jnp.abs(r).max())
+    if max(errs.values()) > ATTN_REL_TOL:
+        raise RuntimeError(f"{rule} {what} vs the recurrence beyond {ATTN_REL_TOL}: {errs}")
+    return errs
+
+
 def _check_kda_vs_plain(seed, on_tpu):
     """ops/kda.py's two kernels (bf16 operands, q and k as a convolution
     leaves them: the heads' l2 norms and the gate made inside the kernels)
@@ -671,13 +702,7 @@ def _check_kda_vs_plain(seed, on_tpu):
     beta = jax.nn.sigmoid(jax.random.normal(ks[7], (b, t, h)))
     ops = (q, k, v, f, a_log, dt_bias, beta)
 
-    def run(form, ops, w):
-        def loss(*ops):
-            o, last = form(*ops)
-            return (o.astype(jnp.float32) * w.astype(jnp.float32)).sum(), (o, last)
-
-        grads, out = jax.jit(jax.grad(loss, argnums=range(7), has_aux=True))(*ops)
-        return (*out, *grads)
+    run = _rule_outputs_and_grads
 
     def gated(interpret):
         def form(*ops):
@@ -696,16 +721,7 @@ def _check_kda_vs_plain(seed, on_tpu):
 
     names = ("o", "state", "dq", "dk", "dv", "df", "dA_log", "ddt_bias", "dbeta")
 
-    def errors(got, want, what):
-        errs = {}
-        for name, g, r in zip(names, got, want):
-            g = g.astype(jnp.float32)
-            if g.shape != r.shape or not bool(jnp.isfinite(g).all()):
-                raise RuntimeError(f"kda {what} {name}: bad shape or non-finite values")
-            errs[name] = float(jnp.abs(g - r).max() / jnp.abs(r).max())
-        if max(errs.values()) > ATTN_REL_TOL:
-            raise RuntimeError(f"kda {what} vs the recurrence beyond {ATTN_REL_TOL}: {errs}")
-        return errs
+    errors = functools.partial(_rule_errors, "kda", names)
 
     as_f32 = lambda ops: [x.astype(jnp.float32) for x in ops]
     want = run(plain, as_f32(ops), w)
@@ -716,6 +732,68 @@ def _check_kda_vs_plain(seed, on_tpu):
     report["rel_err_chunked_form"] = errors(
         run(chunked, part, w[:, :n]),
         run(plain, as_f32(part), w[:, :n]), "chunked form")
+    return report
+
+
+def _check_gdn_vs_plain(seed, on_tpu):
+    """ops/gdn.py's two kernels (bf16 operands, q and k as a convolution
+    leaves them: the heads' l2 norms made inside the kernels; the gate's
+    softplus and beta's sigmoid the layer's lines before them) against the
+    recurrence step by step in float32 at `highest` matmul precision on
+    operands normed in float32, at the benchmark cell's head sizes, (2, 8192)
+    tokens, 16 key heads and 32 value heads of 128, a decay drawn as
+    models/qwen3_next.py's `a_log_init` draws A_log (dt_bias ones), same
+    seed: the output, the state after the last token and the seven gradients
+    (q, k, v, a, A_log, dt_bias, b), as max-abs error over the reference's
+    max-abs value; and the same for the chunked form in jax.numpy on an
+    eighth of the tokens (what a backend without the kernels runs)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.qwen3_next import L2_EPS, a_log_init
+    from ray_tpu.ops import gdn, kda
+
+    b, t, hk, hv, d = (2, 8192, 16, 32, 128) if on_tpu else (1, 128, 1, 2, 128)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    q, k = (jax.nn.silu(jax.random.normal(key, (b, t, hk, d))).astype(jnp.bfloat16)
+            for key in ks[:2])
+    v, w = (jax.random.normal(key, (b, t, hv, d), jnp.bfloat16) for key in ks[2:4])
+    a, pre_beta = (jax.random.normal(key, (b, t, hv)) for key in ks[4:6])
+    a_log, dt_bias = a_log_init(ks[6], (hv,)), jnp.ones((hv,), jnp.float32)
+    ops = (q, k, v, a, a_log, dt_bias, pre_beta)
+
+    run = _rule_outputs_and_grads
+
+    def rates(a, a_log, dt_bias, pre_beta):
+        return gdn.gate_log_decay(a, a_log, dt_bias), jax.nn.sigmoid(pre_beta)
+
+    def kernels(interpret):
+        def form(q, k, v, *rest):
+            o, _, last = gdn.gdn(q, k, v, *rates(*rest), l2_eps=L2_EPS, interpret=interpret)
+            return o.reshape(v.shape), last.swapaxes(-1, -2)  # o comes (b, T, Hv x 128)
+        return form
+
+    def plain(q, k, v, *rest):
+        return gdn.gdn_plain(kda.l2norm(q, L2_EPS), kda.l2norm(k, L2_EPS), v, *rates(*rest))
+
+    def chunked(q, k, v, *rest):
+        o, _, last = gdn.gdn(q, k, v, *rates(*rest), l2_eps=L2_EPS)
+        return o.reshape(v.shape), last.swapaxes(-1, -2)
+
+    names = ("o", "state", "dq", "dk", "dv", "da", "dA_log", "ddt_bias", "db")
+
+    errors = functools.partial(_rule_errors, "gdn", names)
+
+    as_f32 = lambda ops: [x.astype(jnp.float32) for x in ops]
+    want = run(plain, as_f32(ops), w)
+    report = {"shape": [b, t, hk, hv, d], "chunk": gdn.CHUNK, "gdn_path": gdn.gdn_path(t, d, d),
+              "decay_mean": float(jnp.exp(rates(*ops[3:])[0]).mean()),
+              "rel_err": errors(run(kernels(not on_tpu), ops, w), want, "kernels")}
+    if not on_tpu:  # where the kernels run `gdn.gdn` is the kernels: the chunked form is a CPU's
+        n = max(t // 8, gdn.CHUNK)
+        part = [x[:, :n] if x.ndim > 2 else x for x in ops]
+        report["rel_err_chunked_form"] = errors(
+            run(chunked, part, w[:, :n]), run(plain, as_f32(part), w[:, :n]), "chunked form")
     return report
 
 
@@ -852,9 +930,25 @@ def _check_gated_attention(seed, on_tpu):
     x = jax.random.normal(kx, (b, t, cfg.n_embd), jnp.bfloat16)
     w = jax.random.normal(kw, (b, t, cfg.n_embd), jnp.float32)
     report = {"shape": [b, t, cfg.n_head, cfg.n_kv_head, cfg.head_dim], "rel_err": {}}
-    for kind, sliding in (("sliding", True), ("full", False)):
-        layer = LlamaAttention(cfg, window=cfg.sliding_window if sliding else None, qk_norm=True,
-                               rotary=sliding, gate=True)
+    with open(os.path.join(root, "bench", "configs", "qwen3_next_80b_l5_ep32.json")) as f:
+        wide = json.load(f)  # heads of 256, a rotary over a head's first 64: its one full layer
+    if not on_tpu:
+        wide = {**wide, **wide["rehearsal"]}
+    wide_family = families.load(wide["family"])
+    wide_cfg = wide_family.build(wide, "bfloat16")
+    report["shape_partial_rotary_256"] = [b, t, wide_cfg.n_head, wide_cfg.n_kv_head,
+                                          wide_cfg.head_dim, wide_cfg.rotary_dim]
+    for kind, sliding in (("sliding", True), ("full", False), ("partial_rotary_256", None)):
+        if sliding is None:
+            layer = LlamaAttention(wide_cfg, qk_norm=True, gate=True,
+                                   rotary_dim=wide_cfg.rotary_dim)
+            x, w = (a[..., :wide_cfg.n_embd] for a in (x, w))
+            plain_form = lambda x, params: wide_family._gated_attention(x, params, wide)
+        else:
+            layer = LlamaAttention(cfg, window=cfg.sliding_window if sliding else None,
+                                   qk_norm=True, rotary=sliding, gate=True)
+            plain_form = lambda x, params, sliding=sliding: family._attention(
+                x, params, sizes, sliding)
         params = layer.init(kp, x)["params"]
         params["wg"]["kernel"] = 4.0 * params["wg"]["kernel"]  # gates off one half
 
@@ -865,7 +959,7 @@ def _check_gated_attention(seed, on_tpu):
         got = jax.jit(lambda x: run(lambda x: layer.apply({"params": params}, x), x))(x)
         with jax.default_matmul_precision("highest"):
             want = jax.jit(lambda x: run(
-                lambda x: family._attention(x, params, sizes, sliding), x))(x.astype(jnp.float32))
+                lambda x: plain_form(x, params), x))(x.astype(jnp.float32))
         for name, a, ref in zip(("out", "dx"), got, want):
             a = a.astype(jnp.float32)
             if a.shape != ref.shape or not bool(jnp.isfinite(a).all()):
@@ -1030,7 +1124,9 @@ def _flash_calls_by_cell(on_tpu):
     once where the plan saves the chunk states (`kda_states`), else twice,
     the head norm's pair after it (ops/kda_norm.py: kda_norm_bwd once and
     kda_norm_fwd twice, no plan names its output) and the convolution's pair
-    as a `mamba` layer's. A `mamba` layer of
+    as a `mamba` layer's; a `linear_attention` layer (models/qwen3_next.py:
+    the delta rule under one decay a head and step) the same with gdn_fwd
+    and gdn_bwd (`gdn_states`) in their place. A `mamba` layer of
     a family whose scan's decay differs by state (a configuration with
     `ssm_rank`: Mamba-1) has sscan_bwd once, and sscan_fwd once where the
     plan saves `sscan_y`, in place of ssd's pair; a gated memory unit
@@ -1071,6 +1167,7 @@ def _flash_calls_by_cell(on_tpu):
         scans, convs = layer_kinds.count("mamba"), layer_kinds.count("conv")
         mixers_alone = layer_kinds.count("experts")
         deltas, units = layer_kinds.count("kda"), layer_kinds.count("gmu")
+        rules = layer_kinds.count("linear_attention")  # ops/gdn.py's pair (models/qwen3_next.py)
         selective = scans if hasattr(cfg, "ssm_rank") else 0  # ops/selective_scan.py's pair
         kept = remat.traced(cfg).depth  # the layers that save a name run its kernel once
         scan_fwd = 2 * (scans - selective) - kept("ssm_y")
@@ -1078,19 +1175,21 @@ def _flash_calls_by_cell(on_tpu):
         by_group = scans if getattr(cfg, "ssm_groups", 1) > 1 else 0
         prepped = 2 * QK_PREP_LAYERS.get(name, 0)
         if on_tpu and not (fwd == kinds["fused"] == (cfg.n_layer - scans - convs - mixers_alone
-                                                     - deltas - units)
+                                                     - deltas - units - rules)
+                           and found["gdn_fwd"] == 2 * rules - kept("gdn_states")
+                           and found["gdn_bwd"] == rules
                            and found["sscan_fwd"] == 2 * selective - kept("sscan_y")
                            and found["sscan_bwd"] == selective
                            and found["kda_fwd"] == 2 * deltas - kept("kda_states")
                            and found["kda_bwd"] == deltas
-                           and found["kda_norm_fwd"] == 2 * deltas
-                           and found["kda_norm_bwd"] == deltas
+                           and found["kda_norm_fwd"] == 2 * (deltas + rules)
+                           and found["kda_norm_bwd"] == deltas + rules
                            and found["ssd_fwd"] == scan_fwd
                            and found["ssd_bwd"] == scans - selective
                            and found["gated_conv_fwd"] == conv_fwd
                            and found["gated_conv_bwd"] == convs
-                           and found["causal_conv_fwd"] == 2 * (scans + deltas)
-                           and found["causal_conv_bwd"] == scans + deltas
+                           and found["causal_conv_fwd"] == 2 * (scans + deltas + rules)
+                           and found["causal_conv_bwd"] == scans + deltas + rules
                            and found["gated_norm_fwd"] == 2 * by_group
                            and found["gated_norm_bwd"] == by_group
                            and found["qk_prep_bwd"] == prepped
@@ -1188,6 +1287,7 @@ def one_chip_loop(config):
     report["latent_layer_vs_plain"] = _check_latent_layer_vs_plain(config["seed"], on_tpu)
     report["gated_attention_vs_plain"] = _check_gated_attention(config["seed"], on_tpu)
     report["kda_vs_plain"] = _check_kda_vs_plain(config["seed"], on_tpu)
+    report["gdn_vs_plain"] = _check_gdn_vs_plain(config["seed"], on_tpu)
     report["kda_norm_vs_plain"] = _check_kda_norm_vs_plain(config["seed"], on_tpu)
     report["sscan_vs_recurrence"] = _check_sscan_vs_recurrence(config["seed"], on_tpu)
     report["windowed_flash"] = _windowed_flash_plan()

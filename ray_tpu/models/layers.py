@@ -115,7 +115,10 @@ class LlamaAttention(nn.Module):
     layer's input is the doubled stream [noised | clean] of a block-diffusion
     step (models/sdar.py), (B, 2T, C): both halves carry positions 0 .. T-1,
     and a query sees what ops/attention.py's `block_diffusion_mask` shows it
-    with blocks of this length."""
+    with blocks of this length. `rotary_dim`: the rotary turns a head's first
+    so many entries (their halves, positions from 0) and leaves the others as
+    they are (models/qwen3_next.py: 64 of 256, `partial_rotary_factor`);
+    None: the whole head."""
 
     config: Any
     window: Optional[int] = None
@@ -127,6 +130,7 @@ class LlamaAttention(nn.Module):
     q_scale: float = 1.0
     gate: bool = False
     blocks: Optional[int] = None
+    rotary_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
@@ -139,6 +143,7 @@ class LlamaAttention(nn.Module):
         # heads of one vreg's 128 lanes that a layer norms or turns on their way
         # into the flash calls stay where the projections wrote them: `_on_rows`
         on_rows = (cfg.attn_fn is None and cfg.use_flash_attention and hd == 128
+                   and self.rotary_dim is None  # ops/qk_prep.py turns whole heads of 128 lanes
                    and (self.qk_norm or self.rotary) and attention_path(T, self.blocks) == "flash")
         heads = (lambda a, n: a) if on_rows else (lambda a, n: a.reshape(B, T, n, hd))
         q = heads(dense(cfg.n_head * hd, "wq")(x), cfg.n_head)
@@ -181,10 +186,15 @@ class LlamaAttention(nn.Module):
 
         if self.rotary:
             with jax.named_scope("attn.rope"):
-                ang = rope_angles(hd, cfg.rope_theta, self._positions(T, pos_offset),
+                turned = hd if self.rotary_dim is None else self.rotary_dim
+                ang = rope_angles(turned, cfg.rope_theta, self._positions(T, pos_offset),
                                   self.inv_freq)
-                q = apply_rope(q, ang, self.rope_scale)
-                k = apply_rope(k, ang, self.rope_scale)
+                if turned == hd:
+                    q = apply_rope(q, ang, self.rope_scale)
+                    k = apply_rope(k, ang, self.rope_scale)
+                else:
+                    q, k = (jnp.concatenate([apply_rope(a[..., :turned], ang, self.rope_scale),
+                                             a[..., turned:]], axis=-1) for a in (q, k))
         if self.q_scale != 1.0:
             q = q * self.q_scale
 
@@ -527,10 +537,16 @@ class SharedExpert(nn.Module):
     """The expert every token passes through beside its routed ones, and of
     their form (ops/moe.py:ExpertForm): a matrix for each of `form.matrices`,
     `shared_dim` wide, and what `form.hidden` makes of their products into
-    `down`. SWIGLU's leaves are gate, up and down; RELU2's up and down."""
+    `down`. SWIGLU's leaves are gate, up and down; RELU2's up and down. With
+    `scalar_gate` the result is times sigmoid(w_s . x), one number a token
+    from one row of the stream's width (the leaf `token_gate`, (d, 1);
+    models/qwen3_next.py's `shared_expert_gate`), the sigmoid and the product
+    in float32 under the scope `moe.shared_gate`; its mean is sown into
+    "shared_gate" (0.5 at initialisation)."""
 
     config: Any  # any config with `shared_dim`, `n_embd` and `dtype`
     form: moe.ExpertForm = moe.SWIGLU
+    scalar_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -538,12 +554,19 @@ class SharedExpert(nn.Module):
         dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
         products = [checkpoint_name(dense(cfg.shared_dim, name)(x), "shared_up")
                     for name in self.form.matrices]
-        return dense(cfg.n_embd, "down")(self.form.hidden(*products))
+        y = dense(cfg.n_embd, "down")(self.form.hidden(*products))
+        if not self.scalar_gate:
+            return y
+        with jax.named_scope("moe.shared_gate"):
+            open_ = jax.nn.sigmoid(dense(1, "token_gate")(x).astype(jnp.float32))
+            self.sow("shared_gate", "mean", open_.mean())
+            return (y.astype(jnp.float32) * open_).astype(y.dtype)
 
 
 SHARED_EXPERT_SHARDING_PATTERNS = [
     (r"shared/(gate|up)/kernel", P("fsdp", "tp")),
     (r"shared/down/kernel", P("tp", "fsdp")),
+    (r"shared/token_gate/kernel", P()),
 ]
 
 

@@ -97,6 +97,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -261,13 +262,60 @@ def _inverse(M, exact):
     return Tm
 
 
-def _chunk_parts(q, k, v, g, beta, St, scale, roll, exact):
+class Decay(NamedTuple):
+    """What of a chunk follows the decay's kind. By channel (this file's): the
+    products inside a chunk summed over the levels. One number a head and
+    step (ops/gdn.py): g comes with that number on every lane, so G, exp(G)
+    and every product with them are what they are here, and the three below
+    are its own.
+
+    intra(q, k, G, roll) -> (A_qk, A_kk), float32.
+    over(x): of x (rows, K), the decay's gradient channel by channel, what
+    the decay's own numbers take (by channel: x).
+    pull(q, k, p, (ri, ci), dA_qk, dA_kk, dq, dk, dG, roll) -> (dq, dk, dG)
+    with `intra`'s cotangents added: p `_chunk_parts`' dict, ri and ci the
+    (C, C) row and column numbers."""
+
+    intra: Callable
+    over: Callable
+    pull: Callable
+
+
+def _levels_pulled(q, k, p, at, dA_qk, dA_kk, dq, dk, dG, roll):
+    """`_intra`'s cotangents: the diagonal of A_qk, then level by level."""
+    c, dtype = q.shape[0], q.dtype
+    qf, kf, G, (ri, ci) = p["qf"], p["kf"], p["G"], at
+    on_diag = jnp.where(ri == ci, dA_qk, 0.0).astype(dtype)
+    dq = dq + _dot(on_diag, k, _NN)
+    dk = dk + _dot(on_diag, q, _TN)
+    for b in _levels(c):
+        E, qe, ke = _level_operands(qf, kf, G, b, roll, dtype)
+        mask = _level_mask(c, b)
+        Pq = jnp.where(mask, dA_qk, 0.0).astype(dtype)
+        Pk = jnp.where(mask, dA_kk, 0.0).astype(dtype)
+        rows = _dot(jnp.concatenate([Pq, Pk], axis=0), ke, _NN)  # (2 c, K): as rows
+        dqe, dke_row = rows[:c], rows[c:]
+        dke_col = _dot(jnp.concatenate([Pq, Pk], axis=0), jnp.concatenate([qe, ke], axis=0), _TN)
+        dq = dq + dqe * E
+        dk = dk + (dke_row + dke_col) * E
+        # the rounded operands, so that a pair's two shares are one number
+        # with two signs: the running sum up the rows then cancels them
+        # outside (j, i] to float32's last bits, where q E and k E unrounded
+        # leave a bf16 rounding's worth of every pair on every earlier row
+        dG = dG + dqe * qe.astype(_F32) + (dke_row - dke_col) * ke.astype(_F32)
+    return dq, dk, dG
+
+
+BY_CHANNEL = Decay(_intra, lambda x: x, _levels_pulled)
+
+
+def _chunk_parts(q, k, v, g, beta, St, scale, roll, exact, decay=BY_CHANNEL):
     """What both passes make of a chunk: everything up to Vn."""
     dtype = q.dtype
     G = _cumsum_rows(g, roll)
     kd = k.shape[1]
     qf, kf, vf = q.astype(_F32), k.astype(_F32), v.astype(_F32)
-    a_qk, a_kk = _intra(q, k, G, roll)
+    a_qk, a_kk = decay.intra(q, k, G, roll)
     Tm = _inverse(beta * a_kk, exact)
     eG = jnp.exp(G)
     Kg = kf * eG
@@ -282,12 +330,12 @@ def _chunk_parts(q, k, v, g, beta, St, scale, roll, exact):
                 Sd=Sd, Vn=Vn, Qg=Qg, eL=eL, Kend=(kf * eL).astype(dtype))
 
 
-def _chunk_fwd(q, k, v, g, beta, St, scale, roll, exact):
+def _chunk_fwd(q, k, v, g, beta, St, scale, roll, exact, decay=BY_CHANNEL):
     """One chunk of one head: q, k (C, K) and v (C, V) in the compute dtype,
     the log decays g (C, K) float32, beta (C, 1) float32, St the transposed
     state (V, K) float32 at the chunk's start. Returns o (C, V) float32 and
     the state at its end."""
-    p = _chunk_parts(q, k, v, g, beta, St, scale, roll, exact)
+    p = _chunk_parts(q, k, v, g, beta, St, scale, roll, exact, decay)
     dtype, G = q.dtype, p["G"]
     Vn = p["Vn"].astype(dtype)
     o = _dot(p["Qg"].astype(dtype), p["Sd"], _NT) + _dot(
@@ -295,14 +343,14 @@ def _chunk_fwd(q, k, v, g, beta, St, scale, roll, exact):
     return o, jnp.exp(G[-1:]) * St + _dot(Vn, p["Kend"], _TN)
 
 
-def _chunk_bwd(q, k, v, g, beta, St, do, dSt, scale, roll, exact):
+def _chunk_bwd(q, k, v, g, beta, St, do, dSt, scale, roll, exact, decay=BY_CHANNEL):
     """The chunk's cotangents from do (C, V) and the cotangent dSt (V, K) of
     the state at its end: dq, dk, dv, dg, dbeta in float32, and the cotangent
     of the state at its start."""
-    p = _chunk_parts(q, k, v, g, beta, St, scale, roll, exact)
+    p = _chunk_parts(q, k, v, g, beta, St, scale, roll, exact, decay)
     c, kd = k.shape
     dtype, G = q.dtype, p["G"]
-    qf, kf, vf, eG, eL, Sd = p["qf"], p["kf"], p["vf"], p["eG"], p["eL"], p["Sd"]
+    kf, vf, eG, eL, Sd = p["kf"], p["vf"], p["eG"], p["eL"], p["Sd"]
     do = do.astype(dtype)
     dSd = dSt.astype(dtype)
     Vn = p["Vn"].astype(dtype)
@@ -332,28 +380,11 @@ def _chunk_bwd(q, k, v, g, beta, St, do, dSt, scale, roll, exact):
     at_end = dKend * kf * eL
     dq = dQg * eG * scale
     dk = dKg * eG + dKend * eL
-    dG = dQg * p["Qg"] + dKg * p["Kg"] - at_end
-    d_last = (jnp.sum(at_end, axis=0, keepdims=True)
-              + jnp.exp(G[-1:]) * jnp.sum(St * dSt, axis=0, keepdims=True))
-    # the levels of A_qk and A_kk
-    on_diag = jnp.where(ri == ci, dA_qk, 0.0).astype(dtype)
-    dq = dq + _dot(on_diag, k, _NN)
-    dk = dk + _dot(on_diag, q, _TN)
-    for b in _levels(c):
-        E, qe, ke = _level_operands(qf, kf, G, b, roll, dtype)
-        mask = _level_mask(c, b)
-        Pq = jnp.where(mask, dA_qk, 0.0).astype(dtype)
-        Pk = jnp.where(mask, dA_kk, 0.0).astype(dtype)
-        rows = _dot(jnp.concatenate([Pq, Pk], axis=0), ke, _NN)  # (2 c, K): as rows
-        dqe, dke_row = rows[:c], rows[c:]
-        dke_col = _dot(jnp.concatenate([Pq, Pk], axis=0), jnp.concatenate([qe, ke], axis=0), _TN)
-        dq = dq + dqe * E
-        dk = dk + (dke_row + dke_col) * E
-        # the rounded operands, so that a pair's two shares are one number
-        # with two signs: the running sum up the rows then cancels them
-        # outside (j, i] to float32's last bits, where q E and k E unrounded
-        # leave a bf16 rounding's worth of every pair on every earlier row
-        dG = dG + dqe * qe.astype(_F32) + (dke_row - dke_col) * ke.astype(_F32)
+    dG = decay.over(dQg * p["Qg"] + dKg * p["Kg"] - at_end)
+    d_last = decay.over(jnp.sum(at_end, axis=0, keepdims=True)
+                        + jnp.exp(G[-1:]) * jnp.sum(St * dSt, axis=0, keepdims=True))
+    # the products inside the chunk, A_qk and A_kk
+    dq, dk, dG = decay.pull(q, k, p, (ri, ci), dA_qk, dA_kk, dq, dk, dG, roll)
     last = jax.lax.broadcasted_iota(jnp.int32, dG.shape, 0) == c - 1
     dG = dG + jnp.where(last, d_last, 0.0)
     return dq, dk, dv, _cumsum_rows_transposed(dG, roll), dbeta, dS_start
@@ -368,7 +399,7 @@ def _np_roll(x, s):
     return jnp.roll(x, s, axis=0)
 
 
-def kda_chunked(q, k, v, g, beta, chunk):
+def kda_chunked(q, k, v, g, beta, chunk, decay=BY_CHANNEL):
     """The chunked form chunk by chunk, differentiated by JAX: what runs
     where there is no TPU, and what the kernels are tested against. Returns
     o (b, T, H, V) in v's dtype, the float32 states at each chunk's start,
@@ -378,7 +409,8 @@ def kda_chunked(q, k, v, g, beta, chunk):
     vd, nc = v.shape[-1], t // chunk
     scale = kd ** -0.5
     by_chunk = lambda a: a.reshape(b, nc, chunk, h, -1).transpose(1, 0, 3, 2, 4)
-    one = functools.partial(_chunk_fwd, scale=scale, roll=_np_roll, exact=_dot_highest)
+    one = functools.partial(_chunk_fwd, scale=scale, roll=_np_roll, exact=_dot_highest,
+                            decay=decay)
     many = jax.vmap(jax.vmap(one))  # over batch and heads
 
     def step(St, xs):
@@ -599,9 +631,14 @@ def kda_path(seq_len: int, key_dim: int, value_dim: int, chunk: int = CHUNK) -> 
     and a chunk is whole tiles of sublanes in every dtype (T is padded to
     whole chunks, so it decides nothing)."""
     del seq_len
-    fits = key_dim == _LANES and value_dim == _LANES and chunk % 32 == 0 and not (
+    return "pallas" if _on_tpu() and kernels_take(key_dim, value_dim, chunk) else "xla"
+
+
+def kernels_take(key_dim: int, value_dim: int, chunk: int) -> bool:
+    """Whether the kernels of this frame (this file's pair, ops/gdn.py's) take
+    heads of these widths at this chunk."""
+    return key_dim == _LANES and value_dim == _LANES and chunk % 32 == 0 and not (
         chunk & (chunk - 1))
-    return "pallas" if _on_tpu() and fits else "xla"
 
 
 def _padded(t, chunk, *arrays):

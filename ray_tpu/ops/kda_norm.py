@@ -1,13 +1,14 @@
-"""The KDA mixer's head norm and its sigmoid gate between the delta rule and
-W_o, a pallas kernel pair on a TPU and the mixer's own lines in jax.numpy
-elsewhere.
+"""A delta-rule mixer's head norm and its gate between the rule and W_o
+(the KDA mixer's, whose gate is a sigmoid; the Gated DeltaNet mixer's, whose
+gate is a silu: `gate`, a static argument, names the activation), a pallas
+kernel pair on a TPU and the mixer's own lines in jax.numpy elsewhere.
 
 With o (B, T, H * 128) as `kda_fwd` wrote it, a head its 128 lanes, z (B, T,
 H * 128) the gate's pre-activation as `g_b_proj` wrote it and one weight w
 (128,) for every head:
 
     r = rsqrt(mean(o^2 over the head's lanes) + eps)       a head and token
-    y = o * r * w * sigmoid(z)
+    y = o * r * w * act(z)                 act: sigmoid, or silu (z sigmoid(z))
 
 Written as `reshape(B, T, H, 128)`, RMSNorm, `reshape` back and the gate in
 float32, the head axis takes the place of T as the second-minor one, which
@@ -41,7 +42,8 @@ anyway), n = o * r:
 
     dn = dy * s * w                        dw = sum_{b,t,head} dy * s * n
     do = r * (dn - n * mean(dn * n))       over the head's lanes
-    dz = dy * (n * w) * s * (1 - s)
+    dz = dy * (n * w) * s * (1 - s)        under silu: dn = dy * z s * w and
+                                           dz = dy * (n * w) * s * (1 + z (1 - s))
 
 dw is summed in float32 over a batch row's tiles and heads in an output block
 that stays in VMEM, eight sublanes of partial sums, and over those and the
@@ -66,13 +68,17 @@ from ray_tpu.ops.qk_prep import _lane_mean, _over, _padded, _tile
 from ray_tpu.ops.short_conv import _LANES
 
 
-def kda_norm_plain(o, z, weight, eps):
+SIGMOID, SILU = "sigmoid", "silu"  # the gate's activations
+_ACTIVATIONS = {SIGMOID: jax.nn.sigmoid, SILU: jax.nn.silu}
+
+
+def kda_norm_plain(o, z, weight, eps, gate=SIGMOID):
     """The mixer's lines before it had kernels: RMSNorm over a (..., H, W)
     view (`norm_by_group`: float32, rounded, times the weight in o's dtype),
     then the gate in float32, rounded."""
     heads = o.shape[-1] // weight.shape[0]
     normed = norm_by_group(o, jnp.tile(weight, heads), eps, heads)
-    return (normed.astype(jnp.float32) * jax.nn.sigmoid(z.astype(jnp.float32))).astype(o.dtype)
+    return (normed.astype(jnp.float32) * _ACTIVATIONS[gate](z.astype(jnp.float32))).astype(o.dtype)
 
 
 def _gate(z):
@@ -86,7 +92,7 @@ def _gate(z):
     return s, e * s
 
 
-def _fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
+def _fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, eps, gate):
     """One tile of one batch row: y of the tile, a head at a time."""
     f32 = jnp.float32
     w, mean = w_ref[...], _lane_mean()
@@ -94,13 +100,14 @@ def _fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
     def head(lanes, _, __):
         o = o_ref[0, :, lanes].astype(f32)
         n = o * jax.lax.rsqrt(mean(o * o) + eps)
-        s, _ = _gate(z_ref[0, :, lanes].astype(f32))
-        y_ref[0, :, lanes] = (n * w * s).astype(y_ref.dtype)
+        z = z_ref[0, :, lanes].astype(f32)
+        s, _ = _gate(z)
+        y_ref[0, :, lanes] = (n * w * (s if gate == SIGMOID else z * s)).astype(y_ref.dtype)
 
     _over(o_ref.shape[2] // _LANES, head)
 
 
-def _bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps):
+def _bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps, gate):
     """One tile of one batch row: do and dz of the tile, the tile's part of
     the weight's gradient added to dw_ref, eight sublanes of partial sums."""
     @pl.when(pl.program_id(1) == 0)
@@ -112,11 +119,16 @@ def _bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps):
 
     def head(lanes, _, acc):
         o, dy = o_ref[0, :, lanes].astype(f32), dy_ref[0, :, lanes].astype(f32)
-        s, rest = _gate(z_ref[0, :, lanes].astype(f32))
+        z = z_ref[0, :, lanes].astype(f32)
+        s, rest = _gate(z)
         r = jax.lax.rsqrt(mean(o * o) + eps)
         n = o * r
-        gated = dy * s
-        dz_ref[0, :, lanes] = (gated * rest * (n * w)).astype(dz_ref.dtype)
+        if gate == SIGMOID:
+            gated = dy * s
+            dz_ref[0, :, lanes] = (gated * rest * (n * w)).astype(dz_ref.dtype)
+        else:  # act = z s, act' = s (1 + z (1 - s))
+            gated = dy * (z * s)
+            dz_ref[0, :, lanes] = (dy * s * (1 + z * rest) * (n * w)).astype(dz_ref.dtype)
         dw = gated * n
         dn = gated * w
         do_ref[0, :, lanes] = (r * (dn - n * mean(dn * n))).astype(do_ref.dtype)
@@ -134,26 +146,26 @@ def _specs(o):
             pl.BlockSpec((1, _LANES), lambda i, j: (0, 0)))
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def _fwd_call(o, z, weight, *, eps, interpret):
+@functools.partial(jax.jit, static_argnames=("eps", "gate", "interpret"))
+def _fwd_call(o, z, weight, *, eps, gate, interpret):
     """y (B, T, H * 128). Under a jit of its own, as ops/qk_prep.py's calls:
     a model's layers share one trace and one lowering of a kernel."""
     grid, rows, w_row = _specs(o)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps),
+        functools.partial(_fwd_kernel, eps=eps, gate=gate),
         grid=grid, in_specs=[rows, rows, w_row], out_specs=rows,
         out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
         compiler_params=_PARAMS, interpret=interpret, name="kda_norm_fwd",
     )(o, z, weight[None])
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def _bwd_call(o, z, weight, dy, *, eps, interpret):
+@functools.partial(jax.jit, static_argnames=("eps", "gate", "interpret"))
+def _bwd_call(o, z, weight, dy, *, eps, gate, interpret):
     """(do, dz, dweight)."""
     grid, rows, w_row = _specs(o)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
     do, dz, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, eps=eps),
+        functools.partial(_bwd_kernel, eps=eps, gate=gate),
         grid=grid, in_specs=[rows, rows, w_row, rows],
         out_specs=[rows, rows, pl.BlockSpec((1, _SUBLANES, _LANES), lambda i, j: (i, 0, 0))],
         out_shape=[like(o), like(z),
@@ -163,21 +175,21 @@ def _bwd_call(o, z, weight, dy, *, eps, interpret):
     return do, dz, dw.sum((0, 1))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _kda_norm(o, z, weight, eps, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _kda_norm(o, z, weight, eps, interpret, gate=SIGMOID):
     t = o.shape[1]
-    return _fwd_call(*_padded((o, z), t), weight, eps=eps, interpret=interpret)[:, :t]
+    return _fwd_call(*_padded((o, z), t), weight, eps=eps, gate=gate, interpret=interpret)[:, :t]
 
 
-def _kda_norm_fwd_rule(o, z, weight, eps, interpret):
-    return _kda_norm(o, z, weight, eps, interpret), (o, z, weight)
+def _kda_norm_fwd_rule(o, z, weight, eps, interpret, gate):
+    return _kda_norm(o, z, weight, eps, interpret, gate), (o, z, weight)
 
 
-def _kda_norm_bwd_rule(eps, interpret, res, dy):
+def _kda_norm_bwd_rule(eps, interpret, gate, res, dy):
     o, z, weight = res
     t = o.shape[1]
     o, z, dy = _padded((o, z, dy), t)
-    do, dz, dw = _bwd_call(o, z, weight, dy, eps=eps, interpret=interpret)
+    do, dz, dw = _bwd_call(o, z, weight, dy, eps=eps, gate=gate, interpret=interpret)
     return do[:, :t], dz[:, :t], dw
 
 
@@ -190,15 +202,15 @@ def norm_path(width: int) -> str:
     return "pallas" if _on_tpu() and width == _LANES else "xla"
 
 
-def kda_norm(o, z, weight, eps, *, interpret=None):
+def kda_norm(o, z, weight, eps, *, gate=SIGMOID, interpret=None):
     """RMSNorm of each head of o's last axis on its own, times `weight` (W,)
-    float32, one for every head, times sigmoid(z), in o's dtype: o and z
-    (B, T, H * W). The kernels where `norm_path` says so; elsewhere
-    `kda_norm_plain`. `interpret` forces the kernels (True: in interpret
-    mode), for the tests."""
+    float32, one for every head, times `gate` of z (SIGMOID or SILU), in o's
+    dtype: o and z (B, T, H * W). The kernels where `norm_path` says so;
+    elsewhere `kda_norm_plain`. `interpret` forces the kernels (True: in
+    interpret mode), for the tests."""
     width, = weight.shape
-    if o.ndim != 3 or z.shape != o.shape or o.shape[-1] % width:
-        raise ValueError(f"o {o.shape}, z {z.shape}, weight {weight.shape}")
+    if o.ndim != 3 or z.shape != o.shape or o.shape[-1] % width or gate not in _ACTIVATIONS:
+        raise ValueError(f"o {o.shape}, z {z.shape}, weight {weight.shape}, gate {gate!r}")
     if (interpret is not None and width == _LANES) or norm_path(width) == "pallas":
-        return _kda_norm(o, z, weight.astype(jnp.float32), float(eps), bool(interpret))
-    return kda_norm_plain(o, z, weight, eps)
+        return _kda_norm(o, z, weight.astype(jnp.float32), float(eps), bool(interpret), gate)
+    return kda_norm_plain(o, z, weight, eps, gate)

@@ -53,6 +53,8 @@ CLASSES = ("matmul", "kernel", "copy", "collective", "elementwise")
 # themselves are `attn.core`); `moe.shared` the expert every token passes
 # through beside the routed ones; `kda` a delta-rule mixer whole
 # (models/kimi_linear.py: `kda.in_proj`, `.conv`, `.gate`, `.scan`, `.norm`,
+# `.out_proj`), and `gdn` the same of one whose decay is one number a head
+# and step (models/qwen3_next.py: `gdn.in_proj`, `.conv`, `.rule`, `.norm`,
 # `.out_proj`); `gmu` a gated memory unit, which gates another layer's scan
 # output by this layer's stream, and `attn.cross` a layer that attends over
 # another layer's keys and values, its projections and its kernels
@@ -62,7 +64,7 @@ CLASSES = ("matmul", "kernel", "copy", "collective", "elementwise")
 # a sublayer's output normed before its add: models/afmoe.py);
 # `optimizer` the clip and the global norm with AdamW.
 GROUPS = ("embed", "attn.proj", "attn.core", "attn.cross", "mla", "mlp", "moe", "moe.shared",
-          "ssm", "gmu", "kda", "conv", "norm", "head", "loss", "optimizer", "collective", "unscoped")
+          "ssm", "gmu", "kda", "gdn", "conv", "norm", "head", "loss", "optimizer", "collective", "unscoped")
 TOP_ROWS = 5  # (group, pass) rows in what rides a report and the GCS record
 SCOPE_ROWS = 40  # scope rows printed for a terminal (--json holds them all)
 KIND_ROWS = 20  # kinds of instruction kept, largest first
@@ -151,6 +153,8 @@ def group_of(scope: str, cls: str = "elementwise") -> str:
     parts = scope.split("/")
     if parts[0] in ("optimizer", "loss"):
         return parts[0]
+    if parts[0].startswith("loss."):
+        return "loss"  # a family's own objective names its loss (models/sdar.py: `loss.diffusion`)
     if any(p in _HEAD for p in parts):
         return "head"
     if any(p in _EMBED for p in parts):
@@ -167,6 +171,8 @@ def group_of(scope: str, cls: str = "elementwise") -> str:
         return "attn.cross"  # a layer that reads another layer's K and V: its projections and core
     if any(p == "kda" or p.startswith("kda.") for p in parts):
         return "kda"  # a delta-rule mixer whole: its projections, convolution, gates, scan, norm
+    if any(p == "gdn" or p.startswith("gdn.") for p in parts):
+        return "gdn"  # the same of a scalar-decay delta rule
     if any(p == "conv" or p.startswith("conv.") for p in parts):
         return "conv"
     if any(p.startswith("mla.") for p in parts):

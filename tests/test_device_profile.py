@@ -30,6 +30,10 @@ OP_NAMES = {
                                  "ln_f", "bwd", "norm"),
     "loss": ("jit(train_step)/jvp(loss)/reduce_max", "loss", "fwd", "loss"),
     "loss_backward": ("jit(train_step)/transpose(jvp(loss))/div", "loss", "bwd", "loss"),
+    "a_family_s_own_loss": ("jit(train_step)/jvp(loss.diffusion)/reduce_sum",
+                            "loss.diffusion", "fwd", "loss"),
+    "scalar_delta_rule": (T + "rematted_computation/p_0/h_2/gdn/gdn.rule/pallas_call",
+                          "p/h/gdn/gdn.rule", "remat", "gdn"),
     "optimizer": ("jit(train_step)/optimizer/jit(_where)/select_n",
                   "optimizer", "update", "optimizer"),
     "take_under_wte": ("jit(train_step)/jvp(GPT2)/wte/jit(_take)/gather", "wte", "fwd", "embed"),
@@ -213,6 +217,10 @@ def _tiny(family):
         from ray_tpu.models.sdar import SDARConfig
 
         return SDARConfig.tiny(num_held=4), True
+    if family == "qwen3_next":
+        from ray_tpu.models.qwen3_next import Qwen3NextConfig
+
+        return Qwen3NextConfig.tiny(num_held=4), True
     from ray_tpu.models.granite import GraniteConfig
 
     return GraniteConfig.tiny(), True
@@ -227,7 +235,7 @@ def _compiled_text(cfg, t=128):  # an indexed layer packs its mask 128 keys to a
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_moe", "mellum", "mellum_indexed",
                                     "granite", "lfm2", "kanana", "nemotron_h", "afmoe",
-                                    "kimi_linear", "phi4_flash", "sdar"])
+                                    "kimi_linear", "phi4_flash", "sdar", "qwen3_next"])
 def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     """The tiny configuration's step, compiled here: every scheduled
     instruction has a group of the one vocabulary and a pass, few are
@@ -252,7 +260,8 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
     want = {"gpt2": "mlp", "llama": "mlp", "gpt2_moe": "moe", "mellum": "moe",
             "mellum_indexed": "moe", "granite": "ssm", "lfm2": "conv",
             "kanana": "moe.shared", "nemotron_h": "moe.shared", "afmoe": "moe.shared",
-            "kimi_linear": "kda", "phi4_flash": "gmu", "sdar": "moe"}[family]
+            "kimi_linear": "kda", "phi4_flash": "gmu", "sdar": "moe",
+            "qwen3_next": "gdn"}[family]
     assert want in groups
     if family == "sdar":  # the objective is the family's own: its noise, its mask, its loss
         scopes = {part for r in rows.values() for part in r[0].split("/")}
@@ -273,6 +282,16 @@ def test_every_instruction_of_a_family_s_step_is_in_a_group(family):
                       "kda.out_proj", "mla.q", "attn.core", "moe.shared"):
             assert any(scope in s.split("/") for s in scopes), scope
         assert {dp.group_of(s) for s in scopes if "kda.scan" in s.split("/")} == {"kda"}
+        assert not [n for n in unscoped if rows[n][0].startswith("p")], unscoped  # none a block's
+    if family == "qwen3_next":  # the scalar delta-rule mixer by its five scopes, beside a gated attention
+        scopes = {r[0] for r in rows.values()}
+        for scope in ("gdn.in_proj", "gdn.conv", "gdn.rule", "gdn.norm", "gdn.out_proj",
+                      "attn.gate", "attn.qk_norm", "attn.rope", "moe.shared", "moe.shared_gate"):
+            assert any(scope in s.split("/") for s in scopes), scope
+        assert {dp.group_of(s) for s in scopes if "gdn.rule" in s.split("/")} == {"gdn"}
+        assert {dp.group_of(s) for s in scopes if "moe.shared_gate" in s.split("/")} == {
+            "moe.shared"}
+        assert {"moe", "moe.shared"} <= groups
         assert not [n for n in unscoped if rows[n][0].startswith("p")], unscoped  # none a block's
     if family in ("lfm2", "kanana", "afmoe", "kimi_linear"):  # dense and routed MLPs in one model
         assert {"mlp", "moe"} <= groups

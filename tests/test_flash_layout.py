@@ -65,6 +65,9 @@ WANT = {
     # T is the doubled stream's, [noised | clean] of 8,192 tokens: the block-diffusion pair
     "sdar_30b_a3b_l5_ep8.t8192": (1, 16384, 32, 128, {
         "flash_bd4_fwd": 5, "flash_bd4_bwd_fused": 5}),
+    # one attention layer of five, heads of two vregs: 2 key-value heads repeated to the 16
+    # query heads; the four DeltaNet layers' calls are no flash calls (tests/test_qwen3_next.py)
+    "qwen3_next_80b_l5_ep32.t8192": (2, 8192, 16, 256, {"flash_fwd": 1, BWD: 1}),
 }
 # the width of the score's second part, whose key all heads share, where a
 # cell's calls are the latent pair
@@ -124,6 +127,10 @@ OLD_KINDS = {
         "flash_bd4_fwd": "flash_bd4_fwd custom-call -> (bf16[32,16384,128], f32[32,1,16384])",
         "flash_bd4_bwd_fused": "flash_bd4_bwd_fused custom-call -> "
                                "(bf16[32,16384,128], bf16[32,16384,128], bf16[32,16384,128])"},
+    # nor this one: its own first trace's (my chip run, PR 64, call 1)
+    "qwen3_next_80b_l5_ep32.t8192": {
+        "flash_fwd": "flash_fwd custom-call -> (bf16[32,8192,256], f32[32,1,8192])",
+        BWD: f"{BWD} custom-call -> (bf16[32,8192,256], bf16[32,8192,256], bf16[32,8192,256])"},
 }
 # the shape function that each per-kernel roofline of bench/layer_metrics
 # names for a call it matches

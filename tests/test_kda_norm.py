@@ -187,3 +187,36 @@ def test_no_accepted_metric_s_pattern_reads_the_pair_s_calls(call):
         assert found == ["kda_norm_share_pct"], (line, found)
     assert re.search(patterns["kda_share_pct"], "kda_fwd custom-call -> (bf16[2,8192,4096])")
     assert not re.search(patterns["kda_norm_share_pct"], "kda_fwd custom-call -> bf16[2]")
+
+
+@pytest.mark.parametrize("path,heads,t", [("plain", 2, 40), ("kernels", 2, 40),
+                                          ("kernels", 32, 272), ("kernels", 3, 528)])
+def test_under_silu_against_its_plain_lines_in_float64(path, heads, t):
+    """The gate told `silu` (models/qwen3_next.py's mixer): RMSNorm_head(o) *
+    w * z sigmoid(z), y and the three gradients against the equations a head
+    at a time in float64, the plain lines and the kernels in interpret mode."""
+    o, z, w, dy = _inputs(t, heads, seed=heads + 7)
+    form = ((lambda o, z, w: kda_norm_plain(o, z, w, EPS, kn.SILU)) if path == "plain" else
+            (lambda o, z, w: kda_norm(o, z, w, EPS, gate=kn.SILU, interpret=True)))
+    got = _value_and_grads(form, o, z, w, dy)
+    with jax.enable_x64(True):
+        o64, z64, w64, dy64 = (jnp.asarray(np.asarray(v, np.float64)) for v in (o, z, w, dy))
+        y, vjp = jax.vjp(lambda o, z, w: _norm_by_head(o, z, w) * z, o64, z64, w64)
+        want = tuple(np.asarray(v) for v in (y, *vjp(dy64)))
+    _assert_close(got, want, 5e-5 if path == "kernels" else 1e-5)
+
+
+def test_under_sigmoid_the_calls_are_what_they_were():
+    """Told nothing, and told `sigmoid`, the entry gives the same bits and
+    the same two kernels (the cells' pinned steps hold their bodies); an
+    activation it has no lines for is refused."""
+    o, z, w, dy = _inputs(40, 2, dtype=jnp.bfloat16)
+    plain = _value_and_grads(lambda o, z, w: kda_norm(o, z, w, EPS, interpret=True), o, z, w, dy)
+    told = _value_and_grads(lambda o, z, w: kda_norm(o, z, w, EPS, gate=kn.SIGMOID, interpret=True),
+                            o, z, w, dy)
+    for a, b in zip(plain, told):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    text = str(jax.make_jaxpr(lambda: kda_norm(o, z, w, EPS, gate=kn.SILU, interpret=True))())
+    assert "kda_norm_fwd" in text
+    with pytest.raises(ValueError):
+        kda_norm(o, z, w, EPS, gate="tanh")
