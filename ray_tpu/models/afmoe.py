@@ -206,13 +206,16 @@ class AfmoeBlock(nn.Module):
 # (7.55 ms for 0.625 GiB alone, 9.00 beside the operands); the shared
 # expert's gate and up those two matmuls in four layers (2.19 ms for 0.25
 # GiB); the dense MLP's likewise in one (2.30 ms for 0.375 GiB). All four
-# together 429.55 ms at 13.58 GiB, over what the
-# rule is held to: on a v5e it takes the first two, 434.42 ms at 13.04 GiB
-# (call 8, the committed program with the buffers at 2.0: 429.01 ms at 13.044
-# GiB, the first rung alone 453.46 at 11.978), and since PR 62 the shared
-# expert's in the last two routed layers of four beside them (what the
-# room that is left holds: models/remat.py's depths; 0.125 GiB that cost a
-# step 0.15% on the chip, PERF.md section 6, PR 62).
+# together 429.55 ms at 13.58 GiB, over what the rule was held to until
+# PR 65, 13.5 (0.9 of a v5e's limit rounded down to 15 GiB): it took the
+# first two, 434.42 ms at 13.04 GiB (call 8, the committed program with the
+# buffers at 2.0: 429.01 ms at 13.044 GiB, the first rung alone 453.46 at
+# 11.978), and from PR 62 the shared expert's in the last two routed layers
+# of four beside them (what the room that was left held: models/remat.py's
+# depths; 0.125 GiB that cost a step 0.15% on the chip, PERF.md section 6,
+# PR 62). Since PR 65 the limit is the chip's own to within 64 MiB (14.12 of
+# room) and all four rungs are whole (it reckons 13.97; the step compiled for
+# a v5e holds 13.455).
 # The expert layer's three products (ops/moe.py:KEPT_PRODUCTS) are no rung,
 # as in models/kanana.py and for its reason: experts 1,024 wide on 1,024 rows
 # each are cheaper made again than read back in the form that reads them

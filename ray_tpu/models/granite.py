@@ -183,13 +183,16 @@ class GraniteBlock(nn.Module):
 # slices, and are not named: 3.2 ms for 0.88 GiB alone, and 0.9 ms *slower*
 # beside the scan's; the convolution's second run is a kernel's 0.11 ms a
 # layer since PR 48, less still to spare. At the cell's shape the rule's
-# bookkeeping (16 bytes a parameter: 11.5 of the 13.5 GiB) has no room for
-# both whole: until PR 62 it took the MLP's whole and no scan's; since then
-# (models/remat.py's depths) the scan's in the last eight Mamba layers of nine
-# and the MLP's in the last nine layers of ten, which by the worths below
-# spares more and on the chip cost 1.4 ms a step (-0.72%: the scan's worth
-# dates from PR 36's program and spares nothing a step today; these worths
-# are due a new reading, ROADMAP.md A7 b, PERF.md section 6, PR 62).
+# bookkeeping (16 bytes a parameter: 11.5 GiB) had no room for both whole
+# under 13.5 (0.9 of a v5e's limit rounded down to 15 GiB): until PR 62 it
+# took the MLP's whole and no scan's; from then (models/remat.py's depths)
+# the scan's in the last eight Mamba layers of nine and the MLP's in the last
+# nine layers of ten, which by the worths below spared more and on the chip
+# cost 1.4 ms a step (-0.72%: the scan's worth dates from PR 36's program and
+# spares nothing a step today; these worths are due a new reading, ROADMAP.md
+# A7 b, PERF.md section 6, PR 62). Since PR 65 the limit is the chip's own to
+# within 64 MiB (14.12 of room) and both rungs are whole (it reckons 13.68;
+# the step compiled for a v5e holds 11.25): the worths decide nothing here.
 REMAT_RUNGS = ((("ssm_y", "ssm_states"), 14.1), (("mlp_up",), 11.2))
 
 

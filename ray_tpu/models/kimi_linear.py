@@ -326,10 +326,13 @@ class KimiLinearBlock(nn.Module):
 # q and k, PR 55) for 0.625 GiB a layer (the states 0.5 of it): 27.7 ms a
 # GiB. The cell has no room for all four layers': its step holds 12.04 GiB
 # with the first rung alone (12.44 before PR 60, 13.32 before PR 55), the
-# rule reckons 12.18, the rung is 2.5 GiB and the limit 13.5. Since PR 62
-# the rule takes a rung by depth (models/remat.py) and saves them there in
-# the last three KDA layers of four (1.875 GiB; it reckons 12.94): kda_fwd
-# runs five times a step, not eight. The latent layer's operands, the shared expert's and the
+# rule reckons 12.18, the rung is 2.5 GiB and the limit was 13.5 (0.9 of a
+# v5e's 15.75 GiB rounded down to 15). Since PR 62 the rule takes a rung by
+# depth (models/remat.py) and saved them there in the last three KDA layers
+# of four (1.875 GiB; it reckoned 12.94): kda_fwd ran five times a step, not
+# eight. Since PR 65 the limit is the chip's own to within 64 MiB, 14.12 of
+# 15.69: all four layers save them (it reckons 13.57, the step compiled for
+# a v5e holds 13.93) and kda_fwd runs once a layer. The latent layer's operands, the shared expert's and the
 # dense MLP's products are rungs in models/kanana.py at these widths and
 # none here: they are one layer's, four layers' and one layer's, under 10 ms
 # of a step by kanana's readings, the reckoning would take them as free

@@ -119,8 +119,10 @@ class LlamaBlock(nn.Module):
 # kernels' second gather), the kernel's operands the q, k, v projections',
 # the rotary embedding and the repeat of the key-value heads. On a v5e the
 # cell's step has no room for both whole: until PR 62 it saved `mlp_up` whole
-# and no operand; since (models/remat.py's depths) the operands in the last
-# seven layers of eight and `mlp_up` in the last six, +1.47% on the chip.
+# and no operand; from then (models/remat.py's depths) the operands in the
+# last seven layers of eight and `mlp_up` in the last six, +1.47% on the
+# chip; since PR 65, held to the chip's own limit to within 64 MiB, the
+# operands whole and `mlp_up` in the last seven.
 REMAT_RUNGS = ((("mlp_up",), 30.7), (("attn_q", "attn_k", "attn_v"), 38.9))
 
 

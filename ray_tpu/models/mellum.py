@@ -250,9 +250,11 @@ class MellumBlock(nn.Module):
 # pays the headroom buffer's forward work on top of its own, 478.19 ms
 # against the parent's 440.55 (call 7, every layer forced to overflow).
 # On a v5e the cell has no room for all three products: until PR 62 it kept
-# the gate's and the up's whole; since (models/remat.py's depths) the down
-# product in the last three layers of four and the gate's in the last two,
-# +0.1 to +0.4% on the chip (PERF.md section 6, PR 62).
+# the gate's and the up's whole; from then (models/remat.py's depths) the
+# down product in the last three layers of four and the gate's in the last
+# two, +0.1 to +0.4% on the chip (PERF.md section 6, PR 62); since PR 65,
+# held to the chip's own limit to within 64 MiB, the down and the gate's
+# whole and the up's in the last three layers.
 REMAT_RUNGS = ((("attn_q", "attn_k", "attn_v"), 37.6),
                (("moe_gate",), 4.1), (("moe_up",), 4.1), (("moe_out",), 5.8))
 

@@ -51,7 +51,8 @@ _STATE_BYTES = (12, 4)
 # the reckoning leaves out: the compiler's temporaries beside the largest
 # one, fragmentation, and whatever else the process keeps on the chip. The
 # reckoning has read up to 0.35 GiB under and 0.85 over what a v5e's
-# allocator read (tests/test_remat.py), and 15 GiB less a tenth is 13.5.
+# allocator read of whole rungs (tests/test_remat.py; 0.7 under at a depth),
+# and a v5e's 15.6875 GiB (`chip_limit`) less a tenth is 14.119.
 _LIMIT_SHARE = 0.9
 
 
@@ -113,20 +114,25 @@ def chip_limit(stream) -> Optional[int]:
     job reckons it: the least `bytes_limit` (`device.memory_stats()`) of the
     devices this process can ask, among those the residual stream's sharding
     lies on (parallel/mesh.py:stream_sharding; None: one device, the
-    process's first), rounded down to a whole GiB. Chips of one kind read
+    process's first), rounded down to a whole 64 MiB. Chips of one kind read
     a few KiB apart from run to run (16,909,336,064 and 16,909,334,528 on
-    the v5e), and every host of a mesh has to trace the same program: the
-    rounding is what makes them agree. None where a device keeps no such
-    count (a CPU device) or none of the mesh's devices is this process's
-    (a chip that is described and not attached: tests/test_tpu_compile.py).
-    A chip that cannot say raises: a weaker plan is not taken in silence."""
+    the v5e: both 251 x 64 MiB, 15.6875 GiB), and every host of a mesh has
+    to trace the same program: the rounding is what makes them agree. It
+    costs a chip under 64 MiB; a whole GiB, the grain until PR 65, cost the
+    v5e 0.75 of its 15.75, 4.7% of the chip, for an agreement that a grain
+    a thousand times the chips' difference gives as well. None where a
+    device keeps no such count (a CPU device) or none of the mesh's devices
+    is this process's (a chip that is described and not attached:
+    tests/test_tpu_compile.py). A chip that cannot say raises: a weaker plan
+    is not taken in silence."""
     local = set(jax.local_devices())
     asked = jax.local_devices()[:1] if stream is None else [
         d for d in stream.mesh.devices.flat if d in local]
     limits = [(d.memory_stats() or {}).get("bytes_limit") for d in asked]
     if not limits or None in limits:
         return None
-    return min(limits) // GIB * GIB
+    grain = GIB // 16  # 64 MiB
+    return min(limits) // grain * grain
 
 
 class Held(NamedTuple):

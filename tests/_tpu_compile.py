@@ -1,17 +1,62 @@
 """What the files that compile for a described (not attached) TPU v5e share
 (tests/test_tpu_compile*.py; the `topo`, `one_chip` and `mesh_2x2` fixtures are
-tests/conftest.py's): shapes to lower with, and what is read off a compiled
-program."""
+tests/conftest.py's): the described chip's memory, stated once for every test
+file that plans a step for it, shapes to lower with, and what is read off a
+compiled program."""
 
 import collections
 import json
 import os
 import re
+import types
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models import remat
 
 GIB = 1 << 30
+
+
+class Chip:
+    """A device as `remat.chip_limit` sees one: `limit` its `bytes_limit`
+    (None: it keeps no such count; an exception: it cannot be asked)."""
+
+    def __init__(self, limit):
+        self.limit = limit
+
+    def memory_stats(self):
+        if isinstance(self.limit, Exception):
+            raise self.limit
+        return None if self.limit is None else {"bytes_limit": self.limit}
+
+
+def stream_on(chips):
+    """A residual stream's sharding over these devices, as far as
+    `remat.chip_limit` reads one."""
+    mesh = types.SimpleNamespace(devices=np.array(chips, object), shape={"fsdp": len(chips)})
+    return types.SimpleNamespace(mesh=mesh)
+
+
+def chip_limit_of(*readings):
+    """What the rule makes of a process whose chips read so: `chip_limit`'s
+    own rounding, not a copy of it."""
+    chips = [Chip(reading) for reading in readings]
+    with mock.patch.object(jax, "local_devices", lambda: chips):
+        return remat.chip_limit(stream_on(chips))
+
+
+# `bytes_limit` of a TPU v5e chip, as device.memory_stats() gave it in the
+# chip runs of PR 33 (15.748 GiB), and in one of them; the limit the rule
+# makes of either (251 x 64 MiB = 15.6875 GiB since PR 65; 15 GiB before),
+# which is what a test hands `remat_plan` or patches `remat.chip_limit` to
+# for the described v5e; and the room a plan's reckoned total is held to
+# under it (14.119 GiB; 13.5 before).
+V5E_READINGS = (16909336064, 16909334528)
+V5E_LIMIT = chip_limit_of(V5E_READINGS[0])
+V5E_ROOM = int(V5E_LIMIT * remat._LIMIT_SHARE)
 
 
 def _qkv(shape, sharding):

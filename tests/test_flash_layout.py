@@ -29,6 +29,7 @@ from ray_tpu.models import remat
 from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
+from tests._tpu_compile import V5E_LIMIT
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
@@ -147,7 +148,7 @@ def _lowered(cell, monkeypatch):
     """The cell's step lowered for a TPU, on its own mesh, under the remat
     rule's choice for a v5e, as tests/test_mellum.py lowers the pinned ones."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * remat.GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     with open(os.path.join(ROOT, "bench", "configs", CELLS[cell]["config"] + ".json")) as f:
         sizes = json.load(f)
     with open(os.path.join(ROOT, "bench", "traffic", CELLS[cell]["traffic"] + ".json")) as f:

@@ -29,6 +29,7 @@ from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
 from tests.test_lfm2 import _batch, _experts_by_hand, _with_bias  # the sigmoid router's other family
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = families.load("kanana")
@@ -428,14 +429,14 @@ def test_remat_plan_of_the_cell():
     rungs fits. The block's working set is stated from the widths."""
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     shape = remat.StepShape(2, 8192)
-    chosen = kanana.remat_plan(cfg, shape, 15 * GIB)
+    chosen = kanana.remat_plan(cfg, shape, V5E_LIMIT)
     first = remat.FIRST_RUNG + ("moe_plan",)  # the routed layers' choices and plans with it
     assert chosen.names[:3] == first
     # every rung of the family; the expert layer's products are none of them
     # (REMAT_RUNGS says why) and its layers are told so
     assert set(chosen.names[3:]) == {n for names, _ in kanana.REMAT_RUNGS for n in names}
     assert not set(chosen.names) & set(KEPT_PRODUCTS)
-    assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
+    assert chosen.reckoned_bytes <= chosen.limit_bytes == V5E_ROOM
     tokens = 2 * 8192
     assert chosen.block_bytes == tokens * (2 * 32 * (2 * 128 + 64 + 2 * 128) * 2
                                            + 6 * 4 * 2048 * 2) == tokens * 172_032
@@ -444,12 +445,12 @@ def test_remat_plan_of_the_cell():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(kanana, "REMAT_RUNGS", ())
         pair = tokens * 32 * 128 * 2 + tokens * 32 * 4
-        assert kanana.remat_plan(cfg, shape, 15 * GIB).layer_bytes == (
+        assert kanana.remat_plan(cfg, shape, V5E_LIMIT).layer_bytes == (
             (pair,) + (pair + tokens * 6 * 21,) * 4)
     # every rung whole: a depth is out of the layers that make the rung's names
     assert [(k, of) for _, k, of in chosen.depths] == [(5, 5), (4, 4), (1, 1)]
     assert kanana.remat_plan(cfg, shape, None).names == first
-    assert kanana.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
+    assert kanana.remat_plan(cfg, remat.StepShape(8, 8192), V5E_LIMIT).names == first
 
 
 def test_the_cell_s_step_runs_the_latent_pair_once_a_layer(monkeypatch):
@@ -489,7 +490,7 @@ def test_the_sigmoid_router_s_other_cell_lowers_to_the_parent_s_step(monkeypatch
 
     for mod in (attention, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     sizes = _sizes(rehearse=False, name="lfm2_8b_a1b_l5_ep4")
     cfg = families.load(sizes["family"]).build(sizes, "bfloat16")
     ts = TrainStep(cfg, make_mesh(sizes["mesh"], devices=jax.devices()[:1]), telemetry=False)
@@ -512,7 +513,7 @@ KANANA_STEP = "170bafe97d95fb1c34c43092ca728cd6640b9a8b54c0261869aa7a1a14ae07b9"
 def _cell_step(monkeypatch):
     """(cfg, the cell's step traced for a TPU on this box under a v5e's limit)."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))

@@ -23,6 +23,7 @@ from ray_tpu.ops import attention, indexer
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
+from tests._tpu_compile import V5E_LIMIT
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = families.load("keye")
@@ -183,7 +184,7 @@ def test_flops_per_token_at_the_cell_s_size():
 
 def test_remat_plan_of_the_cell_keeps_the_selection():
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
-    plan = mellum.remat_plan(cfg, remat.StepShape(1, 16384), 15 * GIB)
+    plan = mellum.remat_plan(cfg, remat.StepShape(1, 16384), V5E_LIMIT)
     # the expert layer's choices and plan in the first rung beside the
     # selection, and its three products after the kernel's operands (PR 45)
     assert plan.names == ("attn_out", "attn_lse", "moe_plan", "attn_sel", "attn_q", "attn_k",
@@ -195,12 +196,13 @@ def test_remat_plan_of_the_cell_keeps_the_selection():
     assert mellum.remat_plan(cfg, remat.StepShape(1, 16384), None).names == \
         ("attn_out", "attn_lse", "moe_plan", "attn_sel")
     # a sequence of top_k keys or fewer selects nothing and holds no mask
-    assert "attn_sel" not in mellum.remat_plan(cfg, remat.StepShape(8, 2048), 15 * GIB).names
+    assert "attn_sel" not in mellum.remat_plan(cfg, remat.StepShape(8, 2048), V5E_LIMIT).names
     # the other family of this file is as it was
     old = mellum.remat_plan(MellumConfig(num_held=16, vocab_size=24576),
-                            remat.StepShape(2, 8192), 15 * GIB)
+                            remat.StepShape(2, 8192), V5E_LIMIT)
     assert old.names == remat.FIRST_RUNG + ("moe_plan", "attn_q", "attn_k", "attn_v", "moe_gate",
-                                            "moe_out") and old.sel_bytes == 0
+                                            "moe_up", "moe_out") and old.sel_bytes == 0
+    assert old.depth("moe_up") == 3  # of four: the gate's and the down product whole beside it
 
 
 def test_the_cell_s_step_selects_once_a_layer(monkeypatch):
@@ -215,7 +217,7 @@ def test_the_cell_s_step_selects_once_a_layer(monkeypatch):
     from tests.test_qk_prep import heads_stay_where_written
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
@@ -273,7 +275,7 @@ def test_the_fourth_old_cell_lowers_to_the_parent_s_step(monkeypatch):
     from tests.test_mellum import _step_text
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     with open(os.path.join(ROOT, "bench", "configs", "gpt2_small.json")) as f:
         sizes = json.load(f)
     cfg = families.load(sizes["family"]).build(sizes, "bfloat16")
@@ -305,7 +307,7 @@ def test_the_cell_lowers_to_its_pinned_step(monkeypatch):
     from tests.test_mellum import _step_text
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))

@@ -23,6 +23,7 @@ from ray_tpu.ops.moe import SELECTION_BIAS, SELECTION_BIAS_RATE, SIGMOID, Expert
 from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = families.load("lfm2")
@@ -340,10 +341,10 @@ def test_remat_plan_of_the_cell():
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     shape = remat.StepShape(2, 8192)
     first = remat.FIRST_RUNG + ("moe_plan",)
-    chosen = lfm2.remat_plan(cfg, shape, 15 * GIB)
+    chosen = lfm2.remat_plan(cfg, shape, V5E_LIMIT)
     assert chosen.names[:3] == first and set(chosen.names[3:]) == {
         "conv_bcu", "conv_y", "mlp_up", "attn_q", "attn_k", "attn_v", *moe.KEPT_PRODUCTS}
-    assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
+    assert chosen.reckoned_bytes <= chosen.limit_bytes == V5E_ROOM
     tokens = 2 * 8192
     # since PR 44 the gradient's rows are gathered in the stream's dtype
     assert chosen.block_bytes == tokens * (2 * 4 * 2048 * 2 + 4 * 2 * (6 * 2048 + 6 * 1792)) \
@@ -366,7 +367,7 @@ def test_remat_plan_of_the_cell():
     # every rung whole: a depth is out of the layers that make the rung's names
     assert [(k, of) for _, k, of in chosen.depths] == [(4, 4), (1, 1), (1, 1), (4, 4), (4, 4), (4, 4)]
     assert lfm2.remat_plan(cfg, shape, None).names == first
-    assert lfm2.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
+    assert lfm2.remat_plan(cfg, remat.StepShape(8, 8192), V5E_LIMIT).names == first
 
 
 def test_the_cell_s_step_runs_the_kernels_as_its_plan_says(monkeypatch):
@@ -376,7 +377,7 @@ def test_the_cell_s_step_runs_the_kernels_as_its_plan_says(monkeypatch):
     one attention layer with the causal flash pair, and megablox's calls."""
     for mod in (attention, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))

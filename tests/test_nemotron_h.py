@@ -30,6 +30,7 @@ from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
 from tests.test_lfm2 import _batch, _with_bias  # the sigmoid router's first family
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = families.load("nemotron_h")
@@ -408,12 +409,12 @@ def test_remat_plan_of_the_cell():
     rungs fits. The block's working set is stated from the widths."""
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     shape = remat.StepShape(2, 8192)
-    chosen = nemotron_h.remat_plan(cfg, shape, 15 * GIB)
+    chosen = nemotron_h.remat_plan(cfg, shape, V5E_LIMIT)
     first = remat.FIRST_RUNG + ("moe_plan",)  # the expert layers' choices and plans with it
     assert chosen.names[:3] == first
     assert set(chosen.names[3:]) <= {n for names, _ in nemotron_h.REMAT_RUNGS for n in names}
     assert "moe_gate" not in chosen.names  # experts of two matrices have no such product
-    assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
+    assert chosen.reckoned_bytes <= chosen.limit_bytes == V5E_ROOM
     tokens = 2 * 8192
     # the expert block's is the largest: a row an assignment as wide as the
     # stream four times over, and the shared expert's up product four times
@@ -424,12 +425,12 @@ def test_remat_plan_of_the_cell():
         # the one attention layer's output and logsumexp, the four expert layers'
         # choices and plans (five int32 and a bool an assignment); a Mamba layer's nothing
         routed, pair = tokens * 6 * 21, tokens * 32 * 128 * 2 + tokens * 32 * 4
-        assert nemotron_h.remat_plan(cfg, shape, 15 * GIB).layer_bytes == (
+        assert nemotron_h.remat_plan(cfg, shape, V5E_LIMIT).layer_bytes == (
             0, routed, 0, routed, 0, pair, routed, 0, routed)  # MEMEM*EME
     # every rung whole: a depth is out of the layers that make the rung's names
     assert [(k, of) for _, k, of in chosen.depths] == [(4, 4), (1, 1), (4, 4)]
     assert nemotron_h.remat_plan(cfg, shape, None).names == first
-    assert nemotron_h.remat_plan(cfg, remat.StepShape(8, 8192), 15 * GIB).names == first
+    assert nemotron_h.remat_plan(cfg, remat.StepShape(8, 8192), V5E_LIMIT).names == first
 
 
 # This family's own cell (B=2 x T=8192, one chip, a v5e's limit for the remat
@@ -454,7 +455,7 @@ def test_the_cell_s_step_runs_the_scan_s_kernels_and_two_matrices_an_expert(monk
 
     for mod in (attention, ssd, short_conv, gated_norm):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     assert gated_norm.norm_path(cfg.ssm_inner // cfg.ssm_groups) == "pallas"
     assert ssd.ssd_path(8192, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_chunk) \

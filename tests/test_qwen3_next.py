@@ -27,6 +27,7 @@ from ray_tpu.parallel.mesh import kernel_tally, make_mesh
 from ray_tpu.parallel.train_step import TrainStep
 from ray_tpu.train import _telemetry
 from tests.test_lfm2 import _batch
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = families.load("qwen3_next")
@@ -380,7 +381,7 @@ def _cell_step(monkeypatch):
     """(cfg, the cell's step traced for a TPU on this box under a v5e's limit)."""
     for mod in (attention, short_conv, gdn, kda_norm):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     ts = TrainStep(cfg, make_mesh({"dp": 1}, devices=jax.devices()[:1]), telemetry=False)
     state = jax.eval_shape(ts._init, jax.random.PRNGKey(0))
@@ -439,14 +440,14 @@ def test_remat_plan_of_the_cell():
     limit the first rung alone."""
     cfg = FAMILY.build(_sizes(rehearse=False), "bfloat16")
     shape = remat.StepShape(2, 8192)
-    chosen = qwen3_next.remat_plan(cfg, shape, 15 * GIB)
+    chosen = qwen3_next.remat_plan(cfg, shape, V5E_LIMIT)
     first = remat.FIRST_RUNG + ("moe_plan",)
     # beside 7.64 GiB of state the delta rule's outputs (2.5 GiB over four layers) have room
     # in every DeltaNet layer, and the attention layer's gate beside them
     assert chosen.names == first + ("gdn_out", "gdn_states", "attn_gate")
     assert chosen.depths == ((("gdn_out", "gdn_states"), 4, 4), (("attn_gate",), 1, 1))
     assert chosen.saved_in("gdn_states") == (True,) * 5  # the fourth makes none
-    assert chosen.reckoned_bytes <= chosen.limit_bytes == int(13.5 * GIB)
+    assert chosen.reckoned_bytes <= chosen.limit_bytes == V5E_ROOM
     assert chosen.reckoned_bytes / GIB == pytest.approx(12.19, abs=0.01)
     tokens = 2 * 8192
     assert chosen.block_bytes == tokens * 10 * 4 * 2048 * 2  # the expert layer's buffers
@@ -456,7 +457,7 @@ def test_remat_plan_of_the_cell():
         routed + 2 * tokens * 16 * 256 * 2 + tokens * 16 * 4, routed + rule)
     assert qwen3_next.remat_plan(cfg, shape, None).names == first
     # a shape with no room for all four layers' takes some
-    tight = qwen3_next.remat_plan(cfg, remat.StepShape(4, 8192), 15 * GIB)
+    tight = qwen3_next.remat_plan(cfg, remat.StepShape(4, 8192), V5E_LIMIT)
     assert tight.depth("gdn_states") < 4
 
 

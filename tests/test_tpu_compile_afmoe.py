@@ -12,7 +12,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import GIB, _CUSTOM_CALL, _kinds, _live_bytes, _loss, _qkv, _step_args, cell_config
+from tests._tpu_compile import (GIB, V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL, _kinds, _live_bytes, _loss,
+                                _qkv, _step_args, cell_config)
 
 
 def test_windowed_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -65,7 +66,7 @@ def test_qk_prep_pair_compiles_at_the_cells_shapes(one_chip, b, t):
 def test_afmoe_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, rungs):
     """trinity_mini_l5_ep16.t8192's whole step compiled for the described v5e,
     with the plan the rule takes under a v5e's limit and with the first rung
-    alone: the program holds less than the 13.5 GiB the rule is held to and
+    alone: the program holds less than the 14.12 GiB the rule is held to (13.5 until PR 65) and
     within the error the reckoning has shown of what it reckoned
     (tests/test_remat.py: 0.35 GiB under to 0.85 over); four layers run the
     windowed pair at window 2,048 and one the causal pair, the expert layers
@@ -75,7 +76,7 @@ def test_afmoe_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, rung
     from ray_tpu.train._device_profile import scope_table
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     if rungs == "the_first_rung_alone":
         monkeypatch.setattr(afmoe, "REMAT_RUNGS", ())
     cfg = cell_config("trinity_mini_l5_ep16")
@@ -88,7 +89,7 @@ def test_afmoe_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, rung
     else:
         assert set(plan.names) > first | {"attn_q", "attn_k", "attn_v"}, plan
     live = _live_bytes(c)
-    assert live < 13.5 * GIB, c.memory_analysis()
+    assert live < V5E_ROOM, c.memory_analysis()
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     text = c.as_text()
     kinds = _kinds(text)

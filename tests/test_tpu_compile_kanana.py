@@ -10,8 +10,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import (GIB, _CUSTOM_CALL, _bytes_accessed, _kinds, _live_bytes, _step_args,
-                                cell_config)
+from tests._tpu_compile import (GIB, V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL, _bytes_accessed, _kinds,
+                                _live_bytes, _step_args, cell_config)
 
 
 def test_latent_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -42,7 +42,7 @@ def test_latent_kernels_compile_at_the_cell_s_shape(one_chip):
 def test_kanana_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     """kanana2_30b_l5_ep8.t8192's whole step compiled for the described v5e:
     the rule takes every rung at this shape, the program holds less than the
-    13.5 GiB the rule is held to and within the error the reckoning has shown
+    14.12 GiB the rule is held to (13.5 until PR 65) and within the error the reckoning has shown
     of what it reckoned (tests/test_remat.py: 0.35 GiB under to 0.85 over),
     five layers run each latent kernel once and no plain causal call, the
     bias's update is part of the one program, and the step's `bytes accessed`
@@ -53,7 +53,7 @@ def test_kanana_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     from ray_tpu.models import remat
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = cell_config("kanana2_30b_l5_ep8")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (2, 8192))).compile()
@@ -62,7 +62,7 @@ def test_kanana_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
         "attn_q", "attn_k", "attn_v", "attn_q_shared", "attn_k_shared", "shared_up", "mlp_up",
         "moe_plan"}  # the expert layers' choices and plans, since PR 45
     live = _live_bytes(c)
-    assert live < 13.5 * GIB, c.memory_analysis()
+    assert live < V5E_ROOM, c.memory_analysis()
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     assert _bytes_accessed(c) / 1e9 <= 321.50 - 5 * 3.9 + 2.0, _bytes_accessed(c)
     kinds = _kinds(c.as_text())

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests._tpu_compile import _CUSTOM_CALL
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL
 
 
 def test_delta_rule_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -60,12 +60,16 @@ def test_head_norm_s_pair_compiles_at_the_cell_s_shape(one_chip):
 
 @pytest.mark.slow  # 60 s: the lowered step's tally and hash are tests/test_kimi_linear.py's, fast
 @pytest.mark.timeout(600)
-@pytest.mark.parametrize("layers,read_gib", [("last", 13.647), ("first", 13.94)])
+@pytest.mark.parametrize("layers,read_gib", [("all", 13.934), ("last", 13.647), ("first", 13.94)])
 def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, layers, read_gib):
     """kimi_linear_l5_ep32.t8192's whole step compiled for the described v5e:
-    the rule takes the first rung and the delta rule's outputs in the last
-    three KDA layers of four at this shape (all four's do not fit beside 8.98
-    GiB of state), and the program holds what my compile of PR 63 read, 13.647
+    the rule takes the first rung and the delta rule's outputs in all four
+    KDA layers at this shape (`all`: since PR 65, under the chip's own limit
+    to within 64 MiB), and the program holds what my compile of PR 65 read,
+    13.934 GiB: 0.37 over the reckoning (13.567). Under a limit of 15 GiB, the
+    v5e's until then, it took the last three layers' (`last`: all four's did
+    not fit beside 8.98 GiB of state under 13.5), and the program held what
+    my compile of PR 63 read, 13.647
     GiB (PR 62's read 13.575: since PR 63 the latent layer cuts its
     projections on their weights, and the step compiled holds 0.07 GiB
     more): 0.705 over the reckoning, where every case before the rule took a
@@ -75,7 +79,7 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     same step holds 0.3 GiB more, which is why the rule takes the last: their
     backward runs first and lets go of them before most gradients exist. Four
     KDA layers run kda_bwd once, the
-    three that save kda_fwd once and the other twice, the head
+    ones that save kda_fwd once and the other twice, the head
     norm's pair after them (kda_norm_bwd once, kda_norm_fwd twice), the
     convolution's pair beside them, one layer the latent pair, the bias's
     update is part of the one program, and under `kda.conv` the compiled
@@ -99,7 +103,8 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
 
     for mod in (attention, kda, kda_norm, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    limit = V5E_LIMIT if layers == "all" else 15 * GIB
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: limit)
     if layers == "first":  # the rule's layers taken from the other end: `plan` sorts them, the last first
         monkeypatch.setattr(remat, "sorted", lambda of, key=None, reverse=False: builtins.sorted(
             of, key=key), raising=False)
@@ -108,24 +113,29 @@ def test_kimi_linear_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch
     c = ts._step.lower(*_step_args(ts, (2, 8192))).compile()
     plan = remat.traced(cfg)
     assert plan.names == remat.FIRST_RUNG + ("moe_plan", "kda_out", "kda_states")
-    assert plan.depth("kda_states") == 3
+    depth = 4 if layers == "all" else 3
+    assert plan.depth("kda_states") == depth
     # layers kda, kda, kda, mla, kda: the fourth makes none, and what a layer does not make it may save
-    assert plan.saved_in("kda_states") == {"last": (False, True, True, True, True),
+    assert plan.saved_in("kda_states") == {"all": (True,) * 5,
+                                           "last": (False, True, True, True, True),
                                            "first": (True, True, True, True, False)}[layers]
     live = _live_bytes(c)
-    assert live < 14.0 * GIB, c.memory_analysis()
-    assert plan.reckoned_bytes <= 13.5 * GIB
+    assert live < V5E_ROOM, c.memory_analysis()
+    assert plan.reckoned_bytes <= plan.limit_bytes == (V5E_ROOM if layers == "all" else 13.5 * GIB)
     # 13.647 GiB where the rule reckons 12.94: with the outputs saved in the last 0, 1, 2 and 3
     # layers the compiler counted 12.30, 12.33, 12.95 and 13.575, the rule 12.18, 12.18, 12.32
     # and 12.94 (my compiles, PR 62; PR 63's step holds 0.07 more at depth 3, 0.06 with `first`):
     # past the first layer's the compiled step holds every saved
     # byte beside its fullest moment, where `Held.total` lets the gradients' room take them
     # (PERF.md section 7). The case is held to its reading, not to a wider band for all.
+    # With all four layers' (PR 65) the compiler counted 13.934 where the rule reckons 13.567:
+    # the first layer's 0.625 GiB cost the compiled step 0.29, so 0.37 over.
     assert abs(live / GIB - read_gib) <= 0.05, (plan, c.memory_analysis())
-    assert (live - plan.reckoned_bytes <= 0.75 * GIB) == (layers == "last")
+    assert (live - plan.reckoned_bytes <= 0.75 * GIB) == (layers != "first")
+    assert (live - plan.reckoned_bytes <= 0.40 * GIB) == (layers == "all")
     kinds = _kinds(c.as_text())
     assert {k: n for k, n in kinds.items() if "kda" in k or "conv" in k or "flash" in k} == {
-        "kda_fwd": 8 - 3, "kda_bwd": 4, "kda_norm_fwd": 8, "kda_norm_bwd": 4,
+        "kda_fwd": 8 - depth, "kda_bwd": 4, "kda_norm_fwd": 8, "kda_norm_bwd": 4,
         "causal_conv_fwd": 8, "causal_conv_bwd": 4,
         "flash_mla_fwd": 1, "flash_mla_bwd_fused": 1}, kinds
     assert kinds["gmm"] and kinds["tgmm"]

@@ -10,7 +10,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import GIB, _CUSTOM_CALL, _kinds, _live_bytes, _step_args, cell_config
+from tests._tpu_compile import (GIB, V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL, _kinds, _live_bytes,
+                                _step_args, cell_config)
 
 
 def test_gated_conv_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -40,7 +41,7 @@ def test_gated_conv_kernels_compile_at_the_cell_s_shape(one_chip):
 def test_lfm2_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     """lfm2_8b_a1b_l5_ep4.t8192's whole step compiled for the described v5e:
     the rule takes every rung at this shape, the program holds less than the
-    13.5 GiB the rule is held to and within the error the reckoning has shown
+    14.12 GiB the rule is held to (13.5 until PR 65) and within the error the reckoning has shown
     of what it reckoned (tests/test_remat.py: 0.35 GiB under to 0.85 over),
     four conv layers run each kernel once, and the bias's update is part of
     the one program."""
@@ -49,7 +50,7 @@ def test_lfm2_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
 
     for mod in (attention, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = cell_config("lfm2_8b_a1b_l5_ep4")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (2, 8192))).compile()
@@ -58,7 +59,7 @@ def test_lfm2_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
         "conv_bcu", "conv_y", "mlp_up", "attn_q", "attn_k", "attn_v",
         "moe_plan", "moe_gate", "moe_up", "moe_out"}  # the expert layer's, since PR 45
     live = _live_bytes(c)
-    assert live < 13.5 * GIB, c.memory_analysis()
+    assert live < V5E_ROOM, c.memory_analysis()
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     kinds = _kinds(c.as_text())
     conv = {k: n for k, n in kinds.items() if "gated_conv" in k}

@@ -13,22 +13,25 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import (GIB, KERNELS, _CUSTOM_CALL, _kinds, _live_bytes, _loss, _qkv,
-                                _step_args, cell_config)
+from tests._tpu_compile import (GIB, V5E_LIMIT, V5E_ROOM, KERNELS, _CUSTOM_CALL, _kinds, _live_bytes,
+                                _loss, _qkv, _step_args, cell_config)
 
 
 @pytest.mark.slow  # 120 and 100 s: the lowered step's hash is tests/test_mellum.py's PINNED_STEPS["mellum2_12b_l4_ep4"], its bytes tests/test_remat.py's, fast
 @pytest.mark.timeout(600)
-@pytest.mark.parametrize("rows,kept,grouped", [(2, ("moe_gate", "moe_out"), 60 + 3 + 2 + 1 + 1),
+@pytest.mark.parametrize("rows,kept,grouped", [(2, ("moe_gate", "moe_up", "moe_out"), 60 + 1),
                                                (4, (), 4 * 18)])
 def test_mellum_step_holds_what_the_rule_s_block_term_books(topo, monkeypatch, rows, kept, grouped):
     """mellum2_12b_l4_ep4.t8192's whole step compiled for the described v5e
     at the cell's rows and at twice them, the `block` term as PR 45 fitted
     it again (6.5 buffers of a row an assignment). At the cell's rows the rule
-    keeps the kernel's operands, the expert layer's down product in the last
-    three layers of four and its gate product in the last two (since PR 62,
-    when it took a rung by depth; the gate and up products whole before):
-    the program holds less than 14 GiB and stands within the error the
+    keeps the kernel's operands, the expert layer's down and gate products
+    whole and its up product in the last three layers of four (since PR 65,
+    under the chip's own limit to within 64 MiB; the down product in three
+    layers and the gate's in two under 15 GiB, since PR 62 took a rung by
+    depth; the gate and up products whole before): the program holds less
+    than the 14.12 GiB the rule is held to (14.044 by my compile of PR 65,
+    where it reckons 14.085) and stands within the error the
     reckoning has shown of what it reckoned. At
     twice the rows no further rung fits, the first rung is taken whatever it
     costs, and the reckoning stands over the program (16.7 against 14.7: a
@@ -39,7 +42,7 @@ def test_mellum_step_holds_what_the_rule_s_block_term_books(topo, monkeypatch, r
     from ray_tpu.models import remat
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = cell_config("mellum2_12b_l4_ep4")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (rows, 8192))).compile()
@@ -47,7 +50,7 @@ def test_mellum_step_holds_what_the_rule_s_block_term_books(topo, monkeypatch, r
     assert tuple(n for n in plan.names if n.startswith("moe_")) == ("moe_plan",) + kept
     live = _live_bytes(c)
     if kept:
-        assert live < 14 * GIB, c.memory_analysis()
+        assert live < V5E_ROOM, c.memory_analysis()
         assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     else:
         assert plan.names == remat.FIRST_RUNG + ("moe_plan",)

@@ -12,7 +12,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import GIB, _CUSTOM_CALL, _kinds, _live_bytes, _step_args, cell_config
+from tests._tpu_compile import (GIB, V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL, _kinds, _live_bytes,
+                                _step_args, cell_config)
 
 
 def test_scan_kernels_compile_at_eight_groups_and_the_cell_s_shape(one_chip):
@@ -133,7 +134,7 @@ def test_experts_of_two_matrices_compile_at_the_cell_s_size(one_chip, monkeypatc
 @pytest.mark.timeout(600)
 def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
     """nemotron3_nano_l9_ep16.t8192's whole step compiled for the described
-    v5e: the program holds less than the 13.5 GiB the rule is held to and
+    v5e: the program holds less than the 14.12 GiB the rule is held to (13.5 until PR 65) and
     within the error the reckoning has shown of what it reckoned
     (tests/test_remat.py: 0.35 GiB under to 0.85 over), the four Mamba layers
     run the scan's kernels (no einsum form of it), the convolution's and the
@@ -145,14 +146,14 @@ def test_nemotron_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch):
 
     for mod in (attention, ssd, short_conv, gated_norm):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = cell_config("nemotron3_nano_l9_ep16")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (2, 8192))).compile()
     plan = remat.traced(cfg)
     assert set(plan.names) >= set(remat.FIRST_RUNG) | {"moe_plan"}
     live = _live_bytes(c)
-    assert live < 13.5 * GIB, c.memory_analysis()
+    assert live < V5E_ROOM, c.memory_analysis()
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     kinds = _kinds(c.as_text())
     scans = {k: n for k, n in kinds.items() if "ssd_" in k}

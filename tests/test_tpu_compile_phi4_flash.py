@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests._tpu_compile import _CUSTOM_CALL
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL
 
 
 def test_selective_scan_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -35,16 +35,18 @@ def test_selective_scan_kernels_compile_at_the_cell_s_shape(one_chip):
 
 @pytest.mark.slow  # the lowered step's tally and hash are tests/test_phi4_flash.py's, fast
 @pytest.mark.timeout(900)
-@pytest.mark.parametrize("layers,read_gib", [("last", 12.53), ("first", 13.69)])
+@pytest.mark.parametrize("layers,read_gib", [("last", 13.155), ("first", 14.31)])
 def test_phi4_flash_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch, layers, read_gib):
     """phi4_mini_flash_l5.t16384's whole step compiled for the described v5e:
     the rule takes the first rung, the scan's output and states and the
-    MLP's product in the last three layers of five at this shape (12.53 GiB
-    by the compiler's count where the rule reckons 13.30; with the product in
-    0, 1, 2, 4 and 5 layers 12.125, 12.125, 12.14, 13.155 and 13.78 against
-    12.66, 12.66, 12.67, 13.92 and 14.55: my compiles, PR 62), the program
+    MLP's product in the last four layers of five at this shape (13.155 GiB
+    by the compiler's count where the rule reckons 13.92, PR 62's compile and
+    mine of PR 65 alike; with the product in 0, 1, 2, 3 and 5 layers 12.125,
+    12.125, 12.14, 12.53 and 13.78 against 12.66, 12.66, 12.67, 13.30 and
+    14.55: my compiles, PR 62; three layers were the rule's until PR 65, under
+    a limit of 15 GiB), the program
     holds within the error the reckoning has shown of
-    what it reckoned (tests/test_remat.py); with the first three layers
+    what it reckoned (tests/test_remat.py); with the first four layers
     saving in their place (`first`: no rule takes those) the same step holds
     1.16 GiB more, which is why the rule takes the last; the Mamba layer runs sscan_fwd
     and sscan_bwd once each with the convolution's pair beside them, and
@@ -62,7 +64,7 @@ def test_phi4_flash_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch,
 
     for mod in (attention, selective_scan, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     if layers == "first":  # the rule's layers taken from the other end: `plan` sorts them, the last first
         monkeypatch.setattr(remat, "sorted", lambda of, key=None, reverse=False: builtins.sorted(
             of, key=key), raising=False)
@@ -71,13 +73,13 @@ def test_phi4_flash_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch,
     c = ts._step.lower(*_step_args(ts, (1, 16384))).compile()
     plan = remat.traced(cfg)
     assert plan.names == remat.FIRST_RUNG + ("sscan_y", "sscan_states", "mlp_up")
-    assert plan.depth("sscan_y") == 1 and plan.depth("mlp_up") == 3
-    assert plan.saved_in("mlp_up") == {"last": (False, False, True, True, True),
-                                       "first": (True, True, True, False, False)}[layers]
+    assert plan.depth("sscan_y") == 1 and plan.depth("mlp_up") == 4
+    assert plan.saved_in("mlp_up") == {"last": (False, True, True, True, True),
+                                       "first": (True, True, True, True, False)}[layers]
     live = _live_bytes(c)
-    assert live < 14.0 * GIB, c.memory_analysis()
+    assert live < V5E_ROOM or layers == "first", c.memory_analysis()
     assert abs(live / GIB - read_gib) <= 0.05, (plan, c.memory_analysis())
-    assert plan.reckoned_bytes <= 13.5 * GIB
+    assert plan.reckoned_bytes <= V5E_ROOM
     assert -0.85 * GIB <= live - plan.reckoned_bytes, (plan, c.memory_analysis())
     assert (live - plan.reckoned_bytes <= 0.35 * GIB) == (layers == "last")
     kinds = _kinds(c.as_text())

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests._tpu_compile import _CUSTOM_CALL
+from tests._tpu_compile import V5E_LIMIT, V5E_ROOM, _CUSTOM_CALL
 
 
 def test_delta_rule_kernels_compile_at_the_cell_s_shape(one_chip):
@@ -94,7 +94,7 @@ def test_qwen3_next_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch)
 
     for mod in (attention, gdn, kda_norm, short_conv):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = cell_config("qwen3_next_80b_l5_ep32")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (2, 8192))).compile()
@@ -104,7 +104,7 @@ def test_qwen3_next_step_fits_the_chip_under_the_rule_s_limit(topo, monkeypatch)
     live = _live_bytes(c)
     print("live GiB", live / GIB, "reckoned", plan.reckoned_bytes / GIB, c.memory_analysis())
     assert live < 14.0 * GIB, c.memory_analysis()
-    assert plan.reckoned_bytes <= 13.5 * GIB
+    assert plan.reckoned_bytes <= V5E_ROOM
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.75 * GIB, (plan, c.memory_analysis())
     kinds = _kinds(c.as_text())
     assert {k: n for k, n in kinds.items()

@@ -9,8 +9,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import attention
 from ray_tpu.parallel.train_step import TrainStep
-from tests._tpu_compile import (GIB, KERNELS, _CUSTOM_CALL, _kinds, _live_bytes, _loss, _qkv,
-                                _step_args, cell_config)
+from tests._tpu_compile import (GIB, V5E_LIMIT, V5E_ROOM, KERNELS, _CUSTOM_CALL, _kinds,
+                                _live_bytes, _loss, _qkv, _step_args, cell_config)
 
 
 def test_block_diffusion_flash_compiles_at_the_cell_s_shape(one_chip):
@@ -46,20 +46,20 @@ def test_block_diffusion_flash_compiles_at_the_smoke_s_shapes(one_chip, shape, l
 @pytest.mark.timeout(900)
 def test_sdar_step_holds_what_the_rule_books(topo, monkeypatch):
     """sdar_30b_a3b_l5_ep8.t8192's whole step compiled for the described v5e:
-    the program stands under the 13.5 GiB a step is held to and within the
+    the program stands under the 14.12 GiB a step is held to (13.5 until PR 65) and within the
     error the reckoning has shown of what it reckoned; one flash pair, q's
     and k's prep pair and the expert layer's calls a layer."""
     from ray_tpu.models import remat
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(remat, "chip_limit", lambda stream: 15 * GIB)
+    monkeypatch.setattr(remat, "chip_limit", lambda stream: V5E_LIMIT)
     cfg = cell_config("sdar_30b_a3b_l5_ep8")
     ts = TrainStep(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)), telemetry=False)
     c = ts._step.lower(*_step_args(ts, (1, 8192))).compile()
     plan = remat.traced(cfg)
     live = _live_bytes(c)
     print("plan", plan, "live GiB", live / GIB, c.memory_analysis())
-    assert live < 13.5 * GIB, c.memory_analysis()
+    assert live < V5E_ROOM, c.memory_analysis()
     assert -0.85 * GIB <= live - plan.reckoned_bytes <= 0.35 * GIB, (plan, c.memory_analysis())
     kinds = _kinds(c.as_text())
     print(dict(kinds))
